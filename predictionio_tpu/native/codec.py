@@ -264,7 +264,7 @@ def parse_jsonl(data: bytes,
 
 # ---------------------------------------------------------------------------
 # Ingest kernels — vectorized merge/pad/bucketize host passes
-# (lib ingest_kernels; the 35s monolithic bucketize pass of BENCH_r04).
+# (lib ingest_kernels; the 35s monolithic bucketize pass of the round-4 bench).
 # Each wrapper returns None when the native lib is unavailable; callers
 # fall back to the byte-identical numpy path.
 # ---------------------------------------------------------------------------

@@ -302,7 +302,10 @@ class BucketedRatings:
 
     @property
     def nnz(self) -> int:
-        return int(sum(b.mask.sum() for b in self.buckets))
+        # integer sums, bucket by bucket: the masks are float32, and a
+        # float32 running total stops counting exactly at 2^24 (at the
+        # ML-20M shape it came out one pair short of 17,506,609)
+        return sum(int(b.mask.sum(dtype=np.int32)) for b in self.buckets)
 
     @property
     def occupancy(self) -> float:
@@ -1526,8 +1529,9 @@ def warmup_train_als_bucketed(user_side: BucketedRatings,
     computing immediately instead of paying its jit wait. The pipelined
     ingest runs this on a background thread WHILE the bucket tables'
     H2D transfers stream — compile time hides inside the transfer
-    window. Best-effort: returns False (and the normal jit path compiles
-    as before) if this jax version's AOT path declines.
+    window. Returns True once every program of the run is cached; a
+    program the device compiler refuses raises with the compiler's
+    message (the train call that follows would hit the same error).
 
     ``params`` may also be an :class:`~predictionio_tpu.ops.tuning.
     ConfigGrid` — then the VMAPPED multi-config signature is lowered
@@ -1535,80 +1539,51 @@ def warmup_train_als_bucketed(user_side: BucketedRatings,
     same zero-steady-state-compile contract as serial training."""
     import os
 
+    from predictionio_tpu.ops import aot
+
+    def warm(cache, jitted, args, kw) -> None:
+        key = _bucketed_aot_key(args, kw)
+        if key not in cache:
+            cache.put(key, aot.lower_compile(jitted, *args, **kw))
+
     configs = getattr(params, "configs", None)
-    try:
-        from predictionio_tpu.ops import aot
+    if configs is not None:
+        base = configs[0]
+        precision = _als_precision_mode(base)
+        for n in _checkpoint_chunk_lengths(base):
+            warm(_aot_grid, _get_grid_jit(),
+                 *_grid_call_args(user_side, item_side, configs,
+                                  precision, abstract=True,
+                                  num_iterations=n))
+        if _train_telemetry_enabled():
+            # the per-chunk objective sample joins the ladder so the
+            # telemetry plane keeps the zero-steady-state-compile
+            # contract (grid samples run even without checkpointing:
+            # the end-of-run divergence grading needs one)
+            warm(_aot_objective_grid, _get_objective_grid_jit(),
+                 *_objective_call_args(user_side, item_side, base,
+                                       precision, configs=configs))
+        return True
 
-        if configs is not None:
-            base = configs[0]
-            precision = _als_precision_mode(base)
-            ok = True
-            for n in _checkpoint_chunk_lengths(base):
-                args, kw = _grid_call_args(user_side, item_side, configs,
-                                           precision, abstract=True,
-                                           num_iterations=n)
-                key = _bucketed_aot_key(args, kw)
-                if key in _aot_grid:
-                    continue
-                compiled = aot.lower_compile(_get_grid_jit(), *args, **kw)
-                if compiled is None:
-                    ok = False
-                    continue
-                _aot_grid.put(key, compiled)
-            if _train_telemetry_enabled():
-                # the per-chunk objective sample joins the ladder so the
-                # telemetry plane keeps the zero-steady-state-compile
-                # contract (grid samples run even without checkpointing:
-                # the end-of-run divergence grading needs one)
-                args, okw = _objective_call_args(
-                    user_side, item_side, base, precision,
-                    configs=configs)
-                key = _bucketed_aot_key(args, okw)
-                if key not in _aot_objective_grid:
-                    compiled = aot.lower_compile(
-                        _get_objective_grid_jit(), *args, **okw)
-                    if compiled is None:
-                        ok = False
-                    else:
-                        _aot_objective_grid.put(key, compiled)
-            return ok
-
-        precision = _als_precision_mode(params)
-        # with checkpointing active the chunked loop dispatches
-        # chunk-length scans (at most two distinct trip counts) —
-        # lower each so the warmed first train stays compile-free
-        # under the crash-safe lifecycle too
-        ok = True
-        for n in _checkpoint_chunk_lengths(params):
-            args, kw = _bucketed_call_args(user_side, item_side, params,
-                                           precision, abstract=True,
-                                           num_iterations=n)
-            key = _bucketed_aot_key(args, kw)
-            if key in _aot_bucketed:
-                continue
-            compiled = aot.lower_compile(_get_bucketed_jit(), *args, **kw)
-            if compiled is None:
-                ok = False
-                continue
-            _aot_bucketed.put(key, compiled)
-        if _train_telemetry_enabled() and os.environ.get(
-                "PIO_CHECKPOINT_DIR", "").strip():
-            # serial objective samples only run inside the chunked
-            # checkpoint loop — lower the program alongside the
-            # chunk-length scans it will interleave with
-            args, okw = _objective_call_args(user_side, item_side,
-                                             params, precision)
-            key = _bucketed_aot_key(args, okw)
-            if key not in _aot_objective:
-                compiled = aot.lower_compile(
-                    _get_objective_jit(), *args, **okw)
-                if compiled is None:
-                    ok = False
-                else:
-                    _aot_objective.put(key, compiled)
-        return ok
-    except Exception:
-        return False
+    precision = _als_precision_mode(params)
+    # with checkpointing active the chunked loop dispatches
+    # chunk-length scans (at most two distinct trip counts) —
+    # lower each so the warmed first train stays compile-free
+    # under the crash-safe lifecycle too
+    for n in _checkpoint_chunk_lengths(params):
+        warm(_aot_bucketed, _get_bucketed_jit(),
+             *_bucketed_call_args(user_side, item_side, params,
+                                  precision, abstract=True,
+                                  num_iterations=n))
+    if _train_telemetry_enabled() and os.environ.get(
+            "PIO_CHECKPOINT_DIR", "").strip():
+        # serial objective samples only run inside the chunked
+        # checkpoint loop — lower the program alongside the
+        # chunk-length scans it will interleave with
+        warm(_aot_objective, _get_objective_jit(),
+             *_objective_call_args(user_side, item_side, params,
+                                   precision))
+    return True
 
 
 def train_als_bucketed(user_side: BucketedRatings,
@@ -1644,6 +1619,7 @@ def train_als_bucketed(user_side: BucketedRatings,
         import jax.numpy as jnp
 
         from predictionio_tpu.workflow import checkpoint as _checkpoint
+        from predictionio_tpu.workflow import runlog as _runlog
 
         fdt = X.dtype
 
@@ -1658,11 +1634,16 @@ def train_als_bucketed(user_side: BucketedRatings,
             def objective(Xc, Yc):
                 return _objective_pack(Xc, Yc, u_t, **obj_kw)
 
-        X, Y = _checkpoint.run_chunked(
-            run_iters, X, Y, int(params.num_iterations), ckpt,
-            to_host=lambda a: np.asarray(a, dtype=np.float32),
-            from_host=lambda a: jnp.asarray(a, dtype=fdt),
-            objective=objective)
+        # the run-log header names what the platform resolved (solver,
+        # precision), how many pairs train and over how many devices
+        with _runlog.run_context_scope(
+                solver=kw["solver"], precision=precision,
+                trainedPairs=user_side.nnz, devices=1):
+            X, Y = _checkpoint.run_chunked(
+                run_iters, X, Y, int(params.num_iterations), ckpt,
+                to_host=lambda a: np.asarray(a, dtype=np.float32),
+                from_host=lambda a: jnp.asarray(a, dtype=fdt),
+                objective=objective)
     # host factors always land fp32: persistence, serving and the eval
     # stack stay byte-compatible regardless of the training policy
     return (np.asarray(X, dtype=np.float32),

@@ -5,29 +5,21 @@ Three kernels live here:
 1. ``spd_solve`` — batched symmetric positive-definite solve (Cholesky
    factorization + forward/backward triangular substitution fused in
    one kernel, batch on the lane dimension, matrices resident in VMEM
-   across all R steps). XLA's batched ``cho_factor``/``cho_solve`` is
-   the measured bottleneck of the ALS epoch on TPU (~1.1 s for 138k
-   rank-64 systems at the 10M-event scale — its per-column expansion
-   round-trips HBM every step). STATUS — experimental, NOT the default:
-   an earlier batch-major variant compiled but ran slower than
-   cho_solve (1.6 s; lane padding waste + loop-carry copies), and this
-   lane-major variant's dynamic ref indexing wedged the Mosaic compile
-   pipeline on the available toolchain. The production TPU solver is
-   the pure-XLA batch-on-lanes blocked panel factorization
-   ``ops.als.spd_solve_lanes`` (same layout idea, plain dynamic_slice
-   ops, one MXU rank-`panel` trailing update per panel); this kernel is
-   opt-in via ``PIO_ALS_SOLVER=pallas`` and exercised in interpret mode
-   by tests.
+   across all R steps). STATUS — off the default path, opt-in via
+   ``PIO_ALS_SOLVER=pallas``; the TPU default is the pure-XLA
+   batch-on-lanes blocked panel factorization
+   ``ops.als.spd_solve_lanes``. It compiles through Mosaic and
+   agrees with ``spd_solve_lanes`` and XLA's ``cho_solve`` on a TPU v5e
+   at rank 64, and is the fastest of the three on the solve alone
+   (PERF.md section 5, "Off-path kernels"). Its share of a training
+   epoch has not been measured; whether it replaces ``lanes`` is
+   ROADMAP Design 4's decision.
 
 2. ``assemble_normal_equations`` — fused gather + normal-equation
-   assembly. STATUS: correctness-proven, not the default. Measured on a
-   real v5e chip at MovieLens-100K scale (943x1682, rank 64): XLA's
-   fused take+einsum half-step runs ~0.02 ms vs ~2.5 ms for this kernel
-   — the serial row-by-row DMA dominates and XLA's gather fusion is
-   already excellent, so ``ops/als.py`` keeps the XLA path for
-   assembly. The kernel stays as the exercised foundation for
-   DMA-gather work, with interpret-mode tests asserting exact agreement
-   with the XLA math.
+   assembly. STATUS: correct, slow, not the default. On the chip it
+   matches XLA's fused take+einsum and is far slower (PERF.md section
+   5): the serial row-by-row DMA dominates and XLA's gather fusion is
+   already good, so ``ops/als.py`` keeps the XLA path for assembly.
 
 3. ``fused_gather_score_topk`` — the SERVING kernel (ROADMAP item 4):
    score matvec + seen-row masking + top-k selection fused into one
@@ -37,14 +29,17 @@ Three kernels live here:
    tile streams HBM->VMEM exactly once (int8 tiles dequantize against
    their per-row scales in VMEM — the Tensor Casting co-design axis),
    is scored on the MXU against the whole query block, masked in
-   registers, and folded into a running per-query top-k held in VMEM
-   across the grid; only the final ``[B, k]`` winners ever reach HBM.
-   A per-tile early-out skips the selection merge whenever the tile's
-   best score cannot beat any query's current k-th — on real catalogs
-   the vast majority of tiles take it. STATUS: the production device
-   path for ``DeviceTopK`` (``PIO_SERVE_KERNEL=xla`` opts out; CPU
-   serves the XLA chain and exercises this kernel in interpret mode,
-   like ``spd_solve``).
+   registers from the packed seen bitmap, and folded into a running
+   per-query top-k held in VMEM across the grid; only the final
+   ``[B, k]`` winners ever reach HBM. A per-tile early-out skips the
+   selection merge whenever the tile's best score cannot beat any
+   query's current k-th. STATUS: the production device path for
+   ``DeviceTopK`` (``PIO_SERVE_KERNEL=xla`` opts out; CPU serves the
+   XLA chain and exercises this kernel in interpret mode, like
+   ``spd_solve``). On the v5e at the ML-20M store shape its answers
+   equal the XLA chain's on the same fp32, bf16 and int8 stores, and a
+   dispatch takes about as long as the chain's — no faster end to end
+   yet (PERF.md section 5; ROADMAP Speed 5).
 
 Run on CPU (tests) via interpret mode — semantics identical, speed not.
 """
@@ -350,6 +345,54 @@ TOPK_TILE_M = 128
 # with the batch on the lane dimension)
 _TOPK_B_ALIGN = 8
 
+# seen items travel as a packed bitmap: one int32 word per 32 store
+# positions (see pack_seen_bits)
+SEEN_WORD_BITS = 32
+
+
+def pack_seen_bits(hit):
+    """``[..., P]`` boolean hit mask -> ``[..., ceil(P / 32)]`` int32
+    words, bit ``j`` of word ``w`` = position ``32 * w + j`` (the layout
+    the kernel unpacks per tile and ``ops.serving.seen_bitmap`` builds
+    on host)."""
+    import jax.numpy as jnp
+
+    P = hit.shape[-1]
+    pad = (-P) % SEEN_WORD_BITS
+    if pad:
+        hit = jnp.pad(hit, [(0, 0)] * (hit.ndim - 1) + [(0, pad)])
+    h = hit.reshape(hit.shape[:-1] + (-1, SEEN_WORD_BITS))
+    shifts = jnp.arange(SEEN_WORD_BITS, dtype=jnp.int32)
+    # distinct bits: the int32 sum wraps exactly like a bitwise OR
+    return jnp.sum(jnp.left_shift(h.astype(jnp.int32), shifts),
+                   axis=-1, dtype=jnp.int32)
+
+
+def pack_seen_ids(ids, live, n_pos: int):
+    """``[B, L]`` position lists (``live`` marks the real slots) ->
+    the ``[B, ceil(n_pos / 32)]`` packed bitmap. For SHORT lists — a
+    similarity query masking its own query items; a user's history
+    lives in the store's bitmap already."""
+    import jax.numpy as jnp
+
+    ids = jnp.asarray(ids, dtype=jnp.int32)
+    live = jnp.asarray(live) & (ids >= 0) & (ids < n_pos)
+    hit = jnp.zeros((ids.shape[0], n_pos), dtype=jnp.bool_).at[
+        jnp.arange(ids.shape[0])[:, None],
+        jnp.where(live, ids, n_pos)].set(True, mode="drop")
+    return pack_seen_bits(hit)
+
+
+def unpack_seen_bits(words, n_pos: int):
+    """Inverse of :func:`pack_seen_bits`: ``[..., W]`` int32 words ->
+    ``[..., n_pos]`` boolean hit mask."""
+    import jax.numpy as jnp
+
+    shifts = jnp.arange(SEEN_WORD_BITS, dtype=jnp.int32)
+    # (arithmetic shift: the sign fill never reaches bit 0)
+    h = jnp.right_shift(words[..., None], shifts) & 1
+    return h.reshape(words.shape[:-1] + (-1,))[..., :n_pos] > 0
+
 
 def _topk_select_body(scores, item_ids, run_v, run_i, buf_v, buf_i, K):
     """Fold one ``[TM, B]`` score tile into the running per-query
@@ -385,14 +428,16 @@ def _topk_select_body(scores, item_ids, run_v, run_i, buf_v, buf_i, K):
     jax.lax.fori_loop(0, K, sel, 0)
 
 
-def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sc_ref, sm_ref,
+def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sb_ref,
                      vals_ref, idx_ref, run_v, run_i, buf_v, buf_i,
-                     *, K, n_items, n_tiles, mask_seen):
+                     *, K, n_items, n_tiles):
     """One grid step = one ``[TM, R]`` item tile scored, masked, and
     merged (see module docstring). ``ys_ref`` is None for dense f32/
     bf16 stores; for int8 stores it carries the tile's per-row fp32
     scales and the dequantize happens here in VMEM — HBM only ever
-    streams the int8 bytes."""
+    streams the int8 bytes. ``sb_ref`` (None = no seen mask) is the
+    tile's ``[TM // 32, B]`` slice of the packed seen bitmap: bit ``j``
+    of word ``w`` masks tile row ``32 * w + j``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -423,15 +468,20 @@ def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sc_ref, sm_ref,
         # real items are bin-packed, not a contiguous prefix, so a
         # static n_items bound cannot express them)
         scores = jnp.where(rv_ref[:] > 0, scores, -jnp.inf)
-    if mask_seen:
-        L = sc_ref.shape[0]
-
-        def mask_step(l, s):
-            hit = (item_ids == sc_ref[l][None, :]) \
-                & (sm_ref[l] > 0)[None, :]
-            return jnp.where(hit, -jnp.inf, s)
-
-        scores = jax.lax.fori_loop(0, L, mask_step, scores)
+    if sb_ref is not None:
+        # unpack the tile's seen words into a [TM, B] hit mask: one
+        # variable right-shift per 32-row group, no loop over a seen
+        # LIST — the mask costs the same whether a user has seen ten
+        # items or ten thousand
+        words = sb_ref[0]                             # [TM // 32, B]
+        Bq = words.shape[1]
+        bit = jax.lax.broadcasted_iota(jnp.int32, (SEEN_WORD_BITS, Bq), 0)
+        hit = jnp.concatenate([
+            jax.lax.shift_right_logical(
+                jnp.broadcast_to(words[j:j + 1, :],
+                                 (SEEN_WORD_BITS, Bq)), bit) & 1
+            for j in range(TM // SEEN_WORD_BITS)], axis=0)
+        scores = jnp.where(hit > 0, -jnp.inf, scores)
 
     # early-out: a tile whose best score cannot beat any query's
     # current k-th never changes the heap (ties lose to the running
@@ -450,8 +500,8 @@ def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sc_ref, sm_ref,
         idx_ref[:] = run_i[:]
 
 
-def fused_gather_score_topk(Q, Y, seen_cols, seen_mask, *, k: int,
-                            n_items: int, mask_seen: bool = True,
+def fused_gather_score_topk(Q, Y, seen_bits=None, *,
+                            k: int, n_items: int, mask_seen: bool = True,
                             row_valid=None,
                             interpret: Optional[bool] = None,
                             tile_m: Optional[int] = None):
@@ -463,9 +513,13 @@ def fused_gather_score_topk(Q, Y, seen_cols, seen_mask, *, k: int,
     jitted program as this call); ``Y`` the item store — a dense
     ``[M, R]`` fp32/bf16 table or an int8
     :class:`~predictionio_tpu.ops.quantize.QuantFactors` whose per-row
-    scales dequantize in VMEM; ``seen_cols``/``seen_mask`` ``[L, B]``
-    per-query masked item ids (ignored when ``mask_seen`` is False);
-    ``row_valid`` an optional ``[M]`` per-row validity vector (>0 =
+    scales dequantize in VMEM. With ``mask_seen`` the masked rows come
+    as ``seen_bits`` — ``[B, >= ceil(M / 32)]`` int32 words of the
+    packed bitmap (:func:`pack_seen_bits`; what ``DeviceTopK`` keeps
+    per user, so a dispatch moves ``M / 8`` bytes per query however
+    long the user's history; a similarity query packs its own query
+    items with :func:`pack_seen_ids`).
+    ``row_valid`` is an optional ``[M]`` per-row validity vector (>0 =
     real item) for stores whose real rows are not a contiguous prefix
     — the density-sharded per-shard lane.
 
@@ -487,6 +541,9 @@ def fused_gather_score_topk(Q, Y, seen_cols, seen_mask, *, k: int,
     B = Q.shape[0]
     K = int(k)
     TM = int(tile_m) if tile_m else TOPK_TILE_M
+    if TM % SEEN_WORD_BITS:
+        raise ValueError(f"tile_m={TM} must be a multiple of "
+                         f"{SEEN_WORD_BITS} (the seen-bitmap word)")
     padM = (-M) % TM
     if padM:  # DeviceTopK pre-pads its store; direct callers pay once
         Yd = jnp.pad(Yd, ((0, padM), (0, 0)))
@@ -517,17 +574,14 @@ def fused_gather_score_topk(Q, Y, seen_cols, seen_mask, *, k: int,
         in_specs.append(pl.BlockSpec((TM, 1), lambda t: (t, 0)))
         args.append(rv)
     if mask_seen:
-        L = seen_cols.shape[0]
-        sc = jnp.asarray(seen_cols, dtype=jnp.int32)
-        sm = jnp.asarray(seen_mask, dtype=jnp.float32)
-        if padB:
-            sc = jnp.pad(sc, ((0, 0), (0, padB)))
-            sm = jnp.pad(sm, ((0, 0), (0, padB)))
-        in_specs += [
-            pl.BlockSpec((L, Bp), lambda t: (0, 0)),
-            pl.BlockSpec((L, Bp), lambda t: (0, 0)),
-        ]
-        args += [sc, sm]
+        wt = TM // SEEN_WORD_BITS
+        sb = jnp.asarray(seen_bits, dtype=jnp.int32)
+        sb = sb[:, :n_tiles * wt]
+        sb = jnp.pad(sb, ((0, padB), (0, n_tiles * wt - sb.shape[1])))
+        # [n_tiles, TM // 32, Bp]: batch on lanes like the scores, one
+        # leading-dim block per grid step
+        in_specs.append(pl.BlockSpec((1, wt, Bp), lambda t: (t, 0, 0)))
+        args.append(sb.T.reshape(n_tiles, wt, Bp))
 
     def kernel(*refs):
         qr = refs[0]
@@ -541,15 +595,14 @@ def fused_gather_score_topk(Q, Y, seen_cols, seen_mask, *, k: int,
         if has_valid:
             rvr = refs[pos]
             pos += 1
-        scr = smr = None
+        sbr = None
         if mask_seen:
-            scr, smr = refs[pos], refs[pos + 1]
-            pos += 2
+            sbr = refs[pos]
+            pos += 1
         vals_ref, idx_ref, run_v, run_i, buf_v, buf_i = refs[pos:]
-        _fused_topk_body(qr, ydr, ysr, rvr, scr, smr, vals_ref, idx_ref,
+        _fused_topk_body(qr, ydr, ysr, rvr, sbr, vals_ref, idx_ref,
                          run_v, run_i, buf_v, buf_i, K=K,
-                         n_items=n_items, n_tiles=n_tiles,
-                         mask_seen=mask_seen)
+                         n_items=n_items, n_tiles=n_tiles)
 
     vals, idx = pl.pallas_call(
         kernel,

@@ -14,9 +14,12 @@ to call directly. Two consumers share this module:
   no live query ever pays a serve-time compile (SURVEY hard part #4,
   asserted by the jit-compile monitor in ``bench.serving_load_bench``).
 
-Both are best-effort: a cache miss (or a jax version whose AOT path
-declines) falls back to the plain jit wrapper, which compiles as
-before — correctness never depends on the cache, only latency does.
+A cache MISS falls back to the plain jit wrapper, which compiles as
+before — correctness never depends on the cache, only latency does. A
+compile FAILURE is not a miss: :func:`lower_compile` lets the
+compiler's exception out, so a program the device compiler refuses
+fails the deploy (or the train) that asked for it instead of leaving a
+server that reports ready and compiles, or fails, on a live query.
 
 Observability (PR 12): evictions are counted and logged WITH the
 dropped key — a fold-in-growth recompile storm shows up as a rising
@@ -83,10 +86,13 @@ class AOTCache:
                     "%s cache full (%d entries): evicted executable for "
                     "%r to admit %r", self.name, self._max, old_key, key)
 
-    def clear(self) -> None:
+    def discard(self, match) -> None:
+        """Drop every entry whose key ``match`` accepts — a deliberate
+        release (the shape they were compiled for is gone), so it is
+        not counted as an eviction."""
         with self._lock:
-            self._entries.clear()
-            self._mem_cache.clear()
+            for key in [k for k in self._entries if match(k)]:
+                self._mem_cache.pop(id(self._entries.pop(key)), None)
 
     def __len__(self) -> int:
         with self._lock:
@@ -165,16 +171,25 @@ class AOTCache:
                 "totalBytes": total}
 
 
-def lower_compile(jitted, *args, **kwargs) -> Optional[Any]:
-    """``jitted.lower(*args, **kwargs).compile()``, best-effort.
+# Tracing + lowering happens one program at a time, process-wide. It is
+# Python-bound, so threads gain nothing from overlapping it — and
+# tracing ONE jit object from several threads at once is not
+# deterministic: on the four-chip host the second `pio deploy` of the
+# same model re-keyed 12 of 44 ladder programs (the three racers per
+# fresh k-bucket program) and compiled them again instead of finding
+# them in the persistent cache (PR 21). The XLA compile itself runs
+# outside the lock, which is where a thread pool does help.
+_lower_lock = threading.Lock()
+
+
+def lower_compile(jitted, *args, **kwargs) -> Any:
+    """``jitted.lower(*args, **kwargs).compile()``.
 
     ``args`` may mix concrete arrays (their shape/dtype/sharding is
     baked into the executable — pass the REAL factor stores so a
     sharded model compiles for its own mesh) and
-    ``jax.ShapeDtypeStruct`` placeholders for per-call inputs. Returns
-    ``None`` when this jax version's AOT path declines; callers keep
-    the plain jit wrapper as the fallback."""
-    try:
-        return jitted.lower(*args, **kwargs).compile()
-    except Exception:
-        return None
+    ``jax.ShapeDtypeStruct`` placeholders for per-call inputs. A
+    lowering or compile error propagates with the compiler's message."""
+    with _lower_lock:
+        lowered = jitted.lower(*args, **kwargs)
+    return lowered.compile()

@@ -92,12 +92,7 @@ def _ring_attention_local(q, k, v, kv_mask=None, *, axis_name: str,
     # accumulators: numerator [B,H,L,D], denominator + running max [B,H,L].
     # Mark the (device-constant) initializers as varying over the ring
     # axis so the fori_loop carry type matches its per-device outputs.
-    if hasattr(jax.lax, "pcast"):
-        _vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
-    elif hasattr(jax.lax, "pvary"):
-        _vary = lambda x: jax.lax.pvary(x, (axis_name,))
-    else:  # jax <= 0.4.x: no varying-type system — carries need no mark
-        _vary = lambda x: x
+    _vary = lambda x: jax.lax.pcast(x, (axis_name,), to="varying")
     o0 = _vary(jnp.zeros((B, H, L, D), dtype=jnp.float32))
     l0 = _vary(jnp.zeros((B, H, L), dtype=jnp.float32))
     m0 = _vary(jnp.full((B, H, L), -jnp.inf, dtype=jnp.float32))
@@ -171,14 +166,10 @@ def _sp_program(local_body, mesh, axis_name: str, with_mask: bool = False):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     in_specs = (P(None, None, axis_name, None),) * 3
     if with_mask:
         in_specs = in_specs + (P(None, axis_name),)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_body,
         mesh=mesh,
         in_specs=in_specs,

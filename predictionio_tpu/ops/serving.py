@@ -9,8 +9,8 @@ with an AOT-compiled gather→matmul→top_k program:
 
 - scores = Y @ X[uid] runs on the MXU; top_k stays on device; only the
   k winners travel back over PCIe.
-- already-rated items are masked on device from the padded seen table
-  (the same [N, L] layout the trainer uses).
+- already-rated items are masked on device from a packed bitmap (one
+  bit per user and store position, :func:`seen_bitmap`).
 - programs are compiled per top-k BUCKET (next power of two) so any
   (num, blacklist) request reuses a handful of compiled programs; the
   deploy path warms the common buckets so the first query pays no
@@ -24,7 +24,7 @@ Transport discipline (the reference serves from in-JVM memory with zero
 device hops, `CreateServer.scala:533-540` — so every host↔device round
 trip here is pure regression and is treated as such):
 
-- each program packs (scores, bitcast(indices)) into ONE flat float32
+- each program packs (bitcast(scores), indices) into ONE flat int32
   output, so a query pays exactly one blocking device→host fetch; the
   uid travels inside the jit dispatch (no separate transfer op).
 - `users_topk` vmaps the same program over a padded uid bucket: B
@@ -84,12 +84,9 @@ def _default_serve_precision() -> str:
     with scores still accumulated fp32 — quality-gated by the PR-5
     Precision@10 check). CPU keeps fp32: there is no native bf16
     datapath there, so the cast costs latency and buys nothing."""
-    try:
-        import jax
+    import jax
 
-        return "bf16" if jax.default_backend() != "cpu" else "fp32"
-    except Exception:  # pragma: no cover - jax must exist to serve
-        return "fp32"
+    return "bf16" if jax.default_backend() != "cpu" else "fp32"
 
 
 def _serve_precision_mode() -> str:
@@ -211,20 +208,35 @@ def _score_einsum(subscripts: str, *operands, mode: str):
                      f"{', '.join(SERVE_PRECISION_MODES)})")
 
 
-def seen_tables(seen: Dict[int, np.ndarray], n_rows: int,
-                pad_multiple: int = 8) -> Tuple[np.ndarray, np.ndarray]:
-    """Pack a ``{user_idx: item_idx array}`` dict into padded
-    ``(cols [N, L] int32, mask [N, L] float32)`` tables for on-device
-    masking. L = longest seen list, padded to ``pad_multiple``."""
-    longest = max((len(v) for v in seen.values()), default=0)
-    L = max(1, -(-max(longest, 1) // pad_multiple) * pad_multiple)
-    cols = np.zeros((n_rows, L), dtype=np.int32)
-    mask = np.zeros((n_rows, L), dtype=np.float32)
-    for u, items in seen.items():
-        m = min(len(items), L)
-        cols[u, :m] = items[:m]
-        mask[u, :m] = 1.0
-    return cols, mask
+def seen_bitmap(seen: Dict[int, np.ndarray], n_rows: int,
+                n_pos: int) -> np.ndarray:
+    """Pack a ``{user_idx: item position array}`` dict into the
+    ``[n_rows, ceil(n_pos / 32)]`` int32 bitmap the device masks from:
+    bit ``j`` of word ``w`` in row ``u`` = user ``u`` has seen position
+    ``32 * w + j`` (positions outside ``[0, n_pos)`` carry no bit).
+
+    A row costs ``n_pos / 8`` bytes whatever the user's history. The
+    padded id-list layout this replaces cost ``8 * longest_history``
+    bytes for EVERY user: at the ML-20M shape (138k users, 27k items,
+    longest history 18k) that is 20 GB against 0.47 GB here, and the
+    fused kernel looped over the list per item tile."""
+    W = max(1, -(-int(n_pos) // 32))
+    bits = np.zeros((int(n_rows), W), dtype=np.uint32)
+    if seen:
+        users = np.fromiter(seen.keys(), dtype=np.int64, count=len(seen))
+        lens = np.fromiter((len(v) for v in seen.values()),
+                           dtype=np.int64, count=len(seen))
+        if lens.sum():
+            rows = np.repeat(users, lens)
+            pos = np.concatenate(
+                [np.asarray(v, dtype=np.int64).ravel()
+                 for v in seen.values()])
+            ok = (pos >= 0) & (pos < n_pos) & (rows < n_rows)
+            rows, pos = rows[ok], pos[ok]
+            np.bitwise_or.at(
+                bits.reshape(-1), rows * W + (pos >> 5),
+                np.left_shift(np.uint32(1), (pos & 31).astype(np.uint32)))
+    return bits.view(np.int32)
 
 
 def _mask_padding(scores, n_items: int):
@@ -239,19 +251,28 @@ def _mask_padding(scores, n_items: int):
 
 
 def _pack(scores, idx):
-    """Fuse (scores [.., k] f32, idx [.., k] i32) into ONE [.., 2k] f32
-    buffer (indices bitcast, not value-cast — exact at any size) so the
-    host pays a single device→host fetch per dispatch."""
+    """Fuse (scores [.., k] f32, idx [.., k] i32) into ONE [.., 2k]
+    int32 buffer (scores bitcast, not value-cast — exact) so the host
+    pays a single device→host fetch per dispatch.
+
+    The buffer is INTEGER on purpose. Reinterpreted as float32, an
+    index below 2^23 is a denormal, and the TPU flushes denormals to
+    zero wherever XLA routes the copy through a float data path: on
+    the v5e a float32-packed buffer came back with the right scores
+    and every index 0 (PR 21's first chip run; CPU and interpret mode
+    never flush, so no test saw it). Integer lanes carry any bit
+    pattern unchanged, float32 scores included."""
     import jax
     import jax.numpy as jnp
 
     return jnp.concatenate(
-        [scores, jax.lax.bitcast_convert_type(idx, jnp.float32)], axis=-1)
+        [jax.lax.bitcast_convert_type(scores, jnp.int32),
+         idx.astype(jnp.int32)], axis=-1)
 
 
 def _unpack(out: np.ndarray, kb: int) -> Tuple[np.ndarray, np.ndarray]:
     """Host-side inverse of `_pack` on the fetched numpy buffer."""
-    return out[..., kb:].view(np.int32), out[..., :kb]
+    return out[..., kb:], out[..., :kb].view(np.float32)
 
 
 def _take_user_row_f32(X, uid, *, mode: str):
@@ -372,7 +393,7 @@ def _tree_merge_topk(vals, idx, k: int, axis: str, n_sh: int):
     return v, jnp.take_along_axis(ai, sel, axis=-1)
 
 
-def _sharded_score_topk(Y, valid, Q, sc_q, sm_q, *, k: int,
+def _sharded_score_topk(Y, valid, Q, seen_bits, *, k: int,
                         mask_seen: bool, mode: str, mesh, axis: str,
                         fused: bool, interpret: bool):
     """Score + mask + top-k over a mesh-sharded item store, explicitly:
@@ -380,40 +401,46 @@ def _sharded_score_topk(Y, valid, Q, sc_q, sm_q, *, k: int,
     the shard scores it against the replicated queries (XLA chain, or
     the fused Pallas kernel running per-shard on its local tiles),
     masks invalid positions (``valid`` — the density layout's real-item
-    mask) and out-of-shard seen ids, takes its local ``lax.top_k``, and
-    the per-shard runs merge on device (:func:`_tree_merge_topk`).
+    mask) and its own slice of the seen bitmap, takes its local
+    ``lax.top_k``, and the per-shard runs merge on device
+    (:func:`_tree_merge_topk`).
 
-    ``Q [B, R]`` fp32 replicated queries; ``sc_q``/``sm_q`` ``[B, L]``
-    per-query masked POSITIONS (+ mask) in the store's layout. Returns
-    ``(vals [B, k] f32, positions [B, k] i32)`` replicated."""
+    ``Q [B, R]`` fp32 replicated queries; ``seen_bits`` ``[B, W]`` the
+    queries' packed seen bitmap over store POSITIONS (replicated;
+    ignored without ``mask_seen``). Returns ``(vals [B, k] f32,
+    positions [B, k] i32)`` replicated."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
+    from predictionio_tpu.ops.als_pallas import (
+        fused_gather_score_topk,
+        pack_seen_bits,
+        unpack_seen_bits,
+    )
     from predictionio_tpu.ops.quantize import QuantFactors, is_quantized
 
     n_sh = int(mesh.shape[axis])
     quant = is_quantized(Y)
 
-    def body(Yd, Ys, vl, Qb, scq, smq):
+    def body(Yd, Ys, vl, Qb, sbq):
         m = int(Yd.shape[0])
         off = lax.axis_index(axis) * m
-        loc = scq - off                 # [B, L] shard-local seen ids
-        in_shard = (loc >= 0) & (loc < m) & (smq > 0)
+        hit = None
+        if mask_seen:
+            # this shard's [B, m] slice of the seen mask (a shard's
+            # position range need not start on a word boundary, so
+            # slice the unpacked mask, not the words)
+            hit = lax.dynamic_slice_in_dim(
+                unpack_seen_bits(sbq, n_sh * m), off, m, axis=1)
         kl = min(k, m)
         if fused:
-            from predictionio_tpu.ops.als_pallas import (
-                fused_gather_score_topk,
-            )
-
             Yl = QuantFactors(Yd, Ys) if quant else Yd
             vals, li = fused_gather_score_topk(
-                Qb, Yl, jnp.where(in_shard, loc, -1).T,
-                in_shard.T.astype(jnp.float32), k=kl, n_items=m,
-                mask_seen=mask_seen, row_valid=vl,
-                interpret=interpret)
+                Qb, Yl, k=kl, n_items=m, mask_seen=mask_seen,
+                seen_bits=pack_seen_bits(hit) if mask_seen else None,
+                row_valid=vl, interpret=interpret)
         else:
             if quant:
                 # dequant into the fp32 accumulate locally (the int8
@@ -427,10 +454,7 @@ def _sharded_score_topk(Y, valid, Q, sc_q, sm_q, *, k: int,
                 scores = _score_einsum("mr,br->bm", Yd, Qb, mode=mode)
             scores = jnp.where(vl[None, :] > 0, scores, -jnp.inf)
             if mask_seen:
-                lc = jnp.clip(loc, 0, m - 1)
-                add = jnp.where(in_shard, -jnp.inf, 0.0)
-                scores = jax.vmap(
-                    lambda s, i, a: s.at[i].add(a))(scores, lc, add)
+                scores = jnp.where(hit, -jnp.inf, scores)
             vals, li = lax.top_k(scores, kl)
         if kl < k:                      # tiny shard: pad candidates
             vals = jnp.pad(vals, ((0, 0), (0, k - kl)),
@@ -440,33 +464,35 @@ def _sharded_score_topk(Y, valid, Q, sc_q, sm_q, *, k: int,
 
     row, col, repl = P(axis, None), P(axis), P(None, None)
     if quant:
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(row, col, col, repl, repl, repl),
-                       out_specs=(repl, repl), check_rep=False)
-        return fn(Y.data, Y.scale, valid, Q, sc_q, sm_q)
-    fn = shard_map(
-        lambda Yd, vl, Qb, scq, smq: body(Yd, None, vl, Qb, scq, smq),
-        mesh=mesh, in_specs=(row, col, repl, repl, repl),
-        out_specs=(repl, repl), check_rep=False)
-    return fn(Y, valid, Q, sc_q, sm_q)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(row, col, col, repl, repl),
+                           out_specs=(repl, repl), check_vma=False)
+        return fn(Y.data, Y.scale, valid, Q, seen_bits)
+    fn = jax.shard_map(
+        lambda Yd, vl, Qb, sbq: body(Yd, None, vl, Qb, sbq),
+        mesh=mesh, in_specs=(row, col, repl, repl),
+        out_specs=(repl, repl), check_vma=False)
+    return fn(Y, valid, Q, seen_bits)
 
 
-def _user_topk(X, Y, seen_cols, seen_mask, uid, *, k: int, mask_seen: bool,
+def _user_topk(X, Y, seen_bits, uid, *, k: int, mask_seen: bool,
                n_items: int, mode: str = "fp32"):
     """scores = Y @ X[uid], seen + padding masked to -inf, device top_k,
-    packed into one flat output buffer. ``mode`` is the store's declared
-    precision, static per compiled program."""
+    packed into one flat output buffer. ``seen_bits`` is the store's
+    packed seen bitmap (:func:`seen_bitmap`); ``mode`` is the store's
+    declared precision, static per compiled program."""
     import jax
     import jax.numpy as jnp
+
+    from predictionio_tpu.ops.als_pallas import unpack_seen_bits
 
     u = _take_user_row_f32(X, uid, mode=mode)
     scores = _score_einsum("mr,r->m", Y, u, mode=mode)
     if mask_seen:
-        sc = jax.lax.dynamic_index_in_dim(seen_cols, uid, 0, keepdims=False)
-        sm = jax.lax.dynamic_index_in_dim(seen_mask, uid, 0, keepdims=False)
-        # pad slots carry mask 0 -> add 0.0 to item 0; real slots -inf
-        scores = scores.at[sc].add(
-            jnp.where(sm > 0, -jnp.inf, 0.0), mode="drop")
+        row = jax.lax.dynamic_index_in_dim(seen_bits, uid, 0,
+                                           keepdims=False)
+        scores = jnp.where(unpack_seen_bits(row, scores.shape[0]),
+                           -jnp.inf, scores)
     return _pack(*jax.lax.top_k(_mask_padding(scores, n_items), k))
 
 
@@ -1391,6 +1417,33 @@ def device_report() -> Dict[str, Any]:
     }
 
 
+def _placement(arr) -> List[Dict[str, Any]]:
+    """Where ``arr`` actually lives, read from the array itself: one
+    entry per device holding a shard (rows + bytes of that shard) with
+    the device's own ``bytes_in_use`` — so a report can show EVERY
+    device carrying its part of the item store, not infer it from the
+    shard count."""
+    out = []
+    for sh in sorted(arr.addressable_shards, key=lambda s: s.device.id):
+        d = sh.device
+        stats = d.memory_stats() or {}
+        out.append({"device": int(d.id), "platform": d.platform,
+                    "kind": d.device_kind,
+                    "rows": int(sh.data.shape[0]),
+                    "bytes": int(sh.data.nbytes),
+                    "bytesInUse": stats.get("bytes_in_use")})
+    return out
+
+
+def _table_sig(f) -> Tuple:
+    """Shape + dtype of one factor table (int8 stores: of the data)."""
+    from predictionio_tpu.ops.quantize import is_quantized
+
+    if is_quantized(f):
+        return ("int8q", tuple(f.data.shape), str(f.data.dtype))
+    return (tuple(f.shape), str(f.dtype))
+
+
 _scatter_jits: Dict[bool, object] = {}
 
 
@@ -1421,8 +1474,7 @@ _quant_scatter_jits: Dict[bool, object] = {}
 def _scatter_quant_rows(data, scale, idx, row_d, row_s):
     """Int8 data rows and their per-row scales scattered in ONE
     dispatch (donating both on accelerators): a quantized row is only
-    meaningful WITH its scale, so the pair must land or fail together
-    — same discipline as :func:`_scatter_seen`."""
+    meaningful WITH its scale, so the pair must land or fail together."""
     import jax
 
     donate = jax.default_backend() != "cpu"
@@ -1437,30 +1489,6 @@ def _scatter_quant_rows(data, scale, idx, row_d, row_s):
 
     return fn(data, scale, jnp.asarray(idx), jnp.asarray(row_d),
               jnp.asarray(row_s))
-
-
-_seen_scatter_jits: Dict[bool, object] = {}
-
-
-def _scatter_seen(cols, mask, idx, row_c, row_m):
-    """Both seen tables scattered in ONE dispatch (donating both on
-    accelerators): a caller replacing live store references must not
-    be able to land the cols update and then fail the mask update —
-    one program means the pair succeeds or fails together."""
-    import jax
-
-    donate = jax.default_backend() != "cpu"
-    fn = _seen_scatter_jits.get(donate)
-    if fn is None:
-        fn = jax.jit(
-            lambda c, m, i, rc, rm: (c.at[i].set(rc.astype(c.dtype)),
-                                     m.at[i].set(rm.astype(m.dtype))),
-            donate_argnums=(0, 1) if donate else ())
-        _seen_scatter_jits[donate] = fn
-    import jax.numpy as jnp
-
-    return fn(cols, mask, jnp.asarray(idx), jnp.asarray(row_c),
-              jnp.asarray(row_m))
 
 
 class DeviceTopK:
@@ -1514,6 +1542,11 @@ class DeviceTopK:
         )
 
         self._store_lock = threading.RLock()
+        # one store WRITER at a time (fold-in patches). Queries never
+        # take it: a writer holds _store_lock only to swap references,
+        # so a growing patch can compile the grown store's ladder for
+        # as long as it takes while the old store keeps serving
+        self._write_lock = threading.RLock()
         if microbatch is None:
             microbatch = os.environ.get(
                 "PIO_SERVING_MICROBATCH",
@@ -1590,6 +1623,13 @@ class DeviceTopK:
         # mesh-sharded store both run PER SHARD under shard_map with
         # the log-tree merge on top (hard part #5).
         self._kernel = _serve_kernel_mode()
+        # Pallas runs compiled (Mosaic) on TPU and interpreted anywhere
+        # else — derived from the platform, never a switch; stamped
+        # into every fused dispatch's flight record so "the kernel
+        # ran" can be told from "the interpreter ran"
+        import jax
+
+        self._interpret = jax.default_backend() != "tpu"
         if self._kernel == "fused" and self._shard is None:
             # mesh-committed factors WITHOUT a shard context (dim0
             # replicated, or sharded over >1 axis): the per-shard lane
@@ -1612,13 +1652,13 @@ class DeviceTopK:
             self._Y = _pad_item_rows_for_kernel(self._Y)
         self._mask_seen = bool(seen)
         if self._mask_seen:
-            cols, mask = seen_tables(self._translate_seen(seen),
-                                     int(self._X.shape[0]))
+            # one bit per (user, store position): see seen_bitmap
+            bits = seen_bitmap(self._translate_seen(seen),
+                               int(self._X.shape[0]),
+                               int(self._Y.shape[0]))
         else:
-            cols = np.zeros((1, 1), dtype=np.int32)
-            mask = np.zeros((1, 1), dtype=np.float32)
-        self._seen_cols = self._replicate_like_factors(jnp.asarray(cols))
-        self._seen_mask = self._replicate_like_factors(jnp.asarray(mask))
+            bits = np.zeros((1, 1), dtype=np.int32)
+        self._seen_bits = self._replicate_like_factors(jnp.asarray(bits))
         self._user_programs: Dict[int, object] = {}
         self._batch_programs: Dict[Tuple[int, int], object] = {}
         self._item_programs: Dict[object, object] = {}
@@ -1640,6 +1680,9 @@ class DeviceTopK:
         self._aot_misses = 0
         self._ladder: Dict[str, int] = {"planned": 0, "compiled": 0,
                                         "fallback": 0, "warmed": 0}
+        # the plan the last warmup() compiled: what a growing patch
+        # compiles again for the grown store BEFORE publishing it
+        self._ladder_plan: List[Tuple] = []
         self._Yn = None  # normalized item matrix, built on first item query
         _live_servers.add(self)
         # (re)register the HBM pull gauges: a registry reset (test
@@ -1812,18 +1855,17 @@ class DeviceTopK:
 
             mode, mask_seen, n_items = (self._mode, self._mask_seen,
                                         self.n_items)
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, sc, sm, uids):
+            def prog(X, Y, sb, uids):
                 scalar = jnp.ndim(uids) == 0
                 u = uids[None] if scalar else uids
                 Q = _gather_rows_f32(X, u, mode=mode)
-                scg = jnp.take(sc, u, axis=0).T  # [L, B]
-                smg = jnp.take(sm, u, axis=0).T
                 vals, idx = fused_gather_score_topk(
-                    Q, Y, scg, smg, k=kb, n_items=n_items,
-                    mask_seen=mask_seen, interpret=interpret)
+                    Q, Y, k=kb, n_items=n_items, mask_seen=mask_seen,
+                    seen_bits=jnp.take(sb, u, axis=0) if mask_seen
+                    else None, interpret=interpret)
                 packed = _pack(vals, idx)
                 return packed[0] if scalar else packed
 
@@ -1834,7 +1876,7 @@ class DeviceTopK:
         """Fused-kernel item-similarity program: the [G, B] query
         bucket reduces to one summed query row per group, then the SAME
         kernel scores it against every item tile with the query items
-        masked (their idx/mask table plays the seen-table role)."""
+        masked (their idx/mask table plays the seen-mask role)."""
         prog = self._fused_programs.get(("i", kb))
         if prog is None:
             import jax
@@ -1842,18 +1884,21 @@ class DeviceTopK:
 
             from predictionio_tpu.ops.als_pallas import (
                 fused_gather_score_topk,
+                pack_seen_ids,
             )
 
             mode, n_items = self._mode, self.n_items
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
             def prog(Yn, idxs, masks):
                 qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
                 Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
                 vals, idx = fused_gather_score_topk(
-                    Q, Yn, idxs.T, masks.T, k=kb, n_items=n_items,
-                    mask_seen=True, interpret=interpret)
+                    Q, Yn,
+                    pack_seen_ids(idxs, masks > 0, int(Yn.shape[0])),
+                    k=kb, n_items=n_items, mask_seen=True,
+                    interpret=interpret)
                 return _pack(vals, idx)
 
             self._fused_programs[("i", kb)] = prog
@@ -1873,19 +1918,17 @@ class DeviceTopK:
             mode, mask_seen = self._mode, self._mask_seen
             mesh, axis, _ = self._shard
             fused = self._kernel == "fused"
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, valid, sc, sm, uids):
+            def prog(X, Y, valid, sb, uids):
                 scalar = jnp.ndim(uids) == 0
                 u = uids[None] if scalar else uids
                 Q = _gather_rows_f32(X, u, mode=mode)
-                scq = jnp.take(sc, u, axis=0)
-                smq = jnp.take(sm, u, axis=0)
                 vals, pos = _sharded_score_topk(
-                    Y, valid, Q, scq, smq, k=kb, mask_seen=mask_seen,
-                    mode=mode, mesh=mesh, axis=axis, fused=fused,
-                    interpret=interpret)
+                    Y, valid, Q, jnp.take(sb, u, axis=0), k=kb,
+                    mask_seen=mask_seen, mode=mode, mesh=mesh,
+                    axis=axis, fused=fused, interpret=interpret)
                 packed = _pack(vals, pos)
                 return packed[0] if scalar else packed
 
@@ -1896,7 +1939,7 @@ class DeviceTopK:
         """Item-similarity serving over the sharded store: the [G, B]
         query bucket reduces to one summed normalized row per group,
         then the same per-shard score + merge with the query items
-        masked (their position/mask table plays the seen-table role)."""
+        masked (their positions become the seen bitmap)."""
         prog = self._shard_programs.get(("i", kb))
         if prog is None:
             import jax
@@ -1904,16 +1947,21 @@ class DeviceTopK:
             mode = self._mode
             mesh, axis, _ = self._shard
             fused = self._kernel == "fused"
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
             def prog(Yn, valid, idxs, masks):
+                from predictionio_tpu.ops.als_pallas import pack_seen_ids
+
                 qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
                 Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
+                # the query items mask themselves: their positions as a
+                # bitmap over the store (pad slots carry mask 0)
                 vals, pos = _sharded_score_topk(
-                    Yn, valid, Q, idxs, masks, k=kb, mask_seen=True,
-                    mode=mode, mesh=mesh, axis=axis, fused=fused,
-                    interpret=interpret)
+                    Yn, valid, Q,
+                    pack_seen_ids(idxs, masks > 0, int(valid.shape[0])),
+                    k=kb, mask_seen=True, mode=mode, mesh=mesh,
+                    axis=axis, fused=fused, interpret=interpret)
                 return _pack(vals, pos)
 
             self._shard_programs[("i", kb)] = prog
@@ -1949,7 +1997,7 @@ class DeviceTopK:
             prog = jax.jit(jax.vmap(
                 partial(_user_topk, k=k, mask_seen=self._mask_seen,
                         n_items=self.n_items, mode=self._mode),
-                in_axes=(None, None, None, None, 0)))
+                in_axes=(None, None, None, 0)))
             self._batch_programs[(k, b)] = prog
         return prog
 
@@ -1980,22 +2028,24 @@ class DeviceTopK:
 
     # -- AOT bucket ladder -------------------------------------------------
 
-    def _store_sig_locked(self) -> Tuple:
-        """Abstract signature of the live store — what every serving
-        program's compilation is keyed on. AOT executables are cached
-        under it, so a store reshaped by fold-in growth misses cleanly
-        (and takes the jit fallback) instead of crashing a stale
-        executable. Caller holds ``_store_lock``."""
-        from predictionio_tpu.ops.quantize import is_quantized
+    def _store_tables_locked(self) -> Dict[str, Any]:
+        """The device tables every serving program takes as arguments
+        (subclasses add theirs). Caller holds ``_store_lock``."""
+        return {"X": self._X, "Y": self._Y, "seen_bits": self._seen_bits,
+                "valid": self._valid}
 
-        def fsig(f):
-            if is_quantized(f):
-                return ("int8q", tuple(f.data.shape), str(f.data.dtype))
-            return (tuple(f.shape), str(f.dtype))
-
-        return (fsig(self._X), fsig(self._Y),
-                tuple(self._seen_cols.shape), self._mode, self._kernel,
+    def _store_sig(self, tables: Dict[str, Any]) -> Tuple:
+        """Abstract signature of a set of store tables — what every
+        serving program's compilation is keyed on. AOT executables are
+        cached under it, so the ladder compiled for a grown store is
+        found the moment that store is published, and a stale
+        executable can never be handed a reshaped one."""
+        return (_table_sig(tables["X"]), _table_sig(tables["Y"]),
+                tuple(tables["seen_bits"].shape), self._mode, self._kernel,
                 0 if self._shard is None else int(self._shard[2]))
+
+    def _store_sig_locked(self) -> Tuple:
+        return self._store_sig(self._store_tables_locked())
 
     def _aot_get_locked(self, entry: Tuple):
         return self._aot_programs.get((self._store_sig_locked(), entry))
@@ -2047,29 +2097,36 @@ class DeviceTopK:
                 plan.append(("items", kb, self.ITEM_QUERY_BUCKET, gg))
         return plan
 
-    def precompile(self, plan: List[Tuple]) -> Dict[str, int]:
+    def precompile(self, plan: List[Tuple],
+                   tables: Optional[Dict[str, Any]] = None
+                   ) -> Dict[str, int]:
         """AOT-compile every ladder program (``lower().compile()``, no
         device execution, a small thread pool hides XLA's per-program
         latency) into the executable cache the dispatch paths consult
-        first. Best-effort per entry: a program AOT declines stays on
-        the jit fallback, which :meth:`warmup` then compiles by
-        executing it once — still at deploy time, never on a query.
-        ``PIO_SERVE_AOT=0`` skips AOT entirely (everything falls back).
-        """
+        first, keyed on the signature of ``tables`` — the live store's
+        by default; a growing patch passes the grown tables it has not
+        published yet. A program the compiler refuses raises with the
+        compiler's message — the deploy fails instead of serving a
+        ladder with a hole in it. ``fallback`` counts only entries with
+        no AOT lowering at all (``PIO_SERVE_AOT=0``, or a subclass lane
+        without :meth:`_aot_lower_entry`); :meth:`warmup` compiles
+        those by executing them once — still at deploy time, never on
+        a query."""
         if not _serve_aot_enabled():
             return {"compiled": 0, "fallback": len(plan)}
         import jax
         import jax.numpy as jnp
 
-        with self._store_lock:
-            X, Y = self._X, self._Y
-            sc, sm = self._seen_cols, self._seen_mask
-            valid = self._valid
-            sig = self._store_sig_locked()
+        if tables is None:
+            with self._store_lock:
+                tables = self._store_tables_locked()
+        sig = self._store_sig(tables)
+        X, Y, sb = tables["X"], tables["Y"], tables["seen_bits"]
+        valid = tables["valid"]
         Yn = self._normalized_items() \
             if any(e[0] == "items" for e in plan) else None
         sharded = self._shard is not None
-        user_pre = (X, Y, valid, sc, sm) if sharded else (X, Y, sc, sm)
+        user_pre = (X, Y, valid, sb) if sharded else (X, Y, sb)
         items_pre = (Yn, valid) if sharded else (Yn,)
 
         def build(entry: Tuple):
@@ -2098,8 +2155,7 @@ class DeviceTopK:
                     jax.ShapeDtypeStruct((gg, B), jnp.float32))
             # subclass lanes (e.g. the two-stage ("two", ...) entries)
             # lower through the overridable hook
-            return entry, self._aot_lower_entry(entry, user_pre,
-                                                items_pre)
+            return entry, self._aot_lower_entry(entry, tables)
 
         compiled = fallback = 0
         from concurrent.futures import ThreadPoolExecutor
@@ -2115,14 +2171,14 @@ class DeviceTopK:
                     self._aot_programs.put((sig, entry), prog)
         return {"compiled": compiled, "fallback": fallback}
 
-    def _aot_lower_entry(self, entry: Tuple, user_pre: Tuple,
-                         items_pre: Tuple):
+    def _aot_lower_entry(self, entry: Tuple, tables: Dict[str, Any]):
         """AOT-lower one ladder entry of a kind this class does not
-        know — the subclass extension point through which new serving
-        lanes (the two-stage ``("two", ...)`` entries) join the SAME
-        precompile pool, cache and coverage accounting. None means "no
-        AOT" and the entry stays on its jit fallback, which
-        :meth:`warmup` then compiles via :meth:`_warm_entry`."""
+        know, against ``tables`` — the subclass extension point through
+        which new serving lanes (the two-stage ``("two", ...)``
+        entries) join the SAME precompile pool, cache and coverage
+        accounting. None means "no AOT" and the entry stays on its jit
+        fallback, which :meth:`warmup` then compiles via
+        :meth:`_warm_entry`."""
         return None
 
     def _warm_entry(self, entry: Tuple) -> None:
@@ -2144,6 +2200,7 @@ class DeviceTopK:
         plan = self.aot_plan(max_k=max_k, batch_sizes=tuple(batch_sizes))
         stats = self.precompile(plan)
         with self._store_lock:
+            self._ladder_plan = plan
             missing = [e for e in plan if self._aot_get_locked(e) is None]
             # ladder coverage for the /stats.json device block: how
             # many programs the plan holds, how many AOT-compiled, how
@@ -2231,6 +2288,7 @@ class DeviceTopK:
         t2m = time.monotonic()
         rec = _dtel.record_dispatch(
             lane=entry[0], kernel=self._kernel, precision=self._mode,
+            interpret=self._interpret if self._kernel == "fused" else None,
             aot="hit" if aot_prog is not None else "miss_jit",
             k_bucket=int(entry[1]), batch=batch, bucket=bucket,
             host_us=(t2m - t0m) * 1e6, device_us=(t2m - t1m) * 1e6)
@@ -2273,9 +2331,8 @@ class DeviceTopK:
         """The user-lane program's argument tuple for the live store
         (sharded programs additionally take the validity row)."""
         if self._shard is not None:
-            return (self._X, self._Y, self._valid, self._seen_cols,
-                    self._seen_mask, uids)
-        return (self._X, self._Y, self._seen_cols, self._seen_mask, uids)
+            return (self._X, self._Y, self._valid, self._seen_bits, uids)
+        return (self._X, self._Y, self._seen_bits, uids)
 
     def users_topk(self, uids, k: int) -> Tuple[np.ndarray, np.ndarray]:
         """Batched top-k for a vector of user indices: ONE device dispatch
@@ -2369,7 +2426,7 @@ class DeviceTopK:
 
     def memory_report(self) -> Dict[str, Any]:
         """HBM bytes this store pins, by component and dtype — factor
-        tables (int8 stores split data vs per-row scales), seen tables,
+        tables (int8 stores split data vs per-row scales), the seen bitmap,
         and the lazily built normalized item matrix. Reads the LIVE
         references under ``_store_lock``, so the answer tracks fold-in
         growth and int8 requant as they happen."""
@@ -2377,7 +2434,7 @@ class DeviceTopK:
 
         with self._store_lock:
             X, Y, Yn = self._X, self._Y, self._Yn
-            sc, sm = self._seen_cols, self._seen_mask
+            sb = self._seen_bits
             mode, kernel = self._mode, self._kernel
             shard, layout = self._shard, self._layout
 
@@ -2398,9 +2455,8 @@ class DeviceTopK:
             "userFactors": comp(X),
             "itemFactors": comp(Y),
             "normalizedItems": comp(Yn),
-            "seen": {"bytes": int(sc.nbytes + sm.nbytes),
-                     "dtype": f"{sc.dtype}+{sm.dtype}",
-                     "shape": [int(d) for d in sc.shape]}
+            "seen": {"bytes": int(sb.nbytes), "dtype": str(sb.dtype),
+                     "shape": [int(d) for d in sb.shape]}
             if self._mask_seen else None,
         }
         total = sum(c["bytes"] + c.get("scaleBytes", 0)
@@ -2413,6 +2469,7 @@ class DeviceTopK:
             "userCapacity": int(X.shape[0]),
             "components": components,
             "totalBytes": int(total),
+            "placement": _placement(Y.data if is_quantized(Y) else Y),
         }
         if shard is not None:
             # per-shard breakdown (ISSUE 15 satellite): the aggregate
@@ -2533,10 +2590,10 @@ class DeviceTopK:
         the online fold-in write path (no ``/reload``, no retrain).
 
         ``uids`` may index PAST the current capacity: the store grows
-        along the power-of-two bucket ladder (new rows zero until
-        patched), so a stream of brand-new users costs O(log growth)
-        reallocations, and the compiled top-k programs re-specialize at
-        the same cadence. ``factors`` rows are cast to the store dtype
+        along the power-of-two bucket ladder first
+        (:meth:`_reserve_users`; new rows zero until patched), so a
+        stream of brand-new users costs O(log growth) reallocations.
+        ``factors`` rows are cast to the store dtype
         (fp32, the bf16 serving policy, or — for an int8 store —
         re-quantized with freshly recomputed per-row absmax scales, so
         a patched row quantizes exactly as it would have at load).
@@ -2549,13 +2606,8 @@ class DeviceTopK:
         concurrent query sees either the whole old store or the whole
         new one — never a torn mix. On accelerators the scatter donates
         the old buffer (in-place HBM update, the PR-5 donation
-        discipline); growth on a MESH-SHARDED store reshards — the
-        larger row-sharded buffers are allocated in the same placement
-        and the old rows copied in (no more refusal; sharded fold-in
-        deployments grow like single-chip ones).
+        discipline). Writers run one at a time (``_write_lock``).
         """
-        import jax.numpy as jnp
-
         uids = np.asarray(uids, dtype=np.int64)
         factors = np.asarray(factors, dtype=np.float32)
         if factors.ndim != 2 or len(uids) != factors.shape[0]:
@@ -2568,89 +2620,116 @@ class DeviceTopK:
             raise ValueError("patch_users: negative user index")
         seen_items = self._translate_seen(seen_items) if seen_items \
             else seen_items
-        with self._store_lock:
-            sig_before = self._store_sig_locked()
-            # phase 1 — everything that can FAIL, with no live buffer
-            # donated yet: growth builds new arrays (the old store stays
-            # whole), seen prep is pads + host loops. Only after all of
-            # it succeeds does phase 2 donate, and each donating call is
-            # paired with its publish in the same statement — an
-            # exception can therefore never strand self._X (or the seen
-            # tables) pointing at an already-donated, deleted buffer.
-            from predictionio_tpu.ops.quantize import (
-                QuantFactors,
-                is_quantized,
-                quantize_rows_int8_np,
-            )
+        from predictionio_tpu.ops.quantize import (
+            QuantFactors,
+            is_quantized,
+            quantize_rows_int8_np,
+        )
 
-            X = self._X
-            needed = int(uids.max()) + 1
-            cap = X.shape[0]
-            if needed > cap:
-                new_cap = _bucket(needed, lo=max(cap, 16))
-                if self._shard is not None:
-                    # growth reshards: round capacity to the shard
-                    # divisor and run a pad program pinned to the
-                    # store's own row sharding (new rows zero / scale
-                    # 1 until patched)
-                    n_sh = int(self._shard[2])
-                    new_cap = -(-new_cap // n_sh) * n_sh
-                    X = self._grow_rows_sharded(X, new_cap)
-                elif is_quantized(X):
-                    # grown rows: zero data with scale 1 (dequant = 0)
-                    X = QuantFactors(
-                        jnp.concatenate(
-                            [X.data, jnp.zeros((new_cap - cap,
-                                                X.data.shape[1]),
-                                               X.data.dtype)]),
-                        jnp.concatenate(
-                            [X.scale, jnp.ones((new_cap - cap,),
-                                               X.scale.dtype)]))
+        needed = int(uids.max()) + 1
+        # everything that can FAIL comes before the first donation (the
+        # seen rows are host loops), and each donating call is paired
+        # with its publish in the same statement — an exception can
+        # therefore never strand self._X (or the bitmap) pointing at an
+        # already-donated, deleted buffer. Dispatch paths snapshot all
+        # references under _store_lock, so the intermediate states are
+        # invisible to queries.
+        seen_prep = self._prep_seen(seen_items) \
+            if self._mask_seen and seen_items else None
+        with self._write_lock:
+            self._reserve_users(needed)
+            with self._store_lock:
+                if seen_prep is not None:
+                    self._seen_bits = _scatter_rows(self._seen_bits,
+                                                    *seen_prep)
+                X = self._X
+                if is_quantized(X):
+                    # fresh rows re-quantize with RECOMPUTED per-row
+                    # scales (symmetric absmax, the load-time rule) so
+                    # a patched row is bit-identical to
+                    # quantize-from-scratch of the updated matrix;
+                    # data+scale scatter in one donating dispatch so
+                    # the pair can never tear
+                    q = quantize_rows_int8_np(factors)
+                    self._X = QuantFactors(*_scatter_quant_rows(
+                        X.data, X.scale, uids, q.data, q.scale))
                 else:
-                    X = jnp.concatenate(
-                        [X,
-                         jnp.zeros((new_cap - cap, X.shape[1]), X.dtype)])
-            seen_prep = None
-            if self._mask_seen and (
-                    seen_items or X.shape[0] > self._seen_cols.shape[0]):
-                # even a seen-less patch must grow the tables alongside
-                # X: a new uid whose seen row does not exist would
-                # CLAMP into the last existing user's row at gather
-                # time — silently masking the new user's top-k with an
-                # arbitrary other user's seen set. Grown rows are
-                # zero-masked ("nothing seen") until patched.
-                seen_prep = self._prep_seen_locked(
-                    seen_items or {}, int(X.shape[0]))
-            # phase 2 — donate + publish. Dispatch paths snapshot all
-            # four references under this same lock, so the intermediate
-            # states below are invisible to queries. Seen tables land
-            # FIRST: if the X scatter then fails, the store holds old
-            # factors with (possibly larger) seen tables — harmless for
-            # every reachable uid, whereas new-X-with-short-seen would
-            # let a grown uid clamp into another user's seen row.
-            if seen_prep is not None:
-                cols, mask, sids, row_c, row_m = seen_prep
-                self._seen_cols, self._seen_mask = _scatter_seen(
-                    cols, mask, sids, row_c, row_m)
-            if is_quantized(X):
-                # fresh rows re-quantize with RECOMPUTED per-row
-                # scales (symmetric absmax, the load-time rule) so a
-                # patched row is bit-identical to quantize-from-scratch
-                # of the updated matrix; data+scale scatter in one
-                # donating dispatch so the pair can never tear
-                q = quantize_rows_int8_np(factors)
-                self._X = QuantFactors(*_scatter_quant_rows(
-                    X.data, X.scale, uids, q.data, q.scale))
-            else:
-                self._X = _scatter_rows(X, uids, factors)
-            self.n_users = max(self.n_users, needed)
-            if self._store_sig_locked() != sig_before:
-                # grown store: AOT executables are keyed by store
-                # signature so lookups would miss anyway — drop them
-                # eagerly (each pins device code); dispatch falls back
-                # to the shape-polymorphic jit programs until the next
-                # warmup()/precompile() re-ladders the new shape
-                self._aot_programs.clear()
+                    self._X = _scatter_rows(X, uids, factors)
+                self.n_users = max(self.n_users, needed)
+
+    def _reserve_users(self, needed: int) -> None:
+        """Grow the user-side tables to hold ``needed`` rows, in the
+        order that keeps every query on a compiled program: build the
+        grown tables beside the live ones (pads COPY, so the live store
+        keeps serving and stays whole if anything here raises), compile
+        the warmed ladder for the grown signature, and only then swap
+        the references in under ``_store_lock``. Queries therefore see
+        the old store with its ladder or the grown store with its
+        ladder, and never compile; the price is paid by the writer — a
+        batch that grows the store lands one ladder compile later
+        (once per doubling). A store that was never warmed has no plan
+        and grows without compiling, as does one with ``PIO_SERVE_AOT``
+        off; their queries take the jit programs as before. Caller
+        holds ``_write_lock``, so no other writer touches the tables
+        between the snapshot and the swap."""
+        with self._store_lock:
+            old = self._store_tables_locked()
+            plan = list(self._ladder_plan)
+        cap = int(old["X"].shape[0])
+        if needed <= cap:
+            return
+        new_cap = _bucket(needed, lo=max(cap, 16))
+        if self._shard is not None:
+            # growth reshards: capacity rounds to the shard divisor
+            n_sh = int(self._shard[2])
+            new_cap = -(-new_cap // n_sh) * n_sh
+        grown = self._grow_user_tables(old, new_cap)
+        if plan:
+            self.precompile(plan, grown)
+        with self._store_lock:
+            self._publish_user_tables_locked(grown)
+        # the old shape's executables each pin device code
+        old_sig = self._store_sig(old)
+        self._aot_programs.discard(lambda key: key[0] == old_sig)
+
+    def _grow_user_tables(self, tables: Dict[str, Any],
+                          new_cap: int) -> Dict[str, Any]:
+        """``tables`` with the user-side ones (factors, seen bitmap)
+        padded to ``new_cap`` rows: zero factors (int8: zero data,
+        scale 1) and all-zero "nothing seen" bitmap rows, in the
+        placement the compiled programs expect. The bitmap grows WITH
+        the factors even when no seen set arrives: a uid whose seen row
+        does not exist would clamp into the last user's row at gather
+        time and be masked with another user's history."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops.quantize import (
+            QuantFactors,
+            is_quantized,
+        )
+
+        X = tables["X"]
+        pad = new_cap - int(X.shape[0])
+        if self._shard is not None:
+            # a pad program pinned to the store's own row sharding
+            X = self._grow_rows_sharded(X, new_cap)
+        elif is_quantized(X):
+            X = QuantFactors(
+                jnp.pad(X.data, ((0, pad), (0, 0))),
+                jnp.pad(X.scale, ((0, pad),), constant_values=1))
+        else:
+            X = jnp.pad(X, ((0, pad), (0, 0)))
+        grown = dict(tables, X=X)
+        if self._mask_seen:
+            grown["seen_bits"] = self._replicate_like_factors(
+                jnp.pad(tables["seen_bits"], ((0, pad), (0, 0))))
+        return grown
+
+    def _publish_user_tables_locked(self, tables: Dict[str, Any]) -> None:
+        """Swap in the user-side tables of ``tables``. The bitmap lands
+        first, as in every patch. Caller holds ``_store_lock``."""
+        self._seen_bits = tables["seen_bits"]
+        self._X = tables["X"]
 
     def _grow_rows_sharded(self, X, new_cap: int):
         """Grow a mesh-sharded user store to ``new_cap`` rows by
@@ -2685,43 +2764,14 @@ class DeviceTopK:
                                 grow(X.scale, col, 1.0))
         return grow(X, row, 0.0)
 
-    def _prep_seen_locked(self, seen_items: Dict[int, np.ndarray],
-                          n_rows: int):
-        """Seen tables grown (rows and row length, same bucket ladder as
-        the factors) plus the touched users' replacement rows — the
-        fallible half of a seen patch; the caller feeds it to the
-        donating :func:`_scatter_seen`. The pads COPY, so the live
-        tables are untouched if anything here raises. Caller holds
-        ``_store_lock``."""
-        import jax.numpy as jnp
-
-        cols, mask = self._seen_cols, self._seen_mask
-        L = int(cols.shape[1])
-        longest = max((len(v) for v in seen_items.values()), default=0)
-        new_L = _bucket(max(longest, 1), lo=L)
-        grown = False
-        if new_L > L:
-            pad = new_L - L
-            cols = jnp.pad(cols, ((0, 0), (0, pad)))
-            mask = jnp.pad(mask, ((0, 0), (0, pad)))
-            grown = True
-        rows = int(cols.shape[0])
-        if n_rows > rows:
-            cols = jnp.pad(cols, ((0, n_rows - rows), (0, 0)))
-            mask = jnp.pad(mask, ((0, n_rows - rows), (0, 0)))
-            grown = True
-        if grown:
-            # grown tables must keep the mesh-replicated placement the
-            # compiled programs (and AOT executables) expect
-            cols = self._replicate_like_factors(cols)
-            mask = self._replicate_like_factors(mask)
+    def _prep_seen(self, seen_items: Dict[int, np.ndarray]):
+        """The touched users' replacement bitmap rows (a row's width is
+        fixed by the item store, so a user's history growing never
+        reshapes the store) — the fallible half of a seen patch; the
+        caller feeds it to the donating :func:`_scatter_rows`."""
         sids = np.fromiter(seen_items.keys(), dtype=np.int64,
                            count=len(seen_items))
-        row_c = np.zeros((len(sids), new_L), dtype=np.int32)
-        row_m = np.zeros((len(sids), new_L), dtype=np.float32)
-        for i, uid in enumerate(sids):
-            items = np.asarray(seen_items[int(uid)], dtype=np.int32)
-            m = min(len(items), new_L)
-            row_c[i, :m] = items[:m]
-            row_m[i, :m] = 1.0
-        return cols, mask, sids, row_c, row_m
+        new_rows = seen_bitmap(
+            {i: seen_items[int(uid)] for i, uid in enumerate(sids)},
+            len(sids), int(self._Y.shape[0]))
+        return sids, new_rows

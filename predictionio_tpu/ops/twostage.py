@@ -15,7 +15,7 @@ batch records one ``two``-lane dispatch, not a ``users`` + a gather).
 
 :class:`TwoStageTopK` extends :class:`~predictionio_tpu.ops.serving.
 DeviceTopK` — the stage-1 store IS the parent store (same sharding,
-precision, fused-kernel and seen-table policies), and the two-stage
+precision, fused-kernel and seen-mask policies), and the two-stage
 lane rides every existing serving discipline:
 
 * programs are cached per ``(k-bucket, N-bucket)`` and dispatched per
@@ -56,11 +56,11 @@ from predictionio_tpu.ops.serving import (
     _Pending,
     _scatter_quant_rows,
     _scatter_rows,
-    _scatter_seen,
     _score_einsum,
     _serve_precision_explicit,
     _serve_shards_env,
     _sharded_score_topk,
+    _table_sig,
     _unpack,
     foldin_enabled,
     validate_serving_policy,
@@ -101,7 +101,7 @@ def _dispatch_two_group(srv: "TwoStageTopK",
             it.future.set_result((res, row))
 
 
-def _twostage_rerank(E, U, uids, vals1, pos, scq, smq, *, kb: int,
+def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
                      mode: str, mask_seen: bool, pos_ids=None):
     """Stage 2, shared by every stage-1 lane (XLA / fused / sharded):
     candidate gather -> re-rank score -> ONE seen mask -> final top-k,
@@ -109,10 +109,10 @@ def _twostage_rerank(E, U, uids, vals1, pos, scq, smq, *, kb: int,
     HBM).
 
     ``vals1``/``pos`` are the stage-1 run ([B, nb] scores descending +
-    store positions); ``scq``/``smq`` the query users' seen rows in
-    POSITION space. Candidates re-sort ascending by ITEM ID first
-    (``pos_ids`` maps positions to ids on density-permuted stores;
-    identity otherwise) so ``lax.top_k``'s lowest-ordinal tie-break
+    store positions); ``sbq`` the query users' rows of the packed seen
+    bitmap over store POSITIONS. Candidates re-sort ascending by ITEM
+    ID first (``pos_ids`` maps positions to ids on density-permuted
+    stores; identity otherwise) so ``lax.top_k``'s lowest-ordinal tie-break
     equals the brute-force lowest-item-id rule — bit-exact at
     N=catalog on every lane, including sharded."""
     import jax.numpy as jnp
@@ -134,9 +134,13 @@ def _twostage_rerank(E, U, uids, vals1, pos, scq, smq, *, kb: int,
         # the seen mask applies EXACTLY once, here — stage 1 retrieves
         # unmasked so the candidate run is the same one a brute-force
         # re-rank would score
-        hit = ((pos[:, :, None] == scq[:, None, :])
-               & (smq[:, None, :] > 0)).any(axis=-1)
-        s2 = jnp.where(hit, -jnp.inf, s2)
+        # candidate position p = bit (p & 31) of word (p >> 5); merge
+        # pads may sit out of range — clamp the word index (their s2
+        # is already -inf)
+        word = jnp.take_along_axis(
+            sbq, jnp.clip(pos >> 5, 0, sbq.shape[-1] - 1), axis=-1)
+        s2 = jnp.where((jnp.right_shift(word, pos & 31) & 1) > 0,
+                       -jnp.inf, s2)
     out_vals, sel = lax.top_k(s2, kb)
     out_pos = jnp.take_along_axis(pos, sel, axis=-1)
     return _pack(out_vals, out_pos)
@@ -323,10 +327,11 @@ class TwoStageTopK(DeviceTopK):
             scale = s
         return self._cast_stage2(padded, scale)
 
-    def _sync_seq_capacity_locked(self) -> None:
-        """Grow the stage-2 user table to the stage-1 capacity (the
-        parent's growth already ran; new rows dequantize to zero until
-        their encoded state folds in). Caller holds ``_store_lock``."""
+    def _grow_user_tables(self, tables: Dict[str, Any],
+                          new_cap: int) -> Dict[str, Any]:
+        """The parent's growth plus the stage-2 user table, which
+        always spans the stage-1 capacity (grown rows dequantize to
+        zero until their encoded state folds in)."""
         import jax.numpy as jnp
 
         from predictionio_tpu.ops.quantize import (
@@ -334,23 +339,23 @@ class TwoStageTopK(DeviceTopK):
             is_quantized,
         )
 
-        cap = int(self._X.shape[0])
-        U = self._U
-        rows = int(U.shape[0])
-        if rows >= cap:
-            return
+        grown = super()._grow_user_tables(tables, new_cap)
+        U = tables["U"]
+        pad = new_cap - int(U.shape[0])
         if is_quantized(U):
-            data = jnp.concatenate(
-                [U.data, jnp.zeros((cap - rows, U.data.shape[1]),
-                                   U.data.dtype)])
-            scale = jnp.concatenate(
-                [U.scale, jnp.ones((cap - rows,), U.scale.dtype)])
-            self._U = QuantFactors(self._replicate_stage2(data),
-                                   self._replicate_stage2(scale))
+            grown["U"] = QuantFactors(
+                self._replicate_stage2(
+                    jnp.pad(U.data, ((0, pad), (0, 0)))),
+                self._replicate_stage2(
+                    jnp.pad(U.scale, ((0, pad),), constant_values=1)))
         else:
-            grown = jnp.concatenate(
-                [U, jnp.zeros((cap - rows, U.shape[1]), U.dtype)])
-            self._U = self._replicate_stage2(grown)
+            grown["U"] = self._replicate_stage2(
+                jnp.pad(U, ((0, pad), (0, 0))))
+        return grown
+
+    def _publish_user_tables_locked(self, tables: Dict[str, Any]) -> None:
+        super()._publish_user_tables_locked(tables)
+        self._U = tables["U"]
 
     # -- compilation -------------------------------------------------------
 
@@ -378,19 +383,18 @@ class TwoStageTopK(DeviceTopK):
         if self._shard is not None:
             mesh, axis, _ = self._shard
             fused = self._kernel == "fused"
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, valid, E, U, sc, sm, uids):
+            def prog(X, Y, valid, E, U, sb, uids):
                 Q = _gather_rows_f32(X, uids, mode=mode)
-                scq = jnp.take(sc, uids, axis=0)
-                smq = jnp.take(sm, uids, axis=0)
+                sbq = jnp.take(sb, uids, axis=0)
                 vals1, pos = _sharded_score_topk(
-                    Y, valid, Q, scq, smq, k=nb, mask_seen=False,
+                    Y, valid, Q, sbq, k=nb, mask_seen=False,
                     mode=mode, mesh=mesh, axis=axis, fused=fused,
                     interpret=interpret)
-                return _twostage_rerank(E, U, uids, vals1, pos, scq,
-                                        smq, kb=kb, mode=mode,
+                return _twostage_rerank(E, U, uids, vals1, pos, sbq,
+                                        kb=kb, mode=mode,
                                         mask_seen=mask_seen,
                                         pos_ids=pos_ids)
         elif self._kernel == "fused":
@@ -398,37 +402,35 @@ class TwoStageTopK(DeviceTopK):
                 fused_gather_score_topk,
             )
 
-            interpret = jax.default_backend() != "tpu"
+            interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, E, U, sc, sm, uids):
+            def prog(X, Y, E, U, sb, uids):
                 Q = _gather_rows_f32(X, uids, mode=mode)
-                scq = jnp.take(sc, uids, axis=0)
-                smq = jnp.take(sm, uids, axis=0)
                 vals1, pos = fused_gather_score_topk(
-                    Q, Y, scq.T, smq.T, k=nb, n_items=n_items,
-                    mask_seen=False, interpret=interpret)
-                return _twostage_rerank(E, U, uids, vals1, pos, scq,
-                                        smq, kb=kb, mode=mode,
+                    Q, Y, k=nb, n_items=n_items, mask_seen=False,
+                    interpret=interpret)
+                return _twostage_rerank(E, U, uids, vals1, pos,
+                                        jnp.take(sb, uids, axis=0),
+                                        kb=kb, mode=mode,
                                         mask_seen=mask_seen,
                                         pos_ids=pos_ids)
         else:
             n_rows = int(self._Y.shape[0])
 
             @jax.jit
-            def prog(X, Y, E, U, sc, sm, uids):
+            def prog(X, Y, E, U, sb, uids):
                 from jax import lax
 
                 Q = _gather_rows_f32(X, uids, mode=mode)
-                scq = jnp.take(sc, uids, axis=0)
-                smq = jnp.take(sm, uids, axis=0)
                 scores = _score_einsum("mr,br->bm", Y, Q, mode=mode)
                 if n_rows > n_items:
                     pad_ok = jnp.arange(n_rows)[None, :] < n_items
                     scores = jnp.where(pad_ok, scores, -jnp.inf)
                 vals1, pos = lax.top_k(scores, nb)
-                return _twostage_rerank(E, U, uids, vals1, pos, scq,
-                                        smq, kb=kb, mode=mode,
+                return _twostage_rerank(E, U, uids, vals1, pos,
+                                        jnp.take(sb, uids, axis=0),
+                                        kb=kb, mode=mode,
                                         mask_seen=mask_seen,
                                         pos_ids=pos_ids)
 
@@ -440,27 +442,19 @@ class TwoStageTopK(DeviceTopK):
         (sharded programs additionally take the validity row)."""
         if self._shard is not None:
             return (self._X, self._Y, self._valid, self._E, self._U,
-                    self._seen_cols, self._seen_mask, uids)
-        return (self._X, self._Y, self._E, self._U, self._seen_cols,
-                self._seen_mask, uids)
+                    self._seen_bits, uids)
+        return (self._X, self._Y, self._E, self._U, self._seen_bits,
+                uids)
 
     # -- AOT bucket ladder -------------------------------------------------
 
-    def _store_sig_locked(self) -> Tuple:
-        from predictionio_tpu.ops.quantize import is_quantized
+    def _store_tables_locked(self) -> Dict[str, Any]:
+        return dict(super()._store_tables_locked(), E=self._E, U=self._U)
 
-        base = super()._store_sig_locked()
-        E = getattr(self, "_E", None)
-        U = getattr(self, "_U", None)
-        if E is None or U is None:  # mid-__init__: stage 2 not up yet
-            return base
-
-        def fsig(f):
-            if is_quantized(f):
-                return ("int8q", tuple(f.data.shape), str(f.data.dtype))
-            return (tuple(f.shape), str(f.dtype))
-
-        return base + (fsig(E), fsig(U), self._n_bucket)
+    def _store_sig(self, tables: Dict[str, Any]) -> Tuple:
+        return super()._store_sig(tables) + (
+            _table_sig(tables["E"]), _table_sig(tables["U"]),
+            self._n_bucket)
 
     def aot_plan(self, max_k: int = 128,
                  batch_sizes: Tuple[int, ...] = ()) -> List[Tuple]:
@@ -475,22 +469,19 @@ class TwoStageTopK(DeviceTopK):
                 plan.append(("two", kb, self._nb_for(kb), bb))
         return plan
 
-    def _aot_lower_entry(self, entry: Tuple, user_pre: Tuple,
-                         items_pre: Tuple):
+    def _aot_lower_entry(self, entry: Tuple, tables: Dict[str, Any]):
         if entry[0] != "two":
-            return super()._aot_lower_entry(entry, user_pre, items_pre)
+            return super()._aot_lower_entry(entry, tables)
         import jax
         import jax.numpy as jnp
 
         _, kb, nb, bb = entry
-        with self._store_lock:
-            E, U = self._E, self._U
+        t = tables
         if self._shard is not None:
-            X, Y, valid, sc, sm = user_pre
-            pre = (X, Y, valid, E, U, sc, sm)
+            pre = (t["X"], t["Y"], t["valid"], t["E"], t["U"],
+                   t["seen_bits"])
         else:
-            X, Y, sc, sm = user_pre
-            pre = (X, Y, E, U, sc, sm)
+            pre = (t["X"], t["Y"], t["E"], t["U"], t["seen_bits"])
         return lower_compile(self._two_program(kb, nb), *pre,
                              jax.ShapeDtypeStruct((bb,), jnp.int32))
 
@@ -617,19 +608,6 @@ class TwoStageTopK(DeviceTopK):
             return jnp.take(Ef, jnp.asarray(inv), axis=0)
         return Ef[:self.n_items]
 
-    def patch_users(self, uids, factors,
-                    seen_items: Optional[Dict[int, np.ndarray]] = None
-                    ) -> None:
-        """Stage-1 fold-in write path, unchanged — plus the invariant
-        that the stage-2 user table always spans the stage-1 capacity
-        (grown rows zero until :meth:`patch_seq_users` lands them)."""
-        with self._store_lock:
-            super().patch_users(uids, factors, seen_items=seen_items)
-            sig_mid = self._store_sig_locked()
-            self._sync_seq_capacity_locked()
-            if self._store_sig_locked() != sig_mid:
-                self._aot_programs.clear()
-
     def patch_seq_users(self, uids, vectors,
                         seen_items: Optional[Dict[int, np.ndarray]]
                         = None) -> None:
@@ -640,11 +618,9 @@ class TwoStageTopK(DeviceTopK):
         ``_store_lock`` the dispatch paths snapshot under.
 
         A uid past the current capacity grows BOTH stores through the
-        stage-1 growth/reshard ladder first (the new user's retrieval
-        row stays zero until its ALS half-step folds in), so the two
-        tables can never disagree about capacity."""
-        import numpy as _np
-
+        one growth path (:meth:`_reserve_users`; the new user's
+        retrieval row stays zero until its ALS half-step folds in), so
+        the two tables can never disagree about capacity."""
         from predictionio_tpu.ops.quantize import (
             QuantFactors,
             is_quantized,
@@ -661,44 +637,30 @@ class TwoStageTopK(DeviceTopK):
             return
         if uids.min() < 0:
             raise ValueError("patch_seq_users: negative user index")
+        rank2 = int(self._U.shape[1])  # fixed for the store's lifetime
+        if vectors.shape[1] != rank2:
+            raise ValueError(
+                f"patch_seq_users: vectors rank {vectors.shape[1]} "
+                f"vs stage-2 store rank {rank2}")
         seen_tr = self._translate_seen(seen_items) if seen_items \
             else seen_items
-        with self._store_lock:
-            sig_before = self._store_sig_locked()
-            rank2 = int(self._U.shape[1]) if not is_quantized(self._U) \
-                else int(self._U.data.shape[1])
-            if vectors.shape[1] != rank2:
-                raise ValueError(
-                    f"patch_seq_users: vectors rank {vectors.shape[1]} "
-                    f"vs stage-2 store rank {rank2}")
-            needed = int(uids.max()) + 1
-            if needed > int(self._X.shape[0]):
-                # grow/reshard through the stage-1 path so both stores
-                # (and the seen tables) ride the same bucket ladder;
-                # the probe row is a NEW uid, so zero is exactly the
-                # grown fill it would hold anyway
-                r1 = int(self._X.data.shape[1]) \
-                    if is_quantized(self._X) else int(self._X.shape[1])
-                super().patch_users(
-                    _np.asarray([needed - 1], dtype=_np.int64),
-                    _np.zeros((1, r1), dtype=_np.float32))
-            self._sync_seq_capacity_locked()
-            if self._mask_seen and seen_tr:
-                prep = self._prep_seen_locked(seen_tr,
-                                              int(self._X.shape[0]))
-                cols, mask, sids, row_c, row_m = prep
-                self._seen_cols, self._seen_mask = _scatter_seen(
-                    cols, mask, sids, row_c, row_m)
-            U = self._U
-            if is_quantized(U):
-                q = quantize_rows_int8_np(vectors)
-                self._U = QuantFactors(*_scatter_quant_rows(
-                    U.data, U.scale, uids, q.data, q.scale))
-            else:
-                self._U = _scatter_rows(U, uids, vectors)
-            self.n_users = max(self.n_users, needed)
-            if self._store_sig_locked() != sig_before:
-                self._aot_programs.clear()
+        seen_prep = self._prep_seen(seen_tr) \
+            if self._mask_seen and seen_tr else None
+        needed = int(uids.max()) + 1
+        with self._write_lock:
+            self._reserve_users(needed)
+            with self._store_lock:
+                if seen_prep is not None:
+                    self._seen_bits = _scatter_rows(self._seen_bits,
+                                                    *seen_prep)
+                U = self._U
+                if is_quantized(U):
+                    q = quantize_rows_int8_np(vectors)
+                    self._U = QuantFactors(*_scatter_quant_rows(
+                        U.data, U.scale, uids, q.data, q.scale))
+                else:
+                    self._U = _scatter_rows(U, uids, vectors)
+                self.n_users = max(self.n_users, needed)
 
     # -- serving facets ----------------------------------------------------
 

@@ -551,6 +551,7 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
         X, Y = run_iters(X, Y, int(params.num_iterations))
     else:
         from predictionio_tpu.workflow import checkpoint as _checkpoint
+        from predictionio_tpu.workflow import runlog as _runlog
 
         fdt = X.dtype
         objective = None
@@ -562,11 +563,16 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
             def objective(Xc, Yc):
                 return _objective_pack(Xc, Yc, u_t, **obj_kw)
 
-        X, Y = _checkpoint.run_chunked(
-            run_iters, X, Y, int(params.num_iterations), ckpt,
-            to_host=lambda a: np.asarray(a, dtype=np.float32),
-            from_host=lambda a: put(jnp.asarray(a, dtype=fdt), repl),
-            objective=objective)
+        # same run-log header as the one-device trainer, with the mesh
+        # size the tables were actually sharded over
+        with _runlog.run_context_scope(
+                solver=kw["solver"], precision=precision,
+                trainedPairs=user_side.nnz, devices=ndev):
+            X, Y = _checkpoint.run_chunked(
+                run_iters, X, Y, int(params.num_iterations), ckpt,
+                to_host=lambda a: np.asarray(a, dtype=np.float32),
+                from_host=lambda a: put(jnp.asarray(a, dtype=fdt), repl),
+                objective=objective)
     if not gather:
         # PAlgorithm flavor: factors stay in HBM in their sharded
         # placement (rows padded to the factor divisor, bf16 under the

@@ -583,12 +583,22 @@ def build_parser() -> argparse.ArgumentParser:
     return parser
 
 
+# the verbs that compile device programs: they place jax's persistent
+# compilation cache before their first compile (storage and admin verbs
+# never import jax at all)
+_COMPILING_VERBS = frozenset({"train", "eval", "deploy", "batchpredict"})
+
+
 def main(argv: Optional[List[str]] = None) -> int:
     parser = build_parser()
     args = parser.parse_args(argv)
     if not getattr(args, "func", None):
         parser.print_help()
         return 2
+    if args.command in _COMPILING_VERBS:
+        from predictionio_tpu.utils import compile_cache
+
+        compile_cache.configure()
     return args.func(args)
 
 

@@ -251,6 +251,8 @@ def cmd_train(args) -> int:
         else:
             print("[INFO] Training interrupted by a stop-after flag.")
         return 0
+    print(f"[INFO] JIT compiles: {int(metrics.JIT_COMPILES.value())} "
+          f"events, {metrics.JIT_COMPILE_SECONDS.value():.3f} s")
     print(f"[INFO] Training completed. Engine instance ID: {instance_id}")
     return 0
 

@@ -230,7 +230,8 @@ def current_dispatch_context() -> Optional[Dict[str, Any]]:
 def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
                     k_bucket: int, batch: int, bucket: int,
                     host_us: float, device_us: float,
-                    started_epoch: Optional[float] = None
+                    started_epoch: Optional[float] = None,
+                    interpret: Optional[bool] = None
                     ) -> Optional[Dict[str, Any]]:
     """Record one device dispatch (caller already paid the timing; this
     is pure bookkeeping). Returns the record dict, or None when the
@@ -255,6 +256,10 @@ def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
         "hostUs": round(float(host_us), 1),
         "deviceUs": round(float(device_us), 1),
     }
+    if interpret is not None:
+        # Pallas lanes only: False = the Mosaic-compiled kernel ran,
+        # True = the interpreter did (any platform but TPU)
+        rec["interpret"] = bool(interpret)
     RECORDER.record(rec)
     from predictionio_tpu.utils import metrics
 

@@ -872,8 +872,8 @@ DISPATCH_DEVICE_SECONDS = REGISTRY.histogram(
 AOT_CACHE_REQUESTS = REGISTRY.counter(
     "pio_aot_cache_requests_total",
     "Serving-program lookups against the AOT bucket ladder (hit = "
-    "precompiled executable; miss_jit = jit fallback, e.g. a store "
-    "reshaped by fold-in growth before the next warmup)",
+    "precompiled executable; miss_jit = jit fallback: a store that "
+    "was never warmed, or a shape the warmed plan does not hold)",
     ("result",))
 AOT_CACHE_EVICTIONS = REGISTRY.counter(
     "pio_aot_cache_evictions_total",
@@ -974,7 +974,11 @@ def install_jit_compile_listener() -> bool:
         def _on_duration(event: str, duration: float, **kwargs) -> None:
             if not REGISTRY.enabled:
                 return
-            if "compile" in event:
+            # the compile pipeline's own phases (trace, lower, backend
+            # compile — the last is the cache retrieval on a persistent
+            # cache hit). NOT /jax/compilation_cache/*: its
+            # compile_time_saved event is time NOT spent compiling
+            if event.startswith("/jax/core/compile/"):
                 JIT_COMPILES.inc()
                 JIT_COMPILE_SECONDS.inc(max(0.0, float(duration)))
 

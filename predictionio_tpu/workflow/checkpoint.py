@@ -643,6 +643,7 @@ def _chunk_sample(rl, step: int, total: int, n: int, loss: Any,
         else round(float(device_s), 6),
         "loss": loss,
         "hbmBytesInUse": _runlog.hbm_bytes_in_use(),
+        "hbmBytesInUsePerDevice": _runlog.hbm_bytes_in_use_per_device(),
         "checkpointBytes": ckpt_bytes,
         "at": _dt.datetime.now(tz=_dt.timezone.utc).isoformat(),
     }

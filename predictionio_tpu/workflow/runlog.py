@@ -88,18 +88,27 @@ def run_path(checkpoint_dir: str, run_id: str) -> str:
     return os.path.join(runs_dir(checkpoint_dir), f"{run_id}.jsonl")
 
 
-def hbm_bytes_in_use() -> Optional[int]:
-    """Device-0 bytes in use (the HBM watermark each sample records),
-    or None on backends without memory stats (CPU)."""
-    try:
-        import jax
+def hbm_bytes_in_use_per_device() -> Optional[List[int]]:
+    """Bytes in use on EVERY local device, in device order, or None on
+    backends without memory stats (CPU). A sharded run shows here as
+    every device carrying its part of the tables; reading device 0
+    alone cannot tell four loaded chips from one."""
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
+    out = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
         if not stats or "bytes_in_use" not in stats:
             return None
-        return int(stats["bytes_in_use"])
-    except Exception:  # pragma: no cover - backend without stats
-        return None
+        out.append(int(stats["bytes_in_use"]))
+    return out
+
+
+def hbm_bytes_in_use() -> Optional[int]:
+    """Device-0 bytes in use (the HBM watermark each sample has always
+    recorded; the per-device list carries the rest)."""
+    per_device = hbm_bytes_in_use_per_device()
+    return per_device[0] if per_device else None
 
 
 def _parse_line(raw: bytes) -> Optional[dict]:

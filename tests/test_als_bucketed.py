@@ -44,6 +44,24 @@ class TestBucketConstruction:
         want = {(int(r), int(c)): float(v) for r, c, v in zip(ur, uc, uv)}
         assert got == want
 
+    def test_nnz_counts_exactly_past_float32_integers(self):
+        """The masks are float32; the pair count must not be a float32
+        running total, which stops counting exactly at 2^24 (the
+        ML-20M shape's 17.5M pairs came out one short)."""
+        from predictionio_tpu.ops.als import BucketedRatings, RatingsBucket
+
+        def bucket(mask):
+            z = np.zeros((1, 1))
+            return RatingsBucket(np.arange(len(mask), dtype=np.int32),
+                                 z.astype(np.int32), z.astype(np.float32),
+                                 mask)
+
+        big = np.ones((4096, 4100), dtype=np.float32)  # 16,793,600 > 2^24
+        side = BucketedRatings(
+            [bucket(big), bucket(np.ones((1, 3), dtype=np.float32))],
+            4096, 10)
+        assert side.nnz == 4096 * 4100 + 3
+
     def test_occupancy_beats_uniform_padding(self):
         rows, cols, vals = powerlaw_triples(n_users=800, n_items=600,
                                             nnz=8000)

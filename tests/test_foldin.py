@@ -428,7 +428,7 @@ class TestPatchUsers:
         srv = self._server(X, Y, {u: np.array([0]) for u in range(8)})
         row = rng.normal(size=(1, 4)).astype(np.float32)
         srv.patch_users(np.array([15]), row)  # grows, no seen_items
-        assert srv._seen_cols.shape[0] == srv.user_capacity
+        assert srv._seen_bits.shape[0] == srv.user_capacity
         idx, _ = srv.user_topk(15, 6)
         exp = np.argsort(-(Y @ row[0]))[:6]
         # nothing masked for the new user — item 0 ranks wherever the

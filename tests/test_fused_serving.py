@@ -15,7 +15,10 @@ import pytest
 
 import jax.numpy as jnp
 
-from predictionio_tpu.ops.als_pallas import fused_gather_score_topk
+from predictionio_tpu.ops.als_pallas import (
+    fused_gather_score_topk,
+    pack_seen_ids,
+)
 from predictionio_tpu.ops.quantize import (
     dequantize_rows_np,
     quantize_rows_int8,
@@ -45,6 +48,11 @@ def xla_chain_topk(Q, Y, seen_cols, seen_mask, k, n_items):
     return vals, idx
 
 
+def seen_bits(seen_cols, seen_mask, n_pos):
+    """The tests' ``[L, B]`` id lists as the kernel's packed bitmap."""
+    return pack_seen_ids(seen_cols.T, seen_mask.T > 0, n_pos)
+
+
 def int_factors(rng, shape, lo=-6, hi=7):
     return rng.integers(lo, hi, shape).astype(np.float32)
 
@@ -64,7 +72,7 @@ class TestKernelExactAgreement:
         sm = (rng.random((L, B)) < 0.7).astype(np.float32)
         n_items = M - 2
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), sc, sm, k=k,
+            jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, M), k=k,
             n_items=n_items, mask_seen=True, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, sc, sm, k, n_items)
         vals, idx = np.asarray(vals), np.asarray(idx)
@@ -79,7 +87,7 @@ class TestKernelExactAgreement:
         Q = int_factors(rng, (4, 5))
         Y = int_factors(rng, (40, 5))
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), None, None, k=6,
+            jnp.asarray(Q), jnp.asarray(Y), k=6,
             n_items=40, mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, None, None, 6, 40)
         np.testing.assert_array_equal(np.asarray(idx), wi)
@@ -93,7 +101,7 @@ class TestKernelExactAgreement:
         Y = np.zeros((200, 2), dtype=np.float32)
         Y[:, 0] = 7.0                      # every item ties at score 7
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), None, None, k=5,
+            jnp.asarray(Q), jnp.asarray(Y), k=5,
             n_items=200, mask_seen=False, interpret=True)
         np.testing.assert_array_equal(np.asarray(idx)[0],
                                       [0, 1, 2, 3, 4])
@@ -105,7 +113,7 @@ class TestKernelExactAgreement:
         sc = np.tile(np.arange(10, dtype=np.int32)[:, None], (1, 2))
         sm = np.ones((10, 2), dtype=np.float32)
         vals, _ = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), sc, sm, k=4,
+            jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, 10), k=4,
             n_items=10, mask_seen=True, interpret=True)
         assert (np.asarray(vals) == -np.inf).all()
 
@@ -114,7 +122,7 @@ class TestKernelExactAgreement:
         Q = rng.normal(size=(6, 8)).astype(np.float32)
         Y = rng.normal(size=(150, 8)).astype(np.float32)
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), None, None, k=10,
+            jnp.asarray(Q), jnp.asarray(Y), k=10,
             n_items=150, mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, None, None, 10, 150)
         np.testing.assert_allclose(np.asarray(vals), wv, rtol=1e-5)
@@ -134,7 +142,7 @@ class TestKernelInt8:
         Q = rng.integers(-5, 6, (4, 6)).astype(np.float32)
         Yq = quantize_rows_int8(Y)
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), Yq, None, None, k=8, n_items=70,
+            jnp.asarray(Q), Yq, k=8, n_items=70,
             mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, dequantize_rows_np(Yq), None, None,
                                 8, 70)
@@ -147,7 +155,7 @@ class TestKernelInt8:
         Q = rng.normal(size=(3, 5)).astype(np.float32)
         Yq = quantize_rows_int8(Y)
         vals, _ = fused_gather_score_topk(
-            jnp.asarray(Q), Yq, None, None, k=6, n_items=90,
+            jnp.asarray(Q), Yq, k=6, n_items=90,
             mask_seen=False, interpret=True)
         wv, _ = xla_chain_topk(Q, dequantize_rows_np(Yq), None, None,
                                6, 90)
@@ -269,15 +277,15 @@ class TestDeviceTopKFusedEndToEnd:
     @pytest.mark.slow
     def test_large_shape_multi_tile(self, monkeypatch):
         """A multi-tile catalog with a big k bucket (heavier interpret
-        run, slow-marked; `pytest -m pallas` on the bench host covers
-        it)."""
+        run, slow-marked)."""
         rng = np.random.default_rng(40)
         Q = int_factors(rng, (16, 16))
         Y = int_factors(rng, (1000, 16))
         sc = rng.integers(0, 1000, (12, 16)).astype(np.int32)
         sm = np.ones((12, 16), dtype=np.float32)
         vals, idx = fused_gather_score_topk(
-            jnp.asarray(Q), jnp.asarray(Y), sc, sm, k=64,
+            jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, 1000),
+            k=64,
             n_items=997, mask_seen=True, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, sc, sm, 64, 997)
         fin = np.isfinite(wv)

@@ -140,6 +140,72 @@ class TestTornAppendRecovery:
         assert len(list(a.find(app_id=APP))) == 16
 
 
+class TestEntityFilteredFind:
+    """``find(entity_id=...)`` locates candidate lines by byte search
+    (what keeps a fold-in gather off a typed parse of the whole store);
+    it must return exactly what the typed full scan returns, however a
+    line spells its id."""
+
+    IDS = ["u1", "u12", "12", "True", 'q"uote', "back\\slash", "ünï",
+           "u1 ", "[1]", "i3"]
+
+    def test_matches_typed_full_scan_for_every_spelling(self, tmp_path):
+        import json
+
+        from predictionio_tpu.data.storage.jsonlfs import (
+            _literal_searchable,
+        )
+        from predictionio_tpu.data.storage.memory import match_event
+
+        le = JsonlFsLEvents({"path": str(tmp_path / "ev"),
+                             "part_max_events": 7})
+        le.init(APP)
+        lines = []
+        for n, eid in enumerate(self.IDS * 3):
+            lines.append(json.dumps({
+                "event": "rate", "entityType": "user", "entityId": eid,
+                "eventId": f"e{n}", "targetEntityType": "item",
+                # other ids show up as targets and property values too
+                "targetEntityId": "u1" if n % 4 == 0 else "i3",
+                "properties": {"rating": n % 5, "note": "u12"},
+                "eventTime": "2020-01-01T00:00:00+00:00"},
+                ensure_ascii=(n % 2 == 0)))
+        # an id spelled as a JSON number, and one spelled with an escape
+        lines.append('{"event":"rate","entityType":"user","entityId":12,'
+                     '"eventId":"x1","targetEntityType":"item",'
+                     '"targetEntityId":"i1",'
+                     '"eventTime":"2020-01-01T00:00:00+00:00"}')
+        lines.append('{"event":"rate","entityType":"user",'
+                     '"entityId":"\\u00751","eventId":"x2",'
+                     '"targetEntityType":"item","targetEntityId":"i1",'
+                     '"eventTime":"2020-01-01T00:00:00+00:00"}')
+        le.append_raw_lines(lines, APP)
+        d = le._dir(APP, None)
+        searched = 0
+        for eid in self.IDS + ["nobody"]:
+            got = [e.event_id for e in le.find(APP, entity_id=eid)]
+            want = [e.event_id for e in le._iter_events(d)
+                    if match_event(e, entity_id=eid)]
+            assert got == want, eid
+            searched += _literal_searchable(eid)
+        assert [e.event_id for e in le.find(APP, entity_id="u1")][-1] \
+            == "x2"  # the escaped spelling was found
+        assert "x1" in [e.event_id for e in le.find(APP, entity_id="12")]
+        assert 0 < searched < len(self.IDS) + 1  # both lanes exercised
+
+    def test_unterminated_tail_is_not_an_event(self, tmp_path):
+        le = JsonlFsLEvents({"path": str(tmp_path / "ev")})
+        le.init(APP)
+        le.insert_batch(seed_events(4), APP)
+        part = le._parts(le._dir(APP, None))[0]
+        with open(part, "ab") as f:  # a racing append's partial flush
+            f.write(b'{"event":"rate","entityType":"user",'
+                    b'"entityId":"u0","targetEnt')
+        # a partition a writer has rolled to and not yet written
+        open(part.replace("part-00000", "part-00001"), "wb").close()
+        assert len(list(le.find(APP, entity_id="u0"))) == 2
+
+
 class TestColumnar:
     def test_matches_generic_oracle(self, store):
         got = store.find_columnar(

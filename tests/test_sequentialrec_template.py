@@ -295,7 +295,7 @@ class TestTrainPredict:
 
     def test_fold_in_rows_matches_training_encode(self, mem_storage):
         """The fold-in hook re-encodes a user's own (time-ordered)
-        history into their trained user vector: EXACT vs the
+        history into their trained user vector: equal to the
         single-device encoder, and within the sequence-parallel
         reduction-order tolerance vs the model's stored vectors (the
         test mesh makes training encode through ring/Ulysses)."""
@@ -319,11 +319,16 @@ class TestTrainPredict:
             rows = model.fold_in_rows([cols], [np.ones(len(cols),
                                                        np.float32)])
             uidx = model.user_map[user]
-            # exact vs the single-device encode of the same sequence
+            # vs the single-device encode of the same sequence. The
+            # fold pads its batch to 8 rows, the reference encodes 1:
+            # two batch shapes are two compiled programs and owe no
+            # common last bit (~2e-7 apart under jax 0.9.0) — the
+            # bound is ~100x tighter than a bf16 pass would meet
             ref = encode_users(
                 model.theta, bucket_sequences([cols], max_len=16), 1,
                 model.enc_params)
-            np.testing.assert_array_equal(rows[0], ref[0])
+            np.testing.assert_allclose(rows[0], ref[0],
+                                       rtol=1e-5, atol=1e-6)
             # within SP tolerance vs the (mesh-encoded) stored vector
             np.testing.assert_allclose(rows[0],
                                        model.user_vectors[uidx],

@@ -15,7 +15,7 @@ from predictionio_tpu.data import storage
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage.base import App
 from predictionio_tpu.ops.als import ALSParams, pad_ratings, train_als
-from predictionio_tpu.ops.serving import DeviceTopK, seen_tables
+from predictionio_tpu.ops.serving import DeviceTopK, seen_bitmap
 
 UTC = dt.timezone.utc
 CTX = ComputeContext()
@@ -147,13 +147,38 @@ class TestDeviceTopK:
         srv.users_topk(np.arange(9), 5)    # uid bucket 16
         assert len(srv._batch_programs) == 2
 
-    def test_seen_tables_packing(self):
-        cols, mask = seen_tables({0: np.asarray([3, 1]),
-                                  2: np.asarray([7])}, 4)
-        assert cols.shape == mask.shape and cols.shape[0] == 4
-        assert set(cols[0][mask[0] > 0].tolist()) == {3, 1}
-        assert mask[1].sum() == 0
-        assert cols[2][0] == 7 and mask[2].sum() == 1
+    def test_packed_output_is_integer_and_exact(self):
+        """One fetch carries scores AND indices. The carrier must be an
+        integer buffer: reinterpreted as float32, indices below 2^23
+        are denormals, which the TPU flushes to zero (on the v5e a
+        float32 carrier returned every index as 0). The round trip is
+        exact for any score bit pattern, -inf and denormals included."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops.serving import _pack, _unpack
+
+        scores = np.asarray([[3.5, 1e-42, -0.0, -np.inf],
+                             [np.inf, 2.0, 1.0, 0.5]], dtype=np.float32)
+        idx = np.asarray([[0, 1, 8388607, 26999],
+                          [2**31 - 1, 5, 6, 7]], dtype=np.int32)
+        packed = _pack(jnp.asarray(scores), jnp.asarray(idx))
+        assert packed.dtype == jnp.int32 and packed.shape == (2, 8)
+        got_idx, got_scores = _unpack(np.asarray(packed), 4)
+        np.testing.assert_array_equal(got_idx, idx)
+        assert got_scores.dtype == np.float32
+        np.testing.assert_array_equal(got_scores.view(np.int32),
+                                      scores.view(np.int32))
+
+    def test_seen_bitmap_packing(self):
+        # 70 positions -> 3 words; bit j of word w = position 32*w + j,
+        # bit 31 included (the int32 sign bit); out-of-range ids drop
+        bits = seen_bitmap({0: np.asarray([3, 1]),
+                            2: np.asarray([7, 31, 69, 70, -1])}, 4, 70)
+        assert bits.shape == (4, 3) and bits.dtype == np.int32
+        u = bits.view(np.uint32)
+        assert u[0].tolist() == [(1 << 3) | (1 << 1), 0, 0]
+        assert not u[1].any() and not u[3].any()
+        assert u[2].tolist() == [(1 << 7) | (1 << 31), 0, 1 << 5]
 
 
 class TestMicroBatching:

@@ -304,18 +304,44 @@ class TestZeroCompileSteadyState:
         assert not missing, f"warmup left ladder gaps: {missing}"
         srv.close()
 
-    def test_store_growth_invalidates_aot(self):
-        """A fold-in growth reshapes the store: stale executables must
-        never serve it (signature-keyed cache + eager clear)."""
+    def test_store_growth_reladders_before_publish(self):
+        """A fold-in growth reshapes the store. The warmed ladder is
+        compiled for the grown shape BEFORE the grown store is
+        published, so queries after it still hit executables; the old
+        shape's executables are released."""
+        rng = np.random.default_rng(1)
+        srv = DeviceTopK(rng.normal(size=(8, 4)).astype(np.float32),
+                         rng.normal(size=(20, 4)).astype(np.float32),
+                         {0: np.array([1, 2])})
+        srv.warmup(max_k=16)
+        plan = srv.aot_plan(max_k=16)
+        with srv._store_lock:
+            old_sig = srv._store_sig_locked()
+        before = srv.ladder_report()["requests"]
+        srv.patch_users([12], rng.normal(size=(1, 4)).astype(np.float32),
+                        seen_items={12: np.array([3])})
+        with srv._store_lock:
+            new_sig = srv._store_sig_locked()
+        assert new_sig != old_sig and srv.user_capacity == 16
+        assert sorted(srv._aot_programs.keys()) == sorted(
+            (new_sig, e) for e in plan)
+        idx, scores = srv.user_topk(12, 5)
+        assert len(idx) == 5 and np.isfinite(scores).all()
+        assert 3 not in idx
+        srv.users_topk(np.arange(6), 10)
+        srv.items_topk([0], 5)
+        after = srv.ladder_report()["requests"]
+        assert after["missJit"] == before["missJit"] == 0
+        assert after["hit"] - before["hit"] == 3
+        srv.close()
+
+    def test_unwarmed_store_grows_without_compiling_a_ladder(self):
         rng = np.random.default_rng(1)
         srv = DeviceTopK(rng.normal(size=(8, 4)).astype(np.float32),
                          rng.normal(size=(20, 4)).astype(np.float32))
-        srv.warmup(max_k=16)
-        assert len(srv._aot_programs) > 0
         srv.patch_users([12], rng.normal(size=(1, 4)).astype(np.float32))
         assert len(srv._aot_programs) == 0
-        # the jit fallback still serves the grown store correctly
-        idx, scores = srv.user_topk(12, 5)
+        idx, scores = srv.user_topk(12, 5)  # the jit program serves it
         assert len(idx) == 5 and np.isfinite(scores).all()
         srv.close()
 

@@ -38,9 +38,6 @@ def build_inputs(num_iterations: int = DEFAULT_ITERS):
 
 def main(out_path: str) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     from predictionio_tpu.ops.als import train_als
     from predictionio_tpu.workflow import checkpoint
