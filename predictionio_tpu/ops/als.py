@@ -618,12 +618,30 @@ def _solve_rows(Y, cols, weights, mask, lam: float, alpha: float,
     import jax
     import jax.numpy as jnp
 
-    R = Y.shape[1]
-    Yg = jnp.take(Y, cols, axis=0)            # [B, L, R] gather
+    with jax.named_scope("gather"):
+        Yg = jnp.take(Y, cols, axis=0)        # [B, L, R] gather
     if precision == "bf16":
         X = _solve_rows_bf16(Y, Yg, weights, mask, lam, alpha, implicit,
                              gram, solver, refine, extra_ridge)
         return zero_empty_rows(X, mask.astype(X.dtype))
+    with jax.named_scope("assemble"):
+        A, b, mask = _assemble_fp32(Y, Yg, weights, mask, lam, alpha,
+                                    implicit, gram, extra_ridge)
+    with jax.named_scope("solve"):
+        X = _spd_solve(A, b, solver)
+        if refine:
+            X = _refine_solve(A, b, X, solver)
+        return zero_empty_rows(X, mask)
+
+
+def _assemble_fp32(Y, Yg, weights, mask, lam, alpha, implicit: bool,
+                   gram, extra_ridge):
+    """The fp32 normal equations of :func:`_solve_rows`: ``A [B, R, R]``,
+    ``b [B, R]`` and the mask cast to the factor dtype."""
+    import jax
+    import jax.numpy as jnp
+
+    R = Y.shape[1]
     mask = mask.astype(Y.dtype)
     w = weights.astype(Y.dtype) * mask        # zero out padded slots
     # Normal equations are precision-sensitive: force full fp32 MXU passes
@@ -655,10 +673,7 @@ def _solve_rows(Y, cols, weights, mask, lam: float, alpha: float,
     if extra_ridge is not None:
         A += extra_ridge.astype(A.dtype)[None, None, :] \
             * jnp.eye(R, dtype=A.dtype)
-    X = _spd_solve(A, b, solver)
-    if refine:
-        X = _refine_solve(A, b, X, solver)
-    return zero_empty_rows(X, mask)
+    return A, b, mask
 
 
 def _solve_rows_bf16(Y, Yg, weights, mask, lam: float, alpha: float,
@@ -667,6 +682,22 @@ def _solve_rows_bf16(Y, Yg, weights, mask, lam: float, alpha: float,
     """The bf16 lane of :func:`_solve_rows`: bf16 operands into every
     MXU pass, fp32 accumulators out (``preferred_element_type``), fp32
     solve, result cast back to bf16 factor storage."""
+    import jax
+
+    with jax.named_scope("assemble"):
+        A, b = _assemble_bf16(Y, Yg, weights, mask, lam, alpha, implicit,
+                              gram, extra_ridge)
+    with jax.named_scope("solve"):
+        X = _spd_solve(A, b, solver)
+        if refine:
+            X = _refine_solve(A, b, X, solver)
+        return X.astype(Y.dtype)
+
+
+def _assemble_bf16(Y, Yg, weights, mask, lam, alpha, implicit: bool,
+                   gram, extra_ridge):
+    """The bf16 lane's normal equations: bf16 operands, fp32
+    accumulators (``A [B, R, R]``, ``b [B, R]``)."""
     import jax.numpy as jnp
 
     f32, bf16 = jnp.float32, jnp.bfloat16
@@ -693,10 +724,7 @@ def _solve_rows_bf16(Y, Yg, weights, mask, lam: float, alpha: float,
                        preferred_element_type=f32)
     if extra_ridge is not None:
         A += extra_ridge.astype(f32)[None, None, :] * jnp.eye(R, dtype=f32)
-    X = _spd_solve(A, b, solver)
-    if refine:
-        X = _refine_solve(A, b, X, solver)
-    return X.astype(Y.dtype)
+    return A, b
 
 
 def _spd_solver_mode() -> str:
@@ -820,8 +848,9 @@ def spd_solve_lanes(A, b, panel: int = 8):
         A = A - upd * col_gt
         return A, L
 
-    _, L = jax.lax.fori_loop(0, n_panels, panel_step,
-                             (At, jnp.zeros_like(At)))
+    with jax.named_scope("factor"):
+        _, L = jax.lax.fori_loop(0, n_panels, panel_step,
+                                 (At, jnp.zeros_like(At)))
 
     def fwd_step(k, carry):
         y, bw = carry
@@ -832,8 +861,9 @@ def spd_solve_lanes(A, b, panel: int = 8):
         bw = bw - lc * yk                     # rows < k of lc are zero
         return y, bw
 
-    y, _ = jax.lax.fori_loop(0, Rp, fwd_step,
-                             (jnp.zeros_like(bt), bt))
+    with jax.named_scope("forward"):
+        y, _ = jax.lax.fori_loop(0, Rp, fwd_step,
+                                 (jnp.zeros_like(bt), bt))
 
     def bwd_step(i, x):
         k = Rp - 1 - i
@@ -843,7 +873,8 @@ def spd_solve_lanes(A, b, panel: int = 8):
         xk = (jax.lax.dynamic_slice(y, (k, 0), (1, B)) - s) / d
         return jax.lax.dynamic_update_slice(x, xk, (k, 0))
 
-    x = jax.lax.fori_loop(0, Rp, bwd_step, jnp.zeros_like(bt))
+    with jax.named_scope("backward"):
+        x = jax.lax.fori_loop(0, Rp, bwd_step, jnp.zeros_like(bt))
     return jnp.transpose(x, (1, 0))[:, :R]
 
 
@@ -890,10 +921,14 @@ def _als_iterations_impl(X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m, *, lam,
 
     def body(carry, _):
         X, Y = carry
-        X = _solve_side_blocked(Y, u_cols, u_w, u_m, lam, alpha, implicit,
-                                block, solver, precision, refine)
-        Y = _solve_side_blocked(X, i_cols, i_w, i_m, lam, alpha, implicit,
-                                block, solver, precision, refine)
+        with jax.named_scope("user_step"):
+            X = _solve_side_blocked(Y, u_cols, u_w, u_m, lam, alpha,
+                                    implicit, block, solver, precision,
+                                    refine)
+        with jax.named_scope("item_step"):
+            Y = _solve_side_blocked(X, i_cols, i_w, i_m, lam, alpha,
+                                    implicit, block, solver, precision,
+                                    refine)
         return (X, Y), None
 
     (X, Y), _ = jax.lax.scan(body, (X, Y), None, length=num_iterations)
@@ -944,13 +979,15 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
     import jax.numpy as jnp
 
     R = Y.shape[1]
-    if precision == "bf16":
-        # one shared fp32-accumulated Gram from the bf16 factor store
-        gram = jnp.matmul(Y.T, Y, preferred_element_type=jnp.float32) \
-            if implicit else None
-    else:
-        gram = jnp.matmul(Y.T, Y, precision=jax.lax.Precision.HIGHEST) \
-            if implicit else None
+    with jax.named_scope("gram"):
+        if precision == "bf16":
+            # one shared fp32-accumulated Gram from the bf16 factor store
+            gram = jnp.matmul(Y.T, Y, preferred_element_type=jnp.float32) \
+                if implicit else None
+        else:
+            gram = jnp.matmul(Y.T, Y,
+                              precision=jax.lax.Precision.HIGHEST) \
+                if implicit else None
     X = jnp.zeros((n_rows_out, R), Y.dtype)
     for row_ids, cols, w, m in buckets:
         B, L = cols.shape
@@ -979,7 +1016,8 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
             Xb = _solve_rows(Y, cols, w, m, lam, alpha, implicit, gram,
                              solver, precision, refine, extra_ridge)
         # pad rows carry the sentinel row_id == n_rows_out -> dropped
-        X = X.at[row_ids].set(Xb, mode="drop")
+        with jax.named_scope("scatter"):
+            X = X.at[row_ids].set(Xb, mode="drop")
     return X
 
 
@@ -996,10 +1034,14 @@ def _als_iterations_bucketed_impl(X, Y, u_buckets, i_buckets, *, lam,
 
     def body(carry, _):
         X, Y = carry
-        X = _solve_side_bucketed(Y, u_buckets, n_u, lam, alpha, implicit,
-                                 slot_budget, solver, precision, refine)
-        Y = _solve_side_bucketed(X, i_buckets, n_i, lam, alpha, implicit,
-                                 slot_budget, solver, precision, refine)
+        with jax.named_scope("user_step"):
+            X = _solve_side_bucketed(Y, u_buckets, n_u, lam, alpha,
+                                     implicit, slot_budget, solver,
+                                     precision, refine)
+        with jax.named_scope("item_step"):
+            Y = _solve_side_bucketed(X, i_buckets, n_i, lam, alpha,
+                                     implicit, slot_budget, solver,
+                                     precision, refine)
         return (X, Y), None
 
     (X, Y), _ = jax.lax.scan(body, (X, Y), None, length=num_iterations)
@@ -1600,54 +1642,93 @@ def train_als_bucketed(user_side: BucketedRatings,
     once when training repeatedly."""
     assert user_side.n_rows >= item_side.n_cols
     assert item_side.n_rows >= user_side.n_cols
-    precision = _als_precision_mode(params)  # resolved per call
-    X, Y = init_policy_factors(user_side.n_rows, item_side.n_rows,
-                               params.rank, params.seed, dtype, precision)
-    # args/statics built by the SAME helper the AOT warm-up lowers
-    # with, so a warmed executable always matches this call's signature
-    (_, _, u_t, i_t), kw = _bucketed_call_args(user_side, item_side,
-                                               params, precision)
-    ckpt = _maybe_checkpointer(
-        checkpoint_layout_bucketed(user_side, item_side), params,
-        kw["solver"], precision, dtype)
-    if ckpt is None:
-        X, Y = _als_iterations_bucketed(X, Y, u_t, i_t, **kw)
-    else:
-        # crash-safe lane: chunk-length scans with atomic checkpoints,
-        # preemption and the finite guard between them (byte-identical
-        # to the single scan — differential-gated)
-        import jax.numpy as jnp
+    import jax
 
-        from predictionio_tpu.workflow import checkpoint as _checkpoint
-        from predictionio_tpu.workflow import runlog as _runlog
+    from predictionio_tpu.utils import metrics as _metrics
+    from predictionio_tpu.utils import tracing as _tracing
 
-        fdt = X.dtype
+    # one local root per call (a child span inside `pio train`'s root):
+    # stage / iterations / fetch, so a call's time outside the training
+    # program is a span and not a subtraction
+    with _tracing.trace_scope("als.train", slow_exempt=True):
+        with _tracing.span("als.stage"):
+            precision = _als_precision_mode(params)  # resolved per call
+            X, Y = init_policy_factors(user_side.n_rows, item_side.n_rows,
+                                       params.rank, params.seed, dtype,
+                                       precision)
+            # args/statics built by the SAME helper the AOT warm-up
+            # lowers with, so a warmed executable always matches this
+            # call's signature; the tables go up here (a no-op for sides
+            # already staged with .to_device()) and are waited for, so
+            # the upload is this span's and not the first iteration's
+            (_, _, u_t, i_t), kw = _bucketed_call_args(
+                user_side.to_device(), item_side.to_device(), params,
+                precision)
+            jax.block_until_ready((X, Y))
+            ckpt = _maybe_checkpointer(
+                checkpoint_layout_bucketed(user_side, item_side), params,
+                kw["solver"], precision, dtype)
+        with _tracing.span("als.iterations"):
+            compile_s0 = _metrics.JIT_COMPILE_SECONDS.value()
+            t0 = _tracing.span_now()
+            if ckpt is None:
+                X, Y = _als_iterations_bucketed(X, Y, u_t, i_t, **kw)
+            else:
+                X, Y = _run_checkpointed_bucketed(
+                    X, Y, u_t, i_t, kw, ckpt, params, precision,
+                    user_side.nnz)
+            compile_s = _metrics.JIT_COMPILE_SECONDS.value() - compile_s0
+            if compile_s > 0:
+                # a first call: the compile pipeline (trace, lower,
+                # compile or cache load) ran before the program did
+                # (its phases are summed, so never past now)
+                _tracing.record_completed_span(
+                    "als.compile", t0,
+                    min(t0 + compile_s, _tracing.span_now()))
+            jax.block_until_ready((X, Y))
+        with _tracing.span("als.fetch"):
+            # host factors always land fp32: persistence, serving and
+            # the eval stack stay byte-compatible regardless of the
+            # training policy
+            return (np.asarray(X, dtype=np.float32),
+                    np.asarray(Y, dtype=np.float32))
 
-        def run_iters(Xc, Yc, n):
-            return _als_iterations_bucketed(
-                Xc, Yc, u_t, i_t, **dict(kw, num_iterations=int(n)))
 
-        objective = None
-        if _train_telemetry_enabled():
-            obj_kw = _objective_statics(params)
+def _run_checkpointed_bucketed(X, Y, u_t, i_t, kw: dict, ckpt,
+                               params: ALSParams, precision: str,
+                               trained_pairs: int):
+    """The crash-safe lane of :func:`train_als_bucketed`: chunk-length
+    scans with atomic checkpoints, preemption and the finite guard
+    between them (byte-identical to the single scan —
+    differential-gated)."""
+    import jax.numpy as jnp
 
-            def objective(Xc, Yc):
-                return _objective_pack(Xc, Yc, u_t, **obj_kw)
+    from predictionio_tpu.workflow import checkpoint as _checkpoint
+    from predictionio_tpu.workflow import runlog as _runlog
 
-        # the run-log header names what the platform resolved (solver,
-        # precision), how many pairs train and over how many devices
-        with _runlog.run_context_scope(
-                solver=kw["solver"], precision=precision,
-                trainedPairs=user_side.nnz, devices=1):
-            X, Y = _checkpoint.run_chunked(
-                run_iters, X, Y, int(params.num_iterations), ckpt,
-                to_host=lambda a: np.asarray(a, dtype=np.float32),
-                from_host=lambda a: jnp.asarray(a, dtype=fdt),
-                objective=objective)
-    # host factors always land fp32: persistence, serving and the eval
-    # stack stay byte-compatible regardless of the training policy
-    return (np.asarray(X, dtype=np.float32),
-            np.asarray(Y, dtype=np.float32))
+    fdt = X.dtype
+
+    def run_iters(Xc, Yc, n):
+        return _als_iterations_bucketed(
+            Xc, Yc, u_t, i_t, **dict(kw, num_iterations=int(n)))
+
+    objective = None
+    if _train_telemetry_enabled():
+        obj_kw = _objective_statics(params)
+
+        def objective(Xc, Yc):
+            return _objective_pack(Xc, Yc, u_t, **obj_kw)
+
+    # the run-log header names what the platform resolved (solver,
+    # precision), how many pairs train and over how many devices
+    with _runlog.run_context_scope(
+            solver=kw["solver"], precision=precision,
+            trainedPairs=trained_pairs, devices=1):
+        return _checkpoint.run_chunked(
+            run_iters, X, Y, int(params.num_iterations), ckpt,
+            to_host=lambda a: np.asarray(a, dtype=np.float32),
+            from_host=lambda a: jnp.asarray(a, dtype=fdt),
+            objective=objective)
 
 
 def init_factors(n_rows: int, n_cols: int, rank: int,
@@ -1769,14 +1850,17 @@ def _get_fold_in_jit():
     if _fold_in_jit is None:
         import jax
 
-        def impl(Y, cols, weights, mask, *, lam, alpha, implicit,
-                 solver, precision, refine):
-            return _solve_rows(Y, cols, weights, mask, lam, alpha,
-                               implicit, None, solver, precision, refine)
+        def fold_in_solve(Y, cols, weights, mask, *, lam, alpha, implicit,
+                          solver, precision, refine):
+            with jax.named_scope("fold_in"):
+                return _solve_rows(Y, cols, weights, mask, lam, alpha,
+                                   implicit, None, solver, precision,
+                                   refine)
 
         _fold_in_jit = jax.jit(
-            impl, static_argnames=("lam", "alpha", "implicit", "solver",
-                                   "precision", "refine"))
+            fold_in_solve,
+            static_argnames=("lam", "alpha", "implicit", "solver",
+                             "precision", "refine"))
     return _fold_in_jit
 
 
@@ -1888,15 +1972,18 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
         # top-k: record its dispatch->block window in the flight ring
         # (lane "foldin"; kBucket carries the padded history length L,
         # bucket the padded user batch B) and emit the device.execute
-        # span under the ambient foldin.solve span
+        # span under the ambient foldin.solve span (for the profiler,
+        # live annotations round the call and the block)
         from predictionio_tpu.utils import tracing as _tracing
 
-        t0m = _time.monotonic()
         t0e = _tracing.span_now()
-        out = _get_fold_in_jit()(Y, cols, weights, mask, **fold_kwargs)
-        t1m = _time.monotonic()
-        out.block_until_ready()
-        t2m = _time.monotonic()
+        with _tracing.annotation("dispatch.enqueue"):
+            t0m = _time.monotonic()
+            out = _get_fold_in_jit()(Y, cols, weights, mask, **fold_kwargs)
+            t1m = _time.monotonic()
+        with _tracing.annotation("dispatch.wait"):
+            out.block_until_ready()
+            t2m = _time.monotonic()
         rec = _dtel.record_dispatch(
             lane="foldin", kernel="xla", precision=precision,
             aot="jit", k_bucket=int(cols.shape[1]), batch=k,

@@ -129,6 +129,7 @@ def _build(n_rows: int, L: int, M: int, R: int, interpret: bool):
         scratch_shapes=[pltpu.VMEM((_BB * L, R), jnp.float32),
                         pltpu.SemaphoreType.DMA],
         interpret=interpret,
+        name="assemble_normal_equations",
     )
     return jax.jit(fn)
 
@@ -260,6 +261,7 @@ def _build_spd(B: int, R: int, interpret: bool):
             pltpu.VMEM((R, _SPD_BB), jnp.float32),      # bwork
         ],
         interpret=interpret,
+        name="spd_solve",
     )
     return fn
 
@@ -623,5 +625,6 @@ def fused_gather_score_topk(Q, Y, seen_bits=None, *,
             pltpu.VMEM((K + TM, Bp), jnp.int32),
         ],
         interpret=bool(interpret),
+        name="fused_topk",
     )(*args)
     return vals.T[:B], idx.T[:B]

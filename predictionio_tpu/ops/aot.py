@@ -24,16 +24,18 @@ server that reports ready and compiles, or fails, on a live query.
 Observability (PR 12): evictions are counted and logged WITH the
 dropped key — a fold-in-growth recompile storm shows up as a rising
 ``pio_aot_cache_evictions_total`` instead of a mystery — and
-:meth:`AOTCache.memory_report` aggregates ``memory_analysis()`` over
-every compiled entry so the query server's ``/stats.json`` can say how
-much the ladder itself occupies.
+:meth:`AOTCache.memory_report` reads ``memory_analysis()`` of every
+compiled entry so the query server's ``/stats.json`` can say how much
+scratch and code the ladder's programs themselves need.
 """
 
 from __future__ import annotations
 
 import logging
 import threading
-from typing import Any, Dict, Hashable, Iterator, Optional
+from typing import Any, Dict, Hashable, Iterator, Optional, Tuple
+
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger("pio.aot")
 
@@ -55,7 +57,7 @@ class AOTCache:
         self._evictions = 0
         # memory_analysis is not free and the answer is immutable per
         # executable — cache the per-entry byte estimate by object id
-        self._mem_cache: Dict[int, Optional[int]] = {}
+        self._mem_cache: Dict[int, Optional[Tuple[int, int]]] = {}
 
     def get(self, key: Hashable) -> Optional[Any]:
         with self._lock:
@@ -118,39 +120,36 @@ class AOTCache:
                     "evictions": self._evictions}
 
     @staticmethod
-    def _entry_bytes(compiled: Any) -> Optional[int]:
-        """One executable's footprint estimate from XLA's own
-        ``memory_analysis()`` (argument + output + temp + generated
-        code, the ``als_precision_bench`` recipe); None where this
-        backend/jax version has no stats."""
+    def _entry_bytes(compiled: Any) -> Optional[Tuple[int, int]]:
+        """One executable's OWN footprint from XLA's
+        ``memory_analysis()``: (temporaries, generated code). Its
+        arguments and outputs are not its own — every ladder program
+        takes the whole store as arguments, and summing those counted
+        the store once per program. None where this backend/jax version
+        has no stats."""
         try:
             ma = compiled.memory_analysis()
         except Exception:
             return None
         if ma is None:
             return None
-        total = 0
-        found = False
-        for attr in ("argument_size_in_bytes", "output_size_in_bytes",
-                     "temp_size_in_bytes",
-                     "generated_code_size_in_bytes"):
-            try:
-                v = getattr(ma, attr)
-            except AttributeError:
-                continue
-            if v is not None:
-                total += int(v)
-                found = True
-        return total if found else None
+        own = [getattr(ma, attr, None) for attr in
+               ("temp_size_in_bytes", "generated_code_size_in_bytes")]
+        if all(v is None for v in own):
+            return None
+        return int(own[0] or 0), int(own[1] or 0)
 
     def memory_report(self) -> Dict[str, Any]:
-        """Aggregate ``memory_analysis()`` over every compiled entry:
-        total byte estimate + per-entry breakdown availability. The
+        """What the cached programs themselves need on the device:
+        ``tempBytes`` is the LARGEST single program's temporaries (one
+        program runs at a time on a chip, so the runtime sets scratch
+        aside for the hungriest, not for the sum), ``codeBytes`` the sum
+        of generated code, ``totalBytes`` the two together. The
         per-entry answer is cached (executables are immutable), so a
         scrape pays the XLA query once per compile, not once per poll."""
         with self._lock:
             entries = list(self._entries.values())
-        total = 0
+        temp = code = 0
         analyzed = 0
         for compiled in entries:
             cached = self._mem_cache.get(id(compiled), "?")
@@ -165,10 +164,12 @@ class AOTCache:
                     if any(v is compiled for v in self._entries.values()):
                         self._mem_cache[id(compiled)] = cached
             if cached is not None:
-                total += cached
+                temp = max(temp, cached[0])
+                code += cached[1]
                 analyzed += 1
         return {"entries": len(entries), "entriesAnalyzed": analyzed,
-                "totalBytes": total}
+                "tempBytes": temp, "codeBytes": code,
+                "totalBytes": temp + code}
 
 
 # Tracing + lowering happens one program at a time, process-wide. It is
@@ -189,7 +190,15 @@ def lower_compile(jitted, *args, **kwargs) -> Any:
     baked into the executable — pass the REAL factor stores so a
     sharded model compiles for its own mesh) and
     ``jax.ShapeDtypeStruct`` placeholders for per-call inputs. A
-    lowering or compile error propagates with the compiler's message."""
-    with _lower_lock:
+    lowering or compile error propagates with the compiler's message.
+
+    The serialized trace + lower is a ``ladder.lower`` span: from a
+    pool of workers those spans never overlap, so under one parent span
+    round the pool they and the parent's self time (compile or cache
+    load, whichever thread does it) add up to the wall clock. The
+    compile overlaps other workers' lowering, so it is only a profiler
+    annotation on its own thread."""
+    with _lower_lock, _tracing.span("ladder.lower"):
         lowered = jitted.lower(*args, **kwargs)
-    return lowered.compile()
+    with _tracing.annotation("ladder.compile"):
+        return lowered.compile()

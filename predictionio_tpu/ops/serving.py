@@ -43,7 +43,6 @@ import time
 import weakref
 from concurrent.futures import Future
 from concurrent.futures import TimeoutError as _FuturesTimeout
-from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -486,14 +485,21 @@ def _user_topk(X, Y, seen_bits, uid, *, k: int, mask_seen: bool,
 
     from predictionio_tpu.ops.als_pallas import unpack_seen_bits
 
-    u = _take_user_row_f32(X, uid, mode=mode)
-    scores = _score_einsum("mr,r->m", Y, u, mode=mode)
+    with jax.named_scope("gather_q"):
+        u = _take_user_row_f32(X, uid, mode=mode)
+    with jax.named_scope("topk"):
+        scores = _score_einsum("mr,r->m", Y, u, mode=mode)
     if mask_seen:
-        row = jax.lax.dynamic_index_in_dim(seen_bits, uid, 0,
-                                           keepdims=False)
-        scores = jnp.where(unpack_seen_bits(row, scores.shape[0]),
-                           -jnp.inf, scores)
-    return _pack(*jax.lax.top_k(_mask_padding(scores, n_items), k))
+        with jax.named_scope("seen_rows"):
+            row = jax.lax.dynamic_index_in_dim(seen_bits, uid, 0,
+                                               keepdims=False)
+    with jax.named_scope("topk"):
+        if mask_seen:
+            scores = jnp.where(unpack_seen_bits(row, scores.shape[0]),
+                               -jnp.inf, scores)
+        vals, idx = jax.lax.top_k(_mask_padding(scores, n_items), k)
+    with jax.named_scope("pack"):
+        return _pack(vals, idx)
 
 
 def _gather_query_rows_f32(Yn, idx, idx_mask, *, mode: str):
@@ -521,12 +527,16 @@ def _items_topk(Yn, idx, idx_mask, *, k: int, n_items: int,
     import jax
     import jax.numpy as jnp
 
-    qm = _gather_query_rows_f32(Yn, idx, idx_mask, mode=mode)
-    scores = _score_einsum("mr,br->m", Yn, qm, mode=mode)
-    # the query items themselves never recommend (mask to -inf)
-    scores = scores.at[idx].add(
-        jnp.where(idx_mask > 0, -jnp.inf, 0.0), mode="drop")
-    return _pack(*jax.lax.top_k(_mask_padding(scores, n_items), k))
+    with jax.named_scope("gather_q"):
+        qm = _gather_query_rows_f32(Yn, idx, idx_mask, mode=mode)
+    with jax.named_scope("topk"):
+        scores = _score_einsum("mr,br->m", Yn, qm, mode=mode)
+        # the query items themselves never recommend (mask to -inf)
+        scores = scores.at[idx].add(
+            jnp.where(idx_mask > 0, -jnp.inf, 0.0), mode="drop")
+        vals, top = jax.lax.top_k(_mask_padding(scores, n_items), k)
+    with jax.named_scope("pack"):
+        return _pack(vals, top)
 
 
 def _normalize_rows(Y):
@@ -1206,6 +1216,7 @@ class BatchDispatcher:
         return None if earliest is None else max(0.0, earliest - now)
 
     def _run(self) -> None:
+        _dtel.mark_ready()
         while True:
             self._wake.clear()
             self._drain_handoff()
@@ -1221,31 +1232,35 @@ class BatchDispatcher:
             delay = self._next_delay(now)
             if delay is None:
                 # idle: bounded wait, exit when the owner was dropped
-                if not self._wake.wait(1.0) and self._srv_ref() is None:
+                with _dtel.stage("gapIdleUs", "batch.idle"):
+                    woke = self._wake.wait(1.0)
+                if not woke and self._srv_ref() is None:
                     with self._thread_lock:
                         self._drain_handoff()
                         if self._all_empty():
                             self._thread = None
                             return
             elif delay > 0:
-                self._wake.wait(delay)
+                with _dtel.stage("gapWindowUs", "batch.window"):
+                    self._wake.wait(delay)
 
     def _dispatch(self, lane: BatchLane, trigger: str) -> None:
         q = lane.queue
-        with self._stats_lock:
-            depth = lane.pending  # waiting anywhere, handoff included
         group: List[_Pending] = []
-        popped = 0
-        while q and len(group) < lane.max_batch:
-            it = q.pop(0)  # EDF: earliest deadline forms the batch
-            popped += 1
-            # a False return means the waiter shed it (queue-deadline
-            # 503) — drop it from the batch
-            if it.future.set_running_or_notify_cancel():
-                group.append(it)
-        with self._stats_lock:
-            lane.pending -= popped
-        self._set_queue_gauge(lane)
+        with _dtel.stage("formUs", "batch.form"):
+            with self._stats_lock:
+                depth = lane.pending  # waiting anywhere, handoff included
+            popped = 0
+            while q and len(group) < lane.max_batch:
+                it = q.pop(0)  # EDF: earliest deadline forms the batch
+                popped += 1
+                # a False return means the waiter shed it
+                # (queue-deadline 503) — drop it from the batch
+                if it.future.set_running_or_notify_cancel():
+                    group.append(it)
+            with self._stats_lock:
+                lane.pending -= popped
+            self._set_queue_gauge(lane)
         if not group:
             return
         srv = self._srv_ref()
@@ -1254,17 +1269,21 @@ class BatchDispatcher:
                 raise RuntimeError("serving backend was released")
             if _dtel.enabled():
                 # batching context the device dispatch site cannot see:
-                # the oldest grouped query's queue wait, the group
-                # size, and a trace parent (the dispatcher thread has
-                # no ambient trace of its own — borrow the first traced
-                # query's so the device.execute span lands in a tree)
-                wait = max(0.0, time.monotonic()
-                           - min(it.arrival for it in group))
+                # the oldest grouped query's queue wait and the
+                # group's mean, the group size, and a trace parent (the
+                # dispatcher thread has no ambient trace of its own —
+                # borrow the first traced query's so the device.execute
+                # span lands in a tree)
+                now = time.monotonic()
+                arrivals = [it.arrival for it in group]
+                wait = max(0.0, now - min(arrivals))
+                mean = max(0.0, now - sum(arrivals) / len(arrivals))
                 parent = next((it.ctx for it in group
                                if it.ctx is not None), None)
                 with _dtel.dispatch_scope(queue_wait_us=wait * 1e6,
                                           group=len(group),
-                                          trace_parent=parent):
+                                          trace_parent=parent,
+                                          queue_wait_mean_us=mean * 1e6):
                     lane.dispatch_fn(srv, group)
             else:
                 lane.dispatch_fn(srv, group)
@@ -1278,24 +1297,40 @@ class BatchDispatcher:
                 if not it.future.done():
                     it.future.set_exception(RuntimeError(
                         "batch dispatch completed without a result"))
-        with self._stats_lock:
-            lane.dispatches += 1
-            lane.batched_queries += len(group)
-            lane.triggers[trigger] += 1
-            lane.depth_samples.append(depth)
-        from predictionio_tpu.utils import metrics
+        with _dtel.stage("deliverUs", "batch.deliver", done=True):
+            with self._stats_lock:
+                lane.dispatches += 1
+                lane.batched_queries += len(group)
+                lane.triggers[trigger] += 1
+                lane.depth_samples.append(depth)
+            from predictionio_tpu.utils import metrics
 
-        metrics.MICROBATCH_DISPATCHES.inc(batcher=lane.name)
-        metrics.MICROBATCH_QUERIES.inc(amount=len(group),
-                                       batcher=lane.name)
-        metrics.MICROBATCH_BATCH_SIZE.observe(len(group),
-                                              batcher=lane.name)
-        metrics.MICROBATCH_TRIGGERS.inc(batcher=lane.name,
-                                        trigger=trigger)
-        metrics.MICROBATCH_FILL.observe(len(group) / lane.max_batch,
-                                        batcher=lane.name)
-        metrics.MICROBATCH_QUEUE_AT_DISPATCH.observe(depth,
-                                                     batcher=lane.name)
+            metrics.MICROBATCH_DISPATCHES.inc(batcher=lane.name)
+            metrics.MICROBATCH_QUERIES.inc(amount=len(group),
+                                           batcher=lane.name)
+            metrics.MICROBATCH_BATCH_SIZE.observe(len(group),
+                                                  batcher=lane.name)
+            metrics.MICROBATCH_TRIGGERS.inc(batcher=lane.name,
+                                            trigger=trigger)
+            metrics.MICROBATCH_FILL.observe(len(group) / lane.max_batch,
+                                            batcher=lane.name)
+            metrics.MICROBATCH_QUEUE_AT_DISPATCH.observe(
+                depth, batcher=lane.name)
+
+
+def _deliver(group: List[_Pending], idx: np.ndarray,
+             scores: np.ndarray) -> None:
+    """Resolve every waiter's future with the shared result (rendering
+    happens on the waiting threads). The dispatch just recorded on THIS
+    thread (telemetry on) rides along, so a waiter's ``device.*`` span
+    names its bucket, fill and stage stamps."""
+    with _dtel.stage("deliverUs", "batch.deliver", done=True):
+        res = _BatchResult(idx, scores,
+                           telemetry=_dtel.last_record()
+                           if _dtel.enabled() else None)
+        for row, it in enumerate(group):
+            if not it.future.done():
+                it.future.set_result((res, row))
 
 
 def _dispatch_user_group(srv: "DeviceTopK",
@@ -1306,15 +1341,7 @@ def _dispatch_user_group(srv: "DeviceTopK",
     never pay a serve-time compile)."""
     kmax = max(it.k for it in group)
     uids = np.asarray([it.payload for it in group], dtype=np.int64)
-    idx, scores = srv.users_topk(uids, kmax)
-    # the dispatch just recorded on THIS thread (telemetry on): hand
-    # its record to every waiter through the shared result
-    res = _BatchResult(idx, scores,
-                       telemetry=_dtel.last_record()
-                       if _dtel.enabled() else None)
-    for row, it in enumerate(group):
-        if not it.future.done():
-            it.future.set_result((res, row))
+    _deliver(group, *srv.users_topk(uids, kmax))
 
 
 def _dispatch_item_group(srv: "DeviceTopK",
@@ -1323,25 +1350,20 @@ def _dispatch_item_group(srv: "DeviceTopK",
     one vmapped ``_items_topk`` dispatch: the group pads to its
     power-of-two row bucket, each row's item list to the group's common
     power-of-two length."""
-    kmax = max(it.k for it in group)
-    n = len(group)
-    B = srv.ITEM_QUERY_BUCKET
-    while B < max(len(it.payload) for it in group):
-        B *= 2
-    G = _bucket(n, lo=8)
-    idxs = np.zeros((G, B), dtype=np.int32)
-    masks = np.zeros((G, B), dtype=np.float32)
-    for row, it in enumerate(group):
-        m = len(it.payload)
-        idxs[row, :m] = np.asarray(it.payload, dtype=np.int32)
-        masks[row, :m] = 1.0
-    idx, scores = srv._items_topk_batched(idxs, masks, kmax)
-    res = _BatchResult(idx, scores,
-                       telemetry=_dtel.last_record()
-                       if _dtel.enabled() else None)
-    for row, it in enumerate(group):
-        if not it.future.done():
-            it.future.set_result((res, row))
+    with _dtel.stage("formUs", "batch.form"):
+        kmax = max(it.k for it in group)
+        n = len(group)
+        B = srv.ITEM_QUERY_BUCKET
+        while B < max(len(it.payload) for it in group):
+            B *= 2
+        G = _bucket(n, lo=8)
+        idxs = np.zeros((G, B), dtype=np.int32)
+        masks = np.zeros((G, B), dtype=np.float32)
+        for row, it in enumerate(group):
+            m = len(it.payload)
+            idxs[row, :m] = np.asarray(it.payload, dtype=np.int32)
+            masks[row, :m] = 1.0
+    _deliver(group, *srv._items_topk_batched(idxs, masks, kmax))
 
 
 _live_servers: "weakref.WeakSet[DeviceTopK]" = weakref.WeakSet()
@@ -1373,8 +1395,9 @@ def _live_store_bytes() -> float:
 
 
 def _live_ladder_bytes() -> float:
-    """Estimated bytes held by AOT ladder executables across live
-    stores (pull-gauge source for ``pio_aot_ladder_bytes``)."""
+    """Bytes the AOT ladders' programs need for themselves (largest
+    temporaries + code) across live stores (pull-gauge source for
+    ``pio_aot_ladder_bytes``)."""
     total = 0
     for srv in list(_live_servers):
         try:
@@ -1533,6 +1556,7 @@ class DeviceTopK:
                  shards: Optional[int] = None):
         import os
 
+        import jax
         import jax.numpy as jnp
 
         from predictionio_tpu.ops.quantize import (
@@ -1580,27 +1604,31 @@ class DeviceTopK:
         if is_quantized(user_factors) or is_quantized(item_factors):
             mode = "int8"
         self._mode = mode
-        self._X = to_device(user_factors)
-        self._Y = to_device(item_factors)
-        if mode == "bf16":
-            # opt-in bf16 factor store: halves the HBM the model holds
-            # AND the bytes every scoring matmul streams; the cast
-            # preserves an existing mesh sharding (elementwise program).
-            # Scores still accumulate + return fp32 (_score_einsum).
-            if not _is_bf16(self._X):
-                self._X = self._X.astype(jnp.bfloat16)
-            if not _is_bf16(self._Y):
-                self._Y = self._Y.astype(jnp.bfloat16)
-        elif mode == "int8":
-            # int8 store with per-row fp32 scales (symmetric absmax):
-            # ~4x less HBM than fp32, ~2x less than bf16, for the model
-            # AND the per-dispatch item stream; scores still accumulate
-            # + return fp32. Row-wise ops preserve an existing row
-            # sharding; the cast is one-time at load.
-            if not is_quantized(self._X):
-                self._X = quantize_rows_int8(self._X)
-            if not is_quantized(self._Y):
-                self._Y = quantize_rows_int8(self._Y)
+        with _trace_span("store.upload"):
+            self._X = to_device(user_factors)
+            self._Y = to_device(item_factors)
+            if mode == "bf16":
+                # opt-in bf16 factor store: halves the HBM the model
+                # holds AND the bytes every scoring matmul streams; the
+                # cast preserves an existing mesh sharding (elementwise
+                # program). Scores still accumulate + return fp32
+                # (_score_einsum).
+                if not _is_bf16(self._X):
+                    self._X = self._X.astype(jnp.bfloat16)
+                if not _is_bf16(self._Y):
+                    self._Y = self._Y.astype(jnp.bfloat16)
+            elif mode == "int8":
+                # int8 store with per-row fp32 scales (symmetric
+                # absmax): ~4x less HBM than fp32, ~2x less than bf16,
+                # for the model AND the per-dispatch item stream; scores
+                # still accumulate + return fp32. Row-wise ops preserve
+                # an existing row sharding; the cast is one-time at load.
+                if not is_quantized(self._X):
+                    self._X = quantize_rows_int8(self._X)
+                if not is_quantized(self._Y):
+                    self._Y = quantize_rows_int8(self._Y)
+            # the span is the transfer, not its enqueue
+            jax.block_until_ready((self._X, self._Y))
         # factor tables may be padded (sharded training pads rows);
         # n_users/n_items bound the valid index range
         self.n_users = int(n_users if n_users is not None
@@ -1627,8 +1655,6 @@ class DeviceTopK:
         # else — derived from the platform, never a switch; stamped
         # into every fused dispatch's flight record so "the kernel
         # ran" can be told from "the interpreter ran"
-        import jax
-
         self._interpret = jax.default_backend() != "tpu"
         if self._kernel == "fused" and self._shard is None:
             # mesh-committed factors WITHOUT a shard context (dim0
@@ -1653,12 +1679,15 @@ class DeviceTopK:
         self._mask_seen = bool(seen)
         if self._mask_seen:
             # one bit per (user, store position): see seen_bitmap
-            bits = seen_bitmap(self._translate_seen(seen),
-                               int(self._X.shape[0]),
-                               int(self._Y.shape[0]))
+            with _trace_span("store.bitmap"):
+                bits = seen_bitmap(self._translate_seen(seen),
+                                   int(self._X.shape[0]),
+                                   int(self._Y.shape[0]))
         else:
             bits = np.zeros((1, 1), dtype=np.int32)
-        self._seen_bits = self._replicate_like_factors(jnp.asarray(bits))
+        with _trace_span("store.upload"):
+            self._seen_bits = jax.block_until_ready(
+                self._replicate_like_factors(jnp.asarray(bits)))
         self._user_programs: Dict[int, object] = {}
         self._batch_programs: Dict[Tuple[int, int], object] = {}
         self._item_programs: Dict[object, object] = {}
@@ -1858,18 +1887,24 @@ class DeviceTopK:
             interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, sb, uids):
+            def users_topk_fused(X, Y, sb, uids):
                 scalar = jnp.ndim(uids) == 0
                 u = uids[None] if scalar else uids
-                Q = _gather_rows_f32(X, u, mode=mode)
-                vals, idx = fused_gather_score_topk(
-                    Q, Y, k=kb, n_items=n_items, mask_seen=mask_seen,
-                    seen_bits=jnp.take(sb, u, axis=0) if mask_seen
-                    else None, interpret=interpret)
-                packed = _pack(vals, idx)
+                with jax.named_scope("gather_q"):
+                    Q = _gather_rows_f32(X, u, mode=mode)
+                seen = None
+                if mask_seen:
+                    with jax.named_scope("seen_rows"):
+                        seen = jnp.take(sb, u, axis=0)
+                with jax.named_scope("topk"):
+                    vals, idx = fused_gather_score_topk(
+                        Q, Y, k=kb, n_items=n_items, mask_seen=mask_seen,
+                        seen_bits=seen, interpret=interpret)
+                with jax.named_scope("pack"):
+                    packed = _pack(vals, idx)
                 return packed[0] if scalar else packed
 
-            self._fused_programs[("u", kb)] = prog
+            prog = self._fused_programs[("u", kb)] = users_topk_fused
         return prog
 
     def _fused_items_program(self, kb: int):
@@ -1891,17 +1926,20 @@ class DeviceTopK:
             interpret = self._interpret
 
             @jax.jit
-            def prog(Yn, idxs, masks):
-                qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
-                Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
-                vals, idx = fused_gather_score_topk(
-                    Q, Yn,
-                    pack_seen_ids(idxs, masks > 0, int(Yn.shape[0])),
-                    k=kb, n_items=n_items, mask_seen=True,
-                    interpret=interpret)
-                return _pack(vals, idx)
+            def items_topk(Yn, idxs, masks):
+                with jax.named_scope("gather_q"):
+                    qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
+                    Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
+                with jax.named_scope("seen_rows"):
+                    own = pack_seen_ids(idxs, masks > 0, int(Yn.shape[0]))
+                with jax.named_scope("topk"):
+                    vals, idx = fused_gather_score_topk(
+                        Q, Yn, own, k=kb, n_items=n_items, mask_seen=True,
+                        interpret=interpret)
+                with jax.named_scope("pack"):
+                    return _pack(vals, idx)
 
-            self._fused_programs[("i", kb)] = prog
+            prog = self._fused_programs[("i", kb)] = items_topk
         return prog
 
     def _sharded_user_program(self, kb: int):
@@ -1921,18 +1959,23 @@ class DeviceTopK:
             interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, valid, sb, uids):
+            def users_topk_sharded(X, Y, valid, sb, uids):
                 scalar = jnp.ndim(uids) == 0
                 u = uids[None] if scalar else uids
-                Q = _gather_rows_f32(X, u, mode=mode)
-                vals, pos = _sharded_score_topk(
-                    Y, valid, Q, jnp.take(sb, u, axis=0), k=kb,
-                    mask_seen=mask_seen, mode=mode, mesh=mesh,
-                    axis=axis, fused=fused, interpret=interpret)
-                packed = _pack(vals, pos)
+                with jax.named_scope("gather_q"):
+                    Q = _gather_rows_f32(X, u, mode=mode)
+                with jax.named_scope("seen_rows"):
+                    seen = jnp.take(sb, u, axis=0)
+                with jax.named_scope("topk"):
+                    vals, pos = _sharded_score_topk(
+                        Y, valid, Q, seen, k=kb,
+                        mask_seen=mask_seen, mode=mode, mesh=mesh,
+                        axis=axis, fused=fused, interpret=interpret)
+                with jax.named_scope("pack"):
+                    packed = _pack(vals, pos)
                 return packed[0] if scalar else packed
 
-            self._shard_programs[("u", kb)] = prog
+            prog = self._shard_programs[("u", kb)] = users_topk_sharded
         return prog
 
     def _sharded_items_program(self, kb: int):
@@ -1950,21 +1993,26 @@ class DeviceTopK:
             interpret = self._interpret
 
             @jax.jit
-            def prog(Yn, valid, idxs, masks):
+            def items_topk(Yn, valid, idxs, masks):
                 from predictionio_tpu.ops.als_pallas import pack_seen_ids
 
-                qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
-                Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
+                with jax.named_scope("gather_q"):
+                    qf = _gather_rows_f32(Yn, idxs, mode=mode)  # [G, B, R]
+                    Q = (qf * masks[..., None]).sum(axis=1)      # [G, R]
                 # the query items mask themselves: their positions as a
                 # bitmap over the store (pad slots carry mask 0)
-                vals, pos = _sharded_score_topk(
-                    Yn, valid, Q,
-                    pack_seen_ids(idxs, masks > 0, int(valid.shape[0])),
-                    k=kb, mask_seen=True, mode=mode, mesh=mesh,
-                    axis=axis, fused=fused, interpret=interpret)
-                return _pack(vals, pos)
+                with jax.named_scope("seen_rows"):
+                    own = pack_seen_ids(idxs, masks > 0,
+                                        int(valid.shape[0]))
+                with jax.named_scope("topk"):
+                    vals, pos = _sharded_score_topk(
+                        Yn, valid, Q, own, k=kb, mask_seen=True,
+                        mode=mode, mesh=mesh, axis=axis, fused=fused,
+                        interpret=interpret)
+                with jax.named_scope("pack"):
+                    return _pack(vals, pos)
 
-            self._shard_programs[("i", kb)] = prog
+            prog = self._shard_programs[("i", kb)] = items_topk
         return prog
 
     def _user_program(self, k: int):
@@ -1976,12 +2024,21 @@ class DeviceTopK:
 
         prog = self._user_programs.get(k)
         if prog is None:
-            prog = jax.jit(partial(_user_topk, k=k,
-                                   mask_seen=self._mask_seen,
-                                   n_items=self.n_items,
-                                   mode=self._mode))
+            prog = jax.jit(self._xla_user_fn(k))
             self._user_programs[k] = prog
         return prog
+
+    def _xla_user_fn(self, k: int):
+        """The XLA-chain user program for one k bucket under the name
+        the device trace shows its module by (``jit_users_topk_xla``)."""
+        mask_seen, n_items, mode = (self._mask_seen, self.n_items,
+                                    self._mode)
+
+        def users_topk_xla(X, Y, sb, uid):
+            return _user_topk(X, Y, sb, uid, k=k, mask_seen=mask_seen,
+                              n_items=n_items, mode=mode)
+
+        return users_topk_xla
 
     def _batch_program(self, k: int, b: int):
         """vmap of the per-user program over a [b] uid vector: b queries,
@@ -1994,10 +2051,8 @@ class DeviceTopK:
 
         prog = self._batch_programs.get((k, b))
         if prog is None:
-            prog = jax.jit(jax.vmap(
-                partial(_user_topk, k=k, mask_seen=self._mask_seen,
-                        n_items=self.n_items, mode=self._mode),
-                in_axes=(None, None, None, 0)))
+            prog = jax.jit(jax.vmap(self._xla_user_fn(k),
+                                    in_axes=(None, None, None, 0)))
             self._batch_programs[(k, b)] = prog
         return prog
 
@@ -2012,10 +2067,13 @@ class DeviceTopK:
 
         prog = self._item_programs.get((kb, B, G))
         if prog is None:
-            prog = jax.jit(jax.vmap(
-                partial(_items_topk, k=kb, n_items=self.n_items,
-                        mode=self._mode),
-                in_axes=(None, 0, 0)))
+            n_items, mode = self.n_items, self._mode
+
+            def items_topk(Yn, idx, idx_mask):
+                return _items_topk(Yn, idx, idx_mask, k=kb,
+                                   n_items=n_items, mode=mode)
+
+            prog = jax.jit(jax.vmap(items_topk, in_axes=(None, 0, 0)))
             self._item_programs[(kb, B, G)] = prog
         return prog
 
@@ -2163,7 +2221,10 @@ class DeviceTopK:
         with ThreadPoolExecutor(max_workers=min(4, max(1, len(plan))),
                                 thread_name_prefix="pio-serve-aot") \
                 as pool:
-            for entry, prog in pool.map(build, plan):
+            # each worker call carries this thread's trace context, so
+            # its `ladder.lower` span lands in the deploy's trace
+            runs = [(_tracing.carrying_context(build), e) for e in plan]
+            for entry, prog in pool.map(lambda r: r[0](r[1]), runs):
                 if prog is None:
                     fallback += 1
                 else:
@@ -2197,7 +2258,18 @@ class DeviceTopK:
         per lane to pin the runtime dispatch caches. ``batch_sizes``
         extends the uid-bucket ladder for callers with known batch
         shapes (bench/batchpredict)."""
-        plan = self.aot_plan(max_k=max_k, batch_sizes=tuple(batch_sizes))
+        with _trace_span("ladder.plan"):
+            plan = self.aot_plan(max_k=max_k,
+                                 batch_sizes=tuple(batch_sizes))
+        # one parent span round the whole of compile-or-load: the
+        # workers' serialized `ladder.lower` spans are its children, so
+        # its self time is what is left of the wall clock (the compile
+        # pipeline, executable loads, the stragglers and the
+        # sacrificial queries below)
+        with _trace_span("ladder.compile"):
+            return self._compile_and_warm(plan)
+
+    def _compile_and_warm(self, plan: List[Tuple]) -> Dict[str, int]:
         stats = self.precompile(plan)
         with self._store_lock:
             self._ladder_plan = plan
@@ -2254,50 +2326,76 @@ class DeviceTopK:
         the dispatch enqueues, it does not wait on the device), then,
         with telemetry on, the dispatch→``block_until_ready`` window
         timed OUTSIDE the lock on the monotonic clock, recorded into
-        the flight ring and emitted as a ``device.execute`` child span.
-        Telemetry off (``PIO_DEVICE_TELEMETRY=0``) is the killed-lane
-        fast path: exactly the pre-telemetry dispatch, no clock reads.
-        Returns the raw packed device output."""
-        tel = _dtel.enabled()
-        with self._store_lock:
-            aot_prog = self._aot_get_locked(entry)
-            if aot_prog is not None:
-                self._aot_hits += 1
-                prog = aot_prog
-            else:
-                self._aot_misses += 1
-                prog = fallback()
+        the flight ring with its stage stamps (lock wait, enqueue,
+        device) and emitted as a ``device.execute`` child span; the
+        lock wait, the program call and the block are also live
+        profiler annotations (``dispatch.lock`` / ``.enqueue`` /
+        ``.wait``). Telemetry off (``PIO_DEVICE_TELEMETRY=0``) is the
+        killed-lane fast path: exactly the pre-telemetry dispatch, no
+        clock reads. Returns the raw packed device output."""
+        if not _dtel.enabled():
+            with self._store_lock:
+                prog, aot = self._ladder_program_locked(entry, fallback)
+                out = prog(*args_fn())
+            _metrics.AOT_CACHE_REQUESTS.inc(result=aot)
+            return out
+        tl = time.monotonic()
+        with _tracing.annotation("dispatch.lock"):
+            self._store_lock.acquire()
+        try:
+            t_locked = time.monotonic()
+            prog, aot = self._ladder_program_locked(entry, fallback)
             args = args_fn()
-            if not tel:
-                _metrics.AOT_CACHE_REQUESTS.inc(
-                    result="hit" if aot_prog is not None else "miss_jit")
-                return prog(*args)
-            t0m = time.monotonic()
             t0e = _tracing.span_now()
-            out = prog(*args)
-            t1m = time.monotonic()
-        _metrics.AOT_CACHE_REQUESTS.inc(
-            result="hit" if aot_prog is not None else "miss_jit")
+            with _tracing.annotation("dispatch.enqueue"):
+                t0m = time.monotonic()
+                out = prog(*args)
+                t1m = time.monotonic()
+        finally:
+            self._store_lock.release()
+        _metrics.AOT_CACHE_REQUESTS.inc(result=aot)
         # block OUTSIDE the lock (a fold-in patch must not wait on a
         # query's device time); the d2h fetch the caller then pays via
         # np.asarray finds the result already materialized
-        try:
-            out.block_until_ready()
-        except AttributeError:  # non-jax output (host fallback paths)
-            pass
-        t2m = time.monotonic()
+        with _tracing.annotation("dispatch.wait"):
+            try:
+                out.block_until_ready()
+            except AttributeError:  # non-jax output (host fallback paths)
+                pass
+            t2m = time.monotonic()
         rec = _dtel.record_dispatch(
             lane=entry[0], kernel=self._kernel, precision=self._mode,
             interpret=self._interpret if self._kernel == "fused" else None,
-            aot="hit" if aot_prog is not None else "miss_jit",
-            k_bucket=int(entry[1]), batch=batch, bucket=bucket,
-            host_us=(t2m - t0m) * 1e6, device_us=(t2m - t1m) * 1e6)
+            aot=aot, k_bucket=int(entry[1]), batch=batch, bucket=bucket,
+            host_us=(t2m - t0m) * 1e6, device_us=(t2m - t1m) * 1e6,
+            lock_wait_us=(t_locked - tl) * 1e6,
+            locked_us=(t0m - t_locked) * 1e6, called=t0m, ready=t2m)
         ctx = _dtel.current_dispatch_context() or {}
         _tracing.record_completed_span(
             "device.execute", start=t0e, end=t0e + (t2m - t0m),
             attributes=None if rec is None else dict(rec),
             parent=ctx.get("traceParent"))
         return out
+
+    def _ladder_program_locked(self, entry: Tuple, fallback):
+        """(program, ``hit`` | ``miss_jit``) for one ladder entry: the
+        AOT executable compiled for the live store, else the jit
+        fallback. Caller holds ``_store_lock``."""
+        prog = self._aot_get_locked(entry)
+        if prog is not None:
+            self._aot_hits += 1
+            return prog, "hit"
+        self._aot_misses += 1
+        return fallback(), "miss_jit"
+
+    def _fetch(self, out, kb: int, cut=np.s_[:]):
+        """Device output -> host (item ids, scores) cut to the rows and
+        columns asked for: the d2h copy, the unpack and the position ->
+        id map, as the ``dispatch.fetch`` stage of the record just
+        written."""
+        with _dtel.stage("fetchUs", "dispatch.fetch", done=True):
+            idx, scores = _unpack(np.asarray(out), kb)
+            return self._positions_to_items(idx[cut]), scores[cut]
 
     def user_topk(self, uid: int, k: int) -> Tuple[np.ndarray, np.ndarray]:
         """(item indices, scores) for one user, descending; seen items
@@ -2322,8 +2420,7 @@ class DeviceTopK:
             ("user", kb), lambda: self._user_program(kb),
             lambda: self._user_args(np.int32(uid)),
             batch=1, bucket=1)
-        idx, scores = _unpack(np.asarray(out), kb)
-        idx, scores = self._positions_to_items(idx[:k]), scores[:k]
+        idx, scores = self._fetch(out, kb, np.s_[:k])
         valid = np.isfinite(scores)
         return idx[valid], scores[valid]
 
@@ -2348,17 +2445,16 @@ class DeviceTopK:
         n = len(uids)
         with _trace_span("device.users_topk",
                          attributes={"batch": int(n), "k": int(k)}):
-            bb = _bucket(max(n, 1), lo=8)
-            padded = np.zeros(bb, dtype=np.int32)
-            padded[:n] = uids
-            kb = min(_bucket(k), self.n_items)
+            with _dtel.stage("formUs", "batch.form"):
+                bb = _bucket(max(n, 1), lo=8)
+                padded = np.zeros(bb, dtype=np.int32)
+                padded[:n] = uids
+                kb = min(_bucket(k), self.n_items)
             out = self._dispatch_entry(
                 ("users", kb, bb), lambda: self._batch_program(kb, bb),
                 lambda: self._user_args(padded),
                 batch=n, bucket=bb)
-            idx, scores = _unpack(np.asarray(out), kb)
-            return (self._positions_to_items(idx[:n, :k]),
-                    scores[:n, :k])
+            return self._fetch(out, kb, np.s_[:n, :k])
 
     def items_topk(self, idxs, k: int) -> Tuple[np.ndarray, np.ndarray]:
         """Item-similarity top-k for a list of query item indices. With
@@ -2414,8 +2510,7 @@ class DeviceTopK:
             ("items", kb, B, G), lambda: self._items_program(kb, B, G),
             lambda: self._items_args(idxs, masks),
             batch=int(ctx.get("group") or G), bucket=G)
-        idx, scores = _unpack(np.asarray(out), kb)
-        return self._positions_to_items(idx), scores
+        return self._fetch(out, kb)
 
     def _items_args(self, idxs, masks) -> Tuple:
         if self._shard is not None:
@@ -2513,9 +2608,10 @@ class DeviceTopK:
     def ladder_report(self) -> Dict[str, Any]:
         """AOT bucket-ladder coverage and footprint: the last warmup's
         planned/compiled/fallback/warmed counts, live hit/miss-to-jit
-        lookup totals, cache entry/eviction counts, and the aggregated
-        ``memory_analysis()`` byte estimate over every compiled
-        executable."""
+        lookup totals, cache entry/eviction counts, and what the
+        compiled programs themselves need on the device
+        (:meth:`AOTCache.memory_report`: temporaries and code, not the
+        store they take as arguments)."""
         with self._store_lock:
             hits, misses = self._aot_hits, self._aot_misses
             coverage = dict(self._ladder)

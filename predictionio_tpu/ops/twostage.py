@@ -49,8 +49,8 @@ from predictionio_tpu.ops.aot import lower_compile
 from predictionio_tpu.ops.serving import (
     BatchLane,
     DeviceTopK,
-    _BatchResult,
     _bucket,
+    _deliver,
     _gather_rows_f32,
     _pack,
     _Pending,
@@ -61,7 +61,6 @@ from predictionio_tpu.ops.serving import (
     _serve_shards_env,
     _sharded_score_topk,
     _table_sig,
-    _unpack,
     foldin_enabled,
     validate_serving_policy,
 )
@@ -92,13 +91,7 @@ def _dispatch_two_group(srv: "TwoStageTopK",
     group sizes never pay a serve-time compile)."""
     kmax = max(it.k for it in group)
     uids = np.asarray([it.payload for it in group], dtype=np.int64)
-    idx, scores = srv.twos_topk(uids, kmax)
-    res = _BatchResult(idx, scores,
-                       telemetry=_dtel.last_record()
-                       if _dtel.enabled() else None)
-    for row, it in enumerate(group):
-        if not it.future.done():
-            it.future.set_result((res, row))
+    _deliver(group, *srv.twos_topk(uids, kmax))
 
 
 def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
@@ -115,6 +108,7 @@ def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
     stores; identity otherwise) so ``lax.top_k``'s lowest-ordinal tie-break
     equals the brute-force lowest-item-id rule — bit-exact at
     N=catalog on every lane, including sharded."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
@@ -143,7 +137,8 @@ def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
                        -jnp.inf, s2)
     out_vals, sel = lax.top_k(s2, kb)
     out_pos = jnp.take_along_axis(pos, sel, axis=-1)
-    return _pack(out_vals, out_pos)
+    with jax.named_scope("pack"):
+        return _pack(out_vals, out_pos)
 
 
 class TwoStageTopK(DeviceTopK):
@@ -195,8 +190,12 @@ class TwoStageTopK(DeviceTopK):
         self._candidates = n_cand
         self._n_bucket = min(_bucket(max(n_cand, 16)), self.n_items)
         with self._store_lock:
-            self._E = self._prep_stage2_items(seq_item_vectors)
-            self._U = self._prep_stage2_users(seq_user_vectors)
+            with _trace_span("store.upload"):
+                import jax
+
+                self._E, self._U = jax.block_until_ready(
+                    (self._prep_stage2_items(seq_item_vectors),
+                     self._prep_stage2_users(seq_user_vectors)))
             # position -> item id (i32, invalid positions sort last):
             # the re-rank sorts candidates by id so tie-break matches
             # the brute-force rule even on a density-permuted store.
@@ -386,17 +385,22 @@ class TwoStageTopK(DeviceTopK):
             interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, valid, E, U, sb, uids):
-                Q = _gather_rows_f32(X, uids, mode=mode)
-                sbq = jnp.take(sb, uids, axis=0)
-                vals1, pos = _sharded_score_topk(
-                    Y, valid, Q, sbq, k=nb, mask_seen=False,
-                    mode=mode, mesh=mesh, axis=axis, fused=fused,
-                    interpret=interpret)
-                return _twostage_rerank(E, U, uids, vals1, pos, sbq,
-                                        kb=kb, mode=mode,
-                                        mask_seen=mask_seen,
-                                        pos_ids=pos_ids)
+            def two_topk(X, Y, valid, E, U, sb, uids):
+                with jax.named_scope("stage1"):
+                    with jax.named_scope("gather_q"):
+                        Q = _gather_rows_f32(X, uids, mode=mode)
+                    with jax.named_scope("seen_rows"):
+                        sbq = jnp.take(sb, uids, axis=0)
+                    with jax.named_scope("topk"):
+                        vals1, pos = _sharded_score_topk(
+                            Y, valid, Q, sbq, k=nb, mask_seen=False,
+                            mode=mode, mesh=mesh, axis=axis, fused=fused,
+                            interpret=interpret)
+                with jax.named_scope("rerank"):
+                    return _twostage_rerank(E, U, uids, vals1, pos, sbq,
+                                            kb=kb, mode=mode,
+                                            mask_seen=mask_seen,
+                                            pos_ids=pos_ids)
         elif self._kernel == "fused":
             from predictionio_tpu.ops.als_pallas import (
                 fused_gather_score_topk,
@@ -405,37 +409,48 @@ class TwoStageTopK(DeviceTopK):
             interpret = self._interpret
 
             @jax.jit
-            def prog(X, Y, E, U, sb, uids):
-                Q = _gather_rows_f32(X, uids, mode=mode)
-                vals1, pos = fused_gather_score_topk(
-                    Q, Y, k=nb, n_items=n_items, mask_seen=False,
-                    interpret=interpret)
-                return _twostage_rerank(E, U, uids, vals1, pos,
-                                        jnp.take(sb, uids, axis=0),
-                                        kb=kb, mode=mode,
-                                        mask_seen=mask_seen,
-                                        pos_ids=pos_ids)
+            def two_topk(X, Y, E, U, sb, uids):
+                with jax.named_scope("stage1"):
+                    with jax.named_scope("gather_q"):
+                        Q = _gather_rows_f32(X, uids, mode=mode)
+                    with jax.named_scope("topk"):
+                        vals1, pos = fused_gather_score_topk(
+                            Q, Y, k=nb, n_items=n_items, mask_seen=False,
+                            interpret=interpret)
+                with jax.named_scope("seen_rows"):
+                    sbq = jnp.take(sb, uids, axis=0)
+                with jax.named_scope("rerank"):
+                    return _twostage_rerank(E, U, uids, vals1, pos, sbq,
+                                            kb=kb, mode=mode,
+                                            mask_seen=mask_seen,
+                                            pos_ids=pos_ids)
         else:
             n_rows = int(self._Y.shape[0])
 
             @jax.jit
-            def prog(X, Y, E, U, sb, uids):
+            def two_topk(X, Y, E, U, sb, uids):
                 from jax import lax
 
-                Q = _gather_rows_f32(X, uids, mode=mode)
-                scores = _score_einsum("mr,br->bm", Y, Q, mode=mode)
-                if n_rows > n_items:
-                    pad_ok = jnp.arange(n_rows)[None, :] < n_items
-                    scores = jnp.where(pad_ok, scores, -jnp.inf)
-                vals1, pos = lax.top_k(scores, nb)
-                return _twostage_rerank(E, U, uids, vals1, pos,
-                                        jnp.take(sb, uids, axis=0),
-                                        kb=kb, mode=mode,
-                                        mask_seen=mask_seen,
-                                        pos_ids=pos_ids)
+                with jax.named_scope("stage1"):
+                    with jax.named_scope("gather_q"):
+                        Q = _gather_rows_f32(X, uids, mode=mode)
+                    with jax.named_scope("topk"):
+                        scores = _score_einsum("mr,br->bm", Y, Q,
+                                               mode=mode)
+                        if n_rows > n_items:
+                            pad_ok = jnp.arange(n_rows)[None, :] < n_items
+                            scores = jnp.where(pad_ok, scores, -jnp.inf)
+                        vals1, pos = lax.top_k(scores, nb)
+                with jax.named_scope("seen_rows"):
+                    sbq = jnp.take(sb, uids, axis=0)
+                with jax.named_scope("rerank"):
+                    return _twostage_rerank(E, U, uids, vals1, pos, sbq,
+                                            kb=kb, mode=mode,
+                                            mask_seen=mask_seen,
+                                            pos_ids=pos_ids)
 
-        self._two_programs[(kb, nb)] = prog
-        return prog
+        self._two_programs[(kb, nb)] = two_topk
+        return two_topk
 
     def _two_args(self, uids) -> Tuple:
         """The two-stage program's argument tuple for the live store
@@ -535,19 +550,18 @@ class TwoStageTopK(DeviceTopK):
         n = len(uids)
         with _trace_span("device.twos_topk",
                          attributes={"batch": int(n), "k": int(k)}):
-            bb = _bucket(max(n, 1), lo=8)
-            padded = np.zeros(bb, dtype=np.int32)
-            padded[:n] = uids
-            kb = min(_bucket(k), self.n_items)
-            nb = self._nb_for(kb)
+            with _dtel.stage("formUs", "batch.form"):
+                bb = _bucket(max(n, 1), lo=8)
+                padded = np.zeros(bb, dtype=np.int32)
+                padded[:n] = uids
+                kb = min(_bucket(k), self.n_items)
+                nb = self._nb_for(kb)
             out = self._dispatch_entry(
                 ("two", kb, nb, bb),
                 lambda: self._two_program(kb, nb),
                 lambda: self._two_args(padded),
                 batch=n, bucket=bb)
-            idx, scores = _unpack(np.asarray(out), kb)
-            return (self._positions_to_items(idx[:n, :k]),
-                    scores[:n, :k])
+            return self._fetch(out, kb, np.s_[:n, :k])
 
     def stats(self) -> Dict[str, Dict[str, int]]:
         out = super().stats()

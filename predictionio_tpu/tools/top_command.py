@@ -139,6 +139,17 @@ def render(stats: Dict[str, Any], dispatches: Dict[str, Any],
             f"fill {s.get('meanFill') if s.get('meanFill') is not None else '—'} "
             f"· aot {s.get('aot') or {}}")
 
+    # -- where a query's time goes inside the server ------------------------
+    stages = stats.get("stages") or {}
+    if stages.get("roots"):
+        top = sorted((stages.get("selfUsP50") or {}).items(),
+                     key=lambda kv: -(kv[1] or 0))[:8]
+        lines.append(
+            f"stages   query p50 "
+            f"{_fmt_us(stages.get('durationUsP50'))} over "
+            f"{stages['roots']} roots · self p50: "
+            + " · ".join(f"{n} {_fmt_us(v)}" for n, v in top))
+
     # -- health: breakers / degraded / fold-in -----------------------------
     open_breakers = [
         s["labels"].get("endpoint", "?")

@@ -14,6 +14,20 @@ device-time accounting, applied to the serving plane:
   ``miss_jit`` / ``jit`` for unladdered programs), queue wait, host
   wall µs and **device µs** — the dispatch-to-``block_until_ready``
   window on the monotonic clock;
+- a batching dispatcher's thread works strictly one dispatch after
+  another, so its records also carry **stage stamps** that tile its
+  time (:func:`stage`, :func:`record_dispatch`): ``gapUs`` from the
+  previous dispatch's ``block_until_ready`` return to this dispatch's
+  program call, of which ``gapIdleUs`` asleep with every lane empty,
+  ``gapWindowUs`` asleep on a batching window, ``formUs`` forming the
+  batch and ``lockWaitUs`` acquiring the store lock; then
+  ``enqueueUs`` (the program call), ``deviceUs``, and after the record
+  is written ``fetchUs`` and ``deliverUs``. Over one ``dispatcher``
+  thread's consecutive records ``gapUs + enqueueUs + deviceUs`` adds
+  up to the wall clock. Each stage is also a profiler annotation
+  (``batch.idle``, ``batch.window``, ``batch.form``, ``dispatch.lock``,
+  ``dispatch.enqueue``, ``dispatch.wait``, ``dispatch.fetch``,
+  ``batch.deliver``) on the dispatcher thread's line of a capture;
 - the ring is bounded (``PIO_DEVICE_TELEMETRY_RING``, default 2048):
   a long-lived server holds the last N dispatches, never all of them
   (evictions are counted, not silently dropped);
@@ -36,6 +50,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from predictionio_tpu.utils import tracing as _tracing
+
 __all__ = [
     "FlightRecorder",
     "RECORDER",
@@ -46,6 +62,8 @@ __all__ = [
     "last_record",
     "dispatch_scope",
     "current_dispatch_context",
+    "stage",
+    "mark_ready",
 ]
 
 
@@ -209,14 +227,17 @@ _dispatch_ctx = threading.local()
 @contextlib.contextmanager
 def dispatch_scope(queue_wait_us: Optional[float] = None,
                    group: Optional[int] = None,
-                   trace_parent: Any = None):
+                   trace_parent: Any = None,
+                   queue_wait_mean_us: Optional[float] = None):
     """Bind batching context for the device dispatch(es) the block
-    issues: queue wait of the oldest grouped query, the group size, and
-    a trace parent for the ``device.execute`` span (the dispatcher
-    thread has no ambient trace context of its own)."""
+    issues: queue wait of the oldest grouped query and the mean over
+    the group, the group size, and a trace parent for the
+    ``device.execute`` span (the dispatcher thread has no ambient
+    trace context of its own)."""
     prior = getattr(_dispatch_ctx, "ctx", None)
     _dispatch_ctx.ctx = {"queueWaitUs": queue_wait_us, "group": group,
-                         "traceParent": trace_parent}
+                         "traceParent": trace_parent,
+                         "queueWaitMeanUs": queue_wait_mean_us}
     try:
         yield
     finally:
@@ -227,20 +248,102 @@ def current_dispatch_context() -> Optional[Dict[str, Any]]:
     return getattr(_dispatch_ctx, "ctx", None)
 
 
+# -- stage stamps --------------------------------------------------------------
+
+# Per thread: the stamps taken since the last record (``pending``; they
+# belong to the record being formed), when the last dispatch's
+# ``block_until_ready`` returned (``ready``, monotonic), and the
+# thread's ``name/native id`` as records carry it (``thread``).
+_stage = threading.local()
+
+
+@contextlib.contextmanager
+def stage(field: str, name: str, done: bool = False):
+    """Time one stage of the calling thread's dispatch work: a profiler
+    annotation ``name`` round the block, and its µs added to ``field``
+    of a flight record — the one this thread is forming (merged in by
+    the next :func:`record_dispatch`), or with ``done`` the one it wrote
+    last (fetch and deliver happen after the record exists; the dict is
+    the one :func:`last_record` and the ring hold). Killed
+    (``PIO_DEVICE_TELEMETRY=0``): no clock, no annotation."""
+    if not RECORDER.enabled:
+        yield
+        return
+    t = time.monotonic()
+    try:
+        with _tracing.annotation(name):
+            yield
+    finally:
+        us = (time.monotonic() - t) * 1e6
+        if done:
+            into = RECORDER.last()
+        else:
+            into = getattr(_stage, "pending", None)
+            if into is None:
+                into = _stage.pending = {}
+        if into is not None:
+            into[field] = round(into.get(field, 0.0) + us, 1)
+
+
+def mark_ready() -> None:
+    """A dispatcher thread starts its clock: the first record's
+    ``gapUs`` runs from here, so the thread's records tile its whole
+    life and not only the time after its first dispatch."""
+    if RECORDER.enabled:
+        _stage.ready = time.monotonic()
+
+
 def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
                     k_bucket: int, batch: int, bucket: int,
                     host_us: float, device_us: float,
                     started_epoch: Optional[float] = None,
-                    interpret: Optional[bool] = None
+                    interpret: Optional[bool] = None,
+                    lock_wait_us: Optional[float] = None,
+                    locked_us: float = 0.0,
+                    called: Optional[float] = None,
+                    ready: Optional[float] = None
                     ) -> Optional[Dict[str, Any]]:
     """Record one device dispatch (caller already paid the timing; this
     is pure bookkeeping). Returns the record dict, or None when the
     recorder is disabled. Also feeds ``pio_dispatch_device_seconds``
     and ``pio_aot_cache_requests_total`` — both behind the PR-2 metrics
-    switch independently of this recorder's own kill switch."""
+    switch independently of this recorder's own kill switch.
+
+    ``lock_wait_us`` (acquiring the store lock), ``locked_us`` (under
+    it before the program call: executable lookup and ``args_fn``,
+    counted as forming) and the monotonic ``called`` / ``ready`` (the
+    program call's start, ``block_until_ready``'s return) are the
+    stage stamps of a laddered dispatch; with a batching context bound
+    they give ``gapUs`` and move this thread's clock on."""
     if not RECORDER.enabled:
         return None
-    ctx = current_dispatch_context() or {}
+    ctx = current_dispatch_context()
+    pending = getattr(_stage, "pending", None) or {}
+    _stage.pending = None
+    thread = getattr(_stage, "thread", None)
+    if thread is None:
+        thread = _stage.thread = (f"{threading.current_thread().name}"
+                                  f"/{threading.get_native_id()}")
+    stamps: Dict[str, Any] = {
+        "enqueueUs": round(float(host_us - device_us), 1),
+        "dispatcher": thread}
+    if lock_wait_us is not None:
+        stamps["lockWaitUs"] = round(float(lock_wait_us), 1)
+    if "formUs" in pending or locked_us:
+        stamps["formUs"] = round(pending.get("formUs", 0.0)
+                                 + float(locked_us), 1)
+    if ctx is not None and called is not None:
+        # a batching dispatcher's thread: one dispatch after another
+        last = getattr(_stage, "ready", None)
+        stamps["gapUs"] = None if last is None \
+            else round((called - last) * 1e6, 1)
+        stamps["gapIdleUs"] = pending.get("gapIdleUs", 0.0)
+        stamps["gapWindowUs"] = pending.get("gapWindowUs", 0.0)
+        mean = ctx.get("queueWaitMeanUs")
+        stamps["queueWaitMeanUs"] = None if mean is None \
+            else round(float(mean), 1)
+        _stage.ready = ready
+    ctx = ctx or {}
     rec: Dict[str, Any] = {
         "ts": started_epoch if started_epoch is not None else time.time(),
         "lane": lane,
@@ -255,6 +358,7 @@ def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
         else round(float(ctx["queueWaitUs"]), 1),
         "hostUs": round(float(host_us), 1),
         "deviceUs": round(float(device_us), 1),
+        **stamps,
     }
     if interpret is not None:
         # Pallas lanes only: False = the Mosaic-compiled kernel ran,

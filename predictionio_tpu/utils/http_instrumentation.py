@@ -19,7 +19,8 @@ by construction:
   buffer (the slow-query log).
 - ``_respond`` / ``_respond_bytes`` echo the request id AND the
   ``traceparent`` of the server span, and record the status the
-  accounting reads.
+  accounting reads; each is an ``http.write`` span (serialization +
+  send), the last stage of a request's span tree.
 - ``_respond_prometheus`` serves the registry's text exposition;
   ``_respond_traces_index`` / ``_respond_trace`` serve the trace
   buffer (``GET /traces.json``, ``GET /traces/<id>`` — plain span
@@ -107,13 +108,19 @@ class InstrumentedHandlerMixin:
 
     # -- responses ---------------------------------------------------------
     def _respond(self, status: int, payload: Any) -> None:
-        self._respond_bytes(status, json.dumps(payload).encode("utf-8"),
-                            "application/json; charset=UTF-8")
+        with tracing.span("http.write"):
+            self._send(status, json.dumps(payload).encode("utf-8"),
+                       "application/json; charset=UTF-8")
 
     def _respond_bytes(self, status: int, body: bytes,
                        content_type: str,
                        extra_headers: Optional[Mapping[str, str]] = None
                        ) -> None:
+        with tracing.span("http.write"):
+            self._send(status, body, content_type, extra_headers)
+
+    def _send(self, status: int, body: bytes, content_type: str,
+              extra_headers: Optional[Mapping[str, str]] = None) -> None:
         self._status_sent = status
         self.send_response(status)
         self.send_header("Content-Type", content_type)

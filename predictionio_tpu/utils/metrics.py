@@ -885,9 +885,10 @@ DEVICE_STORE_BYTES = REGISTRY.gauge(
     "seen tables + normalized item matrix, across all live servers)", ())
 AOT_LADDER_BYTES = REGISTRY.gauge(
     "pio_aot_ladder_bytes",
-    "Estimated bytes held by AOT-compiled serving ladder executables "
-    "(memory_analysis over every compiled entry; 0 where the backend "
-    "has no stats)", ())
+    "Device bytes the AOT-compiled serving ladder's programs need for "
+    "themselves: the largest program's temporaries plus all generated "
+    "code (memory_analysis; their arguments are the store's bytes; 0 "
+    "where the backend has no stats)", ())
 PROFILE_CAPTURES_ACTIVE = REGISTRY.gauge(
     "pio_profile_capture_active",
     "1 while an on-demand jax.profiler capture (POST /profile/start) "

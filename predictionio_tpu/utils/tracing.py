@@ -34,6 +34,17 @@ observability:
   ``slow-queries.log`` under the directory (``--trace-dir`` /
   ``$PIO_TRACE_DIR``). :func:`render_trace_html` is the dashboard's
   timeline view.
+- **two sinks, one call site**: every :func:`span`, :func:`trace_scope`
+  and :func:`detached_span` also opens a ``jax.profiler.TraceAnnotation``
+  (:func:`annotation`), so while a profiler capture runs the same spans
+  sit on their thread's line of the xplane host plane, on the
+  profiler's clock, beside the device ops. A no-op until ``jax`` has
+  been imported by someone else (the event server never imports it).
+- **stage summaries**: when a local root flushes, its spans reduce to
+  ``{name: self µs}`` and join a ring of the last
+  :data:`STAGE_SUMMARY_RING` roots, whatever head sampling retained
+  (:meth:`TraceBuffer.stage_summaries`): the form of a span tree that
+  survives a whole measurement window.
 - kill switch: ``PIO_TRACING=0|off`` (or ``--tracing off``) disables
   span collection entirely — :func:`span` falls back to the log-line
   timer, so serving overhead stays negligible (the tracing analog of
@@ -54,6 +65,8 @@ import os
 import random
 import re
 import secrets
+import statistics
+import sys
 import threading
 import time
 from bisect import bisect_left
@@ -470,11 +483,49 @@ class Span:
         }
 
 
+def _median(values: List[float]) -> Optional[float]:
+    return round(statistics.median(values), 1) if values else None
+
+
 def _iso(epoch: float) -> str:
     import datetime as _dt
 
     return _dt.datetime.fromtimestamp(
         epoch, tz=_dt.timezone.utc).isoformat()
+
+
+# roots whose stage summary is kept: 51 s x 800 qps is 40,800, and a
+# summary is two short tuples (about 0.5 KB with its floats)
+STAGE_SUMMARY_RING = 65536
+
+
+def stage_self_times(spans: Sequence[Span]) -> Dict[str, float]:
+    """``{span name: self µs}`` over one root's spans: a span's duration
+    less the union of its children's intervals (clipped to the span, so
+    a cross-thread child that outlives its parent takes no more than the
+    parent had). Spans of one name add up. One pass: children sorted by
+    start and swept with a running high-water mark, so overlapping
+    children are not subtracted twice."""
+    kids: Dict[Optional[str], List[Span]] = {}
+    for s in spans:
+        kids.setdefault(s.parent_id, []).append(s)
+    out: Dict[str, float] = {}
+    for s in spans:
+        end = s.end if s.end is not None else s.start
+        covered = 0.0
+        children = kids.get(s.span_id)
+        if children:
+            if len(children) > 1:
+                children.sort(key=lambda c: c.start)
+            hi = s.start
+            for c in children:
+                a = c.start if c.start > hi else hi
+                b = c.end if c.end is not None and c.end < end else end
+                if b > a:
+                    covered += b - a
+                    hi = b
+        out[s.name] = out.get(s.name, 0.0) + (end - s.start - covered) * 1e6
+    return out
 
 
 class TraceBuffer:
@@ -535,6 +586,12 @@ class TraceBuffer:
         self._done: "collections.OrderedDict[str, Dict[str, Any]]" = \
             collections.OrderedDict()
         self._slow: "collections.deque" = collections.deque(maxlen=max_slow)
+        # (root name, start epoch, duration µs, trace id, span names,
+        # self µs) per flushed local root; the names tuple is shared by
+        # every root of the same shape
+        self._stages: "collections.deque" = collections.deque(
+            maxlen=STAGE_SUMMARY_RING)
+        self._stage_names: Dict[Tuple[str, ...], Tuple[str, ...]] = {}
         self._export_dir: Optional[str] = None
         self._export_lock = threading.Lock()
 
@@ -658,6 +715,7 @@ class TraceBuffer:
                     # was slow: the slow log links straight to it
                     slow_entry["profileCapture"] = capture
                 self._slow.append(slow_entry)
+        self._summarise(root, new_spans, duration)
         if slow_entry is not None:
             slow_logger.warning(
                 "%s trace %s: %s took %.3fs (%d spans)",
@@ -666,6 +724,57 @@ class TraceBuffer:
         if record is not None and self._export_dir:
             self._export(self._render(record, spans=new_spans),
                          slow_entry)
+
+    def _summarise(self, root: Span, spans: List[Span],
+                   duration: float) -> None:
+        """The root's stage summary into the ring (computed outside
+        the lock; only the append holds it)."""
+        self_us = stage_self_times(spans)
+        names = tuple(self_us)
+        entry = (root.name, root.start, duration * 1e6, root.trace_id,
+                 self._stage_names.setdefault(names, names),
+                 tuple(self_us.values()))
+        with self._lock:
+            self._stages.append(entry)
+
+    def stage_summaries(self, t0: float = 0.0, t1: float = float("inf"),
+                        root: Optional[str] = None
+                        ) -> List[Dict[str, Any]]:
+        """Stage summaries of the local roots that STARTED in
+        ``[t0, t1)`` on the span clock (epoch seconds), oldest first;
+        ``root`` keeps one root name. Each: ``root``, ``start``,
+        ``durationUs``, ``traceId`` and ``selfUs`` (``{span name: self
+        µs}``, the root's own name included, adding up to
+        ``durationUs`` when children neither overlap nor outlive their
+        parents)."""
+        with self._lock:
+            entries = list(self._stages)
+        return [{"root": name, "start": start, "durationUs": dur,
+                 "traceId": tid, "selfUs": dict(zip(names, values))}
+                for name, start, dur, tid, names, values in entries
+                if t0 <= start < t1 and (root is None or name == root)]
+
+    def stage_p50(self, prefix: str, last: int = 4096
+                  ) -> Dict[str, Any]:
+        """The ``/stats.json`` ``stages`` block: median self µs per
+        span name over the newest ``last`` roots whose name starts with
+        ``prefix`` (a scrape must not sort the whole ring)."""
+        with self._lock:
+            ring = list(self._stages)
+        entries = []
+        for e in reversed(ring):          # newest first, off the lock
+            if e[0].startswith(prefix):
+                entries.append(e)
+                if len(entries) == last:
+                    break
+        per: Dict[str, List[float]] = {}
+        for _, _, _, _, names, values in entries:
+            for n, v in zip(names, values):
+                per.setdefault(n, []).append(v)
+        return {"roots": len(entries),
+                "durationUsP50": _median([e[2] for e in entries]),
+                "selfUsP50": {n: _median(v)
+                              for n, v in sorted(per.items())}}
 
     @staticmethod
     def _render(record: Dict[str, Any],
@@ -716,6 +825,7 @@ class TraceBuffer:
             self._dropped.clear()
             self._done.clear()
             self._slow.clear()
+            self._stages.clear()
 
     # -- file export -------------------------------------------------------
     def set_export_dir(self, path: Optional[str]) -> None:
@@ -838,6 +948,46 @@ def load_slow_log_from_dir(path: str, limit: int = 50
     return entries[::-1]
 
 
+# -- the profiler sink ------------------------------------------------------
+
+_NO_ANNOTATION = contextlib.nullcontext()
+_annotation_cls: Any = None  # jax.profiler.TraceAnnotation, once jax is in
+
+
+def annotation(name: str, **ids: str):
+    """A ``jax.profiler.TraceAnnotation`` for a ``with`` block: while a
+    profiler capture runs, the block is an event named ``name`` (``ids``
+    become its stats, e.g. ``trace_id``) on the calling thread's line of
+    the xplane host plane, on the profiler's clock. With no capture
+    running it costs one atomic read (``TraceMe.is_enabled``). Never
+    imports jax: until some other module has, and with tracing killed,
+    this is a shared null context."""
+    cls = _annotation_cls
+    if cls is None:
+        cls = _resolve_annotation_cls()
+        if cls is None:
+            return _NO_ANNOTATION
+    # no capture running (one atomic read): not even the object
+    if not cls.is_enabled() or not TRACES.enabled:
+        return _NO_ANNOTATION
+    return cls(name, **ids)
+
+
+def _resolve_annotation_cls():
+    """Resolved once: ``jax`` fully imported exposes ``jax.profiler``; a
+    jax still mid-import (another thread) does not yet, so ask again."""
+    global _annotation_cls
+    _annotation_cls = getattr(
+        getattr(sys.modules.get("jax"), "profiler", None),
+        "TraceAnnotation", None)
+    return _annotation_cls
+
+
+def _span_annotation(sp: Optional[Span], name: str):
+    return annotation(name) if sp is None \
+        else annotation(name, trace_id=sp.trace_id)
+
+
 # -- span machinery ---------------------------------------------------------
 
 def begin_span(name: str, attributes: Optional[Dict[str, Any]] = None,
@@ -914,7 +1064,8 @@ def trace_scope(name: str, parent: Optional[SpanContext] = None,
     token = _trace_ctx.set(SpanContext(trace_id, root.span_id, sampled))
     error: Optional[BaseException] = None
     try:
-        yield root
+        with _span_annotation(root, name):
+            yield root
     except BaseException as e:
         error = e
         raise
@@ -927,34 +1078,53 @@ def trace_scope(name: str, parent: Optional[SpanContext] = None,
         buf.flush(root, sampled)  # flush records the root itself
 
 
-@contextlib.contextmanager
-def span(name: str, level: int = logging.DEBUG,
-         histogram: Optional[LatencyHistogram] = None,
-         attributes: Optional[Dict[str, Any]] = None):
-    """Time a block. Inside an active trace this records a real child
-    span (trace/span/parent ids, attributes, error flag) into the trace
-    buffer; otherwise — or with tracing killed — it is exactly the old
-    request-id-tagged log line. ``histogram`` additionally records the
-    duration (how the DASE-stage spans feed ``pio_train_stage_seconds``).
-    Yields the :class:`Span` (or None)."""
-    t0 = time.perf_counter()
-    sp, token = begin_span(name, attributes)
-    error: Optional[BaseException] = None
-    try:
-        yield sp
-    except BaseException as e:
-        error = e
-        raise
-    finally:
-        took = time.perf_counter() - t0
-        finish_span(sp, token, error=error)
-        if histogram is not None:
-            histogram.record(took)
-        rid = current_request_id()
-        if rid:
-            logger.log(level, "%s took %.3fs [rid=%s]", name, took, rid)
-        else:
-            logger.log(level, "%s took %.3fs", name, took)
+class span:
+    """Time a block (``with span(name): ...``). Inside an active trace
+    this records a real child span (trace/span/parent ids, attributes,
+    error flag) into the trace buffer; otherwise — or with tracing
+    killed — it is exactly the old request-id-tagged log line.
+    ``histogram`` additionally records the duration (how the DASE-stage
+    spans feed ``pio_train_stage_seconds``). The block is also a
+    profiler annotation of the same name (:func:`annotation`). Yields
+    the :class:`Span` (or None).
+
+    A class and not a generator: a request opens a dozen of these on a
+    thread that holds the interpreter lock while it does, and on the
+    chip every microsecond of that showed thirty-fold in the median
+    latency (PERF.md, PR 23)."""
+
+    __slots__ = ("name", "level", "histogram", "attributes", "_t0", "_sp",
+                 "_token", "_annotation")
+
+    def __init__(self, name: str, level: int = logging.DEBUG,
+                 histogram: Optional[LatencyHistogram] = None,
+                 attributes: Optional[Dict[str, Any]] = None):
+        self.name = name
+        self.level = level
+        self.histogram = histogram
+        self.attributes = attributes
+
+    def __enter__(self) -> Optional[Span]:
+        self._t0 = time.perf_counter()
+        self._sp, self._token = begin_span(self.name, self.attributes)
+        self._annotation = _span_annotation(self._sp, self.name)
+        self._annotation.__enter__()
+        return self._sp
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
+        took = time.perf_counter() - self._t0
+        finish_span(self._sp, self._token, error=exc)
+        if self.histogram is not None:
+            self.histogram.record(took)
+        if logger.isEnabledFor(self.level):
+            rid = current_request_id()
+            if rid:
+                logger.log(self.level, "%s took %.3fs [rid=%s]", self.name,
+                           took, rid)
+            else:
+                logger.log(self.level, "%s took %.3fs", self.name, took)
+        return False
 
 
 def span_now() -> float:
@@ -972,7 +1142,10 @@ def record_completed_span(name: str, start: float, end: float,
     """Record an ALREADY-FINISHED span — for work whose window was
     timed with raw clock reads rather than a context manager (e.g. the
     dispatch→``block_until_ready`` device window, which must cost two
-    monotonic reads, not a contextvar rebind). Parents under ``parent``
+    monotonic reads, not a contextvar rebind). It cannot reach the
+    profiler after the fact: its call sites put live :func:`annotation`
+    blocks round the same window (``dispatch.enqueue``,
+    ``dispatch.wait``). Parents under ``parent``
     when given, else the ambient context; no-ops (returns None) when
     tracing is off or no trace is active. ``start``/``end`` must come
     from :func:`span_now`."""
@@ -1004,7 +1177,8 @@ def detached_span(name: str, parent: Optional[SpanContext] = None,
               attributes)
     error: Optional[BaseException] = None
     try:
-        yield sp
+        with _span_annotation(sp, name):
+            yield sp
     except BaseException as e:
         error = e
         raise

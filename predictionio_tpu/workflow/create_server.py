@@ -58,6 +58,8 @@ from predictionio_tpu.utils.tracing import (
     LatencyHistogram,
     outbound_context_headers,
     span,
+    trace_buffer,
+    trace_scope,
 )
 from predictionio_tpu.workflow import core_workflow
 from predictionio_tpu.workflow.server_plugins import EngineServerPluginContext
@@ -303,14 +305,15 @@ def build_deployment(instance: EngineInstance, ctx: ComputeContext,
             engine = engine.engine
     engine_params = engine_instance_to_engine_params(engine, instance)
 
-    blob = storage.get_model_data_models().get(instance.id)
-    if blob is None:
-        raise StorageError(
-            f"no persisted models for engine instance {instance.id}")
-    persisted = core_workflow.deserialize_models(blob.models)
-    models = engine.prepare_deploy(
-        ctx, engine_params, instance.id, persisted,
-        params=WorkflowParams(batch=batch))
+    with span("deploy.load_models"):
+        blob = storage.get_model_data_models().get(instance.id)
+        if blob is None:
+            raise StorageError(
+                f"no persisted models for engine instance {instance.id}")
+        persisted = core_workflow.deserialize_models(blob.models)
+        models = engine.prepare_deploy(
+            ctx, engine_params, instance.id, persisted,
+            params=WorkflowParams(batch=batch))
 
     algorithms = engine._algorithms(engine_params)
     # every ensemble member must agree on the query type: queries are
@@ -411,12 +414,13 @@ def warm_up(dep: Deployment,
             if callable(device_server):
                 device_server().warmup()
     if warmup_query is not None:
-        try:
-            query = query_from_json(dict(warmup_query),
-                                    dep.algorithms[0].query_class)
-            serve_query(dep, query)
-        except Exception:
-            logger.exception("warmup query failed (non-fatal)")
+        with span("deploy.warmup_query"):
+            try:
+                query = query_from_json(dict(warmup_query),
+                                        dep.algorithms[0].query_class)
+                serve_query(dep, query)
+            except Exception:
+                logger.exception("warmup query failed (non-fatal)")
 
 
 def serve_query(dep: Deployment, query: Any) -> Any:
@@ -558,8 +562,12 @@ class QueryServer:
                 self._foldin_env_set = True
             os.environ["PIO_FOLDIN"] = "1"
         try:
-            instance = self._resolve_instance()
-            self._deployment = self._build_deployment(instance)
+            # one local root per deploy: load_models, store.*, ladder.*
+            # and the warm-up query are its spans, so set-up decomposes
+            # without a stopwatch round this call
+            with trace_scope("pio.deploy", slow_exempt=True):
+                instance = self._resolve_instance()
+                self._deployment = self._build_deployment(instance)
             if self.config.foldin:
                 self._start_foldin()
         except BaseException:
@@ -631,7 +639,8 @@ class QueryServer:
         t0 = time.perf_counter()
         query_time = _dt.datetime.now(tz=UTC)
         try:
-            query_dict = json.loads(body.decode("utf-8"))
+            with span("query.parse"):
+                query_dict = json.loads(body.decode("utf-8"))
             if not isinstance(query_dict, dict):
                 raise ValueError("query must be a JSON object")
         except (json.JSONDecodeError, UnicodeDecodeError, ValueError) as e:
@@ -666,6 +675,21 @@ class QueryServer:
             logger.exception("query failed")
             return 500, {"message": str(e)}
 
+        with span("query.render"):
+            result = self._render(dep, query_dict, query, prediction,
+                                  degraded, query_time)
+
+        took = time.perf_counter() - t0
+        self.latency.record(took)
+        metrics.QUERY_LATENCY.observe(took,
+                                      variant=self.config.engine_variant)
+        return 200, result
+
+    def _render(self, dep: _Deployment, query_dict: Mapping[str, Any],
+                query: Any, prediction: Any, degraded: List[str],
+                query_time: _dt.datetime) -> Any:
+        """Prediction -> wire JSON, then what may rewrite it: the
+        degraded marks, the feedback loop's ``prId``, output plugins."""
         result = to_jsonable(prediction)
         if degraded:
             # the query WAS served degraded whatever its result shape —
@@ -687,12 +711,7 @@ class QueryServer:
                                 self.plugin_context)
             except Exception:
                 logger.exception("output sniffer failed")
-
-        took = time.perf_counter() - t0
-        self.latency.record(took)
-        metrics.QUERY_LATENCY.observe(took,
-                                      variant=self.config.engine_variant)
-        return 200, result
+        return result
 
     def _feedback(self, dep: _Deployment, query_dict: Mapping[str, Any],
                   query: Any, prediction: Any, result: Any,
@@ -848,7 +867,9 @@ class QueryServer:
         """GET /stats.json: the status page, the live micro-batch
         lanes' unified ``batcher_stats`` (dispatch triggers, batch-fill
         ratio, queue-depth percentiles — one shape for user and item
-        lanes), the ``device`` block (store + AOT ladder HBM bytes,
+        lanes), the ``stages`` block (median self time per span name
+        over the newest query roots), the ``device`` block (store +
+        AOT ladder HBM bytes,
         ladder coverage, flight-recorder dispatch summary), plus the
         process-wide registry snapshot (pio_query_seconds,
         pio_microbatch_*, pio_storage_op_* ... — the same state
@@ -859,6 +880,10 @@ class QueryServer:
         out = {**self.status(),
                "batchers": _serving.batcher_stats(),
                "device": _serving.device_report(),
+               # where a query's time goes inside this server: median
+               # self time per span over the newest query roots
+               "stages": trace_buffer().stage_p50(
+                   "query POST /queries.json"),
                "metrics": metrics.registry().snapshot()}
         # when EVENTDATA is the sharded fleet source, surface the shard
         # topology (per-shard breaker states, partial-read count) here
@@ -1025,8 +1050,9 @@ class _QueryHandler(InstrumentedHandlerMixin, BaseHTTPRequestHandler):
         logger.debug("%s - %s", self.address_string(), fmt % args)
 
     def _drain(self) -> bytes:
-        length = int(self.headers.get("Content-Length") or 0)
-        return self.rfile.read(length) if length else b""
+        with span("http.read_body"):
+            length = int(self.headers.get("Content-Length") or 0)
+            return self.rfile.read(length) if length else b""
 
     _ROUTES = ("/", "/healthz", "/metrics", "/stats.json",
                "/dispatches.json", "/plugins.json", "/queries.json",

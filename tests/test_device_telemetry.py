@@ -326,7 +326,7 @@ class TestAOTCacheObservability:
         cache.put("a", NoStats())
         rep = cache.memory_report()
         assert rep == {"entries": 1, "entriesAnalyzed": 0,
-                       "totalBytes": 0}
+                       "tempBytes": 0, "codeBytes": 0, "totalBytes": 0}
 
     def test_memory_report_real_executable(self):
         import jax
