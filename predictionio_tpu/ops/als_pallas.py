@@ -520,7 +520,10 @@ def fused_gather_score_topk(Q, Y, seen_bits=None, *,
     packed bitmap (:func:`pack_seen_bits`; what ``DeviceTopK`` keeps
     per user, so a dispatch moves ``M / 8`` bytes per query however
     long the user's history; a similarity query packs its own query
-    items with :func:`pack_seen_ids`).
+    items with :func:`pack_seen_ids`). Rows may be WIDER than
+    ``ceil(M / 32)``: the store's are a whole number of 128-word lane
+    tiles (``ops.serving.seen_row_words``), the words past the last
+    item tile are cut off here and never read as items.
     ``row_valid`` is an optional ``[M]`` per-row validity vector (>0 =
     real item) for stores whose real rows are not a contiguous prefix
     — the density-sharded per-shard lane.

@@ -10,7 +10,10 @@ with an AOT-compiled gather→matmul→top_k program:
 - scores = Y @ X[uid] runs on the MXU; top_k stays on device; only the
   k winners travel back over PCIe.
 - already-rated items are masked on device from a packed bitmap (one
-  bit per user and store position, :func:`seen_bitmap`).
+  bit per user and store position, :func:`seen_bitmap`), its rows a
+  whole number of 128-word lane tiles wide (:func:`seen_row_words`) so
+  that the device holds it row-major and a program gathers a batch's
+  rows without first copying all of it.
 - programs are compiled per top-k BUCKET (next power of two) so any
   (num, blacklist) request reuses a handful of compiled programs; the
   deploy path warms the common buckets so the first query pays no
@@ -207,19 +210,52 @@ def _score_einsum(subscripts: str, *operands, mode: str):
                      f"{', '.join(SERVE_PRECISION_MODES)})")
 
 
+# one lane tile of int32: the minor dimension a TPU array is tiled by
+# (T(8,128)); see seen_row_words
+_SEEN_LANE_WORDS = 128
+
+
+def seen_row_words(n_pos: int) -> int:
+    """int32 words in one row of the packed seen bitmap over ``n_pos``
+    store positions: ``ceil(n_pos / 32)`` rounded up to a multiple of
+    128, one lane tile. The width's one owner — everything that builds
+    bitmap rows (:func:`seen_bitmap`, and through it the store, growth
+    and fold-in's replacement rows) takes it from here.
+
+    Why the rounding: the TPU gives an entry parameter the COMPACT
+    tiled layout of its shape. A row of 1288 words would be padded to
+    1408 under ``T(8,128)``, so the compiler made the long user
+    dimension minor instead (``{0,1}``), and every program that gathers
+    rows then re-laid the WHOLE bitmap out row-major first: 8.0-8.5 ms
+    and a bitmap-sized temporary per dispatch at 571,355 x 41,140,
+    four fifths of the device time of a query (PERF.md, PR 22-24). A
+    width that is already a whole number of tiles wastes nothing
+    row-major, the parameter arrives ``{1,0}``, and the row gather
+    reads it in place."""
+    words = max(1, -(-int(n_pos) // 32))
+    return -(-words // _SEEN_LANE_WORDS) * _SEEN_LANE_WORDS
+
+
 def seen_bitmap(seen: Dict[int, np.ndarray], n_rows: int,
                 n_pos: int) -> np.ndarray:
     """Pack a ``{user_idx: item position array}`` dict into the
-    ``[n_rows, ceil(n_pos / 32)]`` int32 bitmap the device masks from:
-    bit ``j`` of word ``w`` in row ``u`` = user ``u`` has seen position
-    ``32 * w + j`` (positions outside ``[0, n_pos)`` carry no bit).
+    ``[n_rows, seen_row_words(n_pos)]`` int32 bitmap the device masks
+    from: bit ``j`` of word ``w`` in row ``u`` = user ``u`` has seen
+    position ``32 * w + j`` (positions outside ``[0, n_pos)`` carry no
+    bit, so the words past ``ceil(n_pos / 32)`` are zero; every reader
+    stops at ``n_pos``).
 
-    A row costs ``n_pos / 8`` bytes whatever the user's history. The
+    A row costs ``n_pos / 8`` bytes rounded up to 512 (one lane tile of
+    words, :func:`seen_row_words`) whatever the user's history. The
     padded id-list layout this replaces cost ``8 * longest_history``
     bytes for EVERY user: at the ML-20M shape (138k users, 27k items,
-    longest history 18k) that is 20 GB against 0.47 GB here, and the
-    fused kernel looped over the list per item tile."""
-    W = max(1, -(-int(n_pos) // 32))
+    longest history 18k) that is 20 GB against 0.49 GB here, and the
+    fused kernel looped over the list per item tile. The rounding is
+    resident memory paid for a bitmap no program has to copy: 2.94 ->
+    3.22 GB at 571,355 x 41,140 (+9.3%), 0.47 -> 0.49 GB at ML-20M
+    (+6%), +0.35% at 2M x 200k, at most 508 bytes a user for a tiny
+    catalog."""
+    W = seen_row_words(n_pos)
     bits = np.zeros((int(n_rows), W), dtype=np.uint32)
     if seen:
         users = np.fromiter(seen.keys(), dtype=np.int64, count=len(seen))
@@ -1684,6 +1720,9 @@ class DeviceTopK:
                                    int(self._X.shape[0]),
                                    int(self._Y.shape[0]))
         else:
+            # a store that masks nothing: one word the programs take
+            # as an argument and never gather rows from, so it needs
+            # no bitmap's width (seen_row_words)
             bits = np.zeros((1, 1), dtype=np.int32)
         with _trace_span("store.upload"):
             self._seen_bits = jax.block_until_ready(
@@ -2521,10 +2560,12 @@ class DeviceTopK:
 
     def memory_report(self) -> Dict[str, Any]:
         """HBM bytes this store pins, by component and dtype — factor
-        tables (int8 stores split data vs per-row scales), the seen bitmap,
-        and the lazily built normalized item matrix. Reads the LIVE
-        references under ``_store_lock``, so the answer tracks fold-in
-        growth and int8 requant as they happen."""
+        tables (int8 stores split data vs per-row scales), the seen
+        bitmap as it lies on the device (``seen`` rows are
+        :func:`seen_row_words` wide: the lane-tile padding is resident
+        and counted), and the lazily built normalized item matrix.
+        Reads the LIVE references under ``_store_lock``, so the answer
+        tracks fold-in growth and int8 requant as they happen."""
         from predictionio_tpu.ops.quantize import is_quantized
 
         with self._store_lock:
@@ -2862,9 +2903,11 @@ class DeviceTopK:
 
     def _prep_seen(self, seen_items: Dict[int, np.ndarray]):
         """The touched users' replacement bitmap rows (a row's width is
-        fixed by the item store, so a user's history growing never
-        reshapes the store) — the fallible half of a seen patch; the
-        caller feeds it to the donating :func:`_scatter_rows`."""
+        fixed by the item store — :func:`seen_bitmap` gives these rows
+        the store's own :func:`seen_row_words` — so a user's history
+        growing never reshapes the store) — the fallible half of a seen
+        patch; the caller feeds it to the donating
+        :func:`_scatter_rows`."""
         sids = np.fromiter(seen_items.keys(), dtype=np.int64,
                            count=len(seen_items))
         new_rows = seen_bitmap(

@@ -1,0 +1,337 @@
+"""The seen bitmap's row width (ISSUE 24): one rule
+(``ops.serving.seen_row_words``: whole 128-word lane tiles), the same
+answers on every lane with the wider rows, and a deviceless v5e compile
+of the real user-lane programs that holds no whole-bitmap copy.
+
+The compiles need libtpu's compiler and no chip. The topology is
+described inside ONE module-scoped fixture of this one file (only the
+worker that is given this file loads the library); nothing runs, so
+nothing here is a result or a time.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+from predictionio_tpu.ops.serving import (
+    DeviceTopK,
+    bucket_size,
+    seen_bitmap,
+    seen_row_words,
+)
+from predictionio_tpu.ops.twostage import TwoStageTopK
+from predictionio_tpu.parallel.als_sharding import (
+    density_aware_item_layout,
+)
+
+# ---------------------------------------------------------------------------
+# the width rule
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n_pos,words", [
+    (1, 128), (70, 128), (4096, 128), (4097, 256), (41216, 1408)])
+def test_row_width_rule(n_pos, words):
+    assert seen_row_words(n_pos) == words
+    bits = seen_bitmap({0: np.asarray([0, n_pos - 1, n_pos, -1])}, 2,
+                       n_pos)
+    assert bits.shape == (2, words) and bits.dtype == np.int32
+    u = bits.view(np.uint32)
+    last = (n_pos - 1) >> 5
+    assert (u[0, last] >> np.uint32((n_pos - 1) & 31)) & 1 == 1
+    # the padding words lie past every real position and stay zero
+    assert not u[:, last + 1:].any() and not u[1].any()
+
+
+def test_row_width_never_under_the_bits_and_always_whole_tiles():
+    for n_pos in list(range(1, 300)) + [4095, 8192, 8193, 26744, 27008,
+                                        41140, 200_000, 384_546]:
+        w = seen_row_words(n_pos)
+        assert w >= -(-n_pos // 32) and w % 128 == 0
+        assert w - (-(-n_pos // 32)) < 128   # at most 508 bytes a row
+
+
+# ---------------------------------------------------------------------------
+# the same answers on every lane: seen items in the last real position,
+# and a fold-in patch of rows after growth
+# ---------------------------------------------------------------------------
+
+def _problem(n=20, m=41, r=8, seed=5):
+    rng = np.random.default_rng(seed)
+    X = rng.normal(size=(n, r)).astype(np.float32)
+    Y = rng.normal(size=(m, r)).astype(np.float32)
+    U = rng.normal(size=(n, r)).astype(np.float32)
+    E = rng.normal(size=(m, r)).astype(np.float32)
+    seen = {u: rng.choice(m - 1, size=4, replace=False)
+            for u in range(n)}
+    # the catalog's last item sits in the last real bit of the bitmap,
+    # next to the padding words: seen by the even users only
+    for u in range(0, n, 2):
+        seen[u] = np.append(seen[u], m - 1)
+    return X, Y, U, E, seen
+
+
+def _oracle(score_rows, item_rows, seen_u, k):
+    s = (item_rows @ score_rows).astype(np.float32)
+    s[np.asarray(seen_u, dtype=np.int64)] = -np.inf
+    order = np.lexsort((np.arange(len(s)), -s))[:k]
+    order = order[np.isfinite(s[order])]
+    return order, s[order]
+
+
+def _layout(seen, m, shards=4):
+    counts = np.zeros(m, np.int64)
+    for v in seen.values():
+        np.add.at(counts, v, 1)
+    return density_aware_item_layout(counts, shards)
+
+
+LANES = ["xla", "fused", "sharded-xla", "sharded-fused",
+         "two-xla", "two-fused", "two-sharded"]
+
+
+@pytest.mark.parametrize("lane", LANES)
+def test_lane_answers_with_wide_rows_growth_and_foldin(
+        lane, monkeypatch, multichip_devices):
+    two = lane.startswith("two")
+    monkeypatch.setenv("PIO_SERVE_KERNEL",
+                       "fused" if lane.endswith("fused") else "xla")
+    X, Y, U, E, seen = _problem()
+    n, m = X.shape[0], Y.shape[0]
+    kw = {"microbatch": False}
+    if "sharded" in lane:
+        kw["item_layout"] = _layout(seen, m)
+    if two:
+        srv = TwoStageTopK(X, Y, U, E,
+                           seen={u: v.copy() for u, v in seen.items()},
+                           candidates=m, **kw)
+    else:
+        srv = DeviceTopK(X, Y,
+                         {u: v.copy() for u, v in seen.items()}, **kw)
+    one = srv.two_topk if two else srv.user_topk
+    many = srv.twos_topk if two else srv.users_topk
+    try:
+        def check(users, Xs, Us, seen_now, k):
+            bi, bs = many(np.asarray(users), k)
+            for row, u in enumerate(users):
+                want_i, want_s = _oracle(Us[u] if two else Xs[u],
+                                         E if two else Y, seen_now[u], k)
+                gi, gs = one(int(u), k)
+                assert gi.tolist() == want_i.tolist(), (lane, u)
+                np.testing.assert_allclose(gs, want_s, atol=1e-5)
+                keep = np.isfinite(bs[row])
+                assert bi[row][keep].tolist() == want_i.tolist()
+
+        # k = the catalog: every unseen item comes back, every seen one
+        # (the last real position among them) does not
+        check(range(n), X, U, seen, m)
+        idx, _ = one(0, m)
+        assert m - 1 not in idx.tolist()
+        idx, _ = one(1, m)
+        assert m - 1 in idx.tolist()
+
+        # growth, then a fold-in patch: new rows past the old capacity,
+        # one of which has seen the last item, and an old user whose
+        # replacement row now carries it too
+        rng = np.random.default_rng(9)
+        uids = np.asarray([n + 1, n + 7, 3])
+        rows = rng.normal(size=(3, X.shape[1])).astype(np.float32)
+        rows2 = rng.normal(size=(3, U.shape[1])).astype(np.float32)
+        upd = {n + 1: np.asarray([0, m - 1]), n + 7: np.asarray([2]),
+               3: np.asarray([5, m - 1])}
+        srv.patch_users(uids, rows,
+                        seen_items={u: v.copy() for u, v in upd.items()})
+        if two:
+            srv.patch_seq_users(uids, rows2)
+        cap = srv.user_capacity
+        assert cap >= n + 8
+        Xg = np.zeros((cap, X.shape[1]), np.float32)
+        Ug = np.zeros((cap, U.shape[1]), np.float32)
+        Xg[:n], Ug[:n] = X, U
+        Xg[uids], Ug[uids] = rows, rows2
+        seen_now = {**seen, **upd}
+        check([int(u) for u in uids] + [0, 1], Xg, Ug, seen_now, m)
+        idx, _ = one(n + 1, m)
+        assert m - 1 not in idx.tolist() and 0 not in idx.tolist()
+        idx, _ = one(n + 7, m)
+        assert m - 1 in idx.tolist() and 2 not in idx.tolist()
+
+        # the grown store keeps the rule's width, and reports it
+        n_pos = int(srv._Y.shape[0])
+        assert srv._seen_bits.shape == (cap, seen_row_words(n_pos))
+        rep = srv.memory_report()["components"]["seen"]
+        assert rep["shape"] == [cap, seen_row_words(n_pos)]
+        assert rep["bytes"] == cap * seen_row_words(n_pos) * 4
+        host = np.asarray(srv._seen_bits).view(np.uint32)
+        assert not host[:, -(-n_pos // 32):].any()
+    finally:
+        srv.close()
+
+
+@pytest.mark.parametrize("kernel", ["xla", "fused"])
+def test_catalog_over_one_lane_tile(kernel, monkeypatch):
+    """4,100 items: 129 words of bits, so a row of 256; the items of
+    the last word are masked and served like any other."""
+    monkeypatch.setenv("PIO_SERVE_KERNEL", kernel)
+    X, Y, _, _, seen = _problem(n=6, m=4100, r=8, seed=11)
+    # the top of user 1's list, so that the mask has something to hide
+    top = _oracle(X[1], Y, [], 4)[0]
+    seen[1] = np.append(top[:2], [4099, 4096])
+    srv = DeviceTopK(X, Y, seen, microbatch=False)
+    try:
+        assert srv._seen_bits.shape[1] == 256
+        for u in range(6):
+            want_i, want_s = _oracle(X[u], Y, seen[u], 16)
+            gi, gs = srv.user_topk(u, 16)
+            assert gi.tolist() == want_i.tolist()
+            np.testing.assert_allclose(gs, want_s, atol=1e-5)
+        bi, _ = srv.users_topk(np.arange(6), 16)
+        assert bi[1].tolist() == _oracle(X[1], Y, seen[1], 16)[0].tolist()
+    finally:
+        srv.close()
+
+
+# ---------------------------------------------------------------------------
+# the deviceless v5e compile guard: no program of the user lanes copies
+# the bitmap
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to compile with
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2.devices[0])
+
+
+# users x items, store precision: cell 2 / 4's store, chip_smoke's, and
+# cell 2's after one _reserve_users doubling
+SHAPES = {
+    "rec-msd": (571_355, 41_140, "bf16"),
+    "ml20m": (138_000, 27_000, "fp32"),
+    "rec-msd-grown": (bucket_size(571_356, lo=571_355), 41_140, "bf16"),
+}
+
+COMPILES = [(shape, lane, b)
+            for shape in ("rec-msd", "ml20m")
+            for lane, buckets in (("fused", (1, 8, 32, 256)),
+                                  ("xla", (1, 8, 32, 256)),
+                                  ("two", (8, 32, 256)))
+            for b in buckets] + [("rec-msd-grown", lane, 8)
+                                 for lane in ("fused", "xla", "two")]
+
+
+def _real_program(lane, mode, n_items, bucket, monkeypatch):
+    """The store's OWN program builder for one lane, taken from a toy
+    store: the jitted closure is what a deploy at any size lowers, with
+    the catalog size and "compiled, not interpreted" (which the store
+    reads off the platform, and the platform here is the CPU) set as a
+    v5e deploy of ``n_items`` has them."""
+    monkeypatch.setenv("PIO_SERVE_KERNEL",
+                       "xla" if lane == "xla" else "fused")
+    monkeypatch.setenv("PIO_SERVE_PRECISION", mode)
+    X, Y, U, E, seen = _problem()
+    if lane == "two":
+        srv = TwoStageTopK(X, Y, U, E, seen=seen, microbatch=False)
+    else:
+        srv = DeviceTopK(X, Y, seen, microbatch=False)
+    srv.n_items, srv._interpret = n_items, False
+    if lane == "two":
+        return srv, srv._two_program(16, 128)
+    if bucket == 1:
+        return srv, srv._user_program(16)
+    return srv, srv._batch_program(16, bucket)
+
+
+def _assert_bitmap_read_in_place(compiled, n_rows, words):
+    hlo = compiled.as_text()
+    bitmap = rf"s32\[{n_rows},{words}\]"
+    copies = re.findall(rf"= ({bitmap}\{{[^}}]*\}}) copy\(", hlo)
+    assert not copies, f"the program re-lays the whole bitmap out: {copies}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < n_rows * words * 4 / 10, \
+        f"{temp / 1e9:.3f} GB of temporaries beside a " \
+        f"{n_rows * words * 4 / 1e9:.3f} GB bitmap"
+    params = re.findall(rf"{bitmap}(\{{[^}}]*\}}) parameter\(", hlo)
+    assert params and not [p for p in params if not p.startswith("{1,0")], \
+        f"the bitmap parameter is not row-major on the device: {params}"
+    return hlo
+
+
+@pytest.mark.parametrize("shape,lane,bucket", COMPILES)
+def test_v5e_user_programs_do_not_copy_the_bitmap(shape, lane, bucket,
+                                                  one_chip, monkeypatch):
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als_pallas
+
+    n_users, n_items, mode = SHAPES[shape]
+    dt = {"bf16": jnp.bfloat16, "fp32": jnp.float32}[mode]
+    rows = n_items if lane == "xla" else \
+        -(-n_items // als_pallas.TOPK_TILE_M) * als_pallas.TOPK_TILE_M
+    words = seen_row_words(rows)
+
+    def sds(s, d):
+        return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+
+    Xa, Ya = sds((n_users, 64), dt), sds((rows, 64), dt)
+    sb = sds((n_users, words), jnp.int32)
+    uids = sds(() if bucket == 1 else (bucket,), jnp.int32)
+    srv, prog = _real_program(lane, mode, n_items, bucket, monkeypatch)
+    try:
+        args = (Xa, Ya, Ya, Xa, sb, uids) if lane == "two" \
+            else (Xa, Ya, sb, uids)
+        compiled = prog.lower(*args).compile()
+    finally:
+        srv.close()
+    hlo = _assert_bitmap_read_in_place(compiled, n_users, words)
+    if lane != "xla":
+        assert "tpu_custom_call" in hlo      # the Mosaic kernel is in it
+
+
+def test_v5e_sharded_user_program_does_not_copy_the_bitmap(
+        v5e_2x2, multichip_devices, monkeypatch):
+    """The sharded lane on the four described chips (item table split,
+    bitmap replicated): each chip would copy its whole replica."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setenv("PIO_SERVE_KERNEL", "fused")
+    monkeypatch.setenv("PIO_SERVE_PRECISION", "bf16")
+    X, Y, _, _, seen = _problem()
+    srv = DeviceTopK(X, Y, seen, microbatch=False,
+                     item_layout=_layout(seen, Y.shape[0]))
+    # the toy store's own program builder, pointed at the described mesh
+    mesh = Mesh(np.asarray(v5e_2x2.devices), ("data",))
+    srv._shard, srv._interpret = (mesh, "data", 4), False
+    n_users, n_pos = 571_356, 41_140       # both divisible by 4 shards
+    words = seen_row_words(n_pos)
+
+    def sds(s, d, *spec):
+        return jax.ShapeDtypeStruct(s, d,
+                                    sharding=NamedSharding(mesh, P(*spec)))
+
+    try:
+        compiled = srv._sharded_user_program(16).lower(
+            sds((n_users, 64), jnp.bfloat16, "data", None),
+            sds((n_pos, 64), jnp.bfloat16, "data", None),
+            sds((n_pos,), jnp.float32, "data"),
+            sds((n_users, words), jnp.int32, None, None),
+            sds((8,), jnp.int32, None)).compile()
+    finally:
+        srv.close()
+    assert "tpu_custom_call" in _assert_bitmap_read_in_place(
+        compiled, n_users, words)

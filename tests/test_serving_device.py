@@ -170,15 +170,18 @@ class TestDeviceTopK:
                                       scores.view(np.int32))
 
     def test_seen_bitmap_packing(self):
-        # 70 positions -> 3 words; bit j of word w = position 32*w + j,
-        # bit 31 included (the int32 sign bit); out-of-range ids drop
+        # 70 positions -> 3 words of bits in a row of one lane tile
+        # (128 words, seen_row_words); bit j of word w = position
+        # 32*w + j, bit 31 included (the int32 sign bit); out-of-range
+        # ids drop, so the padding words stay zero
         bits = seen_bitmap({0: np.asarray([3, 1]),
                             2: np.asarray([7, 31, 69, 70, -1])}, 4, 70)
-        assert bits.shape == (4, 3) and bits.dtype == np.int32
+        assert bits.shape == (4, 128) and bits.dtype == np.int32
         u = bits.view(np.uint32)
-        assert u[0].tolist() == [(1 << 3) | (1 << 1), 0, 0]
+        assert u[0, :3].tolist() == [(1 << 3) | (1 << 1), 0, 0]
         assert not u[1].any() and not u[3].any()
-        assert u[2].tolist() == [(1 << 7) | (1 << 31), 0, 1 << 5]
+        assert u[2, :3].tolist() == [(1 << 7) | (1 << 31), 0, 1 << 5]
+        assert not u[:, 3:].any()
 
 
 class TestMicroBatching:
