@@ -309,3 +309,93 @@ def ulysses_attention(q, k, v, mesh, axis_name: str = "data",
         _ulysses_fn(mesh, axis_name, causal, float(scale),
                     key_padding_mask is not None),
         q, k, v, mesh, axis_name, kv_mask=key_padding_mask)
+
+
+# ---------------------------------------------------------------------------
+# Packed rows: causal attention inside segments
+# ---------------------------------------------------------------------------
+
+def segment_attention_dense(q, k, v, segment_ids, scale: Optional[float] = None):
+    """Causal attention over PACKED rows, the plain way: position ``t``
+    attends ``s <= t`` of its own segment. ``q/k/v: [B, H, L, D]``;
+    ``segment_ids: [B, L]`` with 0 for padding (a pad position attends
+    nothing and outputs exact zeros, the safe-softmax convention of
+    :func:`mha_reference`). With one segment per row (ids 1 on real
+    positions) this is ``mha_reference(causal=True,
+    key_padding_mask=...)`` to the last bit on real positions.
+    Materialises ``[B, H, L, L]`` scores: for short rows and as the
+    blocked kernel's oracle."""
+    import jax
+    import jax.numpy as jnp
+
+    scale = scale if scale is not None else q.shape[-1] ** -0.5
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * scale
+    L = s.shape[-1]
+    seg = jnp.asarray(segment_ids)
+    ok = (jnp.arange(L)[:, None] >= jnp.arange(L)[None, :])[None] \
+        & (seg[:, :, None] == seg[:, None, :]) & (seg[:, None, :] != 0)
+    s = jnp.where(ok[:, None], s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(jnp.isneginf(m), 0.0, jnp.exp(s - m))
+    denom = jnp.sum(p, axis=-1, keepdims=True)
+    p = p / jnp.where(denom == 0.0, 1.0, denom)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+# q / kv block of the blocked kernel at 4k rows and 128-wide heads on a
+# v5e (forward, and the fused backward)
+FLASH_BLOCK = 512
+
+
+@functools.lru_cache(maxsize=16)
+def _splash_kernel(n_heads: int, seq_len: int, block: int, interpret: bool):
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    b = min(int(block), seq_len)
+    sizes = sk.BlockSizes(
+        block_q=b, block_kv=b, block_kv_compute=b, block_q_dkv=b,
+        block_kv_dkv=b, block_kv_dkv_compute=b, use_fused_bwd_kernel=True)
+    mask = sm.MultiHeadMask(
+        [sm.CausalMask((seq_len, seq_len))] * n_heads)
+    # the kernel keeps its block-mask tables as arrays: built here as
+    # constants even when the first caller is being traced (this cache
+    # would otherwise keep that trace's tracers)
+    with jax.ensure_compile_time_eval():
+        return sk.make_splash_mha(mask, block_sizes=sizes, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def segment_attention_flash(q, k, v, segment_ids,
+                            scale: Optional[float] = None,
+                            block: int = FLASH_BLOCK,
+                            interpret: bool = False):
+    """The same attention BLOCKED: the Pallas TPU splash-attention
+    kernel (online softmax over ``block`` x ``block`` tiles, blocks
+    above the diagonal skipped, segment ids compared inside the tile),
+    forward and a fused backward; no ``[L, L]`` array exists. Inputs
+    in the compute dtype (bf16 on the chip), products accumulated in
+    float32. ``L`` a multiple of the block (or shorter than one), the
+    head width a multiple of 128 lanes."""
+    import jax
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    _, H, L, D = q.shape
+    scale = scale if scale is not None else D ** -0.5
+    kernel = _splash_kernel(int(H), int(L), int(block), bool(interpret))
+    seg = segment_ids.astype("int32")
+
+    def one(qi, ki, vi, si):
+        return kernel(qi, ki, vi, segment_ids=sk.SegmentIds(q=si, kv=si))
+
+    out = jax.vmap(one)((q * scale).astype(q.dtype), k, v, seg)
+    # a pad position sees only pads of "segment 0": zero it, as the
+    # dense form does
+    return out * (seg != 0)[:, None, :, None].astype(out.dtype)

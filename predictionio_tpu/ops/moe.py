@@ -1,0 +1,339 @@
+"""Sparse-expert feed-forward layer: router, dropless token dispatch,
+grouped matmul, combine — forward and backward.
+
+The layer OLMoE-1B-7B (and the expert models after it in ROADMAP
+"Reach") is built from. Per token: router logits ``h @ W_r`` in
+float32, softmax over the experts, the top ``k`` of them, their
+probabilities used as they are (NOT renormalised: OLMoE's
+``norm_topk_prob: false``); each chosen expert computes
+``W_down(silu(W_gate x) * W_up x)``; the output is the weighted sum.
+
+Dropless: every (token, expert) pair is computed. The ``T * k`` pairs
+are sorted by expert, so that each expert owns one contiguous run of
+rows whatever its load, and the three expert matmuls run as GROUPED
+matmuls over those runs (``group_sizes`` says where each run ends; one
+expert may own every row, another none). No capacity factor, no
+dropped token; the counters below let a caller assert it.
+
+Two grouped-matmul backends, chosen from the platform:
+``megablox`` (``jax.experimental.pallas.ops.tpu.megablox``: the Pallas
+TPU kernels ``gmm`` / ``tgmm``, under a custom VJP of this module so
+that forward and the two backward products are named apart in a
+trace) and ``ragged`` (``jax.lax.ragged_dot``: XLA, any backend, the
+CPU tests' path and the kernels' oracle).
+
+Dispatch and combine are permutations, so both directions are written
+as GATHERS (a custom VJP whose backward gathers with the inverse
+permutation) instead of leaving XLA a scatter-add with repeated
+indices for the transpose.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+# the package re-exports its ``gmm`` FUNCTION under the module's name
+_megablox = importlib.import_module(
+    "jax.experimental.pallas.ops.tpu.megablox.gmm")
+
+# m, k, n tile of the megablox kernels at the OLMoE widths on a v5e
+# (the (128, 128, 128) default re-reads each operand 8-16 times; of
+# five tiles tried on the chip, PR 25, these were the fastest: 133 and
+# 96 TFLOP/s at 65,536 rows)
+GMM_TILING = (256, 2048, 1024)
+# the weight-gradient kernel holds a float32 [k tile, n tile] block and
+# its accumulator in VMEM: (512, 1024, 1024) does not fit its 16 MiB
+TGMM_TILING = (256, 1024, 1024)
+
+
+def resolve_gmm_impl(impl: str = "auto") -> str:
+    if impl != "auto":
+        return impl
+    return "megablox" if jax.default_backend() == "tpu" else "ragged"
+
+
+def _fit_tiling(m: int, k: int, n: int,
+                tiling: Tuple[int, int, int]) -> Tuple[int, int, int]:
+    """Clip a tile to the problem (the kernels want ``m`` divisible by
+    the m tile; k and n tiles may be ragged but not larger)."""
+    tm, tk, tn = tiling
+    tm = min(tm, m)
+    while m % tm:
+        tm //= 2
+    return max(tm, 8), min(tk, k), min(tn, n)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _megablox_gmm(lhs, rhs, rhs_low, group_sizes, tiling, interpret):
+    """``rhs`` is the (float32 master) weight the gradient is for;
+    ``rhs_low`` its copy in ``lhs``'s dtype, which is what the kernels
+    multiply. The caller may make that copy once for many calls (a
+    step's microbatches, an encode's calls); None makes it here."""
+    if rhs_low is None:
+        rhs_low = rhs.astype(lhs.dtype)
+    m, k = lhs.shape
+    return _megablox.gmm(lhs, rhs_low, group_sizes, lhs.dtype,
+                         _fit_tiling(m, k, rhs.shape[2], tiling),
+                         interpret=interpret)
+
+
+def _megablox_gmm_fwd(lhs, rhs, rhs_low, group_sizes, tiling, interpret):
+    if rhs_low is None:
+        rhs_low = rhs.astype(lhs.dtype)
+    out = _megablox_gmm(lhs, rhs, rhs_low, group_sizes, tiling, interpret)
+    # the master weight itself is not kept: its dtype is all the
+    # backward pass needs of it
+    return out, (lhs, rhs_low, group_sizes, jnp.zeros((0,), rhs.dtype))
+
+
+def _megablox_gmm_bwd(tiling, interpret, res, grad):
+    lhs, rhs_low, group_sizes, like = res
+    m, k = lhs.shape
+    n = rhs_low.shape[2]
+    with jax.named_scope("gmm_dlhs"):
+        d_lhs = _megablox.gmm(grad, rhs_low, group_sizes, lhs.dtype,
+                              _fit_tiling(m, n, k, tiling),
+                              transpose_rhs=True, interpret=interpret)
+    with jax.named_scope("gmm_drhs"):
+        # the weight gradient leaves the kernel in the master weight's
+        # dtype (float32): it is summed over microbatches as it is
+        d_rhs = _megablox.tgmm(lhs.swapaxes(0, 1), grad, group_sizes,
+                               like.dtype,
+                               _fit_tiling(m, k, n, TGMM_TILING),
+                               num_actual_groups=rhs_low.shape[0],
+                               interpret=interpret)
+    return d_lhs, d_rhs, None, None
+
+
+_megablox_gmm.defvjp(_megablox_gmm_fwd, _megablox_gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, *, rhs_low=None,
+                   impl: str = "auto",
+                   tiling: Tuple[int, int, int] = GMM_TILING,
+                   interpret: bool = False):
+    """``out[r] = lhs[r] @ rhs[g(r)]`` where rows are grouped in runs:
+    the first ``group_sizes[0]`` rows use ``rhs[0]``, the next
+    ``group_sizes[1]`` use ``rhs[1]``, ... ``lhs: [m, k]``, ``rhs: [G,
+    k, n]``, ``group_sizes: [G]`` int32 summing to ``m``. The operands
+    are multiplied in ``lhs``'s dtype (``rhs_low``: ``rhs`` already in
+    it, else cast here), products accumulate in float32, the result has
+    ``lhs``'s dtype; the gradient for ``rhs`` comes in ``rhs``'s."""
+    impl = resolve_gmm_impl(impl)
+    if impl == "megablox":
+        return _megablox_gmm(lhs, rhs, rhs_low, group_sizes, tuple(tiling),
+                             bool(interpret))
+    if impl != "ragged":
+        raise ValueError(f"unknown grouped-matmul backend {impl!r}")
+    precision = jax.lax.Precision.HIGHEST \
+        if lhs.dtype == jnp.float32 else None
+    return jax.lax.ragged_dot(
+        lhs, use_low(rhs, rhs_low, lhs.dtype),
+        group_sizes.astype(jnp.int32), precision=precision,
+        preferred_element_type=jnp.float32).astype(lhs.dtype)
+
+
+@jax.custom_vjp
+def _low_of(w, w_low):
+    return w_low
+
+
+def _low_of_fwd(w, w_low):
+    return w_low, jnp.zeros((0,), w.dtype)
+
+
+def _low_of_bwd(like, g):
+    return g.astype(like.dtype), None
+
+
+_low_of.defvjp(_low_of_fwd, _low_of_bwd)
+
+
+def use_low(w, w_low, dtype):
+    """``w`` in ``dtype`` for a matmul: the ready-made copy ``w_low``
+    when there is one (the gradient still goes to ``w``), else a cast."""
+    if w_low is not None:
+        return _low_of(w, w_low)
+    return w if w.dtype == dtype else w.astype(dtype)
+
+
+# -- router ------------------------------------------------------------------
+
+def route(h, w_router, k: int):
+    """``h: [T, D]`` -> router logits ``[T, E]`` (float32, a true
+    float32 product on every backend), the top-``k`` experts ``[T, k]``
+    and their softmax probabilities ``[T, k]``, not renormalised."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    return logits, probs, experts.astype(jnp.int32), weights
+
+
+def aux_losses(logits, probs, experts, valid) -> Tuple[Any, Any]:
+    """(load-balancing loss, router z-loss) over the tokens ``valid``
+    marks (``[T]`` 0/1). Load balancing as in the Switch / OLMoE
+    recipe: ``E * sum_e f_e * P_e`` with ``f_e`` the share of (token,
+    choice) pairs sent to expert ``e`` per token and ``P_e`` the mean
+    router probability of ``e``; z-loss the mean squared
+    log-partition of the router logits."""
+    E = logits.shape[-1]
+    n = jnp.maximum(jnp.sum(valid), 1.0)
+    chosen = jnp.sum(jax.nn.one_hot(experts, E, dtype=jnp.float32), axis=1)
+    f = jnp.sum(chosen * valid[:, None], axis=0) / n
+    p = jnp.sum(probs * valid[:, None], axis=0) / n
+    lb = E * jnp.sum(f * p)
+    z = jnp.sum(jnp.square(jax.nn.logsumexp(logits, axis=-1)) * valid) / n
+    return lb, z
+
+
+# -- dispatch / combine: permutations, gathers both ways ---------------------
+
+def dispatch_plan(experts, n_experts: int) -> Dict[str, Any]:
+    """From ``experts: [T, k]`` the plan of a dropless dispatch:
+    ``order`` (pair indices sorted by expert, stable, so a token's
+    pairs keep their order inside an expert), its inverse ``inv``, the
+    token of each sorted row and ``group_sizes`` ``[E]`` (their sum is
+    ``T * k``: nothing is dropped)."""
+    T, k = experts.shape
+    flat = experts.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    group_sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    return {"order": order, "inv": inv, "token": order // k,
+            "group_sizes": group_sizes}
+
+
+def _rows(x, idx):
+    """``x[idx]`` for indices known to be in range (permutations and
+    their quotients). ``jnp.take``'s default fills out-of-range rows
+    with NaN, and that select costs the TPU 2.8 times the gather (2.55
+    against 0.91 ms for 131,072 rows of 2,048 bf16: my chip run, PR
+    25)."""
+    return jnp.take(x, idx, axis=0, mode="clip")
+
+
+@jax.custom_vjp
+def _dispatch(x, token, inv):
+    return _rows(x, token)
+
+
+def _dispatch_fwd(x, token, inv):
+    return _dispatch(x, token, inv), (inv, x.shape[0])
+
+
+def _dispatch_bwd(res, g):
+    inv, T = res
+    k = inv.shape[0] // T
+    back = _rows(g, inv).reshape(T, k, g.shape[-1])
+    return (jnp.sum(back.astype(jnp.float32), axis=1).astype(g.dtype),
+            None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, weights, order, inv):
+    T, k = weights.shape
+    back = _rows(ys, inv).reshape(T, k, ys.shape[-1])
+    return jnp.sum(back.astype(jnp.float32)
+                   * weights[..., None].astype(jnp.float32), axis=1)
+
+
+def _combine_fwd(ys, weights, order, inv):
+    return _combine(ys, weights, order, inv), (ys, weights, order, inv)
+
+
+def _combine_bwd(res, g):
+    ys, weights, order, inv = res
+    T, k = weights.shape
+    g = g.astype(jnp.float32)
+    w_sorted = _rows(weights.reshape(-1), order)
+    d_ys = (_rows(g, order // k)
+            * w_sorted[:, None].astype(jnp.float32)).astype(ys.dtype)
+    back = _rows(ys, inv).reshape(T, k, ys.shape[-1])
+    d_w = jnp.sum(back.astype(jnp.float32) * g[:, None, :], axis=-1)
+    return d_ys, d_w.astype(weights.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+# -- the layer ---------------------------------------------------------------
+
+def moe_ffn(h, w_router, w_gate, w_up, w_down, *, k: int,
+            compute_dtype=None, valid: Optional[Any] = None,
+            low: Optional[Tuple[Any, Any, Any]] = None):
+    """The expert layer on ``h: [T, D]`` (already normalised).
+
+    ``w_router: [D, E]``, ``w_gate`` / ``w_up``: ``[E, D, F]``,
+    ``w_down: [E, F, D]`` (``low``: the three already in the compute
+    dtype). Returns ``(y [T, D] float32, stats)`` where
+    ``stats`` holds ``logits``, the two auxiliary losses (over
+    ``valid`` tokens; all of them when None), ``group_sizes`` ``[E]``
+    and ``dropped`` (pairs not computed: ``T * k`` less the rows the
+    grouped matmuls covered; 0 by construction, reported so that a
+    caller can assert it)."""
+    T, _ = h.shape
+    E = w_router.shape[1]
+    cd = compute_dtype or h.dtype
+    with jax.named_scope("moe/router"):
+        logits, probs, experts, weights = route(h, w_router, k)
+        ones = jnp.ones((T,), jnp.float32) if valid is None \
+            else valid.astype(jnp.float32)
+        lb, z = aux_losses(logits, probs, experts, ones)
+    with jax.named_scope("moe/dispatch"):
+        plan = dispatch_plan(experts, E)
+        xs = _dispatch(h.astype(cd), plan["token"], plan["inv"])
+    gs = plan["group_sizes"]
+    gmm = functools.partial(grouped_matmul, group_sizes=gs)
+    low = low or (None, None, None)
+    with jax.named_scope("moe/gmm_gate_up"):
+        gate = gmm(xs, w_gate, rhs_low=low[0])
+        up = gmm(xs, w_up, rhs_low=low[1])
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(cd)
+    with jax.named_scope("moe/gmm_down"):
+        ys = gmm(act, w_down, rhs_low=low[2])
+    with jax.named_scope("moe/combine"):
+        y = _combine(ys, weights, plan["order"], plan["inv"])
+    stats = {"logits": logits, "experts": experts, "lb_loss": lb,
+             "z_loss": z, "group_sizes": gs,
+             "dropped": jnp.int32(T * k) - jnp.sum(gs)}
+    return y, stats
+
+
+def moe_ffn_dense(h, w_router, w_gate, w_up, w_down, *, k: int):
+    """The same layer the plain way: every expert on every token, the
+    result masked to the top ``k``. ``O(E / k)`` times the work; the
+    dispatch path's oracle in the tests."""
+    hp = jax.lax.Precision.HIGHEST
+    _, probs, experts, weights = route(h, w_router, k)
+    E = w_router.shape[1]
+    gate = jnp.einsum("td,edf->tef", h, w_gate, precision=hp)
+    up = jnp.einsum("td,edf->tef", h, w_up, precision=hp)
+    out = jnp.einsum("tef,efd->ted", jax.nn.silu(gate) * up, w_down,
+                     precision=hp)
+    w_full = jnp.sum(jax.nn.one_hot(experts, E, dtype=h.dtype)
+                     * weights[..., None], axis=1)
+    return jnp.sum(out * w_full[..., None], axis=1)
+
+
+__all__ = [
+    "GMM_TILING",
+    "aux_losses",
+    "dispatch_plan",
+    "grouped_matmul",
+    "moe_ffn",
+    "moe_ffn_dense",
+    "resolve_gmm_impl",
+    "route",
+    "use_low",
+]

@@ -57,11 +57,14 @@ from predictionio_tpu.data.sliding import (
 )
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.seqrec import (
+    PackedRows,
     SeqRecParams,
     SequenceBucket,
     bucket_sequences,
     encode_users,
     length_bucket,
+    output_table,
+    pack_sequences,
     train_seqrec,
 )
 
@@ -223,25 +226,31 @@ class SequenceDataSource(PDataSource):
 class SeqPreparatorParams(Params):
     """``max_seq_len`` keeps each user's LAST that-many items (recency
     is the signal); the padded length classes round it up the
-    power-of-two ladder."""
+    power-of-two ladder. ``packed`` lays the histories out first-fit in
+    rows of ``max_seq_len`` slots with segment ids instead
+    (``ops/seqrec.pack_sequences``): the layout of a long-row backbone,
+    where padding a mean-59 history to a power of two would waste most
+    of a row."""
 
     max_seq_len: int = 32
+    packed: bool = False
 
 
 @dataclasses.dataclass
 class PreparedSequences:
-    """BiMap-indexed, length-bucketed per-user sequences."""
+    """BiMap-indexed per-user sequences in the layout the trainer
+    reads: length buckets, or one :class:`PackedRows`."""
 
     user_map: StringIndexBiMap
     item_map: StringIndexBiMap
-    buckets: List[SequenceBucket]
+    buckets: Any   # List[SequenceBucket] | PackedRows
     seen: Dict[int, np.ndarray]   # user idx -> unique item idx array
     max_seq_len: int
 
     def sanity_check(self) -> None:
         assert len(self.user_map) > 0, "no users after indexing"
         assert len(self.item_map) > 0, "no items after indexing"
-        assert self.buckets, "no non-empty sequences after bucketing"
+        assert len(self.buckets), "no non-empty sequences after bucketing"
 
 
 class SequencePreparator(PPreparator):
@@ -269,21 +278,35 @@ class SequencePreparator(PPreparator):
         ends = np.searchsorted(s_rows, np.arange(n_u), side="right")
         seqs = [s_cols[starts[u]:ends[u]] for u in range(n_u)]
         seen = {u: np.unique(seqs[u]) for u in range(n_u) if len(seqs[u])}
-        buckets = bucket_sequences(seqs, max_len=int(p.max_seq_len))
-        return PreparedSequences(user_map, item_map, buckets, seen,
+        return PreparedSequences(user_map, item_map,
+                                 self.layout(seqs), seen,
                                  int(p.max_seq_len))
+
+    def layout(self, seqs):
+        """Per-user index sequences -> the trainer's layout."""
+        from predictionio_tpu.utils import metrics, tracing
+
+        p: SeqPreparatorParams = self.params
+        if not p.packed:
+            return bucket_sequences(seqs, max_len=int(p.max_seq_len))
+        with tracing.span("seq.pack"):
+            rows = pack_sequences(seqs, int(p.max_seq_len))
+        metrics.SEQ_PACK_PAD_SHARE.set(rows.pad_share)
+        return rows
 
 
 @dataclasses.dataclass
 class SeqRecModel(_DeviceServedModel):
-    """User vectors + the tied item embedding table, served through the
-    standard factor-store top-k path (``choose_server`` ->
-    ``DeviceTopK`` on device backends) exactly like an ALS model — plus
-    the encoder parameters, so fold-in can RE-ENCODE a user's sequence
-    instead of re-solving a linear system."""
+    """User vectors + the OUTPUT table (the tied item embedding table
+    of a SASRec block, the separate ``out_emb`` of an untied backbone),
+    served through the standard factor-store top-k path
+    (``choose_server`` -> ``DeviceTopK`` on device backends) exactly
+    like an ALS model — plus the encoder parameters, so fold-in can
+    RE-ENCODE a user's sequence instead of re-solving a linear
+    system."""
 
     user_vectors: np.ndarray      # [N, R]
-    item_vectors: np.ndarray      # [M, R] == theta["item_emb"]
+    item_vectors: np.ndarray      # [M, R]: output_table(theta)[:M]
     user_map: StringIndexBiMap
     item_map: StringIndexBiMap
     seen: Dict[int, np.ndarray]
@@ -372,22 +395,38 @@ class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
               pd: PreparedSequences) -> SeqRecModel:
         import jax
 
+        from predictionio_tpu.utils import tracing
+
         p = dataclasses.replace(self.params,
                                 max_seq_len=pd.max_seq_len) \
             if self.params.max_seq_len != pd.max_seq_len else self.params
-        theta, losses = train_seqrec(pd.buckets, len(pd.item_map), p)
-        # a mesh means the sequence-parallel kernels encode (ring /
-        # Ulysses selected per length class; the same topology policy
-        # as train_als_auto's single-host branch)
-        mesh = None
-        if len(jax.devices()) > 1 and p.sp_mode != "off":
-            from predictionio_tpu.parallel.mesh import data_parallel_mesh
+        # one local root per call (a child span inside `pio train`'s
+        # root): stage / steps / encode_users / fetch. The parameters
+        # stay on the device from the first step to the last encode
+        # call; one transfer at the end brings model and vectors down.
+        with tracing.trace_scope("seq.train", slow_exempt=True):
+            theta, losses = train_seqrec(pd.buckets, len(pd.item_map), p,
+                                         to_host=False)
+            # a mesh means the sequence-parallel kernels encode (ring /
+            # Ulysses selected per length class; the same topology
+            # policy as train_als_auto's single-host branch)
+            mesh = None
+            if len(jax.devices()) > 1 and p.sp_mode != "off" \
+                    and not isinstance(pd.buckets, PackedRows):
+                from predictionio_tpu.parallel.mesh import (
+                    data_parallel_mesh,
+                )
 
-            mesh = data_parallel_mesh()
-        U = encode_users(theta, pd.buckets, len(pd.user_map), p,
-                         mesh=mesh)
-        return SeqRecModel(U, theta["item_emb"], pd.user_map,
-                           pd.item_map, pd.seen, theta, p,
+                mesh = data_parallel_mesh()
+            with tracing.span("seq.encode_users"):
+                U = encode_users(theta, pd.buckets, len(pd.user_map), p,
+                                 mesh=mesh, to_host=False)
+                jax.block_until_ready(U)
+            with tracing.span("seq.fetch"):
+                theta, U = jax.device_get((theta, U))
+        self.last_losses = losses
+        return SeqRecModel(U, output_table(theta)[:len(pd.item_map)],
+                           pd.user_map, pd.item_map, pd.seen, theta, p,
                            pd.max_seq_len)
 
     def batch_predict(self, ctx: ComputeContext, model: SeqRecModel,

@@ -926,6 +926,26 @@ TRAIN_CHUNK_SECONDS = REGISTRY.histogram(
     "Wall time of one checkpoint chunk (iteration scan + objective "
     "sample + checkpoint write)", (), buckets=LONG_BUCKETS)
 
+# -- the sequence lane's trainer --------------------------------------------
+SEQ_TRAIN_TARGETS = REGISTRY.counter(
+    "pio_seq_train_targets_total",
+    "Next-item targets scored by the sequence trainer's steps (real "
+    "positions with a successor in their own history; pads not "
+    "counted)", ())
+SEQ_PACK_PAD_SHARE = REGISTRY.gauge(
+    "pio_seq_pack_pad_share",
+    "Share of the slots of the last packed layout that hold no token", ())
+SEQ_EXPERT_LOAD = REGISTRY.gauge(
+    "pio_seq_expert_tokens_per_step",
+    "(token, expert) pairs one expert received in a step of the last "
+    "train call, mean over its steps: the busiest expert (max) and the "
+    "average expert (mean)", ("stat",))
+SEQ_DROPPED_TOKENS = REGISTRY.counter(
+    "pio_seq_dropped_tokens_total",
+    "(token, expert) pairs the dropless dispatch did not compute "
+    "(always 0; the trainer asserts it)", ())
+
+
 
 class BoundedLabel:
     """Cap the distinct values a CLIENT-CONTROLLED label may mint.

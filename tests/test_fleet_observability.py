@@ -688,9 +688,16 @@ class TestBalancerObservability:
             else:  # same socket: keep-alive held across requests
                 assert id(conn.sock) == sock_id
         conn.close()
-        after = metrics.HTTP_REQUESTS.value(
-            server="balancer", route="/queries.json", method="POST",
-            status="200")
+        # a request is counted after its response has gone out, so the
+        # third count can land a moment after the client has its answer
+        deadline = time.time() + 5.0
+        while True:
+            after = metrics.HTTP_REQUESTS.value(
+                server="balancer", route="/queries.json", method="POST",
+                status="200")
+            if after - before >= 3 or time.time() > deadline:
+                break
+            time.sleep(0.01)
         assert after - before == 3
         lat = metrics.REGISTRY.snapshot()["pio_http_request_seconds"]
         assert any(e["labels"] == {"server": "balancer",
