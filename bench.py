@@ -391,12 +391,14 @@ def als_precision_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
             static_argnames=("lam", "alpha", "implicit",
                              "num_iterations", "block", "solver",
                              "precision", "refine"))
+        tables = (X0, Y0,
+                  user_side.cols, user_side.weights, user_side.mask,
+                  item_side.cols, item_side.weights, item_side.mask)
         lowered = fn.lower(
-            X0, Y0, user_side.cols, user_side.weights, user_side.mask,
-            item_side.cols, item_side.weights, item_side.mask,
-            lam=LAMBDA, alpha=ALPHA, implicit=True,
+            *tables, lam=LAMBDA, alpha=ALPHA, implicit=True,
             num_iterations=iterations, block=None,
-            solver=_spd_solver_mode(), precision=mode, refine=False)
+            solver=_spd_solver_mode(rank, tables), precision=mode,
+            refine=False)
         t0 = time.perf_counter()
         compiled = lowered.compile()
         compile_sec = time.perf_counter() - t0

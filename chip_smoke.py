@@ -413,8 +413,13 @@ class Smoke:
               and context["nItems"] <= self.cfg["n_items"],
               f"trained table {context['nUsers']} x {context['nItems']}")
         if self.on_tpu:
-            check(train["solver"] == "lanes",
-                  f"solver {train['solver']!r}, expected 'lanes' on tpu")
+            # rank 64 on one chip takes the Pallas kernel; the sharded
+            # trainer of several chips keeps lanes (ops/als.py,
+            # _resolve_spd_solver: this process never imports jax)
+            want = "pallas" if self.device["count"] == 1 else "lanes"
+            check(train["solver"] == want,
+                  f"solver {train['solver']!r}, expected {want!r} on "
+                  f"{self.device['count']} tpu device(s)")
             per_dev = train["hbmBytesInUsePerDevice"]
             check(per_dev is not None
                   and len(per_dev) == self.device["count"]

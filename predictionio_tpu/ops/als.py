@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import dataclasses
 import time as _time
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -727,48 +727,120 @@ def _assemble_bf16(Y, Yg, weights, mask, lam, alpha, implicit: bool,
     return A, b
 
 
-def _spd_solver_mode() -> str:
-    """``lanes`` (batch-on-lanes blocked Cholesky, the TPU default),
-    ``cho`` (LAPACK-backed cho_solve — CPU/GPU default), or ``pallas``
-    (experimental kernel, ops/als_pallas.py). ``PIO_ALS_SOLVER``
-    overrides; an unknown value raises instead of being silently
-    ignored. Resolved ONCE per ``train_als*`` call and passed down as a
-    static jit argument — never read at trace time, so changing the env
-    var between trainings always takes effect (a trace-time read would
-    be baked into the module-level jit caches forever)."""
+SOLVER_MODES = ("lanes", "cho", "xla", "pallas")
+
+
+class SolverChoice(NamedTuple):
+    """What :func:`_resolve_spd_solver` decided: the ``name`` of the
+    solver that runs, and whether that is ``lanes`` only because the
+    Pallas kernel could not take the systems (``fell_back``)."""
+
+    name: str
+    fell_back: bool
+
+
+def _devices_spanned(operands) -> int:
+    """The most devices any array in the ``operands`` pytree lives on
+    (sharded or replicated alike): more than one makes the jit that
+    reads it a partitioned program. Host arrays and abstract shapes
+    count as one."""
+    import jax
+
+    n = 1
+    for a in jax.tree_util.tree_leaves(operands):
+        sharding = getattr(a, "sharding", None)
+        if sharding is not None:
+            n = max(n, len(sharding.device_set))
+    return n
+
+
+def _resolve_spd_solver(rank: int, operands) -> SolverChoice:
+    """The solver that RUNS for systems of this ``rank`` in the program
+    that reads ``operands`` (the arrays the caller is about to hand its
+    jit): ``pallas`` (``ops/als_pallas.py::spd_solve``), ``lanes``
+    (:func:`spd_solve_lanes`) or ``cho`` (LAPACK-backed ``cho_solve``).
+
+    ``PIO_ALS_SOLVER`` asks (``lanes``, ``cho`` or its alias ``xla``,
+    ``pallas``; an unknown value raises instead of being silently
+    ignored), else the platform does: the Pallas kernel on a TPU,
+    ``cho`` on CPU/GPU. The kernel yields to ``lanes`` where it cannot
+    take the systems: above ``als_pallas.SPD_MAX_RANK`` (its three
+    ``[R, R, 128]`` VMEM buffers no longer fit), and where any operand
+    spans more than one device, because the jit is then a partitioned
+    program and the TPU compiler refuses a Mosaic call there ("cannot
+    be automatically partitioned. Please wrap the call in a
+    shard_map"): the sharded trainers' tables, and fold-in against a
+    serving store sharded or replicated over a mesh. The device count
+    is read off the operands here so that no caller has to remember it.
+
+    Resolved ONCE per ``train_als*`` / ``fold_in_users`` call and passed
+    down as a static jit argument — never read at trace time, so
+    changing the env var between trainings always takes effect — and
+    the same name goes into the checkpoint fingerprint, the run log and
+    the ``als.iterations`` span."""
     import os
 
-    forced = os.environ.get("PIO_ALS_SOLVER", "").strip().lower()
-    if forced:
-        if forced not in ("lanes", "cho", "xla", "pallas"):
+    mode = os.environ.get("PIO_ALS_SOLVER", "").strip().lower()
+    if mode:
+        if mode not in SOLVER_MODES:
             raise ValueError(
-                f"PIO_ALS_SOLVER={forced!r} is not a known solver mode "
-                f"(expected one of: lanes, cho, xla, pallas)")
-        return "cho" if forced == "xla" else forced
+                f"PIO_ALS_SOLVER={mode!r} is not a known solver mode "
+                f"(expected one of: {', '.join(SOLVER_MODES)})")
+        if mode == "xla":
+            mode = "cho"
+    else:
+        import jax
+
+        mode = "pallas" if jax.default_backend() == "tpu" else "cho"
+    if mode == "pallas":
+        from predictionio_tpu.ops import als_pallas
+
+        if int(rank) > als_pallas.SPD_MAX_RANK \
+                or _devices_spanned(operands) > 1:
+            return SolverChoice("lanes", True)
+    return SolverChoice(mode, False)
+
+
+def _spd_solver_mode(rank: int, operands) -> str:
+    """:func:`_resolve_spd_solver`'s name alone, for callers with no
+    span to tell of a fallback."""
+    return _resolve_spd_solver(rank, operands).name
+
+
+def solve_span_attributes(choice: SolverChoice, systems: int) -> dict:
+    """What a span round a batch of solves says of them: the resolved
+    ``solver``, the ``solve_systems`` it was handed (padded rows
+    included) and how many of those took ``lanes`` only because the
+    Pallas kernel could not (``solve_systems_fallback``)."""
+    return {"solver": choice.name, "solve_systems": int(systems),
+            "solve_systems_fallback":
+                int(systems) if choice.fell_back else 0}
+
+
+def _spd_solve(A, b, mode: str):
+    """Batched SPD solve of ``A [B, R, R] x = b [B, R]`` by the solver
+    :func:`_resolve_spd_solver` named (this runs under the callers'
+    jits, where nothing can be resolved any more).
+
+    On a TPU XLA's batched ``cho_factor``/``cho_solve`` round-trips the
+    whole matrix batch through HBM on every column (129 ms per 16,384
+    rank-64 systems on a v5e) and :func:`spd_solve_lanes` once a panel
+    (29.6 ms); the Pallas kernel keeps 128 systems in VMEM for all R
+    steps (4.5 ms alone, 3.7 in the training program), so it is the
+    default there up to
+    ``als_pallas.SPD_MAX_RANK``. CPU/GPU keep LAPACK-backed cho_solve."""
     import jax
 
-    return "lanes" if jax.default_backend() == "tpu" else "cho"
-
-
-def _spd_solve(A, b, mode: Optional[str] = None):
-    """Batched SPD solve of ``A [B, R, R] x = b [B, R]``.
-
-    On TPU, XLA's batched ``cho_factor``/``cho_solve`` is the measured
-    ALS epoch bottleneck (~1.1 s for 138k rank-64 systems — its
-    per-column while-loop round-trips the whole matrix batch through
-    HBM every step), so the default there is :func:`spd_solve_lanes`.
-    CPU/GPU keep LAPACK-backed cho_solve."""
-    import jax
-
-    if mode is None:
-        mode = _spd_solver_mode()
     R = b.shape[-1]
     if mode == "pallas":
         from predictionio_tpu.ops import als_pallas
 
-        if R <= als_pallas.SPD_MAX_RANK:
-            return als_pallas.spd_solve(A, b).astype(b.dtype)
-        mode = "lanes"
+        if R > als_pallas.SPD_MAX_RANK:
+            raise ValueError(
+                f"the Pallas SPD kernel takes rank <= "
+                f"{als_pallas.SPD_MAX_RANK}, got {R}: resolve the solver "
+                f"with _resolve_spd_solver")
+        return als_pallas.spd_solve(A, b).astype(b.dtype)
     if mode == "lanes":
         return spd_solve_lanes(A, b).astype(b.dtype)
     chol = jax.scipy.linalg.cho_factor(A)
@@ -968,8 +1040,9 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
                          extra_ridge=None):
     """One alternating half-step over length buckets: each bucket is a
     batched solve at its own ``L`` (one Gram matrix shared by all), and
-    the results scatter into the full factor matrix. Rows in no bucket
-    (no ratings) keep zero factors — same as ``zero_empty_rows``.
+    the results scatter into the full factor matrix, all buckets in one
+    scatter. Rows in no bucket (no ratings) keep zero factors — same as
+    ``zero_empty_rows``.
 
     ``buckets`` is a sequence of ``(row_ids, cols, weights, mask)``
     array tuples (a pytree — this function runs under jit). A bucket
@@ -988,7 +1061,7 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
             gram = jnp.matmul(Y.T, Y,
                               precision=jax.lax.Precision.HIGHEST) \
                 if implicit else None
-    X = jnp.zeros((n_rows_out, R), Y.dtype)
+    ids, solved = [], []
     for row_ids, cols, w, m in buckets:
         B, L = cols.shape
         if slot_budget and B * L > slot_budget:
@@ -1015,10 +1088,21 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
         else:
             Xb = _solve_rows(Y, cols, w, m, lam, alpha, implicit, gram,
                              solver, precision, refine, extra_ridge)
-        # pad rows carry the sentinel row_id == n_rows_out -> dropped
-        with jax.named_scope("scatter"):
-            X = X.at[row_ids].set(Xb, mode="drop")
-    return X
+        ids.append(row_ids)
+        solved.append(Xb)
+    # ONE scatter a half-step, after the last solve: the new factor
+    # matrix is then born after the last Mosaic call, and the compiler
+    # keeps it in VMEM for the next half-step's gathers (scattered into
+    # bucket by bucket its buffer lives across the kernels, stays in
+    # HBM, and the item step's gathers run at a seventh of the speed:
+    # PERF.md section 6, PR 26). Pad rows carry the sentinel row_id ==
+    # n_rows_out -> dropped
+    X = jnp.zeros((n_rows_out, R), Y.dtype)
+    if not buckets:  # a side with no ratings at all
+        return X
+    with jax.named_scope("scatter"):
+        return X.at[jnp.concatenate(ids)].set(jnp.concatenate(solved),
+                                              mode="drop")
 
 
 def _als_iterations_bucketed_impl(X, Y, u_buckets, i_buckets, *, lam,
@@ -1190,13 +1274,22 @@ def _als_iterations_grid(*args, **kw):
     return jitted(*args, **kw)
 
 
+def _bucket_tables(*sides: BucketedRatings) -> tuple:
+    """Each side's buckets as the ``(row_ids, cols, weights, mask)``
+    tuples the jitted loops take: one tuple of tuples a side."""
+    return tuple(tuple((b.row_ids, b.cols, b.weights, b.mask)
+                       for b in s.buckets) for s in sides)
+
+
 def _grid_call_args(user_side: BucketedRatings,
                     item_side: BucketedRatings, configs,
                     precision: str, abstract: bool = False,
                     num_iterations: Optional[int] = None):
     """The exact (args, static kwargs) grid training passes to
     :func:`_als_iterations_grid` — shared with the AOT warm-up so a
-    warmed grid signature is guaranteed to match the real call.
+    warmed grid signature is guaranteed to match the real call. The
+    solver is resolved here, at the widest rank (the narrower configs
+    are padded to it).
     ``configs`` is the ConfigGrid's resolved ALSParams sequence; shared
     statics (implicit/precision/iterations/...) come from ``configs[0]``
     (the ConfigGrid constructor enforces they are uniform)."""
@@ -1217,9 +1310,8 @@ def _grid_call_args(user_side: BucketedRatings,
         return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype) \
             if abstract else a
 
-    as_tuples = lambda s: tuple(  # noqa: E731
-        (leaf(b.row_ids), leaf(b.cols), leaf(b.weights), leaf(b.mask))
-        for b in s.buckets)
+    tables = _bucket_tables(user_side, item_side)
+    u_t, i_t = jax.tree_util.tree_map(leaf, tables)
     if abstract:
         dt = factor_dtype(precision)
         X = jax.ShapeDtypeStruct((k, user_side.n_rows, r_max), dt)
@@ -1232,8 +1324,7 @@ def _grid_call_args(user_side: BucketedRatings,
         X = Y = None  # caller inits real factors
         lam, alpha = jnp.asarray(lam), jnp.asarray(alpha)
         ridge = jnp.asarray(ridge)
-    args = (X, Y, lam, alpha, ridge,
-            as_tuples(user_side), as_tuples(item_side))
+    args = (X, Y, lam, alpha, ridge, u_t, i_t)
     kw = dict(
         implicit=bool(base.implicit_prefs),
         num_iterations=int(base.num_iterations
@@ -1241,7 +1332,7 @@ def _grid_call_args(user_side: BucketedRatings,
                            else num_iterations),
         slot_budget=None if not base.bucket_slot_budget
         else int(base.bucket_slot_budget),
-        solver=_spd_solver_mode(), precision=precision,
+        solver=_spd_solver_mode(r_max, tables), precision=precision,
         refine=bool(base.solve_refine))
     return args, kw
 
@@ -1527,10 +1618,14 @@ def _checkpoint_chunk_lengths(params: ALSParams) -> tuple:
 def _bucketed_call_args(user_side: BucketedRatings,
                         item_side: BucketedRatings, params: ALSParams,
                         precision: str, abstract: bool = False,
-                        num_iterations: Optional[int] = None):
+                        num_iterations: Optional[int] = None,
+                        solver: Optional[str] = None):
     """The exact (args, static kwargs) train_als_bucketed passes to the
     jitted loop — shared with the AOT warm-up so a warmed signature is
-    guaranteed to match the real call. ``abstract=True`` replaces every
+    guaranteed to match the real call. The solver is resolved here from
+    ``params.rank`` and these sides' tables, unless the caller already
+    holds :func:`_resolve_spd_solver`'s answer for them (``solver``).
+    ``abstract=True`` replaces every
     array with its ShapeDtypeStruct. ``num_iterations`` overrides the
     params value — the chunked checkpoint loop dispatches
     chunk-length scans, and the warm-up lowers the same lengths."""
@@ -1540,16 +1635,15 @@ def _bucketed_call_args(user_side: BucketedRatings,
         return jax.ShapeDtypeStruct(tuple(a.shape), a.dtype) \
             if abstract else a
 
-    as_tuples = lambda s: tuple(  # noqa: E731
-        (leaf(b.row_ids), leaf(b.cols), leaf(b.weights), leaf(b.mask))
-        for b in s.buckets)
+    tables = _bucket_tables(user_side, item_side)
+    u_t, i_t = jax.tree_util.tree_map(leaf, tables)
     if abstract:
         dt = factor_dtype(precision)
         X = jax.ShapeDtypeStruct((user_side.n_rows, int(params.rank)), dt)
         Y = jax.ShapeDtypeStruct((item_side.n_rows, int(params.rank)), dt)
     else:
         X = Y = None  # caller inits real factors
-    args = (X, Y, as_tuples(user_side), as_tuples(item_side))
+    args = (X, Y, u_t, i_t)
     kw = dict(
         lam=float(params.lambda_), alpha=float(params.alpha),
         implicit=bool(params.implicit_prefs),
@@ -1558,8 +1652,8 @@ def _bucketed_call_args(user_side: BucketedRatings,
                            else num_iterations),
         slot_budget=None if not params.bucket_slot_budget
         else int(params.bucket_slot_budget),
-        solver=_spd_solver_mode(), precision=precision,
-        refine=bool(params.solve_refine))
+        solver=solver or _spd_solver_mode(params.rank, tables),
+        precision=precision, refine=bool(params.solve_refine))
     return args, kw
 
 
@@ -1661,14 +1755,21 @@ def train_als_bucketed(user_side: BucketedRatings,
             # call's signature; the tables go up here (a no-op for sides
             # already staged with .to_device()) and are waited for, so
             # the upload is this span's and not the first iteration's
+            u_dev, i_dev = user_side.to_device(), item_side.to_device()
+            choice = _resolve_spd_solver(params.rank,
+                                         _bucket_tables(u_dev, i_dev))
             (_, _, u_t, i_t), kw = _bucketed_call_args(
-                user_side.to_device(), item_side.to_device(), params,
-                precision)
+                u_dev, i_dev, params, precision, solver=choice.name)
             jax.block_until_ready((X, Y))
             ckpt = _maybe_checkpointer(
                 checkpoint_layout_bucketed(user_side, item_side), params,
                 kw["solver"], precision, dtype)
-        with _tracing.span("als.iterations"):
+        # every padded bucket row is one system a half-step (two with
+        # the refinement pass)
+        systems = sum(int(t[1].shape[0]) for t in u_t + i_t) \
+            * kw["num_iterations"] * (2 if kw["refine"] else 1)
+        with _tracing.span("als.iterations", attributes=(
+                solve_span_attributes(choice, systems))):
             compile_s0 = _metrics.JIT_COMPILE_SECONDS.value()
             t0 = _tracing.span_now()
             if ckpt is None:
@@ -1785,7 +1886,9 @@ def train_als(user_side: PaddedRatings, item_side: PaddedRatings,
     i_cols = jnp.asarray(item_side.cols)
     i_w = jnp.asarray(item_side.weights)
     i_m = jnp.asarray(item_side.mask)
-    solver = _spd_solver_mode()  # resolved per call, never at trace
+    # resolved per call, never at trace
+    solver = _spd_solver_mode(
+        params.rank, (X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m))
     kw = dict(
         lam=float(params.lambda_), alpha=float(params.alpha),
         implicit=bool(params.implicit_prefs),
@@ -1957,10 +2060,12 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
         return np.zeros((0, Y.shape[1]), dtype=np.float32)
     cols, weights, mask = pad_fold_in_batch(cols_list, vals_list,
                                             max_len=max_len)
+    # a serving store's Y may live on a whole mesh: the resolver sees it
+    choice = _resolve_spd_solver(Y.shape[1], (Y, cols, weights, mask))
     fold_kwargs = dict(
         lam=float(params.lambda_), alpha=float(params.alpha),
         implicit=bool(params.implicit_prefs),
-        solver=_spd_solver_mode(), precision=precision,
+        solver=choice.name, precision=precision,
         refine=bool(params.solve_refine))
     from predictionio_tpu.utils import device_telemetry as _dtel
 
@@ -1991,7 +2096,10 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
             host_us=(t2m - t0m) * 1e6, device_us=(t2m - t1m) * 1e6)
         _tracing.record_completed_span(
             "device.execute", start=t0e, end=t0e + (t2m - t0m),
-            attributes=None if rec is None else dict(rec))
+            attributes=dict(
+                rec or {}, **solve_span_attributes(
+                    choice, int(cols.shape[0])
+                    * (2 if fold_kwargs["refine"] else 1))))
     return np.asarray(out[:k], dtype=np.float32)
 
 

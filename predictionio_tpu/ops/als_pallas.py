@@ -5,15 +5,24 @@ Three kernels live here:
 1. ``spd_solve`` — batched symmetric positive-definite solve (Cholesky
    factorization + forward/backward triangular substitution fused in
    one kernel, batch on the lane dimension, matrices resident in VMEM
-   across all R steps). STATUS — off the default path, opt-in via
-   ``PIO_ALS_SOLVER=pallas``; the TPU default is the pure-XLA
-   batch-on-lanes blocked panel factorization
-   ``ops.als.spd_solve_lanes``. It compiles through Mosaic and
-   agrees with ``spd_solve_lanes`` and XLA's ``cho_solve`` on a TPU v5e
-   at rank 64, and is the fastest of the three on the solve alone
-   (PERF.md section 5, "Off-path kernels"). Its share of a training
-   epoch has not been measured; whether it replaces ``lanes`` is
-   ROADMAP Design 4's decision.
+   across all R steps). STATUS — the TPU default of every ALS trainer
+   entry point and of fold-in for rank <= ``SPD_MAX_RANK`` since PR 26
+   (``ops.als._resolve_spd_solver``); above that rank, and in every
+   program partitioned over several devices (the sharded trainers,
+   fold-in against a sharded serving store: the TPU compiler refuses
+   to partition a Mosaic call), the pure-XLA batch-on-lanes panel
+   factorization ``ops.als.spd_solve_lanes`` runs, and off the TPU
+   LAPACK's ``cho_solve``. ``PIO_ALS_SOLVER`` still forces any of the
+   three. A checkpoint carries the solver's name in its fingerprint,
+   so one written under ``lanes`` is refused on resume under
+   ``pallas`` (``CheckpointMismatchError``). Runs through Mosaic on a
+   v5e at ranks 8, 10, 20, 50, 64 and 96 and agrees with LAPACK there
+   (rank 96 needs 18.3 MiB of VMEM and asks for it by name); in the
+   ML-20M training program 16,384 rank-64 systems take 3.7 ms where
+   ``lanes`` took 29.6, 13% of an iteration that is now mostly gather
+   and assembly (PERF.md sections 5 and 6, PR 26). Each of the R
+   steps updates the whole ``R x R`` block where only the trailing
+   part is live: the open item of PERF.md section 7.
 
 2. ``assemble_normal_equations`` — fused gather + normal-equation
    assembly. STATUS: correct, slow, not the default. On the chip it
@@ -175,6 +184,8 @@ def assemble_normal_equations(Y, cols, aw, bw, gram,
 # systems per grid step == the lane width: each per-step scalar (pivot,
 # reciprocal sqrt, substitution coefficient) is a [BB]-lane vector
 _SPD_BB = 128
+# room beside the kernel's own buffers for Mosaic's internal scratch
+_SPD_VMEM_MARGIN = 4 << 20
 
 
 def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, lt, ywork, bwork):
@@ -245,6 +256,12 @@ def _build_spd(B: int, R: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     assert B % _SPD_BB == 0
+    # what the kernel holds in VMEM, to the byte: the [R, R, BB] input
+    # block twice (the pipeline double-buffers it), awork and lt once,
+    # b and x twice, ywork and bwork once. Rank 96 needs 18.3 MiB, over
+    # the compiler's default scoped limit of 16; smaller ranks ask for
+    # less than the default and leave the rest to the program around
+    vmem_bytes = 4 * _SPD_BB * (4 * R * R + 6 * R)
     fn = pl.pallas_call(
         _spd_solve_kernel,
         grid=(B // _SPD_BB,),
@@ -260,6 +277,8 @@ def _build_spd(B: int, R: int, interpret: bool):
             pltpu.VMEM((R, _SPD_BB), jnp.float32),      # ywork
             pltpu.VMEM((R, _SPD_BB), jnp.float32),      # bwork
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_bytes + _SPD_VMEM_MARGIN),
         interpret=interpret,
         name="spd_solve",
     )
@@ -267,7 +286,7 @@ def _build_spd(B: int, R: int, interpret: bool):
 
 
 # above this rank the three [R, R, BB] VMEM buffers exceed scoped VMEM;
-# callers fall back to XLA's cho_solve (see ops.als._spd_solve)
+# ops.als._resolve_spd_solver names spd_solve_lanes there
 SPD_MAX_RANK = 96
 
 

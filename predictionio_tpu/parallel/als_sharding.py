@@ -301,7 +301,11 @@ def _train_sharded(user_side: PaddedRatings, item_side: PaddedRatings,
     step = _jit_step(mesh, factor_spec)
     kw = dict(lam=float(params.lambda_), alpha=float(params.alpha),
               implicit=bool(params.implicit_prefs),
-              solver=_spd_solver_mode(),  # resolved per call
+              # resolved per call; the placed tables tell the resolver
+              # how many devices the program is partitioned over
+              solver=_spd_solver_mode(
+                  params.rank,
+                  (X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m)),
               precision=precision, refine=bool(params.solve_refine))
 
     def run_iters(Xc, Yc, n):
@@ -537,7 +541,9 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
               implicit=bool(params.implicit_prefs),
               slot_budget=None if not params.bucket_slot_budget
               else int(params.bucket_slot_budget),
-              solver=_spd_solver_mode(),  # resolved per call
+              # resolved per call; the placed tables tell the resolver
+              # how many devices the program is partitioned over
+              solver=_spd_solver_mode(params.rank, (X, Y, u_t, i_t)),
               precision=precision, refine=bool(params.solve_refine))
 
     def run_iters(Xc, Yc, n):
@@ -652,18 +658,16 @@ def sharded_train_step(mesh, rank: int, params: Optional[ALSParams] = None):
         # — under bf16 the step must actually exercise the half-width
         # gather, not a mongrel fp32-store/bf16-weights lane
         fdt = factor_dtype(precision)
-        return fn(put(jnp.asarray(X, dtype=fdt), replicated),
+        placed = (put(jnp.asarray(X, dtype=fdt), replicated),
                   put(jnp.asarray(Y, dtype=fdt), replicated),
-                  put(jnp.asarray(u_cols), row_sharded),
-                  put(jnp.asarray(u_w), row_sharded),
-                  put(jnp.asarray(u_m), row_sharded),
-                  put(jnp.asarray(i_cols), row_sharded),
-                  put(jnp.asarray(i_w), row_sharded),
-                  put(jnp.asarray(i_m), row_sharded),
+                  *(put(jnp.asarray(a), row_sharded)
+                    for a in (u_cols, u_w, u_m, i_cols, i_w, i_m)))
+        return fn(*placed,
                   lam=float(params.lambda_), alpha=float(params.alpha),
                   implicit=bool(params.implicit_prefs),
                   num_iterations=1,
-                  solver=_spd_solver_mode(),  # resolved per call
+                  # resolved per call, from what was just placed
+                  solver=_spd_solver_mode(params.rank, placed),
                   precision=precision,
                   refine=bool(params.solve_refine))
 

@@ -120,7 +120,8 @@ class TestPolicyPlumbing:
             jnp.asarray(us.mask), jnp.asarray(its.cols),
             jnp.asarray(its.weights), jnp.asarray(its.mask),
             lam=0.01, alpha=1.0, implicit=True, num_iterations=1,
-            block=None, solver=_spd_solver_mode(), precision=precision,
+            block=None, solver=_spd_solver_mode(8, (X, Y)),
+            precision=precision,
             refine=False)
         assert X.is_deleted() and Y.is_deleted()
         assert np.isfinite(np.asarray(Xn, dtype=np.float32)).all()
@@ -137,7 +138,7 @@ class TestPolicyPlumbing:
         Xn, _ = _als_iterations_bucketed(
             X, Y, as_tuples(ub), as_tuples(ib),
             lam=0.01, alpha=1.0, implicit=True, num_iterations=1,
-            slot_budget=None, solver=_spd_solver_mode(),
+            slot_budget=None, solver=_spd_solver_mode(8, (X, Y)),
             precision="fp32", refine=False)
         assert X.is_deleted() and Y.is_deleted()
         assert np.isfinite(np.asarray(Xn)).all()

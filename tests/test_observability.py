@@ -200,9 +200,17 @@ class TestEventServerMetrics:
         addr = event_server.address
         raw_request(addr, "GET", f"/events/ev-123.json?accessKey={KEY}")
         raw_request(addr, "GET", "/totally/made/up")
-        samples, _ = scrape(addr)
-        routes = {dict(k[1]).get("route") for k in samples
-                  if k[0] == "pio_http_requests_total"}
+        # a handler counts its request after the response is written, on
+        # its own thread: on a loaded machine the scrape can get there
+        # first, so give the count a moment
+        deadline = time.monotonic() + 5.0
+        while True:
+            samples, _ = scrape(addr)
+            routes = {dict(k[1]).get("route") for k in samples
+                      if k[0] == "pio_http_requests_total"}
+            if "<other>" in routes or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
         assert "/events/<id>.json" in routes
         assert "<other>" in routes
         assert not any(r and "ev-123" in r for r in routes)

@@ -335,3 +335,120 @@ def test_v5e_sharded_user_program_does_not_copy_the_bitmap(
         srv.close()
     assert "tpu_custom_call" in _assert_bitmap_read_in_place(
         compiled, n_users, words)
+
+
+# ---------------------------------------------------------------------------
+# the ALS trainer's Pallas solve on the described chip (PR 26; kept in
+# this file because the topology may be described in one file only)
+# ---------------------------------------------------------------------------
+
+# B = 300 is three grid steps, so the input block is double-buffered:
+# at rank 96 the kernel then needs 18.3 MiB of VMEM, over the compiler's
+# default scoped limit (the chip refused it until the kernel asked for
+# its bytes by name); rank 10 is a sublane count off the multiple of 8
+@pytest.mark.parametrize("rank", [8, 10, 64, 96])
+def test_v5e_spd_solve_lowers_at_every_template_rank(rank, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als_pallas
+
+    assert rank <= als_pallas.SPD_MAX_RANK
+    compiled = jax.jit(
+        lambda A, b: als_pallas.spd_solve(A, b, interpret=False)).lower(
+        jax.ShapeDtypeStruct((300, rank, rank), jnp.float32,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((300, rank), jnp.float32,
+                             sharding=one_chip)).compile()
+    assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+# the padded bucket tables of cell rec-ml20m.train (138,000 x 27,000,
+# 17.5M pairs): (rows, slots) a bucket, users then items
+ML20M_BUCKETS = (
+    ((8, 32), (30224, 64), (75128, 128), (23088, 256), (6840, 512),
+     (1976, 1024), (560, 2048), (160, 4096), (40, 8192), (16, 16384),
+     (8, 18480)),
+    ((11272, 256), (9192, 512), (3808, 1024), (1608, 2048), (672, 4096),
+     (280, 8192), (112, 16384), (48, 32768), (24, 65536), (8, 127144)))
+
+
+def test_v5e_training_program_keeps_the_factors_in_vmem(one_chip,
+                                                        monkeypatch):
+    """The 5-iteration program of the training cell with the solver a
+    TPU resolves: one Mosaic kernel a bucket, and the user factors born
+    in VMEM (``S(1)``) after the half-step's ONE scatter, so that every
+    item-step gather reads them there. Scattered into bucket by bucket,
+    between the kernels, they stayed in HBM and those gathers ran at a
+    seventh of the rate (PERF.md section 6, PR 26)."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als
+
+    # spd_solve reads "compiled, not interpreted" off the platform
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert als._spd_solver_mode(64, sds((27_000, 64), jnp.float32)) \
+        == "pallas"
+
+    def side(buckets):
+        return tuple((sds((b,), jnp.int32), sds((b, l), jnp.int32),
+                      sds((b, l), jnp.float32), sds((b, l), jnp.float32))
+                     for b, l in buckets)
+
+    compiled = jax.jit(
+        als._als_iterations_bucketed_impl,
+        static_argnames=("lam", "alpha", "implicit", "num_iterations",
+                         "slot_budget", "solver", "precision", "refine"),
+        donate_argnums=(0, 1)).lower(
+        sds((138_000, 64), jnp.float32), sds((27_000, 64), jnp.float32),
+        side(ML20M_BUCKETS[0]), side(ML20M_BUCKETS[1]), lam=0.01,
+        alpha=1.0, implicit=True, num_iterations=5, slot_budget=None,
+        solver="pallas", precision="fp32", refine=False).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 21
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    defs = dict(re.findall(r"^\s*%(\S+) = (\S+)", hlo, re.M))
+    gathers = re.findall(
+        r"^\s*%\S+ = f32\[\d+,64\]\S* fusion\(%(\S+), [^\n]*kind=kCustom"
+        r"[^\n]*item_step/gather", hlo, re.M)
+    assert len(gathers) == 10
+    in_hbm = [t for t in gathers
+              if not (defs[t].startswith("f32[138000,64]")
+                      and "S(1)" in defs[t])]
+    assert not in_hbm, f"item-step gathers read the factors in HBM: {in_hbm}"
+
+
+@pytest.mark.parametrize("spec", [("data", None), (None, None)],
+                         ids=["sharded", "replicated"])
+def test_v5e_fold_in_against_a_store_on_four_chips_keeps_lanes(
+        v5e_2x2, spec, monkeypatch):
+    """``pio deploy --foldin on`` on four chips folds against the
+    store's live item factors, which live on the whole mesh: the jit is
+    a partitioned program, the compiler refuses a Mosaic call in one,
+    and the resolver reads that off ``Y`` and names ``lanes``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from predictionio_tpu.ops import als
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.delenv("PIO_ALS_SOLVER", raising=False)
+    mesh = Mesh(np.asarray(v5e_2x2.devices), ("data",))
+    Y = jax.ShapeDtypeStruct((27_000, 64), jnp.float32,
+                             sharding=NamedSharding(mesh, P(*spec)))
+    host = [jax.ShapeDtypeStruct((8, 64), d)
+            for d in (jnp.int32, jnp.float32, jnp.float32)]
+    kw = dict(lam=0.01, alpha=1.0, implicit=True, precision="fp32",
+              refine=False)
+    assert als._resolve_spd_solver(64, (Y, *host)) == ("lanes", True)
+    als._get_fold_in_jit().lower(Y, *host, solver="lanes", **kw).compile()
+    with pytest.raises(Exception, match="shard_map"):
+        als._get_fold_in_jit().lower(Y, *host, solver="pallas",
+                                     **kw).compile()
