@@ -65,52 +65,44 @@ def synthetic_ratings(n_users: int, n_items: int, nnz: int, seed: int = 7):
 
 def make_sides(n_users: int, n_items: int, nnz: int, seed: int,
                max_len: Optional[int] = None):
-    """Padded solve sides + the entry count the solves actually process
+    """Bucketed solve sides + the entry count the solves actually process
     (post-dedup, post-truncation — the honest throughput denominator)."""
-    from predictionio_tpu.ops.als import pad_ratings
+    from predictionio_tpu.ops.als import bucket_ratings_pair
 
     rows, cols, vals = synthetic_ratings(n_users, n_items, nnz, seed)
-    user_side = pad_ratings(rows, cols, vals, n_users, n_items,
-                            max_len=max_len)
-    item_side = pad_ratings(cols, rows, vals, n_items, n_users,
-                            max_len=max_len)
-    processed = int(user_side.mask.sum() + item_side.mask.sum()) // 2
+    user_side, item_side = bucket_ratings_pair(
+        rows, cols, vals, n_users, n_items, max_len=max_len)
+    processed = (user_side.nnz + item_side.nnz) // 2
     return user_side, item_side, processed
 
 
-def to_device(side):
-    """New PaddedRatings whose tables are device arrays (the original —
-    and its numpy annotations — stay untouched)."""
-    import dataclasses
-
-    import jax.numpy as jnp
-
-    return dataclasses.replace(side, cols=jnp.asarray(side.cols),
-                               weights=jnp.asarray(side.weights),
-                               mask=jnp.asarray(side.mask))
-
-
 def numpy_baseline_epoch(user_side, item_side, rank, lam, alpha, seed):
-    """One full alternating epoch with numpy — the same padded batched
-    solves the device runs, on host BLAS threads (the 8-core CPU analog)."""
+    """One full alternating epoch with numpy — the same per-bucket padded
+    batched solves the device runs, on host BLAS threads (the 8-core CPU
+    analog)."""
     rng = np.random.default_rng(seed)
-    X = rng.normal(size=(user_side.n_rows, rank)).astype(np.float32)
     Y = rng.normal(size=(user_side.n_cols, rank)).astype(np.float32)
 
-    def solve_side(Y, cols, weights):
-        w = weights
-        mask = (w > 0).astype(np.float32)
-        Yg = Y[cols]                                   # [B, L, R]
+    def solve_side(Y, side):
         gram = Y.T @ Y
-        corr = np.einsum("bl,blr,bls->brs", alpha * w, Yg, Yg,
-                         optimize=True)
-        A = corr + gram[None] + lam * np.eye(rank, dtype=np.float32)[None]
-        b = np.einsum("bl,blr->br", mask + alpha * w, Yg, optimize=True)
-        return np.linalg.solve(A, b[..., None])[..., 0]
+        X = np.zeros((side.n_rows, rank), dtype=np.float32)
+        for bk in side.buckets:
+            w = bk.weights
+            Yg = Y[bk.cols]                            # [B, L, R]
+            corr = np.einsum("bl,blr,bls->brs", alpha * w, Yg, Yg,
+                             optimize=True)
+            A = corr + gram[None] \
+                + lam * np.eye(rank, dtype=np.float32)[None]
+            b = np.einsum("bl,blr->br", bk.mask + alpha * w, Yg,
+                          optimize=True)
+            real = bk.row_ids < side.n_rows            # drop pad rows
+            X[bk.row_ids[real]] = np.linalg.solve(
+                A, b[..., None])[..., 0][real]
+        return X
 
     t0 = time.perf_counter()
-    X = solve_side(Y, user_side.cols, user_side.weights)
-    Y = solve_side(X, item_side.cols, item_side.weights)
+    X = solve_side(Y, user_side)
+    Y = solve_side(X, item_side)
     return time.perf_counter() - t0
 
 
@@ -118,15 +110,15 @@ def timed_training(user_side, item_side, params, repeats: int = 3):
     """Warm-compile the exact program, then best-of-N full trainings.
     Returns (best_seconds, factors) without an extra run — the last timed
     run's factors are reused for the finiteness check."""
-    from predictionio_tpu.ops.als import train_als
+    from predictionio_tpu.ops.als import train_als_bucketed
 
     # num_iterations is a static arg: a different value is a different
     # XLA program, so warm-up must use the same params
-    train_als(user_side, item_side, params)
+    train_als_bucketed(user_side, item_side, params)
     best, result = float("inf"), None
     for _ in range(repeats):
         t0 = time.perf_counter()
-        result = train_als(user_side, item_side, params)
+        result = train_als_bucketed(user_side, item_side, params)
         best = min(best, time.perf_counter() - t0)
     return best, result
 
@@ -147,14 +139,14 @@ def train_resume_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
     import shutil
     import tempfile
 
-    from predictionio_tpu.ops.als import ALSParams, train_als
+    from predictionio_tpu.ops.als import ALSParams, train_als_bucketed
     from predictionio_tpu.workflow import checkpoint as ckpt_mod
 
     params = ALSParams(rank=RANK, num_iterations=iterations,
                        lambda_=LAMBDA, alpha=ALPHA, seed=seed)
     user_side, item_side, processed = make_sides(n_users, n_items, nnz,
                                                  seed)
-    user_side, item_side = to_device(user_side), to_device(item_side)
+    user_side, item_side = user_side.to_device(), item_side.to_device()
 
     env_keys = ("PIO_CHECKPOINT_DIR", "PIO_CHECKPOINT_EVERY",
                 "PIO_CHECKPOINT_KEEP", "PIO_RESUME")
@@ -165,7 +157,7 @@ def train_resume_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
         def lane_off():
             os.environ.pop("PIO_CHECKPOINT_DIR", None)
             t0 = time.perf_counter()
-            out = train_als(user_side, item_side, params)
+            out = train_als_bucketed(user_side, item_side, params)
             return time.perf_counter() - t0, out
 
         def lane_on():
@@ -174,7 +166,7 @@ def train_resume_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
             os.environ["PIO_CHECKPOINT_KEEP"] = "3"
             try:
                 t0 = time.perf_counter()
-                out = train_als(user_side, item_side, params)
+                out = train_als_bucketed(user_side, item_side, params)
                 return time.perf_counter() - t0, out
             finally:
                 os.environ.pop("PIO_CHECKPOINT_DIR", None)
@@ -212,13 +204,13 @@ def train_resume_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
         ckpt_mod.request_stop()
         preempted = False
         try:
-            train_als(user_side, item_side, params)
+            train_als_bucketed(user_side, item_side, params)
         except ckpt_mod.TrainingPreempted:
             preempted = True
         finally:
             ckpt_mod.clear_stop()
         os.environ["PIO_RESUME"] = "1"
-        X_res, Y_res = train_als(user_side, item_side, params)
+        X_res, Y_res = train_als_bucketed(user_side, item_side, params)
         resumed_equal = bool(preempted
                              and np.array_equal(X_res, X_off)
                              and np.array_equal(Y_res, Y_off))
@@ -265,7 +257,7 @@ def train_telemetry_overhead_bench(
     import shutil
     import tempfile
 
-    from predictionio_tpu.ops.als import ALSParams, train_als
+    from predictionio_tpu.ops.als import ALSParams, train_als_bucketed
     from predictionio_tpu.utils import metrics
     from predictionio_tpu.workflow import runlog
 
@@ -273,7 +265,7 @@ def train_telemetry_overhead_bench(
                        lambda_=LAMBDA, alpha=ALPHA, seed=seed)
     user_side, item_side, processed = make_sides(n_users, n_items, nnz,
                                                  seed)
-    user_side, item_side = to_device(user_side), to_device(item_side)
+    user_side, item_side = user_side.to_device(), item_side.to_device()
 
     env_keys = ("PIO_CHECKPOINT_DIR", "PIO_CHECKPOINT_EVERY",
                 "PIO_CHECKPOINT_KEEP", "PIO_RESUME",
@@ -293,7 +285,7 @@ def train_telemetry_overhead_bench(
                 "1" if telemetry else "0"
             try:
                 t0 = time.perf_counter()
-                out = train_als(user_side, item_side, params)
+                out = train_als_bucketed(user_side, item_side, params)
                 return time.perf_counter() - t0, out
             finally:
                 os.environ.pop("PIO_CHECKPOINT_DIR", None)
@@ -354,7 +346,7 @@ def als_precision_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
     """fp32 vs bf16 ALS training lanes on the headline workload shape.
 
     Per lane: steady-state events/s/chip (best-of-``repeats`` full
-    trainings through the production `train_als` path — donation and
+    trainings through the production `train_als_bucketed` path — donation and
     the per-call policy resolution included), XLA compile time of the
     full iteration program (a FRESH jit per lane; the module-level
     cache would hide it), and a peak-HBM estimate from
@@ -365,15 +357,15 @@ def als_precision_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
 
     from predictionio_tpu.ops.als import (
         ALSParams,
-        _als_iterations_impl,
-        _spd_solver_mode,
+        _als_iterations_bucketed_impl,
+        _bucketed_call_args,
         factor_dtype,
         init_factors,
-        train_als,
+        train_als_bucketed,
     )
 
     user_np, item_np, processed = make_sides(n_users, n_items, nnz, seed)
-    user_side, item_side = to_device(user_np), to_device(item_np)
+    user_side, item_side = user_np.to_device(), item_np.to_device()
     lanes = {}
     for mode in ("fp32", "bf16"):
         params = ALSParams(rank=rank, num_iterations=iterations,
@@ -387,18 +379,13 @@ def als_precision_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
         X0 = X0.astype(factor_dtype(mode))
         Y0 = Y0.astype(factor_dtype(mode))
         fn = jax.jit(
-            _als_iterations_impl,
+            _als_iterations_bucketed_impl,
             static_argnames=("lam", "alpha", "implicit",
-                             "num_iterations", "block", "solver",
+                             "num_iterations", "slot_budget", "solver",
                              "precision", "refine"))
-        tables = (X0, Y0,
-                  user_side.cols, user_side.weights, user_side.mask,
-                  item_side.cols, item_side.weights, item_side.mask)
-        lowered = fn.lower(
-            *tables, lam=LAMBDA, alpha=ALPHA, implicit=True,
-            num_iterations=iterations, block=None,
-            solver=_spd_solver_mode(rank, tables), precision=mode,
-            refine=False)
+        (_, _, u_t, i_t), kw = _bucketed_call_args(
+            user_side, item_side, params, mode)
+        lowered = fn.lower(X0, Y0, u_t, i_t, **kw)
         t0 = time.perf_counter()
         compiled = lowered.compile()
         compile_sec = time.perf_counter() - t0
@@ -413,10 +400,11 @@ def als_precision_bench(n_users: int = N_USERS, n_items: int = N_ITEMS,
             pass  # backend without memory stats: report null, not a lie
 
         best, result = float("inf"), None
-        train_als(user_side, item_side, params)  # warm the module cache
+        # warm the module cache
+        train_als_bucketed(user_side, item_side, params)
         for _ in range(repeats):
             t0 = time.perf_counter()
-            result = train_als(user_side, item_side, params)
+            result = train_als_bucketed(user_side, item_side, params)
             best = min(best, time.perf_counter() - t0)
         X, Y = result
         assert np.isfinite(X).all() and np.isfinite(Y).all()
@@ -3428,7 +3416,7 @@ def main(smoke: bool = False) -> None:
     # rating tables live in HBM for the whole training job (transferred
     # once at ingest) — so epochs measure compute; the numpy originals
     # feed the CPU baseline
-    user_side, item_side = to_device(user_np), to_device(item_np)
+    user_side, item_side = user_np.to_device(), item_np.to_device()
 
     device_total, (X, Y) = timed_training(user_side, item_side, params)
     assert np.isfinite(X).all() and np.isfinite(Y).all()

@@ -184,7 +184,8 @@ def popularity_precision(train_rows: np.ndarray, train_cols: np.ndarray,
 
 def _numpy_solve_side(Y: np.ndarray, cols: np.ndarray, weights: np.ndarray,
                       mask: np.ndarray, lam: float, alpha: float):
-    """Exact numpy mirror of ops.als._solve_side (implicit path)."""
+    """Numpy mirror of one implicit half-step over one padded table
+    (``ops.als._solve_rows`` plus the shared Gram)."""
     R = Y.shape[1]
     w = weights * mask
     aw = alpha * np.abs(w)
@@ -199,21 +200,39 @@ def _numpy_solve_side(Y: np.ndarray, cols: np.ndarray, weights: np.ndarray,
     return X * has_any[:, None]
 
 
-def train_als_numpy(user_side, item_side, rank: int, iterations: int,
-                    lam: float, alpha: float, seed: int
-                    ) -> Tuple[np.ndarray, np.ndarray]:
-    """Full implicit-ALS training with numpy — the CPU reference whose
-    quality the device path must match. Uses the same factor init as the
-    device path so the comparison isolates the solvers, not seed luck."""
+def _padded_side(rows, cols, vals, n_rows: int, n_cols: int):
+    """Dense ``[n_rows, longest]`` cols / weights / mask with duplicate
+    events summed: the layout the numpy trainer walks."""
+    uniq, inv = np.unique(rows.astype(np.int64) * n_cols + cols,
+                          return_inverse=True)
+    w = np.bincount(inv, weights=vals).astype(np.float32)
+    r, c = uniq // n_cols, uniq % n_cols
+    counts = np.bincount(r, minlength=n_rows)
+    slot = np.arange(len(r)) - (np.cumsum(counts) - counts)[r]
+    shape = (n_rows, max(1, int(counts.max())))
+    C = np.zeros(shape, dtype=np.int64)
+    W = np.zeros(shape, dtype=np.float32)
+    M = np.zeros(shape, dtype=np.float32)
+    C[r, slot], W[r, slot], M[r, slot] = c, w, 1.0
+    return C, W, M
+
+
+def train_als_numpy(rows, cols, vals, n_users: int, n_items: int,
+                    rank: int, iterations: int, lam: float, alpha: float,
+                    seed: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Full implicit-ALS training with numpy from the (row, col, value)
+    triples — the CPU reference whose quality the device path must
+    match. Uses the same factor init as the device path so the
+    comparison isolates the solvers, not seed luck."""
     from predictionio_tpu.ops.als import init_factors
 
-    X0, Y0 = init_factors(user_side.n_rows, user_side.n_cols, rank, seed)
+    u = _padded_side(rows, cols, vals, n_users, n_items)
+    i = _padded_side(cols, rows, vals, n_items, n_users)
+    X0, Y0 = init_factors(n_users, n_items, rank, seed)
     X, Y = np.asarray(X0), np.asarray(Y0)
     for _ in range(iterations):
-        X = _numpy_solve_side(Y, user_side.cols, user_side.weights,
-                              user_side.mask, lam, alpha)
-        Y = _numpy_solve_side(X, item_side.cols, item_side.weights,
-                              item_side.mask, lam, alpha)
+        X = _numpy_solve_side(Y, *u, lam, alpha)
+        Y = _numpy_solve_side(X, *i, lam, alpha)
     return X, Y
 
 
@@ -223,18 +242,22 @@ def run(n_users: int = None, n_items: int = None, nnz: int = None,
     main bench embeds. Defaults to the main bench's dataset shape so the
     speed and quality figures always describe the same workload."""
     import bench
-    from predictionio_tpu.ops.als import ALSParams, pad_ratings, train_als
+    from predictionio_tpu.ops.als import (
+        ALSParams,
+        bucket_ratings_pair,
+        train_als_bucketed,
+    )
 
     n_users = n_users if n_users is not None else bench.N_USERS
     n_items = n_items if n_items is not None else bench.N_ITEMS
     nnz = nnz if nnz is not None else bench.NNZ
     rows, cols, vals, held = build_split(n_users, n_items, nnz, seed)
-    user_side = pad_ratings(rows, cols, vals, n_users, n_items)
-    item_side = pad_ratings(cols, rows, vals, n_items, n_users)
+    user_side, item_side = bucket_ratings_pair(rows, cols, vals, n_users,
+                                               n_items)
 
     params = ALSParams(rank=RANK, num_iterations=ITERATIONS, lambda_=LAMBDA,
                        alpha=ALPHA, implicit_prefs=True, seed=3)
-    X_dev, Y_dev = train_als(user_side, item_side, params)
+    X_dev, Y_dev = train_als_bucketed(user_side, item_side, params)
     dev_scores = _masked_scores(np.asarray(X_dev), np.asarray(Y_dev),
                                 rows, cols)
     p_dev = precision_at_k(X_dev, Y_dev, rows, cols, held,
@@ -244,8 +267,8 @@ def run(n_users: int = None, n_items: int = None, nnz: int = None,
     del dev_scores
 
     t0 = time.perf_counter()
-    X_cpu, Y_cpu = train_als_numpy(user_side, item_side, RANK, ITERATIONS,
-                                   LAMBDA, ALPHA, seed=3)
+    X_cpu, Y_cpu = train_als_numpy(rows, cols, vals, n_users, n_items,
+                                   RANK, ITERATIONS, LAMBDA, ALPHA, seed=3)
     cpu_train_sec = time.perf_counter() - t0
     p_cpu = precision_at_k(X_cpu, Y_cpu, rows, cols, held)
 
@@ -256,7 +279,7 @@ def run(n_users: int = None, n_items: int = None, nnz: int = None,
 
     band = [p_dev]  # seed 3: the (deterministic) headline training
     for s in (17, 42):
-        Xs, Ys = train_als(user_side, item_side,
+        Xs, Ys = train_als_bucketed(user_side, item_side,
                            _dc.replace(params, seed=s))
         band.append(precision_at_k(np.asarray(Xs), np.asarray(Ys),
                                    rows, cols, held))
@@ -307,7 +330,11 @@ def run_precision_check(n_users: int = None, n_items: int = None,
     import dataclasses as _dc
 
     import bench
-    from predictionio_tpu.ops.als import ALSParams, pad_ratings, train_als
+    from predictionio_tpu.ops.als import (
+        ALSParams,
+        bucket_ratings_pair,
+        train_als_bucketed,
+    )
     from predictionio_tpu.ops.quantize import (
         dequantize_rows_np,
         quantize_rows_int8_np,
@@ -317,15 +344,15 @@ def run_precision_check(n_users: int = None, n_items: int = None,
     n_items = n_items if n_items is not None else bench.N_ITEMS
     nnz = nnz if nnz is not None else bench.NNZ
     rows, cols, vals, held = build_split(n_users, n_items, nnz, seed)
-    user_side = pad_ratings(rows, cols, vals, n_users, n_items)
-    item_side = pad_ratings(cols, rows, vals, n_items, n_users)
+    user_side, item_side = bucket_ratings_pair(rows, cols, vals, n_users,
+                                               n_items)
     params = ALSParams(rank=RANK, num_iterations=iterations,
                        lambda_=LAMBDA, alpha=ALPHA, implicit_prefs=True,
                        seed=3)
 
-    X32, Y32 = train_als(user_side, item_side, params)
+    X32, Y32 = train_als_bucketed(user_side, item_side, params)
     p32 = precision_at_k(X32, Y32, rows, cols, held)
-    X16, Y16 = train_als(user_side, item_side,
+    X16, Y16 = train_als_bucketed(user_side, item_side,
                          _dc.replace(params, precision="bf16"))
     p16 = precision_at_k(X16, Y16, rows, cols, held)
     X8 = dequantize_rows_np(quantize_rows_int8_np(np.asarray(X32)))
@@ -351,13 +378,11 @@ def run_truncation_check(n_users: int = 6040, n_items: int = 3706,
     """Quality cost of max_len truncation at the ML-1M shape (round-4
     verdict weak #2: the pairs a cut drops are the heaviest users' —
     nothing measured what that cost). Trains the SAME split two ways —
-    length-bucketed 100% coverage vs uniform tables truncated at
-    ``trunc_max_len`` — and reports both Precision@10."""
+    100% coverage vs every row truncated at ``trunc_max_len`` (the
+    preparator's ``max_len``) — and reports both Precision@10."""
     from predictionio_tpu.ops.als import (
         ALSParams,
         bucket_ratings_pair,
-        pad_ratings,
-        train_als,
         train_als_bucketed,
     )
 
@@ -371,14 +396,12 @@ def run_truncation_check(n_users: int = 6040, n_items: int = 3706,
     p_full = precision_at_k(np.asarray(Xf), np.asarray(Yf), rows, cols,
                             held)
 
-    ut = pad_ratings(rows, cols, vals, n_users, n_items,
-                     max_len=trunc_max_len)
-    it = pad_ratings(cols, rows, vals, n_items, n_users,
-                     max_len=trunc_max_len)
-    Xt, Yt = train_als(ut, it, params)
+    ut, it = bucket_ratings_pair(rows, cols, vals, n_users, n_items,
+                                 max_len=trunc_max_len)
+    Xt, Yt = train_als_bucketed(ut, it, params)
     p_trunc = precision_at_k(np.asarray(Xt), np.asarray(Yt), rows, cols,
                              held)
-    covered = int(ut.mask.sum() + it.mask.sum()) // 2
+    covered = (ut.nnz + it.nnz) // 2
     return {
         "check": "truncation_vs_full_coverage",
         "events": int(len(rows)),
@@ -387,9 +410,8 @@ def run_truncation_check(n_users: int = 6040, n_items: int = 3706,
         "truncated_max_len": trunc_max_len,
         "truncated_coverage_of_pairs": round(covered / len(rows), 3),
         "full_coverage_occupancy": round(ub.occupancy, 3),
-        "note": ("bucketed layout trains every pair (coverage 1.0); the "
-                 "truncated uniform layout is what the scale bench used "
-                 "through round 4"),
+        "note": ("without max_len every pair trains (coverage 1.0); the "
+                 "truncated lane is what a preparator max_len costs"),
     }
 
 
@@ -507,7 +529,11 @@ def run_twostage_check(n_users: int = 200, n_items: int = 100,
     list itself comes from ``TwoStageTopK.twos_topk`` so the gate
     exercises the served kernel, not a host reimplementation."""
     from predictionio_tpu.data.sliding import ndcg_at_k
-    from predictionio_tpu.ops.als import ALSParams, pad_ratings, train_als
+    from predictionio_tpu.ops.als import (
+        ALSParams,
+        bucket_ratings_pair,
+        train_als_bucketed,
+    )
     from predictionio_tpu.ops.seqrec import (
         SeqRecParams,
         bucket_sequences,
@@ -542,9 +568,9 @@ def run_twostage_check(n_users: int = 200, n_items: int = 100,
     als_params = ALSParams(rank=rank_retrieval, num_iterations=ITERATIONS,
                            lambda_=LAMBDA, alpha=ALPHA,
                            implicit_prefs=True, seed=3)
-    X_als, Y_als = train_als(pad_ratings(rows, cols, vals, n_users, n_items),
-                             pad_ratings(cols, rows, vals, n_items, n_users),
-                             als_params)
+    X_als, Y_als = train_als_bucketed(
+        *bucket_ratings_pair(rows, cols, vals, n_users, n_items),
+        als_params)
     X_als, Y_als = np.asarray(X_als), np.asarray(Y_als)
 
     # --- stage-2 model: seqrec on the same walks

@@ -311,10 +311,10 @@ class PipelinedRatingsBuilder(StreamingRatingsBuilder):
     bucket scatter are the very same code the serial
     ``bucket_ratings_pair`` runs.
 
-    Note :meth:`finalize` (the uniform-path contract) returns triples
-    in merged (row, col) order rather than stream order — the same
-    multiset, and identical training inputs for every consumer that
-    dedups (pad_ratings / bucket_ratings_pair both do). A consumer
+    Note :meth:`finalize` returns triples in merged (row, col) order
+    rather than stream order — the same multiset, and identical
+    training inputs for every consumer that dedups
+    (``bucket_ratings_pair`` does). A consumer
     that is sensitive to raw triple ORDER (e.g. a leave-last-out eval
     split) must use :class:`StreamingRatingsBuilder` instead."""
 
@@ -358,7 +358,7 @@ class PipelinedRatingsBuilder(StreamingRatingsBuilder):
         return rows, cols, vals, keys
 
     def finalize(self):
-        """Uniform-path contract (user_map, item_map, rows, cols,
+        """The triples contract (user_map, item_map, rows, cols,
         values) — triples arrive merged-sorted, not stream-ordered."""
         from predictionio_tpu.data.bimap import StringIndexBiMap
 

@@ -3,6 +3,7 @@
 Everything here is jit-compiled, static-shaped, and mesh-shardable.
 """
 
-from predictionio_tpu.ops.als import ALSParams, train_als, PaddedRatings
+from predictionio_tpu.ops.als import (
+    ALSParams, BucketedRatings, train_als_bucketed)
 
-__all__ = ["ALSParams", "PaddedRatings", "train_als"]
+__all__ = ["ALSParams", "BucketedRatings", "train_als_bucketed"]

@@ -8,13 +8,15 @@ ALSAlgorithm.scala:64-71``: rank, iterations, lambda, alpha=1.0, seed).
 The design follows the ALX layout (PAPERS.md: "ALX: Large Scale Matrix
 Factorization on TPUs") rather than MLlib's block-partitioned shuffle:
 
-- Ratings are padded per row into dense ``[N, L]`` index/weight tables
-  (power-law raggedness handled by padding to the longest row, optionally
-  bucketed by the caller). Static shapes keep XLA on the MXU.
-- One alternating half-step solves ALL rows in a single batched program:
-  gather the fixed side's factors ``[B, L, R]``, form normal equations with
-  two einsums (never materializing ``[B, L, R, R]``), add the shared Gram
-  matrix for the implicit term, and batch-solve via Cholesky
+- Ratings are grouped by row length into a few dense ``[B, L]``
+  index/weight tables (:class:`BucketedRatings`: each row pads to its
+  own length class, so power-law raggedness costs < 2x and no pair is
+  dropped). Static shapes keep XLA on the MXU.
+- One alternating half-step solves ALL rows in a single program, one
+  batched solve a bucket: gather the fixed side's factors
+  ``[B, L, R]``, form normal equations with two einsums (never
+  materializing ``[B, L, R, R]``), add the shared Gram matrix for the
+  implicit term, and batch-solve via Cholesky
   (``jax.scipy.linalg.cho_solve``).
 - Multi-chip: rows are sharded over the mesh's data axis (each device
   solves its slice); the fixed factor matrix is replicated and the shared
@@ -51,17 +53,10 @@ class ALSParams(Params):
     alpha: float = 1.0
     implicit_prefs: bool = True
     seed: Optional[int] = None
-    # rows per solve block: bounds the [block, L, R] factor gather that
-    # dominates HBM at scale (10M+ ratings). None solves all rows in one
-    # batch; a set value runs the row blocks sequentially on device
-    # (lax.map) — identical solves (factor init differs only if padding
-    # rows were added to reach a block multiple).
-    solve_block_rows: Optional[int] = None
-    # max rows*L padded slots per solve dispatch on the BUCKETED path
-    # (train_als_bucketed): a bucket whose table exceeds this runs as
-    # sequential row blocks (lax.map), bounding the [rows, L, R] gather
-    # peak the same way solve_block_rows does for the uniform path.
-    # None = solve each bucket in one dispatch.
+    # max rows*L padded slots per solve dispatch: a bucket whose table
+    # exceeds this runs as sequential row blocks (lax.map), bounding
+    # the [rows, L, R] factor gather that dominates HBM at scale (10M+
+    # ratings). None = solve each bucket in one dispatch.
     bucket_slot_budget: Optional[int] = None
     # precision policy for the training loop: "fp32" (default —
     # byte-identical to the historical all-fp32 path) or "bf16" (factor
@@ -90,40 +85,9 @@ class ALSParams(Params):
     checkpoint_every: Optional[int] = None
 
 
-@dataclasses.dataclass
-class PaddedRatings:
-    """One side's ragged ratings padded to ``[n_rows, max_len]``.
-
-    ``cols[i, j]`` is the column index of the j-th rating of row i (0 when
-    padded); ``weights[i, j]`` is its rating value; ``mask[i, j]`` is 1.0
-    for real entries and 0.0 for padding. The explicit mask (rather than
-    ``weights > 0``) keeps zero/negative explicit ratings distinguishable
-    from padding.
-    """
-
-    cols: np.ndarray      # int32 [n_rows, L]
-    weights: np.ndarray   # float32 [n_rows, L]
-    mask: np.ndarray      # float32 [n_rows, L]
-    n_rows: int
-    n_cols: int
-    # set by pad_rows_to_block: rows >= n_valid_rows are padding (their
-    # factors must be zeroed before the first shared Gram term and are
-    # sliced off the result). None = every row is real.
-    n_valid_rows: Optional[int] = None
-
-    @property
-    def max_len(self) -> int:
-        return int(self.cols.shape[1])
-
-    @property
-    def valid_rows(self) -> int:
-        return self.n_rows if self.n_valid_rows is None \
-            else self.n_valid_rows
-
-
 # rows pad to a multiple of this in every solve-table builder
-# (pad_ratings, the bucketed grouper, and the fold-in padder,
-# whose EFFECTIVE max_len cap must match training exactly)
+# (the bucketed grouper and the fold-in padder, whose EFFECTIVE
+# max_len cap must match training exactly)
 PAD_MULTIPLE = 8
 
 
@@ -172,90 +136,6 @@ def dedup_sum_sorted(key: np.ndarray, rows: np.ndarray, cols: np.ndarray,
             cols[starts].astype(np.int64), sums)
 
 
-def pad_ratings(rows: np.ndarray, cols: np.ndarray, values: np.ndarray,
-                n_rows: int, n_cols: int,
-                pad_multiple: int = PAD_MULTIPLE,
-                max_len: Optional[int] = None) -> PaddedRatings:
-    """CSR-style host-side padding of rating triples for one solve side.
-
-    Duplicate (row, col) pairs are summed first — the template's
-    ``reduceByKey(_ + _)`` aggregation (custom-query ALSAlgorithm.scala:50).
-    ``max_len`` truncates pathological rows (keeping the
-    largest-magnitude ratings) to bound memory; default keeps everything.
-    """
-    rows, cols, values = dedup_sum_ratings(rows, cols, values, n_cols)
-
-    counts = np.bincount(rows, minlength=n_rows)
-    true_top = int(counts.max()) if len(counts) and counts.max() > 0 else 1
-    L = true_top
-    if max_len is not None and L > max_len:
-        L = int(max_len)
-    L = max(1, -(-L // pad_multiple) * pad_multiple)
-
-    if true_top > L:
-        # truncation active: order each row strongest-magnitude first so
-        # the cut keeps the heaviest ratings; otherwise the (row-grouped)
-        # dedup order is used as-is — same intra-row order as the
-        # bucketed path, so both paths accumulate identically
-        order = np.lexsort((-np.abs(values), rows))
-        rows, cols, values = rows[order], cols[order], values[order]
-    # position of each rating within its row
-    row_starts = np.zeros(n_rows + 1, dtype=np.int64)
-    np.cumsum(counts, out=row_starts[1:])
-    pos = np.arange(len(rows)) - row_starts[rows]
-    if true_top > L:
-        keep = pos < L
-        rows, cols, values, pos = \
-            rows[keep], cols[keep], values[keep], pos[keep]
-
-    out_cols = np.zeros((n_rows, L), dtype=np.int32)
-    out_w = np.zeros((n_rows, L), dtype=np.float32)
-    out_m = np.zeros((n_rows, L), dtype=np.float32)
-    from predictionio_tpu.native import codec as _native
-
-    # the uniform table is the one-bucket case of the native fill
-    # kernel (row rank == row index); numpy scatter as fallback
-    if not _native.bucket_fill(rows, cols, values, pos,
-                               np.zeros(n_rows, dtype=np.int32),
-                               np.arange(n_rows, dtype=np.int64),
-                               [(out_cols, out_w, out_m)]):
-        out_cols[rows, pos] = cols
-        out_w[rows, pos] = values
-        out_m[rows, pos] = 1.0
-    return PaddedRatings(out_cols, out_w, out_m, n_rows, n_cols)
-
-
-def pad_rows_to_block(side: PaddedRatings, block: int) -> PaddedRatings:
-    """Pad the row dimension to a multiple of ``block`` with empty rows
-    (zero mask -> zero factors) for the blocked solve path, recording
-    the true row count in ``n_valid_rows`` so train_als zeroes the pad
-    rows' random init and slices them off the result. Host-side numpy
-    op — callers that stage tables to HBM (the scale bench) pad first,
-    then transfer once."""
-    n_valid = side.valid_rows
-    pad = (-side.n_rows) % block
-    if pad == 0:
-        return side
-
-    def z(a):
-        return np.concatenate(
-            [np.asarray(a), np.zeros((pad, a.shape[1]), dtype=a.dtype)])
-    return PaddedRatings(z(side.cols), z(side.weights), z(side.mask),
-                         side.n_rows + pad, side.n_cols,
-                         n_valid_rows=n_valid)
-
-
-_pad_rows = pad_rows_to_block  # private alias kept for older callers
-
-
-def transpose_ratings(pr: PaddedRatings, rows: np.ndarray, cols: np.ndarray,
-                      values: np.ndarray, pad_multiple: int = PAD_MULTIPLE,
-                      max_len: Optional[int] = None) -> PaddedRatings:
-    """The other solve side: pad by column."""
-    return pad_ratings(cols, rows, values, pr.n_cols, pr.n_rows,
-                       pad_multiple, max_len)
-
-
 # ---------------------------------------------------------------------------
 # Length-bucketed ratings (SURVEY hard part #1: padding/bucketing to keep
 # MXU utilization on power-law-ragged data)
@@ -287,9 +167,8 @@ class BucketedRatings:
     row, each bucket pads only to its own length class, so padded-slot
     occupancy — and with it the share of MXU work that multiplies real
     data — rises several-fold. The half-step solves each bucket as its
-    own batched program sharing one Gram matrix; numerics are identical
-    to the uniform path (same per-row normal equations, padding
-    contributes exact zeros).
+    own batched program sharing one Gram matrix (padding contributes
+    exact zeros to every row's normal equations).
     """
 
     buckets: List["RatingsBucket"]
@@ -357,8 +236,8 @@ def bucket_ratings(rows: np.ndarray, cols: np.ndarray, values: np.ndarray,
                    row_multiple: int = 8) -> BucketedRatings:
     """Group rows by rating-count into geometric length buckets.
 
-    Duplicates are summed first (``reduceByKey`` semantics, as in
-    :func:`pad_ratings`). With ``max_len=None`` (the default) NOTHING is
+    Duplicates are summed first (``reduceByKey`` semantics,
+    :func:`dedup_sum_ratings`). With ``max_len=None`` (the default) NOTHING is
     truncated: the top bucket's length is the true longest row, so
     coverage of unique pairs is 100% — the full-RDD semantics of MLlib's
     ``ALS.trainImplicit`` (custom-query ALSAlgorithm.scala:64-71).
@@ -425,7 +304,7 @@ def _bucket_grouped(rows, cols, values, n_rows: int, n_cols: int,
 
     if true_top > L_top:
         # truncation active: order each row strongest-magnitude first
-        # so the cut keeps the heaviest ratings (as pad_ratings does)
+        # so the cut keeps the heaviest ratings
         order = np.lexsort((-np.abs(values), rows))
         rows, cols, values = rows[order], cols[order], values[order]
     row_starts = np.zeros(n_rows + 1, dtype=np.int64)
@@ -487,9 +366,9 @@ def _bucket_grouped(rows, cols, values, n_rows: int, n_cols: int,
 # ---------------------------------------------------------------------------
 
 def implicit_weights(w, alpha: float):
-    """Hu-Koren-Volinsky confidence/preference weights shared by the XLA
-    and pallas solve paths: A-matrix weights ``alpha*|r|`` and b-vector
-    weights ``pref*(1+alpha*|r|)`` with ``pref = 1 iff r > 0``."""
+    """Hu-Koren-Volinsky confidence/preference weights: A-matrix
+    weights ``alpha*|r|`` and b-vector weights ``pref*(1+alpha*|r|)``
+    with ``pref = 1 iff r > 0``."""
     import jax.numpy as jnp
 
     aw = alpha * jnp.abs(w)
@@ -499,7 +378,7 @@ def implicit_weights(w, alpha: float):
 
 def zero_empty_rows(X, mask):
     """Rows with no ratings keep a zero factor (matches MLlib dropping
-    them); shared by both solve paths."""
+    them)."""
     import jax.numpy as jnp
 
     has_any = (jnp.sum(mask, axis=1) > 0).astype(X.dtype)
@@ -950,88 +829,6 @@ def spd_solve_lanes(A, b, panel: int = 8):
     return jnp.transpose(x, (1, 0))[:, :R]
 
 
-def _solve_side(Y, cols, weights, mask, lam: float, alpha: float,
-                implicit: bool, solver: Optional[str] = None,
-                precision: str = "fp32", refine: bool = False):
-    """One uniform-table alternating half-step (all rows, one batch)."""
-    return _solve_rows(Y, cols, weights, mask, lam, alpha, implicit,
-                       solver=solver, precision=precision, refine=refine)
-
-
-def _solve_side_blocked(Y, cols, weights, mask, lam: float, alpha: float,
-                        implicit: bool, block: Optional[int],
-                        solver: Optional[str] = None,
-                        precision: str = "fp32", refine: bool = False):
-    """`_solve_side`, optionally over sequential row blocks (lax.map) so
-    the [block, L, R] gather — the HBM peak — is bounded regardless of
-    row count. Caller guarantees rows % block == 0 (train_als pads)."""
-    import jax
-
-    B, L = cols.shape
-    if not block or B <= block:
-        return _solve_side(Y, cols, weights, mask, lam, alpha, implicit,
-                           solver, precision, refine)
-    nb = B // block
-
-    def one(args):
-        c, w, m = args
-        return _solve_side(Y, c, w, m, lam, alpha, implicit, solver,
-                           precision, refine)
-
-    X = jax.lax.map(one, (cols.reshape(nb, block, L),
-                          weights.reshape(nb, block, L),
-                          mask.reshape(nb, block, L)))
-    return X.reshape(B, -1)
-
-
-def _als_iterations_impl(X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m, *, lam,
-                         alpha, implicit, num_iterations, block=None,
-                         solver=None, precision="fp32", refine=False):
-    """Full training loop as one compiled program (lax.scan over
-    iterations; no data-dependent Python control flow)."""
-    import jax
-
-    def body(carry, _):
-        X, Y = carry
-        with jax.named_scope("user_step"):
-            X = _solve_side_blocked(Y, u_cols, u_w, u_m, lam, alpha,
-                                    implicit, block, solver, precision,
-                                    refine)
-        with jax.named_scope("item_step"):
-            Y = _solve_side_blocked(X, i_cols, i_w, i_m, lam, alpha,
-                                    implicit, block, solver, precision,
-                                    refine)
-        return (X, Y), None
-
-    (X, Y), _ = jax.lax.scan(body, (X, Y), None, length=num_iterations)
-    return X, Y
-
-
-_als_iterations_jit = None
-
-
-def _als_iterations(*args, **kw):
-    """Lazily-jitted wrapper (keeps jax out of storage-only imports).
-    ``solver``/``precision`` are STATIC arguments: callers resolve the
-    modes at call time, so an env-var change retriggers compilation
-    instead of being baked in at first trace.
-
-    The X/Y carries (args 0/1) are DONATED: steady-state training
-    iterations write the new factors into the input buffers' HBM
-    instead of copying two ``[N, R]`` matrices per dispatch — callers
-    must treat the factor arrays they pass in as consumed."""
-    global _als_iterations_jit
-    if _als_iterations_jit is None:
-        import jax
-
-        _als_iterations_jit = jax.jit(
-            _als_iterations_impl,
-            static_argnames=("lam", "alpha", "implicit", "num_iterations",
-                             "block", "solver", "precision", "refine"),
-            donate_argnums=(0, 1))
-    return _als_iterations_jit(*args, **kw)
-
-
 def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
                          alpha: float, implicit: bool,
                          slot_budget: Optional[int],
@@ -1185,9 +982,13 @@ def _get_bucketed_jit():
 
 
 def _als_iterations_bucketed(*args, **kw):
-    """Jitted bucketed loop; like :func:`_als_iterations` the X/Y
-    carries are donated (steady-state iterations reuse the factor HBM)
-    and ``solver``/``precision`` arrive resolved as static args. A
+    """Jitted bucketed loop. The X/Y carries are DONATED: steady-state
+    iterations write the new factors into the input buffers' HBM
+    instead of copying two ``[N, R]`` matrices per dispatch, so callers
+    must treat the factor arrays they pass in as consumed.
+    ``solver``/``precision`` arrive resolved as STATIC args: an env-var
+    change retriggers compilation instead of being baked in at first
+    trace. A
     matching AOT executable from :func:`warmup_train_als_bucketed`
     (statics baked at lower time) is used when present."""
     jitted = _get_bucketed_jit()
@@ -1485,12 +1286,6 @@ def _objective_statics(params) -> dict:
                 implicit=bool(params.implicit_prefs))
 
 
-def _uniform_objective_bucket(cols, weights, mask, n_rows: int):
-    """A uniform ``[N, L]`` table viewed as the one-bucket case: table
-    row ``i`` IS factor row ``i``, so ``row_ids`` is just arange."""
-    return (np.arange(int(n_rows), dtype=np.int32), cols, weights, mask)
-
-
 def _train_telemetry_enabled() -> bool:
     from predictionio_tpu.workflow import runlog as _runlog
 
@@ -1531,39 +1326,19 @@ def training_objective(X, Y, user_side, params: ALSParams) -> dict:
     """One objective sample for a factor pair against the USER-side
     solve tables: ``{"fit", "l2", "total", "finite"}``.
 
-    ``user_side`` is the side whose rows align with ``X`` — a uniform
-    :class:`PaddedRatings` or a :class:`BucketedRatings`. This is the
-    public one-shot form of the fused per-chunk reduction the crash-safe
-    loop samples; factors may be host numpy or live device arrays."""
+    ``user_side`` is the :class:`BucketedRatings` whose rows align with
+    ``X``. This is the public one-shot form of the fused per-chunk
+    reduction the crash-safe loop samples; factors may be host numpy or
+    live device arrays."""
     import jax.numpy as jnp
 
-    if isinstance(user_side, BucketedRatings):
-        u_t = tuple((b.row_ids, b.cols, b.weights, b.mask)
-                    for b in user_side.buckets)
-    else:
-        u_t = (_uniform_objective_bucket(
-            user_side.cols, user_side.weights, user_side.mask,
-            np.shape(X)[0]),)
+    u_t, = _bucket_tables(user_side)
     pack = np.asarray(_objective_pack(
         jnp.asarray(X), jnp.asarray(Y), u_t,
         **_objective_statics(params)), dtype=np.float64)
     return {"fit": float(pack[0]), "l2": float(pack[1]),
             "total": float(pack[0] + pack[1]),
             "finite": bool(pack[2] == 1.0)}
-
-
-def checkpoint_layout_uniform(user_side: PaddedRatings,
-                              item_side: PaddedRatings):
-    """Layout half of the checkpoint fingerprint for uniform tables:
-    row/col spaces + padded shapes + valid-row counts. Shared by the
-    single-device and sharded trainers — the numerics are identical
-    across topologies (differential-tested), so a checkpoint is
-    resumable on either."""
-    def side(s):
-        return (int(s.n_rows), int(s.n_cols), int(s.max_len),
-                int(s.valid_rows))
-
-    return ("uniform", side(user_side), side(item_side))
 
 
 def checkpoint_layout_bucketed(user_side: BucketedRatings,
@@ -1728,12 +1503,10 @@ def train_als_bucketed(user_side: BucketedRatings,
     """Train on length-bucketed tables and return host numpy
     ``(user_factors [N, R], item_factors [M, R])``.
 
-    Numerically equivalent to :func:`train_als` on the same ratings
-    (same per-row solves, same seed/init); the padded-slot count — and
-    with it the MXU work — is set by each bucket's own length instead of
-    the global longest row. Build the sides with :func:`bucket_ratings`;
-    call ``.to_device()`` on them first to stage the tables into HBM
-    once when training repeatedly."""
+    The padded-slot count — and with it the MXU work — is set by each
+    bucket's own length, not the global longest row. Build the sides
+    with :func:`bucket_ratings`; call ``.to_device()`` on them first to
+    stage the tables into HBM once when training repeatedly."""
     assert user_side.n_rows >= item_side.n_cols
     assert item_side.n_rows >= user_side.n_cols
     import jax
@@ -1847,93 +1620,6 @@ def init_factors(n_rows: int, n_cols: int, rank: int,
     return X, Y
 
 
-def train_als(user_side: PaddedRatings, item_side: PaddedRatings,
-              params: ALSParams, dtype=None) -> Tuple[np.ndarray, np.ndarray]:
-    """Train and return host numpy ``(user_factors [N, R],
-    item_factors [M, R])``.
-
-    ``user_side`` is padded by user (cols are item indices); ``item_side``
-    by item (cols are user indices).
-    """
-    import jax.numpy as jnp
-
-    # >= (not ==): a pre-padded side's row count may exceed the other
-    # side's column space — indexing into the taller factor matrix is
-    # safe, its pad rows are zero
-    assert user_side.n_rows >= item_side.n_cols
-    assert item_side.n_rows >= user_side.n_cols
-    block = params.solve_block_rows
-    if block:
-        # pad both row dims to a block multiple; extra rows have empty
-        # masks -> zero factors after their first solve. No-ops when the
-        # caller pre-padded (e.g. to stage device tables once) — the true
-        # counts then come from n_valid_rows.
-        user_side = pad_rows_to_block(user_side, block)
-        item_side = pad_rows_to_block(item_side, block)
-    precision = _als_precision_mode(params)  # resolved per call
-    n_u, n_i = user_side.valid_rows, item_side.valid_rows
-    X, Y = init_policy_factors(user_side.n_rows, item_side.n_rows,
-                               params.rank, params.seed, dtype, precision)
-    if n_u < user_side.n_rows or n_i < item_side.n_rows:
-        # the random init filled the pad rows too — zero them NOW, or the
-        # first half-iteration's shared Gram term (Y^T Y over all rows,
-        # _solve_side) would see phantom random factors
-        X = X.at[n_u:].set(0.0)
-        Y = Y.at[n_i:].set(0.0)
-    u_cols = jnp.asarray(user_side.cols)
-    u_w = jnp.asarray(user_side.weights)
-    u_m = jnp.asarray(user_side.mask)
-    i_cols = jnp.asarray(item_side.cols)
-    i_w = jnp.asarray(item_side.weights)
-    i_m = jnp.asarray(item_side.mask)
-    # resolved per call, never at trace
-    solver = _spd_solver_mode(
-        params.rank, (X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m))
-    kw = dict(
-        lam=float(params.lambda_), alpha=float(params.alpha),
-        implicit=bool(params.implicit_prefs),
-        block=None if not block else int(block),
-        solver=solver, precision=precision,
-        refine=bool(params.solve_refine))
-    ckpt = _maybe_checkpointer(
-        checkpoint_layout_uniform(user_side, item_side), params,
-        solver, precision, dtype)
-    if ckpt is None:
-        X, Y = _als_iterations(
-            X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m,
-            num_iterations=int(params.num_iterations), **kw)
-    else:
-        # crash-safe lane (see train_als_bucketed)
-        from predictionio_tpu.workflow import checkpoint as _checkpoint
-
-        fdt = X.dtype
-
-        def run_iters(Xc, Yc, n):
-            return _als_iterations(
-                Xc, Yc, u_cols, u_w, u_m, i_cols, i_w, i_m,
-                num_iterations=int(n), **kw)
-
-        objective = None
-        if _train_telemetry_enabled():
-            # the uniform table is the one-bucket case of the fused
-            # objective: row i of the table IS factor row i
-            obj_bucket = _uniform_objective_bucket(
-                u_cols, u_w, u_m, user_side.n_rows)
-            obj_kw = _objective_statics(params)
-
-            def objective(Xc, Yc):
-                return _objective_pack(Xc, Yc, (obj_bucket,), **obj_kw)
-
-        X, Y = _checkpoint.run_chunked(
-            run_iters, X, Y, int(params.num_iterations), ckpt,
-            to_host=lambda a: np.asarray(a, dtype=np.float32),
-            from_host=lambda a: jnp.asarray(a, dtype=fdt),
-            objective=objective)
-    # host factors always land fp32 (see train_als_bucketed)
-    return (np.asarray(X, dtype=np.float32)[:n_u],
-            np.asarray(Y, dtype=np.float32)[:n_i])
-
-
 # ---------------------------------------------------------------------------
 # Online fold-in (ROADMAP item 3): the normal-equations half-step reused at
 # batch size 1..k against FIXED item factors, so a deployed server can solve
@@ -1979,7 +1665,7 @@ def pad_fold_in_batch(cols_list: Sequence[np.ndarray],
     per distinct (k, longest-row) pair. Duplicate (user, item) pairs
     are summed first — the same ``reduceByKey`` aggregation training
     applies (:func:`dedup_sum_ratings`). ``max_len`` applies the SAME
-    per-row truncation training applies (:func:`pad_ratings`: keep the
+    per-row truncation training applies (``_bucket_grouped``: keep the
     largest-magnitude ratings) — an engine trained with truncation must
     fold truncated, or the fold solves a different objective than the
     trained rows for exactly the long-history users the cap exists for
@@ -1991,7 +1677,7 @@ def pad_fold_in_batch(cols_list: Sequence[np.ndarray],
     from predictionio_tpu.ops.serving import bucket_size
 
     k = len(cols_list)
-    # the EFFECTIVE training cap: pad_ratings/_bucket_grouped round
+    # the EFFECTIVE training cap: _bucket_grouped rounds
     # max_len up to PAD_MULTIPLE and only cut rows beyond that —
     # truncating at the raw max_len here would solve a smaller problem
     # than training did for rows in the rounding gap
@@ -2042,7 +1728,7 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
     The precision policy is the training one (``ALSParams.precision`` /
     ``PIO_ALS_PRECISION``, resolved per call): under ``bf16`` the item
     factors are gathered bfloat16 with fp32 accumulation and solve,
-    matching ``train_als``'s storage/compute split. ``item_factors``
+    matching the trainers' storage/compute split. ``item_factors``
     may be host numpy or a live device array (e.g. the serving store's
     HBM-resident ``Y``, possibly already bf16)."""
     import jax.numpy as jnp
@@ -2106,22 +1792,18 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
 def item_interaction_counts(item_side) -> np.ndarray:
     """Per-item interaction counts from an ITEM-side table (rows are
     items) — the density signal the ALX-style bin-pack shards by
-    (``parallel.als_sharding.density_aware_item_layout``). Accepts a
-    uniform :class:`PaddedRatings` or a :class:`BucketedRatings`;
-    sentinel pad rows contribute nothing."""
-    if isinstance(item_side, BucketedRatings):
-        counts = np.zeros(item_side.n_rows, dtype=np.int64)
-        for b in item_side.buckets:
-            ids = np.asarray(b.row_ids, dtype=np.int64)
-            # reduce BEFORE np.asarray: device-staged tables (the 1B
-            # lane) transfer one [rows] vector, not the padded mask
-            per_row = np.asarray(
-                b.mask.sum(axis=1)).astype(np.int64)
-            real = ids < item_side.n_rows
-            np.add.at(counts, ids[real], per_row[real])
-        return counts
-    per_row = np.asarray(item_side.mask.sum(axis=1)).astype(np.int64)
-    return per_row[:item_side.n_rows]
+    (``parallel.als_sharding.density_aware_item_layout``). Sentinel pad
+    rows contribute nothing."""
+    counts = np.zeros(item_side.n_rows, dtype=np.int64)
+    for b in item_side.buckets:
+        ids = np.asarray(b.row_ids, dtype=np.int64)
+        # reduce BEFORE np.asarray: device-staged tables (the 1B
+        # lane) transfer one [rows] vector, not the padded mask
+        per_row = np.asarray(
+            b.mask.sum(axis=1)).astype(np.int64)
+        real = ids < item_side.n_rows
+        np.add.at(counts, ids[real], per_row[real])
+    return counts
 
 
 # ---------------------------------------------------------------------------

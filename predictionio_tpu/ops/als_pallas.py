@@ -1,6 +1,6 @@
 """Pallas TPU kernels for ALS.
 
-Three kernels live here:
+Two kernels live here:
 
 1. ``spd_solve`` — batched symmetric positive-definite solve (Cholesky
    factorization + forward/backward triangular substitution fused in
@@ -24,13 +24,7 @@ Three kernels live here:
    steps updates the whole ``R x R`` block where only the trailing
    part is live: the open item of PERF.md section 7.
 
-2. ``assemble_normal_equations`` — fused gather + normal-equation
-   assembly. STATUS: correct, slow, not the default. On the chip it
-   matches XLA's fused take+einsum and is far slower (PERF.md section
-   5): the serial row-by-row DMA dominates and XLA's gather fusion is
-   already good, so ``ops/als.py`` keeps the XLA path for assembly.
-
-3. ``fused_gather_score_topk`` — the SERVING kernel (ROADMAP item 4):
+2. ``fused_gather_score_topk`` — the SERVING kernel (ROADMAP item 4):
    score matvec + seen-row masking + top-k selection fused into one
    program. The XLA chain dispatches gather/einsum/mask/top_k as
    separate HLOs whose ``[B, M]`` score intermediate round-trips HBM
@@ -57,124 +51,6 @@ from __future__ import annotations
 
 import functools
 from typing import Optional
-
-# solve rows processed per grid step (TPU sublane tiling needs >= 8)
-_BB = 8
-
-
-def _kernel(cols_ref, aw_ref, bw_ref, y_ref, gram_ref, a_ref, b_ref,
-            yg_ref, sem):
-    """One grid step = ``_BB`` solve rows.
-
-    cols [BB, L] i32 in SMEM (scalar index reads); aw/bw [BB, L] VMEM
-    weights for the A matrix / b vector; y [M, R] left in ANY (HBM) and
-    gathered row-by-row via async DMA into the flat [BB*L, R] VMEM
-    scratch — DMA engines take arbitrary dynamic offsets where the
-    vector ISA cannot; gram [R, R] = YtY + lam*I precomputed.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    BB, L = aw_ref.shape
-
-    def gather(i, _):
-        r = i // L
-        l = i % L
-        idx = cols_ref[r, l]
-        dma = pltpu.make_async_copy(
-            y_ref.at[pl.ds(idx, 1), :],
-            yg_ref.at[pl.ds(i, 1), :],
-            sem)
-        dma.start()
-        dma.wait()
-        return 0
-
-    jax.lax.fori_loop(0, BB * L, gather, 0)
-    gram = gram_ref[:]
-    # per-row 2D MXU matmuls (mosaic has no batched 3D dot); BB is a
-    # small static constant so the loop unrolls at trace time
-    for i in range(BB):
-        ygi = yg_ref[i * L:(i + 1) * L, :]           # [L, R] static slice
-        awygi = ygi * aw_ref[i, :][:, None]
-        # contract on dim 0 == awygi^T @ ygi without a transpose op;
-        # HIGHEST matches the XLA path's full-f32 MXU passes (als.py)
-        a_ref[i] = gram + jax.lax.dot_general(
-            awygi, ygi, (((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32)      # [R, R]
-        b_ref[i] = jnp.sum(ygi * bw_ref[i, :][:, None], axis=0)  # [R]
-
-
-@functools.lru_cache(maxsize=32)
-def _build(n_rows: int, L: int, M: int, R: int, interpret: bool):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    assert n_rows % _BB == 0
-    grid = (n_rows // _BB,)
-    fn = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((_BB, L), lambda b: (b, 0),
-                         memory_space=pltpu.SMEM),             # cols
-            pl.BlockSpec((_BB, L), lambda b: (b, 0)),          # aw
-            pl.BlockSpec((_BB, L), lambda b: (b, 0)),          # bw
-            pl.BlockSpec(memory_space=pl.ANY),                 # Y (HBM)
-            pl.BlockSpec((R, R), lambda b: (0, 0)),            # gram
-        ],
-        out_specs=[
-            pl.BlockSpec((_BB, R, R), lambda b: (b, 0, 0)),
-            pl.BlockSpec((_BB, R), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_rows, R, R), jnp.float32),
-            jax.ShapeDtypeStruct((n_rows, R), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM((_BB * L, R), jnp.float32),
-                        pltpu.SemaphoreType.DMA],
-        interpret=interpret,
-        name="assemble_normal_equations",
-    )
-    return jax.jit(fn)
-
-
-def assemble_normal_equations(Y, cols, aw, bw, gram,
-                              interpret: Optional[bool] = None):
-    """Fused gather + assembly: returns ``(A [B,R,R], b [B,R])``.
-
-    ``Y [M, R]`` fixed-side factors (resident in VMEM); ``cols [B, L]``
-    gather indices (padding rows must carry weight 0 in ``aw``/``bw``);
-    ``aw``/``bw`` [B, L] weights for the A matrix / b vector; ``gram``
-    [R, R] the shared ``YtY + lam*I`` term. ``B`` is padded up to the
-    kernel's row-block size internally.
-    """
-    import jax
-    import jax.numpy as jnp
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    B, L = cols.shape
-    M, R = Y.shape
-    pad = (-B) % _BB
-    if pad:
-        cols = jnp.concatenate(
-            [cols, jnp.zeros((pad, L), dtype=cols.dtype)])
-        aw = jnp.concatenate([aw, jnp.zeros((pad, L), dtype=aw.dtype)])
-        bw = jnp.concatenate([bw, jnp.zeros((pad, L), dtype=bw.dtype)])
-    # DMA slices must be 128-lane aligned: pad rank to a lane multiple
-    # (zero columns contribute zero to A/b; sliced off below)
-    rpad = (-R) % 128
-    if rpad:
-        Y = jnp.pad(Y, ((0, 0), (0, rpad)))
-        gram = jnp.pad(gram, ((0, rpad), (0, rpad)))
-    fn = _build(B + pad, L, M, R + rpad, bool(interpret))
-    A, b = fn(cols, aw, bw, Y, gram)
-    return A[:B, :R, :R], b[:B, :R]
 
 
 # ---------------------------------------------------------------------------
@@ -316,41 +192,6 @@ def spd_solve(A, b, interpret: Optional[bool] = None):
                              axis=1)
     x = _build_spd(B + pad, R, bool(interpret))(At, bt)
     return x[:, :B].T
-
-
-def solve_side_pallas(Y, cols, weights, mask, lam: float, alpha: float,
-                      implicit: bool, interpret: Optional[bool] = None):
-    """Drop-in replacement for ``ops.als._solve_side`` using the fused
-    kernel for A/b assembly (same math, see als.py:136-184); the batched
-    Cholesky solve remains an XLA op."""
-    import jax
-    import jax.numpy as jnp
-
-    from predictionio_tpu.ops.als import implicit_weights, zero_empty_rows
-
-    R = Y.shape[1]
-    hi = jax.lax.Precision.HIGHEST
-    Yf = Y.astype(jnp.float32)
-    mask = mask.astype(jnp.float32)
-    w = weights.astype(jnp.float32) * mask
-    if implicit:
-        aw, bw = implicit_weights(w, alpha)
-        gram = jnp.matmul(Yf.T, Yf, precision=hi) \
-            + lam * jnp.eye(R, dtype=jnp.float32)
-        A, b = assemble_normal_equations(Yf, cols, aw, bw, gram, interpret)
-    else:
-        # explicit ALS-WR: per-row lambda scaling makes gram row-dependent;
-        # fold lam*n_b*I in afterwards
-        aw = mask
-        bw = w
-        gram = jnp.zeros((R, R), dtype=jnp.float32)
-        A, b = assemble_normal_equations(Yf, cols, aw, bw, gram, interpret)
-        n_b = jnp.sum(mask, axis=1)
-        A = A + (lam * jnp.maximum(n_b, 1.0))[:, None, None] \
-            * jnp.eye(R, dtype=jnp.float32)[None]
-    chol = jax.scipy.linalg.cho_factor(A)
-    X = jax.scipy.linalg.cho_solve(chol, b)
-    return zero_empty_rows(X, mask).astype(Y.dtype)
 
 
 # ---------------------------------------------------------------------------

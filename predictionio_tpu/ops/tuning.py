@@ -63,8 +63,6 @@ _NOT_SWEEPABLE_WHY = {
                       "traced program (static jit arg)",
     "seed": "the per-config init already varies by rank; a per-config "
             "seed would break the grid==serial differential contract",
-    "solve_block_rows": "uniform-path execution knob, not part of the "
-                        "bucketed grid program",
     "bucket_slot_budget": "static shape knob of the shared program",
     "precision": "the factor dtype is the stacked array's dtype — one "
                  "per grid",

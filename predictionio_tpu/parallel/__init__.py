@@ -12,8 +12,7 @@ from predictionio_tpu.parallel.als_sharding import (
     ItemShardLayout,
     contiguous_item_layout,
     density_aware_item_layout,
-    train_als_sharded,
-    train_als_sharded_2d,
+    train_als_bucketed_sharded,
 )
 from predictionio_tpu.parallel import distributed  # multi-host runtime
 from predictionio_tpu.parallel.distributed import (
@@ -25,8 +24,8 @@ from predictionio_tpu.ops.attention import (  # sequence parallel
     ulysses_attention,
 )
 
-__all__ = ["data_parallel_mesh", "mesh_2d", "train_als_sharded",
-           "train_als_sharded_2d", "ring_attention", "ulysses_attention",
+__all__ = ["data_parallel_mesh", "mesh_2d",
+           "train_als_bucketed_sharded", "ring_attention", "ulysses_attention",
            "distributed", "DistributedConfig", "host_aware_mesh",
            "ItemShardLayout", "density_aware_item_layout",
            "contiguous_item_layout"]

@@ -3,16 +3,16 @@
 MLlib ALS distributes by block-partitioning both factor matrices and
 shuffling ratings between executors every half-step (invoked from
 ``examples/.../ALSAlgorithm.scala:64-71``). The TPU-native replacement
-(ALX layout): shard the PADDED RATING TABLES row-wise over the mesh's
-``data`` axis so each device solves its slice of users (then items);
+(ALX layout): shard every bucket's padded rating table row-wise over
+the mesh's ``data`` axis so each device solves its slice of users (then items);
 factor matrices are kept replicated and rebuilt each half-step — XLA's
 sharding propagation turns the per-slice solves + gathers into
 all-gather/psum collectives over ICI, replacing the Spark shuffle.
 
 Memory note: replicated factors cost ``(N+M) * R * 4`` bytes per device —
 fine through MovieLens-20M (~165 MB at R=128). Past that,
-``train_als_sharded_2d`` shards the factor matrices over the mesh's
-``model`` axis (per-device factor memory drops by the model-axis size;
+``factor_spec=P("model", None)`` shards the factor matrices over the
+mesh's ``model`` axis (per-device factor memory drops by the model-axis size;
 one transient all-gather per half-step over ICI — the ALX layout).
 """
 
@@ -31,21 +31,17 @@ logger = logging.getLogger("predictionio_tpu.als_sharding")
 from predictionio_tpu.ops.als import (
     ALSParams,
     BucketedRatings,
-    PaddedRatings,
     RatingsBucket,
     _als_iterations_bucketed_impl,
-    _als_iterations_impl,
     _als_precision_mode,
     _maybe_checkpointer,
     _objective_pack,
     _objective_statics,
-    _spd_solver_mode,
+    _resolve_spd_solver,
     _train_telemetry_enabled,
-    _uniform_objective_bucket,
     checkpoint_layout_bucketed,
-    checkpoint_layout_uniform,
-    factor_dtype,
     init_policy_factors,
+    solve_span_attributes,
 )
 
 
@@ -207,204 +203,22 @@ def _multihost_checkpointer(layout, params, solver, precision, dtype,
 
 
 def _pad_rows_to(arr: np.ndarray, n: int) -> np.ndarray:
-    """Pad the leading dim to n rows (zeros = no-op ratings)."""
+    """Pad the leading dim to n rows with zeros."""
     if arr.shape[0] == n:
         return arr
     pad = np.zeros((n - arr.shape[0],) + arr.shape[1:], dtype=arr.dtype)
     return np.concatenate([arr, pad], axis=0)
 
 
-def _jit_step(mesh, factor_spec):
-    """The production jitted iteration program: factor outputs pinned to
-    ``factor_spec`` between iterations; XLA inserts the collectives
-    (all-gather before each index-gather — the ICI analog of MLlib's
-    factor shuffle). The X/Y carries are donated — input and output
-    shardings match, so steady-state steps update the factor shards in
-    place instead of copying them per dispatch."""
-    import jax
-    from jax.sharding import NamedSharding
-
-    factor_sharded = NamedSharding(mesh, factor_spec)
-    return jax.jit(
-        _als_iterations_impl,
-        static_argnames=("lam", "alpha", "implicit", "num_iterations",
-                         "solver", "precision", "refine"),
-        out_shardings=(factor_sharded, factor_sharded),
-        donate_argnums=(0, 1),
-    )
-
-
-def _train_sharded(user_side: PaddedRatings, item_side: PaddedRatings,
-                   params: ALSParams, mesh, row_divisor: int,
-                   factor_spec, dtype,
-                   gather: bool = True) -> Tuple[np.ndarray, np.ndarray]:
-    """Shared sharded-training body: pad rows to ``row_divisor``, shard
-    rating tables over 'data', place factors per ``factor_spec``, run the
-    full iteration scan, slice padding back off."""
-    import jax
-
-    if not isinstance(user_side, PaddedRatings):
-        raise TypeError(
-            "this ALS flavor trains uniform PaddedRatings tables; for "
-            "length-bucketed sides use train_als_bucketed_sharded (or "
-            "the default ALSAlgorithm via train_als_auto), or set "
-            "bucketed=False on the preparator")
-    import jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    precision = _als_precision_mode(params)  # resolved per call
-    X, Y = init_policy_factors(user_side.n_rows, user_side.n_cols,
-                               params.rank, params.seed, dtype, precision)
-    n_u = -(-user_side.n_rows // row_divisor) * row_divisor
-    n_i = -(-item_side.n_rows // row_divisor) * row_divisor
-
-    row_sharded = NamedSharding(mesh, P("data", None))
-    factor_sharded = NamedSharding(mesh, factor_spec)
-    put = jax.device_put
-    # keyed on the MESH, not jax.process_count(): a local mesh inside a
-    # distributed runtime must still take the single-host placement path
-    multi_host = len({d.process_index for d in mesh.devices.flat}) > 1
-
-    def place_rows(a, n):
-        """Rating-table rows, sharded over 'data'. Multi-host: each host
-        contributes only its contiguous row block (host-sharded ingest,
-        parallel/distributed.py); single-host: plain device_put."""
-        a = _pad_rows_to(a, n)
-        if multi_host:
-            from predictionio_tpu.parallel import distributed
-
-            start, stop = distributed.process_row_block(n)
-            return distributed.make_global_array(mesh, P("data", None),
-                                                 a[start:stop])
-        return put(jnp.asarray(a), row_sharded)
-
-    def place_factor(a, n):
-        """Factor matrices: replicated or model-axis sharded. With
-        host_aware_mesh's host-local model groups every host holds all
-        model positions, so its process-local data is the full matrix."""
-        a = _pad_rows_to(np.asarray(a), n)
-        if multi_host:
-            from predictionio_tpu.parallel import distributed
-
-            return distributed.make_global_array(mesh, factor_spec, a)
-        return put(jnp.asarray(a), factor_sharded)
-
-    def rows(side, n):
-        return [place_rows(a, n) for a in (side.cols, side.weights,
-                                           side.mask)]
-
-    u_cols, u_w, u_m = rows(user_side, n_u)
-    i_cols, i_w, i_m = rows(item_side, n_i)
-    X = place_factor(X, n_u)
-    Y = place_factor(Y, n_i)
-
-    step = _jit_step(mesh, factor_spec)
-    kw = dict(lam=float(params.lambda_), alpha=float(params.alpha),
-              implicit=bool(params.implicit_prefs),
-              # resolved per call; the placed tables tell the resolver
-              # how many devices the program is partitioned over
-              solver=_spd_solver_mode(
-                  params.rank,
-                  (X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m)),
-              precision=precision, refine=bool(params.solve_refine))
-
-    def run_iters(Xc, Yc, n):
-        return step(Xc, Yc, u_cols, u_w, u_m, i_cols, i_w, i_m,
-                    num_iterations=int(n), **kw)
-
-    # crash-safe lane: single-host sharded runs checkpoint between
-    # chunks (np.asarray gathers the factor shards)
-    ckpt = _multihost_checkpointer(
-        checkpoint_layout_uniform(user_side, item_side), params,
-        kw["solver"], precision, dtype, multi_host)
-    if ckpt is None:
-        X, Y = run_iters(X, Y, int(params.num_iterations))
-    else:
-        from predictionio_tpu.workflow import checkpoint as _checkpoint
-
-        fdt = X.dtype
-        objective = None
-        if _train_telemetry_enabled():
-            # same jitted objective program as the single-device lane;
-            # the sharded tables flow through jit and GSPMD inserts the
-            # psum merges (the pack stays one replicated [3] scalar)
-            obj_bucket = _uniform_objective_bucket(u_cols, u_w, u_m, n_u)
-            obj_kw = _objective_statics(params)
-
-            def objective(Xc, Yc):
-                return _objective_pack(Xc, Yc, (obj_bucket,), **obj_kw)
-
-        X, Y = _checkpoint.run_chunked(
-            run_iters, X, Y, int(params.num_iterations), ckpt,
-            to_host=lambda a: np.asarray(a, dtype=np.float32),
-            from_host=lambda a: put(jnp.asarray(a, dtype=fdt),
-                                    factor_sharded),
-            objective=objective)
-    if not gather:
-        # PAlgorithm path: factors STAY sharded in HBM (padded to n_u/n_i
-        # rows, bf16 under the bf16 policy); the caller serves from them
-        # directly (ops/serving.py accepts bf16 factor Arrays)
-        return X, Y
-    if multi_host:
-        # factors are needed host-side on every host (model persistence,
-        # serving); gather across processes over DCN
-        from jax.experimental import multihost_utils
-
-        X = multihost_utils.process_allgather(X, tiled=True)
-        Y = multihost_utils.process_allgather(Y, tiled=True)
-    # host factors always land fp32 (see ops.als.train_als)
-    return (np.asarray(X, dtype=np.float32)[:user_side.n_rows],
-            np.asarray(Y, dtype=np.float32)[:item_side.n_rows])
-
-
-def train_als_sharded(user_side: PaddedRatings, item_side: PaddedRatings,
-                      params: ALSParams, mesh,
-                      dtype=None) -> Tuple[np.ndarray, np.ndarray]:
-    """Train with rating tables sharded over ``mesh`` axis 'data' and
-    factor matrices replicated.
-
-    Produces the same numerics as :func:`~predictionio_tpu.ops.als.train_als`
-    (same init, same solves) — verified by tests on the virtual CPU mesh.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    return _train_sharded(user_side, item_side, params, mesh,
-                          row_divisor=mesh.devices.size,
-                          factor_spec=P(None, None), dtype=dtype)
-
-
-def train_als_sharded_2d(user_side: PaddedRatings, item_side: PaddedRatings,
-                         params: ALSParams, mesh,
-                         dtype=None) -> Tuple[np.ndarray, np.ndarray]:
-    """2-D (data x model) sharded training: rating tables row-sharded over
-    'data', FACTOR MATRICES row-sharded over 'model'.
-
-    This is the scale step beyond replicated factors (module docstring):
-    each device stores only ``rows/model_size`` of each factor matrix in
-    HBM; GSPMD all-gathers the fixed side transiently for the gather-by-
-    index of each half-step and scatters the solve output back to its
-    shard — factor memory per device drops by the model-axis size at the
-    cost of one all-gather per half-step over ICI (the ALX layout).
-    Numerics identical to :func:`~predictionio_tpu.ops.als.train_als`.
-    Rows pad to a multiple of data*model so BOTH shardings split evenly.
-    """
-    from jax.sharding import PartitionSpec as P
-
-    return _train_sharded(user_side, item_side, params, mesh,
-                          row_divisor=mesh.shape["data"] * mesh.shape["model"],
-                          factor_spec=P("model", None), dtype=dtype)
-
-
 def train_als_device(user_side, item_side,
                      params: ALSParams, mesh=None, dtype=None):
     """Train and KEEP the factors sharded in HBM — the PAlgorithm flavor
     (PAlgorithm.scala:44-126: the model lives distributed; nothing is
-    gathered to host). Accepts uniform :class:`PaddedRatings` or
-    length-bucketed :class:`BucketedRatings` sides.
+    gathered to host).
 
-    Returns ``(X, Y)`` as jax Arrays padded to the mesh divisor — on a
-    2-D mesh they are row-sharded over the 'model' axis (each device
-    stores 1/model of each factor matrix), on a 1-D mesh replicated.
+    Returns ``(X, Y)`` as jax Arrays — on a 2-D mesh row-sharded over
+    the 'model' axis (each device stores 1/model of each factor matrix,
+    rows padded to that axis' size), on a 1-D mesh replicated.
     Serve them with :class:`predictionio_tpu.ops.serving.DeviceTopK`,
     passing the true n_users/n_items as the index bounds.
     """
@@ -417,22 +231,11 @@ def train_als_device(user_side, item_side,
 
         n = len(jax.devices())
         mesh = host_aware_mesh(model=2 if (n % 2 == 0 and n >= 4) else 1)
-    if "model" in mesh.axis_names:
-        divisor = mesh.shape["data"] * mesh.shape["model"]
-        spec = P("model", None)
-    else:
-        divisor = mesh.devices.size
-        spec = P(None, None)
-    if isinstance(user_side, BucketedRatings):
-        # the scale combination: bucketed solves + factors kept in HBM
-        # (model-sharded on a 2-D mesh); note the returned Arrays are
-        # NOT row-padded — bucketed training sizes them exactly
-        return train_als_bucketed_sharded(
-            user_side, item_side, params, mesh, dtype=dtype,
-            factor_spec=spec, gather=False)
-    return _train_sharded(user_side, item_side, params, mesh,
-                          row_divisor=divisor, factor_spec=spec,
-                          dtype=dtype, gather=False)
+    spec = P("model", None) if "model" in mesh.axis_names \
+        else P(None, None)
+    return train_als_bucketed_sharded(
+        user_side, item_side, params, mesh, dtype=dtype,
+        factor_spec=spec, gather=False)
 
 
 def _pad_bucket_rows(b: RatingsBucket, multiple: int,
@@ -465,8 +268,7 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
     sentinel ids). By default the factor matrices stay replicated, so
     each device's per-bucket solves scatter into its replica and XLA
     merges the disjoint scatters with one psum per half-step — the
-    collective analog of MLlib's factor shuffle, at bucketed occupancy
-    instead of longest-row padding. ``factor_spec`` (e.g.
+    collective analog of MLlib's factor shuffle. ``factor_spec`` (e.g.
     ``P("model", None)``) shards the factor matrices instead (the ALX
     layout's memory step; factor rows pad to the sharded-dim divisor);
     ``gather=False`` returns the factors as device Arrays in that
@@ -475,6 +277,8 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from predictionio_tpu.utils import tracing as _tracing
 
     ndev = int(mesh.shape.get("data", 1))
     rows_sharded = NamedSharding(mesh, P("data", None))
@@ -537,13 +341,14 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
         out_shardings=(repl, repl),
         donate_argnums=(0, 1))
     u_t, i_t = place(user_side), place(item_side)
+    # resolved per call; the placed tables tell the resolver how many
+    # devices the program is partitioned over
+    choice = _resolve_spd_solver(params.rank, (X, Y, u_t, i_t))
     kw = dict(lam=float(params.lambda_), alpha=float(params.alpha),
               implicit=bool(params.implicit_prefs),
               slot_budget=None if not params.bucket_slot_budget
               else int(params.bucket_slot_budget),
-              # resolved per call; the placed tables tell the resolver
-              # how many devices the program is partitioned over
-              solver=_spd_solver_mode(params.rank, (X, Y, u_t, i_t)),
+              solver=choice.name,
               precision=precision, refine=bool(params.solve_refine))
 
     def run_iters(Xc, Yc, n):
@@ -553,48 +358,56 @@ def train_als_bucketed_sharded(user_side: BucketedRatings,
     ckpt = _multihost_checkpointer(
         checkpoint_layout_bucketed(user_side, item_side), params,
         kw["solver"], precision, dtype, multi_host)
-    if ckpt is None:
-        X, Y = run_iters(X, Y, int(params.num_iterations))
-    else:
-        from predictionio_tpu.workflow import checkpoint as _checkpoint
-        from predictionio_tpu.workflow import runlog as _runlog
+    # the one-device trainer's root and span, so a trace says what this
+    # mesh resolved: every padded bucket row is one system a half-step
+    # (two with the refinement pass)
+    systems = sum(int(t[1].shape[0]) for t in u_t + i_t) \
+        * int(params.num_iterations) * (2 if kw["refine"] else 1)
+    with _tracing.trace_scope("als.train", slow_exempt=True), \
+            _tracing.span("als.iterations", attributes=dict(
+                solve_span_attributes(choice, systems),
+                devices=int(mesh.devices.size))):
+        if ckpt is None:
+            X, Y = run_iters(X, Y, int(params.num_iterations))
+        else:
+            from predictionio_tpu.workflow import checkpoint as _checkpoint
+            from predictionio_tpu.workflow import runlog as _runlog
 
-        fdt = X.dtype
-        objective = None
-        if _train_telemetry_enabled():
-            # closure over the PLACED bucket tuples (see _objective_pack:
-            # sharded inputs through the same jitted program)
-            obj_kw = _objective_statics(params)
+            fdt = X.dtype
+            objective = None
+            if _train_telemetry_enabled():
+                # closure over the PLACED bucket tuples (see _objective_pack:
+                # sharded inputs through the same jitted program)
+                obj_kw = _objective_statics(params)
 
-            def objective(Xc, Yc):
-                return _objective_pack(Xc, Yc, u_t, **obj_kw)
+                def objective(Xc, Yc):
+                    return _objective_pack(Xc, Yc, u_t, **obj_kw)
 
-        # same run-log header as the one-device trainer, with the mesh
-        # size the tables were actually sharded over
-        with _runlog.run_context_scope(
-                solver=kw["solver"], precision=precision,
-                trainedPairs=user_side.nnz, devices=ndev):
-            X, Y = _checkpoint.run_chunked(
-                run_iters, X, Y, int(params.num_iterations), ckpt,
-                to_host=lambda a: np.asarray(a, dtype=np.float32),
-                from_host=lambda a: put(jnp.asarray(a, dtype=fdt), repl),
-                objective=objective)
+            # same run-log header as the one-device trainer, with the mesh
+            # size the tables were actually sharded over
+            with _runlog.run_context_scope(
+                    solver=kw["solver"], precision=precision,
+                    trainedPairs=user_side.nnz, devices=ndev):
+                X, Y = _checkpoint.run_chunked(
+                    run_iters, X, Y, int(params.num_iterations), ckpt,
+                    to_host=lambda a: np.asarray(a, dtype=np.float32),
+                    from_host=lambda a: put(jnp.asarray(a, dtype=fdt), repl),
+                    objective=objective)
+        jax.block_until_ready((X, Y))
     if not gather:
         # PAlgorithm flavor: factors stay in HBM in their sharded
         # placement (rows padded to the factor divisor, bf16 under the
         # bf16 policy); serve via ops.serving.DeviceTopK with the true
         # n_users/n_items bounds
         return X, Y
-    # host factors always land fp32 (see ops.als.train_als)
+    # host factors always land fp32 (see ops.als.train_als_bucketed)
     return (np.asarray(X, dtype=np.float32)[:user_side.n_rows],
             np.asarray(Y, dtype=np.float32)[:item_side.n_rows])
 
 
 def train_als_auto(user_side, item_side, params: ALSParams, dtype=None
                    ) -> Tuple[np.ndarray, np.ndarray]:
-    """Topology-aware trainer — what the templates call. Accepts either
-    uniform :class:`PaddedRatings` or length-bucketed
-    :class:`BucketedRatings` sides (the Preparator's choice).
+    """Topology-aware trainer — what the templates call.
 
     Multi-host runtime (``pio train --num-hosts K``): a global host-aware
     mesh so all hosts train ONE collective program over DCN+ICI.
@@ -604,71 +417,18 @@ def train_als_auto(user_side, item_side, params: ALSParams, dtype=None
     """
     import jax
 
-    from predictionio_tpu.ops.als import train_als, train_als_bucketed
+    from predictionio_tpu.ops.als import train_als_bucketed
 
-    bucketed = isinstance(user_side, BucketedRatings)
     if jax.process_count() > 1:
         from predictionio_tpu.parallel import distributed
 
-        mesh = distributed.host_aware_mesh()
-        if bucketed:
-            return train_als_bucketed_sharded(user_side, item_side,
-                                              params, mesh, dtype=dtype)
-        return train_als_sharded(user_side, item_side, params, mesh,
-                                 dtype=dtype)
+        return train_als_bucketed_sharded(
+            user_side, item_side, params, distributed.host_aware_mesh(),
+            dtype=dtype)
     from predictionio_tpu.parallel.mesh import data_parallel_mesh
 
     if len(jax.devices()) > 1:
-        if bucketed:
-            return train_als_bucketed_sharded(
-                user_side, item_side, params, data_parallel_mesh(),
-                dtype=dtype)
-        return train_als_sharded(user_side, item_side, params,
-                                 data_parallel_mesh(), dtype=dtype)
-    if bucketed:
-        return train_als_bucketed(user_side, item_side, params,
-                                  dtype=dtype)
-    return train_als(user_side, item_side, params, dtype=dtype)
-
-
-def sharded_train_step(mesh, rank: int, params: Optional[ALSParams] = None):
-    """Return (jitted_step_fn, sharding_specs) for ONE alternating
-    iteration — the unit the multichip dry-run compiles and executes."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    params = params or ALSParams(rank=rank, num_iterations=1)
-    row_sharded = NamedSharding(mesh, P("data", None))
-    replicated = NamedSharding(mesh, P(None, None))
-
-    fn = jax.jit(
-        _als_iterations_impl,
-        static_argnames=("lam", "alpha", "implicit", "num_iterations",
-                         "solver", "precision", "refine"),
-        out_shardings=(replicated, replicated),
-        donate_argnums=(0, 1),
-    )
-
-    def run(X, Y, u_cols, u_w, u_m, i_cols, i_w, i_m):
-        import jax.numpy as jnp
-
-        put = jax.device_put
-        precision = _als_precision_mode(params)  # resolved per call
-        # the caller's host factors enter in the policy's storage dtype
-        # — under bf16 the step must actually exercise the half-width
-        # gather, not a mongrel fp32-store/bf16-weights lane
-        fdt = factor_dtype(precision)
-        placed = (put(jnp.asarray(X, dtype=fdt), replicated),
-                  put(jnp.asarray(Y, dtype=fdt), replicated),
-                  *(put(jnp.asarray(a), row_sharded)
-                    for a in (u_cols, u_w, u_m, i_cols, i_w, i_m)))
-        return fn(*placed,
-                  lam=float(params.lambda_), alpha=float(params.alpha),
-                  implicit=bool(params.implicit_prefs),
-                  num_iterations=1,
-                  # resolved per call, from what was just placed
-                  solver=_spd_solver_mode(params.rank, placed),
-                  precision=precision,
-                  refine=bool(params.solve_refine))
-
-    return run, {"rows": row_sharded, "factors": replicated}
+        return train_als_bucketed_sharded(
+            user_side, item_side, params, data_parallel_mesh(),
+            dtype=dtype)
+    return train_als_bucketed(user_side, item_side, params, dtype=dtype)

@@ -44,8 +44,8 @@ from predictionio_tpu.parallel.als_sharding import (
 )
 from predictionio_tpu.ops.als import (
     ALSParams,
+    bucket_ratings_pair,
     cosine_scores,
-    pad_ratings,
     predict_scores_for_user,
 )
 
@@ -204,8 +204,7 @@ class ECommAlgorithm(P2LAlgorithm):
                     template="ecommercerecommendation",
                     nUsers=n_u, nItems=n_i):
             X, Y = _train_als_auto(
-                pad_ratings(rows, cols, vals, n_u, n_i),
-                pad_ratings(cols, rows, vals, n_i, n_u),
+                *bucket_ratings_pair(rows, cols, vals, n_u, n_i),
                 ALSParams(rank=p.rank, num_iterations=p.num_iterations,
                           lambda_=p.lambda_,
                           seed=0 if p.seed is None else p.seed))

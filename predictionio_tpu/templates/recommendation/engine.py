@@ -45,8 +45,8 @@ from predictionio_tpu.data.bimap import BiMap, StringIndexBiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops.als import (
     ALSParams,
-    PaddedRatings,
-    pad_ratings,
+    BucketedRatings,
+    bucket_ratings_pair,
 )
 
 
@@ -386,8 +386,8 @@ class PreparedData:
 
     user_map: StringIndexBiMap
     item_map: StringIndexBiMap
-    user_side: PaddedRatings
-    item_side: PaddedRatings
+    user_side: BucketedRatings
+    item_side: BucketedRatings
     seen: Dict[int, np.ndarray]  # user idx -> item idx array (for blacklist)
     # filter-by-category variant: item idx -> categories (None = unread)
     item_categories: Optional[Dict[int, Tuple[str, ...]]] = None
@@ -399,16 +399,13 @@ class PreparedData:
 
 @dataclasses.dataclass(frozen=True)
 class PreparatorParams(Params):
-    """``bucketed=True`` lays the ratings out as length buckets
-    (``ops.als.bucket_ratings_pair``): each row pads only to its own
-    length class, so the solves stop multiplying longest-row padding
-    AND nothing is truncated — 100% pair coverage at any scale (the
-    full-RDD semantics of ``ALS.trainImplicit``). The recommended
-    layout at 10M+ events.
+    """``max_len`` bounds the padded row length (keeping the
+    largest-magnitude ratings per row); unset, every pair trains (the
+    full-RDD semantics of ``ALS.trainImplicit``).
 
-    ``max_len`` bounds the padded row length (keeping the
-    largest-magnitude ratings per row); with ``bucketed=False`` it is
-    what kept the uniform [N, L] table affordable at scale."""
+    ``bucketed`` is no longer read: the layout is always length buckets
+    (``ops.als.bucket_ratings_pair``). The field goes when the
+    benchmark stops passing it (ROADMAP Design 7j)."""
 
     max_len: Optional[int] = None
     bucketed: bool = False
@@ -442,16 +439,8 @@ class RatingsPreparator(PPreparator):
             vals = np.asarray(td.values, dtype=np.float32)
         n_u, n_i = len(user_map), len(item_map)
         max_len = getattr(self.params, "max_len", None)
-        if getattr(self.params, "bucketed", False):
-            from predictionio_tpu.ops.als import bucket_ratings_pair
-
-            user_side, item_side = bucket_ratings_pair(
-                rows, cols, vals, n_u, n_i, max_len=max_len)
-        else:
-            user_side = pad_ratings(rows, cols, vals, n_u, n_i,
-                                    max_len=max_len)
-            item_side = pad_ratings(cols, rows, vals, n_i, n_u,
-                                    max_len=max_len)
+        user_side, item_side = bucket_ratings_pair(
+            rows, cols, vals, n_u, n_i, max_len=max_len)
         # per-user seen-item lists via one stable sort (vs n_u boolean scans)
         order = np.argsort(rows, kind="stable")
         s_rows, s_cols = rows[order], cols[order]

@@ -45,7 +45,11 @@ from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.parallel.als_sharding import (
     train_als_auto as _train_als_auto,
 )
-from predictionio_tpu.ops.als import ALSParams, cosine_scores, pad_ratings
+from predictionio_tpu.ops.als import (
+    ALSParams,
+    bucket_ratings_pair,
+    cosine_scores,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -253,8 +257,7 @@ def _factors_from_ratings(ratings: Dict[Tuple[int, int], float],
                        lambda_=p.lambda_,
                        seed=0 if p.seed is None else p.seed)
     return _train_als_auto(
-        pad_ratings(keys[:, 0], keys[:, 1], vals, n_rows, n_cols),
-        pad_ratings(keys[:, 1], keys[:, 0], vals, n_cols, n_rows),
+        *bucket_ratings_pair(keys[:, 0], keys[:, 1], vals, n_rows, n_cols),
         params)
 
 

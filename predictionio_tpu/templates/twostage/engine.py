@@ -28,7 +28,7 @@ from predictionio_tpu.controller import Engine, Params, PPreparator
 from predictionio_tpu.controller.controllers import TwoStageServing
 from predictionio_tpu.core.context import ComputeContext
 from predictionio_tpu.data.bimap import StringIndexBiMap
-from predictionio_tpu.ops.als import pad_ratings
+from predictionio_tpu.ops.als import bucket_ratings_pair
 from predictionio_tpu.ops.seqrec import bucket_sequences
 from predictionio_tpu.templates.recommendation.engine import (
     ALSAlgorithm,
@@ -98,10 +98,8 @@ class TwoStagePreparator(PPreparator):
         cols = cols.astype(np.int64)
         n_u, n_i = len(user_map), len(item_map)
         vals = np.ones(len(rows), dtype=np.float32)
-        user_side = pad_ratings(rows, cols, vals, n_u, n_i,
-                                max_len=p.max_len)
-        item_side = pad_ratings(cols, rows, vals, n_i, n_u,
-                                max_len=p.max_len)
+        user_side, item_side = bucket_ratings_pair(
+            rows, cols, vals, n_u, n_i, max_len=p.max_len)
         # time-ordered per-user runs for the sequence side, seen sets
         # for serving — one stable sort each (the source templates'
         # vectorized discipline)

@@ -764,8 +764,8 @@ def run_chunked(run_iters: Callable[[Any, Any, int], Tuple[Any, Any]],
     program — guard finiteness on device, save an atomic checkpoint,
     and honor the preemption flag. ``to_host``/``from_host`` are the
     caller's placement policy (plain ``np.asarray`` fp32 / a
-    dtype-and-sharding-preserving put), so uniform, bucketed and
-    single-host sharded trainers all share this one driver.
+    dtype-and-sharding-preserving put), so the one-device and the
+    single-host sharded trainer share this one driver.
 
     ``objective`` (when telemetry is on) returns the fused
     ``[fit, l2, finite]`` pack for the current carries; it replaces the

@@ -29,11 +29,10 @@ def raw_triples():
 
 
 def make_problem():
-    from predictionio_tpu.ops.als import ALSParams, pad_ratings
+    from predictionio_tpu.ops.als import ALSParams, bucket_ratings_pair
 
-    rows, cols, vals = raw_triples()
-    user_side = pad_ratings(rows, cols, vals, N_USERS, N_ITEMS)
-    item_side = pad_ratings(cols, rows, vals, N_ITEMS, N_USERS)
+    user_side, item_side = bucket_ratings_pair(*raw_triples(), N_USERS,
+                                               N_ITEMS)
     return user_side, item_side, ALSParams(rank=4, num_iterations=3,
                                            seed=0)
 
@@ -45,7 +44,9 @@ def main() -> None:
     import numpy as np
 
     from predictionio_tpu.parallel import distributed
-    from predictionio_tpu.parallel.als_sharding import train_als_sharded
+    from predictionio_tpu.parallel.als_sharding import (
+        train_als_bucketed_sharded,
+    )
 
     cfg = distributed.DistributedConfig(
         coordinator=coordinator, num_hosts=num_hosts, process_id=process_id)
@@ -55,21 +56,9 @@ def main() -> None:
 
     user_side, item_side, params = make_problem()
 
+    # each host contributes its row block of every bucket table
     mesh = distributed.host_aware_mesh()
-    X, Y = train_als_sharded(user_side, item_side, params, mesh)
-
-    # the bucketed layout over the same global mesh (each host
-    # contributes its row block of every bucket table) must land on the
-    # same factors
-    from predictionio_tpu.ops.als import bucket_ratings_pair
-    from predictionio_tpu.parallel.als_sharding import (
-        train_als_bucketed_sharded,
-    )
-
-    rows, cols, vals = raw_triples()
-    ub, ib = bucket_ratings_pair(rows, cols, vals, user_side.n_rows,
-                                 item_side.n_rows)
-    Xb, Yb = train_als_bucketed_sharded(ub, ib, params, mesh)
+    X, Y = train_als_bucketed_sharded(user_side, item_side, params, mesh)
 
     print(json.dumps({
         "process_id": process_id,
@@ -77,9 +66,6 @@ def main() -> None:
         "x_sum": float(np.abs(X).sum()),
         "y_sum": float(np.abs(Y).sum()),
         "x_row0": [float(v) for v in X[0]],
-        "bucketed_x_sum": float(np.abs(Xb).sum()),
-        "bucketed_max_dx": float(np.abs(Xb - X).max()),
-        "bucketed_max_dy": float(np.abs(Yb - Y).max()),
     }), flush=True)
     distributed.shutdown()
 

@@ -1,17 +1,17 @@
-"""ALS kernel tests: padding, convergence, and numerics vs a plain-numpy
+"""ALS kernel tests: convergence, and numerics vs a plain-numpy
 reference implementation of the same normal equations (capability parity
 check for MLlib ALS.trainImplicit as used by the recommendation template)."""
 
 import numpy as np
 import pytest
 
+from als_reference import numpy_half_step, train_from_triples
 from predictionio_tpu.ops.als import (
     ALSParams,
+    bucket_ratings,
     cosine_scores,
-    pad_ratings,
     predict_scores_for_user,
     top_k_items,
-    train_als,
 )
 
 RNG = np.random.default_rng(42)
@@ -31,78 +31,42 @@ def synthetic_ratings(n_users=60, n_items=40, rank=4, density=0.3, seed=0):
     return rows[keep], cols[keep], vals[keep].astype(np.float32)
 
 
-class TestPadding:
-    def test_pad_shapes_and_weights(self):
-        rows = np.array([0, 0, 2, 2, 2])
-        cols = np.array([1, 3, 0, 1, 2])
-        vals = np.array([1.0, 2.0, 3.0, 4.0, 5.0], dtype=np.float32)
-        pr = pad_ratings(rows, cols, vals, n_rows=4, n_cols=4)
-        assert pr.cols.shape == pr.weights.shape == (4, 8)  # padded to 8
-        # row 1 empty -> all zero weights
-        assert pr.weights[1].sum() == 0
-        # row 2 has its three ratings (column order — heaviest-first
-        # ordering applies only when a max_len cut is active)
-        assert sorted(pr.weights[2][pr.weights[2] > 0].tolist()) == [3, 4, 5]
-        assert pr.weights[2][:3].tolist() == [3.0, 4.0, 5.0]
-
-    def test_duplicates_are_summed(self):
-        # reduceByKey(_ + _) parity (custom-query ALSAlgorithm.scala:50)
-        rows = np.array([0, 0, 0])
-        cols = np.array([1, 1, 2])
-        vals = np.array([1.0, 1.0, 1.0], dtype=np.float32)
-        pr = pad_ratings(rows, cols, vals, n_rows=1, n_cols=3)
-        w = sorted(pr.weights[0][pr.weights[0] > 0].tolist())
-        assert w == [1.0, 2.0]
-
-    def test_max_len_truncates_keeping_heaviest(self):
-        rows = np.zeros(10, dtype=int)
-        cols = np.arange(10)
-        vals = np.arange(1, 11, dtype=np.float32)
-        pr = pad_ratings(rows, cols, vals, 1, 10, pad_multiple=1, max_len=3)
-        assert pr.max_len == 3
-        assert sorted(pr.weights[0].tolist()) == [8.0, 9.0, 10.0]
-
-
-def numpy_implicit_als_step(Y, rows, cols, vals, n_rows, lam, alpha):
-    """Reference solve: per-row dense normal equations, no padding."""
-    R = Y.shape[1]
-    gram = Y.T @ Y
-    X = np.zeros((n_rows, R), dtype=np.float64)
-    for u in range(n_rows):
-        sel = rows == u
-        if not sel.any():
-            continue
-        y = Y[cols[sel]]                      # [nnz, R]
-        r = vals[sel]
-        A = gram + (y.T * (alpha * r)) @ y + lam * np.eye(R)
-        b = ((1.0 + alpha * r)[:, None] * y).sum(axis=0)
-        X[u] = np.linalg.solve(A, b)
-    return X
+# None solves each bucket in one dispatch; 256 slots split the largest
+# bucket of every fixture below (320 to 1,024 slots) into lax.map blocks
+SLOT_BUDGETS = pytest.mark.parametrize("slot_budget", [None, 256])
 
 
 class TestNumerics:
-    def test_half_step_matches_numpy_reference(self):
-        """The padded einsum solve must agree with the dense per-row
-        reference to float32 tolerance."""
+    @SLOT_BUDGETS
+    @pytest.mark.parametrize("implicit", [True, False])
+    def test_half_step_matches_numpy_reference(self, implicit,
+                                               slot_budget):
+        """The bucketed einsum solve, whole or in ``lax.map`` blocks,
+        must agree with the dense per-row reference to float32
+        tolerance: Hu-Koren-Volinsky with dislikes among the ratings,
+        and ALS-WR's per-row ridge."""
         import jax.numpy as jnp
-        from predictionio_tpu.ops.als import _solve_side
+        from predictionio_tpu.ops.als import (
+            _bucket_tables, _solve_side_bucketed)
 
         rows, cols, vals = synthetic_ratings(20, 15, 3, 0.4)
+        vals = np.where(np.arange(len(vals)) % 5 == 0, -vals, vals)
         n_users, n_items, rank = 20, 15, 5
         Y = RNG.normal(size=(n_items, rank)).astype(np.float32)
-        pr = pad_ratings(rows, cols, vals, n_users, n_items)
-        got = np.asarray(_solve_side(
-            jnp.asarray(Y), jnp.asarray(pr.cols), jnp.asarray(pr.weights),
-            jnp.asarray(pr.mask), lam=0.1, alpha=1.0, implicit=True))
-        want = numpy_implicit_als_step(
-            Y.astype(np.float64), rows, cols, vals, n_users, 0.1, 1.0)
+        tables, = _bucket_tables(
+            bucket_ratings(rows, cols, vals, n_users, n_items))
+        assert tables[0][1].shape == (24, 8)
+        got = np.asarray(_solve_side_bucketed(
+            jnp.asarray(Y), tables, n_users, lam=0.1, alpha=1.0,
+            implicit=implicit,
+            slot_budget=slot_budget and 64))   # blocks of 8 rows
+        want = numpy_half_step(Y, rows, cols, vals, n_users, 0.1, 1.0,
+                               implicit=implicit)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
     def test_training_reduces_loss(self):
         rows, cols, vals = synthetic_ratings()
         n_users, n_items = 60, 40
-        user_side = pad_ratings(rows, cols, vals, n_users, n_items)
-        item_side = pad_ratings(cols, rows, vals, n_items, n_users)
 
         def implicit_loss(X, Y):
             P = np.zeros((n_users, n_items))
@@ -113,38 +77,43 @@ class TestNumerics:
             return float((C * E * E).sum())
 
         params0 = ALSParams(rank=8, num_iterations=1, lambda_=0.01, seed=7)
-        X1, Y1 = train_als(user_side, item_side, params0)
+        X1, Y1 = train_from_triples(rows, cols, vals, n_users, n_items,
+                                    params0)
         params = ALSParams(rank=8, num_iterations=10, lambda_=0.01, seed=7)
-        X, Y = train_als(user_side, item_side, params)
+        X, Y = train_from_triples(rows, cols, vals, n_users, n_items,
+                                  params)
         assert implicit_loss(X, Y) < implicit_loss(X1, Y1) * 0.9
 
-    def test_recovers_preferences(self):
+    @SLOT_BUDGETS
+    def test_recovers_preferences(self, slot_budget):
         """Observed pairs must outscore unobserved ones on average."""
         rows, cols, vals = synthetic_ratings()
         n_users, n_items = 60, 40
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, n_users, n_items),
-            pad_ratings(cols, rows, vals, n_items, n_users),
-            ALSParams(rank=8, num_iterations=10, lambda_=0.05, seed=3))
+        X, Y = train_from_triples(
+            rows, cols, vals, n_users, n_items,
+            ALSParams(rank=8, num_iterations=10, lambda_=0.05, seed=3,
+                      bucket_slot_budget=slot_budget))
         S = X @ Y.T
         observed = np.zeros((n_users, n_items), dtype=bool)
         observed[rows, cols] = True
         assert S[observed].mean() > S[~observed].mean() + 0.2
 
-    def test_explicit_mode(self):
+    @SLOT_BUDGETS
+    def test_explicit_mode(self, slot_budget):
         rows, cols, vals = synthetic_ratings()
         n_users, n_items = 60, 40
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, n_users, n_items),
-            pad_ratings(cols, rows, vals, n_items, n_users),
+        X, Y = train_from_triples(
+            rows, cols, vals, n_users, n_items,
             ALSParams(rank=8, num_iterations=10, lambda_=0.1,
-                      implicit_prefs=False, seed=3))
+                      implicit_prefs=False, seed=3,
+                      bucket_slot_budget=slot_budget))
         pred = (X @ Y.T)[rows, cols]
         # explicit mode regresses the rating values themselves
         err = np.abs(pred - vals).mean() / vals.mean()
         assert err < 0.35
 
-    def test_implicit_mode_negative_signal_stays_finite(self):
+    @SLOT_BUDGETS
+    def test_implicit_mode_negative_signal_stays_finite(self, slot_budget):
         """Implicit mode with negative ratings (dislikes): confidence uses
         |r|, preference r>0 — factors stay finite and dislikes score below
         likes (MLlib trainImplicit semantics)."""
@@ -154,13 +123,13 @@ class TestNumerics:
         cols = rng.integers(0, n_items, rows.shape[0])
         vals = np.where(rng.random(rows.shape[0]) < 0.3, -5.0,
                         1.0 + 2 * rng.random(rows.shape[0])).astype(np.float32)
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, n_users, n_items),
-            pad_ratings(cols, rows, vals, n_items, n_users),
-            ALSParams(rank=6, num_iterations=8, lambda_=0.05, seed=1))
+        X, Y = train_from_triples(
+            rows, cols, vals, n_users, n_items,
+            ALSParams(rank=6, num_iterations=8, lambda_=0.05, seed=1,
+                      bucket_slot_budget=slot_budget))
         assert np.isfinite(X).all() and np.isfinite(Y).all()
         S = X @ Y.T
-        # pad_ratings sums duplicates, so score by the summed sign
+        # the tables sum duplicates, so score by the summed sign
         agg = {}
         for r, c, v in zip(rows, cols, vals):
             agg[(r, c)] = agg.get((r, c), 0.0) + v
@@ -168,7 +137,8 @@ class TestNumerics:
         disliked = np.array([S[r, c] for (r, c), v in agg.items() if v < 0])
         assert liked.mean() > disliked.mean() + 0.2
 
-    def test_explicit_mode_negative_and_zero_ratings(self):
+    @SLOT_BUDGETS
+    def test_explicit_mode_negative_and_zero_ratings(self, slot_budget):
         """Zero/negative explicit ratings are real observations, not
         padding: regression for the weights>0 masking bug."""
         rng = np.random.default_rng(5)
@@ -179,11 +149,11 @@ class TestNumerics:
         rows, cols = np.nonzero(rng.random((n_users, n_items)) < 0.6)
         vals = R[rows, cols].astype(np.float32)
         assert (vals < 0).any()
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, n_users, n_items),
-            pad_ratings(cols, rows, vals, n_items, n_users),
+        X, Y = train_from_triples(
+            rows, cols, vals, n_users, n_items,
             ALSParams(rank=rank, num_iterations=10, lambda_=0.05,
-                      implicit_prefs=False, seed=3))
+                      implicit_prefs=False, seed=3,
+                      bucket_slot_budget=slot_budget))
         pred = (X @ Y.T)[rows, cols]
         # negative ratings must be regressed toward negative predictions
         neg = vals < -0.5
@@ -191,103 +161,11 @@ class TestNumerics:
         err = np.abs(pred - vals).mean() / np.abs(vals).mean()
         assert err < 0.35
 
-    def test_blocked_solves_match_unblocked(self):
-        """solve_block_rows bounds HBM without changing the math: when
-        the row counts are block multiples (no pad rows, so the seeded
-        init is shape-identical) the factors match exactly."""
-        rows, cols, vals = synthetic_ratings(n_users=64, n_items=32,
-                                             seed=5)
-        us = pad_ratings(rows, cols, vals, 64, 32)
-        its = pad_ratings(cols, rows, vals, 32, 64)
-        base = ALSParams(rank=4, num_iterations=3, seed=2)
-        X0, Y0 = train_als(us, its, base)
-        import dataclasses as dc
-
-        X1, Y1 = train_als(us, its,
-                           dc.replace(base, solve_block_rows=16))
-        np.testing.assert_allclose(X0, X1, rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(Y0, Y1, rtol=1e-5, atol=1e-6)
-
-    def test_blocked_with_row_padding(self):
-        """Non-multiple row counts get padded internally; outputs keep
-        the true shapes and stay finite/useful."""
-        rows, cols, vals = synthetic_ratings(n_users=50, n_items=30,
-                                             seed=6)
-        us = pad_ratings(rows, cols, vals, 50, 30)
-        its = pad_ratings(cols, rows, vals, 30, 50)
-        X, Y = train_als(us, its, ALSParams(rank=4, num_iterations=3,
-                                            seed=2, solve_block_rows=16))
-        assert X.shape == (50, 4) and Y.shape == (30, 4)
-        assert np.isfinite(X).all() and np.isfinite(Y).all()
-        # learned something: observed pairs outscore random unobserved
-        obs = (X[rows] * Y[cols]).sum(axis=1).mean()
-        rng = np.random.default_rng(0)
-        ur, uc = rng.integers(0, 50, 500), rng.integers(0, 30, 500)
-        rand = (X[ur] * Y[uc]).sum(axis=1).mean()
-        assert obs > rand
-
-    def test_prepadded_sides_match_internal_padding(self):
-        """Callers may pad to the block multiple THEMSELVES (to stage
-        device tables once, like the scale bench) — results must be
-        identical to letting train_als pad, because n_valid_rows keeps
-        the pad-row zeroing and final slicing intact."""
-        from predictionio_tpu.ops.als import pad_rows_to_block
-
-        rows, cols, vals = synthetic_ratings(n_users=50, n_items=30,
-                                             seed=7)
-        us = pad_ratings(rows, cols, vals, 50, 30)
-        its = pad_ratings(cols, rows, vals, 30, 50)
-        params = ALSParams(rank=4, num_iterations=2, seed=3,
-                           solve_block_rows=16)
-        Xa, Ya = train_als(us, its, params)                   # internal pad
-        usp = pad_rows_to_block(us, 16)
-        itp = pad_rows_to_block(its, 16)
-        assert usp.n_valid_rows == 50 and itp.n_valid_rows == 30
-        Xb, Yb = train_als(usp, itp, params)                  # pre-padded
-        assert Xb.shape == (50, 4) and Yb.shape == (30, 4)
-        np.testing.assert_allclose(Xa, Xb, rtol=1e-6)
-        np.testing.assert_allclose(Ya, Yb, rtol=1e-6)
-
-    def test_blocked_padding_rows_never_pollute_gram(self):
-        """Regression: _pad_rows-added rows must enter the shared Gram
-        term as ZEROS from iteration one (the random init fills them
-        too). Oracle: unblocked iterations on the same padded problem
-        with explicitly zeroed pad-row init."""
-        import jax.numpy as jnp
-
-        from predictionio_tpu.ops.als import (
-            _als_iterations_impl, _pad_rows, init_factors,
-        )
-
-        rows, cols, vals = synthetic_ratings(n_users=50, n_items=30,
-                                             seed=7)
-        us = pad_ratings(rows, cols, vals, 50, 30)
-        its = pad_ratings(cols, rows, vals, 30, 50)
-        params = ALSParams(rank=4, num_iterations=2, seed=3,
-                           solve_block_rows=16)
-        Xb, Yb = train_als(us, its, params)
-
-        usp, itp = _pad_rows(us, 16), _pad_rows(its, 16)  # 64 / 32 rows
-        X0, Y0 = init_factors(usp.n_rows, itp.n_rows, 4, 3)
-        X0, Y0 = X0.at[50:].set(0.0), Y0.at[30:].set(0.0)
-        Xo, Yo = _als_iterations_impl(
-            X0, Y0, jnp.asarray(usp.cols), jnp.asarray(usp.weights),
-            jnp.asarray(usp.mask), jnp.asarray(itp.cols),
-            jnp.asarray(itp.weights), jnp.asarray(itp.mask),
-            lam=0.01, alpha=1.0, implicit=True, num_iterations=2)
-        np.testing.assert_allclose(Xb, np.asarray(Xo)[:50], rtol=1e-5,
-                                   atol=1e-6)
-        np.testing.assert_allclose(Yb, np.asarray(Yo)[:30], rtol=1e-5,
-                                   atol=1e-6)
-
     def test_deterministic_given_seed(self):
         rows, cols, vals = synthetic_ratings(20, 15, 3, 0.4)
-        a = train_als(pad_ratings(rows, cols, vals, 20, 15),
-                      pad_ratings(cols, rows, vals, 15, 20),
-                      ALSParams(rank=4, num_iterations=3, seed=11))
-        b = train_als(pad_ratings(rows, cols, vals, 20, 15),
-                      pad_ratings(cols, rows, vals, 15, 20),
-                      ALSParams(rank=4, num_iterations=3, seed=11))
+        params = ALSParams(rank=4, num_iterations=3, seed=11)
+        a = train_from_triples(rows, cols, vals, 20, 15, params)
+        b = train_from_triples(rows, cols, vals, 20, 15, params)
         np.testing.assert_array_equal(a[0], b[0])
         np.testing.assert_array_equal(a[1], b[1])
 

@@ -1,17 +1,16 @@
-"""Length-bucketed ALS: numerics identical to the uniform padded path,
-occupancy several-fold better on power-law data, nothing truncated by
+"""Length-bucketed ALS: numerics held to the plain-numpy trainer over
+the triples, few padded slots on power-law data, nothing truncated by
 default (100% unique-pair coverage — MLlib's full-RDD semantics,
 custom-query ALSAlgorithm.scala:64-71)."""
 
 import numpy as np
 import pytest
 
+from als_reference import numpy_train_als
 from predictionio_tpu.ops.als import (
     ALSParams,
     bucket_ratings,
     dedup_sum_ratings,
-    pad_ratings,
-    train_als,
     train_als_bucketed,
 )
 
@@ -62,14 +61,23 @@ class TestBucketConstruction:
             4096, 10)
         assert side.nnz == 4096 * 4100 + 3
 
-    def test_occupancy_beats_uniform_padding(self):
+    def test_occupancy_beats_longest_row_padding(self):
         rows, cols, vals = powerlaw_triples(n_users=800, n_items=600,
                                             nnz=8000)
         b = bucket_ratings(rows, cols, vals, 800, 600)
-        uniform = pad_ratings(rows, cols, vals, 800, 600)
-        uniform_slots = uniform.cols.size
-        assert b.padded_slots < uniform_slots / 3
+        counts = np.bincount(dedup_sum_ratings(rows, cols, vals, 600)[0],
+                             minlength=800)
+        # one table padded to the longest row, counted by hand
+        longest_row_slots = 800 * (-(-int(counts.max()) // 8) * 8)
+        assert b.padded_slots < longest_row_slots / 3
         assert b.occupancy > 0.3
+        # each bucket's slots, counted by hand: its rows (to a multiple
+        # of 8) times its own length
+        lengths = np.asarray(sorted(bk.max_len for bk in b.buckets))
+        of_row = lengths[np.searchsorted(lengths, counts[counts > 0])]
+        want = sum(-(-int((of_row == L).sum()) // 8) * 8 * int(L)
+                   for L in lengths)
+        assert b.padded_slots == want
 
     def test_each_row_in_smallest_fitting_bucket(self):
         rows, cols, vals = powerlaw_triples()
@@ -106,24 +114,23 @@ class TestBucketConstruction:
 
 
 class TestBucketedTraining:
-    @pytest.mark.parametrize("implicit", [True, False])
-    def test_matches_uniform_path(self, implicit):
+    @pytest.mark.parametrize("implicit,dislikes", [
+        (True, False), (False, False), (True, True)])
+    def test_matches_numpy_trainer(self, implicit, dislikes):
         rows, cols, vals = powerlaw_triples()
+        if dislikes:   # MLlib trainImplicit: confidence |r|, pref r > 0
+            vals = np.where(vals == 1.0, -3.0, vals)
         params = ALSParams(rank=8, num_iterations=3, lambda_=0.05,
                            alpha=1.0, implicit_prefs=implicit, seed=4)
-        Xu, Yu = train_als(pad_ratings(rows, cols, vals, 220, 90),
-                           pad_ratings(cols, rows, vals, 90, 220), params)
+        Xn, Yn = numpy_train_als(rows, cols, vals, 220, 90, params)
         Xb, Yb = train_als_bucketed(
             bucket_ratings(rows, cols, vals, 220, 90),
             bucket_ratings(cols, rows, vals, 90, 220), params)
-        # triaged (PR 6): the two layouts batch the einsums differently
-        # (per-bucket vs one table), so fp32 reduction order differs;
-        # on this CPU/BLAS the explicit lane (ALS-WR lambda*n scaling,
-        # larger dynamic range) left 3/1760 entries at rel ~3e-3 vs the
-        # old 2e-4 gate. 5e-3 still fails loudly on any real layout bug
-        # (those diverge by O(1)).
-        np.testing.assert_allclose(Xb, Xu, rtol=5e-3, atol=2e-5)
-        np.testing.assert_allclose(Yb, Yu, rtol=5e-3, atol=2e-5)
+        # float32 einsums against float64 per-row solves; the explicit
+        # lane (ALS-WR lambda*n scaling, larger dynamic range) is the
+        # looser one. A layout bug diverges by O(1).
+        np.testing.assert_allclose(Xb, Xn, rtol=5e-3, atol=2e-5)
+        np.testing.assert_allclose(Yb, Yn, rtol=5e-3, atol=2e-5)
 
     def test_slot_budget_blocked_solves_match(self):
         rows, cols, vals = powerlaw_triples(nnz=3000)
@@ -151,15 +158,25 @@ class TestBucketedTraining:
         assert X.shape == (220, 6) and Y.shape == (90, 6)
         assert np.isfinite(X).all() and np.isfinite(Y).all()
 
-    def test_duplicates_summed_like_uniform(self):
+    def test_duplicates_summed(self):
+        # reduceByKey(_ + _) parity (custom-query ALSAlgorithm.scala:50)
         rows = np.asarray([0, 0, 1, 1, 1])
         cols = np.asarray([2, 2, 0, 0, 1])
         vals = np.asarray([1.0, 2.0, 3.0, 1.0, 5.0], dtype=np.float32)
-        params = ALSParams(rank=4, num_iterations=2, seed=7)
-        Xu, Yu = train_als(pad_ratings(rows, cols, vals, 2, 3),
-                           pad_ratings(cols, rows, vals, 3, 2), params)
+        side = bucket_ratings(rows, cols, vals, 2, 3)
+        [bk] = side.buckets
+        assert bk.weights[0][bk.mask[0] > 0].tolist() == [3.0]
+        assert bk.weights[1][bk.mask[1] > 0].tolist() == [4.0, 5.0]
+        # and the trainer sees the summed pairs: the numpy trainer over
+        # the three unique pairs gives the same factors
+        # (a ridge that conditions rows of one and two pairs at rank 4:
+        # float32 then tracks float64 to 1e-4, and unsummed duplicates
+        # move the factors by O(1))
+        params = ALSParams(rank=4, num_iterations=2, lambda_=0.5, seed=7)
+        Xn, Yn = numpy_train_als(
+            np.asarray([0, 1, 1]), np.asarray([2, 0, 1]),
+            np.asarray([3.0, 4.0, 5.0]), 2, 3, params)
         Xb, Yb = train_als_bucketed(
-            bucket_ratings(rows, cols, vals, 2, 3),
-            bucket_ratings(cols, rows, vals, 3, 2), params)
-        np.testing.assert_allclose(Xb, Xu, rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(Yb, Yu, rtol=1e-5, atol=1e-6)
+            side, bucket_ratings(cols, rows, vals, 3, 2), params)
+        np.testing.assert_allclose(Xb, Xn, rtol=2e-3, atol=1e-4)
+        np.testing.assert_allclose(Yb, Yn, rtol=2e-3, atol=1e-4)

@@ -17,14 +17,12 @@ import jax.numpy as jnp
 
 from predictionio_tpu.ops.als import (
     ALSParams,
-    _als_iterations,
     _als_iterations_bucketed,
     _als_precision_mode,
     _spd_solver_mode,
     bucket_ratings,
+    bucket_ratings_pair,
     init_factors,
-    pad_ratings,
-    train_als,
     train_als_bucketed,
 )
 
@@ -67,10 +65,9 @@ class TestPolicyPlumbing:
         monkeypatch.delenv("PIO_ALS_PRECISION", raising=False)
         rows, cols, vals = random_stream(20, 15, 100, 0)
         with pytest.raises(ValueError, match="precision"):
-            train_als(pad_ratings(rows, cols, vals, 20, 15),
-                      pad_ratings(cols, rows, vals, 15, 20),
-                      ALSParams(rank=4, num_iterations=1,
-                                precision="turbo"))
+            train_als_bucketed(
+                *bucket_ratings_pair(rows, cols, vals, 20, 15),
+                ALSParams(rank=4, num_iterations=1, precision="turbo"))
 
     def test_env_overrides_params(self, monkeypatch):
         monkeypatch.setenv("PIO_ALS_PRECISION", "bf16")
@@ -84,94 +81,61 @@ class TestPolicyPlumbing:
         take effect WITHOUT clearing any jit cache (regression mirror
         of the PIO_ALS_SOLVER trace-time-read test)."""
         rows, cols, vals = random_stream(40, 25, 400, 3)
-        us = pad_ratings(rows, cols, vals, 40, 25)
-        its = pad_ratings(cols, rows, vals, 25, 40)
+        us, its = bucket_ratings_pair(rows, cols, vals, 40, 25)
         params = ALSParams(rank=8, num_iterations=3, seed=2)
 
         monkeypatch.delenv("PIO_ALS_PRECISION", raising=False)
-        X32, _ = train_als(us, its, params)
+        X32, _ = train_als_bucketed(us, its, params)
         monkeypatch.setenv("PIO_ALS_PRECISION", "bf16")
-        Xenv, _ = train_als(us, its, params)
+        Xenv, _ = train_als_bucketed(us, its, params)
         monkeypatch.delenv("PIO_ALS_PRECISION")
-        Xpar, _ = train_als(us, its, dc.replace(params, precision="bf16"))
+        Xpar, _ = train_als_bucketed(
+            us, its, dc.replace(params, precision="bf16"))
 
         # env-forced bf16 runs the exact program the params ask for...
         np.testing.assert_array_equal(Xenv, Xpar)
         # ...and it is genuinely the OTHER lane, not the cached fp32 one
         assert not np.array_equal(Xenv, X32)
         # flipping back re-selects the fp32 program bit-exactly
-        X32b, _ = train_als(us, its, params)
+        X32b, _ = train_als_bucketed(us, its, params)
         np.testing.assert_array_equal(X32, X32b)
 
     @pytest.mark.parametrize("precision", ["fp32", "bf16"])
-    def test_uniform_carry_buffers_are_donated(self, precision):
+    def test_bucketed_carry_buffers_are_donated(self, precision):
         """The X/Y carries of the jitted iteration loop are donated:
         after a train step the INPUT factor buffers must be invalidated
         (their HBM was reused for the outputs) — the no-copy contract
         the steady-state epoch rate depends on."""
-        rows, cols, vals = random_stream(40, 25, 400, 1)
-        us = pad_ratings(rows, cols, vals, 40, 25)
-        its = pad_ratings(cols, rows, vals, 25, 40)
-        X, Y = init_factors(40, 25, 8, 0)
-        if precision == "bf16":
-            X, Y = X.astype(jnp.bfloat16), Y.astype(jnp.bfloat16)
-        Xn, Yn = _als_iterations(
-            X, Y, jnp.asarray(us.cols), jnp.asarray(us.weights),
-            jnp.asarray(us.mask), jnp.asarray(its.cols),
-            jnp.asarray(its.weights), jnp.asarray(its.mask),
-            lam=0.01, alpha=1.0, implicit=True, num_iterations=1,
-            block=None, solver=_spd_solver_mode(8, (X, Y)),
-            precision=precision,
-            refine=False)
-        assert X.is_deleted() and Y.is_deleted()
-        assert np.isfinite(np.asarray(Xn, dtype=np.float32)).all()
-        with pytest.raises(RuntimeError, match="deleted"):
-            np.asarray(X)
-
-    def test_bucketed_carry_buffers_are_donated(self):
         rows, cols, vals = random_stream(40, 25, 400, 1)
         ub = bucket_ratings(rows, cols, vals, 40, 25)
         ib = bucket_ratings(cols, rows, vals, 25, 40)
         as_tuples = lambda s: tuple(  # noqa: E731
             (b.row_ids, b.cols, b.weights, b.mask) for b in s.buckets)
         X, Y = init_factors(40, 25, 8, 0)
+        if precision == "bf16":
+            X, Y = X.astype(jnp.bfloat16), Y.astype(jnp.bfloat16)
         Xn, _ = _als_iterations_bucketed(
             X, Y, as_tuples(ub), as_tuples(ib),
             lam=0.01, alpha=1.0, implicit=True, num_iterations=1,
             slot_budget=None, solver=_spd_solver_mode(8, (X, Y)),
-            precision="fp32", refine=False)
+            precision=precision, refine=False)
         assert X.is_deleted() and Y.is_deleted()
-        assert np.isfinite(np.asarray(Xn)).all()
+        assert np.isfinite(np.asarray(Xn, dtype=np.float32)).all()
+        with pytest.raises(RuntimeError, match="deleted"):
+            np.asarray(X)
 
     def test_host_factors_always_fp32(self):
         """Whatever the training policy, gathered host factors land
         float32 — persistence/serving/eval stay byte-compatible."""
         rows, cols, vals = random_stream(30, 20, 200, 4)
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, 30, 20),
-            pad_ratings(cols, rows, vals, 20, 30),
+        X, Y = train_als_bucketed(
+            *bucket_ratings_pair(rows, cols, vals, 30, 20),
             ALSParams(rank=4, num_iterations=2, seed=1,
                       precision="bf16"))
         assert X.dtype == np.float32 and Y.dtype == np.float32
 
 
 class TestDifferentialNumerics:
-    @pytest.mark.parametrize("seed", [0, 7, 23])
-    def test_padded_bf16_close_to_fp32(self, seed):
-        """bf16 vs fp32 on randomized PADDED streams: with fp32
-        accumulation the divergence stays at input-rounding scale (see
-        EPS_BF16 note), nowhere near bf16's raw ~0.4% * L drift."""
-        rows, cols, vals = random_stream(60, 40, 900, seed)
-        us = pad_ratings(rows, cols, vals, 60, 40)
-        its = pad_ratings(cols, rows, vals, 40, 60)
-        params = ALSParams(rank=8, num_iterations=3, seed=2)
-        X32, Y32 = train_als(us, its, params)
-        X16, Y16 = train_als(us, its,
-                             dc.replace(params, precision="bf16"))
-        iters = params.num_iterations
-        assert rel_err(X16, X32) < 4 * iters * EPS_BF16
-        assert rel_err(Y16, Y32) < 4 * iters * EPS_BF16
-
     @pytest.mark.parametrize("seed", [1, 11])
     def test_bucketed_bf16_close_to_fp32(self, seed):
         rows, cols, vals = random_stream(80, 50, 1500, seed)
@@ -185,22 +149,6 @@ class TestDifferentialNumerics:
         assert rel_err(X16, X32) < 4 * iters * EPS_BF16
         assert rel_err(Y16, Y32) < 4 * iters * EPS_BF16
 
-    def test_bucketed_and_padded_bf16_agree(self):
-        """The two bf16 layouts run the same per-row equations; they
-        may round in different accumulation orders but must stay within
-        one rounding scale of each other."""
-        rows, cols, vals = random_stream(60, 40, 900, 5)
-        params = ALSParams(rank=8, num_iterations=3, seed=2,
-                           precision="bf16")
-        Xp, Yp = train_als(pad_ratings(rows, cols, vals, 60, 40),
-                           pad_ratings(cols, rows, vals, 40, 60), params)
-        Xb, Yb = train_als_bucketed(
-            bucket_ratings(rows, cols, vals, 60, 40),
-            bucket_ratings(cols, rows, vals, 40, 60), params)
-        iters = params.num_iterations
-        assert rel_err(Xb, Xp) < 4 * iters * EPS_BF16
-        assert rel_err(Yb, Yp) < 4 * iters * EPS_BF16
-
     def test_explicit_mode_bf16(self):
         """The explicit ALS-WR lane under bf16 still regresses the
         ratings (same acceptance the fp32 lane's test uses)."""
@@ -211,9 +159,8 @@ class TestDifferentialNumerics:
         R = Xt @ Yt.T
         rows, cols = np.nonzero(rng.random((n_users, n_items)) < 0.6)
         vals = R[rows, cols].astype(np.float32)
-        X, Y = train_als(
-            pad_ratings(rows, cols, vals, n_users, n_items),
-            pad_ratings(cols, rows, vals, n_items, n_users),
+        X, Y = train_als_bucketed(
+            *bucket_ratings_pair(rows, cols, vals, n_users, n_items),
             ALSParams(rank=rank, num_iterations=10, lambda_=0.05,
                       implicit_prefs=False, seed=3, precision="bf16"))
         pred = (X @ Y.T)[rows, cols]
@@ -225,11 +172,10 @@ class TestDifferentialNumerics:
         trace, stay finite, and land within the same bf16-vs-fp32 band —
         it tightens the solve residual, never degrades it."""
         rows, cols, vals = random_stream(60, 40, 900, 9)
-        us = pad_ratings(rows, cols, vals, 60, 40)
-        its = pad_ratings(cols, rows, vals, 40, 60)
+        us, its = bucket_ratings_pair(rows, cols, vals, 60, 40)
         params = ALSParams(rank=8, num_iterations=3, seed=2)
-        X32, _ = train_als(us, its, params)
-        Xr, Yr = train_als(us, its, dc.replace(
+        X32, _ = train_als_bucketed(us, its, params)
+        Xr, Yr = train_als_bucketed(us, its, dc.replace(
             params, precision="bf16", solve_refine=True))
         assert np.isfinite(Xr).all() and np.isfinite(Yr).all()
         assert rel_err(Xr, X32) < 4 * params.num_iterations * EPS_BF16
@@ -238,16 +184,15 @@ class TestDifferentialNumerics:
         """The mesh-sharded trainer under bf16 stays in the same band
         as the single-device lane (virtual 8-device CPU mesh)."""
         from predictionio_tpu.parallel.als_sharding import (
-            train_als_sharded,
+            train_als_bucketed_sharded,
         )
         from predictionio_tpu.parallel.mesh import data_parallel_mesh
 
         rows, cols, vals = random_stream(64, 40, 900, 2)
-        us = pad_ratings(rows, cols, vals, 64, 40)
-        its = pad_ratings(cols, rows, vals, 40, 64)
+        us, its = bucket_ratings_pair(rows, cols, vals, 64, 40)
         params = ALSParams(rank=8, num_iterations=2, seed=2)
-        X32, _ = train_als(us, its, params)
-        Xs, Ys = train_als_sharded(
+        X32, _ = train_als_bucketed(us, its, params)
+        Xs, Ys = train_als_bucketed_sharded(
             us, its, dc.replace(params, precision="bf16"),
             data_parallel_mesh())
         assert Xs.dtype == np.float32

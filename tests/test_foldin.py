@@ -1,7 +1,7 @@
 """Online fold-in suite (PR 8): storage tail reads on all four event
 backends, the batch-k fold-in kernel's differential oracle against full
-``train_als`` rows, live-store patch atomicity under concurrent serving,
-the ``--foldin`` serving-backend policy, ``/reload`` hardening, and the
+``train_als_bucketed`` rows, live-store patch atomicity under concurrent
+serving, the ``--foldin`` serving-backend policy, ``/reload`` hardening, and the
 deployed end-to-end path (event -> servable in seconds, degradation when
 the tail fails)."""
 
@@ -20,11 +20,10 @@ from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage.base import AccessKey, App
 from predictionio_tpu.ops.als import (
     ALSParams,
+    bucket_ratings,
     bucket_ratings_pair,
     fold_in_users,
     init_factors,
-    pad_ratings,
-    train_als,
     train_als_bucketed,
 )
 
@@ -268,7 +267,7 @@ def _ragged_sets(rows, cols, vals, users):
 
 
 class TestFoldInDifferential:
-    """``train_als`` solves X against the initial Y in its FIRST
+    """``train_als_bucketed`` solves X against the initial Y in its FIRST
     half-iteration — so with ``num_iterations=1`` the returned user rows
     ARE "the full retrain's user rows given fixed item factors"
     (``init_factors`` is seed-deterministic, handing the oracle the
@@ -277,7 +276,7 @@ class TestFoldInDifferential:
 
     @pytest.mark.parametrize("precision", ["fp32", "bf16"])
     @pytest.mark.parametrize("implicit", [True, False])
-    def test_uniform_lane(self, precision, implicit):
+    def test_implicit_and_explicit_lanes(self, precision, implicit):
         rng = np.random.default_rng(11)
         n_u, n_i, nnz = 40, 25, 500
         rows = rng.integers(0, n_u, nnz)
@@ -285,9 +284,8 @@ class TestFoldInDifferential:
         vals = rng.uniform(1, 5, nnz).astype(np.float32)
         params = ALSParams(rank=8, num_iterations=1, seed=5,
                            implicit_prefs=implicit, precision=precision)
-        us = pad_ratings(rows, cols, vals, n_u, n_i)
-        it = pad_ratings(cols, rows, vals, n_i, n_u)
-        X1, _ = train_als(us, it, params)
+        us, it = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
+        X1, _ = train_als_bucketed(us, it, params)
         _, Y0 = init_factors(n_u, n_i, 8, 5)
         touched = rng.choice(n_u, size=9, replace=False)
         folded = fold_in_users(
@@ -324,7 +322,7 @@ class TestFoldInDifferential:
         different objective than their trained rows."""
         rng = np.random.default_rng(17)
         # ~26 distinct ratings/user; max_len=10 is deliberately NOT a
-        # multiple of pad_ratings' pad_multiple (8): training rounds the
+        # multiple of the tables' pad_multiple (8): training rounds the
         # cap up to 16 before cutting, and the fold must cut at the same
         # EFFECTIVE cap — truncating at the raw 10 silently solves a
         # smaller problem than the trained rows did
@@ -333,9 +331,9 @@ class TestFoldInDifferential:
         cols = rng.integers(0, n_i, nnz)
         vals = rng.uniform(1, 5, nnz).astype(np.float32)
         params = ALSParams(rank=6, num_iterations=1, seed=9)
-        us = pad_ratings(rows, cols, vals, n_u, n_i, max_len=10)
-        it = pad_ratings(cols, rows, vals, n_i, n_u)
-        X1, _ = train_als(us, it, params)
+        us = bucket_ratings(rows, cols, vals, n_u, n_i, max_len=10)
+        it = bucket_ratings(cols, rows, vals, n_i, n_u)
+        X1, _ = train_als_bucketed(us, it, params)
         _, Y0 = init_factors(n_u, n_i, 6, 9)
         touched = rng.choice(n_u, size=6, replace=False)
         folded = fold_in_users(
@@ -352,7 +350,7 @@ class TestFoldInDifferential:
 
     def test_duplicates_summed_like_training(self):
         # the same (user, item) rated twice must fold as the SUM
-        # (reduceByKey parity with pad_ratings)
+        # (reduceByKey parity with the training tables)
         params = ALSParams(rank=4, num_iterations=1, seed=1)
         _, Y0 = init_factors(4, 6, 4, 1)
         dup = fold_in_users(np.asarray(Y0),

@@ -134,7 +134,7 @@ class TestPipelinedDifferential:
         assert res.user_side.buckets == [] or \
             all(len(b.row_ids) == 0 for b in res.user_side.buckets)
 
-    def test_finalize_uniform_contract_same_multiset(self):
+    def test_finalize_triples_contract_same_multiset(self):
         """PipelinedRatingsBuilder.finalize returns merged-sorted
         triples — same multiset as the serial stream order, and the
         deduped result matches exactly."""

@@ -484,7 +484,9 @@ class TestStreamingTrainE2E:
     def test_streaming_plus_bucketed_preparator(self, tmp_path):
         """The full scale recipe: jsonlfs store -> threaded streaming
         blocks -> bucketed layout -> sharded-capable training -> serve.
-        The model must match the uniform-layout model's predictions."""
+        The model must give the predictions of the plain-numpy trainer
+        over the same events."""
+        from als_reference import numpy_train_als
         from predictionio_tpu.controller import ComputeContext, EngineParams
         from predictionio_tpu.data import storage
         from predictionio_tpu.data.storage.base import App
@@ -517,26 +519,31 @@ class TestStreamingTrainE2E:
 
             engine = engine_factory()
 
-            def run(prep_params):
-                params = EngineParams(
-                    data_source_params=("", DataSourceParams(
-                        app_name="bigapp", streaming_block_size=64)),
-                    preparator_params=("", prep_params),
-                    algorithm_params_list=[
-                        ("als", ALSParams(rank=4, num_iterations=2,
-                                          seed=0))])
-                persistable = engine.train(ComputeContext(), params, "x")
-                [model] = engine.prepare_deploy(ComputeContext(), params,
-                                                "x", persistable)
-                algo = engine._algorithms(params)[0]
-                return algo.predict(model, Query(user="u1", num=5))
+            als = ALSParams(rank=4, num_iterations=2, seed=0)
+            params = EngineParams(
+                data_source_params=("", DataSourceParams(
+                    app_name="bigapp", streaming_block_size=64)),
+                preparator_params=("", PreparatorParams()),
+                algorithm_params_list=[("als", als)])
+            persistable = engine.train(ComputeContext(), params, "x")
+            [model] = engine.prepare_deploy(ComputeContext(), params,
+                                            "x", persistable)
+            algo = engine._algorithms(params)[0]
+            got = algo.predict(model, Query(user="u1", num=5))
 
-            bucketed = run(PreparatorParams(bucketed=True))
-            uniform = run(PreparatorParams())
-            assert [s.item for s in bucketed.item_scores] == \
-                [s.item for s in uniform.item_scores]
+            rows = [model.user_map[e.entity_id] for e in evs]
+            cols = [model.item_map[e.target_entity_id] for e in evs]
+            vals = [e.properties["rating"] for e in evs]
+            X, Y = numpy_train_als(rows, cols, vals, len(model.user_map),
+                                   len(model.item_map), als)
+            u1 = model.user_map["u1"]
+            scores = X[u1] @ Y.T
+            scores[[c for r, c in zip(rows, cols) if r == u1]] = -np.inf
+            top = np.argsort(-scores)[:5]
+            assert [s.item for s in got.item_scores] == \
+                list(model.item_map.decode(top))
             np.testing.assert_allclose(
-                [s.score for s in bucketed.item_scores],
-                [s.score for s in uniform.item_scores], rtol=1e-3)
+                [s.score for s in got.item_scores], scores[top],
+                rtol=1e-3)
         finally:
             storage.reset()

@@ -14,7 +14,11 @@ from predictionio_tpu.controller import ComputeContext, EngineParams
 from predictionio_tpu.data import storage
 from predictionio_tpu.data.event import Event
 from predictionio_tpu.data.storage.base import App
-from predictionio_tpu.ops.als import ALSParams, pad_ratings, train_als
+from predictionio_tpu.ops.als import (
+    ALSParams,
+    bucket_ratings_pair,
+    train_als_bucketed,
+)
 from predictionio_tpu.ops.serving import DeviceTopK, seen_bitmap
 
 UTC = dt.timezone.utc
@@ -103,8 +107,7 @@ class TestDeviceTopK:
         rows = rng.integers(0, n_u, nnz)
         cols = rng.integers(0, n_i, nnz)
         vals = rng.random(nnz).astype(np.float32) + 0.5
-        us = pad_ratings(rows, cols, vals, n_u, n_i)
-        its = pad_ratings(cols, rows, vals, n_i, n_u)
+        us, its = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
         params = ALSParams(rank=4, num_iterations=2, seed=1)
 
         mesh = host_aware_mesh(model=2)
@@ -118,7 +121,7 @@ class TestDeviceTopK:
         idx, scores = srv.user_topk(3, 5)
 
         # oracle: the same training gathered to host
-        X, Y = train_als(us, its, params)
+        X, Y = train_als_bucketed(us, its, params)
         oidx, oscores = host_oracle_topk(X, Y, {}, 3, 5)
         np.testing.assert_allclose(scores, oscores[:len(scores)], rtol=1e-4)
         assert set(idx.tolist()) <= set(oidx.tolist())
@@ -838,40 +841,36 @@ class TestShardedFlavor:
         full = algo.predict(model, Query(user="u1", num=50))
         assert not ({s.item for s in full.item_scores} & seen_items)
 
-    def test_bucketed_device_resident_matches_uniform(self, mem_storage):
-        """The scale combination: bucketed-layout training with the
-        factors kept sharded in HBM — same predictions as the uniform
-        device-resident flavor."""
+    def test_device_resident_matches_host_factors(self, mem_storage):
+        """The factors kept sharded in HBM (``train_als_device``) give
+        the predictions of the host-factors engine
+        (``train_als_auto``) on the same events."""
         from predictionio_tpu.templates.recommendation import (
-            PreparatorParams, Query, ShardedALSModel,
+            ALSModel, Query, ShardedALSModel, engine_factory,
             sharded_engine_factory,
         )
 
         _seed()
-        engine = sharded_engine_factory()
-        uniform_params = _engine_params()
-        bucketed_params = EngineParams(
-            data_source_params=uniform_params.data_source_params,
-            preparator_params=("", PreparatorParams(bucketed=True)),
-            algorithm_params_list=uniform_params.algorithm_params_list)
+        params = _engine_params()
 
-        def deploy(params, iid):
+        def deploy(engine, iid):
             persistable = engine.train(CTX, params, iid)
             [model] = engine.prepare_deploy(CTX, params, iid, persistable)
             return engine._algorithms(params)[0], model
 
-        algo_u, model_u = deploy(uniform_params, "du")
-        algo_b, model_b = deploy(bucketed_params, "db")
-        assert isinstance(model_b, ShardedALSModel)
-        assert hasattr(model_b.user_factors, "sharding")
+        algo_h, model_h = deploy(engine_factory(), "dh")
+        algo_d, model_d = deploy(sharded_engine_factory(), "dd")
+        assert isinstance(model_h, ALSModel)
+        assert isinstance(model_d, ShardedALSModel)
+        assert hasattr(model_d.user_factors, "sharding")
         for u in ("u1", "u7", "u15"):
-            ru = algo_u.predict(model_u, Query(user=u, num=5))
-            rb = algo_b.predict(model_b, Query(user=u, num=5))
-            assert [s.item for s in rb.item_scores] == \
-                [s.item for s in ru.item_scores], u
+            rh = algo_h.predict(model_h, Query(user=u, num=5))
+            rd = algo_d.predict(model_d, Query(user=u, num=5))
+            assert [s.item for s in rd.item_scores] == \
+                [s.item for s in rh.item_scores], u
             np.testing.assert_allclose(
-                [s.score for s in rb.item_scores],
-                [s.score for s in ru.item_scores], rtol=1e-3)
+                [s.score for s in rd.item_scores],
+                [s.score for s in rh.item_scores], rtol=1e-3)
 
     def test_bucketed_device_resident_uneven_rows(self):
         """Regression: user/item counts NOT divisible by the model-axis

@@ -391,8 +391,8 @@ class TestShardedTrainingDifferential:
             self, multichip_mesh):
         from predictionio_tpu.ops.als import (
             ALSParams,
-            pad_ratings,
-            train_als,
+            bucket_ratings_pair,
+            train_als_bucketed,
         )
         from predictionio_tpu.parallel.als_sharding import (
             train_als_device,
@@ -402,11 +402,10 @@ class TestShardedTrainingDifferential:
         rows = rng.integers(0, 30, 400)
         cols = rng.integers(0, 50, 400)
         vals = rng.integers(1, 6, 400).astype(np.float32)
-        us = pad_ratings(rows, cols, vals, 30, 50)
-        its = pad_ratings(cols, rows, vals, 50, 30)
+        us, its = bucket_ratings_pair(rows, cols, vals, 30, 50)
         params = ALSParams(rank=8, num_iterations=3, seed=1)
         Xd, Yd = train_als_device(us, its, params, mesh=multichip_mesh)
-        Xh, Yh = train_als(us, its, params)
+        Xh, Yh = train_als_bucketed(us, its, params)
         np.testing.assert_allclose(np.asarray(Xd)[:30], Xh, rtol=1e-4,
                                    atol=1e-4)
         np.testing.assert_allclose(np.asarray(Yd)[:50], Yh, rtol=1e-4,

@@ -20,9 +20,7 @@ from predictionio_tpu.ops.als import (
     bucket_ratings,
     bucket_ratings_pair,
     fold_in_users,
-    pad_ratings,
     spd_solve_lanes,
-    train_als,
     train_als_bucketed,
 )
 
@@ -247,13 +245,9 @@ class TestSolverSwapPreservesTraining:
             # static jit arg — flipping the env var between trainings
             # must take effect WITHOUT clearing any jit cache
             monkeypatch.setenv("PIO_ALS_SOLVER", flavor)
-            Xu, Yu = train_als(pad_ratings(rows, cols, vals, 60, 40),
-                               pad_ratings(cols, rows, vals, 40, 60),
-                               params)
-            Xb, Yb = train_als_bucketed(
+            return train_als_bucketed(
                 bucket_ratings(rows, cols, vals, 60, 40),
                 bucket_ratings(cols, rows, vals, 40, 60), params)
-            return Xu, Yu, Xb, Yb
 
         cho = train_both("cho")
         lanes = train_both("lanes")
@@ -326,17 +320,13 @@ class TestSolverSwapPreservesTraining:
             else dict(rtol=3e-2, atol=3e-3)
         np.testing.assert_allclose(got, want, **tol)
 
-    @pytest.mark.parametrize("flavor", ["bucketed", "uniform"])
-    def test_sharded_trainers_keep_lanes(self, monkeypatch, flavor):
-        """The sharded trainers are jits over sharded tables, and the TPU
+    def test_sharded_trainer_keeps_lanes(self, monkeypatch):
+        """The sharded trainer is a jit over sharded tables, and the TPU
         compiler will not partition a Mosaic call: on a mesh of several
         devices the kernel must never be reached, whatever was asked."""
         from predictionio_tpu.ops import als_pallas
         from predictionio_tpu.parallel import (
             data_parallel_mesh,
-            train_als_sharded,
-        )
-        from predictionio_tpu.parallel.als_sharding import (
             train_als_bucketed_sharded,
         )
 
@@ -345,18 +335,13 @@ class TestSolverSwapPreservesTraining:
 
         rows, cols, vals = small_ratings()
         params = ALSParams(rank=8, num_iterations=2, seed=2)
-        if flavor == "bucketed":
-            sides = bucket_ratings_pair(rows, cols, vals, 60, 40)
-            sharded, single = train_als_bucketed_sharded, train_als_bucketed
-        else:
-            sides = (pad_ratings(rows, cols, vals, 60, 40),
-                     pad_ratings(cols, rows, vals, 40, 60))
-            sharded, single = train_als_sharded, train_als
+        sides = bucket_ratings_pair(rows, cols, vals, 60, 40)
         monkeypatch.setenv("PIO_ALS_SOLVER", "cho")
-        want = single(*sides, params)
+        want = train_als_bucketed(*sides, params)
         monkeypatch.setenv("PIO_ALS_SOLVER", "pallas")
         monkeypatch.setattr(als_pallas, "spd_solve", refuse)
-        got = sharded(*sides, params, data_parallel_mesh(8))
+        got = train_als_bucketed_sharded(*sides, params,
+                                         data_parallel_mesh(8))
         for g, w in zip(got, want):
             np.testing.assert_allclose(g, w, rtol=5e-3, atol=5e-4)
 

@@ -2,7 +2,7 @@
 ``train_als*`` loops).
 
 - Differential gates: chunked training (every chunk length, every
-  layout — uniform / bucketed / blocked / sharded / bf16) is
+  placement — one device / blocked solves / sharded / bf16) is
   BYTE-IDENTICAL to the historical single-scan path, and a
   preempt-then-resume run is byte-identical to an uninterrupted one.
 - Torn-file conformance: truncated blobs, truncated manifests
@@ -31,8 +31,6 @@ import pytest
 from predictionio_tpu.ops.als import (
     ALSParams,
     bucket_ratings_pair,
-    pad_ratings,
-    train_als,
     train_als_bucketed,
     warmup_train_als_bucketed,
 )
@@ -52,12 +50,6 @@ def make_triples(seed=0, n_u=50, n_i=30, nnz=400):
     cols = rng.integers(0, n_i, nnz)
     vals = (rng.random(nnz).astype(np.float32) + 0.5)
     return rows, cols, vals, n_u, n_i
-
-
-def make_uniform(seed=0, **kw):
-    rows, cols, vals, n_u, n_i = make_triples(seed, **kw)
-    return (pad_ratings(rows, cols, vals, n_u, n_i),
-            pad_ratings(cols, rows, vals, n_i, n_u))
 
 
 def make_bucketed(seed=0, **kw):
@@ -110,66 +102,44 @@ class TestChunkedDifferential:
     (and with it every reduction order) is unchanged; only the scan
     trip count splits."""
 
-    def test_uniform(self, ckpt_env, monkeypatch):
-        user_side, item_side = make_uniform()
-        monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
-        monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        for every in ("1", "2", "4"):
-            monkeypatch.setenv("PIO_CHECKPOINT_EVERY", every)
-            X1, Y1 = train_als(user_side, item_side, PARAMS)
-            assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
-        assert manifests(ckpt_env)  # checkpoints actually landed
-
     def test_bucketed(self, ckpt_env, monkeypatch):
         user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
         X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
-        assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
+        for every in ("1", "2", "4"):
+            monkeypatch.setenv("PIO_CHECKPOINT_EVERY", every)
+            X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
+            assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
+        assert manifests(ckpt_env)  # checkpoints actually landed
 
-    def test_blocked_solve(self, ckpt_env, monkeypatch):
-        user_side, item_side = make_uniform()
+    def test_slot_budget_blocked_solve(self, ckpt_env, monkeypatch):
+        user_side, item_side = make_bucketed()
+        # 256 slots: the 56 x 16 user table runs as lax.map blocks
         params = ALSParams(rank=4, num_iterations=6, seed=3,
-                           solve_block_rows=16)
+                           bucket_slot_budget=256)
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, params)
+        X0, Y0 = train_als_bucketed(user_side, item_side, params)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        X1, Y1 = train_als(user_side, item_side, params)
+        X1, Y1 = train_als_bucketed(user_side, item_side, params)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     def test_bf16(self, ckpt_env, monkeypatch):
         # the checkpoint stores fp32 host factors, but bf16 -> fp32 ->
         # bf16 is lossless, so the crash-safe lane stays byte-identical
         # under the bf16 policy too
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         params = ALSParams(rank=4, num_iterations=6, seed=3,
                            precision="bf16")
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, params)
+        X0, Y0 = train_als_bucketed(user_side, item_side, params)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        X1, Y1 = train_als(user_side, item_side, params)
+        X1, Y1 = train_als_bucketed(user_side, item_side, params)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
-
-    def test_sharded(self, ckpt_env, monkeypatch):
-        # single-host sharded training checkpoints too (np.asarray
-        # gathers the factor shards per chunk)
-        from predictionio_tpu.parallel.als_sharding import (
-            train_als_sharded)
-        from predictionio_tpu.parallel.mesh import data_parallel_mesh
-
-        user_side, item_side = make_uniform(n_u=48, n_i=32)
-        monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als_sharded(user_side, item_side, PARAMS,
-                                   data_parallel_mesh())
-        monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        X1, Y1 = train_als_sharded(user_side, item_side, PARAMS,
-                                   data_parallel_mesh())
-        assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
-        assert manifests(ckpt_env)
 
     def test_bucketed_sharded(self, ckpt_env, monkeypatch):
+        # single-host sharded training checkpoints too (np.asarray
+        # gathers the factor shards per chunk)
         from predictionio_tpu.parallel.als_sharding import (
             train_als_bucketed_sharded)
         from predictionio_tpu.parallel.mesh import data_parallel_mesh
@@ -182,12 +152,13 @@ class TestChunkedDifferential:
         X1, Y1 = train_als_bucketed_sharded(user_side, item_side,
                                             PARAMS, data_parallel_mesh())
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
+        assert manifests(ckpt_env)
 
 
 class TestCheckpointFiles:
     def test_manifest_contents(self, ckpt_env):
-        user_side, item_side = make_uniform()
-        train_als(user_side, item_side, PARAMS)
+        user_side, item_side = make_bucketed()
+        train_als_bucketed(user_side, item_side, PARAMS)
         names = manifests(ckpt_env)
         assert names == ["ckpt-00000002.json", "ckpt-00000004.json",
                          "ckpt-00000006.json"]
@@ -205,8 +176,8 @@ class TestCheckpointFiles:
     def test_retention_keeps_last_n(self, ckpt_env, monkeypatch):
         monkeypatch.setenv("PIO_CHECKPOINT_EVERY", "1")
         monkeypatch.setenv("PIO_CHECKPOINT_KEEP", "2")
-        user_side, item_side = make_uniform()
-        train_als(user_side, item_side, PARAMS)
+        user_side, item_side = make_bucketed()
+        train_als_bucketed(user_side, item_side, PARAMS)
         assert manifests(ckpt_env) == ["ckpt-00000005.json",
                                        "ckpt-00000006.json"]
         # blobs of dropped steps are gone too
@@ -223,64 +194,64 @@ class TestCheckpointFiles:
         monkeypatch.setenv("PIO_CHECKPOINT_KEEP", "2")
         os.makedirs(ckpt_env, exist_ok=True)
         (ckpt_env / "ckpt-00000099.npz").write_bytes(b"orphan")
-        user_side, item_side = make_uniform()
-        train_als(user_side, item_side, PARAMS)
+        user_side, item_side = make_bucketed()
+        train_als_bucketed(user_side, item_side, PARAMS)
         assert not (ckpt_env / "ckpt-00000099.npz").exists()
 
 
 class TestPreemptResume:
     def test_preempt_then_resume_byte_identical(self, ckpt_env,
                                                 monkeypatch):
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
+        X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
         checkpoint.request_stop()
         with pytest.raises(TrainingPreempted):
-            train_als(user_side, item_side, PARAMS)
+            train_als_bucketed(user_side, item_side, PARAMS)
         checkpoint.clear_stop()
         assert manifests(ckpt_env) == ["ckpt-00000002.json"]
         saved = metrics.TRAIN_CHECKPOINTS.value(status="resumed")
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(user_side, item_side, PARAMS)
+        X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
         assert metrics.TRAIN_CHECKPOINTS.value(status="resumed") \
             == saved + 1
 
     def test_resume_empty_dir_is_fresh_start(self, ckpt_env,
                                              monkeypatch):
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, _ = train_als(user_side, item_side, PARAMS)
+        X0, _ = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, _ = train_als(user_side, item_side, PARAMS)
+        X1, _ = train_als_bucketed(user_side, item_side, PARAMS)
         assert np.array_equal(X0, X1)
 
     def test_resume_at_total_loads_final(self, ckpt_env, monkeypatch):
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.setenv("PIO_RESUME", "1")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
+        X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         # second run resumes from the step==total checkpoint: zero
         # further iterations, same factors
-        X1, Y1 = train_als(user_side, item_side, PARAMS)
+        X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     def test_resume_with_different_chunk_size(self, ckpt_env,
                                               monkeypatch):
         # chunking is an execution knob: a checkpoint from an every=2
         # run resumes under every=3 and still lands byte-identical
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
+        X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
         checkpoint.request_stop()
         with pytest.raises(TrainingPreempted):
-            train_als(user_side, item_side, PARAMS)
+            train_als_bucketed(user_side, item_side, PARAMS)
         checkpoint.clear_stop()
         monkeypatch.setenv("PIO_CHECKPOINT_EVERY", "3")
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(user_side, item_side, PARAMS)
+        X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
 
@@ -290,11 +261,11 @@ class TestTornRecovery:
 
     def _run_to_completion_keeping_all(self, ckpt_env, monkeypatch):
         monkeypatch.setenv("PIO_CHECKPOINT_KEEP", "10")
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
+        X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
-        train_als(user_side, item_side, PARAMS)
+        train_als_bucketed(user_side, item_side, PARAMS)
         return user_side, item_side, X0, Y0
 
     def test_torn_blob_falls_back(self, ckpt_env, monkeypatch):
@@ -305,7 +276,7 @@ class TestTornRecovery:
             blob[:len(blob) // 2])  # sheared mid-write
         torn0 = metrics.TRAIN_CHECKPOINTS.value(status="torn_skipped")
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(us, its, PARAMS)  # resumes from step 4
+        X1, Y1 = train_als_bucketed(us, its, PARAMS)  # resumes from step 4
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
         assert metrics.TRAIN_CHECKPOINTS.value(
             status="torn_skipped") == torn0 + 1
@@ -324,7 +295,7 @@ class TestTornRecovery:
         cut = raw.rindex("é".encode("utf-8")) + 1  # mid-char
         path.write_bytes(raw[:cut])
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(us, its, PARAMS)
+        X1, Y1 = train_als_bucketed(us, its, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     def test_manifest_without_blob_falls_back(self, ckpt_env,
@@ -333,7 +304,7 @@ class TestTornRecovery:
             ckpt_env, monkeypatch)
         os.unlink(ckpt_env / "ckpt-00000006.npz")
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(us, its, PARAMS)
+        X1, Y1 = train_als_bucketed(us, its, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     def test_all_torn_is_fresh_start(self, ckpt_env, monkeypatch):
@@ -344,7 +315,7 @@ class TestTornRecovery:
             if p.is_file():  # skip the runs/ history subdir
                 p.write_bytes(p.read_bytes()[:10])
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(us, its, PARAMS)
+        X1, Y1 = train_als_bucketed(us, its, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     def test_injected_torn_checkpoint_then_resume(self, ckpt_env,
@@ -353,28 +324,28 @@ class TestTornRecovery:
         shears mid-blob (partial bytes at the final path, no manifest)
         and fails the run; --resume falls back to the first checkpoint
         and completes byte-identically."""
-        user_side, item_side = make_uniform()
+        user_side, item_side = make_bucketed()
         monkeypatch.delenv("PIO_CHECKPOINT_DIR")
-        X0, Y0 = train_als(user_side, item_side, PARAMS)
+        X0, Y0 = train_als_bucketed(user_side, item_side, PARAMS)
         monkeypatch.setenv("PIO_CHECKPOINT_DIR", str(ckpt_env))
         faults.install(
             "backend=checkpoint,op=save,kind=torn,after=1,times=1")
         try:
             with pytest.raises(faults.InjectedTornWrite):
-                train_als(user_side, item_side, PARAMS)
+                train_als_bucketed(user_side, item_side, PARAMS)
         finally:
             faults.clear()
         assert manifests(ckpt_env) == ["ckpt-00000002.json"]
         assert (ckpt_env / "ckpt-00000004.npz").exists()  # the shear
         monkeypatch.setenv("PIO_RESUME", "1")
-        X1, Y1 = train_als(user_side, item_side, PARAMS)
+        X1, Y1 = train_als_bucketed(user_side, item_side, PARAMS)
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
 
 class TestFingerprint:
     def _checkpoints_for(self, ckpt_env, params, monkeypatch):
-        user_side, item_side = make_uniform()
-        train_als(user_side, item_side, params)
+        user_side, item_side = make_bucketed()
+        train_als_bucketed(user_side, item_side, params)
         assert manifests(ckpt_env)
         return user_side, item_side
 
@@ -382,38 +353,38 @@ class TestFingerprint:
         us, its = self._checkpoints_for(ckpt_env, PARAMS, monkeypatch)
         monkeypatch.setenv("PIO_RESUME", "1")
         with pytest.raises(CheckpointMismatchError):
-            train_als(us, its, ALSParams(rank=4, num_iterations=6,
-                                         seed=3, lambda_=0.02))
+            train_als_bucketed(us, its, ALSParams(
+                rank=4, num_iterations=6, seed=3, lambda_=0.02))
 
     def test_precision_change_refused(self, ckpt_env, monkeypatch):
         us, its = self._checkpoints_for(ckpt_env, PARAMS, monkeypatch)
         monkeypatch.setenv("PIO_RESUME", "1")
         monkeypatch.setenv("PIO_ALS_PRECISION", "bf16")
         with pytest.raises(CheckpointMismatchError):
-            train_als(us, its, PARAMS)
+            train_als_bucketed(us, its, PARAMS)
 
     def test_solver_change_refused(self, ckpt_env, monkeypatch):
         us, its = self._checkpoints_for(ckpt_env, PARAMS, monkeypatch)
         monkeypatch.setenv("PIO_RESUME", "1")
         monkeypatch.setenv("PIO_ALS_SOLVER", "lanes")
         with pytest.raises(CheckpointMismatchError):
-            train_als(us, its, PARAMS)
+            train_als_bucketed(us, its, PARAMS)
 
     def test_layout_change_refused(self, ckpt_env, monkeypatch):
         self._checkpoints_for(ckpt_env, PARAMS, monkeypatch)
         monkeypatch.setenv("PIO_RESUME", "1")
-        us2, its2 = make_uniform(seed=9, n_u=64, n_i=40, nnz=500)
+        us2, its2 = make_bucketed(seed=9, n_u=64, n_i=40, nnz=500)
         with pytest.raises(CheckpointMismatchError):
-            train_als(us2, its2, PARAMS)
+            train_als_bucketed(us2, its2, PARAMS)
 
     def test_checkpoint_every_not_in_fingerprint(self):
         a = checkpoint.training_fingerprint(
-            ("uniform",), ALSParams(checkpoint_every=2), "cho", "fp32")
+            ("bucketed",), ALSParams(checkpoint_every=2), "cho", "fp32")
         b = checkpoint.training_fingerprint(
-            ("uniform",), ALSParams(checkpoint_every=5), "cho", "fp32")
+            ("bucketed",), ALSParams(checkpoint_every=5), "cho", "fp32")
         assert a == b
         c = checkpoint.training_fingerprint(
-            ("uniform",), ALSParams(lambda_=0.5), "cho", "fp32")
+            ("bucketed",), ALSParams(lambda_=0.5), "cho", "fp32")
         assert a != c
 
     def test_bimap_scope_changes_fingerprint(self):
@@ -422,13 +393,13 @@ class TestFingerprint:
         m1 = StringIndexBiMap(["a", "b"])
         m2 = StringIndexBiMap(["a", "c"])
         base = checkpoint.training_fingerprint(
-            ("uniform",), ALSParams(), "cho", "fp32")
+            ("bucketed",), ALSParams(), "cho", "fp32")
         with checkpoint.fingerprint_scope(checkpoint.bimap_digest(m1)):
             fp1 = checkpoint.training_fingerprint(
-                ("uniform",), ALSParams(), "cho", "fp32")
+                ("bucketed",), ALSParams(), "cho", "fp32")
         with checkpoint.fingerprint_scope(checkpoint.bimap_digest(m2)):
             fp2 = checkpoint.training_fingerprint(
-                ("uniform",), ALSParams(), "cho", "fp32")
+                ("bucketed",), ALSParams(), "cho", "fp32")
         assert len({base, fp1, fp2}) == 3
         # digest is order-sensitive and injective across map boundaries
         assert checkpoint.bimap_digest(m1) != checkpoint.bimap_digest(
@@ -442,14 +413,13 @@ class TestDivergenceGuard:
         rows, cols, vals, n_u, n_i = make_triples()
         vals = vals.copy()
         vals[7] = np.nan
-        return (pad_ratings(rows, cols, vals, n_u, n_i),
-                pad_ratings(cols, rows, vals, n_i, n_u))
+        return bucket_ratings_pair(rows, cols, vals, n_u, n_i)
 
     def test_nan_aborts_with_metric(self, ckpt_env):
         us, its = self._nan_sides()
         before = metrics.TRAIN_DIVERGED.value()
         with pytest.raises(TrainingDivergedError):
-            train_als(us, its, PARAMS)
+            train_als_bucketed(us, its, PARAMS)
         assert metrics.TRAIN_DIVERGED.value() == before + 1
         # the poisoned state was never checkpointed
         assert manifests(ckpt_env) == []
@@ -457,14 +427,14 @@ class TestDivergenceGuard:
     def test_last_good_checkpoints_retained(self, ckpt_env,
                                             monkeypatch):
         monkeypatch.setenv("PIO_CHECKPOINT_KEEP", "10")
-        user_side, item_side = make_uniform()
-        train_als(user_side, item_side, PARAMS)
+        user_side, item_side = make_bucketed()
+        train_als_bucketed(user_side, item_side, PARAMS)
         kept = {f: (ckpt_env / f).read_bytes()
                 for f in os.listdir(ckpt_env)
                 if (ckpt_env / f).is_file()}  # runs/ is history, not ckpt
         us, its = self._nan_sides()
         with pytest.raises(TrainingDivergedError):
-            train_als(us, its, PARAMS)
+            train_als_bucketed(us, its, PARAMS)
         assert {f: (ckpt_env / f).read_bytes()
                 for f in os.listdir(ckpt_env)
                 if (ckpt_env / f).is_file()} == kept
@@ -473,7 +443,7 @@ class TestDivergenceGuard:
         # without a checkpoint dir the single-scan path runs untouched
         monkeypatch.delenv("PIO_CHECKPOINT_DIR", raising=False)
         us, its = self._nan_sides()
-        X, _ = train_als(us, its, PARAMS)  # historical behavior: no
+        X, _ = train_als_bucketed(us, its, PARAMS)  # historical behavior: no
         assert not np.isfinite(X).all()    # guard, NaN flows out
 
 
@@ -699,7 +669,7 @@ class TestChaosSubprocess:
                     "PIO_RESUME", "PIO_FAULTS"):
             monkeypatch.delenv(var, raising=False)
         us, its, params = build_inputs()
-        return train_als(us, its, params)
+        return train_als_bucketed(us, its, params)
 
     def test_kill9_then_resume_byte_identical(self, tmp_path,
                                               monkeypatch):

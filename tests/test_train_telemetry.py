@@ -4,10 +4,10 @@ chunk loops + ``pio runs``).
 
 - Objective correctness: the fused on-device pack matches dense numpy
   references for both the implicit (Hu-Koren-Volinsky) and explicit
-  (ALS-WR) losses, bucketed == uniform, and the fused ``finite``
+  (ALS-WR) losses, duplicates summed, and the fused ``finite``
   element flags non-finite factors.
 - Observer purity: telemetry-on factors are BYTE-IDENTICAL to
-  telemetry-off across the uniform / bucketed / sharded / grid / bf16
+  telemetry-off across the one-device / sharded / grid / bf16
   lanes (``PIO_TRAIN_TELEMETRY=0`` is the kill switch), and the loss
   decreases monotonically on the seeded smoke shape.
 - Run-log crash-safety: a preempted-then-resumed run appends to the
@@ -31,8 +31,6 @@ import pytest
 from predictionio_tpu.ops.als import (
     ALSParams,
     bucket_ratings_pair,
-    pad_ratings,
-    train_als,
     train_als_bucketed,
     training_objective,
 )
@@ -57,12 +55,6 @@ def make_triples(seed=0, n_u=50, n_i=30, nnz=400):
     cols = rng.integers(0, n_i, nnz)
     vals = (rng.random(nnz).astype(np.float32) + 0.5)
     return rows, cols, vals, n_u, n_i
-
-
-def make_uniform(seed=0, **kw):
-    rows, cols, vals, n_u, n_i = make_triples(seed, **kw)
-    return (pad_ratings(rows, cols, vals, n_u, n_i),
-            pad_ratings(cols, rows, vals, n_i, n_u))
 
 
 def make_bucketed(seed=0, **kw):
@@ -116,7 +108,7 @@ class TestTrainingObjective:
         rng = np.random.default_rng(2)
         X = rng.normal(size=(n_u, 3)).astype(np.float32) * 0.3
         Y = rng.normal(size=(n_i, 3)).astype(np.float32) * 0.3
-        us = pad_ratings(rows, cols, vals, n_u, n_i)
+        us, _ = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
         obj = training_objective(X, Y, us, params)
 
         # dense HKV loss over ALL pairs: c = 1 + alpha*r (observed),
@@ -140,7 +132,7 @@ class TestTrainingObjective:
         rng = np.random.default_rng(4)
         X = rng.normal(size=(n_u, 3)).astype(np.float32) * 0.3
         Y = rng.normal(size=(n_i, 3)).astype(np.float32) * 0.3
-        us = pad_ratings(rows, cols, vals, n_u, n_i)
+        us, _ = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
         obj = training_objective(X, Y, us, params)
 
         S = X.astype(np.float64) @ Y.astype(np.float64).T
@@ -154,22 +146,29 @@ class TestTrainingObjective:
         np.testing.assert_allclose(obj["fit"], fit, rtol=2e-4)
         np.testing.assert_allclose(obj["l2"], l2, rtol=2e-4)
 
-    def test_bucketed_matches_uniform(self):
+    def test_duplicate_pairs_match_dense_reference(self):
+        # 400 draws over 50 x 30: repeated pairs are summed into one
+        # rating before the loss sees them
         rows, cols, vals, n_u, n_i = make_triples(seed=5)
+        assert len(set(zip(rows.tolist(), cols.tolist()))) < len(rows)
         params = ALSParams(rank=4, lambda_=0.1, alpha=1.5)
         rng = np.random.default_rng(6)
         X = rng.normal(size=(n_u, 4)).astype(np.float32) * 0.2
         Y = rng.normal(size=(n_i, 4)).astype(np.float32) * 0.2
-        uni = training_objective(
-            X, Y, pad_ratings(rows, cols, vals, n_u, n_i), params)
         us_b, _ = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
-        buck = training_objective(X, Y, us_b, params)
-        np.testing.assert_allclose(buck["fit"], uni["fit"], rtol=1e-5)
-        np.testing.assert_allclose(buck["l2"], uni["l2"], rtol=1e-5)
+        obj = training_objective(X, Y, us_b, params)
+        R = np.zeros((n_u, n_i))
+        np.add.at(R, (rows, cols), vals)
+        S = X.astype(np.float64) @ Y.astype(np.float64).T
+        fit = float(((1.0 + params.alpha * R) * ((R > 0) - S) ** 2).sum())
+        l2 = params.lambda_ * float((X.astype(np.float64) ** 2).sum()
+                                    + (Y.astype(np.float64) ** 2).sum())
+        np.testing.assert_allclose(obj["fit"], fit, rtol=2e-4)
+        np.testing.assert_allclose(obj["l2"], l2, rtol=2e-4)
 
     def test_nonfinite_factors_flagged(self):
         rows, cols, vals, n_u, n_i = unique_triples(seed=7)
-        us = pad_ratings(rows, cols, vals, n_u, n_i)
+        us, _ = bucket_ratings_pair(rows, cols, vals, n_u, n_i)
         X = np.zeros((n_u, 3), np.float32)
         Y = np.zeros((n_i, 3), np.float32)
         X[2, 1] = np.nan
@@ -188,26 +187,20 @@ class TestObserverPurity:
         on = train()
         return off, on
 
-    def test_uniform(self, ckpt_env, monkeypatch):
-        us, its = make_uniform()
-        (X0, Y0), (X1, Y1) = self._on_off(
-            monkeypatch, lambda: train_als(us, its, PARAMS))
-        assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
-        # and the on lane actually recorded history
-        assert runlog.list_runs(str(ckpt_env))
-
     def test_bucketed(self, ckpt_env, monkeypatch):
         us, its = make_bucketed()
         (X0, Y0), (X1, Y1) = self._on_off(
             monkeypatch, lambda: train_als_bucketed(us, its, PARAMS))
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
+        # and the on lane actually recorded history
+        assert runlog.list_runs(str(ckpt_env))
 
     def test_bf16(self, ckpt_env, monkeypatch):
-        us, its = make_uniform()
+        us, its = make_bucketed()
         params = ALSParams(rank=4, num_iterations=6, seed=3,
                            precision="bf16")
         (X0, Y0), (X1, Y1) = self._on_off(
-            monkeypatch, lambda: train_als(us, its, params))
+            monkeypatch, lambda: train_als_bucketed(us, its, params))
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
 
     @pytest.mark.multichip
@@ -218,14 +211,14 @@ class TestObserverPurity:
             pytest.skip("needs the 8-device CPU scaffold")
         from predictionio_tpu.parallel import (
             data_parallel_mesh,
-            train_als_sharded,
+            train_als_bucketed_sharded,
         )
 
         mesh = data_parallel_mesh(8)
-        us, its = make_uniform()
+        us, its = make_bucketed()
         (X0, Y0), (X1, Y1) = self._on_off(
             monkeypatch,
-            lambda: train_als_sharded(us, its, PARAMS, mesh))
+            lambda: train_als_bucketed_sharded(us, its, PARAMS, mesh))
         assert np.array_equal(X0, X1) and np.array_equal(Y0, Y1)
         assert runlog.list_runs(str(ckpt_env))
 
@@ -245,8 +238,8 @@ class TestObserverPurity:
 
     def test_loss_monotone_on_smoke_shape(self, ckpt_env, monkeypatch):
         monkeypatch.setenv("PIO_CHECKPOINT_EVERY", "1")
-        us, its = make_uniform(seed=9)
-        train_als(us, its, PARAMS)
+        us, its = make_bucketed(seed=9)
+        train_als_bucketed(us, its, PARAMS)
         samples = one_run(ckpt_env)["samples"]
         totals = [runlog._loss_total(s) for s in samples]
         assert len(totals) == PARAMS.num_iterations
@@ -259,8 +252,8 @@ class TestObserverPurity:
 
     def test_kill_switch_writes_nothing(self, ckpt_env, monkeypatch):
         monkeypatch.setenv("PIO_TRAIN_TELEMETRY", "0")
-        us, its = make_uniform()
-        train_als(us, its, PARAMS)
+        us, its = make_bucketed()
+        train_als_bucketed(us, its, PARAMS)
         assert runlog.list_runs(str(ckpt_env)) == []
 
 
@@ -269,17 +262,17 @@ class TestRunLogCrashSafety:
         checkpoint.request_stop()
         try:
             with pytest.raises(TrainingPreempted):
-                train_als(us, its, PARAMS)
+                train_als_bucketed(us, its, PARAMS)
         finally:
             checkpoint.clear_stop()
 
     def test_resume_continues_same_run(self, ckpt_env, monkeypatch):
-        us, its = make_uniform()
+        us, its = make_bucketed()
         self._preempt(us, its)
         interrupted = one_run(ckpt_env)
         assert [s["step"] for s in interrupted["samples"]] == [2]
         monkeypatch.setenv("PIO_RESUME", "1")
-        train_als(us, its, PARAMS)
+        train_als_bucketed(us, its, PARAMS)
         run = one_run(ckpt_env)  # still ONE run file
         assert run["runId"] == interrupted["runId"]
         steps = [s["step"] for s in run["samples"]]
@@ -287,14 +280,14 @@ class TestRunLogCrashSafety:
         assert all(s["runId"] == run["runId"] for s in run["samples"])
 
     def test_torn_tail_repaired_on_resume(self, ckpt_env, monkeypatch):
-        us, its = make_uniform()
+        us, its = make_bucketed()
         self._preempt(us, its)
         run = one_run(ckpt_env)
         path = runlog.run_path(str(ckpt_env), run["runId"])
         with open(path, "ab") as f:  # kill mid-append: no newline
             f.write(b'{"type":"sample","runId":"x","step":99')
         monkeypatch.setenv("PIO_RESUME", "1")
-        train_als(us, its, PARAMS)
+        train_als_bucketed(us, its, PARAMS)
         with open(path, "rb") as f:
             raw = f.read()
         # every surviving line parses; the torn fragment is gone
@@ -308,7 +301,7 @@ class TestRunLogCrashSafety:
         # a crash AFTER the append but BEFORE its checkpoint committed
         # leaves a sample past the resumed step: repair drops it so the
         # resumed history stays monotone without doubled steps
-        us, its = make_uniform()
+        us, its = make_bucketed()
         self._preempt(us, its)
         run = one_run(ckpt_env)
         path = runlog.run_path(str(ckpt_env), run["runId"])
@@ -317,13 +310,13 @@ class TestRunLogCrashSafety:
                    "loss": {"fit": 1.0, "l2": 1.0, "total": 2.0}})
         rl.close()
         monkeypatch.setenv("PIO_RESUME", "1")
-        train_als(us, its, PARAMS)
+        train_als_bucketed(us, its, PARAMS)
         steps = [s["step"] for s in one_run(ckpt_env)["samples"]]
         assert steps == [2, 4, 6]
 
     def test_reader_tolerates_torn_tail(self, ckpt_env):
-        us, its = make_uniform()
-        train_als(us, its, PARAMS)
+        us, its = make_bucketed()
+        train_als_bucketed(us, its, PARAMS)
         run = one_run(ckpt_env)
         path = runlog.run_path(str(ckpt_env), run["runId"])
         with open(path, "ab") as f:
@@ -333,9 +326,9 @@ class TestRunLogCrashSafety:
         assert runlog.list_runs(str(ckpt_env))[0]["lastStep"] == 6
 
     def test_separate_trainings_get_separate_runs(self, ckpt_env):
-        us, its = make_uniform()
-        train_als(us, its, PARAMS)
-        train_als(us, its, PARAMS)  # fresh start, not a resume
+        us, its = make_bucketed()
+        train_als_bucketed(us, its, PARAMS)
+        train_als_bucketed(us, its, PARAMS)  # fresh start, not a resume
         runs = runlog.list_runs(str(ckpt_env))
         assert len(runs) == 2
         assert runs[0]["runId"] != runs[1]["runId"]
@@ -346,13 +339,12 @@ class TestDivergedReporting:
         rows, cols, vals, n_u, n_i = make_triples()
         vals = vals.copy()
         vals[7] = np.nan
-        return (pad_ratings(rows, cols, vals, n_u, n_i),
-                pad_ratings(cols, rows, vals, n_i, n_u))
+        return bucket_ratings_pair(rows, cols, vals, n_u, n_i)
 
     def test_serial_message_names_chunk_and_loss_state(self, ckpt_env):
         us, its = self._nan_sides()
         with pytest.raises(TrainingDivergedError) as ei:
-            train_als(us, its, PARAMS)
+            train_als_bucketed(us, its, PARAMS)
         msg = str(ei.value)
         assert "iteration 2/6" in msg
         assert "no finite loss sample was recorded" in msg
@@ -376,15 +368,15 @@ class TestDivergedReporting:
 
 class TestRunsCli:
     def _interrupted_then_resumed(self, ckpt_env, monkeypatch):
-        us, its = make_uniform()
+        us, its = make_bucketed()
         checkpoint.request_stop()
         try:
             with pytest.raises(TrainingPreempted):
-                train_als(us, its, PARAMS)
+                train_als_bucketed(us, its, PARAMS)
         finally:
             checkpoint.clear_stop()
         monkeypatch.setenv("PIO_RESUME", "1")
-        train_als(us, its, PARAMS)
+        train_als_bucketed(us, its, PARAMS)
         monkeypatch.delenv("PIO_RESUME")
         return one_run(ckpt_env)["runId"]
 
@@ -408,8 +400,8 @@ class TestRunsCli:
         assert cli_main(["runs", "show", rid[:16], "--dir", d]) == 0
         capsys.readouterr()
 
-        us, its = make_uniform()
-        train_als(us, its, PARAMS)  # a second run to diff against
+        us, its = make_bucketed()
+        train_als_bucketed(us, its, PARAMS)  # a second run to diff against
         runs = runlog.list_runs(d)
         assert len(runs) == 2
         other = next(r["runId"] for r in runs if r["runId"] != rid)
@@ -419,8 +411,8 @@ class TestRunsCli:
         assert "B - A" in out
 
     def test_dir_from_env(self, ckpt_env, monkeypatch, capsys):
-        us, its = make_uniform()
-        train_als(us, its, PARAMS)
+        us, its = make_bucketed()
+        train_als_bucketed(us, its, PARAMS)
         # --dir omitted: $PIO_CHECKPOINT_DIR (set by ckpt_env) wins
         assert cli_main(["runs", "list"]) == 0
         assert one_run(ckpt_env)["runId"] in capsys.readouterr().out

@@ -1,7 +1,7 @@
 """Subprocess target for the crash-safe-training chaos suite.
 
-Runs ONE deterministic `train_als` job (fixed seed, fixed synthetic
-ratings) with checkpointing configured purely through the PIO_* env
+Runs ONE deterministic `train_als_bucketed` job (fixed seed, fixed
+synthetic ratings) with checkpointing configured purely through the PIO_* env
 vars the parent test sets, mimicking the `pio train` lifecycle: signal
 handlers installed (SIGTERM/SIGINT -> graceful drain + clean exit 0)
 and a `PIO_FAULTS` slow rule on checkpoint saves is the deterministic
@@ -24,14 +24,14 @@ DEFAULT_ITERS = 8
 def build_inputs(num_iterations: int = DEFAULT_ITERS):
     """The deterministic training problem shared by the worker and the
     parent test's in-process reference run."""
-    from predictionio_tpu.ops.als import ALSParams, pad_ratings
+    from predictionio_tpu.ops.als import ALSParams, bucket_ratings_pair
 
     rng = np.random.default_rng(7)
     rows = rng.integers(0, N_USERS, NNZ)
     cols = rng.integers(0, N_ITEMS, NNZ)
     vals = (rng.random(NNZ).astype(np.float32) + 0.5)
-    user_side = pad_ratings(rows, cols, vals, N_USERS, N_ITEMS)
-    item_side = pad_ratings(cols, rows, vals, N_ITEMS, N_USERS)
+    user_side, item_side = bucket_ratings_pair(rows, cols, vals,
+                                               N_USERS, N_ITEMS)
     params = ALSParams(rank=8, num_iterations=num_iterations, seed=SEED)
     return user_side, item_side, params
 
@@ -39,7 +39,7 @@ def build_inputs(num_iterations: int = DEFAULT_ITERS):
 def main(out_path: str) -> int:
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-    from predictionio_tpu.ops.als import train_als
+    from predictionio_tpu.ops.als import train_als_bucketed
     from predictionio_tpu.workflow import checkpoint
 
     checkpoint.install_signal_handlers()
@@ -48,7 +48,7 @@ def main(out_path: str) -> int:
     user_side, item_side, params = build_inputs(iters)
     print("[INFO] worker: training starts", flush=True)
     try:
-        X, Y = train_als(user_side, item_side, params)
+        X, Y = train_als_bucketed(user_side, item_side, params)
     except checkpoint.TrainingPreempted as e:
         print(f"[INFO] Training interrupted: {e}", flush=True)
         return 0
