@@ -34,15 +34,26 @@ Two kernels live here:
    is scored on the MXU against the whole query block, masked in
    registers from the packed seen bitmap, and folded into a running
    per-query top-k held in VMEM across the grid; only the final
-   ``[B, k]`` winners ever reach HBM. A per-tile early-out skips the
-   selection merge whenever the tile's best score cannot beat any
-   query's current k-th. STATUS: the production device path for
-   ``DeviceTopK`` (``PIO_SERVE_KERNEL=xla`` opts out; CPU serves the
-   XLA chain and exercises this kernel in interpret mode, like
-   ``spd_solve``). On the v5e at the ML-20M store shape its answers
-   equal the XLA chain's on the same fp32, bf16 and int8 stores, and a
-   dispatch takes about as long as the chain's — no faster end to end
-   yet (PERF.md section 5; ROADMAP Speed 5).
+   ``[B, k]`` winners ever reach HBM. The fold is a bounded merge
+   (:func:`_topk_select_body`, PR 29): a tile costs as many insertion
+   rounds as the query that gains most from it has scores over its
+   k-th, none when no score beats any query's k-th (the early-out),
+   and the pass reports the rounds it ran (``selectRounds`` on the
+   dispatch record, ``pio_topk_select_rounds_total``). STATUS: the
+   production device path for ``DeviceTopK`` and ``TwoStageTopK``
+   (``PIO_SERVE_KERNEL=xla`` opts out; CPU serves the XLA chain and
+   exercises this kernel in interpret mode, like ``spd_solve``); its
+   answers equal ``lax.top_k``'s to the bit on fp32, bf16 and int8
+   stores. On the v5e at 41,140 bf16 items (my chip runs, PR 29) a
+   top-128 pass for 8 queries takes 0.40 ms at 1,520 rounds where the
+   K-rounds-a-tile selection it replaced took 5.28 ms at 42,000 (256
+   queries: 0.64 against 5.68), a top-16 pass with the seen mask 0.25
+   against 0.41, top-64 0.29 against 1.97; scores that RISE with the
+   item id, the worst case, cost min(K, 128) rounds on every tile, the
+   old kernel's count: 4.61 ms against its 5.34 (256 queries: 6.44
+   against 5.69; K = 16: 0.54, 0.57). The two-stage cell's median query
+   went from 20.0 to 9.1 ms (PERF.md 5-6; ROADMAP Speed 3). The batch sits
+   on the lane axis, so 8 queries use 8 lanes of 128.
 
 Run on CPU (tests) via interpret mode — semantics identical, speed not.
 """
@@ -200,7 +211,12 @@ def spd_solve(A, b, interpret: Optional[bool] = None):
 
 # item rows per grid step: one f32 tile of the streamed factor table.
 # DeviceTopK pads its item store to this multiple ONCE at construction
-# so dispatches never pay a per-call pad copy.
+# so dispatches never pay a per-call pad copy. With the selection
+# rounds bounded (PR 29) a tile of 512 rows was 7% (K = 128) to 29%
+# (K = 16) faster on a v5e at rank 64, and does not fit VMEM at rank
+# 2048 with 256 queries (the sequence lane's store; the [TM, R] tile is
+# held twice and once more as f32): 128 fits every store served today
+# (tests/test_seen_bitmap_layout.py compiles the widest for a v5e).
 TOPK_TILE_M = 128
 
 # query block rounds up to a lane-friendly multiple (scores sit [TM, B]
@@ -256,42 +272,70 @@ def unpack_seen_bits(words, n_pos: int):
     return h.reshape(words.shape[:-1] + (-1,))[..., :n_pos] > 0
 
 
-def _topk_select_body(scores, item_ids, run_v, run_i, buf_v, buf_i, K):
+def _topk_select_body(scores, off, rounds, run_v, run_i, work, K):
     """Fold one ``[TM, B]`` score tile into the running per-query
-    top-K (``run_v``/``run_i`` [K, B], value-sorted descending).
+    top-K (``run_v``/``run_i`` [K, B], value-sorted descending) in
+    ``rounds`` selection rounds (int32 scalar, :func:`_topk_rounds`).
 
-    Selection is K rounds of argmax-extract over the union buffer
-    ``[K + TM, B]`` — every per-round op is a full-lane-width VPU
-    reduction/select, nothing indexes a lane dynamically. Tie-breaking
-    matches ``jax.lax.top_k`` (lowest index wins): the running entries
-    occupy the LOW buffer positions and earlier tiles hold strictly
-    lower item ids, so ``argmax``'s first-match rule reproduces the
-    XLA chain's ordering exactly."""
+    A round takes each query's best REMAINING tile score (max + first
+    match over the tile alone) and, for the queries where it beats
+    their current k-th, inserts it into the sorted list by rank: the
+    rows from the rank down shift by one, the last falls off. A query
+    that is done sits the remaining rounds out. Every per-round op is
+    a full-width VPU select or sublane reduction, nothing indexes a
+    lane dynamically.
+    Tie-breaking matches ``jax.lax.top_k`` (lowest index wins): a
+    newcomer's rank counts the running values ``>=`` it, which are
+    earlier tiles' (lower ids) or this tile's earlier rows (first
+    match), and a score equal to the k-th never enters."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
     TM = scores.shape[0]
-    buf_v[0:K] = run_v[:]
-    buf_i[0:K] = run_i[:]
-    buf_v[K:K + TM] = scores
-    buf_i[K:K + TM] = jnp.broadcast_to(item_ids, scores.shape)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (K + TM, 1), 0)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (TM, 1), 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, (K, 1), 0)
+    work[:] = scores
 
-    def sel(j, _):
-        bv = buf_v[:]
-        m = jnp.max(bv, axis=0)                       # [B]
-        am = jnp.argmax(bv, axis=0).astype(jnp.int32)  # first max
-        one = pos == am[None, :]                      # [K+TM, B]
-        run_v[j] = m
-        run_i[j] = jnp.sum(jnp.where(one, buf_i[:], 0), axis=0)
-        buf_v[:] = jnp.where(one, -jnp.inf, bv)
-        return 0
+    def insert(_, m):                                 # m [1, B]
+        w = work[:]
+        am = jnp.min(jnp.where(w == m, pos, TM), axis=0, keepdims=True)
+        rv, ri = run_v[:], run_i[:]
+        rank = jnp.sum((rv >= m).astype(jnp.int32), axis=0, keepdims=True)
+        rank = jnp.where(m > rv[K - 1:K], rank, K)    # done: no row
+        run_v[:] = jnp.where(row == rank, m,
+                             jnp.where(row > rank, pltpu.roll(rv, 1, 0),
+                                       rv))
+        run_i[:] = jnp.where(row == rank, am + off,
+                             jnp.where(row > rank, pltpu.roll(ri, 1, 0),
+                                       ri))
+        w = jnp.where(pos == am, -jnp.inf, w)
+        work[:] = w
+        return jnp.max(w, axis=0, keepdims=True)
 
-    jax.lax.fori_loop(0, K, sel, 0)
+    jax.lax.fori_loop(0, rounds, insert,
+                      jnp.max(scores, axis=0, keepdims=True))
+
+
+def _topk_rounds(scores, kth, K):
+    """The selection rounds one ``[TM, B]`` tile earns against the
+    queries' current k-th scores ``kth [1, B]``: best first against a
+    rising k-th, a query inserts at most as many scores as beat its
+    k-th when the tile arrives, so the count is that number for the
+    query with most of them, ``min(K, TM)`` at most, taken in one pass
+    before the loop: none for a tile no query gains from, 3-5 of
+    K = 128 in the middle of a pass, every row when the scores rise
+    with the item id. (A ``while`` on "any query still gains" runs a
+    tenth fewer rounds and each costs twice as much on a v5e: the
+    vector-to-scalar hop of its condition; my chip run, PR 28, call 1.)"""
+    import jax.numpy as jnp
+
+    newcomers = jnp.sum((scores > kth).astype(jnp.int32), axis=0)
+    return jnp.minimum(jnp.max(newcomers), min(K, scores.shape[0]))
 
 
 def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sb_ref,
-                     vals_ref, idx_ref, run_v, run_i, buf_v, buf_i,
+                     vals_ref, idx_ref, rounds_ref, run_v, run_i, work,
                      *, K, n_items, n_tiles):
     """One grid step = one ``[TM, R]`` item tile scored, masked, and
     merged (see module docstring). ``ys_ref`` is None for dense f32/
@@ -311,6 +355,7 @@ def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sb_ref,
     def _init():
         run_v[:] = jnp.full(run_v.shape, -jnp.inf, run_v.dtype)
         run_i[:] = jnp.zeros(run_i.shape, run_i.dtype)
+        rounds_ref[0, 0] = 0
 
     off = t * TM
     y = yd_ref[:].astype(jnp.float32)
@@ -345,16 +390,15 @@ def _fused_topk_body(q_ref, yd_ref, ys_ref, rv_ref, sb_ref,
             for j in range(TM // SEEN_WORD_BITS)], axis=0)
         scores = jnp.where(hit > 0, -jnp.inf, scores)
 
-    # early-out: a tile whose best score cannot beat any query's
-    # current k-th never changes the heap (ties lose to the running
-    # entry, which is always an earlier == lower item id)
-    kth = run_v[K - 1]                                # [B]
-    need = jnp.any(jnp.max(scores, axis=0) > kth)
+    # the early-out is the zero-round case: a tile with no score over
+    # any query's current k-th never changes the list (ties lose to the
+    # running entry, which is always an earlier == lower item id)
+    rounds = _topk_rounds(scores, run_v[K - 1:K], K)
 
-    @pl.when(need)
+    @pl.when(rounds > 0)
     def _merge():
-        _topk_select_body(scores, item_ids, run_v, run_i, buf_v, buf_i,
-                          K)
+        rounds_ref[0, 0] += rounds
+        _topk_select_body(scores, off, rounds, run_v, run_i, work, K)
 
     @pl.when(t == n_tiles - 1)
     def _out():
@@ -388,9 +432,13 @@ def fused_gather_score_topk(Q, Y, seen_bits=None, *,
     real item) for stores whose real rows are not a contiguous prefix
     — the density-sharded per-shard lane.
 
-    Returns ``(vals [B, k] f32, idx [B, k] i32)``, rows descending,
-    -inf past the valid candidates — the same contract as the XLA
-    ``top_k`` chain, tie-broken identically (lowest item id first)."""
+    Returns ``(vals [B, k] f32, idx [B, k] i32, rounds)``: rows
+    descending, -inf past the valid candidates — the same contract as
+    the XLA ``top_k`` chain, tie-broken identically (lowest item id
+    first) — and the int32 count of selection rounds the pass ran
+    (:func:`_topk_select_body`), which the serving programs pack beside
+    the winners (``ops.serving._pack``) for the dispatch record's
+    ``selectRounds``."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -464,30 +512,31 @@ def fused_gather_score_topk(Q, Y, seen_bits=None, *,
         if mask_seen:
             sbr = refs[pos]
             pos += 1
-        vals_ref, idx_ref, run_v, run_i, buf_v, buf_i = refs[pos:]
+        vals_ref, idx_ref, rounds_ref, run_v, run_i, work = refs[pos:]
         _fused_topk_body(qr, ydr, ysr, rvr, sbr, vals_ref, idx_ref,
-                         run_v, run_i, buf_v, buf_i, K=K,
+                         rounds_ref, run_v, run_i, work, K=K,
                          n_items=n_items, n_tiles=n_tiles)
 
-    vals, idx = pl.pallas_call(
+    vals, idx, rounds = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((K, Bp), lambda t: (0, 0)),
             pl.BlockSpec((K, Bp), lambda t: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),   # rounds, summed
         ],
         out_shape=[
             jax.ShapeDtypeStruct((K, Bp), jnp.float32),
             jax.ShapeDtypeStruct((K, Bp), jnp.int32),
+            jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((K, Bp), jnp.float32),        # running top-k
             pltpu.VMEM((K, Bp), jnp.int32),
-            pltpu.VMEM((K + TM, Bp), jnp.float32),   # selection union
-            pltpu.VMEM((K + TM, Bp), jnp.int32),
+            pltpu.VMEM((TM, Bp), jnp.float32),       # the tile, picked over
         ],
         interpret=bool(interpret),
         name="fused_topk",
     )(*args)
-    return vals.T[:B], idx.T[:B]
+    return vals.T[:B], idx.T[:B], rounds[0, 0]

@@ -285,10 +285,13 @@ def _mask_padding(scores, n_items: int):
     return scores
 
 
-def _pack(scores, idx):
+def _pack(scores, idx, rounds=None):
     """Fuse (scores [.., k] f32, idx [.., k] i32) into ONE [.., 2k]
     int32 buffer (scores bitcast, not value-cast — exact) so the host
-    pays a single device→host fetch per dispatch.
+    pays a single device→host fetch per dispatch. ``rounds``, the fused
+    kernel's count of selection rounds for the dispatch (an int32
+    scalar; the XLA chains have none), rides as one more column, the
+    same number in every row: :func:`_packed_rounds` reads it back.
 
     The buffer is INTEGER on purpose. Reinterpreted as float32, an
     index below 2^23 is a denormal, and the TPU flushes denormals to
@@ -300,14 +303,25 @@ def _pack(scores, idx):
     import jax
     import jax.numpy as jnp
 
-    return jnp.concatenate(
-        [jax.lax.bitcast_convert_type(scores, jnp.int32),
-         idx.astype(jnp.int32)], axis=-1)
+    parts = [jax.lax.bitcast_convert_type(scores, jnp.int32),
+             idx.astype(jnp.int32)]
+    if rounds is not None:
+        parts.append(jnp.broadcast_to(rounds.astype(jnp.int32),
+                                      scores.shape[:-1] + (1,)))
+    return jnp.concatenate(parts, axis=-1)
 
 
 def _unpack(out: np.ndarray, kb: int) -> Tuple[np.ndarray, np.ndarray]:
     """Host-side inverse of `_pack` on the fetched numpy buffer."""
-    return out[..., kb:], out[..., :kb].view(np.float32)
+    return out[..., kb:2 * kb], out[..., :kb].view(np.float32)
+
+
+def _packed_rounds(out: np.ndarray, kb: int) -> Optional[int]:
+    """The selection rounds a fused program packed behind its ``2 kb``
+    result columns; None for a program that packs none."""
+    if out.shape[-1] <= 2 * kb:
+        return None
+    return int(out[..., 2 * kb].flat[0])
 
 
 def _take_user_row_f32(X, uid, *, mode: str):
@@ -443,7 +457,9 @@ def _sharded_score_topk(Y, valid, Q, seen_bits, *, k: int,
     ``Q [B, R]`` fp32 replicated queries; ``seen_bits`` ``[B, W]`` the
     queries' packed seen bitmap over store POSITIONS (replicated;
     ignored without ``mask_seen``). Returns ``(vals [B, k] f32,
-    positions [B, k] i32)`` replicated."""
+    positions [B, k] i32, rounds)`` replicated; ``rounds`` is the sum
+    of the shards' kernels' selection rounds (None off the fused
+    kernel), for :func:`_pack`."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -472,7 +488,7 @@ def _sharded_score_topk(Y, valid, Q, seen_bits, *, k: int,
         kl = min(k, m)
         if fused:
             Yl = QuantFactors(Yd, Ys) if quant else Yd
-            vals, li = fused_gather_score_topk(
+            vals, li, rounds = fused_gather_score_topk(
                 Qb, Yl, k=kl, n_items=m, mask_seen=mask_seen,
                 seen_bits=pack_seen_bits(hit) if mask_seen else None,
                 row_valid=vl, interpret=interpret)
@@ -495,19 +511,24 @@ def _sharded_score_topk(Y, valid, Q, seen_bits, *, k: int,
             vals = jnp.pad(vals, ((0, 0), (0, k - kl)),
                            constant_values=-jnp.inf)
             li = jnp.pad(li, ((0, 0), (0, k - kl)))
-        return _tree_merge_topk(vals, li + off, k, axis, n_sh)
+        merged = _tree_merge_topk(vals, li + off, k, axis, n_sh)
+        # the shards' kernels each count their own rounds: their sum
+        return merged + (lax.psum(rounds, axis),) if fused else merged
 
     row, col, repl = P(axis, None), P(axis), P(None, None)
+    outs = (repl, repl, P()) if fused else (repl, repl)
     if quant:
         fn = jax.shard_map(body, mesh=mesh,
                            in_specs=(row, col, col, repl, repl),
-                           out_specs=(repl, repl), check_vma=False)
-        return fn(Y.data, Y.scale, valid, Q, seen_bits)
-    fn = jax.shard_map(
-        lambda Yd, vl, Qb, sbq: body(Yd, None, vl, Qb, sbq),
-        mesh=mesh, in_specs=(row, col, repl, repl),
-        out_specs=(repl, repl), check_vma=False)
-    return fn(Y, valid, Q, seen_bits)
+                           out_specs=outs, check_vma=False)
+        out = fn(Y.data, Y.scale, valid, Q, seen_bits)
+    else:
+        fn = jax.shard_map(
+            lambda Yd, vl, Qb, sbq: body(Yd, None, vl, Qb, sbq),
+            mesh=mesh, in_specs=(row, col, repl, repl),
+            out_specs=outs, check_vma=False)
+        out = fn(Y, valid, Q, seen_bits)
+    return out if fused else out + (None,)
 
 
 def _user_topk(X, Y, seen_bits, uid, *, k: int, mask_seen: bool,
@@ -1936,11 +1957,11 @@ class DeviceTopK:
                     with jax.named_scope("seen_rows"):
                         seen = jnp.take(sb, u, axis=0)
                 with jax.named_scope("topk"):
-                    vals, idx = fused_gather_score_topk(
+                    vals, idx, rounds = fused_gather_score_topk(
                         Q, Y, k=kb, n_items=n_items, mask_seen=mask_seen,
                         seen_bits=seen, interpret=interpret)
                 with jax.named_scope("pack"):
-                    packed = _pack(vals, idx)
+                    packed = _pack(vals, idx, rounds)
                 return packed[0] if scalar else packed
 
             prog = self._fused_programs[("u", kb)] = users_topk_fused
@@ -1972,11 +1993,11 @@ class DeviceTopK:
                 with jax.named_scope("seen_rows"):
                     own = pack_seen_ids(idxs, masks > 0, int(Yn.shape[0]))
                 with jax.named_scope("topk"):
-                    vals, idx = fused_gather_score_topk(
+                    vals, idx, rounds = fused_gather_score_topk(
                         Q, Yn, own, k=kb, n_items=n_items, mask_seen=True,
                         interpret=interpret)
                 with jax.named_scope("pack"):
-                    return _pack(vals, idx)
+                    return _pack(vals, idx, rounds)
 
             prog = self._fused_programs[("i", kb)] = items_topk
         return prog
@@ -2006,12 +2027,12 @@ class DeviceTopK:
                 with jax.named_scope("seen_rows"):
                     seen = jnp.take(sb, u, axis=0)
                 with jax.named_scope("topk"):
-                    vals, pos = _sharded_score_topk(
+                    vals, pos, rounds = _sharded_score_topk(
                         Y, valid, Q, seen, k=kb,
                         mask_seen=mask_seen, mode=mode, mesh=mesh,
                         axis=axis, fused=fused, interpret=interpret)
                 with jax.named_scope("pack"):
-                    packed = _pack(vals, pos)
+                    packed = _pack(vals, pos, rounds)
                 return packed[0] if scalar else packed
 
             prog = self._shard_programs[("u", kb)] = users_topk_sharded
@@ -2044,12 +2065,12 @@ class DeviceTopK:
                     own = pack_seen_ids(idxs, masks > 0,
                                         int(valid.shape[0]))
                 with jax.named_scope("topk"):
-                    vals, pos = _sharded_score_topk(
+                    vals, pos, rounds = _sharded_score_topk(
                         Yn, valid, Q, own, k=kb, mask_seen=True,
                         mode=mode, mesh=mesh, axis=axis, fused=fused,
                         interpret=interpret)
                 with jax.named_scope("pack"):
-                    return _pack(vals, pos)
+                    return _pack(vals, pos, rounds)
 
             prog = self._shard_programs[("i", kb)] = items_topk
         return prog
@@ -2433,7 +2454,9 @@ class DeviceTopK:
         id map, as the ``dispatch.fetch`` stage of the record just
         written."""
         with _dtel.stage("fetchUs", "dispatch.fetch", done=True):
-            idx, scores = _unpack(np.asarray(out), kb)
+            host = np.asarray(out)
+            idx, scores = _unpack(host, kb)
+            _dtel.note_select_rounds(_packed_rounds(host, kb))
             return self._positions_to_items(idx[cut]), scores[cut]
 
     def user_topk(self, uid: int, k: int) -> Tuple[np.ndarray, np.ndarray]:

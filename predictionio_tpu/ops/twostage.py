@@ -95,7 +95,8 @@ def _dispatch_two_group(srv: "TwoStageTopK",
 
 
 def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
-                     mode: str, mask_seen: bool, pos_ids=None):
+                     mode: str, mask_seen: bool, pos_ids=None,
+                     rounds=None):
     """Stage 2, shared by every stage-1 lane (XLA / fused / sharded):
     candidate gather -> re-rank score -> ONE seen mask -> final top-k,
     all inside the caller's jitted program (the candidates never leave
@@ -138,7 +139,7 @@ def _twostage_rerank(E, U, uids, vals1, pos, sbq, *, kb: int,
     out_vals, sel = lax.top_k(s2, kb)
     out_pos = jnp.take_along_axis(pos, sel, axis=-1)
     with jax.named_scope("pack"):
-        return _pack(out_vals, out_pos)
+        return _pack(out_vals, out_pos, rounds)
 
 
 class TwoStageTopK(DeviceTopK):
@@ -392,7 +393,7 @@ class TwoStageTopK(DeviceTopK):
                     with jax.named_scope("seen_rows"):
                         sbq = jnp.take(sb, uids, axis=0)
                     with jax.named_scope("topk"):
-                        vals1, pos = _sharded_score_topk(
+                        vals1, pos, rounds = _sharded_score_topk(
                             Y, valid, Q, sbq, k=nb, mask_seen=False,
                             mode=mode, mesh=mesh, axis=axis, fused=fused,
                             interpret=interpret)
@@ -400,7 +401,8 @@ class TwoStageTopK(DeviceTopK):
                     return _twostage_rerank(E, U, uids, vals1, pos, sbq,
                                             kb=kb, mode=mode,
                                             mask_seen=mask_seen,
-                                            pos_ids=pos_ids)
+                                            pos_ids=pos_ids,
+                                            rounds=rounds)
         elif self._kernel == "fused":
             from predictionio_tpu.ops.als_pallas import (
                 fused_gather_score_topk,
@@ -414,7 +416,7 @@ class TwoStageTopK(DeviceTopK):
                     with jax.named_scope("gather_q"):
                         Q = _gather_rows_f32(X, uids, mode=mode)
                     with jax.named_scope("topk"):
-                        vals1, pos = fused_gather_score_topk(
+                        vals1, pos, rounds = fused_gather_score_topk(
                             Q, Y, k=nb, n_items=n_items, mask_seen=False,
                             interpret=interpret)
                 with jax.named_scope("seen_rows"):
@@ -423,7 +425,8 @@ class TwoStageTopK(DeviceTopK):
                     return _twostage_rerank(E, U, uids, vals1, pos, sbq,
                                             kb=kb, mode=mode,
                                             mask_seen=mask_seen,
-                                            pos_ids=pos_ids)
+                                            pos_ids=pos_ids,
+                                            rounds=rounds)
         else:
             n_rows = int(self._Y.shape[0])
 

@@ -22,7 +22,9 @@ device-time accounting, applied to the serving plane:
   ``gapWindowUs`` asleep on a batching window, ``formUs`` forming the
   batch and ``lockWaitUs`` acquiring the store lock; then
   ``enqueueUs`` (the program call), ``deviceUs``, and after the record
-  is written ``fetchUs`` and ``deliverUs``. Over one ``dispatcher``
+  is written ``fetchUs`` and ``deliverUs`` (and, from the fetched
+  result of a fused-kernel dispatch, ``selectRounds``:
+  :func:`note_select_rounds`). Over one ``dispatcher``
   thread's consecutive records ``gapUs + enqueueUs + deviceUs`` adds
   up to the wall clock. Each stage is also a profiler annotation
   (``batch.idle``, ``batch.window``, ``batch.form``, ``dispatch.lock``,
@@ -64,6 +66,7 @@ __all__ = [
     "current_dispatch_context",
     "stage",
     "mark_ready",
+    "note_select_rounds",
 ]
 
 
@@ -291,6 +294,20 @@ def mark_ready() -> None:
     life and not only the time after its first dispatch."""
     if RECORDER.enabled:
         _stage.ready = time.monotonic()
+
+
+def note_select_rounds(rounds: Optional[int]) -> None:
+    """The fused kernel's selection rounds for the dispatch this thread
+    recorded last, read out of its fetched result: ``selectRounds`` on
+    that record and ``pio_topk_select_rounds_total``. None (an XLA
+    chain, which counts none) and a killed recorder change nothing."""
+    rec = RECORDER.last() if RECORDER.enabled else None
+    if rounds is None or rec is None:
+        return
+    rec["selectRounds"] = rounds = int(rounds)
+    from predictionio_tpu.utils import metrics
+
+    metrics.TOPK_SELECT_ROUNDS.inc(rounds, lane=rec["lane"])
 
 
 def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,

@@ -869,6 +869,12 @@ DISPATCH_DEVICE_SECONDS = REGISTRY.histogram(
     "Device time per serving dispatch (dispatch -> block_until_ready on "
     "the monotonic clock) by lane, kernel family and store precision",
     ("lane", "kernel", "precision"), buckets=DEVICE_DISPATCH_BUCKETS)
+TOPK_SELECT_ROUNDS = REGISTRY.counter(
+    "pio_topk_select_rounds_total",
+    "Selection rounds the fused top-k kernel ran (one insertion pass "
+    "over a tile's scores each; rounds / (item tiles x k) is the share "
+    "of a full re-selection per tile that was needed) by lane",
+    ("lane",))
 AOT_CACHE_REQUESTS = REGISTRY.counter(
     "pio_aot_cache_requests_total",
     "Serving-program lookups against the AOT bucket ladder (hit = "

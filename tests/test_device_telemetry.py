@@ -218,6 +218,48 @@ class TestDispatchInstrumentation:
         assert hist.summary()["count"] >= 1
         srv.close()
 
+    @pytest.mark.parametrize("lane", ["user", "users", "items"])
+    def test_fused_dispatch_records_select_rounds(self, lane,
+                                                  fresh_recorder,
+                                                  monkeypatch):
+        """The fused kernel's count of selection rounds rides in the
+        packed result and lands on the record of its dispatch and in
+        ``pio_topk_select_rounds_total``; the answers are the ones the
+        XLA chain gives."""
+        metrics.REGISTRY.reset()
+        xla = self._store(n_items=100)
+        monkeypatch.setenv("PIO_SERVE_KERNEL", "fused")
+        srv = self._store(n_items=100)
+        assert srv._kernel == "fused" and xla._kernel == "xla"
+        call = {"user": lambda s: s.user_topk(0, 5),
+                "users": lambda s: s.users_topk(np.arange(6), 5),
+                "items": lambda s: s.items_topk([3, 4], 5)}[lane]
+        want = call(xla)
+        assert "selectRounds" not in fresh_recorder.snapshot(1)[0]
+        got = call(srv)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_allclose(got[1], want[1], rtol=1e-5)
+        rec = fresh_recorder.snapshot(1)[0]
+        assert rec["lane"] == lane and rec["kernel"] == "fused"
+        # 100 items are one tile, and the empty list takes its best 16
+        assert rec["selectRounds"] == 16
+        assert metrics.TOPK_SELECT_ROUNDS.value(lane=lane) \
+            == rec["selectRounds"]
+        xla.close()
+        srv.close()
+
+    def test_select_rounds_off_with_the_recorder(self, fresh_recorder,
+                                                 monkeypatch):
+        metrics.REGISTRY.reset()
+        monkeypatch.setenv("PIO_SERVE_KERNEL", "fused")
+        device_telemetry.set_enabled(False)
+        srv = self._store(n_items=200)
+        idx, _ = srv.user_topk(0, 5)
+        assert len(idx) == 5
+        assert fresh_recorder.counts()["recorded"] == 0
+        assert metrics.TOPK_SELECT_ROUNDS.value(lane="user") == 0
+        srv.close()
+
     def test_killed_lane_still_serves(self, fresh_recorder):
         device_telemetry.set_enabled(False)
         srv = self._store()
@@ -397,6 +439,15 @@ def request(addr, method, path, body=None, params=None):
     return resp.status, json.loads(data) if data else None
 
 
+def scrape_metrics(addr):
+    host, port = addr
+    conn = http.client.HTTPConnection(host, port, timeout=30)
+    conn.request("GET", "/metrics")
+    text = conn.getresponse().read().decode("utf-8")
+    conn.close()
+    return text
+
+
 class TestDeployedSurfaces:
     def _drive(self, addr, n=6):
         for u in range(n):
@@ -456,16 +507,37 @@ class TestDeployedSurfaces:
 
     def test_device_gauges_exposed(self, deployed):
         self._drive(deployed.address, n=2)
-        host, port = deployed.address
-        conn = http.client.HTTPConnection(host, port, timeout=30)
-        conn.request("GET", "/metrics")
-        text = conn.getresponse().read().decode("utf-8")
-        conn.close()
+        text = scrape_metrics(deployed.address)
         store_line = next(ln for ln in text.splitlines()
                           if ln.startswith("pio_device_store_bytes"))
         assert float(store_line.split()[-1]) > 0
         assert "pio_aot_cache_requests_total" in text
         assert "pio_dispatch_device_seconds_bucket" in text
+
+    def test_select_rounds_on_dispatches_and_metrics(self, mem_storage,
+                                                     monkeypatch):
+        """A deploy on the fused kernel: ``selectRounds`` on the
+        ``/dispatches.json`` records and the counter on ``/metrics``."""
+        monkeypatch.setenv("PIO_SERVING_BACKEND", "device")
+        monkeypatch.setenv("PIO_SERVE_KERNEL", "fused")
+        seed_and_train()
+        srv = QueryServer(ServerConfig(ip="127.0.0.1", port=0)).start(
+            undeploy_stale=False)
+        try:
+            self._drive(srv.address, n=3)
+            _, payload = request(srv.address, "GET", "/dispatches.json")
+            recs = [r for r in payload["dispatches"]
+                    if r["lane"] == "users"]
+            assert recs and all(r["kernel"] == "fused" and
+                                r["selectRounds"] > 0 for r in recs)
+            line = next(ln for ln in
+                        scrape_metrics(srv.address).splitlines()
+                        if ln.startswith(
+                            'pio_topk_select_rounds_total{lane="users"}'))
+            assert float(line.split()[-1]) >= sum(
+                r["selectRounds"] for r in recs)
+        finally:
+            srv.stop()
 
     def test_slow_query_log_carries_dispatch_context(
             self, deployed, monkeypatch):

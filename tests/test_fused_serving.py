@@ -16,10 +16,12 @@ import pytest
 import jax.numpy as jnp
 
 from predictionio_tpu.ops.als_pallas import (
+    TOPK_TILE_M,
     fused_gather_score_topk,
     pack_seen_ids,
 )
 from predictionio_tpu.ops.quantize import (
+    QuantFactors,
     dequantize_rows_np,
     quantize_rows_int8,
 )
@@ -71,7 +73,7 @@ class TestKernelExactAgreement:
         sc = rng.integers(0, M, (L, B)).astype(np.int32)
         sm = (rng.random((L, B)) < 0.7).astype(np.float32)
         n_items = M - 2
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, M), k=k,
             n_items=n_items, mask_seen=True, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, sc, sm, k, n_items)
@@ -86,7 +88,7 @@ class TestKernelExactAgreement:
         rng = np.random.default_rng(0)
         Q = int_factors(rng, (4, 5))
         Y = int_factors(rng, (40, 5))
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), k=6,
             n_items=40, mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, None, None, 6, 40)
@@ -100,7 +102,7 @@ class TestKernelExactAgreement:
         Q = np.asarray([[1.0, 0.0]], dtype=np.float32)
         Y = np.zeros((200, 2), dtype=np.float32)
         Y[:, 0] = 7.0                      # every item ties at score 7
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), k=5,
             n_items=200, mask_seen=False, interpret=True)
         np.testing.assert_array_equal(np.asarray(idx)[0],
@@ -112,7 +114,7 @@ class TestKernelExactAgreement:
         Y = np.ones((10, 3), dtype=np.float32)
         sc = np.tile(np.arange(10, dtype=np.int32)[:, None], (1, 2))
         sm = np.ones((10, 2), dtype=np.float32)
-        vals, _ = fused_gather_score_topk(
+        vals, _, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, 10), k=4,
             n_items=10, mask_seen=True, interpret=True)
         assert (np.asarray(vals) == -np.inf).all()
@@ -121,7 +123,7 @@ class TestKernelExactAgreement:
         rng = np.random.default_rng(7)
         Q = rng.normal(size=(6, 8)).astype(np.float32)
         Y = rng.normal(size=(150, 8)).astype(np.float32)
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), k=10,
             n_items=150, mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, Y, None, None, 10, 150)
@@ -129,6 +131,215 @@ class TestKernelExactAgreement:
         for b in range(6):
             assert set(np.asarray(idx)[b].tolist()) == \
                 set(wi[b].tolist())
+
+
+# ---------------------------------------------------------------------------
+# the bounded merge (ISSUE 29): a tile costs as many selection rounds as
+# the query that gains most from it has newcomers, and the answers stay
+# lax.top_k's to the bit
+# ---------------------------------------------------------------------------
+
+MERGE_M = 333            # three tiles of 128, the last one part padding
+ORDERS = ("random", "rising", "falling", "blocks")
+STORES = ("fp32", "bf16", "int8", "row_valid")
+
+
+def _merge_problem(order, store, B, seed, m=MERGE_M):
+    """Integer-valued item rows ``[m, 2]`` and query rows whose scores
+    are exact small integers in every store (bf16 holds integers to
+    256; the int8 store is built with power-of-two scales), laid out so
+    the scores rise with the item id (every tile is all newcomers),
+    fall with it (only the first tiles merge), or come in blocks of 37
+    equal scores (duplicated item rows: a block straddles the tile edge
+    at 128 and the k-th place of K = 16, 32, 64, 128 and 200)."""
+    rng = np.random.default_rng(seed)
+    i = np.arange(m)
+    if order == "random":
+        Y = rng.integers(-6, 7, (m, 2))
+        Q = rng.integers(-5, 6, (B, 2))
+    else:
+        Y = np.stack([i // 16, i % 16], axis=1)       # 16 a + b = id
+        c = rng.integers(1, 4, (B, 1))
+        Q = c * np.asarray([[16, 1]])
+        if order == "falling":
+            Q = -Q
+        elif order == "blocks":
+            Y = np.stack([i // 37, np.zeros_like(i)], axis=1)
+            Q = -c * np.asarray([[1, 0]])
+    Y = Y.astype(np.float32)
+    Q = Q.astype(np.float32)
+    valid = None
+    if store == "row_valid":                           # holes
+        valid = (rng.random(m) < 0.75).astype(np.float32)
+    if store == "bf16":
+        Yd = jnp.asarray(Y).astype(jnp.bfloat16)
+    elif store == "int8":
+        # data * scale == Y exactly: scales 1 and 2, even rows halved
+        half = (Y % 2 == 0).all(axis=1)
+        Yd = QuantFactors(
+            jnp.asarray(np.where(half[:, None], Y / 2, Y).astype(np.int8)),
+            jnp.asarray(np.where(half, 2.0, 1.0).astype(np.float32)))
+    else:
+        Yd = jnp.asarray(Y)
+    return Q, Y, Yd, valid
+
+
+# the merge tests stream three tiles of a small catalog at the kernel's
+# own tile size
+MERGE_TM = TOPK_TILE_M
+# a wider tile (what PR 28 and PR 29's first round ran): ``tile_m``
+# takes any multiple of the seen word
+WIDE_TM = 512
+# ... and a catalog that is no multiple of either, the last tile ragged
+RAGGED_M = 1100
+
+
+def _rule_rounds(scores, K, tm=MERGE_TM):
+    """What the merge rule says a pass costs, counted in numpy:
+    ``scores [M, B]`` masked, B a multiple of 8 as the kernel pads it;
+    per tile the largest number, over the queries, of tile scores that
+    beat the query's k-th as the tile arrives (a score equal to it
+    does not), at most ``min(K, tm)``."""
+    M, B = scores.shape
+    run = np.full((K, B), -np.inf, dtype=np.float32)
+    rounds = 0
+    for t0 in range(0, M, tm):
+        tile = scores[t0:t0 + tm]
+        rounds += min(int((tile > run[K - 1]).sum(axis=0).max()), K, tm)
+        union = np.concatenate([run, tile])
+        run = -np.sort(-union, axis=0, kind="stable")[:K]
+    return rounds
+
+
+def _assert_merge_exact(K, B, order, store, *, m=MERGE_M, tm=MERGE_TM,
+                        masked=False, n_items=None, valid=None):
+    """One pass of the kernel against ``lax.top_k`` over the same
+    masked scores: values to the bit, ids wherever a value is finite
+    (the -inf tail past the valid candidates carries no id), and the
+    returned round count against :func:`_rule_rounds`."""
+    import jax
+
+    from predictionio_tpu.ops.als_pallas import pack_seen_bits
+
+    n_items = m if n_items is None else n_items
+    Q, Y, Yd, holes = _merge_problem(order, store, B, seed=K * 31 + B, m=m)
+    valid = holes if valid is None else valid
+    hit = None
+    if masked:
+        hit = np.random.default_rng(K + B).random((B, m)) < 0.4
+    vals, idx, rounds = fused_gather_score_topk(
+        jnp.asarray(Q), Yd,
+        pack_seen_bits(jnp.asarray(hit)) if masked else None, k=K,
+        n_items=n_items, mask_seen=masked, row_valid=valid,
+        interpret=True, tile_m=tm)
+    scores = Y @ Q.T                               # [M, B], exact
+    scores[n_items:] = -np.inf
+    if valid is not None:
+        scores[valid <= 0] = -np.inf
+    if masked:
+        scores[hit.T] = -np.inf
+    wv, wi = jax.lax.top_k(jnp.asarray(scores.T), K)
+    wv, wi = np.asarray(wv), np.asarray(wi)
+    vals, idx = np.asarray(vals), np.asarray(idx)
+    np.testing.assert_array_equal(vals, wv)
+    fin = np.isfinite(wv)
+    np.testing.assert_array_equal(idx[fin], wi[fin])
+    # the counter: the kernel pads the query block to a multiple of
+    # 8 with zero rows, which score 0 on every valid unmasked item
+    # (their seen words are zero padding too)
+    padded = np.zeros((m, -(-B // 8) * 8), dtype=np.float32)
+    padded[n_items:] = -np.inf
+    if valid is not None:
+        padded[valid <= 0] = -np.inf
+    padded[:, :B] = scores
+    assert int(rounds) == _rule_rounds(padded, K, tm)
+    return wv
+
+
+class TestBoundedMerge:
+    @pytest.mark.parametrize("store", STORES)
+    @pytest.mark.parametrize("order", ORDERS)
+    @pytest.mark.parametrize("B", [1, 8, 16])
+    @pytest.mark.parametrize("K", [1, 16, 128, 200])
+    def test_exact_against_lax_top_k(self, K, B, order, store):
+        _assert_merge_exact(K, B, order, store)
+
+    @pytest.mark.parametrize("store", STORES)
+    @pytest.mark.parametrize("order", ORDERS)
+    @pytest.mark.parametrize("K,B", [(32, 8), (64, 8), (16, 256),
+                                     (64, 256), (128, 256)])
+    def test_exact_at_the_ladders_other_buckets(self, K, B, order, store):
+        """The user lane's K = 32 and 64, and the bucket a backlog or
+        ``BatchPredictor`` fills (256 queries: two lane tiles wide)."""
+        _assert_merge_exact(K, B, order, store)
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("order", ORDERS)
+    @pytest.mark.parametrize("K,store", [(16, "bf16"), (64, "int8"),
+                                         (128, "fp32"),
+                                         (128, "row_valid")])
+    def test_exact_at_a_wide_tile(self, K, store, order, masked):
+        """512 rows a tile (16 seen words unpacked a step, K under the
+        tile) over a catalog that is no multiple of it, with and
+        without the seen bitmap."""
+        _assert_merge_exact(K, 8, order, store, m=RAGGED_M, tm=WIDE_TM,
+                            masked=masked)
+
+    @pytest.mark.parametrize("masked", [False, True])
+    @pytest.mark.parametrize("K", [16, 32, 64, 128])
+    @pytest.mark.parametrize("how", ["n_items", "row_valid"])
+    def test_fewer_valid_candidates_than_k(self, how, K, masked):
+        """Eleven real items under K places: the finite prefix is
+        ``lax.top_k``'s and the tail is -inf, whichever way the catalog
+        says what is real; the padding rows cost no round."""
+        valid = None
+        if how == "row_valid":
+            valid = np.zeros(MERGE_M, np.float32)
+            valid[np.arange(11) * 30] = 1.0            # spread over tiles
+        wv = _assert_merge_exact(K, 8, "random", "fp32", masked=masked,
+                                 n_items=11 if valid is None else None,
+                                 valid=valid)
+        assert (np.isfinite(wv).sum(axis=1) <= 11).all()
+        assert (wv[:, 11:] == -np.inf).all()
+
+    @pytest.mark.parametrize("tm", [MERGE_TM, WIDE_TM])
+    @pytest.mark.parametrize("K", [1, 16, 128, 200])
+    @pytest.mark.parametrize("order", ["rising", "falling"])
+    def test_rounds_closed_form(self, order, K, tm):
+        """Falling scores: the list fills from the first tile, K rounds
+        in all, and no later tile merges (a K over the tile fills over
+        two tiles, and the second is counted against an open k-th: all
+        of its rows). Rising scores: every tile is all newcomers,
+        min(K, rows) rounds each, which is the old kernel's cost and the
+        most the loop can run."""
+        Q, _, Yd, _ = _merge_problem(order, "fp32", 8, seed=K)
+        _, _, rounds = fused_gather_score_topk(
+            jnp.asarray(Q), Yd, k=K, n_items=MERGE_M, mask_seen=False,
+            interpret=True, tile_m=tm)
+        tiles = [min(tm, MERGE_M - t) for t in range(0, MERGE_M, tm)]
+        if order == "rising":
+            want = sum(min(K, n) for n in tiles)
+        else:
+            want = K if K <= tm else 2 * tm
+        assert int(rounds) == want
+
+    def test_seen_mask_counts_only_unseen_newcomers(self):
+        """With the seen bitmap the masked rows are -inf before the
+        merge sees them: they cost no round and never enter."""
+        rng = np.random.default_rng(5)
+        Q, Y, Yd, _ = _merge_problem("rising", "fp32", 8, seed=5)
+        hit = rng.random((8, MERGE_M)) < 0.5
+        from predictionio_tpu.ops.als_pallas import pack_seen_bits
+
+        vals, idx, rounds = fused_gather_score_topk(
+            jnp.asarray(Q), Yd, pack_seen_bits(jnp.asarray(hit)), k=16,
+            n_items=MERGE_M, mask_seen=True, interpret=True,
+            tile_m=MERGE_TM)
+        scores = Y @ Q.T
+        scores[hit.T] = -np.inf
+        order = np.argsort(-scores, axis=0, kind="stable")[:16].T
+        np.testing.assert_array_equal(np.asarray(idx), order)
+        assert int(rounds) == _rule_rounds(scores, 16)
 
 
 class TestKernelInt8:
@@ -141,7 +352,7 @@ class TestKernelInt8:
         Y[:, 0] = 127.0                     # pin scale == 1.0 per row
         Q = rng.integers(-5, 6, (4, 6)).astype(np.float32)
         Yq = quantize_rows_int8(Y)
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), Yq, k=8, n_items=70,
             mask_seen=False, interpret=True)
         wv, wi = xla_chain_topk(Q, dequantize_rows_np(Yq), None, None,
@@ -154,7 +365,7 @@ class TestKernelInt8:
         Y = (rng.normal(size=(90, 5)) * 3).astype(np.float32)
         Q = rng.normal(size=(3, 5)).astype(np.float32)
         Yq = quantize_rows_int8(Y)
-        vals, _ = fused_gather_score_topk(
+        vals, _, _ = fused_gather_score_topk(
             jnp.asarray(Q), Yq, k=6, n_items=90,
             mask_seen=False, interpret=True)
         wv, _ = xla_chain_topk(Q, dequantize_rows_np(Yq), None, None,
@@ -283,7 +494,7 @@ class TestDeviceTopKFusedEndToEnd:
         Y = int_factors(rng, (1000, 16))
         sc = rng.integers(0, 1000, (12, 16)).astype(np.int32)
         sm = np.ones((12, 16), dtype=np.float32)
-        vals, idx = fused_gather_score_topk(
+        vals, idx, _ = fused_gather_score_topk(
             jnp.asarray(Q), jnp.asarray(Y), seen_bits(sc, sm, 1000),
             k=64,
             n_items=997, mask_seen=True, interpret=True)

@@ -215,21 +215,30 @@ def one_chip(v5e_2x2):
     return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
-# users x items, store precision: cell 2 / 4's store, chip_smoke's, and
-# cell 2's after one _reserve_users doubling
+# users x items, store precision, rank: cell 2 / 4's store, chip_smoke's,
+# cell 2's after one _reserve_users doubling, and the widest store
+# served: cell 5's, the OLMoE block's 2,048-wide user and output tables
+# (its [TM, 2048] item tile and [256, 2048] query block are what the
+# kernel's tile size has to leave room for in VMEM)
 SHAPES = {
-    "rec-msd": (571_355, 41_140, "bf16"),
-    "ml20m": (138_000, 27_000, "fp32"),
-    "rec-msd-grown": (bucket_size(571_356, lo=571_355), 41_140, "bf16"),
+    "rec-msd": (571_355, 41_140, "bf16", 64),
+    "ml20m": (138_000, 27_000, "fp32", 64),
+    "rec-msd-grown": (bucket_size(571_356, lo=571_355), 41_140, "bf16",
+                      64),
+    "seqrec-olmoe": (571_355, 41_140, "fp32", 2048),
+    "seqrec-olmoe-bf16": (571_355, 41_140, "bf16", 2048),
 }
 
 COMPILES = [(shape, lane, b)
             for shape in ("rec-msd", "ml20m")
             for lane, buckets in (("fused", (1, 8, 32, 256)),
                                   ("xla", (1, 8, 32, 256)),
-                                  ("two", (8, 32, 256)))
+                                  ("two", (8, 16, 32, 256)))
             for b in buckets] + [("rec-msd-grown", lane, 8)
-                                 for lane in ("fused", "xla", "two")]
+                                 for lane in ("fused", "xla", "two")] + [
+                (shape, "fused", b)
+                for shape in ("seqrec-olmoe", "seqrec-olmoe-bf16")
+                for b in (1, 8, 256)]
 
 
 def _real_program(lane, mode, n_items, bucket, monkeypatch):
@@ -252,6 +261,27 @@ def _real_program(lane, mode, n_items, bucket, monkeypatch):
     if bucket == 1:
         return srv, srv._user_program(16)
     return srv, srv._batch_program(16, bucket)
+
+
+def _kernel_scratch_rows(jaxpr):
+    """(rows, lanes) of VMEM scratch of every Pallas call in a traced
+    program: the rows of its ``[rows, lanes]`` 32-bit scratch buffers
+    added up."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n = eqn.params["grid_mapping"].num_scratch_operands
+            refs = [v.aval for v in eqn.params["jaxpr"].invars[-n:]]
+            assert all(a.dtype.itemsize == 4 and len(a.shape) == 2
+                       for a in refs)
+            assert len({a.shape[1] for a in refs}) == 1
+            found.append((sum(a.shape[0] for a in refs),
+                          refs[0].shape[1]))
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                found.extend(_kernel_scratch_rows(sub))
+    return found
 
 
 def _assert_bitmap_read_in_place(compiled, n_rows, words):
@@ -277,7 +307,7 @@ def test_v5e_user_programs_do_not_copy_the_bitmap(shape, lane, bucket,
 
     from predictionio_tpu.ops import als_pallas
 
-    n_users, n_items, mode = SHAPES[shape]
+    n_users, n_items, mode, rank = SHAPES[shape]
     dt = {"bf16": jnp.bfloat16, "fp32": jnp.float32}[mode]
     rows = n_items if lane == "xla" else \
         -(-n_items // als_pallas.TOPK_TILE_M) * als_pallas.TOPK_TILE_M
@@ -286,19 +316,27 @@ def test_v5e_user_programs_do_not_copy_the_bitmap(shape, lane, bucket,
     def sds(s, d):
         return jax.ShapeDtypeStruct(s, d, sharding=one_chip)
 
-    Xa, Ya = sds((n_users, 64), dt), sds((rows, 64), dt)
+    Xa, Ya = sds((n_users, rank), dt), sds((rows, rank), dt)
     sb = sds((n_users, words), jnp.int32)
     uids = sds(() if bucket == 1 else (bucket,), jnp.int32)
     srv, prog = _real_program(lane, mode, n_items, bucket, monkeypatch)
     try:
         args = (Xa, Ya, Ya, Xa, sb, uids) if lane == "two" \
             else (Xa, Ya, sb, uids)
-        compiled = prog.lower(*args).compile()
+        traced = prog.trace(*args)
+        compiled = traced.lower().compile()
     finally:
         srv.close()
     hlo = _assert_bitmap_read_in_place(compiled, n_users, words)
     if lane != "xla":
         assert "tpu_custom_call" in hlo      # the Mosaic kernel is in it
+        # the bounded merge (PR 29) holds the running list and one tile;
+        # the K-round selection it replaced held the list and two
+        # [K + TM] union buffers, and nothing may grow back past that
+        k = 128 if lane == "two" else 16
+        (rows, lanes), = _kernel_scratch_rows(traced.jaxpr.jaxpr)
+        assert lanes == max(bucket, 8)
+        assert rows <= 2 * k + 2 * (k + als_pallas.TOPK_TILE_M)
 
 
 def test_v5e_sharded_user_program_does_not_copy_the_bitmap(

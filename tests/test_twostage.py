@@ -121,6 +121,114 @@ def _assert_exact(store, E, U, seen, k=7):
 
 
 # ---------------------------------------------------------------------------
+# N < catalog on the fused kernel: the stage-1 cut is the bounded merge's
+# (ISSUE 29), so where the 128th candidate ties, who rises with the item
+# id and who falls is decided there
+# ---------------------------------------------------------------------------
+
+CUT_M, CUT_N = 700, 128          # six kernel tiles, the last ragged
+
+
+def _cut_problem(order, seed):
+    """Stage-1 tables whose scores are exact integers laid out against
+    the item id (``tests/test_fused_serving.py::_merge_problem``'s
+    orders: rising, falling, blocks of 37 duplicated rows so that the
+    cut at 128 falls inside a block, random), under random stage-2
+    tables; user 5 has seen every item, the even users a few."""
+    rng = np.random.default_rng(seed)
+    n, i = 12, np.arange(CUT_M)
+    if order == "random":
+        Y = rng.integers(-3, 4, (CUT_M, 2))
+        X = rng.integers(-3, 4, (n, 2))
+    else:
+        Y = np.stack([i // 16, i % 16], axis=1)
+        c = rng.integers(1, 4, (n, 1))
+        X = c * np.asarray([[16, 1]])
+        if order == "falling":
+            X = -X
+        elif order == "blocks":
+            Y = np.stack([i // 37, np.zeros_like(i)], axis=1)
+            X = -c * np.asarray([[1, 0]])
+    U = rng.integers(-3, 4, size=(n, 5)).astype(np.float32)
+    E = rng.integers(-3, 4, size=(CUT_M, 5)).astype(np.float32)
+    seen = {u: np.unique(rng.choice(CUT_M, size=40, replace=False))
+            for u in range(0, n, 2)}
+    seen[5] = np.arange(CUT_M)
+    return X.astype(np.float32), Y.astype(np.float32), U, E, seen
+
+
+def _cut_oracle(X, Y, U, E, seen, k):
+    """Numpy: the 128 best stage-1 scores of a user (lowest item id
+    among equals), re-ranked by stage 2 with the seen items out."""
+    from jax import lax
+    import jax.numpy as jnp
+
+    s1 = X @ Y.T
+    cand = np.argsort(-s1, axis=1, kind="stable")[:, :CUT_N]
+    s2 = np.full(s1.shape, -np.inf, dtype=np.float32)
+    rows = np.arange(len(X))[:, None]
+    s2[rows, cand] = (U @ E.T)[rows, cand]
+    for u, items in seen.items():
+        s2[u, items] = -np.inf
+    vals, idx = lax.top_k(jnp.asarray(s2), k)
+    return np.asarray(idx), np.asarray(vals), s1
+
+
+class TestFusedStage1Merge:
+    @pytest.mark.parametrize("precision", ["fp32", "bf16", "int8"])
+    @pytest.mark.parametrize("order", ["random", "rising", "falling",
+                                       "blocks"])
+    def test_cut_matches_oracle_and_counts_rounds(self, order, precision,
+                                                  monkeypatch):
+        from predictionio_tpu.ops.als_pallas import TOPK_TILE_M
+        from predictionio_tpu.utils import device_telemetry
+        from test_fused_serving import _rule_rounds
+
+        monkeypatch.setenv("PIO_SERVE_KERNEL", "fused")
+        if precision == "bf16":
+            monkeypatch.setenv("PIO_SERVE_PRECISION", "bf16")
+        X, Y, U, E, seen = _cut_problem(order, seed=len(order))
+        tables = (X, Y, U, E)
+        if precision == "int8":
+            tables = tuple(_quant(t) for t in tables)
+        store = TwoStageTopK(*tables, seen=seen, candidates=CUT_N,
+                             microbatch=False, n_users=X.shape[0],
+                             n_items=CUT_M)
+        rec = device_telemetry.recorder()
+        was = device_telemetry.enabled()
+        device_telemetry.set_enabled(True)
+        try:
+            assert store._kernel == "fused"
+            want_idx, want_vals, s1 = _cut_oracle(X, Y, U, E, seen, 7)
+            rec.reset()
+            got_idx, got_vals = store.twos_topk(np.arange(12), 7)
+            np.testing.assert_array_equal(got_vals, want_vals)
+            fin = np.isfinite(want_vals)
+            np.testing.assert_array_equal(got_idx[fin], want_idx[fin])
+            assert not fin[5].any()            # saw it all: nothing left
+            # the one dispatch's selectRounds: the 12 users in a bucket
+            # of 16 (padded with user 0's row), top-128 over two tiles
+            record, = rec.snapshot(10)
+            assert record["lane"] == "two" and record["bucket"] == 16
+            block = np.concatenate([s1, s1[:1].repeat(4, axis=0)]).T
+            assert record["selectRounds"] == _rule_rounds(
+                block.astype(np.float32), CUT_N, TOPK_TILE_M)
+            if order == "rising":              # the worst case, bounded
+                assert record["selectRounds"] == sum(
+                    min(CUT_N, TOPK_TILE_M, CUT_M - t)
+                    for t in range(0, CUT_M, TOPK_TILE_M))
+            elif order == "falling":           # the first tile fills it
+                assert record["selectRounds"] == CUT_N
+            idx1, vals1 = store.two_topk(3, 7)
+            np.testing.assert_array_equal(idx1, want_idx[3][fin[3]])
+            np.testing.assert_array_equal(vals1, want_vals[3][fin[3]])
+        finally:
+            device_telemetry.set_enabled(was)
+            rec.reset()
+            store.close()
+
+
+# ---------------------------------------------------------------------------
 # N = catalog exactness, every precision lane
 # ---------------------------------------------------------------------------
 
