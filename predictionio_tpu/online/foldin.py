@@ -412,7 +412,11 @@ class FoldInConsumer:
                         # live traffic it would hand back a concurrent
                         # QUERY dispatch's record and publish a wrong
                         # lane/deviceUs as the fold solve's
-                        rows = self._fold_hook(cols_list, vals_list)
+                        if getattr(model, "foldin_wants_ids", False):
+                            rows = self._fold_hook(cols_list, vals_list,
+                                                   ids=kept_ids)
+                        else:
+                            rows = self._fold_hook(cols_list, vals_list)
                         rec = None
                     else:
                         rows = fold_in_users(server.item_factors,

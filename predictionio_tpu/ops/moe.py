@@ -326,6 +326,63 @@ def moe_ffn_dense(h, w_router, w_gate, w_up, w_down, *, k: int):
     return jnp.sum(out * w_full[..., None], axis=1)
 
 
+# -- sigmoid router, a held share of the experts (GLM-5 family) ---------------
+
+def route_sigmoid(h, w_router, bias, k: int, scale: float):
+    """GLM-5 / DeepSeek-V3 ``noaux_tc`` routing of ``h: [T, D]`` over
+    ALL the published experts (``w_router: [D, E]``): scores
+    ``sigmoid(h @ W_r)`` in float32; the ``k`` experts with the
+    largest ``score + bias`` (``bias: [E]``, the correction that moves
+    the choice and never the weight); weights ``scale * s_i /
+    sum_chosen s`` (``norm_topk_prob`` with ``routed_scaling_factor``).
+    Returns (scores ``[T, E]``, experts ``[T, k]`` int32, weights
+    ``[T, k]`` float32)."""
+    logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return scores, experts.astype(jnp.int32), weights
+
+
+def moe_ffn_share(h, experts, weights, w_gate, w_up, w_down, *,
+                  first: int, compute_dtype=None):
+    """The routed part of an expert layer that HOLDS experts ``first
+    .. first + E_held`` of those the router chose among (``w_gate`` /
+    ``w_up``: ``[E_held, D, F]``, ``w_down: [E_held, F, D]``, already
+    in the compute dtype): the sum over a token's picks that live here
+    of ``weight * expert(h)``; a pick of an expert held elsewhere adds
+    nothing (its chip adds it in the deployment; summed over all
+    shares the parts are the uncut layer). The dispatch plan, grouped
+    matmuls and combine are :func:`moe_ffn`'s: absent picks sort into
+    a last, weightless run that no group covers and whose rows are
+    zeroed. Returns ``(y [T, D] float32, local [T, k] bool,
+    group_sizes [E_held])``."""
+    T, k = experts.shape
+    held = w_gate.shape[0]
+    cd = compute_dtype or h.dtype
+    local = (experts >= first) & (experts < first + held)
+    with jax.named_scope("moe/dispatch"):
+        plan = dispatch_plan(jnp.where(local, experts - first, held),
+                             held + 1)
+        xs = _dispatch(h.astype(cd), plan["token"], plan["inv"])
+    gs = plan["group_sizes"][:held]
+    covered = (jnp.arange(T * k) < jnp.sum(gs))[:, None]
+    gmm = functools.partial(grouped_matmul, group_sizes=gs)
+    with jax.named_scope("moe/gmm_gate_up"):
+        gate = jnp.where(covered, gmm(xs, w_gate, rhs_low=w_gate), 0)
+        up = jnp.where(covered, gmm(xs, w_up, rhs_low=w_up), 0)
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(cd)
+    with jax.named_scope("moe/gmm_down"):
+        ys = jnp.where(covered, gmm(act, w_down, rhs_low=w_down), 0)
+    with jax.named_scope("moe/combine"):
+        y = _combine(ys, jnp.where(local, weights, 0.0), plan["order"],
+                     plan["inv"])
+    return y, local, gs
+
+
 __all__ = [
     "GMM_TILING",
     "aux_losses",
@@ -333,7 +390,9 @@ __all__ = [
     "grouped_matmul",
     "moe_ffn",
     "moe_ffn_dense",
+    "moe_ffn_share",
     "resolve_gmm_impl",
     "route",
+    "route_sigmoid",
     "use_low",
 ]

@@ -109,6 +109,32 @@ class SeqRecParams(Params):
     compute_dtype: str = "float32"
     micro_rows: int = 0
     encode_rows: int = 8
+    # the glm_moe_dsa block (ops/mla.py), under config.json's names
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    n_dense_layers: int = 0
+    dense_width: int = 0
+    n_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0
+    expert_share: int = 0
+    # the session lane that serves that block (ops/sessions.py): the
+    # cache pool's rows (0: twice the stored histories) and how many
+    # dispatches' audits it keeps for a check to read (0: the
+    # programs compute none)
+    session_pool_tokens: int = 0
+    session_audit: int = 0
+    # serve the block's SEEDED INITIAL weights, drawn on the device at
+    # deploy from ``seed``, instead of trained ones: what a benchmark
+    # or a smoke test of a backbone too large to train here deploys.
+    # ``num_steps`` 0 is refused without it.
+    seeded_weights: bool = False
 
 
 # the block of OLMoE-1B-7B-0125-Instruct as its config.json publishes
@@ -119,6 +145,20 @@ OLMOE_1B_7B = dict(
     norm_eps=1e-5, positions="rope", rope_theta=10000.0, tied=False,
     vocab_rows=50304, n_experts=64, expert_width=1024,
     experts_per_token=8)
+
+# the block of GLM-5 (https://huggingface.co/zai-org/GLM-5, model_type
+# glm_moe_dsa) as its config.json publishes it; ``n_layers``,
+# ``n_dense_layers`` (first_k_dense_replace: 3 of the 78), the experts
+# a chip holds (``experts_held`` of the 256, share ``expert_share``)
+# and the rows of the tables are the deployment's
+GLM_5 = dict(
+    block="glm_moe_dsa", rank=6144, n_heads=64, norm="rmsnorm",
+    norm_eps=1e-5, positions="rope", rope_theta=1000000.0, tied=False,
+    q_lora_rank=2048, kv_lora_rank=512, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=256, index_n_heads=32,
+    index_head_dim=128, index_topk=2048, dense_width=12288,
+    n_experts=256, expert_width=2048, experts_per_token=8,
+    n_shared_experts=1, routed_scaling_factor=2.5)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,6 +181,7 @@ class BlockSpec:
     lb_coef: float
     z_coef: float
     compute_dtype: str
+    glm: Any = None   # ops/mla.py::GlmSpec of the glm_moe_dsa block
 
     @property
     def sparse(self) -> bool:
@@ -177,6 +218,11 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         raise ValueError(
             "the olmoe block takes norm rmsnorm, positions rope and "
             "untied tables (tied false), as OLMoE publishes it")
+    glm = None
+    if params.block == "glm_moe_dsa":
+        from predictionio_tpu.ops import mla
+
+        glm = mla.glm_spec(params)
     return BlockSpec(
         params.block, int(params.n_layers), H, head_dim, params.norm,
         float(params.norm_eps), params.positions,
@@ -184,7 +230,7 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         int(params.n_experts) if sparse else 0,
         int(params.experts_per_token) if sparse else 0,
         float(params.lb_coef), float(params.z_coef),
-        params.compute_dtype)
+        params.compute_dtype, glm)
 
 
 @dataclasses.dataclass
@@ -379,6 +425,10 @@ def _theta_shapes(n_items: int, params: SeqRecParams
     bias starts from."""
     spec = block_spec(params)
     D, V = int(params.rank), table_rows(n_items, params)
+    if spec.glm is not None:
+        from predictionio_tpu.ops import mla
+
+        return mla.theta_shapes(V, spec.glm)
     A = spec.n_heads * spec.head_dim
     out: List[Tuple[str, Tuple[int, ...], Any]] = [
         ("item_emb", (V, D), ("div", math.sqrt(D)))]
@@ -609,8 +659,18 @@ def _olmoe_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
     return x + y.reshape(B, L, D), stats
 
 
+def _glm_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
+               attention_fn, low):
+    """GLM-5's layer (``ops/mla.py``): latent attention under the
+    indexer's cut, then the dense or the expert feed-forward."""
+    from predictionio_tpu.ops import mla
+
+    return mla.glm_layer(theta, i, x, seg, pos, spec.glm), None
+
+
 # one function per layer kind; ``SeqRecParams.block`` names one
-BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer}
+BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer,
+          "glm_moe_dsa": _glm_layer}
 
 
 def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,

@@ -2380,7 +2380,7 @@ class DeviceTopK:
     # -- serving ----------------------------------------------------------
 
     def _dispatch_entry(self, entry: Tuple, fallback, args_fn, *,
-                        batch: int, bucket: int):
+                        batch: int, bucket: int, take=None):
         """One laddered device dispatch: AOT-executable lookup + the
         program call under ``_store_lock`` (the historical lock scope —
         the dispatch enqueues, it does not wait on the device), then,
@@ -2392,11 +2392,17 @@ class DeviceTopK:
         profiler annotations (``dispatch.lock`` / ``.enqueue`` /
         ``.wait``). Telemetry off (``PIO_DEVICE_TELEMETRY=0``) is the
         killed-lane fast path: exactly the pre-telemetry dispatch, no
-        clock reads. Returns the raw packed device output."""
+        clock reads. Returns the raw packed device output. ``take``
+        (a lane whose program also returns new store tables, as the
+        session lane's does its caches) is called with the program's
+        outputs while the lock is still held, publishes the tables and
+        returns the packed output."""
         if not _dtel.enabled():
             with self._store_lock:
                 prog, aot = self._ladder_program_locked(entry, fallback)
                 out = prog(*args_fn())
+                if take is not None:
+                    out = take(out)
             _metrics.AOT_CACHE_REQUESTS.inc(result=aot)
             return out
         tl = time.monotonic()
@@ -2410,6 +2416,8 @@ class DeviceTopK:
             with _tracing.annotation("dispatch.enqueue"):
                 t0m = time.monotonic()
                 out = prog(*args)
+                if take is not None:
+                    out = take(out)
                 t1m = time.monotonic()
         finally:
             self._store_lock.release()

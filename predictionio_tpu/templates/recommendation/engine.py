@@ -475,6 +475,8 @@ class _DeviceServedModel:
         state.pop("_cat_index", None)
         state.pop("_cat_black_cache", None)
         state.pop("_theta_device", None)  # sequentialrec device cache
+        state.pop("_fold_histories", None)  # session fold-in bookkeeping
+        state.pop("_folded", None)
         return state
 
 

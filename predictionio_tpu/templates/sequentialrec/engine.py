@@ -79,8 +79,10 @@ from predictionio_tpu.templates.recommendation.engine import (
     PredictedResult,
     PrecisionAtK,
     Query,
+    _coerce_query,
     _DeviceServedModel,
     _DeviceServingAlgo,
+    _winners_to_result,
 )
 
 
@@ -246,6 +248,9 @@ class PreparedSequences:
     buckets: Any   # List[SequenceBucket] | PackedRows
     seen: Dict[int, np.ndarray]   # user idx -> unique item idx array
     max_seq_len: int
+    # user idx -> time-ordered item idx (the last max_seq_len): what a
+    # session-served model keeps to build its per-user caches from
+    sequences: Optional[List[np.ndarray]] = None
 
     def sanity_check(self) -> None:
         assert len(self.user_map) > 0, "no users after indexing"
@@ -280,7 +285,8 @@ class SequencePreparator(PPreparator):
         seen = {u: np.unique(seqs[u]) for u in range(n_u) if len(seqs[u])}
         return PreparedSequences(user_map, item_map,
                                  self.layout(seqs), seen,
-                                 int(p.max_seq_len))
+                                 int(p.max_seq_len),
+                                 [q[-int(p.max_seq_len):] for q in seqs])
 
     def layout(self, seqs):
         """Per-user index sequences -> the trainer's layout."""
@@ -303,16 +309,27 @@ class SeqRecModel(_DeviceServedModel):
     (``choose_server`` -> ``DeviceTopK`` on device backends) exactly
     like an ALS model — plus the encoder parameters, so fold-in can
     RE-ENCODE a user's sequence instead of re-solving a linear
-    system."""
+    system.
 
-    user_vectors: np.ndarray      # [N, R]
-    item_vectors: np.ndarray      # [M, R]: output_table(theta)[:M]
+    A ``glm_moe_dsa`` block is SESSION-served instead
+    (``ops/sessions.py::SessionTopK``): a backbone that wide has no
+    user-vector table worth holding, so each user's history lives on
+    the device as a cache (built at deploy from ``histories``) and a
+    query ``{"user": u, "items": [new events], "num": n}`` extends it.
+    With ``seededWeights`` (and ``numSteps: 0``) such a model carries
+    an EMPTY ``theta`` and no vectors: the backbone's seeded initial
+    weights are drawn on the device at deploy from
+    ``enc_params.seed``."""
+
+    user_vectors: Optional[np.ndarray]      # [N, R]
+    item_vectors: Optional[np.ndarray]      # [M, R]: output_table(theta)[:M]
     user_map: StringIndexBiMap
     item_map: StringIndexBiMap
     seen: Dict[int, np.ndarray]
     theta: Dict[str, np.ndarray]
     enc_params: SeqRecParams
     max_seq_len: int
+    histories: Optional[Dict[int, np.ndarray]] = None
     _server: Any = dataclasses.field(default=None, repr=False,
                                      compare=False)
 
@@ -326,11 +343,38 @@ class SeqRecModel(_DeviceServedModel):
     # implicit-ALS positivity filter would truncate their results)
     serve_positive_scores_only = False
 
+    @property
+    def session_served(self) -> bool:
+        return self.enc_params.block == "glm_moe_dsa"
+
+    # the fold-in consumer hands such a model the touched users' ids,
+    # so that a fold APPENDS to their sessions
+    foldin_wants_ids = property(lambda self: self.session_served)
+
     def _make_server(self):
         from predictionio_tpu.ops.serving import choose_server
 
-        return choose_server(self.user_vectors, self.item_vectors,
-                             self.seen)
+        if not self.session_served:
+            return choose_server(self.user_vectors, self.item_vectors,
+                                 self.seen)
+        from predictionio_tpu.ops import mla
+        from predictionio_tpu.ops.seqrec import table_rows
+        from predictionio_tpu.ops.sessions import SessionTopK
+
+        p = self.enc_params
+        n_items = len(self.item_map)
+        if self.theta:
+            theta = mla.serving_theta(self.theta, mla.glm_spec(p))
+        elif p.seeded_weights:
+            theta = mla.draw_serving_theta(table_rows(n_items, p), p)
+        else:
+            raise ValueError("a glm_moe_dsa model without weights is "
+                             "served only with seededWeights")
+        return SessionTopK(theta["out_emb"][:n_items], theta, p,
+                           n_users=len(self.user_map),
+                           histories=self.histories, seen=self.seen,
+                           pool_tokens=int(p.session_pool_tokens),
+                           audit=int(p.session_audit))
 
     def _device_theta(self):
         """Encoder params as DEVICE arrays, cached: the host-numpy
@@ -345,15 +389,19 @@ class SeqRecModel(_DeviceServedModel):
             self._theta_device = th
         return th
 
-    def fold_in_rows(self, cols_list, vals_list) -> np.ndarray:
+    def fold_in_rows(self, cols_list, vals_list, ids=None) -> np.ndarray:
         """Re-encode ``k`` users' full time-ordered item sequences into
         fresh ``[k, R]`` user vectors — the fold-in consumer's solve
         hook (the sequence-model analog of ``ops.als.fold_in_users``).
         The batch pads to power-of-two (rows, length) classes so a
         long-lived server's folds reuse a handful of compiled encode
-        programs."""
+        programs. A session-served model APPENDS instead (``ids``: the
+        users' entity ids): the events past what a user's session
+        already caches go through the session lane's program."""
         from predictionio_tpu.ops.serving import bucket_size
 
+        if self.session_served:
+            return self._fold_in_sessions(cols_list, ids)
         k = len(cols_list)
         if k == 0:
             return np.zeros((0, self.item_vectors.shape[1]),
@@ -378,11 +426,46 @@ class SeqRecModel(_DeviceServedModel):
         return encode_bucket(self._device_theta(), bucket,
                              self.enc_params)[:k]
 
+    def _fold_in_sessions(self, cols_list, ids) -> np.ndarray:
+        """Append each touched user's new events to their session and
+        return the sessions' last hidden states ``[k, R]`` (what
+        ``patch_users`` then writes is what the lane already holds).
+        An application sends a user's new events EITHER with its
+        queries or to the event store for this fold to find: one that
+        does both appends them twice. A user the model does not know
+        yet has no row to hold a session under: their history is
+        encoded into blocks that are given back at once
+        (``SessionTopK.encode``) and waits in ``_fold_histories``
+        until their first query opens their session."""
+        srv = self.device_server()
+        if ids is None:
+            raise ValueError("a session-served model folds by user id")
+        pending = self.__dict__.setdefault("_fold_histories", {})
+        folded = self.__dict__.setdefault("_folded", {})
+        rows = []
+        for uid, cols in zip(ids, cols_list):
+            cols = np.asarray(cols, dtype=np.int32)
+            uidx = self.user_map.get(uid)
+            if uidx is None:
+                pending[uid] = cols
+                rows.append(srv.encode(cols))
+                continue
+            # the STORE's events past those already folded (the
+            # model's history at train time, then every fold's);
+            # events a query brought itself are not in the store and
+            # are not counted
+            have = folded.get(uidx, len((self.histories or {}).get(
+                uidx, ())))
+            srv.sess_topk(uidx, cols[have:], 1)
+            folded[uidx] = max(have, len(cols))
+            rows.append(srv.last_hidden(uidx))
+        return np.stack(rows) if rows else np.zeros(
+            (0, int(self.enc_params.rank)), np.float32)
+
     def sanity_check(self) -> None:
-        assert np.isfinite(self.user_vectors).all(), \
-            "non-finite user vectors"
-        assert np.isfinite(self.item_vectors).all(), \
-            "non-finite item vectors"
+        for name in ("user_vectors", "item_vectors"):
+            a = getattr(self, name)
+            assert a is None or np.isfinite(a).all(), f"non-finite {name}"
 
 
 class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
@@ -404,6 +487,21 @@ class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
         # root): stage / steps / encode_users / fetch. The parameters
         # stay on the device from the first step to the last encode
         # call; one transfer at the end brings model and vectors down.
+        histories = None
+        if p.block == "glm_moe_dsa":
+            histories = {u: np.asarray(q, dtype=np.int32)
+                         for u, q in enumerate(pd.sequences) if len(q)}
+            if int(p.num_steps) == 0:
+                if not p.seeded_weights:
+                    raise ValueError(
+                        "numSteps 0 trains nothing: a glm_moe_dsa model "
+                        "is persisted without weights only with "
+                        "seededWeights (its seeded initial weights are "
+                        "then drawn at deploy)")
+                self.last_losses = []
+                return SeqRecModel(None, None, pd.user_map, pd.item_map,
+                                   pd.seen, {}, p, pd.max_seq_len,
+                                   histories)
         with tracing.trace_scope("seq.train", slow_exempt=True):
             theta, losses = train_seqrec(pd.buckets, len(pd.item_map), p,
                                          to_host=False)
@@ -427,7 +525,31 @@ class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
         self.last_losses = losses
         return SeqRecModel(U, output_table(theta)[:len(pd.item_map)],
                            pd.user_map, pd.item_map, pd.seen, theta, p,
-                           pd.max_seq_len)
+                           pd.max_seq_len, histories)
+
+    def predict(self, model: SeqRecModel, query) -> PredictedResult:
+        """A session-served model takes the upstream query form with
+        BOTH fields, ``{"user": u, "items": [new events, oldest
+        first], "num": n}``: append the events to ``u``'s session and
+        recommend. Every other model, and an item-only query, is
+        served as the recommendation template serves it."""
+        query = _coerce_query(query)
+        if not model.session_served or query.user is None:
+            return super().predict(model, query)
+        item_map = model.item_map
+        uidx = model.user_map.get(query.user)
+        if uidx is None:
+            return PredictedResult(())
+        srv = model.device_server()
+        waiting = model.__dict__.get("_fold_histories", {}).pop(
+            query.user, None)
+        if waiting is not None:
+            srv.open_session(uidx, waiting)
+        events = [item_map[i] for i in query.items if i in item_map]
+        black = {item_map[i] for i in query.blacklist if i in item_map}
+        idx, scores = srv.sess_topk(uidx, events, query.num + len(black))
+        return _winners_to_result(idx, scores, black, query.num, item_map,
+                                  positive_only=False)
 
     def batch_predict(self, ctx: ComputeContext, model: SeqRecModel,
                       indexed_queries) -> List[Tuple[int, Any]]:

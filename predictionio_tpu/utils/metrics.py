@@ -951,6 +951,47 @@ SEQ_DROPPED_TOKENS = REGISTRY.counter(
     "(token, expert) pairs the dropless dispatch did not compute "
     "(always 0; the trainer asserts it)", ())
 
+# -- the session lane (ops/sessions.py) --------------------------------------
+SESS_TOKENS = REGISTRY.counter(
+    "pio_sess_tokens_total",
+    "Events run through the backbone and written to the per-user "
+    "caches, by program (extend: a query's new events; prefill: a "
+    "session built from a stored history)", ("program",))
+SESS_CACHE_TOKENS = REGISTRY.gauge(
+    "pio_sess_cache_tokens",
+    "Cache rows (tokens, whole blocks) the live sessions hold", ())
+SESS_CACHE_CAPACITY = REGISTRY.gauge(
+    "pio_sess_cache_tokens_capacity",
+    "Cache rows the block pool holds", ())
+SESS_SELECTED_SHARE = REGISTRY.gauge(
+    "pio_sess_selected_share",
+    "Keys the indexer selected over the keys eligible (cached "
+    "positions up to the query's own), over the last dispatch's new "
+    "events and every layer", ())
+SESS_SELECTED = REGISTRY.counter(
+    "pio_sess_keys_total",
+    "Cached positions the indexer scored (eligible) and kept "
+    "(selected), summed over new events and layers", ("kind",))
+SESS_LOCAL_PICKS = REGISTRY.counter(
+    "pio_sess_expert_picks_local_total",
+    "(event, expert) picks of the router that fell on an expert this "
+    "chip holds, summed over the expert layers", ())
+SESS_POSITIONS = REGISTRY.counter(
+    "pio_sess_positions_total",
+    "Cached positions the lane's queries could see (a query's cached "
+    "length with its own new events), summed over queries: what the "
+    "indexer has to score a layer", ())
+SESS_EXPERTS_TOUCHED = REGISTRY.counter(
+    "pio_sess_experts_touched_total",
+    "Held experts that a valid new token picked in a dispatch (padded "
+    "token rows route too and are not counted), summed over expert "
+    "layers and dispatches: whose weights the mathematics has to read",
+    ())
+SESS_EVICTIONS = REGISTRY.counter(
+    "pio_sess_evictions_total",
+    "Sessions whose cache blocks were released to make room (their "
+    "events stay on the host; the next touch prefills them again)", ())
+
 
 
 class BoundedLabel:
