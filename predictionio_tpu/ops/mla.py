@@ -18,10 +18,14 @@ Latent attention comes in two forms that give the same numbers:
 - ABSORBED (:func:`extend_step`, :func:`prefill_chunk`, the served
   programs): ``W_kvb``'s key half is multiplied into the query and its
   value half into the output, and attention runs over the latents as
-  they lie in the cache. :func:`extend_step` GATHERS the selected
-  latents (a few new tokens a row); :func:`prefill_chunk` masks a dense
-  product a block of queries at a time (2,048 tokens a chunk, where a
-  gather of 2,048 latents a token would not fit).
+  they lie in the cache. :func:`extend_step` attends through
+  :func:`mla_sparse_attend`, the lane's only attend: a loop over the
+  VALID new tokens that gathers one token's selected latents at a time
+  (operands: the absorbed queries, the layer's pool, the selected pool
+  rows with their mask, the new events a query brought);
+  :func:`prefill_chunk` masks a dense product a block of queries at a
+  time (2,048 tokens a chunk, where a gather of 2,048 latents a token
+  would not fit).
 
 The float32 reference of the same equations is
 ``ops/glm_reference.py``.
@@ -508,6 +512,55 @@ def absorbed_output(theta, i: int, ol, spec: GlmSpec):
     return _mm(o.reshape(o.shape[0], -1), theta[f"l{i}_wo"], spec)
 
 
+def mla_sparse_attend(qf, pool, phys, ok, n_new, spec: GlmSpec):
+    """Attention of ``B x T`` token rows over the latents each selected,
+    read where they lie in the pool: ``qf [B, T, H, lat_width]`` (the
+    absorbed queries), ``pool [rows, lat_width]`` (the layer's latent
+    rows AFTER this dispatch's were written), ``phys [B, T, K]`` int32
+    (the pool rows a token attends over) and ``ok [B, T, K]`` (which of
+    them count), ``n_new [B]``. Returns ``[B, T, H, lat_width]``
+    float32, ``softmax(scale qf . rows) rows`` in ``extend_step``'s
+    precision, and ZEROS for every token row ``t >= n_new[b]``.
+
+    One loop over the VALID token rows alone (their count is the trip
+    count, so a padded row costs nothing): a step gathers its row's
+    ``K`` latents (``[K, lat_width]``: 2.6 MB as published, where all
+    rows at once were ``[B, T, K, lat_width]``, 168 MB at B 8, written
+    and read twice), and its two products are plain matrix products
+    (``[H, width] x [width, K]``), which the batched form over ``(b,
+    t)`` was not.
+
+    It is XLA's own gather and not a Pallas kernel because the chip's
+    compiler refuses a copy out of a tiled HBM array that is not whole
+    tiles of 8 rows: a kernel fetching each selected row's group of 8
+    moved 10 KB a row and took 69-75 ns a valid row, one streaming a
+    session's whole blocks under a mask costs the session's length and
+    not ``K``, and this loop takes 17-36 (PERF.md section 6, PR 31)."""
+    import jax
+    import jax.numpy as jnp
+
+    B, T, H, W = qf.shape
+    qf, phys, ok = (a.reshape((B * T,) + a.shape[2:])
+                    for a in (qf, phys, ok))
+    valid = (jnp.arange(T)[None, :] < n_new[:, None]).reshape(-1)
+    order = jnp.argsort(~valid, stable=True)        # the valid rows first
+
+    def one(i, out):
+        r = order[i]
+        row = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
+            a, r, keepdims=False)
+        g = jnp.take(pool, row(phys), axis=0, mode="clip")      # [K, W]
+        s = _ein("hc,kc->hk", row(qf), g, spec) * spec.scale
+        a = jax.nn.softmax(jnp.where(row(ok)[None, :], s, -jnp.inf),
+                           axis=-1)
+        return jax.lax.dynamic_update_index_in_dim(
+            out, _ein("hk,kc->hc", a, g, spec), r, 0)
+
+    out = jax.lax.fori_loop(0, jnp.sum(valid), one,
+                            jnp.zeros((B * T, H, W), jnp.float32))
+    return out.reshape(B, T, H, W)
+
+
 def _new_bits(tok, valid, words: int):
     """The seen-bitmap words of a row's new events: ``tok: [T]`` item
     positions -> ``[words]`` int32 with their bits set."""
@@ -543,7 +596,9 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
     table]``; ``lat`` / ``ik``: per layer the
     latent and index-key pools ``[blocks, bs, width]``; ``X``: the
     users' last hidden states (the store's user table); ``Y``: the
-    output table. Returns the packed top-k, the new ``X``,
+    output table. Every layer attends over the selected latents
+    through :func:`mla_sparse_attend` alone (a padded token row is
+    never gathered or scored). Returns the packed top-k, the new ``X``,
     ``seen_bits``, ``lat``, ``ik`` and, compiled with ``audit``, what a
     check compares (else None): every item's ``scores`` ``[B, items]``
     and, for each row's last new event, ``layers`` ``[n_layers, B,
@@ -609,13 +664,9 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
                     B, T, K)
             phys = blk * bs + idx % bs
         with jax.named_scope("sess/attend"):
-            g = jnp.take(lat_i, phys, axis=0, mode="clip")
             qf = absorbed_query(theta, i, p, spec).reshape(
                 B, T, spec.n_heads, spec.lat_width)
-            s = _ein("bthc,btkc->bthk", qf, g, spec) * spec.scale
-            a = jax.nn.softmax(jnp.where(ok[:, :, None, :], s, -jnp.inf),
-                               axis=-1)
-            ol = _ein("bthk,btkc->bthc", a, g, spec)
+            ol = mla_sparse_attend(qf, lat_i, phys, ok, n_new, spec)
             x = x + absorbed_output(
                 theta, i, ol.reshape(B * T, spec.n_heads, -1),
                 spec).reshape(B, T, D)

@@ -192,6 +192,7 @@ class SessionTopK(DeviceTopK):
         self._audits: collections.deque = collections.deque(
             maxlen=max(1, self._audit_keep))
         self._watched: Optional[set] = None
+        self._token_rows = {"valid": 0, "padded": 0}
         self._sess_programs: Dict[Tuple, Any] = {}
         self._sess_batcher: Optional[BatchLane] = None
         if self._dispatcher is not None:
@@ -556,6 +557,10 @@ class SessionTopK(DeviceTopK):
                     in zip(sessions, rows)]))
             if tokens:
                 _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
+            for kind, rows_ in (("valid", tokens),
+                                ("padded", bb * T - tokens)):
+                _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
+                self._token_rows[kind] += rows_
             _metrics.SESS_POSITIONS.inc(
                 amount=sum(s_.length for s_ in sessions))
         idx, scores = _unpack(host[:, :2 * kb], kb)
@@ -670,7 +675,13 @@ class SessionTopK(DeviceTopK):
     def session_report(self) -> Dict[str, Any]:
         with self._sess_lock:
             held = self._n_blocks - 1 - len(self._free)
+            rows = dict(self._token_rows)
             return {"sessions": len(self._sessions),
+                    "tokenRows": rows,
+                    # of the token rows dispatched, the share the
+                    # attend loop never runs
+                    "skippedRowShare": rows["padded"]
+                    / max(sum(rows.values()), 1),
                     "blockTokens": self._bs,
                     "cacheTokens": held * self._bs,
                     "capacityTokens": (self._n_blocks - 1) * self._bs,

@@ -957,6 +957,12 @@ SESS_TOKENS = REGISTRY.counter(
     "Events run through the backbone and written to the per-user "
     "caches, by program (extend: a query's new events; prefill: a "
     "session built from a stored history)", ("program",))
+SESS_TOKEN_ROWS = REGISTRY.counter(
+    "pio_sess_token_rows_total",
+    "Token rows the extend programs were dispatched with (query bucket "
+    "x events a query may bring), by kind: valid rows carry a new "
+    "event; padded rows are the ones the attend loop never runs",
+    ("kind",))
 SESS_CACHE_TOKENS = REGISTRY.gauge(
     "pio_sess_cache_tokens",
     "Cache rows (tokens, whole blocks) the live sessions hold", ())

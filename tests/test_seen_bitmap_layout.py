@@ -490,3 +490,82 @@ def test_v5e_fold_in_against_a_store_on_four_chips_keeps_lanes(
     with pytest.raises(Exception, match="shard_map"):
         als._get_fold_in_jit().lower(Y, *host, solver="pallas",
                                      **kw).compile()
+
+
+# ---------------------------------------------------------------------------
+# the session lane's extend program on the described chip (PR 31; kept
+# in this file because the topology may be described in one file only)
+# ---------------------------------------------------------------------------
+
+# temporaries of the extend program at B 8 BEFORE PR 31 (the [B, T, K,
+# 640] gathered latents and their products; deviceless compiles of
+# commit 3c8c469), by cached-length bucket
+EXTEND_TEMP_BEFORE = {16384: 202_678_784, 65536: 215_253_504}
+
+
+@pytest.mark.parametrize("S", sorted(EXTEND_TEMP_BEFORE))
+def test_v5e_extend_program_gathers_a_token_row_at_a_time(S, one_chip,
+                                                          monkeypatch):
+    """``extend_step`` at GLM-5's published widths (the cell
+    ``seqrec-glm5.sess-extend``: 8 queries x 8 events, a pool of
+    458,752 rows): the latents of all 64 token rows are never gathered
+    at once (168 MB a layer), a layer's attend is a loop whose step
+    holds one row's ``[K, 640]``, no pool is copied (587 MB a layer),
+    the pools stay donated, and the temporaries are under those of the
+    gathered form."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import mla
+    from predictionio_tpu.ops.seqrec import SeqRecParams
+    from predictionio_tpu.ops.sessions import (
+        SESS_BLOCK,
+        SESS_EVENTS,
+        SESS_MAX_BATCH,
+    )
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = SeqRecParams(
+        block="glm_moe_dsa", rank=6144, n_heads=64, n_layers=6,
+        n_dense_layers=1, norm="rmsnorm", norm_eps=1e-5, positions="rope",
+        rope_theta=1e6, tied=False, q_lora_rank=2048, kv_lora_rank=512,
+        qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256,
+        index_n_heads=32, index_head_dim=128, index_topk=2048,
+        dense_width=12288, n_experts=256, expert_width=2048,
+        experts_per_token=8, n_shared_experts=1,
+        routed_scaling_factor=2.5, experts_held=16, expert_share=0,
+        compute_dtype="bfloat16", num_steps=0, seeded_weights=True)
+    spec = mla.glm_spec(params)
+    V, bf16 = 19_360, jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    theta = {name: sds(shape, bf16 if mla.is_low(name) else jnp.float32)
+             for name, shape, _ in mla.theta_shapes(V, spec)}
+    Y = theta.pop("out_emb")
+    bs = SESS_BLOCK
+    nb = 1 + 458_752 // bs
+    lat = tuple(sds((nb, bs, spec.lat_width), bf16)
+                for _ in range(spec.n_layers))
+    ik = tuple(sds((nb, bs, spec.idx_dim), bf16)
+               for _ in range(spec.n_layers))
+    T, B, K, W = SESS_EVENTS, SESS_MAX_BATCH, spec.idx_topk, spec.lat_width
+    compiled = jax.jit(functools.partial(
+        mla.extend_step, spec=spec, kb=128, T=T, S=S, bs=bs, n_items=V,
+        mode="bf16", mask_seen=True), donate_argnums=(1, 2, 3, 4)).lower(
+        theta, sds((24, spec.width), bf16), sds((24, 640), jnp.int32),
+        lat, ik, Y, sds((B, 3 + 2 * T + S // bs), jnp.int32)).compile()
+    hlo = compiled.as_text()
+    assert f"bf16[{B},{T},{K},{W}]" not in hlo
+    assert f"bf16[{B * T},{K},{W}]" not in hlo
+    assert f"bf16[{K},{W}]" in hlo               # one token row's latents
+    pool_rows = rf"bf16\[(?:{nb},{bs}|{nb * bs}),{W}\]"
+    copies = re.findall(rf"= ({pool_rows}\S*) copy\(", hlo)
+    assert not copies, f"a latent pool is copied: {copies}"
+    mem = compiled.memory_analysis()
+    pool = 2 * nb * bs * (W + spec.idx_dim) * spec.n_layers
+    assert mem.alias_size_in_bytes >= pool      # the pools, in place
+    assert mem.temp_size_in_bytes < EXTEND_TEMP_BEFORE[S]

@@ -377,6 +377,32 @@ def test_ladder_is_complete_after_warm_up():
     srv.close()
 
 
+def test_token_rows_are_counted_valid_and_padded():
+    """``pio_sess_token_rows_total``: a dispatch is ``query bucket x
+    SESS_EVENTS`` token rows, those with a new event and the padded
+    ones the attend loop never runs; ``session_report()`` says what
+    share was skipped."""
+    from predictionio_tpu.utils import metrics
+
+    params, _, theta, _ = build()
+    srv = server(params, theta, {u: history(10, u) for u in range(5)},
+                 microbatch=False)
+    before = {k: metrics.SESS_TOKEN_ROWS.value(kind=k)
+              for k in ("valid", "padded")}
+    # buckets 1, 4 (two queries, one without events) and 8 (five)
+    srv.extend([(0, history(3, 7))], 8)
+    srv.extend([(1, history(SESS_EVENTS, 8)), (2, history(0))], 8)
+    srv.extend([(u, history(1 + u, 9)) for u in range(5)], 8)
+    valid, total = 3 + SESS_EVENTS + 15, (1 + 4 + 8) * SESS_EVENTS
+    got = {k: metrics.SESS_TOKEN_ROWS.value(kind=k) - before[k]
+           for k in before}
+    assert got == {"valid": valid, "padded": total - valid}
+    report = srv.session_report()
+    assert report["tokenRows"] == {"valid": valid, "padded": total - valid}
+    assert report["skippedRowShare"] == pytest.approx(1 - valid / total)
+    srv.close()
+
+
 def test_two_queries_of_one_user_in_one_group_are_ordered():
     """Both land in one group of the lane; the first answers for its
     own prefix, the second for both, as two groups would."""
