@@ -399,3 +399,142 @@ def segment_attention_flash(q, k, v, segment_ids,
     # a pad position sees only pads of "segment 0": zero it, as the
     # dense form does
     return out * (seg != 0)[:, None, :, None].astype(out.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention of a few query rows over a PAGED cache
+# ---------------------------------------------------------------------------
+
+PAGED_NEG = -1e30   # "no key yet": finite, so that an empty cache is no NaN
+
+
+def paged_gqa_attention_xla(q, pool_k, pool_v, table, length, *,
+                            scale: float, compute_dtype=None):
+    """The paged attention below, the plain way (any backend; the
+    kernel's oracle): the session's blocks are GATHERED out of the
+    pool (``[B, S, KV, d]`` copies) and scored under a length mask."""
+    import jax.numpy as jnp
+
+    B, KV, RG, d = q.shape
+    bs = pool_k.shape[1]
+    S = table.shape[1] * bs
+    cd = jnp.dtype(compute_dtype or q.dtype)
+    ks = jnp.take(pool_k, table, axis=0, mode="clip").reshape(B, S, KV, d)
+    vs = jnp.take(pool_v, table, axis=0, mode="clip").reshape(B, S, KV, d)
+    s = jnp.einsum("bkrd,bskd->bkrs", q.astype(cd), ks.astype(cd),
+                   preferred_element_type=jnp.float32) * scale
+    ok = (jnp.arange(S)[None, :] < length[:, None])[:, None, None, :]
+    s = jnp.where(ok, s, PAGED_NEG)
+    m = jnp.max(s, axis=-1)
+    p = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
+    acc = jnp.einsum("bkrs,bskd->bkrd", p.astype(cd), vs.astype(cd),
+                     preferred_element_type=jnp.float32)
+    return acc, m, jnp.sum(p, axis=-1)
+
+
+def _paged_gqa_kernel(table_ref, nblk_ref, len_ref, q_ref, k_ref, v_ref,
+                      acc_ref, m_ref, l_ref, acc_sc, m_sc, l_sc, *,
+                      bs: int, n_kv: int, d: int, scale: float):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    b, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, PAGED_NEG)
+        l_sc[...] = jnp.zeros_like(l_sc)
+
+    @pl.when(j < nblk_ref[b])
+    def _():
+        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        ok = col < len_ref[b]
+        for h in range(n_kv):
+            qh = q_ref[0, h]                                  # [RG, d]
+            kh = k_ref[0, :, h * d:(h + 1) * d]               # [bs, d]
+            vh = v_ref[0, :, h * d:(h + 1) * d]
+            s = jax.lax.dot_general(
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [RG, bs]
+            s = jnp.where(ok, s, PAGED_NEG)
+            m_prev = m_sc[h]                                  # [RG, 128]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(ok, jnp.exp(s - m_new[:, :1]), 0.0)
+            l_sc[h] = alpha * l_sc[h] + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[h] = acc_sc[h] * alpha[:, :1] + jnp.dot(
+                p.astype(vh.dtype), vh, preferred_element_type=jnp.float32)
+            m_sc[h] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        acc_ref[0] = acc_sc[...]
+        m_ref[0] = m_sc[...]
+        l_ref[0] = l_sc[...]
+
+
+def paged_gqa_attention(q, pool_k, pool_v, table, length, *, scale: float,
+                        interpret: bool = False):
+    """Attention of a FEW query rows over a long per-sequence cache
+    that lives in blocks of a shared pool, read WHERE IT LIES: ``q [B,
+    KV, RG, d]`` (for each of the ``KV`` key/value heads the ``RG``
+    query rows that read it: token rows x the query heads of its
+    group), ``pool_k`` / ``pool_v`` ``[blocks, bs, KV * d]``, ``table
+    [B, nb]`` int32 (a sequence's blocks in order), ``length [B]`` (its
+    cached rows: every one visible to every query row). Returns the
+    UNNORMALISED parts of an online softmax, float32: ``acc [B, KV,
+    RG, d]`` (``sum_s exp(score - m) v``), ``m [B, KV, RG]`` (the
+    running maximum, ``PAGED_NEG`` for an empty cache) and ``l``
+    (``sum_s exp(score - m)``), so that the caller can join them with
+    the keys that are not in the cache (the block being decoded).
+
+    A Pallas TPU kernel: grid ``(B, nb)``; the block table, the
+    sequences' block counts and lengths are prefetched scalars, so
+    block ``j`` of sequence ``b`` is DMA'd straight from pool block
+    ``table[b, j]`` (a step past the sequence's last block names that
+    block again: nothing is fetched, nothing computed). No ``[B, S,
+    ...]`` copy of the cache exists; bytes read are the cached rows'
+    own. ``d`` a multiple of 128 lanes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, KV, RG, d = q.shape
+    bs = pool_k.shape[1]
+    nb = table.shape[1]
+    length = length.astype(jnp.int32)
+    nblk = (length + bs - 1) // bs
+
+    def kv_map(b, j, table_ref, nblk_ref, len_ref):
+        last = jnp.maximum(nblk_ref[b] - 1, 0)
+        return (table_ref[b, jnp.minimum(j, last)], 0, 0)
+
+    def q_map(b, j, *_):
+        return (b, 0, 0, 0)
+
+    out_shape = (jax.ShapeDtypeStruct((B, KV, RG, d), jnp.float32),
+                 jax.ShapeDtypeStruct((B, KV, RG, 128), jnp.float32),
+                 jax.ShapeDtypeStruct((B, KV, RG, 128), jnp.float32))
+    acc, m, l = pl.pallas_call(
+        functools.partial(_paged_gqa_kernel, bs=bs, n_kv=KV, d=d,
+                          scale=float(scale)),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(B, nb),
+            in_specs=[pl.BlockSpec((1, KV, RG, d), q_map),
+                      pl.BlockSpec((1, bs, KV * d), kv_map),
+                      pl.BlockSpec((1, bs, KV * d), kv_map)],
+            out_specs=[pl.BlockSpec((1, KV, RG, d), q_map),
+                       pl.BlockSpec((1, KV, RG, 128), q_map),
+                       pl.BlockSpec((1, KV, RG, 128), q_map)],
+            scratch_shapes=[pltpu.VMEM((KV, RG, d), jnp.float32),
+                            pltpu.VMEM((KV, RG, 128), jnp.float32),
+                            pltpu.VMEM((KV, RG, 128), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="paged_gqa_attention",
+    )(table.astype(jnp.int32), nblk, length, q, pool_k, pool_v)
+    return acc, m[..., 0], l[..., 0]

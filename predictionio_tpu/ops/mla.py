@@ -188,23 +188,31 @@ def theta_shapes(V: int, spec: GlmSpec
 def draw_serving_theta(V: int, params, skip: Tuple[str, ...] = ()):
     """The seeded parameters ``init_theta_device`` draws (same keys,
     same order), drawn ON THE DEVICE straight into the dtype each is
-    served in, one jitted call a layer (the five expert layers share
-    one compiled program): 4.7 billion parameters never exist in
-    float32 all at once, on either side of the bus, and a deploy
-    compiles four programs for them, not one an operation."""
+    served in (:func:`draw_shapes`)."""
+    spec = glm_spec(params)
+    return draw_shapes(theta_shapes(V, spec), int(params.seed),
+                       spec.n_layers, spec.compute_dtype, is_low, skip)
+
+
+def draw_shapes(shapes, seed: int, n_layers: int, compute_dtype: str,
+                low, skip: Tuple[str, ...] = ()):
+    """``shapes`` (``theta_shapes``'s form) drawn on the device from
+    ``seed``, a parameter ``low(name)`` says so in the compute dtype,
+    one jitted call a layer (layers of one kind share one compiled
+    program): billions of parameters never exist in float32 all at
+    once, on either side of the bus, and a deploy compiles a handful
+    of programs for them, not one an operation."""
     import functools
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    spec = glm_spec(params)
-    shapes = theta_shapes(V, spec)
     drawn = sum(isinstance(s[2], tuple) for s in shapes)
-    keys = jax.random.split(jax.random.PRNGKey(int(params.seed)),
-                            max(drawn, 2 + 8 * spec.n_layers))
+    keys = jax.random.split(jax.random.PRNGKey(int(seed)),
+                            max(drawn, 2 + 8 * n_layers))
     keys_host = np.asarray(keys)
-    cd = spec.compute_dtype
+    cd = compute_dtype
 
     @functools.partial(jax.jit, static_argnums=(1,))
     def draw_group(ks, group):
@@ -225,7 +233,7 @@ def draw_serving_theta(V: int, params, skip: Tuple[str, ...] = ()):
         head, _, tail = name.partition("_")
         layer = head if head[:1] == "l" and head[1:].isdigit() else ""
         entry = (tail if layer else name, shape, init,
-                 cd if is_low(name) else "float32")
+                 cd if low(name) else "float32")
         key = None
         if isinstance(init, tuple):
             key, kx = kx, kx + 1

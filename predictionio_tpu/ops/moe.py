@@ -164,14 +164,18 @@ def use_low(w, w_low, dtype):
 
 # -- router ------------------------------------------------------------------
 
-def route(h, w_router, k: int):
+def route(h, w_router, k: int, renorm: bool = False):
     """``h: [T, D]`` -> router logits ``[T, E]`` (float32, a true
     float32 product on every backend), the top-``k`` experts ``[T, k]``
-    and their softmax probabilities ``[T, k]``, not renormalised."""
+    and their softmax probabilities ``[T, k]``: as they are (OLMoE's
+    ``norm_topk_prob: false``) or, with ``renorm``, divided by their
+    sum (the Qwen3 / SDAR ``norm_topk_prob: true``)."""
     logits = jnp.dot(h.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
     weights, experts = jax.lax.top_k(probs, k)
+    if renorm:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return logits, probs, experts.astype(jnp.int32), weights
 
 

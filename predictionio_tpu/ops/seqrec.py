@@ -124,6 +124,23 @@ class SeqRecParams(Params):
     routed_scaling_factor: float = 1.0
     experts_held: int = 0
     expert_share: int = 0
+    # the sdar_moe block (ops/sdar.py): key/value heads shared by
+    # groups of query heads, the router's weights divided by their sum
+    # (``norm_topk_prob``), and generation by diffusion over blocks: a
+    # slate is decoded ``block_length`` positions at a time in passes
+    # that unmask by ``remasking`` (``low_confidence_static``: the
+    # ``ceil(masked / denoising_steps)`` most confident a pass;
+    # ``low_confidence_dynamic``: every position at
+    # ``confidence_threshold`` or above, at least one); ``mask_token``
+    # is the mask token's row of the tables (negative: a last row the
+    # tables get for it)
+    n_kv_heads: int = 0
+    norm_topk_prob: bool = False
+    block_length: int = 4
+    denoising_steps: int = 4
+    remasking: str = "low_confidence_static"
+    confidence_threshold: float = 0.9
+    mask_token: int = -1
     # the session lane that serves that block (ops/sessions.py): the
     # cache pool's rows (0: twice the stored histories) and how many
     # dispatches' audits it keeps for a check to read (0: the
@@ -161,6 +178,18 @@ GLM_5 = dict(
     n_shared_experts=1, routed_scaling_factor=2.5)
 
 
+# the block of SDAR-30B-A3B-Chat
+# (https://huggingface.co/JetLM/SDAR-30B-A3B-Chat, model_type sdar_moe)
+# as its config.json publishes it; ``n_layers`` and the generation
+# settings (block_length, denoising_steps, remasking) are the
+# deployment's
+SDAR_30B_A3B = dict(
+    block="sdar_moe", rank=2048, n_heads=32, n_kv_heads=4, head_dim=128,
+    norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=1000000.0,
+    tied=False, vocab_rows=151936, n_experts=128, expert_width=768,
+    experts_per_token=8, norm_topk_prob=True, mask_token=151669)
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What of :class:`SeqRecParams` shapes the compiled programs (the
@@ -182,6 +211,7 @@ class BlockSpec:
     z_coef: float
     compute_dtype: str
     glm: Any = None   # ops/mla.py::GlmSpec of the glm_moe_dsa block
+    sdar: Any = None  # ops/sdar.py::SdarSpec of the sdar_moe block
 
     @property
     def sparse(self) -> bool:
@@ -223,6 +253,11 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         from predictionio_tpu.ops import mla
 
         glm = mla.glm_spec(params)
+    sdar = None
+    if params.block == "sdar_moe":
+        from predictionio_tpu.ops import sdar as _sdar
+
+        sdar = _sdar.sdar_spec(params)
     return BlockSpec(
         params.block, int(params.n_layers), H, head_dim, params.norm,
         float(params.norm_eps), params.positions,
@@ -230,7 +265,7 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         int(params.n_experts) if sparse else 0,
         int(params.experts_per_token) if sparse else 0,
         float(params.lb_coef), float(params.z_coef),
-        params.compute_dtype, glm)
+        params.compute_dtype, glm, sdar)
 
 
 @dataclasses.dataclass
@@ -404,8 +439,14 @@ def pack_sequences(seqs: Sequence[np.ndarray], row_len: int) -> PackedRows:
 
 def table_rows(n_items: int, params: SeqRecParams) -> int:
     """Rows of the item table(s): ``vocab_rows`` when the block
-    publishes a vocabulary larger than the catalog."""
-    return max(int(n_items), int(params.vocab_rows))
+    publishes a vocabulary larger than the catalog; the ``sdar_moe``
+    block's tables hold its mask token's row too (one past the catalog
+    when ``mask_token`` names none)."""
+    rows = max(int(n_items), int(params.vocab_rows))
+    if params.block == "sdar_moe":
+        rows = max(rows, int(params.mask_token) + 1) \
+            if int(params.mask_token) >= 0 else max(rows, int(n_items) + 1)
+    return rows
 
 
 def init_theta(n_items: int, params: SeqRecParams) -> Dict[str, np.ndarray]:
@@ -429,6 +470,10 @@ def _theta_shapes(n_items: int, params: SeqRecParams
         from predictionio_tpu.ops import mla
 
         return mla.theta_shapes(V, spec.glm)
+    if spec.sdar is not None:
+        from predictionio_tpu.ops import sdar as _sdar
+
+        return _sdar.theta_shapes(V, spec.sdar)
     A = spec.n_heads * spec.head_dim
     out: List[Tuple[str, Tuple[int, ...], Any]] = [
         ("item_emb", (V, D), ("div", math.sqrt(D)))]
@@ -668,9 +713,20 @@ def _glm_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
     return mla.glm_layer(theta, i, x, seg, pos, spec.glm), None
 
 
+def _sdar_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
+                attention_fn, low):
+    """SDAR's layer (``ops/sdar.py``): grouped-query attention under
+    the block-causal mask (a position sees its whole block and every
+    earlier one of its segment), then the renormalised expert layer.
+    An id that is the mask token's row is a masked position."""
+    from predictionio_tpu.ops import sdar
+
+    return sdar.sdar_layer(theta, i, x, seg, pos, spec.sdar), None
+
+
 # one function per layer kind; ``SeqRecParams.block`` names one
 BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer,
-          "glm_moe_dsa": _glm_layer}
+          "glm_moe_dsa": _glm_layer, "sdar_moe": _sdar_layer}
 
 
 def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
@@ -1104,6 +1160,13 @@ def train_seqrec(buckets, n_items: int, params: SeqRecParams,
         raise ValueError("train_seqrec: no non-empty sequences to train "
                          "on (every user history was empty)")
     spec = block_spec(params)
+    if spec.sdar is not None and int(params.num_steps) > 0:
+        raise ValueError(
+            "the sdar_moe block is not trained here: under its "
+            "block-causal mask a position sees the rest of its block, so "
+            "the next-item loss would read its own target; the "
+            "masked-block diffusion objective is not implemented "
+            "(ROADMAP Reach). Serve it with numSteps 0 and seededWeights")
     with _tracing.span("seq.stage"):
         if theta is None:
             theta = init_theta_device(n_items, params)

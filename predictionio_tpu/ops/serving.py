@@ -973,6 +973,31 @@ class _Pending:
         return (self.deadline, self.seq) < (other.deadline, other.seq)
 
 
+class _Running:
+    """The future of a query its lane HANDED BACK (see
+    :meth:`BatchDispatcher._carry`): the query is running already (its
+    waiter can no longer shed it), so the group that takes it up again
+    claims it without asking, through the calls every queued query's
+    future gets."""
+
+    __slots__ = ("_future",)
+
+    def __init__(self, future: Future):
+        self._future = future
+
+    def set_running_or_notify_cancel(self) -> bool:
+        return True
+
+    def done(self) -> bool:
+        return self._future.done()
+
+    def set_result(self, result) -> None:
+        self._future.set_result(result)
+
+    def set_exception(self, exc) -> None:
+        self._future.set_exception(exc)
+
+
 class BatchLane:
     """One query kind's lane inside the shared :class:`BatchDispatcher`
     — its own EDF queue, batch cap and group-dispatch function, but the
@@ -982,7 +1007,7 @@ class BatchLane:
     def __init__(self, dispatcher: "BatchDispatcher", name: str,
                  max_batch: int,
                  dispatch_fn: Callable[["DeviceTopK", List[_Pending]],
-                                       None]):
+                                       Optional[List[_Pending]]]):
         self._d = dispatcher
         self.name = name
         self.max_batch = int(max_batch)
@@ -1076,6 +1101,16 @@ class BatchDispatcher:
       load pays at most the ~2ms window, never an unbounded wait;
     - ``drain``:  the dispatcher is closing and flushes what is queued.
 
+    A lane whose queries take several device rounds (the slate lane,
+    ``ops/slates.py``) RETURNS from its dispatch function the queries
+    it has not finished; :meth:`_carry` puts them back at the head of
+    the lane's queue with the deadlines they came with, so the next
+    group is those queries and then new arrivals, and a query is
+    delivered (by the lane, as ever) when its own last round ends.
+    Between two rounds :meth:`_pick` offers every OTHER lane one turn
+    first, by the same deadline rule. A lane that returns None takes
+    none of that path.
+
     Results travel back through per-request futures; per-request
     rendering runs on the waiting threads (:class:`_BatchResult`). The
     PR-7 queue-deadline shedding is preserved: a query still queued
@@ -1102,6 +1137,9 @@ class BatchDispatcher:
         self._thread_lock = threading.Lock()
         self._stats_lock = threading.Lock()
         self._closed = False
+        # between two rounds of a lane that handed queries back: the
+        # other lanes, each still owed one turn (see _carry, _pick)
+        self._owed: List[BatchLane] = []
 
     def add_lane(self, name: str, max_batch: int,
                  dispatch_fn) -> BatchLane:
@@ -1120,6 +1158,10 @@ class BatchDispatcher:
         item = _Pending(payload, k, now + w, next(self._seq),
                         arrival=now,
                         ctx=_tracing.current_trace_context())
+        # the waiter's future, taken before the dispatcher can see the
+        # item: a lane that hands the query back wraps ``item.future``
+        # (_carry), possibly before this call returns
+        future = item.future
         # pending is incremented BEFORE the item becomes visible in the
         # handoff: the dispatcher's decrement (at pop, under the stats
         # lock) can then never run before this increment, so the depth
@@ -1147,7 +1189,7 @@ class BatchDispatcher:
         self._set_queue_gauge(lane)
         self._wake.set()
         self._ensure_thread()
-        return item.future
+        return future
 
     def submit_wait(self, lane: BatchLane, payload,
                     k: int) -> Tuple[_BatchResult, int]:
@@ -1247,10 +1289,23 @@ class BatchDispatcher:
                                          Optional[str]]:
         """The lane to dispatch NOW, with its trigger — a full lane
         first, else the lane whose earliest deadline has expired
-        (earliest wins across lanes), else nothing yet."""
+        (earliest wins across lanes), else nothing yet. Between two
+        rounds of a lane that handed queries back, the lanes still
+        ``_owed`` a turn are asked first, by the same rule, once each:
+        a long query holds no other lane back for longer than a round."""
+        if self._owed:
+            owed, self._owed = self._owed, []
+            lane, trigger = self._due(owed, now)
+            if lane is not None:
+                self._owed = [ln for ln in owed if ln is not lane]
+                return lane, trigger
+        return self._due(self._lanes, now)
+
+    def _due(self, lanes: List[BatchLane], now: float
+             ) -> Tuple[Optional[BatchLane], Optional[str]]:
         best: Optional[BatchLane] = None
         best_deadline = 0.0
-        for lane in self._lanes:
+        for lane in lanes:
             q = lane.queue
             if not q:
                 continue
@@ -1321,6 +1376,7 @@ class BatchDispatcher:
         if not group:
             return
         srv = self._srv_ref()
+        back = None
         try:
             if srv is None:
                 raise RuntimeError("serving backend was released")
@@ -1341,16 +1397,17 @@ class BatchDispatcher:
                                           group=len(group),
                                           trace_parent=parent,
                                           queue_wait_mean_us=mean * 1e6):
-                    lane.dispatch_fn(srv, group)
+                    back = lane.dispatch_fn(srv, group)
             else:
-                lane.dispatch_fn(srv, group)
+                back = lane.dispatch_fn(srv, group)
         except BaseException as e:  # propagate to every waiter
             for it in group:
                 if not it.future.done():
                     it.future.set_exception(e)
         finally:
             del srv  # never hold the server across the idle wait
-            for it in group:
+            for it in (group if back is None
+                       else [it for it in group if it not in back]):
                 if not it.future.done():
                     it.future.set_exception(RuntimeError(
                         "batch dispatch completed without a result"))
@@ -1373,6 +1430,24 @@ class BatchDispatcher:
                                             batcher=lane.name)
             metrics.MICROBATCH_QUEUE_AT_DISPATCH.observe(
                 depth, batcher=lane.name)
+        if back is not None:
+            self._carry(lane, back)
+
+    def _carry(self, lane: BatchLane, back: List[_Pending]) -> None:
+        """``back``: the queries of the group just dispatched that
+        their lane has not finished. They return to the head of the
+        lane's queue (their deadlines are older than any arrival's;
+        their futures, running already, are wrapped so that the next
+        group claims them like any other), and every other lane is
+        owed one turn before this lane's next round (:meth:`_pick`)."""
+        for it in back:
+            if type(it.future) is not _Running:
+                it.future = _Running(it.future)
+            bisect.insort(lane.queue, it)
+        with self._stats_lock:
+            lane.pending += len(back)
+        self._set_queue_gauge(lane)
+        self._owed = [ln for ln in self._lanes if ln is not lane]
 
 
 def _deliver(group: List[_Pending], idx: np.ndarray,

@@ -5,27 +5,38 @@ DeviceTopK` (the pattern is ``TwoStageTopK``: a subclass, its own
 ``BatchLane`` in the shared ``BatchDispatcher``, its rows in the AOT
 ladder) whose store holds, beside the output table ``Y``:
 
-- the backbone's weights (``ops/mla.py``, the ``glm_moe_dsa`` block);
+- the weights of the BACKBONE it is handed (:func:`backbone_of`: the
+  ``glm_moe_dsa`` block, :class:`GlmBackbone` over ``ops/mla.py``; the
+  ``sdar_moe`` block, ``ops/slates.py::SdarBackbone`` over
+  ``ops/sdar.py``);
 - ``X``: every user's LAST hidden state (final norm applied), so that
   the inherited ``users`` lane answers a query without new events;
-- a POOL of cache blocks: per layer a latent array ``[blocks, bs,
-  lat_width]`` and an index-key array ``[blocks, bs, index_head_dim]``
-  that share one block table, so a block id names a session's rows of
-  both kinds in every layer. Block 0 is never handed out: padding
-  writes land there.
+- a POOL of cache blocks: per layer one array ``[blocks, bs, width]``
+  for every per-token cache row the backbone DECLARES (``cache_rows``:
+  GLM-5's latent and index key; SDAR's key and value rows), all under
+  one block table, so a block id names a session's rows of every kind
+  in every layer. Block 0 is never handed out: padding writes land
+  there.
 
-A query ``(user, new events, k)`` appends the events to the user's
-session and recommends: ONE dispatch runs the backbone over the
+The manager keeps the blocks, the tables, eviction, the waves two
+queries of one user take, the ladder and the lane; the backbone brings
+its prefill-chunk program, the programs of its queries with their
+ladder entries, the lane's dispatch function and its audits.
+
+GLM-5's query ``(user, new events, k)`` appends the events to the
+user's session and recommends: ONE dispatch runs the backbone over the
 group's new tokens against the caches, writes their cache rows, scores
 the output table, masks what the user has seen and takes the top-k,
 fetched as one packed buffer. Queries of one user in one group are
 applied in arrival order, each in a wave of its own, so every answer
-reflects exactly its own prefix. A user without a session is prefilled
-from the history the model stored (``prefill_chunk``, ``index_topk``
-tokens a chunk), which is also how :meth:`warmup` builds the resident
-sessions at deploy time. When the pool is full the session touched
-longest ago gives up its blocks; its events stay on the host and its
-next touch prefills it again.
+reflects exactly its own prefix. (SDAR's query takes several rounds:
+``ops/slates.py``.) A user without a session is prefilled from the
+history the model stored (the backbone's chunk program), which is also
+how :meth:`warmup` builds the resident sessions at deploy time. A
+backbone that commits cache rows in whole blocks (``commit_multiple``)
+leaves a session's newest events as ids: its provisional TAIL. When
+the pool is full the session touched longest ago gives up its blocks;
+its events stay on the host and its next touch prefills it again.
 
 Host-side bookkeeping (block tables, lengths, the events themselves)
 lives under ``_sess_lock``; the device tables are swapped under
@@ -71,19 +82,46 @@ NO_ROW = -1              # the user row of a query row that writes none
 
 
 class _Session:
-    __slots__ = ("items", "length", "blocks", "touched")
+    __slots__ = ("items", "events", "length", "blocks", "touched",
+                 "inflight")
 
     def __init__(self, items: np.ndarray):
         self.items = np.asarray(items, dtype=np.int32)
+        self.events = len(self.items)   # events the session holds
         self.length = 0          # events whose rows are in the cache
         self.blocks: List[int] = []
         self.touched = 0
+        self.inflight = 0        # queries between two of their rounds
+
+    def append(self, items) -> None:
+        """``items`` behind the session's events (ids only: the caller
+        says what of them is cached)."""
+        n = self.events + len(items)
+        if n > len(self.items):
+            grown = np.zeros(max(2 * len(self.items), n, 64), np.int32)
+            grown[:self.events] = self.items[:self.events]
+            self.items = grown
+        self.items[self.events:n] = items
+        self.events = n
+
+
+def waves(steps: Dict[int, list]):
+    """``{user: [its steps in arrival order]}`` -> lists of ``(user,
+    step)``: the first step of every user, then the second of those
+    that have one, ... so that one dispatch never holds two steps of
+    one user and each sees exactly its own prefix."""
+    wave = 0
+    while True:
+        rows = [(u, s[wave]) for u, s in steps.items() if len(s) > wave]
+        if not rows:
+            return
+        yield rows
+        wave += 1
 
 
 def _dispatch_sess_group(srv: "SessionTopK",
                          group: List[_Pending]) -> None:
-    """Session queries -> one dispatch a WAVE: the first step of every
-    user in the group, then the second of those that have one, ... A
+    """Session queries -> one dispatch a WAVE (:func:`waves`). A
     step is a query's (at most ``SESS_EVENTS``) new events; a query
     with more is cut into steps of which only the last answers."""
 
@@ -100,49 +138,257 @@ def _dispatch_sess_group(srv: "SessionTopK",
                          row if j == len(cuts) - 1 else None))
     idx = np.zeros((len(group), kb), dtype=np.int32)
     scores = np.full((len(group), kb), -np.inf, dtype=np.float32)
-    wave = 0
-    while True:
-        rows = [(u, s[wave]) for u, s in steps.items() if len(s) > wave]
-        if not rows:
-            break
+    for rows in waves(steps):
         for lo in range(0, len(rows), SESS_MAX_BATCH):
             part = rows[lo:lo + SESS_MAX_BATCH]
             wi, ws = srv.extend([(u, st[0]) for u, st in part], kb)
             for j, (_, st) in enumerate(part):
                 if st[1] is not None:
                     idx[st[1]], scores[st[1]] = wi[j], ws[j]
-        wave += 1
     _deliver(group, idx, scores)
+
+
+class GlmBackbone:
+    """GLM-5's block (``ops/mla.py``) as the session lane serves it:
+    two cache rows a token and layer (the latent and the indexer's
+    key), a query answered by ONE extend dispatch, events committed one
+    by one."""
+
+    entries = ("sess", "sesspre")
+    commit_multiple = 1
+    max_batch = SESS_MAX_BATCH
+    dispatch = staticmethod(_dispatch_sess_group)
+
+    def __init__(self, params):
+        from predictionio_tpu.ops import mla
+
+        self.spec = spec = mla.glm_spec(params)
+        self.width = spec.width
+        self.compute_dtype = spec.compute_dtype
+        # (name, width, the component's name in memory_report())
+        self.cache_rows = (("lat", spec.lat_width, "sessionLatents"),
+                           ("ik", spec.idx_dim, "sessionIndexKeys"))
+        # the shortest cached-length bucket is this many selections
+        # (``index_topk``) long; a prefill chunk is one selection
+        self.floor = SESS_FLOOR_SELECTIONS * spec.idx_topk
+        self.chunk = spec.idx_topk
+        self.qb = min(32, self.chunk)
+
+    def serving_theta(self, theta):
+        from predictionio_tpu.ops import mla
+
+        return mla.serving_theta(theta, self.spec)
+
+    def draw_theta(self, V: int, params):
+        from predictionio_tpu.ops import mla
+
+        return mla.draw_serving_theta(V, params)
+
+    def extend_program(self, m: "SessionTopK", kb: int, S: int):
+        def make():
+            import jax
+
+            from predictionio_tpu.ops import mla
+
+            def sess_extend(theta, X, seen_bits, pool, Y, ints):
+                packed, X, seen_bits, lat, ik, audit = mla.extend_step(
+                    theta, X, seen_bits, pool["lat"], pool["ik"], Y, ints,
+                    spec=self.spec, kb=kb, T=SESS_EVENTS, S=S, bs=m._bs,
+                    n_items=m.n_items, mode=m._mode, mask_seen=True,
+                    audit=bool(m._audit_keep))
+                return packed, X, seen_bits, {"lat": lat, "ik": ik}, audit
+
+            return jax.jit(sess_extend, donate_argnums=(1, 2, 3))
+
+        return m._program(("sess", kb, S), make)
+
+    def prefill_program(self, m: "SessionTopK", S: int):
+        def make():
+            import jax
+
+            from predictionio_tpu.ops import mla
+
+            def sess_prefill(theta, X, pool, ints):
+                X, lat, ik, h = mla.prefill_chunk(
+                    theta, X, pool["lat"], pool["ik"], ints,
+                    spec=self.spec, C=self.chunk, S=S, bs=m._bs,
+                    qb=self.qb)
+                return X, {"lat": lat, "ik": ik}, h
+
+            return jax.jit(sess_prefill, donate_argnums=(1, 2))
+
+        return m._program(("sesspre", S), make)
+
+    def plan(self, m: "SessionTopK", kb: int) -> List[Tuple]:
+        """``("sess", kb, bb, S)`` for every (query bucket,
+        cached-length bucket) at the largest k bucket."""
+        return [("sess", kb, bb, S) for bb in SESS_BATCHES
+                for S in m._s_buckets]
+
+    def lower(self, m: "SessionTopK", entry: Tuple, tables, theta, pool):
+        import jax
+        import jax.numpy as jnp
+
+        _, kb, bb, S = entry
+        return lower_compile(
+            self.extend_program(m, kb, S), theta, tables["X"],
+            tables["seen_bits"], pool, tables["Y"],
+            jax.ShapeDtypeStruct(
+                (bb, m._ints_width(SESS_EVENTS, S)), jnp.int32))
+
+    def warm(self, m: "SessionTopK", entry: Tuple) -> None:
+        _, kb, bb, S = entry
+        self._run_extend(m, np.zeros(
+            (bb, m._ints_width(SESS_EVENTS, S)), np.int32)
+            + m._pad_row(SESS_EVENTS, S), kb, S, n=0)
+
+    def _run_extend(self, m: "SessionTopK", ints: np.ndarray, kb: int,
+                    S: int, n: int):
+        """One extend dispatch; returns the fetched packed buffer and
+        the (device) audit outputs (None from a lane built without
+        ``audit``)."""
+        got = {}
+
+        def take(out):
+            packed, m._X, m._seen_bits, m._pool, got["audit"] = out
+            return packed
+
+        bb = ints.shape[0]
+        out = m._dispatch_entry(
+            ("sess", kb, bb, S), lambda: self.extend_program(m, kb, S),
+            lambda: (m._theta, m._X, m._seen_bits, m._pool, m._Y, ints),
+            batch=n, bucket=bb, take=take)
+        with _dtel.stage("fetchUs", "dispatch.fetch", done=True):
+            host = np.asarray(out)
+        return host, got["audit"]
+
+    def extend(self, m: "SessionTopK", rows: List[Tuple[int, np.ndarray]],
+               kb: int) -> Tuple[np.ndarray, np.ndarray]:
+        n = len(rows)
+        T = SESS_EVENTS
+        with m._sess_lock, _trace_span(
+                "sess.extend", attributes={"queries": n}):
+            with _dtel.stage("formUs", "batch.form"):
+                busy = {int(u) for u, _ in rows}
+                if len(busy) != n:
+                    raise ValueError("one dispatch takes one query a user")
+                sessions = []
+                for u, items in rows:
+                    sess = m._ensure_session(int(u), busy)
+                    m._reserve(sess, sess.length + len(items), busy)
+                    sessions.append(sess)
+                S = m._s_bucket(max(s.length + len(it) for s, (_, it)
+                                    in zip(sessions, rows)))
+                bb = next(b for b in SESS_BATCHES if b >= n)
+                ints = np.tile(m._pad_row(T, S), (bb, 1))
+                for j, (sess, (u, items)) in enumerate(
+                        zip(sessions, rows)):
+                    k = len(items)
+                    ints[j, 0], ints[j, 1], ints[j, 2] = u, sess.length, k
+                    ints[j, 3:3 + k] = items
+                    ints[j, 3 + T:3 + T + k] = m._phys(
+                        sess, np.arange(sess.length, sess.length + k))
+                    ints[j, 3 + 2 * T:] = m._table(sess, S)
+            host, audit = self._run_extend(m, ints, kb, S, n)
+            m._clock += 1
+            tokens = 0
+            for j, (sess, (u, items)) in enumerate(zip(sessions, rows)):
+                if len(items):
+                    sess.append(items)
+                    sess.length = sess.events
+                    tokens += len(items)
+                sess.touched = m._clock
+            if audit is not None and (m._watched is None or any(
+                    int(u) in m._watched for u, _ in rows)):
+                m._audits.append((audit, bb, [
+                    (int(u), sess.length) for sess, (u, _)
+                    in zip(sessions, rows)]))
+            if tokens:
+                _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
+            for kind, rows_ in (("valid", tokens),
+                                ("padded", bb * T - tokens)):
+                _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
+                m._token_rows[kind] += rows_
+            _metrics.SESS_POSITIONS.inc(
+                amount=sum(s_.length for s_ in sessions))
+        idx, scores = _unpack(host[:, :2 * kb], kb)
+        selected, eligible, local, touched = (
+            float(c) for c in host[0, 2 * kb:].view(np.float32))
+        if eligible > 0:
+            _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
+            _metrics.SESS_SELECTED.inc(amount=selected, kind="selected")
+            _metrics.SESS_SELECTED.inc(amount=eligible, kind="eligible")
+        if local > 0:
+            _metrics.SESS_LOCAL_PICKS.inc(amount=local)
+        if touched > 0:
+            _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
+        return idx[:n], scores[:n]
+
+    def audits(self, m: "SessionTopK", kept, uid: int
+               ) -> List[Dict[str, Any]]:
+        import jax
+
+        out = []
+        for audit, bb, rows in kept:
+            for slot, (u, length) in enumerate(rows):
+                if u != int(uid):
+                    continue
+                host = jax.device_get(audit)
+                got = {k: v[:, slot] for k, v in host.items()
+                       if k != "scores"}
+                got.update(scores=host["scores"][slot][:m.n_items],
+                           length=int(length), slot=slot,
+                           queries=len(rows), bucket=int(bb))
+                out.append(got)
+        return out
+
+    def report(self, m: "SessionTopK") -> Dict[str, Any]:
+        rows = dict(m._token_rows)
+        # of the token rows dispatched, the share the attend loop
+        # never runs
+        return {"tokenRows": rows, "skippedRowShare": rows["padded"]
+                / max(sum(rows.values()), 1)}
+
+
+def backbone_of(params):
+    """The backbone that serves ``params.block`` from per-user
+    caches."""
+    if params.block == "glm_moe_dsa":
+        return GlmBackbone(params)
+    if params.block == "sdar_moe":
+        from predictionio_tpu.ops.slates import SdarBackbone
+
+        return SdarBackbone(params)
+    raise ValueError(f"no session backbone for block {params.block!r}")
 
 
 class SessionTopK(DeviceTopK):
     """See the module docstring. ``item_factors``: the output table;
-    ``theta``: the backbone's parameters as served
-    (:func:`~predictionio_tpu.ops.mla.serving_theta` /
-    ``draw_serving_theta``, without ``out_emb``); ``histories``: ``{user
-    row: item ids, oldest first}``; ``pool_tokens``: the pool's cache
-    rows (``SeqRecParams.session_pool_tokens``; 0: twice the stored
+    ``theta``: the backbone's parameters as served (the backbone's
+    ``serving_theta`` / ``draw_theta``, without ``out_emb``);
+    ``histories``: ``{user row: item ids, oldest first}``;
+    ``pool_tokens``: the pool's cache rows
+    (``SeqRecParams.session_pool_tokens``; 0: twice the stored
     histories); ``audit``: how many dispatches' audits the lane keeps
     (``SeqRecParams.session_audit``; 0: the programs compute none; see
-    :meth:`audits`). A block holds ``SESS_BLOCK`` rows; the programs
-    are laddered over ``SESS_BATCHES`` queries and over the cached
-    length in powers of two from ``SESS_FLOOR_SELECTIONS x index_topk``
-    to twice the longest stored history's bucket."""
+    :meth:`audits`); ``backbone``: :func:`backbone_of` ``(params)``
+    when None. A block holds ``SESS_BLOCK`` rows; the programs are
+    laddered over the backbone's query buckets and over the cached
+    length in powers of two from the backbone's ``floor`` to twice the
+    longest stored history's bucket."""
 
     def __init__(self, item_factors, theta: Dict[str, Any], params,
                  n_users: int, histories: Optional[Dict[int, Any]] = None,
                  seen: Optional[Dict[int, np.ndarray]] = None,
                  pool_tokens: int = 0, audit: int = 0,
-                 microbatch: Optional[bool] = None):
+                 microbatch: Optional[bool] = None, backbone=None):
         import jax
         import jax.numpy as jnp
 
-        from predictionio_tpu.ops import mla
-
-        spec = mla.glm_spec(params)
-        self._spec = spec
+        self._bb = bb = backbone or backbone_of(params)
+        self._spec = bb.spec
         n_users = int(n_users)
-        X = np.zeros((n_users, spec.width), dtype=np.float32)
+        X = np.zeros((n_users, bb.width), dtype=np.float32)
         if not seen:
             # what a user has seen is their history (an empty entry
             # keeps the bitmap when there is none)
@@ -162,7 +408,7 @@ class SessionTopK(DeviceTopK):
         self._histories = {int(u): np.asarray(h, dtype=np.int32)
                            for u, h in (histories or {}).items()}
         self._bs = SESS_BLOCK
-        lo = _bucket(SESS_FLOOR_SELECTIONS * spec.idx_topk, lo=self._bs)
+        lo = _bucket(bb.floor, lo=self._bs)
         longest = max((len(h) for h in self._histories.values()), default=0)
         self._s_max = 2 * _bucket(longest, lo=lo)
         self._s_buckets = []
@@ -170,20 +416,18 @@ class SessionTopK(DeviceTopK):
         while s <= self._s_max:
             self._s_buckets.append(s)
             s *= 2
-        self._chunk = spec.idx_topk
-        self._qb = min(32, self._chunk)
+        self._chunk = bb.chunk
         stored = sum(len(h) for h in self._histories.values())
         tokens = int(pool_tokens) or max(2 * stored, 4 * lo)
         self._n_blocks = 1 + max(2, -(-tokens // self._bs))
-        cache_dtype = jnp.dtype(spec.compute_dtype)
+        cache_dtype = jnp.dtype(bb.compute_dtype)
         with self._store_lock, _trace_span("store.upload"):
             shape = (self._n_blocks, self._bs)
-            self._lat = tuple(jnp.zeros(shape + (spec.lat_width,),
-                                        cache_dtype)
-                              for _ in range(spec.n_layers))
-            self._ik = tuple(jnp.zeros(shape + (spec.idx_dim,), cache_dtype)
-                             for _ in range(spec.n_layers))
-            jax.block_until_ready((self._lat, self._ik))
+            self._pool = {
+                name: tuple(jnp.zeros(shape + (width,), cache_dtype)
+                            for _ in range(bb.spec.n_layers))
+                for name, width, _ in bb.cache_rows}
+            jax.block_until_ready(self._pool)
         self._sess_lock = threading.RLock()
         self._sessions: Dict[int, _Session] = {}
         self._free = list(range(self._n_blocks - 1, 0, -1))
@@ -197,8 +441,8 @@ class SessionTopK(DeviceTopK):
         self._sess_batcher: Optional[BatchLane] = None
         if self._dispatcher is not None:
             self._sess_batcher = self._dispatcher.add_lane(
-                "pio-microbatch-sess", max_batch=SESS_MAX_BATCH,
-                dispatch_fn=_dispatch_sess_group)
+                "pio-microbatch-sess", max_batch=bb.max_batch,
+                dispatch_fn=bb.dispatch)
         _metrics.SESS_CACHE_CAPACITY.set(
             (self._n_blocks - 1) * self._bs)
         _metrics.SESS_CACHE_TOKENS.set(0)
@@ -221,60 +465,31 @@ class SessionTopK(DeviceTopK):
     _ladder_kmax = 128
     _resident_s = 0.0     # what warmup() spent building the sessions
 
-    def _extend_program(self, kb: int, S: int):
-        key = ("sess", kb, S)
+    def _program(self, key: Tuple, make):
+        """The backbone's jitted program under ``key``, made once."""
         prog = self._sess_programs.get(key)
         if prog is None:
-            import jax
-
-            from predictionio_tpu.ops import mla
-
-            def sess_extend(theta, X, seen_bits, lat, ik, Y, ints):
-                return mla.extend_step(
-                    theta, X, seen_bits, lat, ik, Y, ints, spec=self._spec,
-                    kb=kb, T=SESS_EVENTS, S=S, bs=self._bs,
-                    n_items=self.n_items, mode=self._mode, mask_seen=True,
-                    audit=bool(self._audit_keep))
-
-            prog = jax.jit(sess_extend, donate_argnums=(1, 2, 3, 4))
-            self._sess_programs[key] = prog
-        return prog
-
-    def _prefill_program(self, S: int):
-        key = ("sesspre", S)
-        prog = self._sess_programs.get(key)
-        if prog is None:
-            import jax
-
-            from predictionio_tpu.ops import mla
-
-            def sess_prefill(theta, X, lat, ik, ints):
-                return mla.prefill_chunk(
-                    theta, X, lat, ik, ints, spec=self._spec,
-                    C=self._chunk, S=S, bs=self._bs, qb=self._qb)
-
-            prog = jax.jit(sess_prefill, donate_argnums=(1, 2, 3))
-            self._sess_programs[key] = prog
+            prog = self._sess_programs[key] = make()
         return prog
 
     def _ints_width(self, T: int, S: int) -> int:
         return 3 + 2 * T + S // self._bs
 
     def _store_sig(self, tables: Dict[str, Any]) -> Tuple:
+        # (by name: a dict comes back from a program with sorted keys)
+        first = self._pool[self._bb.cache_rows[0][0]][0]
         return super()._store_sig(tables) + (
-            tuple(self._lat[0].shape), str(self._lat[0].dtype),
-            self._spec, bool(self._audit_keep))
+            tuple(first.shape), str(first.dtype), self._spec,
+            bool(self._audit_keep))
 
     def aot_plan(self, max_k: int = 128,
                  batch_sizes: Tuple[int, ...] = ()) -> List[Tuple]:
-        """The parent ladder plus ``("sess", kb, bb, S)`` for every
-        (query bucket, cached-length bucket) at the largest k bucket,
-        and ``("sesspre", chunk, S)`` for every cached-length bucket."""
+        """The parent ladder plus the backbone's own entries at the
+        largest k bucket, and ``("sesspre", chunk, S)`` for every
+        cached-length bucket."""
         plan = super().aot_plan(max_k=max_k, batch_sizes=batch_sizes)
         self._ladder_kmax = max(e[1] for e in plan if e[0] == "user")
-        kb = self._sess_kb(1)
-        for bb in SESS_BATCHES:
-            plan += [("sess", kb, bb, S) for S in self._s_buckets]
+        plan += self._bb.plan(self, self._sess_kb(1))
         plan += [("sesspre", self._chunk, S) for S in self._s_buckets]
         return plan
 
@@ -282,37 +497,28 @@ class SessionTopK(DeviceTopK):
         import jax
         import jax.numpy as jnp
 
-        i32 = jnp.int32
+        if entry[0] not in self._bb.entries:
+            return super()._aot_lower_entry(entry, tables)
         with self._store_lock:
-            lat, ik, theta = self._lat, self._ik, self._theta
-        if entry[0] == "sess":
-            _, kb, bb, S = entry
-            return lower_compile(
-                self._extend_program(kb, S), theta, tables["X"],
-                tables["seen_bits"], lat, ik, tables["Y"],
-                jax.ShapeDtypeStruct(
-                    (bb, self._ints_width(SESS_EVENTS, S)), i32))
+            pool, theta = self._pool, self._theta
         if entry[0] == "sesspre":
             _, C, S = entry
             return lower_compile(
-                self._prefill_program(S), theta, tables["X"], lat, ik,
-                jax.ShapeDtypeStruct((self._ints_width(C, S),), i32))
-        return super()._aot_lower_entry(entry, tables)
+                self._bb.prefill_program(self, S), theta, tables["X"], pool,
+                jax.ShapeDtypeStruct((self._ints_width(C, S),), jnp.int32))
+        return self._bb.lower(self, entry, tables, theta, pool)
 
     def _warm_entry(self, entry: Tuple) -> None:
-        if entry[0] not in ("sess", "sesspre"):
+        if entry[0] not in self._bb.entries:
             return super()._warm_entry(entry)
         with self._sess_lock:
-            if entry[0] == "sess":
-                _, kb, bb, S = entry
-                self._run_extend(np.zeros(
-                    (bb, self._ints_width(SESS_EVENTS, S)), np.int32)
-                    + self._pad_row(SESS_EVENTS, S), kb, S, n=0)
-            else:
+            if entry[0] == "sesspre":
                 _, C, S = entry
                 ints = np.zeros(self._ints_width(C, S), np.int32)
                 ints[0] = NO_ROW
                 self._run_prefill(ints, S, n=0)
+            else:
+                self._bb.warm(self, entry)
 
     def warmup(self, max_k: int = 128,
                batch_sizes: Tuple[int, ...] = ()) -> Dict[str, int]:
@@ -338,7 +544,7 @@ class SessionTopK(DeviceTopK):
         logger.info("session lane: ladder %s in %.1fs, %d resident "
                     "sessions (%d events) prefilled in %.1fs", stats,
                     t0 - t_start, len(self._sessions),
-                    sum(s.length for s in self._sessions.values()),
+                    sum(s.events for s in self._sessions.values()),
                     self._resident_s)
         return stats
 
@@ -356,28 +562,35 @@ class SessionTopK(DeviceTopK):
         return (blocks[pos // self._bs] * self._bs
                 + pos % self._bs).astype(np.int32)
 
+    def _note_fill(self) -> None:
+        _metrics.SESS_CACHE_TOKENS.set(
+            (self._n_blocks - 1 - len(self._free)) * self._bs)
+
+    def _make_room(self, need: int, keep: Optional[_Session], busy) -> None:
+        """``need`` free blocks, evicting the sessions touched longest
+        ago (never ``keep``, one of ``busy`` or one with a query
+        between two of its rounds) when fewer are free."""
+        while len(self._free) < need:
+            victims = [(s.touched, u) for u, s in self._sessions.items()
+                       if s is not keep and u not in busy and s.blocks
+                       and not s.inflight]
+            if not victims:
+                raise RuntimeError(
+                    f"the session pool ({self._n_blocks - 1} blocks "
+                    f"of {self._bs}) cannot hold {need} more blocks "
+                    "beside the sessions of this dispatch")
+            self.release(min(victims)[1])
+            _metrics.SESS_EVICTIONS.inc()
+
     def _reserve(self, sess: _Session, length: int, busy) -> None:
-        """Blocks for ``length`` cached events, evicting the sessions
-        touched longest ago (never one of ``busy``) when none is
-        free."""
+        """Blocks for ``length`` cached events (:meth:`_make_room`)."""
         need = -(-int(length) // self._bs) - len(sess.blocks)
         if need <= 0:
             return
         with _trace_span("sess.cache_alloc", attributes={"blocks": need}):
-            while len(self._free) < need:
-                victims = [(s.touched, u) for u, s in
-                           self._sessions.items()
-                           if s is not sess and u not in busy and s.blocks]
-                if not victims:
-                    raise RuntimeError(
-                        f"the session pool ({self._n_blocks - 1} blocks "
-                        f"of {self._bs}) cannot hold {length} events "
-                        "beside the sessions of this dispatch")
-                self.release(min(victims)[1])
-                _metrics.SESS_EVICTIONS.inc()
+            self._make_room(need, sess, busy)
             sess.blocks += [self._free.pop() for _ in range(need)]
-            _metrics.SESS_CACHE_TOKENS.set(
-                (self._n_blocks - 1 - len(self._free)) * self._bs)
+            self._note_fill()
 
     def release(self, uid: int) -> None:
         """Give a session's blocks back; its events stay on the host
@@ -386,10 +599,9 @@ class SessionTopK(DeviceTopK):
             sess = self._sessions.pop(int(uid), None)
             if sess is None:
                 return
-            self._histories[int(uid)] = sess.items[:sess.length]
+            self._histories[int(uid)] = sess.items[:sess.events]
             self._free += sess.blocks
-            _metrics.SESS_CACHE_TOKENS.set(
-                (self._n_blocks - 1 - len(self._free)) * self._bs)
+            self._note_fill()
 
     def open_session(self, uid: int, items) -> None:
         """(Re)build ``uid``'s session from ``items`` (oldest first)."""
@@ -426,20 +638,24 @@ class SessionTopK(DeviceTopK):
         return sess
 
     def _prefill(self, sess: _Session, row: int, busy):
-        """``sess.items`` through the prefill program into blocks of
-        the pool, a chunk at a time; the last chunk leaves the
-        history's last hidden state in user row ``row`` (``NO_ROW``:
-        in none). Returns that state (device), None for no events."""
-        hist, C, h_last = sess.items, self._chunk, None
-        if len(hist) == 0:
+        """The session's events, in whole multiples of the backbone's
+        ``commit_multiple`` (the rest stays its tail), through the
+        prefill program into blocks of the pool, a chunk at a time; the
+        last chunk leaves the last cached event's hidden state in user
+        row ``row`` (``NO_ROW``: in none). Returns that state (device),
+        None for nothing cached."""
+        C, h_last = self._chunk, None
+        n_all = sess.events - sess.events % self._bb.commit_multiple
+        hist = sess.items[:n_all]
+        if n_all == 0:
             return None
-        with _trace_span("sess.prefill", attributes={"events": len(hist)}):
-            self._reserve(sess, len(hist), busy)
-            for p0 in range(0, len(hist), C):
-                n = min(C, len(hist) - p0)
+        with _trace_span("sess.prefill", attributes={"events": n_all}):
+            self._reserve(sess, n_all, busy)
+            for p0 in range(0, n_all, C):
+                n = min(C, n_all - p0)
                 S = self._s_bucket(p0 + C)
                 ints = np.zeros(self._ints_width(C, S), np.int32)
-                final = p0 + n == len(hist)
+                final = p0 + n == n_all
                 ints[0] = row if final else NO_ROW
                 ints[1], ints[2] = p0, n
                 ints[3:3 + n] = hist[p0:p0 + n]
@@ -447,7 +663,7 @@ class SessionTopK(DeviceTopK):
                                                    np.arange(p0, p0 + n))
                 ints[3 + 2 * C:] = self._table(sess, S)
                 h_last = self._run_prefill(ints, S, n=n)
-            sess.length = len(hist)
+            sess.length = n_all
         return h_last
 
     def encode(self, items) -> np.ndarray:
@@ -463,20 +679,19 @@ class SessionTopK(DeviceTopK):
                 h = self._prefill(sess, NO_ROW, busy=())
             finally:
                 self._free += sess.blocks
-                _metrics.SESS_CACHE_TOKENS.set(
-                    (self._n_blocks - 1 - len(self._free)) * self._bs)
-        return np.zeros(self._spec.width, np.float32) if h is None \
+                self._note_fill()
+        return np.zeros(self._bb.width, np.float32) if h is None \
             else np.asarray(h, dtype=np.float32)
 
     def _run_prefill(self, ints: np.ndarray, S: int, n: int):
         def take(out):
-            self._X, self._lat, self._ik, h = out
+            self._X, self._pool, h = out
             return h
 
         out = self._dispatch_entry(
             ("sesspre", self._chunk, S),
-            lambda: self._prefill_program(S),
-            lambda: (self._theta, self._X, self._lat, self._ik, ints),
+            lambda: self._bb.prefill_program(self, S),
+            lambda: (self._theta, self._X, self._pool, ints),
             batch=n, bucket=self._chunk, take=take)
         if n:
             _metrics.SESS_TOKENS.inc(amount=n, program="prefill")
@@ -484,104 +699,22 @@ class SessionTopK(DeviceTopK):
 
     # -- serving -----------------------------------------------------------
 
-    def _run_extend(self, ints: np.ndarray, kb: int, S: int, n: int):
-        """One extend dispatch; returns the fetched packed buffer and
-        the (device) audit outputs (None from a lane built without
-        ``audit``)."""
-        got = {}
-
-        def take(out):
-            packed, self._X, self._seen_bits, self._lat, self._ik, \
-                got["audit"] = out
-            return packed
-
-        bb = ints.shape[0]
-        out = self._dispatch_entry(
-            ("sess", kb, bb, S), lambda: self._extend_program(kb, S),
-            lambda: (self._theta, self._X, self._seen_bits, self._lat,
-                     self._ik, self._Y, ints),
-            batch=n, bucket=bb, take=take)
-        with _dtel.stage("fetchUs", "dispatch.fetch", done=True):
-            host = np.asarray(out)
-        return host, got["audit"]
-
     def extend(self, rows: List[Tuple[int, np.ndarray]],
                kb: int) -> Tuple[np.ndarray, np.ndarray]:
-        """Append each ``(user row, new events)`` (distinct users, at
-        most ``SESS_EVENTS`` events each) and return ``(item ids [n,
-        kb], scores [n, kb])``: one dispatch."""
-        n = len(rows)
-        T = SESS_EVENTS
-        with self._sess_lock, _trace_span(
-                "sess.extend", attributes={"queries": n}):
-            with _dtel.stage("formUs", "batch.form"):
-                busy = {int(u) for u, _ in rows}
-                if len(busy) != n:
-                    raise ValueError("one dispatch takes one query a user")
-                sessions = []
-                for u, items in rows:
-                    sess = self._ensure_session(int(u), busy)
-                    self._reserve(sess, sess.length + len(items), busy)
-                    sessions.append(sess)
-                S = self._s_bucket(max(s.length + len(it) for s, (_, it)
-                                       in zip(sessions, rows)))
-                bb = next(b for b in SESS_BATCHES if b >= n)
-                ints = np.tile(self._pad_row(T, S), (bb, 1))
-                for j, (sess, (u, items)) in enumerate(
-                        zip(sessions, rows)):
-                    m = len(items)
-                    ints[j, 0], ints[j, 1], ints[j, 2] = u, sess.length, m
-                    ints[j, 3:3 + m] = items
-                    ints[j, 3 + T:3 + T + m] = self._phys(
-                        sess, np.arange(sess.length, sess.length + m))
-                    ints[j, 3 + 2 * T:] = self._table(sess, S)
-            host, audit = self._run_extend(ints, kb, S, n)
-            self._clock += 1
-            tokens = 0
-            for j, (sess, (u, items)) in enumerate(zip(sessions, rows)):
-                m = len(items)
-                if m:
-                    if sess.length + m > len(sess.items):
-                        grown = np.zeros(max(2 * len(sess.items),
-                                             sess.length + m, 64), np.int32)
-                        grown[:sess.length] = sess.items[:sess.length]
-                        sess.items = grown
-                    sess.items[sess.length:sess.length + m] = items
-                    sess.length += m
-                    tokens += m
-                sess.touched = self._clock
-            if audit is not None and (self._watched is None or any(
-                    int(u) in self._watched for u, _ in rows)):
-                self._audits.append((audit, bb, [
-                    (int(u), sess.length) for sess, (u, _)
-                    in zip(sessions, rows)]))
-            if tokens:
-                _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
-            for kind, rows_ in (("valid", tokens),
-                                ("padded", bb * T - tokens)):
-                _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
-                self._token_rows[kind] += rows_
-            _metrics.SESS_POSITIONS.inc(
-                amount=sum(s_.length for s_ in sessions))
-        idx, scores = _unpack(host[:, :2 * kb], kb)
-        selected, eligible, local, touched = (
-            float(c) for c in host[0, 2 * kb:].view(np.float32))
-        if eligible > 0:
-            _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
-            _metrics.SESS_SELECTED.inc(amount=selected, kind="selected")
-            _metrics.SESS_SELECTED.inc(amount=eligible, kind="eligible")
-        if local > 0:
-            _metrics.SESS_LOCAL_PICKS.inc(amount=local)
-        if touched > 0:
-            _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
-        return idx[:n], scores[:n]
+        """(A backbone whose query is one dispatch:) append each
+        ``(user row, new events)`` (distinct users, at most
+        ``SESS_EVENTS`` events each) and return ``(item ids [n, kb],
+        scores [n, kb])``: one dispatch."""
+        return self._bb.extend(self, rows, kb)
 
     def sess_topk(self, uid: int, items, k: int
                   ) -> Tuple[np.ndarray, np.ndarray]:
         """Append ``items`` (item rows, oldest first; may be empty) to
-        ``uid``'s session and return its top ``k`` ``(item rows,
-        scores)``, seen items masked. Concurrent callers share
-        dispatches through the ``pio-microbatch-sess`` lane."""
+        ``uid``'s session and return ``(item rows, scores)``: its top
+        ``k``, seen items masked, or from a backbone that generates
+        slates the slate of ``k`` items in position order, each with
+        its confidence. Concurrent callers share dispatches through
+        the ``pio-microbatch-sess`` lane."""
         items = np.asarray(items, dtype=np.int32).reshape(-1)
         with _trace_span("device.sess_topk",
                          attributes={"k": int(k), "events": len(items)}) \
@@ -589,22 +722,32 @@ class SessionTopK(DeviceTopK):
             if self._sess_batcher is not None:
                 return self._sess_batcher.submit((int(uid), items), int(k),
                                                  span=sp)
-            group = [_Pending((int(uid), items), int(k), 0.0, 0, 0.0)]
-            group[0].future.set_running_or_notify_cancel()
-            _dispatch_sess_group(self, group)
-            res, row = group[0].future.result()
+            item = _Pending((int(uid), items), int(k), 0.0, 0, 0.0)
+            item.future.set_running_or_notify_cancel()
+            group = [item]
+            while group:        # a query of several rounds comes back
+                group = self._bb.dispatch(self, group)
+            res, row = item.future.result()
             return res.render(row, int(k))
 
     # -- what the lane knows of a session ----------------------------------
 
     def session_events(self, uid: int) -> np.ndarray:
-        """The events cached for ``uid`` (a copy), oldest first."""
+        """The events ``uid``'s session holds (a copy), oldest first:
+        the cached ones and its tail."""
         with self._sess_lock:
             sess = self._sessions.get(int(uid))
             if sess is None:
                 return np.array(self._histories.get(
                     int(uid), np.zeros(0, np.int32)))
-            return np.array(sess.items[:sess.length])
+            return np.array(sess.items[:sess.events])
+
+    def cached_length(self, uid: int) -> int:
+        """Events of ``uid`` whose rows are in the cache (0: no
+        session)."""
+        with self._sess_lock:
+            sess = self._sessions.get(int(uid))
+            return 0 if sess is None else int(sess.length)
 
     def last_hidden(self, uid: int) -> np.ndarray:
         """``uid``'s last hidden state (final norm applied), float32:
@@ -623,33 +766,19 @@ class SessionTopK(DeviceTopK):
     def audits(self, uid: int) -> List[Dict[str, Any]]:
         """What the lane computed for ``uid`` in the dispatches whose
         audit it still keeps (the latest ``audit`` of them; oldest
-        first), fetched from the device. An answer: ``length`` (the
-        events it reflects), ``scores`` (every item's, before the seen
-        mask) and, for the query's last event, ``layers`` (the residual
-        stream after every layer), ``selected`` (the positions a layer
-        attended over), ``lat`` / ``ik`` (the two cache rows a layer
-        wrote), ``picks`` / ``gates`` / ``h2`` (an expert layer's
-        router picks, their weights, and the router's input);
-        ``slot``, ``queries`` and ``bucket`` say where in which
-        dispatch it rode. Empty when the lane was built without
-        ``audit``."""
+        first), fetched from the device. GLM-5's answer: ``length``
+        (the events it reflects), ``scores`` (every item's, before the
+        seen mask) and, for the query's last event, ``layers`` (the
+        residual stream after every layer), ``selected`` (the
+        positions a layer attended over), ``lat`` / ``ik`` (the two
+        cache rows a layer wrote), ``picks`` / ``gates`` / ``h2`` (an
+        expert layer's router picks, their weights, and the router's
+        input); ``slot``, ``queries`` and ``bucket`` say where in which
+        dispatch it rode. SDAR's: ``ops/slates.py``. Empty when the
+        lane was built without ``audit``."""
         with self._sess_lock:
             kept = list(self._audits)
-        import jax
-
-        out = []
-        for audit, bb, rows in kept:
-            for slot, (u, length) in enumerate(rows):
-                if u != int(uid):
-                    continue
-                host = jax.device_get(audit)
-                got = {k: v[:, slot] for k, v in host.items()
-                       if k != "scores"}
-                got.update(scores=host["scores"][slot][:self.n_items],
-                           length=int(length), slot=slot,
-                           queries=len(rows), bucket=int(bb))
-                out.append(got)
-        return out
+        return self._bb.audits(self, kept, uid)
 
     def close(self) -> None:
         """Release the dispatcher AND the pool's device memory, and
@@ -657,10 +786,11 @@ class SessionTopK(DeviceTopK):
         serves nothing afterwards; the weights stay readable."""
         super().close()
         with self._sess_lock, self._store_lock:
-            for a in self._lat + self._ik:
-                if not a.is_deleted():
-                    a.delete()
-            self._lat = self._ik = ()
+            for arrays in self._pool.values():
+                for a in arrays:
+                    if not a.is_deleted():
+                        a.delete()
+            self._pool = {name: () for name in self._pool}
             self._sessions.clear()
             self._audits.clear()
             self._aot_programs.discard(lambda key: True)
@@ -675,36 +805,29 @@ class SessionTopK(DeviceTopK):
     def session_report(self) -> Dict[str, Any]:
         with self._sess_lock:
             held = self._n_blocks - 1 - len(self._free)
-            rows = dict(self._token_rows)
-            return {"sessions": len(self._sessions),
-                    "tokenRows": rows,
-                    # of the token rows dispatched, the share the
-                    # attend loop never runs
-                    "skippedRowShare": rows["padded"]
-                    / max(sum(rows.values()), 1),
-                    "blockTokens": self._bs,
-                    "cacheTokens": held * self._bs,
-                    "capacityTokens": (self._n_blocks - 1) * self._bs,
-                    "events": int(sum(s.length for s in
-                                      self._sessions.values())),
-                    "lengthBuckets": list(self._s_buckets),
-                    "residentSeconds": self._resident_s}
+            live = list(self._sessions.values())
+            return dict(
+                self._bb.report(self),
+                sessions=len(live), blockTokens=self._bs,
+                cacheTokens=held * self._bs,
+                capacityTokens=(self._n_blocks - 1) * self._bs,
+                events=int(sum(s.events for s in live)),
+                tailTokens=int(sum(s.events - s.length for s in live)),
+                lengthBuckets=list(self._s_buckets),
+                residentSeconds=self._resident_s)
 
     def memory_report(self) -> Dict[str, Any]:
         report = super().memory_report()
         with self._store_lock:
-            theta, lat, ik = self._theta, self._lat, self._ik
-        extra = {
-            "backbone": {"bytes": int(sum(v.nbytes for v in
-                                          theta.values())),
-                         "scaleBytes": 0,
-                         "dtype": self._spec.compute_dtype},
-            "sessionLatents": {"bytes": int(sum(a.nbytes for a in lat)),
-                               "scaleBytes": 0,
-                               "dtype": self._spec.compute_dtype},
-            "sessionIndexKeys": {"bytes": int(sum(a.nbytes for a in ik)),
-                                 "scaleBytes": 0,
-                                 "dtype": self._spec.compute_dtype}}
+            theta, pool = self._theta, self._pool
+        dtype = self._bb.compute_dtype
+        extra = {"backbone": {"bytes": int(sum(v.nbytes for v in
+                                               theta.values())),
+                              "scaleBytes": 0, "dtype": dtype}}
+        for name, _, component in self._bb.cache_rows:
+            extra[component] = {
+                "bytes": int(sum(a.nbytes for a in pool[name])),
+                "scaleBytes": 0, "dtype": dtype}
         report["components"].update(extra)
         report["totalBytes"] += sum(c["bytes"] for c in extra.values())
         report["sessions"] = self.session_report()

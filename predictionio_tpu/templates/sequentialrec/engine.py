@@ -106,6 +106,10 @@ class DataSourceParams(Params):
     eval_count: int = 0
 
 
+# blocks served from per-user caches (``ops/sessions.py``)
+SESSION_BLOCKS = ("glm_moe_dsa", "sdar_moe")
+
+
 class SequenceTrainingData:
     """Columnar (user, item, time) interaction triples in storage order
     — the Preparator does the time sort once, vectorized."""
@@ -311,11 +315,14 @@ class SeqRecModel(_DeviceServedModel):
     RE-ENCODE a user's sequence instead of re-solving a linear
     system.
 
-    A ``glm_moe_dsa`` block is SESSION-served instead
+    A ``glm_moe_dsa`` or ``sdar_moe`` block is SESSION-served instead
     (``ops/sessions.py::SessionTopK``): a backbone that wide has no
     user-vector table worth holding, so each user's history lives on
     the device as a cache (built at deploy from ``histories``) and a
-    query ``{"user": u, "items": [new events], "num": n}`` extends it.
+    query ``{"user": u, "items": [new events], "num": n}`` extends it
+    (``sdar_moe``: and generates a SLATE of ``n`` items by diffusion
+    over blocks: ``itemScores`` in slate order, each score the item's
+    confidence).
     With ``seededWeights`` (and ``numSteps: 0``) such a model carries
     an EMPTY ``theta`` and no vectors: the backbone's seeded initial
     weights are drawn on the device at deploy from
@@ -345,7 +352,7 @@ class SeqRecModel(_DeviceServedModel):
 
     @property
     def session_served(self) -> bool:
-        return self.enc_params.block == "glm_moe_dsa"
+        return self.enc_params.block in SESSION_BLOCKS
 
     # the fold-in consumer hands such a model the touched users' ids,
     # so that a fold APPENDS to their sessions
@@ -357,24 +364,24 @@ class SeqRecModel(_DeviceServedModel):
         if not self.session_served:
             return choose_server(self.user_vectors, self.item_vectors,
                                  self.seen)
-        from predictionio_tpu.ops import mla
         from predictionio_tpu.ops.seqrec import table_rows
-        from predictionio_tpu.ops.sessions import SessionTopK
+        from predictionio_tpu.ops.sessions import SessionTopK, backbone_of
 
         p = self.enc_params
         n_items = len(self.item_map)
+        backbone = backbone_of(p)
         if self.theta:
-            theta = mla.serving_theta(self.theta, mla.glm_spec(p))
+            theta = backbone.serving_theta(self.theta)
         elif p.seeded_weights:
-            theta = mla.draw_serving_theta(table_rows(n_items, p), p)
+            theta = backbone.draw_theta(table_rows(n_items, p), p)
         else:
-            raise ValueError("a glm_moe_dsa model without weights is "
+            raise ValueError(f"a {p.block} model without weights is "
                              "served only with seededWeights")
         return SessionTopK(theta["out_emb"][:n_items], theta, p,
                            n_users=len(self.user_map),
                            histories=self.histories, seen=self.seen,
                            pool_tokens=int(p.session_pool_tokens),
-                           audit=int(p.session_audit))
+                           audit=int(p.session_audit), backbone=backbone)
 
     def _device_theta(self):
         """Encoder params as DEVICE arrays, cached: the host-numpy
@@ -488,13 +495,13 @@ class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
         # stay on the device from the first step to the last encode
         # call; one transfer at the end brings model and vectors down.
         histories = None
-        if p.block == "glm_moe_dsa":
+        if p.block in SESSION_BLOCKS:
             histories = {u: np.asarray(q, dtype=np.int32)
                          for u, q in enumerate(pd.sequences) if len(q)}
             if int(p.num_steps) == 0:
                 if not p.seeded_weights:
                     raise ValueError(
-                        "numSteps 0 trains nothing: a glm_moe_dsa model "
+                        f"numSteps 0 trains nothing: a {p.block} model "
                         "is persisted without weights only with "
                         "seededWeights (its seeded initial weights are "
                         "then drawn at deploy)")
@@ -548,6 +555,8 @@ class SeqRecAlgorithm(_DeviceServingAlgo, P2LAlgorithm):
         events = [item_map[i] for i in query.items if i in item_map]
         black = {item_map[i] for i in query.blacklist if i in item_map}
         idx, scores = srv.sess_topk(uidx, events, query.num + len(black))
+        # (a slate comes back in position order, a top-k by score: the
+        # result keeps the order it is given)
         return _winners_to_result(idx, scores, black, query.num, item_map,
                                   positive_only=False)
 

@@ -310,6 +310,16 @@ def note_select_rounds(rounds: Optional[int]) -> None:
     metrics.TOPK_SELECT_ROUNDS.inc(rounds, lane=rec["lane"])
 
 
+def note_fields(**fields) -> None:
+    """Fields a lane read out of its fetched result, on the record this
+    thread wrote last (a slate round's ``passes``, ``tokensUnmasked``,
+    ``carried``, ``lengthBucket``). A killed recorder changes
+    nothing."""
+    rec = RECORDER.last() if RECORDER.enabled else None
+    if rec is not None:
+        rec.update(fields)
+
+
 def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
                     k_bucket: int, batch: int, bucket: int,
                     host_us: float, device_us: float,

@@ -997,6 +997,46 @@ SESS_EVICTIONS = REGISTRY.counter(
     "pio_sess_evictions_total",
     "Sessions whose cache blocks were released to make room (their "
     "events stay on the host; the next touch prefills them again)", ())
+SESS_TAIL_TOKENS = REGISTRY.gauge(
+    "pio_sess_tail_tokens",
+    "Events the live sessions hold as ids only: the newest events of a "
+    "backbone that commits cache rows in whole blocks, waiting for "
+    "their block to fill", ())
+
+# -- slates generated by block diffusion (ops/slates.py) ----------------------
+SLATE_ROUNDS = REGISTRY.counter(
+    "pio_slate_rounds_total",
+    "Blocks of slates decoded: one query row of one round dispatch "
+    "(a slate of n items after a tail of t events is ceil((t + n) / "
+    "block) rounds)", ())
+SLATE_PASSES = REGISTRY.counter(
+    "pio_slate_passes_total",
+    "Forward passes over a block, by kind: query (a query row's own "
+    "denoising passes and its commit pass, summed over rows) and device "
+    "(passes a round dispatch ran: its longest row's, and the commit)",
+    ("kind",))
+SLATE_TOKENS_UNMASKED = REGISTRY.counter(
+    "pio_slate_tokens_unmasked_total",
+    "Slate positions unmasked (items generated), summed over queries",
+    ())
+SLATE_CARRIED = REGISTRY.counter(
+    "pio_slate_carried_queries_total",
+    "Query rows a round handed back to the dispatcher for their next "
+    "block", ())
+SLATE_SCRATCH_BLOCKS = REGISTRY.gauge(
+    "pio_slate_scratch_blocks",
+    "Pool blocks that hold the generated blocks' cache rows of queries "
+    "in flight (one a query, given back at its end)", ())
+SLATE_CACHE_ROWS_READ = REGISTRY.counter(
+    "pio_slate_cache_rows_read_total",
+    "Cached rows (committed and scratch) a pass had to read, summed "
+    "over query rows and device passes: one key and one value row a "
+    "layer each", ())
+SLATE_EXPERTS_TOUCHED = REGISTRY.counter(
+    "pio_slate_experts_touched_total",
+    "Experts a valid token row picked in a pass, summed over layers, "
+    "passes and round dispatches: whose weights the mathematics has "
+    "to read", ())
 
 
 

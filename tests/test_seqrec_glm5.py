@@ -259,8 +259,8 @@ def test_cache_manager_allocates_grows_evicts_and_keeps_sessions_apart():
     # 16 blocks of 4: the three histories need 3 + 6 + 8 = 17
     srv = server(params, theta, hist, pool_tokens=64, microbatch=False)
     g = srv._spec
-    assert srv._lat[0].shape == (17, 4, g.lat_width)     # block 0: spare
-    assert srv._ik[0].shape == (17, 4, g.idx_dim)        # two kinds, one table
+    assert srv._pool["lat"][0].shape == (17, 4, g.lat_width)     # block 0: spare
+    assert srv._pool["ik"][0].shape == (17, 4, g.idx_dim)        # two kinds, one table
     srv.sess_topk(0, [], 5)
     srv.sess_topk(1, [], 5)
     rep = srv.session_report()
@@ -291,10 +291,9 @@ def test_cache_manager_allocates_grows_evicts_and_keeps_sessions_apart():
     poison = np.ones(17, bool)
     poison[mine] = False
     with srv._store_lock:
-        srv._lat = tuple(jnp.where(poison[:, None, None], 1e4, a)
-                         for a in srv._lat)
-        srv._ik = tuple(jnp.where(poison[:, None, None], 1e4, a)
-                        for a in srv._ik)
+        srv._pool = {name: tuple(jnp.where(poison[:, None, None], 1e4, a)
+                                 for a in rows)
+                     for name, rows in srv._pool.items()}
     srv.sess_topk(1, [], 5)
     srv.sess_topk(1, history(1, 11), 5)
     full = np.concatenate([full, history(1, 11)])
@@ -336,7 +335,7 @@ def test_audits_are_kept_only_when_asked_and_only_of_watched_users(keep):
     assert len(srv._free) == free and sorted(srv._sessions) == [0, 1]
     np.testing.assert_array_equal(np.asarray(srv._X, np.float32), rows)
     srv.close()
-    assert srv._lat == ()
+    assert srv._pool == {"lat": (), "ik": ()}
 
 
 def test_ladder_is_complete_after_warm_up():
