@@ -18,11 +18,14 @@ Latent attention comes in two forms that give the same numbers:
 - ABSORBED (:func:`extend_step`, :func:`prefill_chunk`, the served
   programs): ``W_kvb``'s key half is multiplied into the query and its
   value half into the output, and attention runs over the latents as
-  they lie in the cache. :func:`extend_step` attends through
-  :func:`mla_sparse_attend`, the lane's only attend: a loop over the
-  VALID new tokens that gathers one token's selected latents at a time
-  (operands: the absorbed queries, the layer's pool, the selected pool
-  rows with their mask, the new events a query brought);
+  they lie in the cache. :func:`extend_step` cuts and attends through
+  :func:`mla_select_attend`, the lane's only attend: a loop over the
+  VALID new tokens that takes one token's cut (:func:`index_cut`, a
+  kernel: its ``index_topk`` best positions out of its index scores)
+  and gathers and attends its selected latents (operands: the absorbed
+  queries, the index scores, the block tables, the layer's pool, the
+  new events a query brought), so that a padded token row is neither
+  cut nor attended;
   :func:`prefill_chunk` masks a dense product a block of queries at a
   time (2,048 tokens a chunk, where a gather of 2,048 latents a token
   would not fit).
@@ -34,6 +37,7 @@ The float32 reference of the same equations is
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, List, Tuple
 
@@ -520,53 +524,225 @@ def absorbed_output(theta, i: int, ol, spec: GlmSpec):
     return _mm(o.reshape(o.shape[0], -1), theta[f"l{i}_wo"], spec)
 
 
-def mla_sparse_attend(qf, pool, phys, ok, n_new, spec: GlmSpec):
-    """Attention of ``B x T`` token rows over the latents each selected,
-    read where they lie in the pool: ``qf [B, T, H, lat_width]`` (the
-    absorbed queries), ``pool [rows, lat_width]`` (the layer's latent
-    rows AFTER this dispatch's were written), ``phys [B, T, K]`` int32
-    (the pool rows a token attends over) and ``ok [B, T, K]`` (which of
-    them count), ``n_new [B]``. Returns ``[B, T, H, lat_width]``
-    float32, ``softmax(scale qf . rows) rows`` in ``extend_step``'s
-    precision, and ZEROS for every token row ``t >= n_new[b]``.
+def _shift_left(a, s: int, lane):
+    """``out[x] = a[x + s]`` in the flat order of a ``[R, 128]`` tile
+    (circular): whole rows by a sublane rotation, lanes by a lane
+    rotation whose wrapped lanes come from the next row."""
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
 
-    One loop over the VALID token rows alone (their count is the trip
-    count, so a padded row costs nothing): a step gathers its row's
-    ``K`` latents (``[K, lat_width]``: 2.6 MB as published, where all
-    rows at once were ``[B, T, K, lat_width]``, 168 MB at B 8, written
-    and read twice), and its two products are plain matrix products
-    (``[H, width] x [width, K]``), which the batched form over ``(b,
-    t)`` was not.
+    R = a.shape[0]
+    if s % LANES == 0:
+        return pltpu.roll(a, (R - s // LANES) % R, 0)
+    y = pltpu.roll(a, LANES - s, 1)
+    return jnp.where(lane < LANES - s, y, pltpu.roll(y, R - 1, 0))
+
+
+def _index_cut_kernel(sc_ref, rows_ref, idx_ref, out_ref, *, K: int):
+    """One token row's cut, whole in VMEM. ``sc_ref [R, 128]`` float32
+    (position ``128 r + l``), ``rows_ref`` the pool row behind each
+    position; ``idx_ref`` / ``out_ref`` ``[>= K / 128, 128]``: the kept
+    positions in rising order (-1 past the last) and their pool rows.
+
+    Three steps, none a sort: (1) the ``K``-th largest score as
+    :func:`kth_largest` finds it (the floats as integers of the same
+    order, the answer a bit a counting pass), and where scores tie
+    with it the tied positions' lowest, by 17 more counting passes
+    over the positions; (2) every kept position's rank, by two
+    products with triangles of ones (counts within a 128-lane row,
+    then over the rows before); (3) each kept position moved left by
+    the number of holes before it, a bit of that distance a pass,
+    lowest bit first, which keeps their order and lets no two
+    meet."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas import tpu as pltpu
+
+    lax = jax.lax
+    R = sc_ref.shape[0]
+    n_out = idx_ref.shape[0]
+    i32 = jnp.int32
+    sign, none = i32(-2 ** 31), i32(0x807FFFFF - 2 ** 32)   # (-inf's key)
+    bits = pltpu.bitcast(sc_ref[...], i32)
+    key = jnp.where(bits >= 0, bits, bits ^ i32(0x7FFFFFFF))
+    lane = lax.broadcasted_iota(i32, (R, LANES), 1)
+    pos = lax.broadcasted_iota(i32, (R, LANES), 0) * LANES + lane
+
+    def count(mask):
+        return jnp.sum(mask.astype(jnp.float32)).astype(i32)
+
+    def score_bit(i, ans):
+        cand = ans | lax.shift_left(i32(1), i32(31) - i)
+        return jnp.where(count(key >= (cand ^ sign)) >= K, cand, ans)
+
+    kth = lax.fori_loop(0, 32, score_bit, i32(0)) ^ sign
+    above = key > jnp.maximum(kth, none)
+    tied = (key == kth) & (kth > none)
+    room = K - count(above)
+
+    def position_bit(i, ans):
+        cand = ans | lax.shift_left(i32(1), i32(16) - i)
+        return jnp.where(count(tied & (pos < cand)) <= room, cand, ans)
+
+    keep = above | (tied & (pos < lax.fori_loop(0, 17, position_bit,
+                                                i32(0))))
+    ones = keep.astype(jnp.bfloat16)
+    upto = (lax.broadcasted_iota(i32, (LANES, LANES), 0)
+            <= lax.broadcasted_iota(i32, (LANES, LANES), 1))
+    in_row = jnp.dot(ones, upto.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    rows_before = (lax.broadcasted_iota(i32, (R, R), 1)
+                   < lax.broadcasted_iota(i32, (R, R), 0))
+    before = jnp.dot(
+        rows_before.astype(jnp.bfloat16),
+        jnp.broadcast_to(in_row[:, LANES - 1:], (R, LANES)).astype(
+            jnp.bfloat16), preferred_element_type=jnp.float32)
+    rank = (before + in_row).astype(i32) - 1
+    at = jnp.where(keep, pos, -1)
+    holes = jnp.where(keep, pos - rank, 0)
+    rows = rows_ref[...]
+    s = 1
+    while s < R * LANES:
+        moves = (at >= 0) & ((holes & s) != 0)
+        lands = _shift_left(moves.astype(i32), s, lane) != 0
+        at = jnp.where(lands, _shift_left(at, s, lane),
+                       jnp.where(moves, -1, at))
+        holes = jnp.where(lands, _shift_left(holes, s, lane), holes)
+        rows = jnp.where(lands, _shift_left(rows, s, lane), rows)
+        s *= 2
+    idx_ref[...] = at[:n_out]
+    out_ref[...] = jnp.where(at[:n_out] >= 0, rows[:n_out], 0)
+
+
+def index_cut(scores, rows, K: int, *, interpret: bool):
+    """The indexer's cut of ONE token row, exactly: ``scores [S]``
+    float32 (-inf: not eligible), ``rows [S]`` int32 (the pool row
+    behind each position) -> ``(idx [K], kept [K])``: the positions of
+    the ``K`` largest scores in rising order (fewer where fewer are
+    eligible, then -1; of the scores tied with the ``K``-th the lowest
+    positions) and their pool rows (0 past the last). A Pallas TPU
+    kernel (:func:`_index_cut_kernel`; ``interpret``: off the TPU): a sort
+    of one row costs the chip what a sort of eight does, and XLA's
+    counting passes and compaction a fusion each (PERF.md section 6,
+    PR 34)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    S = scores.shape[0]
+    n_out = -(-K // LANES)
+    # whole float32 tiles of 8 x 128, and room for the K outputs
+    padded = -(-max(S, n_out * LANES) // (8 * LANES)) * 8 * LANES
+    scores = jnp.pad(scores.astype(jnp.float32), (0, padded - S),
+                     constant_values=-jnp.inf)
+    rows = jnp.pad(rows.astype(jnp.int32), (0, padded - S))
+    shape = jax.ShapeDtypeStruct((n_out, LANES), jnp.int32)
+    idx, kept = pl.pallas_call(
+        functools.partial(_index_cut_kernel, K=K), out_shape=(shape, shape),
+        interpret=interpret, name="dsa_index_cut",
+    )(scores.reshape(-1, LANES), rows.reshape(-1, LANES))
+    return idx.reshape(-1)[:K], kept.reshape(-1)[:K]
+
+
+def mla_select_attend(qf, I, table, pool, n_new, *, spec: GlmSpec,
+                      bs: int, audit: bool = False):
+    """:func:`_select_attend` as ONE jitted function of its shapes: a
+    program's layers then share one trace and one lowering of the loop
+    and its kernel (a layer's cost the host 0.06 s to trace and lower,
+    and a deploy lowers twelve programs of six layers)."""
+    import jax
+
+    # (off the TPU the kernel runs interpreted)
+    return _select_attend_program(
+        spec, bs, audit, jax.default_backend() != "tpu")(
+            qf, I, table, pool, n_new)
+
+
+@functools.lru_cache(maxsize=None)
+def _select_attend_program(spec: GlmSpec, bs: int, audit: bool,
+                           interpret: bool):
+    import jax
+
+    return jax.jit(functools.partial(
+        _select_attend, spec=spec, bs=bs, audit=audit, interpret=interpret))
+
+
+def _select_attend(qf, I, table, pool, n_new, *, spec: GlmSpec, bs: int,
+                   audit: bool, interpret: bool):
+    """The indexer's cut and the attention over what it keeps, for the
+    VALID token rows of ``B x T`` alone: ``qf [B, T, H, lat_width]``
+    (the absorbed queries), ``I [B, T, S]`` float32 (a token's index
+    scores over its session's positions, -inf where it may not look),
+    ``table [B, S / bs]`` int32 (the pool block behind each ``bs``
+    positions of a query's session), ``pool [rows, lat_width]`` (the
+    layer's latent rows AFTER this dispatch's were written), ``n_new
+    [B]``. Returns ``(out, kept, selected)``: ``out [B, T, H,
+    lat_width]`` float32, ``softmax(scale qf . rows) rows`` over the
+    ``index_topk`` best-scored positions of each row (all of them
+    where fewer have a score) in ``extend_step``'s precision, and
+    ZEROS for every token row ``t >= n_new[b]``; ``kept``, the
+    positions kept summed over the valid rows (int32); with ``audit``
+    ``selected [B, K]``, the positions each query's LAST valid row
+    kept (-1: none, all of them for a query that brought nothing),
+    else None.
+
+    One loop over the valid token rows (their count is the trip count,
+    so a padded row is never cut, looked up, gathered or scored). A
+    step cuts its row (scope ``sess/select``: the ``K`` best of ``S``
+    scores, exactly, and their pool rows out of the block table) and
+    attends it (scope ``sess/attend``: the row's ``K`` latents
+    gathered, ``[K, lat_width]``, 2.6 MB as published, and two plain
+    matrix products ``[H, width] x [width, K]``); the two scopes stand
+    side by side, never one inside the other, so that a trace books
+    the cut to the selection and the gather to the attention.
 
     It is XLA's own gather and not a Pallas kernel because the chip's
     compiler refuses a copy out of a tiled HBM array that is not whole
-    tiles of 8 rows: a kernel fetching each selected row's group of 8
-    moved 10 KB a row and took 69-75 ns a valid row, one streaming a
-    session's whole blocks under a mask costs the session's length and
-    not ``K``, and this loop takes 17-36 (PERF.md section 6, PR 31)."""
+    tiles of 8 rows (PERF.md section 6, PR 31); how a row is cut was
+    settled on the chip (PERF.md section 6, PR 34)."""
     import jax
     import jax.numpy as jnp
 
     B, T, H, W = qf.shape
-    qf, phys, ok = (a.reshape((B * T,) + a.shape[2:])
-                    for a in (qf, phys, ok))
+    S = I.shape[-1]
+    K = min(spec.idx_topk, S)
+    qf = qf.reshape(B * T, H, W)
+    I = I.reshape(B * T, S)
     valid = (jnp.arange(T)[None, :] < n_new[:, None]).reshape(-1)
     order = jnp.argsort(~valid, stable=True)        # the valid rows first
+    with jax.named_scope("sess/select"):
+        # the pool row behind every position of a QUERY's session
+        rows_of = (table[:, :, None] * bs
+                   + jnp.arange(bs, dtype=jnp.int32)).reshape(B, S)
 
-    def one(i, out):
+    def one(i, carry):
+        out, kept, selected = carry
         r = order[i]
-        row = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
-            a, r, keepdims=False)
-        g = jnp.take(pool, row(phys), axis=0, mode="clip")      # [K, W]
-        s = _ein("hc,kc->hk", row(qf), g, spec) * spec.scale
-        a = jax.nn.softmax(jnp.where(row(ok)[None, :], s, -jnp.inf),
-                           axis=-1)
-        return jax.lax.dynamic_update_index_in_dim(
-            out, _ein("hk,kc->hc", a, g, spec), r, 0)
+        b = r // T
+        row = lambda a, j=r: jax.lax.dynamic_index_in_dim(  # noqa: E731
+            a, j, keepdims=False)
+        with jax.named_scope("sess/select"):
+            idx, phys = index_cut(row(I), row(rows_of, b), K,
+                                  interpret=interpret)
+            ok = idx >= 0
+            kept = kept + jnp.sum(ok)
+            if audit:
+                selected = jax.lax.dynamic_update_index_in_dim(
+                    selected, jnp.where(r % T == n_new[b] - 1, idx,
+                                        row(selected, b)), b, 0)
+        with jax.named_scope("sess/attend"):
+            g = jnp.take(pool, phys, axis=0, mode="clip")       # [K, W]
+            s = _ein("hc,kc->hk", row(qf), g, spec) * spec.scale
+            a = jax.nn.softmax(jnp.where(ok[None, :], s, -jnp.inf), axis=-1)
+            out = jax.lax.dynamic_update_index_in_dim(
+                out, _ein("hk,kc->hc", a, g, spec), r, 0)
+        return out, kept, selected
 
-    out = jax.lax.fori_loop(0, jnp.sum(valid), one,
-                            jnp.zeros((B * T, H, W), jnp.float32))
-    return out.reshape(B, T, H, W)
+    out, kept, selected = jax.lax.fori_loop(
+        0, jnp.sum(valid), one,
+        (jnp.zeros((B * T, H, W), jnp.float32), jnp.int32(0),
+         jnp.full((B, K) if audit else (), -1, jnp.int32)))
+    return out.reshape(B, T, H, W), kept, selected if audit else None
 
 
 def _new_bits(tok, valid, words: int):
@@ -604,9 +780,11 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
     table]``; ``lat`` / ``ik``: per layer the
     latent and index-key pools ``[blocks, bs, width]``; ``X``: the
     users' last hidden states (the store's user table); ``Y``: the
-    output table. Every layer attends over the selected latents
-    through :func:`mla_sparse_attend` alone (a padded token row is
-    never gathered or scored). Returns the packed top-k, the new ``X``,
+    output table. Every layer scores the index keys for the whole
+    bucket (``[B, T, S]``, one batched product) and then cuts and
+    attends through :func:`mla_select_attend` alone (a padded token
+    row is never cut, gathered or scored). Returns the packed top-k,
+    the new ``X``,
     ``seen_bits``, ``lat``, ``ik`` and, compiled with ``audit``, what a
     check compares (else None): every item's ``scores`` ``[B, items]``
     and, for each row's last new event, ``layers`` ``[n_layers, B,
@@ -635,7 +813,6 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
     tpos = len0[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]
     tvalid = jnp.arange(T)[None, :] < n_new[:, None]
     last = jnp.maximum(n_new - 1, 0)
-    K = min(spec.idx_topk, S)
     x = jnp.take(theta["item_emb"], tok, axis=0).astype(jnp.float32)
     lat, ik = list(lat), list(ik)
     kept = {k: [] for k in ("layers", "selected", "lat", "ik", "picks",
@@ -664,17 +841,13 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
                  p["w"].reshape(B, T, spec.idx_heads), kis))
             I = jnp.where(s_ar[None, None, :] <= tpos[:, :, None], I,
                           -jnp.inf)
-        with jax.named_scope("sess/select"):
-            vals, idx = jax.lax.top_k(I, K)
-            ok = vals > -jnp.inf
-            blk = jnp.take_along_axis(
-                table, (idx // bs).reshape(B, T * K), axis=1).reshape(
-                    B, T, K)
-            phys = blk * bs + idx % bs
         with jax.named_scope("sess/attend"):
             qf = absorbed_query(theta, i, p, spec).reshape(
                 B, T, spec.n_heads, spec.lat_width)
-            ol = mla_sparse_attend(qf, lat_i, phys, ok, n_new, spec)
+        # (the loop opens its own scopes: its cut is the selection's)
+        ol, n_kept, last_kept = mla_select_attend(
+            qf, I, table, lat_i, n_new, spec=spec, bs=bs, audit=audit)
+        with jax.named_scope("sess/attend"):
             x = x + absorbed_output(
                 theta, i, ol.reshape(B * T, spec.n_heads, -1),
                 spec).reshape(B, T, D)
@@ -685,12 +858,11 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
         take_last = lambda a: jnp.take_along_axis(  # noqa: E731
             a, last.reshape((B, 1) + (1,) * (a.ndim - 2)), axis=1)[:, 0]
         eligible = jnp.minimum(tpos + 1, S).astype(jnp.float32)
-        share_n += jnp.sum(jnp.where(tvalid, jnp.sum(ok, -1), 0))
+        share_n += n_kept
         share_d += jnp.sum(jnp.where(tvalid, eligible, 0.0))
         if audit:
             kept["layers"].append(take_last(x))
-            kept["selected"].append(
-                jnp.where(take_last(ok), take_last(idx), -1))
+            kept["selected"].append(last_kept)
             kept["lat"].append(take_last(rl.reshape(B, T, -1)))
             kept["ik"].append(take_last(rk.reshape(B, T, -1)))
         if routed is not None:

@@ -343,9 +343,10 @@ class GlmBackbone:
         return out
 
     def report(self, m: "SessionTopK") -> Dict[str, Any]:
+        """``tokenRows``: the token rows dispatched, valid and padded;
+        ``skippedRowShare``: the share of them that the loop which cuts
+        and attends never runs (``mla.mla_select_attend``)."""
         rows = dict(m._token_rows)
-        # of the token rows dispatched, the share the attend loop
-        # never runs
         return {"tokenRows": rows, "skippedRowShare": rows["padded"]
                 / max(sum(rows.values()), 1)}
 

@@ -961,7 +961,8 @@ SESS_TOKEN_ROWS = REGISTRY.counter(
     "pio_sess_token_rows_total",
     "Token rows the extend programs were dispatched with (query bucket "
     "x events a query may bring), by kind: valid rows carry a new "
-    "event; padded rows are the ones the attend loop never runs",
+    "event; padded rows are the ones the loop that cuts and attends "
+    "never runs",
     ("kind",))
 SESS_CACHE_TOKENS = REGISTRY.gauge(
     "pio_sess_cache_tokens",

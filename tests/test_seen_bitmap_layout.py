@@ -510,6 +510,7 @@ def test_v5e_extend_program_gathers_a_token_row_at_a_time(S, one_chip,
     ``seqrec-glm5.sess-extend``: 8 queries x 8 events, a pool of
     458,752 rows): the latents of all 64 token rows are never gathered
     at once (168 MB a layer), a layer's attend is a loop whose step
+    cuts one row (``mla.index_cut``, compiled by Mosaic here) and
     holds one row's ``[K, 640]``, no pool is copied (587 MB a layer),
     the pools stay donated, and the temporaries are under those of the
     gathered form."""
@@ -562,6 +563,11 @@ def test_v5e_extend_program_gathers_a_token_row_at_a_time(S, one_chip,
     assert f"bf16[{B},{T},{K},{W}]" not in hlo
     assert f"bf16[{B * T},{K},{W}]" not in hlo
     assert f"bf16[{K},{W}]" in hlo               # one token row's latents
+    # the cut is the kernel's, a row a loop step (PR 34): nothing sorts
+    # the bucket's index scores
+    assert "dsa_index_cut" in hlo
+    assert not [ln for ln in hlo.splitlines() if " sort(" in ln and (
+        f"f32[{B},{T},{S}]" in ln or f"f32[{B * T},{S}]" in ln)]
     pool_rows = rf"bf16\[(?:{nb},{bs}|{nb * bs}),{W}\]"
     copies = re.findall(rf"= ({pool_rows}\S*) copy\(", hlo)
     assert not copies, f"a latent pool is copied: {copies}"

@@ -379,8 +379,8 @@ def test_ladder_is_complete_after_warm_up():
 def test_token_rows_are_counted_valid_and_padded():
     """``pio_sess_token_rows_total``: a dispatch is ``query bucket x
     SESS_EVENTS`` token rows, those with a new event and the padded
-    ones the attend loop never runs; ``session_report()`` says what
-    share was skipped."""
+    ones the loop that cuts and attends never runs;
+    ``session_report()`` says what share was skipped."""
     from predictionio_tpu.utils import metrics
 
     params, _, theta, _ = build()
