@@ -929,7 +929,7 @@ class _BatchResult:
     hundred-query batch does not serialize a hundred numpy filters
     behind one thread."""
 
-    __slots__ = ("idx", "scores", "telemetry")
+    __slots__ = ("idx", "scores", "telemetry", "lives", "delivered")
 
     def __init__(self, idx: np.ndarray, scores: np.ndarray,
                  telemetry: Optional[Dict[str, Any]] = None):
@@ -940,6 +940,12 @@ class _BatchResult:
         # handler threads attach it to their device.* trace span, so a
         # slow query's exemplar names its bucket/fill/kernel/AOT fate
         self.telemetry = telemetry
+        # what a batching dispatcher stamped of every delivered query
+        # (:meth:`_Pending.life`, a row each) and when it resolved
+        # their futures, on the span clock: the waiter's wake-up runs
+        # from there (None from a direct caller or with telemetry off)
+        self.lives: Optional[List[Dict[str, Any]]] = None
+        self.delivered: Optional[float] = None
 
     def render(self, row: int, k: int) -> Tuple[np.ndarray, np.ndarray]:
         ri = self.idx[row, :k]
@@ -954,10 +960,22 @@ class _Pending:
     future the waiting thread blocks on. ``arrival`` (monotonic) feeds
     the flight recorder's queue-wait figure; ``ctx`` carries the
     submitting thread's trace context so the dispatcher thread can
-    parent the ``device.execute`` span under a real query trace."""
+    parent the ``device.execute`` span under a real query trace.
+
+    The query's LIFE is stamped by the dispatcher thread alone, on the
+    monotonic clock, with telemetry on: :meth:`claim` when a group
+    takes it, :meth:`handed_back` when its lane returns it unfinished,
+    :meth:`life` at delivery. ``first_wait`` (arrival to the first
+    claim), ``riding`` (claim to the dispatch function's return, summed
+    over its ``rounds``; to delivery in the last) and ``between``
+    (hand-back to the next claim: other lanes' turns, newcomers'
+    opening dispatches, a wait unopened behind an earlier query of its
+    user) add up to delivery less arrival. ``claimed`` is None until a
+    dispatcher claims it (a direct caller's never is)."""
 
     __slots__ = ("payload", "k", "deadline", "seq", "future", "arrival",
-                 "ctx")
+                 "ctx", "claimed", "rounds", "first_wait", "riding",
+                 "between", "back_at")
 
     def __init__(self, payload, k: int, deadline: float, seq: int,
                  arrival: float, ctx=None):
@@ -968,9 +986,32 @@ class _Pending:
         self.arrival = arrival
         self.ctx = ctx
         self.future: Future = Future()
+        self.claimed: Optional[float] = None
+        self.rounds = 0
+        self.first_wait = self.riding = self.between = self.back_at = 0.0
 
     def __lt__(self, other: "_Pending") -> bool:
         return (self.deadline, self.seq) < (other.deadline, other.seq)
+
+    def claim(self, now: float) -> None:
+        if self.claimed is None:
+            self.first_wait = now - self.arrival
+        else:
+            self.between += now - self.back_at
+        self.claimed = now
+        self.rounds += 1
+
+    def handed_back(self, now: float) -> None:
+        self.riding += now - self.claimed
+        self.back_at = now
+
+    def life(self, now: float) -> Dict[str, Any]:
+        """The query's record at its delivery (``now``)."""
+        return {"firstWaitUs": round(self.first_wait * 1e6, 1),
+                "rounds": self.rounds,
+                "ridingUs": round(
+                    (self.riding + now - self.claimed) * 1e6, 1),
+                "betweenUs": round(self.between * 1e6, 1)}
 
 
 class _Running:
@@ -1034,11 +1075,22 @@ class BatchLane:
         after the PR-7 queue deadline. ``span`` (a live trace
         :class:`~predictionio_tpu.utils.tracing.Span`) receives the
         dispatch's flight record as a ``dispatch`` attribute — how slow
-        query exemplars get their bucket/fill/kernel/AOT context."""
+        query exemplars get their bucket/fill/kernel/AOT context — and
+        beside it the query's own ``life`` (:meth:`_Pending.life`) and
+        ``wakeUs``, the time from the dispatcher resolving the future
+        to this thread running again, which the span's stage summary
+        reports as ``device.wake`` (a ``device.*`` name: part of the
+        wait for the answer, not of the handler's own work; an
+        attribute and not a child span, which cost the median of the
+        busiest cell more than a percent: PERF.md section 6, PR 36)."""
         k = int(k)
         res, row = self._d.submit_wait(self, payload, k)
         if span is not None and res.telemetry is not None:
             span.attributes["dispatch"] = res.telemetry
+            if res.lives is not None:
+                span.attributes["life"] = res.lives[row]
+                span.attributes["wakeUs"] = round(
+                    (_tracing.span_now() - res.delivered) * 1e6, 1)
         return res.render(row, k)
 
     def submit_async(self, payload, k: int,
@@ -1330,10 +1382,11 @@ class BatchDispatcher:
     def _run(self) -> None:
         _dtel.mark_ready()
         while True:
-            self._wake.clear()
-            self._drain_handoff()
-            now = time.monotonic()
-            lane, trigger = self._pick(now)
+            with _dtel.stage("pickUs", "batch.pick"):
+                self._wake.clear()
+                self._drain_handoff()
+                now = time.monotonic()
+                lane, trigger = self._pick(now)
             if lane is not None:
                 self._dispatch(lane, trigger)
                 continue
@@ -1382,22 +1435,26 @@ class BatchDispatcher:
                 raise RuntimeError("serving backend was released")
             if _dtel.enabled():
                 # batching context the device dispatch site cannot see:
-                # the oldest grouped query's queue wait and the
-                # group's mean, the group size, and a trace parent (the
-                # dispatcher thread has no ambient trace of its own —
-                # borrow the first traced query's so the device.execute
-                # span lands in a tree)
+                # the age of the oldest grouped query (its earlier
+                # rounds included, in a lane that hands queries back),
+                # the group size, and a trace parent (the dispatcher
+                # thread has no ambient trace of its own — borrow the
+                # first traced query's so the device.execute span lands
+                # in a tree); every query's own life is stamped here
                 now = time.monotonic()
-                arrivals = [it.arrival for it in group]
-                wait = max(0.0, now - min(arrivals))
-                mean = max(0.0, now - sum(arrivals) / len(arrivals))
+                for it in group:
+                    it.claim(now)
+                wait = max(0.0, now - min(it.arrival for it in group))
                 parent = next((it.ctx for it in group
                                if it.ctx is not None), None)
                 with _dtel.dispatch_scope(queue_wait_us=wait * 1e6,
                                           group=len(group),
-                                          trace_parent=parent,
-                                          queue_wait_mean_us=mean * 1e6):
+                                          trace_parent=parent):
                     back = lane.dispatch_fn(srv, group)
+                if back:
+                    now = time.monotonic()
+                    for it in back:
+                        it.handed_back(now)
             else:
                 back = lane.dispatch_fn(srv, group)
         except BaseException as e:  # propagate to every waiter
@@ -1431,7 +1488,8 @@ class BatchDispatcher:
             metrics.MICROBATCH_QUEUE_AT_DISPATCH.observe(
                 depth, batcher=lane.name)
         if back is not None:
-            self._carry(lane, back)
+            with _dtel.stage("pickUs", "batch.pick"):
+                self._carry(lane, back)
 
     def _carry(self, lane: BatchLane, back: List[_Pending]) -> None:
         """``back``: the queries of the group just dispatched that
@@ -1455,11 +1513,18 @@ def _deliver(group: List[_Pending], idx: np.ndarray,
     """Resolve every waiter's future with the shared result (rendering
     happens on the waiting threads). The dispatch just recorded on THIS
     thread (telemetry on) rides along, so a waiter's ``device.*`` span
-    names its bucket, fill and stage stamps."""
+    names its bucket, fill and stage stamps; the lives of the queries
+    a dispatcher claimed are closed here, the one place every lane
+    delivers through, and join that record's ``lives`` in the group's
+    order."""
     with _dtel.stage("deliverUs", "batch.deliver", done=True):
-        res = _BatchResult(idx, scores,
-                           telemetry=_dtel.last_record()
-                           if _dtel.enabled() else None)
+        rec = _dtel.last_record() if _dtel.enabled() else None
+        res = _BatchResult(idx, scores, telemetry=rec)
+        if rec is not None and group and group[0].claimed is not None:
+            now = time.monotonic()
+            res.lives = [it.life(now) for it in group]
+            rec.setdefault("lives", []).extend(res.lives)
+            res.delivered = _tracing.span_now()
         for row, it in enumerate(group):
             if not it.future.done():
                 it.future.set_result((res, row))
@@ -2512,12 +2577,14 @@ class DeviceTopK:
             aot=aot, k_bucket=int(entry[1]), batch=batch, bucket=bucket,
             host_us=(t2m - t0m) * 1e6, device_us=(t2m - t1m) * 1e6,
             lock_wait_us=(t_locked - tl) * 1e6,
-            locked_us=(t0m - t_locked) * 1e6, called=t0m, ready=t2m)
-        ctx = _dtel.current_dispatch_context() or {}
-        _tracing.record_completed_span(
-            "device.execute", start=t0e, end=t0e + (t2m - t0m),
-            attributes=None if rec is None else dict(rec),
-            parent=ctx.get("traceParent"))
+            locked_us=(t0m - t_locked) * 1e6, called=t0m, ready=t2m,
+            called_ts=t0e)
+        with _dtel.stage("bookUs", "batch.book", done=True):
+            ctx = _dtel.current_dispatch_context() or {}
+            _tracing.record_completed_span(
+                "device.execute", start=t0e, end=t0e + (t2m - t0m),
+                attributes=None if rec is None else dict(rec),
+                parent=ctx.get("traceParent"))
         return out
 
     def _ladder_program_locked(self, entry: Tuple, fallback):

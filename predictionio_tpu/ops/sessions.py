@@ -125,26 +125,28 @@ def _dispatch_sess_group(srv: "SessionTopK",
     step is a query's (at most ``SESS_EVENTS``) new events; a query
     with more is cut into steps of which only the last answers."""
 
-    kmax = max(it.k for it in group)
-    kb = srv._sess_kb(kmax)
-    steps: Dict[int, List[Tuple[np.ndarray, Optional[int]]]] = {}
-    for row, it in enumerate(group):
-        uid, items = it.payload
-        mine = steps.setdefault(int(uid), [])
-        items = np.asarray(items, dtype=np.int32)
-        cuts = list(range(0, max(len(items), 1), SESS_EVENTS))
-        for j, a in enumerate(cuts):
-            mine.append((items[a:a + SESS_EVENTS],
-                         row if j == len(cuts) - 1 else None))
-    idx = np.zeros((len(group), kb), dtype=np.int32)
-    scores = np.full((len(group), kb), -np.inf, dtype=np.float32)
+    with _dtel.stage("bookUs", "batch.book"):
+        kmax = max(it.k for it in group)
+        kb = srv._sess_kb(kmax)
+        steps: Dict[int, List[Tuple[np.ndarray, Optional[int]]]] = {}
+        for row, it in enumerate(group):
+            uid, items = it.payload
+            mine = steps.setdefault(int(uid), [])
+            items = np.asarray(items, dtype=np.int32)
+            cuts = list(range(0, max(len(items), 1), SESS_EVENTS))
+            for j, a in enumerate(cuts):
+                mine.append((items[a:a + SESS_EVENTS],
+                             row if j == len(cuts) - 1 else None))
+        idx = np.zeros((len(group), kb), dtype=np.int32)
+        scores = np.full((len(group), kb), -np.inf, dtype=np.float32)
     for rows in waves(steps):
         for lo in range(0, len(rows), SESS_MAX_BATCH):
             part = rows[lo:lo + SESS_MAX_BATCH]
             wi, ws = srv.extend([(u, st[0]) for u, st in part], kb)
-            for j, (_, st) in enumerate(part):
-                if st[1] is not None:
-                    idx[st[1]], scores[st[1]] = wi[j], ws[j]
+            with _dtel.stage("bookUs", "batch.book", done=True):
+                for j, (_, st) in enumerate(part):
+                    if st[1] is not None:
+                        idx[st[1]], scores[st[1]] = wi[j], ws[j]
     _deliver(group, idx, scores)
 
 
@@ -268,15 +270,15 @@ class GlmBackbone:
         T = SESS_EVENTS
         with m._sess_lock, _trace_span(
                 "sess.extend", attributes={"queries": n}):
+            busy = {int(u) for u, _ in rows}
+            if len(busy) != n:
+                raise ValueError("one dispatch takes one query a user")
+            # (outside every stage: a user without a session is
+            # prefilled here, in dispatches with records of their own)
+            sessions = [m._ensure_session(int(u), busy) for u, _ in rows]
             with _dtel.stage("formUs", "batch.form"):
-                busy = {int(u) for u, _ in rows}
-                if len(busy) != n:
-                    raise ValueError("one dispatch takes one query a user")
-                sessions = []
-                for u, items in rows:
-                    sess = m._ensure_session(int(u), busy)
+                for sess, (_, items) in zip(sessions, rows):
                     m._reserve(sess, sess.length + len(items), busy)
-                    sessions.append(sess)
                 S = m._s_bucket(max(s.length + len(it) for s, (_, it)
                                     in zip(sessions, rows)))
                 bb = next(b for b in SESS_BATCHES if b >= n)
@@ -290,38 +292,42 @@ class GlmBackbone:
                         sess, np.arange(sess.length, sess.length + k))
                     ints[j, 3 + 2 * T:] = m._table(sess, S)
             host, audit = self._run_extend(m, ints, kb, S, n)
-            m._clock += 1
-            tokens = 0
-            for j, (sess, (u, items)) in enumerate(zip(sessions, rows)):
-                if len(items):
-                    sess.append(items)
-                    sess.length = sess.events
-                    tokens += len(items)
-                sess.touched = m._clock
-            if audit is not None and (m._watched is None or any(
-                    int(u) in m._watched for u, _ in rows)):
-                m._audits.append((audit, bb, [
-                    (int(u), sess.length) for sess, (u, _)
-                    in zip(sessions, rows)]))
-            if tokens:
-                _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
-            for kind, rows_ in (("valid", tokens),
-                                ("padded", bb * T - tokens)):
-                _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
-                m._token_rows[kind] += rows_
-            _metrics.SESS_POSITIONS.inc(
-                amount=sum(s_.length for s_ in sessions))
-        idx, scores = _unpack(host[:, :2 * kb], kb)
-        selected, eligible, local, touched = (
-            float(c) for c in host[0, 2 * kb:].view(np.float32))
-        if eligible > 0:
-            _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
-            _metrics.SESS_SELECTED.inc(amount=selected, kind="selected")
-            _metrics.SESS_SELECTED.inc(amount=eligible, kind="eligible")
-        if local > 0:
-            _metrics.SESS_LOCAL_PICKS.inc(amount=local)
-        if touched > 0:
-            _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
+            with _dtel.stage("bookUs", "batch.book", done=True):
+                m._clock += 1
+                tokens = 0
+                for sess, (u, items) in zip(sessions, rows):
+                    if len(items):
+                        sess.append(items)
+                        sess.length = sess.events
+                        tokens += len(items)
+                    sess.touched = m._clock
+                if audit is not None and (m._watched is None or any(
+                        int(u) in m._watched for u, _ in rows)):
+                    m._audits.append((audit, bb, [
+                        (int(u), sess.length) for sess, (u, _)
+                        in zip(sessions, rows)]))
+                if tokens:
+                    _metrics.SESS_TOKENS.inc(amount=tokens,
+                                             program="extend")
+                for kind, rows_ in (("valid", tokens),
+                                    ("padded", bb * T - tokens)):
+                    _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
+                    m._token_rows[kind] += rows_
+                _metrics.SESS_POSITIONS.inc(
+                    amount=sum(s_.length for s_ in sessions))
+                idx, scores = _unpack(host[:, :2 * kb], kb)
+                selected, eligible, local, touched = (
+                    float(c) for c in host[0, 2 * kb:].view(np.float32))
+                if eligible > 0:
+                    _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
+                    _metrics.SESS_SELECTED.inc(amount=selected,
+                                               kind="selected")
+                    _metrics.SESS_SELECTED.inc(amount=eligible,
+                                               kind="eligible")
+                if local > 0:
+                    _metrics.SESS_LOCAL_PICKS.inc(amount=local)
+                if touched > 0:
+                    _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
         return idx[:n], scores[:n]
 
     def audits(self, m: "SessionTopK", kept, uid: int

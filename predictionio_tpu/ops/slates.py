@@ -267,32 +267,44 @@ class SdarBackbone:
                 if not 0 < it.k <= SLATE_MAX:
                     raise ValueError(
                         f"a slate holds 1 to {SLATE_MAX} items, not {it.k}")
+                # (outside every stage: a user without a session is
+                # prefilled here, in dispatches with records of their
+                # own)
                 sess = m._ensure_session(uid, busy)
-                m._s_bucket(sess.events + len(items))
-                # scratch: the rows of the slate's blocks, its tail's too
-                need = -(-(self.spec.block_len - 1 + it.k) // m._bs)
-                m._make_room(need, sess, busy)
             except Exception as e:  # noqa: BLE001 — this query alone
                 it.future.set_exception(e)
                 continue
-            sess.append(np.asarray(items, dtype=np.int32))
-            whole = (sess.events - sess.length) // B * B
-            steps[uid] = [(sess.length + a, min(EVENT_ROWS, whole - a))
-                          for a in range(0, whole, EVENT_ROWS)]
-            sess.inflight += 1
-            self._scratch_blocks += need
-            it.payload = _Slate(uid, int(it.k), sess,
-                                [m._free.pop() for _ in range(need)])
+            with _dtel.stage("bookUs", "batch.book"):
+                try:
+                    m._s_bucket(sess.events + len(items))
+                    # scratch: the rows of the slate's blocks, its
+                    # tail's too
+                    need = -(-(self.spec.block_len - 1 + it.k) // m._bs)
+                    m._make_room(need, sess, busy)
+                except Exception as e:  # noqa: BLE001 — this query alone
+                    it.future.set_exception(e)
+                    continue
+                sess.append(np.asarray(items, dtype=np.int32))
+                whole = (sess.events - sess.length) // B * B
+                steps[uid] = [(sess.length + a,
+                               min(EVENT_ROWS, whole - a))
+                              for a in range(0, whole, EVENT_ROWS)]
+                sess.inflight += 1
+                self._scratch_blocks += need
+                it.payload = _Slate(uid, int(it.k), sess,
+                                    [m._free.pop() for _ in range(need)])
         for rows in waves(steps):
             for lo in range(0, len(rows), SLATE_MAX_BATCH):
                 self._commit(m, rows[lo:lo + SLATE_MAX_BATCH], busy)
-        for it in fresh:
-            st = it.payload
-            if isinstance(st, _Slate):
-                st.len0 = st.pos0 = int(st.sess.length)
-                st.tail = np.array(st.sess.items[st.len0:st.sess.events])
-        self._note_state(m)
-        m._note_fill()
+        with _dtel.stage("bookUs", "batch.book"):
+            for it in fresh:
+                st = it.payload
+                if isinstance(st, _Slate):
+                    st.len0 = st.pos0 = int(st.sess.length)
+                    st.tail = np.array(
+                        st.sess.items[st.len0:st.sess.events])
+            self._note_state(m)
+            m._note_fill()
 
     def _commit(self, m, rows, busy) -> None:
         """One ``commit_events`` dispatch: ``rows``: ``(user, (first
@@ -301,37 +313,40 @@ class SdarBackbone:
         with _trace_span("sess.slate.commit_events", attributes={
                 "queries": len(rows),
                 "tokens": int(sum(st[1] for _, st in rows))}):
-            sessions = [m._sessions[u] for u, _ in rows]
-            for sess, (_, (p0, n)) in zip(sessions, rows):
-                m._reserve(sess, p0 + n, busy)
-            S = m._s_bucket(max(p0 + n for _, (p0, n) in rows))
-            bb = next(b for b in SLATE_BATCHES if b >= len(rows))
-            ints = np.zeros((bb, sdar.events_width(T, S, m._bs)), np.int32)
-            ints[:, 0], ints[:, 3] = NO_ROW, -1
-            watched = [j for j, (u, _) in enumerate(rows)
-                       if m._audit_keep and (m._watched is None
-                                             or u in m._watched)]
-            if watched:
-                ints[0, 3] = watched[0]
-            for j, (sess, (u, (p0, n))) in enumerate(
-                    zip(sessions, rows)):
-                ints[j, 0], ints[j, 1], ints[j, 2] = u, p0, n
-                ints[j, 4:4 + n] = sess.items[p0:p0 + n]
-                ints[j, 4 + T:4 + T + n] = m._phys(
-                    sess, np.arange(p0, p0 + n))
-                ints[j, 4 + 2 * T:] = m._table(sess, S)
+            with _dtel.stage("formUs", "batch.form"):
+                sessions = [m._sessions[u] for u, _ in rows]
+                for sess, (_, (p0, n)) in zip(sessions, rows):
+                    m._reserve(sess, p0 + n, busy)
+                S = m._s_bucket(max(p0 + n for _, (p0, n) in rows))
+                bb = next(b for b in SLATE_BATCHES if b >= len(rows))
+                ints = np.zeros((bb, sdar.events_width(T, S, m._bs)),
+                                np.int32)
+                ints[:, 0], ints[:, 3] = NO_ROW, -1
+                watched = [j for j, (u, _) in enumerate(rows)
+                           if m._audit_keep and (m._watched is None
+                                                 or u in m._watched)]
+                if watched:
+                    ints[0, 3] = watched[0]
+                for j, (sess, (u, (p0, n))) in enumerate(
+                        zip(sessions, rows)):
+                    ints[j, 0], ints[j, 1], ints[j, 2] = u, p0, n
+                    ints[j, 4:4 + n] = sess.items[p0:p0 + n]
+                    ints[j, 4 + T:4 + T + n] = m._phys(
+                        sess, np.arange(p0, p0 + n))
+                    ints[j, 4 + 2 * T:] = m._table(sess, S)
             audit = self._run_events(m, ints, S, len(rows))
-            tokens = 0
-            for sess, (_, (p0, n)) in zip(sessions, rows):
-                sess.length = p0 + n
-                tokens += n
-            _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
-            if watched and audit is not None:
-                u, (p0, n) = rows[watched[0]]
-                m._audits.append((audit, {
-                    "kind": "events", "uid": int(u), "len0": int(p0),
-                    "tokens": int(n), "ids": np.array(
-                        sessions[watched[0]].items[p0:p0 + n])}))
+            with _dtel.stage("bookUs", "batch.book", done=True):
+                tokens = 0
+                for sess, (_, (p0, n)) in zip(sessions, rows):
+                    sess.length = p0 + n
+                    tokens += n
+                _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
+                if watched and audit is not None:
+                    u, (p0, n) = rows[watched[0]]
+                    m._audits.append((audit, {
+                        "kind": "events", "uid": int(u), "len0": int(p0),
+                        "tokens": int(n), "ids": np.array(
+                            sessions[watched[0]].items[p0:p0 + n])}))
 
     def _note_state(self, m) -> None:
         live = list(m._sessions.values())
@@ -379,48 +394,49 @@ class SdarBackbone:
                         m, st, held, held + rows)
                     ints[j, c + R:] = m._table(st.sess, S)
             host, audit = self._run_round(m, ints, S, n)
-            m._clock += 1
-            conf = host[:, R:2 * R].view(np.float32)
-            when = host[:, 2 * R:3 * R]
-            device_passes, touched, read = (
-                int(c) for c in host[0, 3 * R + 1:3 * R + 1 + COUNTERS])
-            unmasked = row_passes = 0
-            for j, (st, (n_fixed, rows)) in enumerate(zip(slates, shape)):
-                st.slate += host[j, n_fixed:rows].tolist()
-                st.conf += conf[j, n_fixed:rows].tolist()
-                st.pos0 += rows
-                st.rounds += 1
-                st.sess.touched = m._clock
-                unmasked += rows - n_fixed
-                row_passes += int(host[j, 3 * R])
-            if watched and audit is not None:
-                st = slates[watched[0]]
-                n_fixed, rows = shape[watched[0]]
-                m._audits.append((audit, {
-                    "kind": "round", "uid": st.uid, "len0": st.len0,
-                    "pos0": st.pos0 - rows, "rows": rows,
-                    "fixed": np.array(st.tail[:n_fixed]),
-                    "tail": np.array(st.tail), "num": st.num,
-                    "taken": list(st.slate[:len(st.slate)
-                                           - (rows - n_fixed)]),
-                    "round": st.rounds - 1, "slot": watched[0],
-                    "queries": n, "bucket": bb,
-                    "tokens": host[watched[0], :R].copy(),
-                    "conf": conf[watched[0]].copy(),
-                    "when": when[watched[0]].copy()}))
-            _metrics.SLATE_ROUNDS.inc(amount=n)
-            _metrics.SLATE_PASSES.inc(amount=row_passes, kind="query")
-            _metrics.SLATE_PASSES.inc(amount=device_passes, kind="device")
-            _metrics.SLATE_TOKENS_UNMASKED.inc(amount=unmasked)
-            _metrics.SLATE_EXPERTS_TOUCHED.inc(amount=touched)
-            _metrics.SLATE_CACHE_ROWS_READ.inc(amount=read)
-            if carried:
-                _metrics.SLATE_CARRIED.inc(amount=carried)
-            fields = {"passes": device_passes, "tokensUnmasked": unmasked,
-                      "carried": carried, "lengthBucket": S}
-            if sp is not None:      # (no trace is being recorded)
-                sp.attributes.update(fields)
-            _dtel.note_fields(**fields)
+            with _dtel.stage("bookUs", "batch.book", done=True):
+                m._clock += 1
+                conf = host[:, R:2 * R].view(np.float32)
+                when = host[:, 2 * R:3 * R]
+                device_passes, touched, read = (
+                    int(c) for c in host[0, 3 * R + 1:3 * R + 1 + COUNTERS])
+                unmasked = row_passes = 0
+                for j, (st, (n_fixed, rows)) in enumerate(zip(slates, shape)):
+                    st.slate += host[j, n_fixed:rows].tolist()
+                    st.conf += conf[j, n_fixed:rows].tolist()
+                    st.pos0 += rows
+                    st.rounds += 1
+                    st.sess.touched = m._clock
+                    unmasked += rows - n_fixed
+                    row_passes += int(host[j, 3 * R])
+                if watched and audit is not None:
+                    st = slates[watched[0]]
+                    n_fixed, rows = shape[watched[0]]
+                    m._audits.append((audit, {
+                        "kind": "round", "uid": st.uid, "len0": st.len0,
+                        "pos0": st.pos0 - rows, "rows": rows,
+                        "fixed": np.array(st.tail[:n_fixed]),
+                        "tail": np.array(st.tail), "num": st.num,
+                        "taken": list(st.slate[:len(st.slate)
+                                               - (rows - n_fixed)]),
+                        "round": st.rounds - 1, "slot": watched[0],
+                        "queries": n, "bucket": bb,
+                        "tokens": host[watched[0], :R].copy(),
+                        "conf": conf[watched[0]].copy(),
+                        "when": when[watched[0]].copy()}))
+                _metrics.SLATE_ROUNDS.inc(amount=n)
+                _metrics.SLATE_PASSES.inc(amount=row_passes, kind="query")
+                _metrics.SLATE_PASSES.inc(amount=device_passes, kind="device")
+                _metrics.SLATE_TOKENS_UNMASKED.inc(amount=unmasked)
+                _metrics.SLATE_EXPERTS_TOUCHED.inc(amount=touched)
+                _metrics.SLATE_CACHE_ROWS_READ.inc(amount=read)
+                if carried:
+                    _metrics.SLATE_CARRIED.inc(amount=carried)
+                fields = {"passes": device_passes, "tokensUnmasked": unmasked,
+                          "carried": carried, "lengthBucket": S}
+                if sp is not None:      # (no trace is being recorded)
+                    sp.attributes.update(fields)
+                _dtel.note_fields(**fields)
 
     def _finish(self, m, st: _Slate) -> None:
         m._free += st.scratch
@@ -439,17 +455,19 @@ class SdarBackbone:
         the queries whose slate is whole and hand the others back."""
         with m._sess_lock:
             try:
-                flying = {it.payload.uid for it in group
-                          if isinstance(it.payload, _Slate)}
-                fresh = []
-                for it in group:
-                    if isinstance(it.payload, _Slate):
-                        continue
-                    uid = int(it.payload[0])
-                    sess = m._sessions.get(uid)
-                    if uid not in flying and not (sess and sess.inflight):
-                        flying.add(uid)
-                        fresh.append(it)
+                with _dtel.stage("bookUs", "batch.book"):
+                    flying = {it.payload.uid for it in group
+                              if isinstance(it.payload, _Slate)}
+                    fresh = []
+                    for it in group:
+                        if isinstance(it.payload, _Slate):
+                            continue
+                        uid = int(it.payload[0])
+                        sess = m._sessions.get(uid)
+                        if uid not in flying and not (sess
+                                                      and sess.inflight):
+                            flying.add(uid)
+                            fresh.append(it)
                 if fresh:
                     self._open(m, fresh)
                 now = [it for it in group
@@ -463,20 +481,23 @@ class SdarBackbone:
                         self._finish(m, it.payload)
                         it.payload = (it.payload.uid, ())
                 raise
-            done = [it for it in now if it.payload.done]
-            for it in done:
-                self._finish(m, it.payload)
+            with _dtel.stage("bookUs", "batch.book", done=True):
+                done = [it for it in now if it.payload.done]
+                for it in done:
+                    self._finish(m, it.payload)
+        with _dtel.stage("bookUs", "batch.book", done=True):
+            back = [it for it in group
+                    if it not in done and not it.future.done()]
+            if done:
+                width = max(it.payload.num for it in done)
+                idx = np.zeros((len(done), width), np.int32)
+                scores = np.full((len(done), width), -np.inf, np.float32)
+                for row, it in enumerate(done):
+                    st = it.payload
+                    idx[row, :st.num] = st.slate[:st.num]
+                    scores[row, :st.num] = st.conf[:st.num]
         if done:
-            width = max(it.payload.num for it in done)
-            idx = np.zeros((len(done), width), np.int32)
-            scores = np.full((len(done), width), -np.inf, np.float32)
-            for row, it in enumerate(done):
-                st = it.payload
-                idx[row, :st.num] = st.slate[:st.num]
-                scores[row, :st.num] = st.conf[:st.num]
             _deliver(done, idx, scores)
-        back = [it for it in group
-                if it not in done and not it.future.done()]
         return back or None
 
     # -- what a check reads ------------------------------------------------------

@@ -135,7 +135,8 @@ def render(stats: Dict[str, Any], dispatches: Dict[str, Any],
             f"· device p50 {_fmt_us(s.get('deviceUsP50'))} "
             f"p99 {_fmt_us(s.get('deviceUsP99'))} · "
             f"host p50 {_fmt_us(s.get('hostUsP50'))} · "
-            f"wait p50 {_fmt_us(s.get('queueWaitUsP50'))} · "
+            f"oldest age p50 {_fmt_us(s.get('queueWaitUsP50'))} · "
+            f"first wait p50 {_fmt_us(s.get('firstWaitUsP50'))} · "
             f"fill {s.get('meanFill') if s.get('meanFill') is not None else '—'} "
             f"· aot {s.get('aot') or {}}")
 

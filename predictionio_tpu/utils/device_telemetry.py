@@ -8,35 +8,65 @@ thread-safe ring those answers live in — the ALX-style per-step
 device-time accounting, applied to the serving plane:
 
 - every device dispatch (user top-k, batched users, item similarity,
-  the fold-in solve) records one :class:`DispatchRecord`: lane, k/batch
-  bucket shape, batch size + fill ratio, store precision, kernel lane
-  (fused Pallas vs XLA chain), AOT ladder result (``hit`` /
-  ``miss_jit`` / ``jit`` for unladdered programs), queue wait, host
-  wall µs and **device µs** — the dispatch-to-``block_until_ready``
-  window on the monotonic clock;
+  the session lanes' programs, the fold-in solve) leaves one record:
+  lane, k/batch bucket shape, batch size + fill ratio, store precision,
+  kernel lane (fused Pallas vs XLA chain), AOT ladder result (``hit`` /
+  ``miss_jit`` / ``jit`` for unladdered programs), ``queueWaitUs`` (the
+  AGE of the group's oldest query when the group was formed: in a lane
+  that carries queries over several rounds, ``ops/slates.py``, earlier
+  rounds included; a query's own wait is in ``lives``), host wall µs
+  and **device µs** — the dispatch-to-``block_until_ready`` window on
+  the monotonic clock. ``ts`` is ``time.time()`` when the record is
+  WRITTEN, just after ``block_until_ready`` returned; ``calledTs`` and
+  ``readyTs`` are the program call's start and that return on the SPAN
+  clock (``tracing.span_now()``), the axis of the ``device.execute``
+  span and every other span;
 - a batching dispatcher's thread works strictly one dispatch after
   another, so its records also carry **stage stamps** that tile its
-  time (:func:`stage`, :func:`record_dispatch`): ``gapUs`` from the
+  time (:class:`stage`, :func:`record_dispatch`): ``gapUs`` from the
   previous dispatch's ``block_until_ready`` return to this dispatch's
   program call, of which ``gapIdleUs`` asleep with every lane empty,
-  ``gapWindowUs`` asleep on a batching window, ``formUs`` forming the
-  batch and ``lockWaitUs`` acquiring the store lock; then
-  ``enqueueUs`` (the program call), ``deviceUs``, and after the record
-  is written ``fetchUs`` and ``deliverUs`` (and, from the fetched
-  result of a fused-kernel dispatch, ``selectRounds``:
-  :func:`note_select_rounds`). Over one ``dispatcher``
-  thread's consecutive records ``gapUs + enqueueUs + deviceUs`` adds
-  up to the wall clock. Each stage is also a profiler annotation
-  (``batch.idle``, ``batch.window``, ``batch.form``, ``dispatch.lock``,
-  ``dispatch.enqueue``, ``dispatch.wait``, ``dispatch.fetch``,
-  ``batch.deliver``) on the dispatcher thread's line of a capture;
-- the ring is bounded (``PIO_DEVICE_TELEMETRY_RING``, default 2048):
-  a long-lived server holds the last N dispatches, never all of them
-  (evictions are counted, not silently dropped);
+  ``gapWindowUs`` asleep on a batching window, ``pickUs`` moving
+  arrivals into the lanes' queues, choosing the lane and putting
+  handed-back queries back, ``formUs`` forming the batch, ``bookUs``
+  the bookkeeping between a program's end and the next forming (the
+  record itself and its ``device.execute`` span; a lane's sessions,
+  audits, counters, a slate's rows), ``lockWaitUs``
+  acquiring the store lock and ``otherUs`` what is left of the gap once
+  every named part is taken off (this record's, and the ``fetchUs`` /
+  ``deliverUs`` / ``bookUs`` the same thread's previous record took
+  after it was written): the loop itself and waits for the interpreter
+  lock that fall between stages; then ``enqueueUs`` (the program call),
+  ``deviceUs``, and after the record is written ``fetchUs`` and
+  ``deliverUs`` (and, from the fetched result of a fused-kernel
+  dispatch, ``selectRounds``: :func:`note_select_rounds`). Stages nest
+  nowhere. Over one ``dispatcher`` thread's consecutive records
+  ``gapUs + enqueueUs + deviceUs`` adds up to the wall clock. Each
+  stage is also a profiler annotation (``batch.idle``,
+  ``batch.window``, ``batch.pick``, ``batch.form``, ``batch.book``,
+  ``dispatch.lock``, ``dispatch.enqueue``, ``dispatch.wait``,
+  ``dispatch.fetch``, ``batch.deliver``) on the dispatcher thread's
+  line of a capture;
+- ``lives``: for every query a batching dispatcher delivered after this
+  record was written, in the delivered group's order, ``{firstWaitUs,
+  rounds, ridingUs, betweenUs}``: arrival to the first group that
+  claimed it; the groups it rode in; the sum over them of claim to the
+  dispatch function's return (to delivery in its last); the sum of
+  hand-back to next claim. The three times add up to delivery less
+  arrival (``ops/serving.py::_Pending``). A direct caller without a
+  batcher (``SessionTopK.sess_topk`` with micro-batching off, the
+  fold-in solve) writes no ``lives``;
+- the ring is bounded (``PIO_DEVICE_TELEMETRY_RING``, default 16384:
+  a measurement window of the busiest lane, about 10 MB of dicts at
+  worst): a long-lived server holds the last N dispatches, never all
+  of them (evictions are counted, not silently dropped); what threads
+  waited for the ring's lock is counted too (``lockContended``,
+  ``lockWaitedUs`` in :meth:`FlightRecorder.counts`);
 - surfaces: ``GET /dispatches.json`` on the query server (snapshot +
-  per-lane summary), the ``pio_dispatch_device_seconds`` histogram,
-  ``device.execute`` child spans in the PR-4 trace tree (Perfetto shows
-  device time under each ``device.*`` span), and ``pio top``;
+  per-lane summary, with each stage's sum over the retained records),
+  the ``pio_dispatch_device_seconds`` histogram, ``device.execute``
+  child spans in the PR-4 trace tree (Perfetto shows device time under
+  each ``device.*`` span), and ``pio top``;
 - kill switch ``PIO_DEVICE_TELEMETRY=0``: every record site returns on
   one attribute check before touching a clock or a lock — the same
   killed-lane fast-path discipline as ``PIO_METRICS`` (PR 2), gated by
@@ -50,7 +80,7 @@ import contextlib
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 from predictionio_tpu.utils import tracing as _tracing
 
@@ -65,6 +95,7 @@ __all__ = [
     "dispatch_scope",
     "current_dispatch_context",
     "stage",
+    "STAGE_FIELDS",
     "mark_ready",
     "note_select_rounds",
 ]
@@ -75,7 +106,7 @@ def _env_enabled() -> bool:
         not in ("0", "off", "false")
 
 
-def _env_capacity(default: int = 2048) -> int:
+def _env_capacity(default: int = 16384) -> int:
     raw = os.environ.get("PIO_DEVICE_TELEMETRY_RING", "").strip()
     try:
         cap = int(raw) if raw else default
@@ -98,7 +129,7 @@ class FlightRecorder:
         self.capacity = _env_capacity() if capacity is None \
             else max(16, int(capacity))
         self.enabled = _env_enabled() if enabled is None else bool(enabled)
-        self._lock = threading.Lock()
+        self._lock = _tracing.CountedLock()
         self._ring: collections.deque = collections.deque(
             maxlen=self.capacity)
         self._recorded = 0
@@ -110,9 +141,14 @@ class FlightRecorder:
     # -- write side --------------------------------------------------------
 
     def record(self, rec: Dict[str, Any]) -> Dict[str, Any]:
-        with self._lock:
+        lock = self._lock
+        if not lock.try_acquire(False):
+            lock.wait()
+        try:
             self._ring.append(rec)
             self._recorded += 1
+        finally:
+            lock.release()
         self._tls.last = rec
         return rec
 
@@ -137,15 +173,22 @@ class FlightRecorder:
         with self._lock:
             retained = len(self._ring)
             recorded = self._recorded
+        lock = self._lock.stats()
         return {"recorded": recorded, "retained": retained,
                 "evicted": recorded - retained,
-                "capacity": self.capacity}
+                "capacity": self.capacity,
+                "lockContended": lock["contended"],
+                "lockWaitedUs": lock["waitedUs"]}
 
     def summary(self) -> Dict[str, Any]:
         """Per-lane aggregates over the retained window: dispatch count,
-        device/host-µs percentiles, queue-wait p50, mean batch fill,
-        AOT hit/miss counts — the compact view ``pio top`` and the bench
-        artifacts embed."""
+        device/host-µs percentiles, the p50 of ``queueWaitUs`` (the
+        oldest grouped query's age) and of the delivered queries' own
+        ``firstWaitUs``, mean batch fill, AOT hit/miss counts, and
+        under ``stageUs`` the sum of every stage stamp over the lane's
+        records beside ``spanSec``, the time from its oldest retained
+        record to its newest — the compact view ``pio top`` and the
+        bench artifacts embed."""
         with self._lock:
             records = list(self._ring)
         lanes: Dict[str, List[Dict[str, Any]]] = {}
@@ -167,6 +210,8 @@ class FlightRecorder:
             waits = [r["queueWaitUs"] for r in rs
                      if r.get("queueWaitUs") is not None]
             fills = [r["fill"] for r in rs if r.get("fill") is not None]
+            first = [life["firstWaitUs"] for r in rs
+                     for life in r.get("lives") or ()]
             aot = collections.Counter(r.get("aot", "?") for r in rs)
             out[lane] = {
                 "dispatches": len(rs),
@@ -175,9 +220,13 @@ class FlightRecorder:
                 "hostUsP50": pct(host, 0.50),
                 "hostUsP99": pct(host, 0.99),
                 "queueWaitUsP50": pct(waits, 0.50),
+                "firstWaitUsP50": pct(first, 0.50),
                 "meanFill": round(sum(fills) / len(fills), 4)
                 if fills else None,
                 "aot": dict(aot),
+                "stageUs": {f: round(sum(r.get(f) or 0.0 for r in rs), 1)
+                            for f in STAGE_FIELDS},
+                "spanSec": round(rs[-1]["ts"] - rs[0]["ts"], 3),
             }
         return out
 
@@ -230,17 +279,14 @@ _dispatch_ctx = threading.local()
 @contextlib.contextmanager
 def dispatch_scope(queue_wait_us: Optional[float] = None,
                    group: Optional[int] = None,
-                   trace_parent: Any = None,
-                   queue_wait_mean_us: Optional[float] = None):
+                   trace_parent: Any = None):
     """Bind batching context for the device dispatch(es) the block
-    issues: queue wait of the oldest grouped query and the mean over
-    the group, the group size, and a trace parent for the
-    ``device.execute`` span (the dispatcher thread has no ambient
-    trace context of its own)."""
+    issues: the age of the oldest grouped query (``queueWaitUs``), the
+    group size, and a trace parent for the ``device.execute`` span
+    (the dispatcher thread has no ambient trace context of its own)."""
     prior = getattr(_dispatch_ctx, "ctx", None)
     _dispatch_ctx.ctx = {"queueWaitUs": queue_wait_us, "group": group,
-                         "traceParent": trace_parent,
-                         "queueWaitMeanUs": queue_wait_mean_us}
+                         "traceParent": trace_parent}
     try:
         yield
     finally:
@@ -253,39 +299,71 @@ def current_dispatch_context() -> Optional[Dict[str, Any]]:
 
 # -- stage stamps --------------------------------------------------------------
 
-# Per thread: the stamps taken since the last record (``pending``; they
-# belong to the record being formed), when the last dispatch's
+# every stamp of a record that is a time of the dispatcher thread, in
+# the order the thread lives them (``FlightRecorder.summary`` sums each)
+STAGE_FIELDS: Tuple[str, ...] = (
+    "gapUs", "gapIdleUs", "gapWindowUs", "pickUs", "formUs", "bookUs",
+    "lockWaitUs", "otherUs", "enqueueUs", "deviceUs", "fetchUs",
+    "deliverUs")
+# the stages a thread takes BEFORE a program call (``stage`` without
+# ``done``), each a named part of the record's gap
+_GAP_STAGES = ("gapIdleUs", "gapWindowUs", "pickUs", "formUs", "bookUs")
+
+# Per thread: the µs of the stages taken since the last record that
+# belong to the record being formed (``pending``) and of those that
+# belonged to the record written last (``after``: they are parts of
+# the NEXT record's gap), when the last dispatch's
 # ``block_until_ready`` returned (``ready``, monotonic), and the
 # thread's ``name/native id`` as records carry it (``thread``).
 _stage = threading.local()
 
 
-@contextlib.contextmanager
-def stage(field: str, name: str, done: bool = False):
-    """Time one stage of the calling thread's dispatch work: a profiler
-    annotation ``name`` round the block, and its µs added to ``field``
-    of a flight record — the one this thread is forming (merged in by
-    the next :func:`record_dispatch`), or with ``done`` the one it wrote
-    last (fetch and deliver happen after the record exists; the dict is
-    the one :func:`last_record` and the ring hold). Killed
-    (``PIO_DEVICE_TELEMETRY=0``): no clock, no annotation."""
-    if not RECORDER.enabled:
-        yield
-        return
-    t = time.monotonic()
-    try:
-        with _tracing.annotation(name):
-            yield
-    finally:
-        us = (time.monotonic() - t) * 1e6
-        if done:
+class stage:
+    """Time one stage of the calling thread's dispatch work (``with
+    stage(field, name): ...``): a profiler annotation ``name`` round
+    the block, and its µs added to ``field`` of a flight record — the
+    one this thread is forming (merged in by the next
+    :func:`record_dispatch`), or with ``done`` the one it wrote last
+    (fetch, deliver and a lane's bookkeeping happen after the record
+    exists; the dict is the one :func:`last_record` and the ring
+    hold). Stages do not nest, and no dispatch is recorded inside one:
+    :func:`record_dispatch` takes every stage since the thread's last
+    record as a part of the gap before the program call. Killed
+    (``PIO_DEVICE_TELEMETRY=0``): no clock, no annotation. A class
+    and not a generator, as ``tracing.span`` is: a dispatch passes
+    through ten of these."""
+
+    __slots__ = ("field", "name", "done", "_t", "_annotation")
+
+    def __init__(self, field: str, name: str, done: bool = False):
+        self.field = field
+        self.name = name
+        self.done = done
+
+    def __enter__(self) -> None:
+        if not RECORDER.enabled:
+            self._t = None
+            return
+        self._t = time.monotonic()
+        self._annotation = _tracing.annotation(self.name)
+        self._annotation.__enter__()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if self._t is None:
+            return
+        self._annotation.__exit__(exc_type, exc, tb)
+        us = (time.monotonic() - self._t) * 1e6
+        if self.done:
+            _stage.after = getattr(_stage, "after", 0.0) + us
             into = RECORDER.last()
+            if into is not None:
+                into[self.field] = round(
+                    into.get(self.field, 0.0) + us, 1)
         else:
             into = getattr(_stage, "pending", None)
             if into is None:
                 into = _stage.pending = {}
-        if into is not None:
-            into[field] = round(into.get(field, 0.0) + us, 1)
+            into[self.field] = into.get(self.field, 0.0) + us
 
 
 def mark_ready() -> None:
@@ -323,12 +401,12 @@ def note_fields(**fields) -> None:
 def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
                     k_bucket: int, batch: int, bucket: int,
                     host_us: float, device_us: float,
-                    started_epoch: Optional[float] = None,
                     interpret: Optional[bool] = None,
                     lock_wait_us: Optional[float] = None,
                     locked_us: float = 0.0,
                     called: Optional[float] = None,
-                    ready: Optional[float] = None
+                    ready: Optional[float] = None,
+                    called_ts: Optional[float] = None
                     ) -> Optional[Dict[str, Any]]:
     """Record one device dispatch (caller already paid the timing; this
     is pure bookkeeping). Returns the record dict, or None when the
@@ -341,12 +419,19 @@ def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
     counted as forming) and the monotonic ``called`` / ``ready`` (the
     program call's start, ``block_until_ready``'s return) are the
     stage stamps of a laddered dispatch; with a batching context bound
-    they give ``gapUs`` and move this thread's clock on."""
+    they give ``gapUs`` and ``otherUs`` and move this thread's clock
+    on; the time from ``ready`` to this function's end (the record's
+    own writing) is the first ``bookUs`` booked to it. ``called_ts``
+    is ``called`` on the span clock (``tracing.span_now()``): the
+    record's ``calledTs``, and with ``host_us`` its ``readyTs``. The
+    record's ``ts`` is ``time.time()`` now: it is written at ready."""
     if not RECORDER.enabled:
         return None
     ctx = current_dispatch_context()
     pending = getattr(_stage, "pending", None) or {}
     _stage.pending = None
+    after = getattr(_stage, "after", 0.0)
+    _stage.after = 0.0
     thread = getattr(_stage, "thread", None)
     if thread is None:
         thread = _stage.thread = (f"{threading.current_thread().name}"
@@ -354,25 +439,29 @@ def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
     stamps: Dict[str, Any] = {
         "enqueueUs": round(float(host_us - device_us), 1),
         "dispatcher": thread}
+    if called_ts is not None:
+        stamps["calledTs"] = float(called_ts)
+        stamps["readyTs"] = float(called_ts) + float(host_us) / 1e6
     if lock_wait_us is not None:
         stamps["lockWaitUs"] = round(float(lock_wait_us), 1)
-    if "formUs" in pending or locked_us:
-        stamps["formUs"] = round(pending.get("formUs", 0.0)
-                                 + float(locked_us), 1)
+    if locked_us:
+        pending["formUs"] = pending.get("formUs", 0.0) + float(locked_us)
     if ctx is not None and called is not None:
-        # a batching dispatcher's thread: one dispatch after another
+        # a batching dispatcher's thread: one dispatch after another,
+        # so every stage since its last record is a part of this gap
         last = getattr(_stage, "ready", None)
-        stamps["gapUs"] = None if last is None \
-            else round((called - last) * 1e6, 1)
-        stamps["gapIdleUs"] = pending.get("gapIdleUs", 0.0)
-        stamps["gapWindowUs"] = pending.get("gapWindowUs", 0.0)
-        mean = ctx.get("queueWaitMeanUs")
-        stamps["queueWaitMeanUs"] = None if mean is None \
-            else round(float(mean), 1)
+        gap = None if last is None else (called - last) * 1e6
+        for field in _GAP_STAGES:
+            pending.setdefault(field, 0.0)
+        stamps["gapUs"] = None if gap is None else round(gap, 1)
+        stamps["otherUs"] = None if gap is None else round(
+            gap - after - sum(pending[f] for f in _GAP_STAGES)
+            - float(lock_wait_us or 0.0), 1)
         _stage.ready = ready
+    stamps.update((field, round(us, 1)) for field, us in pending.items())
     ctx = ctx or {}
     rec: Dict[str, Any] = {
-        "ts": started_epoch if started_epoch is not None else time.time(),
+        "ts": time.time(),
         "lane": lane,
         "kernel": kernel,
         "precision": precision,
@@ -396,4 +485,8 @@ def record_dispatch(*, lane: str, kernel: str, precision: str, aot: str,
 
     metrics.DISPATCH_DEVICE_SECONDS.observe(
         device_us / 1e6, lane=lane, kernel=kernel, precision=precision)
+    if ready is not None:
+        # the record's own writing is the first thing booked to it
+        _stage.after = us = (time.monotonic() - ready) * 1e6
+        rec["bookUs"] = round(rec.get("bookUs", 0.0) + us, 1)
     return rec

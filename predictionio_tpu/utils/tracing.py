@@ -45,6 +45,9 @@ observability:
   :data:`STAGE_SUMMARY_RING` roots, whatever head sampling retained
   (:meth:`TraceBuffer.stage_summaries`): the form of a span tree that
   survives a whole measurement window.
+- **its own lock, counted**: :class:`CountedLock` is the buffer's lock
+  (and the flight recorder's): how often a thread found it held and how
+  long it then waited, in ``/stats.json`` ``stages.lock``.
 - kill switch: ``PIO_TRACING=0|off`` (or ``--tracing off``) disables
   span collection entirely — :func:`span` falls back to the log-line
   timer, so serving overhead stays negligible (the tracing analog of
@@ -82,6 +85,54 @@ from typing import (
 
 logger = logging.getLogger("pio.tracing")
 slow_logger = logging.getLogger("pio.tracing.slow")
+
+
+class CountedLock:
+    """A ``threading.Lock`` that counts what its callers waited: an
+    acquisition tries without blocking first and, only when another
+    thread holds the lock, reads the clock round the blocking acquire
+    (:meth:`wait`). ``contended`` (acquisitions that waited) and
+    ``waited_us`` (their sum) are updated while the lock is held;
+    totals since the process started. An uncontended acquisition reads
+    no clock. The recorders' own locks are of this kind
+    (``TraceBuffer._lock``, ``FlightRecorder._lock``): what a
+    request's spans wait for one another is a number, not a guess.
+
+    ``with lock:`` works; a path a request takes a dozen times spells
+    it out (``if not lock.try_acquire(False): lock.wait()`` ...
+    ``finally: lock.release()``), which calls the lock's own C methods
+    and costs what the plain ``with`` of a ``threading.Lock`` did, where
+    ``__enter__`` and ``__exit__`` are two Python frames an
+    acquisition on a thread that holds the interpreter lock
+    (PERF.md section 6, PR 36)."""
+
+    __slots__ = ("_lock", "contended", "waited_us", "try_acquire",
+                 "release")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.try_acquire = self._lock.acquire    # call with False
+        self.release = self._lock.release
+        self.contended = 0
+        self.waited_us = 0.0
+
+    def wait(self) -> None:
+        """The blocking acquire after a failed try, counted."""
+        t = time.perf_counter()
+        self._lock.acquire()
+        self.contended += 1
+        self.waited_us += (time.perf_counter() - t) * 1e6
+
+    def __enter__(self) -> None:
+        if not self.try_acquire(False):
+            self.wait()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.release()
+
+    def stats(self) -> Dict[str, Any]:
+        return {"contended": self.contended,
+                "waitedUs": round(self.waited_us, 1)}
 
 # bucket upper bounds in seconds (log-ish scale), last bucket = +inf
 _BOUNDS = (0.0005, 0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.2, 0.5,
@@ -503,7 +554,9 @@ def stage_self_times(spans: Sequence[Span]) -> Dict[str, float]:
     """``{span name: self µs}`` over one root's spans: a span's duration
     less the union of its children's intervals (clipped to the span, so
     a cross-thread child that outlives its parent takes no more than the
-    parent had). Spans of one name add up. One pass: children sorted by
+    parent had). Spans of one name add up; a span's ``wakeUs``
+    attribute is reported as ``device.wake`` and not as the span's
+    own. One pass: children sorted by
     start and swept with a running high-water mark, so overlapping
     children are not subtracted twice."""
     kids: Dict[Optional[str], List[Span]] = {}
@@ -524,7 +577,16 @@ def stage_self_times(spans: Sequence[Span]) -> Dict[str, float]:
                 if b > a:
                     covered += b - a
                     hi = b
-        out[s.name] = out.get(s.name, 0.0) + (end - s.start - covered) * 1e6
+        self_us = (end - s.start - covered) * 1e6
+        if s.attributes:
+            # a waiter's wake-up after its batched dispatch, stamped
+            # on the ``device.*`` span it ended
+            # (``ops/serving.py::BatchLane.submit``): a name of its own
+            wake = s.attributes.get("wakeUs")
+            if wake is not None:
+                self_us -= wake
+                out["device.wake"] = out.get("device.wake", 0.0) + wake
+        out[s.name] = out.get(s.name, 0.0) + self_us
     return out
 
 
@@ -576,7 +638,7 @@ class TraceBuffer:
         self.slow_threshold_sec = float(slow_threshold_sec)
         self.max_traces = int(max_traces)
         self.max_spans_per_trace = int(max_spans_per_trace)
-        self._lock = threading.Lock()
+        self._lock = CountedLock()
         self._rng = random.Random(seed)
         # open local roots per trace_id (a trace can have several, e.g.
         # two resthttp calls of one remote query hitting this server)
@@ -608,8 +670,13 @@ class TraceBuffer:
 
     # -- collection --------------------------------------------------------
     def root_started(self, trace_id: str) -> None:
-        with self._lock:
+        lock = self._lock
+        if not lock.try_acquire(False):
+            lock.wait()
+        try:
             self._roots[trace_id] = self._roots.get(trace_id, 0) + 1
+        finally:
+            lock.release()
 
     def add_span(self, span: Span) -> None:
         """A finished span. Goes to the in-flight set while a local root
@@ -619,7 +686,10 @@ class TraceBuffer:
         if not self.enabled:
             return
         tid = span.trace_id
-        with self._lock:
+        lock = self._lock
+        if not lock.try_acquire(False):
+            lock.wait()
+        try:
             if self._roots.get(tid):
                 spans = self._open.setdefault(tid, [])
                 if len(spans) >= self.max_spans_per_trace:
@@ -631,6 +701,8 @@ class TraceBuffer:
             if rec is not None \
                     and len(rec["spans"]) < self.max_spans_per_trace:
                 rec["spans"].append(span)
+        finally:
+            lock.release()
 
     def flush(self, root: Span, sampled: bool) -> None:
         """Retire a local root: decide retention, update the slow-query
@@ -649,7 +721,10 @@ class TraceBuffer:
         record: Optional[Dict[str, Any]] = None
         new_spans: List[Span] = []
         slow_entry: Optional[Dict[str, Any]] = None
-        with self._lock:
+        lock = self._lock
+        if not lock.try_acquire(False):
+            lock.wait()
+        try:
             open_roots = self._roots.get(tid, 1) - 1
             if open_roots > 0:
                 self._roots[tid] = open_roots
@@ -715,6 +790,8 @@ class TraceBuffer:
                     # was slow: the slow log links straight to it
                     slow_entry["profileCapture"] = capture
                 self._slow.append(slow_entry)
+        finally:
+            lock.release()
         self._summarise(root, new_spans, duration)
         if slow_entry is not None:
             slow_logger.warning(
@@ -734,8 +811,13 @@ class TraceBuffer:
         entry = (root.name, root.start, duration * 1e6, root.trace_id,
                  self._stage_names.setdefault(names, names),
                  tuple(self_us.values()))
-        with self._lock:
+        lock = self._lock
+        if not lock.try_acquire(False):
+            lock.wait()
+        try:
             self._stages.append(entry)
+        finally:
+            lock.release()
 
     def stage_summaries(self, t0: float = 0.0, t1: float = float("inf"),
                         root: Optional[str] = None
@@ -754,11 +836,17 @@ class TraceBuffer:
                 for name, start, dur, tid, names, values in entries
                 if t0 <= start < t1 and (root is None or name == root)]
 
+    def lock_stats(self) -> Dict[str, Any]:
+        """``{contended, waitedUs}`` of the buffer's lock."""
+        return self._lock.stats()
+
     def stage_p50(self, prefix: str, last: int = 4096
                   ) -> Dict[str, Any]:
         """The ``/stats.json`` ``stages`` block: median self µs per
         span name over the newest ``last`` roots whose name starts with
-        ``prefix`` (a scrape must not sort the whole ring)."""
+        ``prefix`` (a scrape must not sort the whole ring), and under
+        ``lock`` what every thread has waited for this buffer's lock
+        since the process started (:class:`CountedLock`)."""
         with self._lock:
             ring = list(self._stages)
         entries = []
@@ -774,7 +862,8 @@ class TraceBuffer:
         return {"roots": len(entries),
                 "durationUsP50": _median([e[2] for e in entries]),
                 "selfUsP50": {n: _median(v)
-                              for n, v in sorted(per.items())}}
+                              for n, v in sorted(per.items())},
+                "lock": self.lock_stats()}
 
     @staticmethod
     def _render(record: Dict[str, Any],

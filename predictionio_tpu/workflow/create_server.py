@@ -868,7 +868,8 @@ class QueryServer:
         lanes' unified ``batcher_stats`` (dispatch triggers, batch-fill
         ratio, queue-depth percentiles — one shape for user and item
         lanes), the ``stages`` block (median self time per span name
-        over the newest query roots), the ``device`` block (store +
+        over the newest query roots, and under ``lock`` what threads
+        waited for the span buffer's lock), the ``device`` block (store +
         AOT ladder HBM bytes,
         ladder coverage, flight-recorder dispatch summary), plus the
         process-wide registry snapshot (pio_query_seconds,
@@ -895,8 +896,10 @@ class QueryServer:
     def dispatches_json(self, limit: int = 100) -> Dict[str, Any]:
         """GET /dispatches.json: the device-plane flight recorder —
         the last N dispatches (lane, bucket shape, batch/fill,
-        precision, kernel, AOT hit/miss, queue wait, host + device µs)
-        plus per-lane percentile summaries."""
+        precision, kernel, AOT hit/miss, queue wait, host + device µs,
+        the dispatcher thread's stage stamps, the delivered queries'
+        ``lives``) plus per-lane summaries (percentiles, each stage's
+        sum) and what threads waited for the ring's lock."""
         from predictionio_tpu.utils import device_telemetry
 
         return device_telemetry.recorder().report(limit=limit)

@@ -81,6 +81,14 @@ class TestFlightRecorder:
     def test_capacity_floor(self):
         assert FlightRecorder(capacity=1).capacity == 16
 
+    def test_default_ring_holds_a_window(self, monkeypatch):
+        # 51 s of the busiest session lane is about 2,400 dispatches,
+        # of the user lane about 12,000
+        monkeypatch.delenv("PIO_DEVICE_TELEMETRY_RING", raising=False)
+        assert FlightRecorder().capacity == 16384
+        monkeypatch.setenv("PIO_DEVICE_TELEMETRY_RING", "64")
+        assert FlightRecorder().capacity == 64
+
     def test_kill_switch_fast_path(self, fresh_recorder):
         device_telemetry.set_enabled(False)
         assert not device_telemetry.enabled()
@@ -109,6 +117,60 @@ class TestFlightRecorder:
         assert u["deviceUsP99"] >= u["deviceUsP50"]
         assert u["aot"] == {"hit": 10}
         assert u["meanFill"] is not None
+
+    def test_summary_sums_the_stages_and_the_first_waits(self):
+        rec = FlightRecorder(capacity=64, enabled=True)
+        t0 = time.time()
+        for i in range(5):
+            rec.record({
+                "ts": t0 + i, "lane": "sess", "aot": "hit",
+                "deviceUs": 100.0, "hostUs": 150.0, "queueWaitUs": 900.0,
+                "gapUs": 40.0, "gapIdleUs": 10.0, "gapWindowUs": 5.0,
+                "pickUs": 4.0, "formUs": 6.0, "bookUs": 3.0,
+                "lockWaitUs": 1.0, "otherUs": 2.0, "enqueueUs": 50.0,
+                "fetchUs": 7.0, "deliverUs": 2.0,
+                "lives": [{"firstWaitUs": 10.0 * (i + 1), "rounds": 1,
+                           "ridingUs": 1.0, "betweenUs": 0.0}] * (i % 2)})
+        _record(rec, lane="foldin")       # a record without stage stamps
+        s = rec.summary()
+        assert s["sess"]["stageUs"] == {
+            "gapUs": 200.0, "gapIdleUs": 50.0, "gapWindowUs": 25.0,
+            "pickUs": 20.0, "formUs": 30.0, "bookUs": 15.0,
+            "lockWaitUs": 5.0, "otherUs": 10.0, "enqueueUs": 250.0,
+            "deviceUs": 500.0, "fetchUs": 35.0, "deliverUs": 10.0}
+        assert tuple(s["sess"]["stageUs"]) == device_telemetry.STAGE_FIELDS
+        assert s["sess"]["spanSec"] == 4.0
+        assert s["sess"]["queueWaitUsP50"] == 900.0
+        assert s["sess"]["firstWaitUsP50"] in (20.0, 40.0)   # of 2 lives
+        assert s["foldin"]["firstWaitUsP50"] is None
+        assert s["foldin"]["stageUs"]["gapUs"] == 0.0
+        assert s["foldin"]["stageUs"]["deviceUs"] == 100.0
+        assert s["foldin"]["spanSec"] == 0.0
+
+    def test_counts_carry_the_rings_lock_waits(self):
+        rec = FlightRecorder(capacity=16, enabled=True)
+        _record(rec)
+        counts = rec.counts()
+        assert counts["lockContended"] == 0
+        assert counts["lockWaitedUs"] == 0.0
+        held, release = threading.Event(), threading.Event()
+
+        def holder():
+            with rec._lock:
+                held.set()
+                release.wait(5.0)
+
+        t = threading.Thread(target=holder)
+        t.start()
+        assert held.wait(5.0)
+        threading.Timer(0.02, release.set).start()
+        _record(rec)                        # waits for the holder
+        t.join(timeout=5.0)
+        assert not t.is_alive()
+        counts = rec.counts()
+        assert counts["lockContended"] == 1
+        assert counts["lockWaitedUs"] >= 1_000
+        assert counts["recorded"] == 2
 
     def test_concurrency_stress(self):
         """Dispatcher-style writers + scraper-style readers hammer the
@@ -177,6 +239,26 @@ class TestDispatchInstrumentation:
         assert r["kBucket"] == 16  # k=5 -> min bucket 16 (= n_items)
         assert r["deviceUs"] is not None and r["deviceUs"] >= 0
         assert r["hostUs"] >= r["deviceUs"]
+        srv.close()
+
+    def test_record_lies_on_the_span_clock(self, fresh_recorder):
+        from predictionio_tpu.utils import tracing
+
+        srv = self._store()
+        before = tracing.span_now()
+        with tracing.trace_scope("probe") as root:
+            srv.users_topk(np.arange(4), 5)
+        after = tracing.span_now()
+        (r,) = fresh_recorder.snapshot(10)
+        assert before <= r["calledTs"] < r["readyTs"] <= after
+        assert (r["readyTs"] - r["calledTs"]) * 1e6 == \
+            pytest.approx(r["hostUs"], abs=1.0)
+        # `ts` is the wall clock when the record was written: at ready
+        assert r["ts"] == pytest.approx(r["readyTs"], abs=0.05)
+        spans = tracing.trace_buffer().get(root.trace_id)["spans"]
+        (execute,) = [s for s in spans if s["name"] == "device.execute"]
+        assert execute["start"] == r["calledTs"]
+        assert execute["end"] == pytest.approx(r["readyTs"], abs=1e-6)
         srv.close()
 
     def test_aot_hit_after_warmup(self, fresh_recorder):
@@ -462,7 +544,8 @@ class TestDeployedSurfaces:
         assert status == 200
         assert payload["enabled"] is True
         for key in ("recorded", "retained", "evicted", "capacity",
-                    "summary", "dispatches"):
+                    "lockContended", "lockWaitedUs", "summary",
+                    "dispatches"):
             assert key in payload
         assert payload["recorded"] > 0
         rec = payload["dispatches"][0]
@@ -474,6 +557,12 @@ class TestDeployedSurfaces:
         lane = payload["summary"]["users"]
         assert lane["dispatches"] > 0
         assert lane["deviceUsP50"] is not None
+        # the operator's view of the dispatcher's tiling
+        assert lane["firstWaitUsP50"] is not None
+        assert lane["stageUs"]["gapUs"] >= lane["stageUs"]["otherUs"] >= 0
+        assert lane["stageUs"]["enqueueUs"] > 0 and lane["spanSec"] >= 0
+        _, stats = request(deployed.address, "GET", "/stats.json")
+        assert set(stats["stages"]["lock"]) == {"contended", "waitedUs"}
 
     def test_dispatches_json_limit(self, deployed):
         self._drive(deployed.address)
@@ -566,6 +655,7 @@ class TestDeployedSurfaces:
         assert rc == 0
         out = capsys.readouterr().out
         assert "pio top" in out
+        assert "oldest age p50" in out and "first wait p50" in out
         assert "device" in out and "HBM store" in out
         assert "queries" in out
         assert "\x1b[" not in out  # --once is plain text (scripts/CI)

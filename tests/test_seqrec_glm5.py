@@ -454,6 +454,44 @@ README_ENGINE_JSON = {
     "computeDtype": "bfloat16", "numSteps": 0, "seededWeights": True}
 
 
+def test_a_prefill_on_the_way_is_a_dispatch_of_its_own_in_the_tiling():
+    """A query of a user without a session, through the dispatcher:
+    the prefill is a record of the dispatcher thread before the
+    extend's, no stage holds another dispatch (what is left of either
+    gap is never negative), the lane's bookkeeping is a named stage,
+    and the query's life is on the record that answered it."""
+    import time
+
+    from predictionio_tpu.utils import device_telemetry
+
+    params, _, theta, _ = build()
+    rec = device_telemetry.recorder()
+    srv = server(params, theta, {0: history(12, 1)}, microbatch=None)
+    rec.reset()
+    srv.sess_topk(0, history(3, 5), 5)
+    time.sleep(0.05)            # the last record's stages land
+    recs = rec.snapshot(10)[::-1]
+    srv.close()
+    assert [r["lane"] for r in recs] == ["sesspre", "sess"]
+    assert len({r["dispatcher"] for r in recs}) == 1
+    pre, ext = recs
+    assert ext["otherUs"] >= 0 and ext["bookUs"] > 0
+    # the extend's gap runs from the prefill's ready: its named parts
+    # fit inside it, so none of them holds the prefill's own program
+    assert sum(ext[f] for f in ("gapIdleUs", "gapWindowUs", "pickUs",
+                                "formUs", "lockWaitUs", "otherUs")) \
+        <= ext["gapUs"] + 1
+    (life,) = ext["lives"]
+    assert "lives" not in pre
+    assert life["rounds"] == 1 and life["betweenUs"] == 0.0
+    assert life["ridingUs"] >= pre["hostUs"] + ext["hostUs"]
+    direct = server(params, theta, {0: history(12, 1)}, microbatch=False)
+    rec.reset()
+    direct.sess_topk(0, history(3, 5), 5)
+    assert not any("lives" in r for r in rec.snapshot(10))
+    direct.close()
+
+
 def test_no_steps_without_seeded_weights_is_refused():
     """``numSteps: 0`` persists a model without weights only when the
     parameters say the seeded initial weights are to be served, and

@@ -596,6 +596,50 @@ def test_a_lane_that_hands_nothing_back_records_the_same_stamps():
     srv.close()
 
 
+def test_a_slates_rounds_are_its_life_and_the_lanes_stages_tile_them():
+    """One query through the dispatcher on a lane that has prefilled
+    nothing: the prefill, the events' commit and every round are
+    dispatches of the ONE dispatcher thread, each record's gap is named
+    stages plus a remainder that is never negative, and the query's
+    life counts its rounds; a direct caller leaves no life."""
+    from predictionio_tpu.utils import device_telemetry
+
+    params, theta, cfg = build()
+    hist = {0: history(13, 1)}
+    rec = device_telemetry.recorder()
+    srv = server(params, theta, hist, microbatch=None)
+    rec.reset()
+    idx, _ = srv.sess_topk(0, history(5, 2), 10)
+    time.sleep(0.05)            # the last record's stages land
+    recs = rec.snapshot(100)[::-1]
+    srv.close()
+    assert len(idx) == 10
+    assert len({r["dispatcher"] for r in recs}) == 1
+    lanes = [r["lane"] for r in recs]
+    # 13 + 5 events: 16 committed (12 by the prefill, 4 by the query),
+    # a tail of 2 and a slate of 10 in blocks of 4: three rounds
+    assert lanes == ["sesspre", "sessev", "sess", "sess", "sess"]
+    for r in recs[1:]:
+        assert r["otherUs"] >= 0 and r["bookUs"] > 0 and r["pickUs"] >= 0
+        named = sum(r[f] for f in ("gapIdleUs", "gapWindowUs", "pickUs",
+                                   "formUs", "lockWaitUs", "otherUs"))
+        assert named <= r["gapUs"] + 1
+    (life,) = recs[-1]["lives"]
+    assert not any("lives" in r for r in recs[:-1])
+    assert life["rounds"] == 3 and life["betweenUs"] > 0
+    assert life["ridingUs"] >= sum(r["hostUs"] for r in recs)
+    # the oldest's age at the last round holds the earlier rounds
+    assert recs[-1]["queueWaitUs"] > life["firstWaitUs"] \
+        + sum(r["hostUs"] for r in recs[:-1])
+    direct = server(params, theta, hist)
+    rec.reset()
+    direct.sess_topk(0, history(5, 2), 10)
+    assert [r["lane"] for r in rec.snapshot(100)[::-1]] == lanes
+    assert not any("lives" in r or "otherUs" in r
+                   for r in rec.snapshot(100))
+    direct.close()
+
+
 # -- training is refused ---------------------------------------------------------------
 
 def test_train_seqrec_refuses_the_block():

@@ -15,7 +15,7 @@ import time
 import numpy as np
 import pytest
 
-from predictionio_tpu.ops import als
+from predictionio_tpu.ops import als, serving
 from predictionio_tpu.ops.serving import DeviceTopK
 from predictionio_tpu.utils import device_telemetry, metrics, tracing
 from predictionio_tpu.utils.tracing import Span, TraceBuffer
@@ -27,9 +27,9 @@ from test_device_telemetry import (  # noqa: F401  (fixtures)
 )
 
 QUERY_ROOT = "query POST /queries.json"
-STAGE_ANNOTATIONS = {"batch.idle", "batch.window", "batch.form",
-                     "dispatch.lock", "dispatch.enqueue", "dispatch.wait",
-                     "dispatch.fetch", "batch.deliver"}
+STAGE_ANNOTATIONS = {"batch.idle", "batch.window", "batch.pick",
+                     "batch.form", "dispatch.lock", "dispatch.enqueue",
+                     "dispatch.wait", "dispatch.fetch", "batch.deliver"}
 
 
 @pytest.fixture(autouse=True)
@@ -230,9 +230,9 @@ def _store(microbatch=True):
 
 
 class TestStageStamps:
-    STAMPS = ("gapUs", "gapIdleUs", "gapWindowUs", "formUs", "lockWaitUs",
-              "enqueueUs", "fetchUs", "deliverUs", "queueWaitMeanUs",
-              "dispatcher")
+    STAMPS = ("gapUs", "gapIdleUs", "gapWindowUs", "pickUs", "formUs",
+              "bookUs", "lockWaitUs", "otherUs", "enqueueUs", "fetchUs",
+              "deliverUs", "calledTs", "readyTs", "lives", "dispatcher")
 
     def test_batched_record_holds_every_stamp(self, fresh_recorder):
         srv = _store()
@@ -253,7 +253,13 @@ class TestStageStamps:
             assert r["gapIdleUs"] + r["gapWindowUs"] <= r["gapUs"] + 1
             assert r["enqueueUs"] + r["deviceUs"] == \
                 pytest.approx(r["hostUs"], abs=0.2)
-            assert r["queueWaitMeanUs"] <= r["queueWaitUs"] + 1
+            # a lone query's own first wait IS the oldest's age
+            (life,) = r["lives"]
+            assert life["firstWaitUs"] == pytest.approx(r["queueWaitUs"],
+                                                        abs=0.2)
+            assert life["rounds"] == 1 and life["betweenUs"] == 0.0
+            assert r["readyTs"] - r["calledTs"] == \
+                pytest.approx(r["hostUs"] / 1e6, abs=1e-6)
             assert r["dispatcher"].startswith("pio-microbatch-dispatcher/")
             # a lone query waits out the batching window
             assert r["gapWindowUs"] >= 1000
@@ -264,7 +270,8 @@ class TestStageStamps:
         srv = _store(microbatch=False)
         srv.users_topk(np.arange(4), 5)
         (r,) = fresh_recorder.snapshot(10)
-        assert "gapUs" not in r and "queueWaitMeanUs" not in r
+        assert "gapUs" not in r and "otherUs" not in r
+        assert "lives" not in r     # no dispatcher claimed the query
         assert r["lockWaitUs"] >= 0 and r["enqueueUs"] >= 0
         assert r["formUs"] > 0 and r["fetchUs"] > 0
         srv.close()
@@ -316,11 +323,346 @@ class TestStageStamps:
                     for r in recs[1:])
         wall = (recs[-1]["ts"] - recs[0]["ts"]) * 1e6
         assert tiled == pytest.approx(wall, rel=0.02)
-        # and the named parts stay inside the gap they are parts of
+        # and the named parts, with what is left over, ARE the gap they
+        # are parts of: nothing is counted twice, nothing is dropped
+        # (the user lane books nothing BEFORE a program call: a record's
+        # ``bookUs`` is its own writing and its span, after its ready)
         for prev, r in zip(recs, recs[1:]):
-            parts = (prev["fetchUs"] + prev["deliverUs"] + r["gapIdleUs"]
-                     + r["gapWindowUs"] + r["formUs"] + r["lockWaitUs"])
-            assert parts <= r["gapUs"] + 5
+            parts = (prev["fetchUs"] + prev["deliverUs"] + prev["bookUs"]
+                     + r["gapIdleUs"] + r["gapWindowUs"] + r["pickUs"]
+                     + r["formUs"] + r["lockWaitUs"])
+            assert r["otherUs"] >= 0
+            assert parts + r["otherUs"] == pytest.approx(r["gapUs"], abs=1)
+
+
+# ---------------------------------------------------------------------------
+# a query's life, stamped by the dispatcher
+# ---------------------------------------------------------------------------
+
+class _StubServer:
+    """What a ``BatchDispatcher`` needs of its owner: to be alive."""
+
+
+def _stub_program(group, lane="stub", seconds=0.002):
+    """A laddered dispatch in miniature: a 'program' that takes
+    ``seconds``, recorded with the stamps ``_dispatch_entry`` passes
+    (and like it clock-free with the recorder killed)."""
+    if not device_telemetry.enabled():
+        return time.sleep(seconds)
+    t0e = tracing.span_now()
+    t0 = time.monotonic()
+    time.sleep(seconds)
+    t1 = time.monotonic()
+    return device_telemetry.record_dispatch(
+        lane=lane, kernel="xla", precision="fp32", aot="hit", k_bucket=1,
+        batch=len(group), bucket=8, host_us=(t1 - t0) * 1e6,
+        device_us=(t1 - t0) * 0.9e6, lock_wait_us=0.0, called=t0,
+        ready=t1, called_ts=t0e)
+
+
+def _answer(group):
+    serving._deliver(group, np.zeros((len(group), 1), np.int32),
+                     np.zeros((len(group), 1), np.float32))
+
+
+def _one_round(srv, group):
+    _stub_program(group, lane="plain")
+    _answer(group)
+
+
+class _Rounds:
+    """The slate lane in miniature: a query ``(user, rounds)`` takes
+    ``rounds`` dispatches; ONE query of a user is open at a time, a
+    second one is handed back unopened; ``between`` (if given) runs on
+    the dispatcher thread at the end of a query's first round."""
+
+    def __init__(self, between=None):
+        self.left = {}          # id(item) -> rounds still to ride
+        self.open = {}          # user -> the item that is open
+        self.groups = []        # every group, as tuples of payloads
+        self.between = between
+
+    def __call__(self, srv, group):
+        self.groups.append(tuple(it.payload for it in group))
+        riding = []
+        for it in group:
+            user, rounds = it.payload
+            if self.open.setdefault(user, it) is it:
+                self.left.setdefault(id(it), rounds)
+                riding.append(it)
+        _stub_program(riding, lane="rounds")
+        done = []
+        for it in riding:
+            self.left[id(it)] -= 1
+            if not self.left[id(it)]:
+                done.append(it)
+                del self.open[it.payload[0]]
+        if self.between is not None:
+            between, self.between = self.between, None
+            between()
+        if done:
+            _answer(done)
+        back = [it for it in group if it not in done]
+        return back or None
+
+
+@pytest.fixture
+def lives_of(monkeypatch):
+    """``{payload: (life, delivery - arrival in us)}`` of every query
+    delivered while the test runs: :meth:`_Pending.life` is handed the
+    monotonic clock the delivery read."""
+    seen = {}
+    life = serving._Pending.life
+
+    def spy(self, now):
+        got = life(self, now)
+        seen[self.payload] = (got, (now - self.arrival) * 1e6)
+        return got
+
+    monkeypatch.setattr(serving._Pending, "life", spy)
+    return seen
+
+
+def _adds_up(life, total_us):
+    assert life["firstWaitUs"] + life["ridingUs"] + life["betweenUs"] \
+        == pytest.approx(total_us, abs=0.5)
+    assert min(life["firstWaitUs"], life["ridingUs"],
+               life["betweenUs"]) >= 0
+
+
+class TestQueryLives:
+    def _dispatcher(self, window=0.0):
+        owner = _StubServer()
+        return owner, serving.BatchDispatcher(owner, window=window)
+
+    @staticmethod
+    def _submit_together(d, lane, payloads):
+        """Every payload in the hand-off before the dispatcher thread
+        starts: one group, however late this thread is scheduled."""
+        start, d._ensure_thread = d._ensure_thread, lambda: None
+        try:
+            futs = []
+            for payload in payloads:
+                futs.append(lane.submit_async(payload, 1))
+                time.sleep(0.001)       # distinct arrivals, in order
+        finally:
+            d._ensure_thread = start
+        start()
+        return futs
+
+    def test_one_round_query(self, fresh_recorder, lives_of):
+        owner, d = self._dispatcher(window=0.005)
+        lane = d.add_lane("plain", 8, _one_round)
+        try:
+            res, row = lane.submit_async(("u", 1), 1).result(timeout=10)
+        finally:
+            d.close()
+        life, total = lives_of[("u", 1)]
+        _adds_up(life, total)
+        assert life["rounds"] == 1 and life["betweenUs"] == 0.0
+        assert life["firstWaitUs"] >= 5000          # the window it waited
+        assert life["ridingUs"] >= 2000             # the program it rode
+        (rec,) = fresh_recorder.snapshot(10)
+        assert rec["lives"] == [life] and res.lives[row] == life
+        assert rec["calledTs"] < rec["readyTs"] <= res.delivered \
+            <= tracing.span_now()
+
+    def test_three_rounds_with_another_lane_owed_a_turn(
+            self, fresh_recorder, lives_of):
+        owner, d = self._dispatcher()
+        futures = {}
+        # a query of the OTHER lane arrives while the long one rides its
+        # first round: the dispatcher owes that lane the next turn
+        script = _Rounds(between=lambda: futures.update(
+            plain=plain.submit_async(("v", 1), 1)))
+        rounds = d.add_lane("rounds", 8, script)
+        plain = d.add_lane("plain", 8, _one_round)
+        try:
+            rounds.submit_async(("u", 3), 1).result(timeout=10)
+            futures["plain"].result(timeout=10)
+        finally:
+            d.close()
+        life, total = lives_of[("u", 3)]
+        _adds_up(life, total)
+        assert life["rounds"] == 3
+        recs = fresh_recorder.snapshot(10)[::-1]
+        assert [r["lane"] for r in recs] == \
+            ["rounds", "plain", "rounds", "rounds"]
+        # between its first two rounds the other lane's whole dispatch
+        assert life["betweenUs"] >= recs[1]["hostUs"]
+        assert life["ridingUs"] >= sum(r["hostUs"] for r in recs
+                                       if r["lane"] == "rounds")
+        # the life is on the record of the round that delivered it
+        assert [r.get("lives") for r in recs] == \
+            [None, [lives_of[("v", 1)][0]], None, [life]]
+        _adds_up(*lives_of[("v", 1)])
+        # the oldest's age counts its earlier rounds, its first wait not
+        assert recs[3]["queueWaitUs"] > life["firstWaitUs"] \
+            + sum(r["hostUs"] for r in recs[:3])
+
+    def test_second_query_of_a_user_waits_unopened_behind_the_first(
+            self, fresh_recorder, lives_of):
+        owner, d = self._dispatcher(window=0.02)
+        script = _Rounds()
+        lane = d.add_lane("rounds", 8, script)
+        try:
+            futs = self._submit_together(
+                d, lane, [("u", 2), ("u", 1), ("w", 1)])
+            results = [f.result(timeout=10) for f in futs]
+        finally:
+            d.close()
+        # one window, one group of three; then the two of user u
+        assert script.groups == [(("u", 2), ("u", 1), ("w", 1)),
+                                 (("u", 2), ("u", 1)), (("u", 1),)]
+        for payload in script.groups[0]:
+            _adds_up(*lives_of[payload])
+        first, held, other = (lives_of[p][0] for p in script.groups[0])
+        assert (first["rounds"], held["rounds"], other["rounds"]) == \
+            (2, 3, 1)
+        # the dispatcher cannot tell a round a query was held from one
+        # it rode: its lane took it into the group either way
+        assert held["ridingUs"] > first["ridingUs"] > other["ridingUs"]
+        assert held["betweenUs"] > first["betweenUs"] > 0
+        recs = fresh_recorder.snapshot(10)[::-1]
+        assert [r["lives"] for r in recs] == [[other], [first], [held]]
+        assert [res.lives[row] for res, row in results] == \
+            [first, held, other]
+
+    def test_lives_are_in_the_groups_order(self, fresh_recorder, lives_of):
+        owner, d = self._dispatcher(window=0.05)
+        lane = d.add_lane("plain", 8, _one_round)
+        try:
+            futs = self._submit_together(
+                d, lane, [("u", i) for i in range(4)])
+            results = [f.result(timeout=10) for f in futs]
+        finally:
+            d.close()
+        (rec,) = fresh_recorder.snapshot(10)
+        assert rec["lives"] == [lives_of[("u", i)][0] for i in range(4)]
+        assert [row for _, row in results] == [0, 1, 2, 3]
+        waits = [life["firstWaitUs"] for life in rec["lives"]]
+        assert waits == sorted(waits, reverse=True)     # oldest first
+        assert rec["queueWaitUs"] == pytest.approx(waits[0], abs=0.2)
+
+    def test_killed_recorder_stamps_no_life(self, fresh_recorder,
+                                            monkeypatch):
+        fresh_recorder.enabled = False
+        calls = []
+        for name in ("claim", "handed_back", "life"):
+            monkeypatch.setattr(serving._Pending, name,
+                                lambda self, now, _n=name: calls.append(_n))
+        monkeypatch.setattr(serving._tracing, "span_now",
+                            lambda: calls.append("span_now") or 0.0)
+        owner, d = self._dispatcher()
+        lane = d.add_lane("rounds", 8, _Rounds())
+        try:
+            res, row = lane.submit_async(("u", 3), 1).result(timeout=10)
+        finally:
+            d.close()
+        assert not calls
+        assert res.lives is None and res.delivered is None
+        assert res.telemetry is None and row == 0
+
+    def test_wake_up_is_stamped_on_the_device_span(self, deployed):
+        addr = deployed.address
+        t0 = tracing.span_now()
+        status, _ = request(addr, "POST", "/queries.json",
+                            {"user": "u1", "num": 3})
+        assert status == 200
+        deadline = time.monotonic() + 5.0
+        while not (got := tracing.trace_buffer().stage_summaries(
+                t0, root=QUERY_ROOT)) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        (s,) = got
+        spans = {sp["name"]: sp for sp in tracing.trace_buffer().get(
+            s["traceId"])["spans"]}
+        topk, execute = spans["device.user_topk"], spans["device.execute"]
+        life = topk["attributes"]["life"]
+        assert set(life) == {"firstWaitUs", "rounds", "ridingUs",
+                             "betweenUs"}
+        assert life in topk["attributes"]["dispatch"]["lives"]
+        # the wake-up runs from the delivery, after the dispatch, to
+        # the handler thread running again inside its span
+        wake = topk["attributes"]["wakeUs"]
+        assert 0 < wake <= (topk["end"] - execute["end"]) * 1e6 + 1
+        assert "device.wake" not in spans       # no span of its own
+        assert s["selfUs"]["device.wake"] == wake
+        # device.execute ends before the wake-up starts, both inside
+        # device.user_topk: the device.* self times are that span's
+        # duration, with the wake-up named or not, and what
+        # ``handler_host_p50_us`` subtracts does not move
+        device = sum(us for name, us in s["selfUs"].items()
+                     if name.startswith("device."))
+        assert device == pytest.approx(topk["durationSec"] * 1e6, abs=2.0)
+        assert sum(s["selfUs"].values()) == \
+            pytest.approx(s["durationUs"], abs=2.0)
+
+    def test_a_wake_attribute_is_a_stage_of_its_own(self):
+        root = _span("root", 0.0, 10.0)
+        dev = _span("device.user_topk", 1.0, 9.0, root.span_id)
+        exe = _span("device.execute", 2.0, 5.0, dev.span_id, thread=2)
+        dev.attributes["wakeUs"] = 1.5e6
+        us = tracing.stage_self_times([exe, dev, root])
+        assert us == {"device.execute": pytest.approx(3e6),
+                      "device.wake": pytest.approx(1.5e6),
+                      "device.user_topk": pytest.approx(3.5e6),
+                      "root": pytest.approx(2e6)}
+
+
+class TestRecorderLocks:
+    def test_counts_a_wait_only_when_another_thread_holds_it(
+            self, monkeypatch):
+        lock = tracing.CountedLock()
+        clock = []
+        monkeypatch.setattr(
+            tracing.time, "perf_counter",
+            lambda _real=time.perf_counter: clock.append(1) or _real())
+        for _ in range(100):
+            with lock:
+                pass
+        assert lock.stats() == {"contended": 0, "waitedUs": 0.0}
+        assert not clock        # an uncontended acquisition reads none
+        held, release = threading.Event(), threading.Event()
+
+        def holder():
+            with lock:
+                held.set()
+                release.wait(5.0)
+
+        t = threading.Thread(target=holder)
+        t.start()
+        assert held.wait(5.0)
+        threading.Timer(0.03, release.set).start()
+        with lock:
+            pass
+        t.join(timeout=5.0)
+        assert not t.is_alive()
+        stats = lock.stats()
+        assert stats["contended"] == 1 and len(clock) == 2
+        assert 2_000 <= stats["waitedUs"] < 5e6
+
+    def test_both_recorders_report_their_lock(self, fresh_recorder):
+        buf = TraceBuffer(enabled=True)
+        assert buf.stage_p50("query")["lock"] == buf.lock_stats() == \
+            {"contended": 0, "waitedUs": 0.0}
+        counts = fresh_recorder.counts()
+        assert counts["lockContended"] >= 0 and counts["lockWaitedUs"] >= 0
+        held, release = threading.Event(), threading.Event()
+
+        def holder():
+            with buf._lock:
+                held.set()
+                release.wait(5.0)
+
+        t = threading.Thread(target=holder)
+        t.start()
+        assert held.wait(5.0)
+        threading.Timer(0.02, release.set).start()
+        buf.root_started("t" * 32)          # any path through the lock
+        t.join(timeout=5.0)
+        assert not t.is_alive()
+        lock = buf.stage_p50("query")["lock"]
+        assert lock["contended"] == 1 and lock["waitedUs"] >= 1_000
 
 
 # ---------------------------------------------------------------------------
