@@ -197,19 +197,62 @@ def aux_losses(logits, probs, experts, valid) -> Tuple[Any, Any]:
 
 
 # -- dispatch / combine: permutations, gathers both ways ---------------------
+#
+# What these cost a v5e, measured at the OLMoE widths ([T k, 2048]
+# bf16 rows, k 8; my chip runs, PR 37: each form alone, and the cell's
+# trace, op by op). T 8,192 is a microbatch, 16,384 an encode call:
+#
+# - XLA:TPU compiles every row gather as an op of its own (a DMA
+#   gather; never fused into what reads it), and its time is set by
+#   WHERE ITS SOURCE LIES, not by the bytes: from a source the compiler
+#   put in VMEM (h, g: [T, 2048], 33-67 MB) 65,536 / 131,072 rows take
+#   0.42 / 0.83 ms (6.3 ns a row, the speed of the write); from a
+#   source in HBM (ys, d_xs: [T k, 2048], 268-537 MB) 2.24 / 4.47 ms
+#   (34 ns a row), bf16 or float32 alike. The un-permutations out of
+#   expert order (``_combine``, ``_dispatch_bwd``) are of the second
+#   kind and are over half of what dispatch and combine still cost.
+#   Nothing XLA can be told makes them faster: indices that follow
+#   each other, or ascend, change nothing (2.16 / 4.31); a scatter by
+#   ``order`` takes 3.45 / 6.91; column slices that fit VMEM cost more
+#   in slicing than they save (3.9); rows laid out as one 4 KB tile
+#   each ([T k, 16, 128]) gather in 1.19 but the two layout copies
+#   cost 0.82 each; two hops through token chunks that fit VMEM 5.2.
+# - The reduction over a token's k gathered rows is NOT the slow part:
+#   0.48 / 0.96 ms, the speed of its bytes, whether the block is
+#   [T, k, D] summed over the middle or [k, T, D] added slab by slab,
+#   behind an ``optimization_barrier`` or written ``tk,tkd->td`` (the
+#   compiler's own estimate for it, 2.0M / 4.0M cycles, is four times
+#   too high). Re-spelling it buys nothing; do not try again.
+# - What indexes SCALARS one by one is slow out of all proportion: a
+#   scatter-add of 65,536 / 131,072 ones into 64 bins (``bincount``)
+#   0.57 / 1.14 ms, a scatter of as many int32 0.30 / 0.61, a gather
+#   of as many float32 0.47; a sort of as many keys with a payload
+#   0.045 / 0.10. So the plan and the backward pass move scalars by
+#   SORTING (``_place``) and count by compare-and-sum.
+
+def _place(values, to):
+    """``out[to[i]] = values[i]`` for a permutation ``to`` of scalars,
+    as a SORT by ``to`` that carries ``values`` (a tenth of XLA's
+    scatter or gather of as many scalars: the section comment). With
+    ``to`` a permutation's inverse it is ``values[perm]``."""
+    return jax.lax.sort((to, values), num_keys=1)[1]
+
 
 def dispatch_plan(experts, n_experts: int) -> Dict[str, Any]:
     """From ``experts: [T, k]`` the plan of a dropless dispatch:
     ``order`` (pair indices sorted by expert, stable, so a token's
     pairs keep their order inside an expert), its inverse ``inv``, the
     token of each sorted row and ``group_sizes`` ``[E]`` (their sum is
-    ``T * k``: nothing is dropped)."""
+    ``T * k``: nothing is dropped). Two sorts and a compare-and-sum,
+    no scatter and no ``bincount`` (the section comment)."""
     T, k = experts.shape
-    flat = experts.reshape(-1)
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(T * k, dtype=jnp.int32))
-    group_sizes = jnp.bincount(flat, length=n_experts).astype(jnp.int32)
+    flat = experts.reshape(-1).astype(jnp.int32)
+    pair = jnp.arange(T * k, dtype=jnp.int32)
+    _, order = jax.lax.sort((flat, pair), num_keys=1, is_stable=True)
+    inv = _place(pair, order)
+    group_sizes = jnp.sum(
+        flat[:, None] == jnp.arange(n_experts, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
     return {"order": order, "inv": inv, "token": order // k,
             "group_sizes": group_sizes}
 
@@ -218,8 +261,10 @@ def _rows(x, idx):
     """``x[idx]`` for indices known to be in range (permutations and
     their quotients). ``jnp.take``'s default fills out-of-range rows
     with NaN, and that select costs the TPU 2.8 times the gather (2.55
-    against 0.91 ms for 131,072 rows of 2,048 bf16: my chip run, PR
-    25)."""
+    against 0.91 ms for 131,072 rows of 2,048 bf16 out of 16,384: my
+    chip run, PR 25). That 0.91 ms is a gather whose SOURCE fits VMEM;
+    the same 131,072 rows out of a 537 MB source in HBM take 4.47 ms
+    (the section comment; my chip runs, PR 37)."""
     return jnp.take(x, idx, axis=0, mode="clip")
 
 
@@ -258,12 +303,14 @@ def _combine_fwd(ys, weights, order, inv):
 def _combine_bwd(res, g):
     ys, weights, order, inv = res
     T, k = weights.shape
-    g = g.astype(jnp.float32)
-    w_sorted = _rows(weights.reshape(-1), order)
-    d_ys = (_rows(g, order // k)
-            * w_sorted[:, None].astype(jnp.float32)).astype(ys.dtype)
-    back = _rows(ys, inv).reshape(T, k, ys.shape[-1])
-    d_w = jnp.sum(back.astype(jnp.float32) * g[:, None, :], axis=-1)
+    # ``g``'s rows in expert order, gathered once for both gradients
+    gs = _rows(g.astype(jnp.float32), order // k)
+    w_sorted = _place(weights.reshape(-1), inv)
+    d_ys = (gs * w_sorted[:, None].astype(jnp.float32)).astype(ys.dtype)
+    # the weights' gradient where the rows lie, <ys[r], g[token[r]]>,
+    # in the pass that writes ``d_ys``; then T k scalars to token order
+    dw_sorted = jnp.sum(ys.astype(jnp.float32) * gs, axis=-1)
+    d_w = _place(dw_sorted, order).reshape(T, k)
     return d_ys, d_w.astype(weights.dtype), None, None
 
 

@@ -260,6 +260,116 @@ def test_dropless_dispatch_equals_the_dense_masked_form(kind):
         np.testing.assert_allclose(g, gd, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("case", [
+    "random picks", "every pick is expert 3", "one pick a token",
+    "a bin past the held experts"])
+def test_dispatch_plan_is_the_stable_sort_its_inverse_and_the_counts(case):
+    """The plan is made of sorts and a compare-and-sum (no scatter):
+    held to numpy's stable argsort, the scattered inverse and
+    ``bincount``; ``_place`` to the indexing it stands for."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(5)
+    T, k, E = 48, (1 if case == "one pick a token" else 4), 8
+    experts = rng.integers(0, E, size=(T, k))
+    if case == "every pick is expert 3":
+        experts[:] = 3
+    if case == "a bin past the held experts":
+        experts, E = np.where(experts < 5, experts, 5), 6
+    plan = moe.dispatch_plan(jnp.asarray(experts, jnp.int32), E)
+    flat = experts.reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    inv = np.empty_like(order)
+    inv[order] = np.arange(T * k)
+    assert plan["order"].dtype == plan["inv"].dtype == jnp.int32
+    assert np.array_equal(plan["order"], order)
+    assert np.array_equal(plan["inv"], inv)
+    assert np.array_equal(plan["token"], order // k)
+    assert plan["group_sizes"].dtype == jnp.int32
+    assert np.array_equal(plan["group_sizes"], np.bincount(flat, minlength=E))
+    values = np.asarray(rng.normal(size=T * k), np.float32)
+    assert np.array_equal(moe._place(jnp.asarray(values), plan["inv"]),
+                          values[order])
+    assert np.array_equal(moe._place(jnp.asarray(values), plan["order"]),
+                          values[inv])
+
+
+PERMUTATIONS = {
+    "64 tokens, 8 picks": (64, 8, False),
+    "32 tokens, 8 picks, a share's weightless last run": (32, 8, True),
+    "16 tokens, 1 pick": (16, 1, False),
+}
+
+
+def _permutation(T, k, share):
+    """A dispatch plan with its rows in expert order. ``share``: the
+    plan of ``moe_ffn_share`` for experts 2..5 of 8: picks of the other
+    four sort into a last run, whose rows are zero and whose weights
+    are 0. Some held picks weigh 0 too (a router may say so)."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(T * k)
+    D, E = 40, 8
+    experts = jnp.asarray(rng.integers(0, E, size=(T, k)), jnp.int32)
+    weights = np.asarray(rng.random((T, k)), np.float32)
+    weights[rng.random((T, k)) < 0.2] = 0.0
+    ys = np.asarray(rng.normal(size=(T * k, D)), np.float32)
+    if share:
+        local = (experts >= 2) & (experts < 6)
+        plan = moe.dispatch_plan(jnp.where(local, experts - 2, 4), 5)
+        ys[int(plan["group_sizes"][:4].sum()):] = 0.0
+        weights = np.where(local, weights, 0.0)
+        assert 0 < int(plan["group_sizes"][4]) < T * k
+    else:
+        plan = moe.dispatch_plan(experts, E)
+    x = np.asarray(rng.normal(size=(T, D)), np.float32)
+    g = np.asarray(rng.normal(size=(T, D)), np.float32)
+    return plan, jnp.asarray(ys), jnp.asarray(weights), jnp.asarray(x), \
+        jnp.asarray(g)
+
+
+@pytest.mark.parametrize("which", ["combine", "dispatch"])
+@pytest.mark.parametrize("case", list(PERMUTATIONS))
+def test_permutations_equal_the_plain_expressions(case, which):
+    """The two custom VJPs alone (op by op: under jit LLVM contracts a
+    multiply-add on the CPU and the last bit is the compiler's) against
+    ``jax.vjp`` of what they stand for: the forward bit for bit, the
+    gradients to float32 rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    T, k, share = PERMUTATIONS[case]
+    plan, ys, weights, x, g = _permutation(T, k, share)
+    order, inv, token = plan["order"], plan["inv"], plan["token"]
+    if which == "dispatch":
+        got, back = jax.vjp(lambda x: moe._dispatch(x, token, inv), x)
+        want, plain = jax.vjp(lambda x: x[token], x)
+        assert np.array_equal(got, want)
+        d = jnp.asarray(np.random.default_rng(1).normal(size=got.shape),
+                        jnp.float32)
+        np.testing.assert_allclose(back(d)[0], plain(d)[0], rtol=1e-6,
+                                   atol=1e-6)
+        return
+    got, back = jax.vjp(
+        lambda ys, w: moe._combine(ys, w, order, inv), ys, weights)
+    want, plain = jax.vjp(
+        lambda ys, w: jnp.sum(ys[inv].reshape(T, k, -1) * w[..., None],
+                              axis=1), ys, weights)
+    assert got.dtype == jnp.float32 and np.array_equal(got, want)
+    (d_ys, d_w), (p_ys, p_w) = back(g), plain(g)
+    np.testing.assert_allclose(d_ys, p_ys, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(d_w, p_w, rtol=1e-5, atol=1e-5)
+    # a pick that weighs 0 adds nothing forward, and its weight's
+    # gradient is still <its row, g>: the router learns from it
+    zero = np.asarray(weights) == 0.0
+    dots = np.einsum("tkd,td->tk", np.asarray(ys)[np.asarray(inv)].reshape(
+        T, k, -1).astype(np.float64), np.asarray(g, np.float64))
+    assert zero.any() and np.abs(dots[zero]).max() > 0.1
+    np.testing.assert_allclose(np.asarray(d_w)[zero], dots[zero], rtol=1e-5,
+                               atol=1e-5)
+    assert not np.asarray(d_ys)[np.asarray(inv).reshape(T, k)[zero]].any()
+
+
 def test_router_weights_are_not_renormalised():
     import jax
     import jax.numpy as jnp
