@@ -409,10 +409,12 @@ PAGED_NEG = -1e30   # "no key yet": finite, so that an empty cache is no NaN
 
 
 def paged_gqa_attention_xla(q, pool_k, pool_v, table, length, *,
-                            scale: float, compute_dtype=None):
+                            scale: float, compute_dtype=None, base=None,
+                            first=None):
     """The paged attention below, the plain way (any backend; the
     kernel's oracle): the session's blocks are GATHERED out of the
-    pool (``[B, S, KV, d]`` copies) and scored under a length mask."""
+    pool (``[B, S, KV, d]`` copies) and scored under a length mask
+    and, with ``first``, each query row's own lower bound."""
     import jax.numpy as jnp
 
     B, KV, RG, d = q.shape
@@ -423,7 +425,13 @@ def paged_gqa_attention_xla(q, pool_k, pool_v, table, length, *,
     vs = jnp.take(pool_v, table, axis=0, mode="clip").reshape(B, S, KV, d)
     s = jnp.einsum("bkrd,bskd->bkrs", q.astype(cd), ks.astype(cd),
                    preferred_element_type=jnp.float32) * scale
-    ok = (jnp.arange(S)[None, :] < length[:, None])[:, None, None, :]
+    at = jnp.arange(S)[None, :]
+    if first is None:
+        ok = (at < length[:, None])[:, None, None, :]
+    else:
+        at = at + (0 if base is None else base[:, None])
+        ok = ((at < length[:, None])[:, None, :]
+              & (at[:, None, :] >= first[:, :, None]))[:, None]
     s = jnp.where(ok, s, PAGED_NEG)
     m = jnp.max(s, axis=-1)
     p = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
@@ -432,14 +440,22 @@ def paged_gqa_attention_xla(q, pool_k, pool_v, table, length, *,
     return acc, m, jnp.sum(p, axis=-1)
 
 
-def _paged_gqa_kernel(table_ref, nblk_ref, len_ref, q_ref, k_ref, v_ref,
-                      acc_ref, m_ref, l_ref, acc_sc, m_sc, l_sc, *,
-                      bs: int, n_kv: int, d: int, scale: float):
+def _paged_gqa_kernel(*refs, bs: int, n_kv: int, d: int, scale: float,
+                      bounded: bool):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
+    if bounded:
+        (table_ref, nblk_ref, len_ref, start_ref, q_ref, lo_ref, k_ref,
+         v_ref, acc_ref, m_ref, l_ref, acc_sc, m_sc, l_sc) = refs
+    else:
+        (table_ref, nblk_ref, len_ref, q_ref, k_ref, v_ref,
+         acc_ref, m_ref, l_ref, acc_sc, m_sc, l_sc) = refs
     b, j = pl.program_id(0), pl.program_id(1)
+    # the table's block this step reads: with bounds, the blocks wholly
+    # before every row's first visible key are skipped
+    blk = j + start_ref[b] if bounded else j
 
     @pl.when(j == 0)
     def _():
@@ -447,10 +463,12 @@ def _paged_gqa_kernel(table_ref, nblk_ref, len_ref, q_ref, k_ref, v_ref,
         m_sc[...] = jnp.full_like(m_sc, PAGED_NEG)
         l_sc[...] = jnp.zeros_like(l_sc)
 
-    @pl.when(j < nblk_ref[b])
+    @pl.when(blk < nblk_ref[b])
     def _():
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        col = blk * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
         ok = col < len_ref[b]
+        if bounded:
+            ok = ok & (col >= lo_ref[0][:, :1])               # [RG, bs]
         for h in range(n_kv):
             qh = q_ref[0, h]                                  # [RG, d]
             kh = k_ref[0, :, h * d:(h + 1) * d]               # [bs, d]
@@ -476,7 +494,7 @@ def _paged_gqa_kernel(table_ref, nblk_ref, len_ref, q_ref, k_ref, v_ref,
 
 
 def paged_gqa_attention(q, pool_k, pool_v, table, length, *, scale: float,
-                        interpret: bool = False):
+                        base=None, first=None, interpret: bool = False):
     """Attention of a FEW query rows over a long per-sequence cache
     that lives in blocks of a shared pool, read WHERE IT LIES: ``q [B,
     KV, RG, d]`` (for each of the ``KV`` key/value heads the ``RG``
@@ -490,13 +508,25 @@ def paged_gqa_attention(q, pool_k, pool_v, table, length, *, scale: float,
     (``sum_s exp(score - m)``), so that the caller can join them with
     the keys that are not in the cache (the block being decoded).
 
+    With ``first [B, RG]`` the rows differ in their FIRST visible key
+    (a sliding window): query row ``r`` sees the cached positions
+    ``first[b, r] <= position < length[b]``, ``base [B]`` being the
+    position of the table's first row (a multiple of ``bs``; None: 0:
+    a window layer's table starts at the oldest block the session
+    still holds). Rows before a row's bound are masked inside the
+    kernel, and the blocks that lie wholly before every row's bound
+    are never fetched. Without ``first`` the call, and the kernel it
+    compiles to, are as they were.
+
     A Pallas TPU kernel: grid ``(B, nb)``; the block table, the
     sequences' block counts and lengths are prefetched scalars, so
     block ``j`` of sequence ``b`` is DMA'd straight from pool block
     ``table[b, j]`` (a step past the sequence's last block names that
     block again: nothing is fetched, nothing computed). No ``[B, S,
     ...]`` copy of the cache exists; bytes read are the cached rows'
-    own. ``d`` a multiple of 128 lanes."""
+    own. ``d`` a multiple of 128 lanes. ``RG`` is padded to whole
+    sublane tiles here (7 query heads a key/value head make 7 x token
+    rows); the pad rows see nothing and are cut off the result."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -505,36 +535,59 @@ def paged_gqa_attention(q, pool_k, pool_v, table, length, *, scale: float,
     B, KV, RG, d = q.shape
     bs = pool_k.shape[1]
     nb = table.shape[1]
+    bounded = first is not None
     length = length.astype(jnp.int32)
-    nblk = (length + bs - 1) // bs
-
-    def kv_map(b, j, table_ref, nblk_ref, len_ref):
-        last = jnp.maximum(nblk_ref[b] - 1, 0)
-        return (table_ref[b, jnp.minimum(j, last)], 0, 0)
-
+    sub = 8 * max(1, 4 // jnp.dtype(q.dtype).itemsize)
+    pad = -RG % sub
+    RP = RG + pad
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad), (0, 0)))
     def q_map(b, j, *_):
         return (b, 0, 0, 0)
 
-    out_shape = (jax.ShapeDtypeStruct((B, KV, RG, d), jnp.float32),
-                 jax.ShapeDtypeStruct((B, KV, RG, 128), jnp.float32),
-                 jax.ShapeDtypeStruct((B, KV, RG, 128), jnp.float32))
+    scalars = [table.astype(jnp.int32), None, length]
+    operands = [q]
+    in_specs = [pl.BlockSpec((1, KV, RP, d), q_map)]
+    if bounded:
+        # everything relative to the table's first row
+        if base is not None:
+            length = length - base.astype(jnp.int32)
+            first = first - base.astype(jnp.int32)[:, None]
+        lo = jnp.maximum(first.astype(jnp.int32), 0)
+        start = jnp.min(lo, axis=1) // bs
+        # a pad row's bound lies past every key
+        lo = jnp.pad(lo, ((0, 0), (0, pad)),
+                     constant_values=jnp.iinfo(jnp.int32).max)
+        scalars = [scalars[0], None, length, start]
+        operands.append(jnp.broadcast_to(lo[:, :, None], (B, RP, 128)))
+        in_specs.append(pl.BlockSpec((1, RP, 128),
+                                     lambda b, j, *_: (b, 0, 0)))
+    scalars[1] = nblk = (length + bs - 1) // bs
+
+    def kv_map(b, j, table_ref, nblk_ref, len_ref, *start_ref):
+        last = jnp.maximum(nblk_ref[b] - 1, 0)
+        at = j + start_ref[0][b] if start_ref else j
+        return (table_ref[b, jnp.minimum(at, last)], 0, 0)
+
+    out_shape = (jax.ShapeDtypeStruct((B, KV, RP, d), jnp.float32),
+                 jax.ShapeDtypeStruct((B, KV, RP, 128), jnp.float32),
+                 jax.ShapeDtypeStruct((B, KV, RP, 128), jnp.float32))
     acc, m, l = pl.pallas_call(
         functools.partial(_paged_gqa_kernel, bs=bs, n_kv=KV, d=d,
-                          scale=float(scale)),
+                          scale=float(scale), bounded=bounded),
         out_shape=out_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(B, nb),
-            in_specs=[pl.BlockSpec((1, KV, RG, d), q_map),
-                      pl.BlockSpec((1, bs, KV * d), kv_map),
-                      pl.BlockSpec((1, bs, KV * d), kv_map)],
-            out_specs=[pl.BlockSpec((1, KV, RG, d), q_map),
-                       pl.BlockSpec((1, KV, RG, 128), q_map),
-                       pl.BlockSpec((1, KV, RG, 128), q_map)],
-            scratch_shapes=[pltpu.VMEM((KV, RG, d), jnp.float32),
-                            pltpu.VMEM((KV, RG, 128), jnp.float32),
-                            pltpu.VMEM((KV, RG, 128), jnp.float32)]),
+            num_scalar_prefetch=len(scalars), grid=(B, nb),
+            in_specs=in_specs + [pl.BlockSpec((1, bs, KV * d), kv_map),
+                                 pl.BlockSpec((1, bs, KV * d), kv_map)],
+            out_specs=[pl.BlockSpec((1, KV, RP, d), q_map),
+                       pl.BlockSpec((1, KV, RP, 128), q_map),
+                       pl.BlockSpec((1, KV, RP, 128), q_map)],
+            scratch_shapes=[pltpu.VMEM((KV, RP, d), jnp.float32),
+                            pltpu.VMEM((KV, RP, 128), jnp.float32),
+                            pltpu.VMEM((KV, RP, 128), jnp.float32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret, name="paged_gqa_attention",
-    )(table.astype(jnp.int32), nblk, length, q, pool_k, pool_v)
-    return acc, m[..., 0], l[..., 0]
+    )(*scalars, *operands, pool_k, pool_v)
+    return acc[:, :, :RG], m[:, :, :RG, 0], l[:, :, :RG, 0]

@@ -769,6 +769,58 @@ def _user_rows(uid, n_rows: int):
     return jnp.where(uid < 0, n_rows, uid)
 
 
+def score_head(theta, X, seen_bits, Y, x_last, uid, n_new, tok, tvalid,
+               counts, *, eps: float, kb: int, n_items: int, mode: str,
+               mask_seen: bool):
+    """The end of an extend dispatch, whatever the backbone: ``x_last
+    [B, D]`` (the residual stream at each query's last new event)
+    through the final norm into the users' rows of ``X``, the scores
+    against ``Y``, the seen mask (the new events ``tok`` under
+    ``tvalid`` marked first) and the top ``kb``, packed with ``counts``
+    (float32 counters, the same in every row, as int32 bits behind the
+    ``2 kb`` result columns: one fetch brings everything). A query
+    without new events scores its stored row. Returns ``(packed, X,
+    seen_bits, every item's scores before any mask)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops.als_pallas import unpack_seen_bits
+    from predictionio_tpu.ops.serving import _pack, _score_einsum
+
+    B = uid.shape[0]
+    h_new = rms_norm(x_last, theta["ln_f_g"], eps)
+    h_old = jnp.take(X, uid, axis=0, mode="clip").astype(jnp.float32)
+    if X.dtype == jnp.bfloat16:
+        # round HERE, by an operation the compiler may not elide:
+        # with excess precision allowed it scored the unrounded
+        # state while the store kept the rounded one, and the same
+        # prefix asked again (no new events: the stored row) gave
+        # other scores (my chip run, PR 30)
+        h_new = jax.lax.reduce_precision(h_new, exponent_bits=8,
+                                         mantissa_bits=7)
+    hq = jnp.where((n_new > 0)[:, None], h_new, h_old).astype(X.dtype)
+    urow = _user_rows(uid, X.shape[0])
+    X = X.at[urow].set(hq, mode="drop")
+    scores = _score_einsum("mr,br->bm", Y, hq.astype(Y.dtype), mode=mode)
+    masked = jnp.where(jnp.arange(scores.shape[1])[None, :] < n_items,
+                       scores, -jnp.inf)
+    if mask_seen:
+        words = seen_bits.shape[1]
+        rows = jnp.take(seen_bits, uid, axis=0, mode="clip") | jax.vmap(
+            lambda t, v: _new_bits(t, v, words))(tok, tvalid)
+        seen_bits = seen_bits.at[urow].set(rows, mode="drop")
+        masked = jnp.where(jax.vmap(
+            lambda r: unpack_seen_bits(r, scores.shape[1]))(rows),
+            -jnp.inf, masked)
+    vals, top = jax.lax.top_k(masked, kb)
+    packed = jnp.concatenate(
+        [_pack(vals, top), jnp.broadcast_to(
+            jax.lax.bitcast_convert_type(counts.astype(jnp.float32),
+                                         jnp.int32),
+            (B, counts.shape[0]))], axis=-1)
+    return packed, X, seen_bits, scores
+
+
 def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
                 kb: int, T: int, S: int, bs: int, n_items: int, mode: str,
                 mask_seen: bool, audit: bool = False):
@@ -800,9 +852,6 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
     token rows route too and count for nothing)."""
     import jax
     import jax.numpy as jnp
-
-    from predictionio_tpu.ops.als_pallas import unpack_seen_bits
-    from predictionio_tpu.ops.serving import _pack, _score_einsum
 
     B = ints.shape[0]
     D = spec.width
@@ -877,42 +926,13 @@ def extend_step(theta, X, seen_bits, lat, ik, Y, ints, *, spec: GlmSpec,
                 kept["gates"].append(take_last(weights))
                 kept["h2"].append(take_last(h2))
     with jax.named_scope("sess/head"):
-        h_new = rms_norm(jnp.take_along_axis(
-            x, last[:, None, None], axis=1)[:, 0], theta["ln_f_g"],
-            spec.norm_eps)
-        h_old = jnp.take(X, uid, axis=0, mode="clip").astype(jnp.float32)
-        if X.dtype == jnp.bfloat16:
-            # round HERE, by an operation the compiler may not elide:
-            # with excess precision allowed it scored the unrounded
-            # state while the store kept the rounded one, and the same
-            # prefix asked again (no new events: the stored row) gave
-            # other scores (my chip run, PR 30)
-            h_new = jax.lax.reduce_precision(h_new, exponent_bits=8,
-                                             mantissa_bits=7)
-        hq = jnp.where((n_new > 0)[:, None], h_new, h_old).astype(X.dtype)
-        urow = _user_rows(uid, X.shape[0])
-        X = X.at[urow].set(hq, mode="drop")
-        scores = _score_einsum("mr,br->bm", Y, hq.astype(Y.dtype),
-                               mode=mode)
-        masked = jnp.where(jnp.arange(scores.shape[1])[None, :] < n_items,
-                           scores, -jnp.inf)
-        if mask_seen:
-            words = seen_bits.shape[1]
-            rows = jnp.take(seen_bits, uid, axis=0, mode="clip") | jax.vmap(
-                lambda t, v: _new_bits(t, v, words))(tok, tvalid)
-            seen_bits = seen_bits.at[urow].set(rows, mode="drop")
-            masked = jnp.where(jax.vmap(
-                lambda r: unpack_seen_bits(r, scores.shape[1]))(rows),
-                -jnp.inf, masked)
-        vals, top = jax.lax.top_k(masked, kb)
-        # the counters ride behind the 2 kb result columns, the same
-        # three numbers in every row: one fetch brings everything
         counts = jnp.stack([share_n, share_d, local_n, touched]).astype(
             jnp.float32)
-        packed = jnp.concatenate(
-            [_pack(vals, top), jnp.broadcast_to(
-                jax.lax.bitcast_convert_type(counts, jnp.int32),
-                (B, COUNTERS))], axis=-1)
+        packed, X, seen_bits, scores = score_head(
+            theta, X, seen_bits, Y, jnp.take_along_axis(
+                x, last[:, None, None], axis=1)[:, 0], uid, n_new, tok,
+            tvalid, counts, eps=spec.norm_eps, kb=kb, n_items=n_items,
+            mode=mode, mask_seen=mask_seen)
     if not audit:
         return packed, X, seen_bits, tuple(lat), tuple(ik), None
     empty = {"picks": jnp.zeros((0, B, spec.per_token), jnp.int32),

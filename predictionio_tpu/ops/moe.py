@@ -361,17 +361,28 @@ def moe_ffn(h, w_router, w_gate, w_up, w_down, *, k: int,
     return y, stats
 
 
-def moe_ffn_dense(h, w_router, w_gate, w_up, w_down, *, k: int):
+# the experts' gate activation, by name (data of the call): SiLU (OLMoE,
+# GLM-5, SDAR) or ReLU (SmallThinker's ReGLU)
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def moe_ffn_dense(h, w_router, w_gate, w_up, w_down, *, k: int,
+                  renorm: bool = False, activation: str = "silu",
+                  router_input=None):
     """The same layer the plain way: every expert on every token, the
     result masked to the top ``k``. ``O(E / k)`` times the work; the
-    dispatch path's oracle in the tests."""
+    dispatch path's oracle in the tests. ``router_input``: what the
+    router reads where that is not ``h`` (a router placed before
+    attention)."""
     hp = jax.lax.Precision.HIGHEST
-    _, probs, experts, weights = route(h, w_router, k)
+    _, probs, experts, weights = route(
+        h if router_input is None else router_input, w_router, k,
+        renorm=renorm)
     E = w_router.shape[1]
     gate = jnp.einsum("td,edf->tef", h, w_gate, precision=hp)
     up = jnp.einsum("td,edf->tef", h, w_up, precision=hp)
-    out = jnp.einsum("tef,efd->ted", jax.nn.silu(gate) * up, w_down,
-                     precision=hp)
+    out = jnp.einsum("tef,efd->ted", ACTIVATIONS[activation](gate) * up,
+                     w_down, precision=hp)
     w_full = jnp.sum(jax.nn.one_hot(experts, E, dtype=h.dtype)
                      * weights[..., None], axis=1)
     return jnp.sum(out * w_full[..., None], axis=1)
@@ -398,7 +409,7 @@ def route_sigmoid(h, w_router, bias, k: int, scale: float):
 
 
 def moe_ffn_share(h, experts, weights, w_gate, w_up, w_down, *,
-                  first: int, compute_dtype=None):
+                  first: int, compute_dtype=None, activation: str = "silu"):
     """The routed part of an expert layer that HOLDS experts ``first
     .. first + E_held`` of those the router chose among (``w_gate`` /
     ``w_up``: ``[E_held, D, F]``, ``w_down: [E_held, F, D]``, already
@@ -408,8 +419,9 @@ def moe_ffn_share(h, experts, weights, w_gate, w_up, w_down, *,
     shares the parts are the uncut layer). The dispatch plan, grouped
     matmuls and combine are :func:`moe_ffn`'s: absent picks sort into
     a last, weightless run that no group covers and whose rows are
-    zeroed. Returns ``(y [T, D] float32, local [T, k] bool,
-    group_sizes [E_held])``."""
+    zeroed. ``activation`` names the gate's (:data:`ACTIVATIONS`).
+    Returns ``(y [T, D] float32, local [T, k] bool, group_sizes
+    [E_held])``."""
     T, k = experts.shape
     held = w_gate.shape[0]
     cd = compute_dtype or h.dtype
@@ -424,7 +436,7 @@ def moe_ffn_share(h, experts, weights, w_gate, w_up, w_down, *,
     with jax.named_scope("moe/gmm_gate_up"):
         gate = jnp.where(covered, gmm(xs, w_gate, rhs_low=w_gate), 0)
         up = jnp.where(covered, gmm(xs, w_up, rhs_low=w_up), 0)
-        act = (jax.nn.silu(gate.astype(jnp.float32))
+        act = (ACTIVATIONS[activation](gate.astype(jnp.float32))
                * up.astype(jnp.float32)).astype(cd)
     with jax.named_scope("moe/gmm_down"):
         ys = jnp.where(covered, gmm(act, w_down, rhs_low=w_down), 0)
@@ -435,6 +447,7 @@ def moe_ffn_share(h, experts, weights, w_gate, w_up, w_down, *,
 
 
 __all__ = [
+    "ACTIVATIONS",
     "GMM_TILING",
     "aux_losses",
     "dispatch_plan",

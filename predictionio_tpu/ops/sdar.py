@@ -177,24 +177,29 @@ def rope_half(x, pos, theta: float):
                            axis=-1)
 
 
-def project(theta, i: int, h, pos, spec: SdarSpec):
+def project(theta, i: int, h, pos, spec: SdarSpec, rotate: bool = True):
     """A layer's attention operands from the normed input ``h: [T, D]``
     at positions ``pos: [T]``: queries ``[T, H, d]`` and keys ``[T, KV,
     d]``, each RMS-normed over its ``d`` values with the projection's
-    one learned weight and rotated; values ``[T, KV, d]``."""
+    one learned weight (where the model has QK norms: ``qn_g`` /
+    ``kn_g`` in ``theta``) and, with ``rotate``, rotated; values ``[T,
+    KV, d]``."""
     p = f"l{i}_"
     T, d = h.shape[0], spec.head_dim
     q = _mm(h, theta[p + "wq"], spec).reshape(T, spec.n_heads, d)
     k = _mm(h, theta[p + "wk"], spec).reshape(T, spec.n_kv, d)
     v = _mm(h, theta[p + "wv"], spec).reshape(T, spec.n_kv, d)
-    q = rope_half(rms_norm(q, theta[p + "qn_g"], spec.norm_eps), pos,
-                  spec.rope_theta)
-    k = rope_half(rms_norm(k, theta[p + "kn_g"], spec.norm_eps), pos,
-                  spec.rope_theta)
+    if p + "qn_g" in theta:
+        q = rms_norm(q, theta[p + "qn_g"], spec.norm_eps)
+        k = rms_norm(k, theta[p + "kn_g"], spec.norm_eps)
+    if rotate:
+        q = rope_half(q, pos, spec.rope_theta)
+        k = rope_half(k, pos, spec.rope_theta)
     return q, k, v
 
 
-def moe_layer(theta, i: int, h2, valid, spec: SdarSpec):
+def moe_layer(theta, i: int, h2, valid, spec: SdarSpec, router_input=None,
+              activation: str = "silu"):
     """The expert layer on normed ``h2: [T, D]``: softmax over the 128
     router logits in float32, the 8 largest, their weights divided by
     their sum; every expert is held, so this is ``moe_ffn_share`` with
@@ -202,21 +207,24 @@ def moe_layer(theta, i: int, h2, valid, spec: SdarSpec):
     too: one dispatch plan, one pair of grouped matmuls, one combine).
     A token row ``valid`` marks False (padding of a query bucket)
     routes NOWHERE: its picks fall past the held range, so no expert's
-    weights are read for it. Returns ``(y [T, D], picks [T, k], gates
-    [T, k], experts that got a row)``."""
+    weights are read for it. ``router_input``: what the router reads
+    where that is not ``h2`` (``ops/smallthinker.py``: the attention's
+    input); ``activation``: the experts' gate's. Returns ``(y [T, D],
+    picks [T, k], gates [T, k], experts that got a row)``."""
     import jax.numpy as jnp
 
     from predictionio_tpu.ops import moe
 
     p = f"l{i}_"
     cd = jnp.dtype(spec.compute_dtype)
-    _, _, experts, weights = moe.route(h2, theta[p + "router"],
-                                       spec.per_token, renorm=spec.renorm)
+    _, _, experts, weights = moe.route(
+        h2 if router_input is None else router_input, theta[p + "router"],
+        spec.per_token, renorm=spec.renorm)
     sent = jnp.where(valid[:, None], experts, spec.n_experts)
     y, _, gs = moe.moe_ffn_share(
         h2, sent, weights, theta[p + "we_gate"].astype(cd),
         theta[p + "we_up"].astype(cd), theta[p + "we_down"].astype(cd),
-        first=0, compute_dtype=cd)
+        first=0, compute_dtype=cd, activation=activation)
     return y, experts, weights, jnp.sum(gs > 0)
 
 
@@ -261,12 +269,15 @@ def sdar_layer(theta, i: int, x, seg, pos, spec: SdarSpec):
 
 # -- the served programs, over the block cache -----------------------------------
 
-def cache_attend(q, pool_k, pool_v, table, length, spec: SdarSpec):
+def cache_attend(q, pool_k, pool_v, table, length, spec: SdarSpec,
+                 base=None, first=None):
     """``q [B, R, H, d]`` over each row's cached keys (every one
-    visible: the cache holds whole earlier blocks): the unnormalised
-    online-softmax parts ``(acc [B, R, H, d], m [B, R, H], l)``. On a
-    TPU with whole 128-lane heads the pool is read where it lies
-    (``attention.paged_gqa_attention``); elsewhere the blocks are
+    visible: the cache holds whole earlier blocks; or, with ``base [B]``
+    the position of the table's first row and ``first [B, R]`` each
+    token row's first visible position, those from it on): the
+    unnormalised online-softmax parts ``(acc [B, R, H, d], m [B, R, H],
+    l)``. On a TPU with whole 128-lane heads the pool is read where it
+    lies (``attention.paged_gqa_attention``); elsewhere the blocks are
     gathered (its oracle)."""
     import jax
     import jax.numpy as jnp
@@ -279,13 +290,17 @@ def cache_attend(q, pool_k, pool_v, table, length, spec: SdarSpec):
     # [B, R, KV, G, d] -> [B, KV, R x G, d]: a KV head's query rows
     qk = q.reshape(B, R, KV, G, d).transpose(0, 2, 1, 3, 4).reshape(
         B, KV, R * G, d).astype(cd)
+    bounds = {}
+    if first is not None:
+        # a KV head's query rows are (token row, head of the group)
+        bounds = dict(base=base, first=jnp.repeat(first, G, axis=1))
     if jax.default_backend() == "tpu" and d % 128 == 0:
         acc, m, l = attention.paged_gqa_attention(
-            qk, pool_k, pool_v, table, length, scale=spec.scale)
+            qk, pool_k, pool_v, table, length, scale=spec.scale, **bounds)
     else:
         acc, m, l = attention.paged_gqa_attention_xla(
             qk, pool_k, pool_v, table, length, scale=spec.scale,
-            compute_dtype=cd)
+            compute_dtype=cd, **bounds)
 
     def back(a):
         a = a.reshape((B, KV, R, G) + a.shape[3:])
@@ -295,7 +310,7 @@ def cache_attend(q, pool_k, pool_v, table, length, spec: SdarSpec):
 
 
 def attend(q, k_loc, v_loc, ok_loc, pool_k, pool_v, table, length,
-           spec: SdarSpec):
+           spec: SdarSpec, base=None, first=None):
     """Attention of ``q [B, R, H, d]`` over the cache (above) JOINED
     with the keys that are not in it: ``k_loc`` / ``v_loc`` ``[B, J,
     KV, d]`` (a query's scratch rows and the block itself) under
@@ -305,7 +320,8 @@ def attend(q, k_loc, v_loc, ok_loc, pool_k, pool_v, table, length,
 
     B, R, H, d = q.shape
     KV, G = spec.n_kv, spec.group
-    acc_c, m_c, l_c = cache_attend(q, pool_k, pool_v, table, length, spec)
+    acc_c, m_c, l_c = cache_attend(q, pool_k, pool_v, table, length, spec,
+                                   base, first)
     s = _ein("brkgd,bjkd->brkgj", q.reshape(B, R, KV, G, d), k_loc,
              spec) * spec.scale
     ok = ok_loc[:, :, None, None, :]

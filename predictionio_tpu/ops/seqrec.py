@@ -141,6 +141,14 @@ class SeqRecParams(Params):
     remasking: str = "low_confidence_static"
     confidence_threshold: float = 0.9
     mask_token: int = -1
+    # the smallthinker block (ops/smallthinker.py), under config.json's
+    # names: a layer ``i`` with ``sliding_window_layout[i]`` 1 is rotated
+    # and attends the ``sliding_window_size`` newest positions, one with
+    # 0 has no positions and attends all (the published ``rope_layout``
+    # is the same list); ``max_seq_len`` is the positions a served
+    # session may hold (``max_position_embeddings``)
+    sliding_window_size: int = 0
+    sliding_window_layout: Tuple[int, ...] = ()
     # the session lane that serves that block (ops/sessions.py): the
     # cache pool's rows (0: twice the stored histories) and how many
     # dispatches' audits it keeps for a check to read (0: the
@@ -189,6 +197,18 @@ SDAR_30B_A3B = dict(
     tied=False, vocab_rows=151936, n_experts=128, expert_width=768,
     experts_per_token=8, norm_topk_prob=True, mask_token=151669)
 
+# the block of SmallThinker-21BA3B-Instruct
+# (https://huggingface.co/PowerInfer/SmallThinker-21BA3B-Instruct,
+# model_name smallthinker_21b_instruct) as its config.json publishes
+# it; ``n_layers`` is the deployment's (the layout's period is 4)
+SMALLTHINKER_21B_A3B = dict(
+    block="smallthinker", rank=2560, n_heads=28, n_kv_heads=4,
+    head_dim=128, norm="rmsnorm", norm_eps=1e-6, positions="rope",
+    rope_theta=1500000.0, tied=False, vocab_rows=151936, n_experts=64,
+    expert_width=768, experts_per_token=6, norm_topk_prob=True,
+    sliding_window_size=4096, sliding_window_layout=(0, 1, 1, 1) * 13,
+    max_seq_len=16384)
+
 
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
@@ -212,6 +232,7 @@ class BlockSpec:
     compute_dtype: str
     glm: Any = None   # ops/mla.py::GlmSpec of the glm_moe_dsa block
     sdar: Any = None  # ops/sdar.py::SdarSpec of the sdar_moe block
+    swa: Any = None   # ops/smallthinker.py::SwaSpec of the smallthinker block
 
     @property
     def sparse(self) -> bool:
@@ -258,6 +279,11 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         from predictionio_tpu.ops import sdar as _sdar
 
         sdar = _sdar.sdar_spec(params)
+    swa = None
+    if params.block == "smallthinker":
+        from predictionio_tpu.ops import smallthinker
+
+        swa = smallthinker.swa_spec(params)
     return BlockSpec(
         params.block, int(params.n_layers), H, head_dim, params.norm,
         float(params.norm_eps), params.positions,
@@ -265,7 +291,7 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         int(params.n_experts) if sparse else 0,
         int(params.experts_per_token) if sparse else 0,
         float(params.lb_coef), float(params.z_coef),
-        params.compute_dtype, glm, sdar)
+        params.compute_dtype, glm, sdar, swa)
 
 
 @dataclasses.dataclass
@@ -474,6 +500,10 @@ def _theta_shapes(n_items: int, params: SeqRecParams
         from predictionio_tpu.ops import sdar as _sdar
 
         return _sdar.theta_shapes(V, spec.sdar)
+    if spec.swa is not None:
+        from predictionio_tpu.ops import smallthinker
+
+        return smallthinker.theta_shapes(V, spec.swa)
     A = spec.n_heads * spec.head_dim
     out: List[Tuple[str, Tuple[int, ...], Any]] = [
         ("item_emb", (V, D), ("div", math.sqrt(D)))]
@@ -724,9 +754,22 @@ def _sdar_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
     return sdar.sdar_layer(theta, i, x, seg, pos, spec.sdar), None
 
 
+def _smallthinker_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
+                        attention_fn, low):
+    """SmallThinker's layer (``ops/smallthinker.py``): grouped-query
+    attention of the layer's kind (global without positions, or
+    rotated inside a sliding window), then ReGLU experts routed from
+    the attention's input."""
+    from predictionio_tpu.ops import smallthinker
+
+    return smallthinker.smallthinker_layer(theta, i, x, seg, pos,
+                                           spec.swa), None
+
+
 # one function per layer kind; ``SeqRecParams.block`` names one
 BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer,
-          "glm_moe_dsa": _glm_layer, "sdar_moe": _sdar_layer}
+          "glm_moe_dsa": _glm_layer, "sdar_moe": _sdar_layer,
+          "smallthinker": _smallthinker_layer}
 
 
 def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
@@ -1167,6 +1210,11 @@ def train_seqrec(buckets, n_items: int, params: SeqRecParams,
             "the next-item loss would read its own target; the "
             "masked-block diffusion objective is not implemented "
             "(ROADMAP Reach). Serve it with numSteps 0 and seededWeights")
+    if spec.swa is not None and int(params.num_steps) > 0:
+        raise ValueError(
+            "the smallthinker block is not trained here (no training "
+            "cell holds it: the packed rows of 4,096 never reach its "
+            "window). Serve it with numSteps 0 and seededWeights")
     with _tracing.span("seq.stage"):
         if theta is None:
             theta = init_theta_device(n_items, params)

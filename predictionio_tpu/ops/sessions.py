@@ -8,20 +8,31 @@ ladder) whose store holds, beside the output table ``Y``:
 - the weights of the BACKBONE it is handed (:func:`backbone_of`: the
   ``glm_moe_dsa`` block, :class:`GlmBackbone` over ``ops/mla.py``; the
   ``sdar_moe`` block, ``ops/slates.py::SdarBackbone`` over
-  ``ops/sdar.py``);
+  ``ops/sdar.py``; the ``smallthinker`` block,
+  :class:`SmallThinkerBackbone` over ``ops/smallthinker.py``);
 - ``X``: every user's LAST hidden state (final norm applied), so that
   the inherited ``users`` lane answers a query without new events;
 - a POOL of cache blocks: per layer one array ``[blocks, bs, width]``
   for every per-token cache row the backbone DECLARES (``cache_rows``:
-  GLM-5's latent and index key; SDAR's key and value rows), all under
-  one block table, so a block id names a session's rows of every kind
-  in every layer. Block 0 is never handed out: padding writes land
-  there.
+  GLM-5's latent and index key; SDAR's and SmallThinker's key and
+  value rows), under one block table and one free list a LAYER KIND
+  the backbone declares (``kinds``: :class:`LayerKind`: which layers,
+  and how many trailing positions a layer of that kind reads; None:
+  all). A block id names a session's rows of every ``cache_rows``
+  entry in every layer OF ITS KIND. A kind that keeps ``keep``
+  positions gives a session's oldest blocks back as its end moves on:
+  a block that lies wholly before ``length - keep + 1`` is read by no
+  later query, so a window layer's table starts at the oldest block
+  the session still holds. GLM-5 and SDAR declare ONE kind that keeps
+  everything; SmallThinker a global and a window kind. Block 0 of
+  every kind is never handed out: padding writes land there.
 
-The manager keeps the blocks, the tables, eviction, the waves two
-queries of one user take, the ladder and the lane; the backbone brings
-its prefill-chunk program, the programs of its queries with their
-ladder entries, the lane's dispatch function and its audits.
+The manager keeps the blocks, the tables, allocation, release and
+eviction over all kinds (a session leaves every kind at once), the
+waves two queries of one user take, the ladder and the lane; the
+backbone brings its prefill-chunk program, the programs of its queries
+with their ladder entries, the lane's dispatch function and its
+audits.
 
 GLM-5's query ``(user, new events, k)`` appends the events to the
 user's session and recommends: ONE dispatch runs the backbone over the
@@ -46,9 +57,10 @@ lives under ``_sess_lock``; the device tables are swapped under
 from __future__ import annotations
 
 import collections
+import functools
 import logging
 import threading
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -81,17 +93,63 @@ SESS_FLOOR_SELECTIONS = 8
 NO_ROW = -1              # the user row of a query row that writes none
 
 
+class LayerKind(NamedTuple):
+    """A kind of layer a backbone declares: the layers of that kind
+    and how many trailing positions one of them reads (None: all)."""
+
+    name: str
+    layers: Tuple[int, ...]
+    keep: Optional[int]
+
+
+def one_kind(n_layers: int) -> Tuple[LayerKind, ...]:
+    """Every layer of one kind that keeps everything."""
+    return (LayerKind("all", tuple(range(n_layers)), None),)
+
+
+@functools.lru_cache(maxsize=None)     # (asked for every query row formed)
+def kind_layout(kinds, T: int, S: int, bs: int
+                ) -> Tuple[Tuple[Tuple[int, int, int, int], ...], int]:
+    """Where a row of ``ints`` (``[3 header, item ids x T, ...]``)
+    holds each kind's part, kinds in order: ``(cache rows to write x
+    T, [a kind that keeps a window: the position of its table's first
+    row,] its block table)``. Returns per kind ``(offset of the cache
+    rows, of the base | -1, of the table, the table's blocks)`` and the
+    row's width. A kind that keeps everything has ``S / bs`` blocks; a
+    window kind the ``T`` rows' and the ``keep - 1`` positions before
+    them, wherever they fall in their blocks. One kind that keeps all:
+    ``3 + 2T + S / bs``, the layout the lane has always had."""
+    out, at = [], 3 + T
+    for kind in kinds:
+        nb = S // bs
+        if kind.keep is not None:
+            nb = min(nb, -(-(kind.keep + T) // bs) + 1)
+        table = at + T + (kind.keep is not None)
+        out.append((at, -1 if kind.keep is None else at + T, table, nb))
+        at = table + nb
+    return tuple(out), at
+
+
 class _Session:
-    __slots__ = ("items", "events", "length", "blocks", "touched",
+    __slots__ = ("items", "events", "length", "held", "first", "touched",
                  "inflight")
 
-    def __init__(self, items: np.ndarray):
+    def __init__(self, items: np.ndarray, kinds: int = 1):
         self.items = np.asarray(items, dtype=np.int32)
         self.events = len(self.items)   # events the session holds
         self.length = 0          # events whose rows are in the cache
-        self.blocks: List[int] = []
+        # a layer kind each: the blocks it holds, oldest first, and how
+        # many older ones it has given back (a window kind's)
+        self.held: List[List[int]] = [[] for _ in range(kinds)]
+        self.first = [0] * kinds
         self.touched = 0
         self.inflight = 0        # queries between two of their rounds
+
+    @property
+    def blocks(self) -> List[int]:
+        """The first kind's blocks (the only kind's of most
+        backbones)."""
+        return self.held[0]
 
     def append(self, items) -> None:
         """``items`` behind the session's events (ids only: the caller
@@ -159,6 +217,7 @@ class GlmBackbone:
     entries = ("sess", "sesspre")
     commit_multiple = 1
     max_batch = SESS_MAX_BATCH
+    max_positions = 0       # events a session may hold (0: the ladder's)
     dispatch = staticmethod(_dispatch_sess_group)
 
     def __init__(self, params):
@@ -167,6 +226,7 @@ class GlmBackbone:
         self.spec = spec = mla.glm_spec(params)
         self.width = spec.width
         self.compute_dtype = spec.compute_dtype
+        self.kinds = one_kind(spec.n_layers)
         # (name, width, the component's name in memory_report())
         self.cache_rows = (("lat", spec.lat_width, "sessionLatents"),
                            ("ik", spec.idx_dim, "sessionIndexKeys"))
@@ -288,9 +348,7 @@ class GlmBackbone:
                     k = len(items)
                     ints[j, 0], ints[j, 1], ints[j, 2] = u, sess.length, k
                     ints[j, 3:3 + k] = items
-                    ints[j, 3 + T:3 + T + k] = m._phys(
-                        sess, np.arange(sess.length, sess.length + k))
-                    ints[j, 3 + 2 * T:] = m._table(sess, S)
+                    m._kind_fill(ints[j], sess, T, S, sess.length, k)
             host, audit = self._run_extend(m, ints, kb, S, n)
             with _dtel.stage("bookUs", "batch.book", done=True):
                 m._clock += 1
@@ -300,6 +358,7 @@ class GlmBackbone:
                         sess.append(items)
                         sess.length = sess.events
                         tokens += len(items)
+                        m._trim(sess, sess.length)
                     sess.touched = m._clock
                 if audit is not None and (m._watched is None or any(
                         int(u) in m._watched for u, _ in rows)):
@@ -316,19 +375,22 @@ class GlmBackbone:
                 _metrics.SESS_POSITIONS.inc(
                     amount=sum(s_.length for s_ in sessions))
                 idx, scores = _unpack(host[:, :2 * kb], kb)
-                selected, eligible, local, touched = (
-                    float(c) for c in host[0, 2 * kb:].view(np.float32))
-                if eligible > 0:
-                    _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
-                    _metrics.SESS_SELECTED.inc(amount=selected,
-                                               kind="selected")
-                    _metrics.SESS_SELECTED.inc(amount=eligible,
-                                               kind="eligible")
-                if local > 0:
-                    _metrics.SESS_LOCAL_PICKS.inc(amount=local)
-                if touched > 0:
-                    _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
+                self._book_counters(
+                    *(float(c) for c in host[0, 2 * kb:].view(np.float32)))
         return idx[:n], scores[:n]
+
+    @staticmethod
+    def _book_counters(selected, eligible, local, touched) -> None:
+        """The counters that rode behind a dispatch's packed columns
+        (``mla.extend_step``)."""
+        if eligible > 0:
+            _metrics.SESS_SELECTED_SHARE.set(selected / eligible)
+            _metrics.SESS_SELECTED.inc(amount=selected, kind="selected")
+            _metrics.SESS_SELECTED.inc(amount=eligible, kind="eligible")
+        if local > 0:
+            _metrics.SESS_LOCAL_PICKS.inc(amount=local)
+        if touched > 0:
+            _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
 
     def audits(self, m: "SessionTopK", kept, uid: int
                ) -> List[Dict[str, Any]]:
@@ -357,6 +419,87 @@ class GlmBackbone:
                 / max(sum(rows.values()), 1)}
 
 
+SWA_CHUNK = 2048        # tokens a prefill chunk of SmallThinker holds
+
+
+class SmallThinkerBackbone(GlmBackbone):
+    """SmallThinker's block (``ops/smallthinker.py``) as the session
+    lane serves it: key and value rows a token and layer, layers of TWO
+    kinds (global layers keep every position, window layers the
+    ``sliding_window_size`` newest), a query answered by ONE extend
+    dispatch as GLM-5's is, events committed one by one, a session held
+    to the model's ``max_position_embeddings``."""
+
+    def __init__(self, params):
+        from predictionio_tpu.ops import smallthinker
+
+        self.spec = spec = smallthinker.swa_spec(params)
+        self.width = spec.width
+        self.compute_dtype = spec.compute_dtype
+        self.kinds = tuple(LayerKind(*k) for k in spec.kinds)
+        self.max_positions = spec.max_positions
+        self.cache_rows = (("k", spec.kv_width, "sessionKeys"),
+                           ("v", spec.kv_width, "sessionValues"))
+        # a prefill chunk: a quarter of the longest session's bucket at
+        # most; the shortest cached-length bucket is four chunks
+        self.chunk = max(4, min(
+            SWA_CHUNK, _bucket(max(spec.max_positions, 16)) // 4))
+        self.floor = 4 * self.chunk
+        self.qb = min(32, self.chunk)
+
+    def serving_theta(self, theta):
+        from predictionio_tpu.ops import smallthinker
+
+        return smallthinker.serving_theta(theta, self.spec)
+
+    def draw_theta(self, V: int, params):
+        from predictionio_tpu.ops import smallthinker
+
+        return smallthinker.draw_serving_theta(V, params)
+
+    def extend_program(self, m: "SessionTopK", kb: int, S: int):
+        def make():
+            import jax
+
+            from predictionio_tpu.ops import smallthinker
+
+            def swa_extend(theta, X, seen_bits, pool, Y, ints):
+                return smallthinker.extend_step(
+                    theta, X, seen_bits, pool, Y, ints, spec=self.spec,
+                    kb=kb, T=SESS_EVENTS, S=S, bs=m._bs, n_items=m.n_items,
+                    mode=m._mode, layout=m._kind_layout(SESS_EVENTS, S),
+                    audit=bool(m._audit_keep))
+
+            return jax.jit(swa_extend, donate_argnums=(1, 2, 3))
+
+        return m._program(("sess", kb, S), make)
+
+    def prefill_program(self, m: "SessionTopK", S: int):
+        def make():
+            import jax
+
+            from predictionio_tpu.ops import smallthinker
+
+            def swa_prefill(theta, X, pool, ints):
+                return smallthinker.prefill_chunk(
+                    theta, X, pool, ints, spec=self.spec, C=self.chunk,
+                    S=S, bs=m._bs, qb=self.qb,
+                    layout=m._kind_layout(self.chunk, S))
+
+            return jax.jit(swa_prefill, donate_argnums=(1, 2))
+
+        return m._program(("sesspre", S), make)
+
+    @staticmethod
+    def _book_counters(read_global, read_window, touched, _spare) -> None:
+        """``smallthinker.extend_step``'s counters."""
+        for kind, rows in (("global", read_global), ("window", read_window)):
+            if rows > 0:
+                _metrics.SESS_ROWS_READ.inc(amount=rows, kind=kind)
+        if touched > 0:
+            _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
+
+
 def backbone_of(params):
     """The backbone that serves ``params.block`` from per-user
     caches."""
@@ -366,7 +509,11 @@ def backbone_of(params):
         from predictionio_tpu.ops.slates import SdarBackbone
 
         return SdarBackbone(params)
-    raise ValueError(f"no session backbone for block {params.block!r}")
+    if params.block == "smallthinker":
+        return SmallThinkerBackbone(params)
+    raise ValueError(
+        f"no session backbone for block {params.block!r}: the lane "
+        "serves glm_moe_dsa, sdar_moe and smallthinker")
 
 
 class SessionTopK(DeviceTopK):
@@ -374,15 +521,19 @@ class SessionTopK(DeviceTopK):
     ``theta``: the backbone's parameters as served (the backbone's
     ``serving_theta`` / ``draw_theta``, without ``out_emb``);
     ``histories``: ``{user row: item ids, oldest first}``;
-    ``pool_tokens``: the pool's cache rows
-    (``SeqRecParams.session_pool_tokens``; 0: twice the stored
-    histories); ``audit``: how many dispatches' audits the lane keeps
+    ``pool_tokens``: the cache rows the pool of a layer kind that
+    keeps everything holds (``SeqRecParams.session_pool_tokens``; 0:
+    twice the stored histories); a kind that keeps a window gets the
+    share of it that the stored histories' blocks under the window are
+    of their blocks whole, so both kinds fill alike;
+    ``audit``: how many dispatches' audits the lane keeps
     (``SeqRecParams.session_audit``; 0: the programs compute none; see
     :meth:`audits`); ``backbone``: :func:`backbone_of` ``(params)``
     when None. A block holds ``SESS_BLOCK`` rows; the programs are
     laddered over the backbone's query buckets and over the cached
     length in powers of two from the backbone's ``floor`` to twice the
-    longest stored history's bucket."""
+    longest stored history's bucket (the backbone's ``max_positions``
+    at most: a session that an append would take past it is refused)."""
 
     def __init__(self, item_factors, theta: Dict[str, Any], params,
                  n_users: int, histories: Optional[Dict[int, Any]] = None,
@@ -415,9 +566,15 @@ class SessionTopK(DeviceTopK):
         self._histories = {int(u): np.asarray(h, dtype=np.int32)
                            for u, h in (histories or {}).items()}
         self._bs = SESS_BLOCK
+        self._kinds: Tuple[LayerKind, ...] = tuple(bb.kinds)
+        self._windowed = [k for k, kind in enumerate(self._kinds)
+                          if kind.keep is not None]
         lo = _bucket(bb.floor, lo=self._bs)
         longest = max((len(h) for h in self._histories.values()), default=0)
         self._s_max = 2 * _bucket(longest, lo=lo)
+        if bb.max_positions:
+            self._s_max = max(lo, min(self._s_max,
+                                      _bucket(bb.max_positions, lo=lo)))
         self._s_buckets = []
         s = lo
         while s <= self._s_max:
@@ -426,18 +583,31 @@ class SessionTopK(DeviceTopK):
         self._chunk = bb.chunk
         stored = sum(len(h) for h in self._histories.values())
         tokens = int(pool_tokens) or max(2 * stored, 4 * lo)
-        self._n_blocks = 1 + max(2, -(-tokens // self._bs))
+        whole = max(2, -(-tokens // self._bs))
+        # what the stored histories hold of each kind once resident
+        need = np.sum([self._blocks_of(len(h)) for h in
+                       self._histories.values()] or
+                      [[0] * len(self._kinds)], axis=0)
+        self._kind_blocks = [
+            1 + (whole if kind.keep is None or not need[0] else max(
+                -(-whole * int(n) // int(need[0])),
+                self._blocks_of(self._s_max, chunk=self._chunk)[k] + 1))
+            for k, (kind, n) in enumerate(zip(self._kinds, need))]
+        self._layer_kind = {i: k for k, kind in enumerate(self._kinds)
+                            for i in kind.layers}
         cache_dtype = jnp.dtype(bb.compute_dtype)
         with self._store_lock, _trace_span("store.upload"):
-            shape = (self._n_blocks, self._bs)
             self._pool = {
-                name: tuple(jnp.zeros(shape + (width,), cache_dtype)
-                            for _ in range(bb.spec.n_layers))
+                name: tuple(jnp.zeros(
+                    (self._kind_blocks[self._layer_kind[i]], self._bs,
+                     width), cache_dtype)
+                    for i in range(bb.spec.n_layers))
                 for name, width, _ in bb.cache_rows}
             jax.block_until_ready(self._pool)
         self._sess_lock = threading.RLock()
         self._sessions: Dict[int, _Session] = {}
-        self._free = list(range(self._n_blocks - 1, 0, -1))
+        # a free list a kind; block 0 of each is the spare
+        self._frees = [list(range(n - 1, 0, -1)) for n in self._kind_blocks]
         self._clock = 0
         self._audit_keep = int(audit)
         self._audits: collections.deque = collections.deque(
@@ -450,9 +620,9 @@ class SessionTopK(DeviceTopK):
             self._sess_batcher = self._dispatcher.add_lane(
                 "pio-microbatch-sess", max_batch=bb.max_batch,
                 dispatch_fn=bb.dispatch)
-        _metrics.SESS_CACHE_CAPACITY.set(
-            (self._n_blocks - 1) * self._bs)
-        _metrics.SESS_CACHE_TOKENS.set(0)
+        _metrics.SESS_CACHE_CAPACITY.set(self._row_layers(
+            [n - 1 for n in self._kind_blocks]))
+        self._note_fill()
 
     # -- programs and the ladder ------------------------------------------
 
@@ -479,15 +649,18 @@ class SessionTopK(DeviceTopK):
             prog = self._sess_programs[key] = make()
         return prog
 
+    def _kind_layout(self, T: int, S: int) -> Tuple:
+        return kind_layout(self._kinds, T, S, self._bs)[0]
+
     def _ints_width(self, T: int, S: int) -> int:
-        return 3 + 2 * T + S // self._bs
+        return kind_layout(self._kinds, T, S, self._bs)[1]
 
     def _store_sig(self, tables: Dict[str, Any]) -> Tuple:
         # (by name: a dict comes back from a program with sorted keys)
         first = self._pool[self._bb.cache_rows[0][0]][0]
         return super()._store_sig(tables) + (
             tuple(first.shape), str(first.dtype), self._spec,
-            bool(self._audit_keep))
+            bool(self._audit_keep)) + tuple(self._kind_blocks[1:])
 
     def aot_plan(self, max_k: int = 128,
                  batch_sizes: Tuple[int, ...] = ()) -> List[Tuple]:
@@ -543,8 +716,11 @@ class SessionTopK(DeviceTopK):
                            key=lambda u: -len(self._histories[u]))
             for u in order:
                 with self._sess_lock:
-                    need = -(-len(self._histories[u]) // self._bs) + 1
-                    if u in self._sessions or need > len(self._free):
+                    need = self._blocks_of(len(self._histories[u]),
+                                           chunk=self._chunk)
+                    if u in self._sessions or any(
+                            n + 1 > len(free)
+                            for n, free in zip(need, self._frees)):
                         continue
                     self._ensure_session(u, busy=())
         self._resident_s = time.perf_counter() - t0
@@ -564,51 +740,129 @@ class SessionTopK(DeviceTopK):
         row[0] = NO_ROW
         return row
 
-    def _phys(self, sess: _Session, pos: np.ndarray) -> np.ndarray:
-        blocks = np.asarray(sess.blocks, dtype=np.int64)
-        return (blocks[pos // self._bs] * self._bs
+    @property
+    def _free(self) -> List[int]:
+        """The first kind's free list (the only kind's of most
+        backbones)."""
+        return self._frees[0]
+
+    @_free.setter
+    def _free(self, blocks: List[int]) -> None:
+        self._frees[0] = blocks
+
+    def _blocks_of(self, length: int, chunk: int = 0) -> List[int]:
+        """Blocks a session of ``length`` cached events holds of each
+        kind: all of them, or under a window the blocks that still hold
+        one of the ``keep - 1`` positions before its end (``chunk``:
+        while its last chunk of that many tokens is prefilled, the
+        most it ever holds)."""
+        whole = -(-int(length) // self._bs)
+        return [whole if kind.keep is None else min(
+            whole, whole - max(0, int(length) - chunk - kind.keep + 1)
+            // self._bs) for kind in self._kinds]
+
+    def _row_layers(self, blocks) -> float:
+        """``blocks`` a kind as cache rows: row-layers summed over the
+        kinds, over the layers (one kind: its rows), so that the
+        gauges count what is HELD whichever kind holds it."""
+        return self._bs * sum(n * len(kind.layers) for n, kind in
+                              zip(blocks, self._kinds)) \
+            / sum(len(kind.layers) for kind in self._kinds)
+
+    def _held_blocks(self) -> List[int]:
+        return [n - 1 - len(free)
+                for n, free in zip(self._kind_blocks, self._frees)]
+
+    def _phys(self, sess: _Session, pos: np.ndarray, kind: int = 0
+              ) -> np.ndarray:
+        blocks = np.asarray(sess.held[kind], dtype=np.int64)
+        return (blocks[pos // self._bs - sess.first[kind]] * self._bs
                 + pos % self._bs).astype(np.int32)
 
-    def _note_fill(self) -> None:
-        _metrics.SESS_CACHE_TOKENS.set(
-            (self._n_blocks - 1 - len(self._free)) * self._bs)
+    def _kind_fill(self, row: np.ndarray, sess: _Session, T: int, S: int,
+                   pos0: int, n: int) -> None:
+        """Every kind's part of a row of ``ints`` (:func:`kind_layout`)
+        for ``n`` new rows at positions ``pos0 ..`` of ``sess``."""
+        pos = np.arange(pos0, pos0 + n)
+        for k, (w, b, t, nb) in enumerate(self._kind_layout(T, S)):
+            row[w:w + n] = self._phys(sess, pos, k)
+            if b >= 0:
+                row[b] = sess.first[k] * self._bs
+            have = min(len(sess.held[k]), nb)
+            row[t:t + have] = sess.held[k][:have]
 
-    def _make_room(self, need: int, keep: Optional[_Session], busy) -> None:
-        """``need`` free blocks, evicting the sessions touched longest
-        ago (never ``keep``, one of ``busy`` or one with a query
-        between two of its rounds) when fewer are free."""
-        while len(self._free) < need:
+    def _note_fill(self) -> None:
+        held = self._held_blocks()
+        _metrics.SESS_CACHE_TOKENS.set(self._row_layers(held))
+        for kind, n in zip(self._kinds, held):
+            _metrics.SESS_KIND_TOKENS.set(n * self._bs, kind=kind.name)
+
+    def _make_room(self, need, keep: Optional[_Session], busy) -> None:
+        """``need`` free blocks (a count a kind; an int: of the first
+        kind), evicting the sessions touched longest ago (never
+        ``keep``, one of ``busy`` or one with a query between two of
+        its rounds) when fewer are free. A session leaves every kind
+        at once."""
+        if isinstance(need, int):
+            need = [need] + [0] * (len(self._kinds) - 1)
+        while any(len(free) < n for free, n in zip(self._frees, need)):
             victims = [(s.touched, u) for u, s in self._sessions.items()
-                       if s is not keep and u not in busy and s.blocks
+                       if s is not keep and u not in busy and any(s.held)
                        and not s.inflight]
             if not victims:
                 raise RuntimeError(
-                    f"the session pool ({self._n_blocks - 1} blocks "
-                    f"of {self._bs}) cannot hold {need} more blocks "
-                    "beside the sessions of this dispatch")
+                    f"the session pool ({[n - 1 for n in self._kind_blocks]}"
+                    f" blocks of {self._bs}) cannot hold {list(need)} more "
+                    "blocks beside the sessions of this dispatch")
             self.release(min(victims)[1])
             _metrics.SESS_EVICTIONS.inc()
 
     def _reserve(self, sess: _Session, length: int, busy) -> None:
-        """Blocks for ``length`` cached events (:meth:`_make_room`)."""
-        need = -(-int(length) // self._bs) - len(sess.blocks)
-        if need <= 0:
+        """Blocks for ``length`` cached events in every kind
+        (:meth:`_make_room`)."""
+        whole = -(-int(length) // self._bs)
+        need = [max(0, whole - first - len(held))
+                for first, held in zip(sess.first, sess.held)]
+        if not any(need):
             return
-        with _trace_span("sess.cache_alloc", attributes={"blocks": need}):
+        with _trace_span("sess.cache_alloc",
+                         attributes={"blocks": sum(need)}):
             self._make_room(need, sess, busy)
-            sess.blocks += [self._free.pop() for _ in range(need)]
+            for held, free, n in zip(sess.held, self._frees, need):
+                held += [free.pop() for _ in range(n)]
             self._note_fill()
 
+    def _trim(self, sess: _Session, floor: int) -> None:
+        """The next rows of ``sess`` lie at positions ``floor ..``: a
+        kind that keeps ``keep`` positions gives back the blocks that
+        lie wholly before ``floor - keep + 1``."""
+        released = 0
+        for k in self._windowed:
+            lo = max(0, int(floor) - self._kinds[k].keep + 1) // self._bs
+            n = min(lo - sess.first[k], len(sess.held[k]))
+            if n > 0:
+                self._frees[k] += sess.held[k][:n]
+                del sess.held[k][:n]
+                released += n
+            sess.first[k] = max(lo, sess.first[k])
+        if released:
+            _metrics.SESS_BLOCKS_RELEASED.inc(amount=released)
+            self._note_fill()
+
+    def _give_back(self, sess: _Session) -> None:
+        for free, held in zip(self._frees, sess.held):
+            free += held
+        self._note_fill()
+
     def release(self, uid: int) -> None:
-        """Give a session's blocks back; its events stay on the host
-        (the next touch prefills them again)."""
+        """Give a session's blocks of every kind back; its events stay
+        on the host (the next touch prefills them again)."""
         with self._sess_lock:
             sess = self._sessions.pop(int(uid), None)
             if sess is None:
                 return
             self._histories[int(uid)] = sess.items[:sess.events]
-            self._free += sess.blocks
-            self._note_fill()
+            self._give_back(sess)
 
     def open_session(self, uid: int, items) -> None:
         """(Re)build ``uid``'s session from ``items`` (oldest first)."""
@@ -626,6 +880,7 @@ class SessionTopK(DeviceTopK):
             f"({self._s_max}: twice the longest stored history's bucket)")
 
     def _table(self, sess: _Session, S: int) -> np.ndarray:
+        """The first kind's block table over ``S`` positions."""
         t = np.zeros(S // self._bs, np.int32)
         n = min(len(sess.blocks), len(t))
         t[:n] = sess.blocks[:n]
@@ -639,7 +894,7 @@ class SessionTopK(DeviceTopK):
             return sess
         hist = self._histories.get(uid, np.zeros(0, np.int32))
         self._s_bucket(len(hist))
-        sess = _Session(hist)
+        sess = _Session(hist, len(self._kinds))
         self._sessions[uid] = sess
         self._prefill(sess, uid, busy)
         return sess
@@ -657,20 +912,24 @@ class SessionTopK(DeviceTopK):
         if n_all == 0:
             return None
         with _trace_span("sess.prefill", attributes={"events": n_all}):
-            self._reserve(sess, n_all, busy)
+            # room for the most it will hold, made before the first
+            # chunk; the blocks are taken a chunk at a time, a window
+            # kind giving back what the next chunk no longer reads
+            self._make_room(self._blocks_of(n_all, chunk=C), sess, busy)
             for p0 in range(0, n_all, C):
                 n = min(C, n_all - p0)
                 S = self._s_bucket(p0 + C)
+                self._trim(sess, p0)
+                self._reserve(sess, p0 + n, busy)
                 ints = np.zeros(self._ints_width(C, S), np.int32)
                 final = p0 + n == n_all
                 ints[0] = row if final else NO_ROW
                 ints[1], ints[2] = p0, n
                 ints[3:3 + n] = hist[p0:p0 + n]
-                ints[3 + C:3 + C + n] = self._phys(sess,
-                                                   np.arange(p0, p0 + n))
-                ints[3 + 2 * C:] = self._table(sess, S)
+                self._kind_fill(ints, sess, C, S, p0, n)
                 h_last = self._run_prefill(ints, S, n=n)
             sess.length = n_all
+            self._trim(sess, n_all)
         return h_last
 
     def encode(self, items) -> np.ndarray:
@@ -681,12 +940,11 @@ class SessionTopK(DeviceTopK):
         items = np.asarray(items, dtype=np.int32)
         self._s_bucket(len(items))
         with self._sess_lock:
-            sess = _Session(items)
+            sess = _Session(items, len(self._kinds))
             try:
                 h = self._prefill(sess, NO_ROW, busy=())
             finally:
-                self._free += sess.blocks
-                self._note_fill()
+                self._give_back(sess)
         return np.zeros(self._bb.width, np.float32) if h is None \
             else np.asarray(h, dtype=np.float32)
 
@@ -811,13 +1069,18 @@ class SessionTopK(DeviceTopK):
 
     def session_report(self) -> Dict[str, Any]:
         with self._sess_lock:
-            held = self._n_blocks - 1 - len(self._free)
+            held = self._held_blocks()
             live = list(self._sessions.values())
             return dict(
                 self._bb.report(self),
                 sessions=len(live), blockTokens=self._bs,
-                cacheTokens=held * self._bs,
-                capacityTokens=(self._n_blocks - 1) * self._bs,
+                cacheTokens=int(self._row_layers(held)),
+                capacityTokens=int(self._row_layers(
+                    [n - 1 for n in self._kind_blocks])),
+                kinds=[{"name": kind.name, "layers": len(kind.layers),
+                        "keep": kind.keep, "blocks": n - 1, "held": h}
+                       for kind, n, h in zip(self._kinds,
+                                             self._kind_blocks, held)],
                 events=int(sum(s.events for s in live)),
                 tailTokens=int(sum(s.events - s.length for s in live)),
                 lengthBuckets=list(self._s_buckets),

@@ -40,7 +40,7 @@ from predictionio_tpu.ops.aot import lower_compile
 from predictionio_tpu.ops import sdar
 from predictionio_tpu.ops.sdar import COUNTERS, NEW_EVENTS
 from predictionio_tpu.ops.serving import _bucket, _deliver, _Pending
-from predictionio_tpu.ops.sessions import NO_ROW, waves
+from predictionio_tpu.ops.sessions import NO_ROW, one_kind, waves
 from predictionio_tpu.utils import device_telemetry as _dtel
 from predictionio_tpu.utils import metrics as _metrics
 from predictionio_tpu.utils.tracing import span as _trace_span
@@ -86,11 +86,13 @@ class SdarBackbone:
 
     entries = ("sess", "sesspre", "sessev")
     max_batch = SLATE_MAX_BATCH
+    max_positions = 0
 
     def __init__(self, params):
         self.spec = spec = sdar.sdar_spec(params)
         self.width = spec.width
         self.compute_dtype = spec.compute_dtype
+        self.kinds = one_kind(spec.n_layers)
         self.commit_multiple = B = spec.block_len
         if B - 1 > NEW_EVENTS or EVENT_ROWS % B:
             raise ValueError(f"block_length {B}: a divisor of {EVENT_ROWS}")

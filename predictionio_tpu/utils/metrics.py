@@ -970,6 +970,22 @@ SESS_CACHE_TOKENS = REGISTRY.gauge(
 SESS_CACHE_CAPACITY = REGISTRY.gauge(
     "pio_sess_cache_tokens_capacity",
     "Cache rows the block pool holds", ())
+SESS_KIND_TOKENS = REGISTRY.gauge(
+    "pio_sess_cache_kind_tokens",
+    "Cache rows (tokens, whole blocks) the live sessions hold in each "
+    "layer of a kind (a backbone with window layers keeps a block "
+    "table a kind: its window layers hold fewer than its global ones)",
+    ("kind",))
+SESS_BLOCKS_RELEASED = REGISTRY.counter(
+    "pio_sess_window_blocks_released_total",
+    "Cache blocks a window kind gave back because its session's end "
+    "moved on (a block wholly before the oldest position a later query "
+    "reads), at prefill and on the query path", ())
+SESS_ROWS_READ = REGISTRY.counter(
+    "pio_sess_cache_rows_read_total",
+    "Cache rows the extend dispatches had to read, by layer kind: a "
+    "query's visible rows (a window layer: the newest ones) with its "
+    "new ones, summed over queries and the kind's layers", ("kind",))
 SESS_SELECTED_SHARE = REGISTRY.gauge(
     "pio_sess_selected_share",
     "Keys the indexer selected over the keys eligible (cached "
