@@ -198,6 +198,30 @@ def draw_serving_theta(V: int, params, skip: Tuple[str, ...] = ()):
                        spec.n_layers, spec.compute_dtype, is_low, skip)
 
 
+def draw_value(key, shape, init):
+    """One drawn parameter, float32, by its ``init`` (``theta_shapes``'s
+    form): ``("div", x)`` / ``("mul", x)`` a normal draw divided /
+    multiplied by ``x``; ``("log_uniform", lo, hi)`` the LOG of a
+    uniform draw on ``[lo, hi)`` (a decay rate's ``A_log``);
+    ``("softplus_inv_log_uniform", lo, hi)`` the inverse softplus of a
+    draw log-uniform on ``[lo, hi)`` (a step's ``dt_bias``)."""
+    import jax
+    import jax.numpy as jnp
+
+    kind = init[0]
+    if kind in ("div", "mul"):
+        z = jax.random.normal(key, shape)
+        return z / init[1] if kind == "div" else z * init[1]
+    lo, hi = float(init[1]), float(init[2])
+    u = jax.random.uniform(key, shape)
+    if kind == "log_uniform":
+        return jnp.log(lo + u * (hi - lo))
+    if kind == "softplus_inv_log_uniform":
+        dt = jnp.exp(math.log(lo) + u * (math.log(hi) - math.log(lo)))
+        return dt + jnp.log(-jnp.expm1(-dt))
+    raise ValueError(f"unknown init {init!r}")
+
+
 def draw_shapes(shapes, seed: int, n_layers: int, compute_dtype: str,
                 low, skip: Tuple[str, ...] = ()):
     """``shapes`` (``theta_shapes``'s form) drawn on the device from
@@ -223,10 +247,8 @@ def draw_shapes(shapes, seed: int, n_layers: int, compute_dtype: str,
         out, kx = {}, 0
         for name, shape, init, dtype in group:
             if isinstance(init, tuple):
-                z = jax.random.normal(ks[kx], shape)
+                out[name] = draw_value(ks[kx], shape, init).astype(dtype)
                 kx += 1
-                out[name] = (z / init[1] if init[0] == "div"
-                             else z * init[1]).astype(dtype)
             else:
                 out[name] = jnp.full(shape, init, jnp.float32)
         return out

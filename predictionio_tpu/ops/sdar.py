@@ -199,7 +199,7 @@ def project(theta, i: int, h, pos, spec: SdarSpec, rotate: bool = True):
 
 
 def moe_layer(theta, i: int, h2, valid, spec: SdarSpec, router_input=None,
-              activation: str = "silu"):
+              activation: str = "silu", first: int = 0):
     """The expert layer on normed ``h2: [T, D]``: softmax over the 128
     router logits in float32, the 8 largest, their weights divided by
     their sum; every expert is held, so this is ``moe_ffn_share`` with
@@ -209,7 +209,9 @@ def moe_layer(theta, i: int, h2, valid, spec: SdarSpec, router_input=None,
     routes NOWHERE: its picks fall past the held range, so no expert's
     weights are read for it. ``router_input``: what the router reads
     where that is not ``h2`` (``ops/smallthinker.py``: the attention's
-    input); ``activation``: the experts' gate's. Returns ``(y [T, D],
+    input); ``activation``: the experts' gate's; ``first``: the first
+    expert of the share ``theta`` holds, where it holds fewer than the
+    router chooses among (``ops/qwen3next.py``). Returns ``(y [T, D],
     picks [T, k], gates [T, k], experts that got a row)``."""
     import jax.numpy as jnp
 
@@ -224,7 +226,7 @@ def moe_layer(theta, i: int, h2, valid, spec: SdarSpec, router_input=None,
     y, _, gs = moe.moe_ffn_share(
         h2, sent, weights, theta[p + "we_gate"].astype(cd),
         theta[p + "we_up"].astype(cd), theta[p + "we_down"].astype(cd),
-        first=0, compute_dtype=cd, activation=activation)
+        first=first, compute_dtype=cd, activation=activation)
     return y, experts, weights, jnp.sum(gs > 0)
 
 

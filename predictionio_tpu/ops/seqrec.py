@@ -149,7 +149,23 @@ class SeqRecParams(Params):
     # session may hold (``max_position_embeddings``)
     sliding_window_size: int = 0
     sliding_window_layout: Tuple[int, ...] = ()
-    # the session lane that serves that block (ops/sessions.py): the
+    # the qwen3_next block (ops/qwen3next.py), under config.json's names
+    # (linear_num_key_heads, linear_num_value_heads, linear_key_head_dim,
+    # linear_value_head_dim, linear_conv_kernel_dim,
+    # full_attention_interval, partial_rotary_factor,
+    # shared_expert_intermediate_size): layer ``i`` is gated attention
+    # where ``(i + 1) % full_attention_interval == 0`` and a Gated
+    # DeltaNet layer otherwise; ``experts_held`` / ``expert_share`` say
+    # which of the router's ``n_experts`` outputs this chip holds
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 0
+    full_attention_interval: int = 0
+    partial_rotary_factor: float = 1.0
+    shared_expert_width: int = 0
+    # the session lane that serves these blocks (ops/sessions.py): the
     # cache pool's rows (0: twice the stored histories) and how many
     # dispatches' audits it keeps for a check to read (0: the
     # programs compute none)
@@ -210,6 +226,22 @@ SMALLTHINKER_21B_A3B = dict(
     max_seq_len=16384)
 
 
+# the block of Qwen3-Next-80B-A3B-Instruct
+# (https://huggingface.co/Qwen/Qwen3-Next-80B-A3B-Instruct, model_type
+# qwen3_next) as its config.json publishes it; ``n_layers``, the experts
+# a chip holds (``experts_held`` of the 512, share ``expert_share``) and
+# the rows of the tables are the deployment's
+QWEN3_NEXT_80B_A3B = dict(
+    block="qwen3_next", rank=2048, n_heads=16, n_kv_heads=2, head_dim=256,
+    norm="rmsnorm", norm_eps=1e-6, positions="rope", rope_theta=10000000.0,
+    partial_rotary_factor=0.25, tied=False, vocab_rows=151936,
+    n_experts=512, expert_width=512, experts_per_token=10,
+    norm_topk_prob=True, shared_expert_width=512, linear_key_heads=16,
+    linear_value_heads=32, linear_key_head_dim=128,
+    linear_value_head_dim=128, linear_conv_kernel=4,
+    full_attention_interval=4)
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What of :class:`SeqRecParams` shapes the compiled programs (the
@@ -233,6 +265,7 @@ class BlockSpec:
     glm: Any = None   # ops/mla.py::GlmSpec of the glm_moe_dsa block
     sdar: Any = None  # ops/sdar.py::SdarSpec of the sdar_moe block
     swa: Any = None   # ops/smallthinker.py::SwaSpec of the smallthinker block
+    lin: Any = None   # ops/qwen3next.py::LinSpec of the qwen3_next block
 
     @property
     def sparse(self) -> bool:
@@ -284,6 +317,11 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         from predictionio_tpu.ops import smallthinker
 
         swa = smallthinker.swa_spec(params)
+    lin = None
+    if params.block == "qwen3_next":
+        from predictionio_tpu.ops import qwen3next
+
+        lin = qwen3next.lin_spec(params)
     return BlockSpec(
         params.block, int(params.n_layers), H, head_dim, params.norm,
         float(params.norm_eps), params.positions,
@@ -291,7 +329,7 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         int(params.n_experts) if sparse else 0,
         int(params.experts_per_token) if sparse else 0,
         float(params.lb_coef), float(params.z_coef),
-        params.compute_dtype, glm, sdar, swa)
+        params.compute_dtype, glm, sdar, swa, lin)
 
 
 @dataclasses.dataclass
@@ -504,6 +542,10 @@ def _theta_shapes(n_items: int, params: SeqRecParams
         from predictionio_tpu.ops import smallthinker
 
         return smallthinker.theta_shapes(V, spec.swa)
+    if spec.lin is not None:
+        from predictionio_tpu.ops import qwen3next
+
+        return qwen3next.theta_shapes(V, spec.lin)
     A = spec.n_heads * spec.head_dim
     out: List[Tuple[str, Tuple[int, ...], Any]] = [
         ("item_emb", (V, D), ("div", math.sqrt(D)))]
@@ -549,6 +591,8 @@ def init_theta_device(n_items: int, params: SeqRecParams):
     import jax
     import jax.numpy as jnp
 
+    from predictionio_tpu.ops.mla import draw_value
+
     shapes = _theta_shapes(n_items, params)
     drawn = sum(isinstance(s[2], tuple) for s in shapes)
     keys = jax.random.split(jax.random.PRNGKey(int(params.seed)),
@@ -557,9 +601,8 @@ def init_theta_device(n_items: int, params: SeqRecParams):
     kx = 0
     for name, shape, init in shapes:
         if isinstance(init, tuple):
-            z = jax.random.normal(keys[kx], shape)
-            theta[name] = (z / init[1] if init[0] == "div"
-                           else z * init[1]).astype(jnp.float32)
+            theta[name] = draw_value(keys[kx], shape, init).astype(
+                jnp.float32)
             kx += 1
         else:
             theta[name] = jnp.full(shape, init, jnp.float32)
@@ -616,7 +659,10 @@ def _rms_norm(x, g, eps: float):
 
 def _norm(theta, name: str, x, spec: BlockSpec):
     if spec.norm == "rmsnorm":
-        return _rms_norm(x, theta[f"{name}_g"], spec.norm_eps)
+        # (the qwen3_next block's norms are zero-centred)
+        g = theta[f"{name}_g"]
+        return _rms_norm(x, g if spec.lin is None else 1.0 + g,
+                         spec.norm_eps)
     return _layer_norm(x, theta[f"{name}_g"], theta[f"{name}_b"],
                        spec.norm_eps)
 
@@ -766,10 +812,22 @@ def _smallthinker_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
                                            spec.swa), None
 
 
+def _qwen3next_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
+                     attention_fn, low):
+    """Qwen3-Next's layer (``ops/qwen3next.py``): gated attention every
+    ``full_attention_interval``-th layer, a Gated DeltaNet layer (the
+    chunked form from a zero state; ONE segment a row) otherwise, then
+    the held routed experts beside the gated shared one."""
+    from predictionio_tpu.ops import qwen3next
+
+    return qwen3next.qwen3next_layer(theta, i, x, seg, pos, spec.lin), None
+
+
 # one function per layer kind; ``SeqRecParams.block`` names one
 BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer,
           "glm_moe_dsa": _glm_layer, "sdar_moe": _sdar_layer,
-          "smallthinker": _smallthinker_layer}
+          "smallthinker": _smallthinker_layer,
+          "qwen3_next": _qwen3next_layer}
 
 
 def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
@@ -1215,6 +1273,11 @@ def train_seqrec(buckets, n_items: int, params: SeqRecParams,
             "the smallthinker block is not trained here (no training "
             "cell holds it: the packed rows of 4,096 never reach its "
             "window). Serve it with numSteps 0 and seededWeights")
+    if spec.lin is not None and int(params.num_steps) > 0:
+        raise ValueError(
+            "the qwen3_next block is not trained here (the chunked "
+            "rule has no backward pass and no training cell holds it: "
+            "ROADMAP Reach). Serve it with numSteps 0 and seededWeights")
     with _tracing.span("seq.stage"):
         if theta is None:
             theta = init_theta_device(n_items, params)

@@ -9,7 +9,9 @@ ladder) whose store holds, beside the output table ``Y``:
   ``glm_moe_dsa`` block, :class:`GlmBackbone` over ``ops/mla.py``; the
   ``sdar_moe`` block, ``ops/slates.py::SdarBackbone`` over
   ``ops/sdar.py``; the ``smallthinker`` block,
-  :class:`SmallThinkerBackbone` over ``ops/smallthinker.py``);
+  :class:`SmallThinkerBackbone` over ``ops/smallthinker.py``; the
+  ``qwen3_next`` block, :class:`Qwen3NextBackbone` over
+  ``ops/qwen3next.py``);
 - ``X``: every user's LAST hidden state (final norm applied), so that
   the inherited ``users`` lane answers a query without new events;
 - a POOL of cache blocks: per layer one array ``[blocks, bs, width]``
@@ -26,6 +28,21 @@ ladder) whose store holds, beside the output table ``Y``:
   the session still holds. GLM-5 and SDAR declare ONE kind that keeps
   everything; SmallThinker a global and a window kind. Block 0 of
   every kind is never handed out: padding writes land there.
+- SLOTS, for a kind of layer that keeps no row a token but one
+  constant-size STATE a session (``LayerKind.state``: Qwen3-Next's
+  Gated DeltaNet layers: a float32 recurrent state and a convolution's
+  tail): per layer of that kind one array ``[slots, ...]`` for every
+  array the kind names. A slot is to such a kind what a block is to
+  the others, and a session holds exactly ONE, from its first cached
+  event on: the same free list, admission (a session is admitted when
+  EVERY kind can take it), release and eviction; slot 0 is never
+  handed out (a padded query row names it). A program overwrites a
+  slot in place, so what a slot holds is only ever valid TOGETHER with
+  its session's ``length``: the device arrays are swapped first, the
+  length is booked after them, and a dispatch that fails in between
+  forgets its sessions (:meth:`SessionTopK._forget`: the next touch
+  prefills them again from the host's events), so that no query ever
+  runs on a state ahead of its session's length.
 
 The manager keeps the blocks, the tables, allocation, release and
 eviction over all kinds (a session leaves every kind at once), the
@@ -95,11 +112,16 @@ NO_ROW = -1              # the user row of a query row that writes none
 
 class LayerKind(NamedTuple):
     """A kind of layer a backbone declares: the layers of that kind
-    and how many trailing positions one of them reads (None: all)."""
+    and what a session holds there: BLOCKS of the backbone's
+    ``cache_rows`` (``keep``: how many trailing positions a layer of
+    that kind reads; None: all) or, with ``state``, one SLOT of the
+    arrays it names: ``(name, shape, dtype, the component's name in
+    memory_report())``."""
 
     name: str
     layers: Tuple[int, ...]
     keep: Optional[int]
+    state: Tuple[Tuple[str, Tuple[int, ...], str, str], ...] = ()
 
 
 def one_kind(n_layers: int) -> Tuple[LayerKind, ...]:
@@ -118,9 +140,15 @@ def kind_layout(kinds, T: int, S: int, bs: int
     row's width. A kind that keeps everything has ``S / bs`` blocks; a
     window kind the ``T`` rows' and the ``keep - 1`` positions before
     them, wherever they fall in their blocks. One kind that keeps all:
-    ``3 + 2T + S / bs``, the layout the lane has always had."""
+    ``3 + 2T + S / bs``, the layout the lane has always had. A SLOT
+    kind adds one id, its session's slot: ``(-1, -1, its offset, 1)``,
+    a table of that one entry and no cache rows."""
     out, at = [], 3 + T
     for kind in kinds:
+        if kind.state:
+            out.append((-1, -1, at, 1))
+            at += 1
+            continue
         nb = S // bs
         if kind.keep is not None:
             nb = min(nb, -(-(kind.keep + T) // bs) + 1)
@@ -349,35 +377,49 @@ class GlmBackbone:
                     ints[j, 0], ints[j, 1], ints[j, 2] = u, sess.length, k
                     ints[j, 3:3 + k] = items
                     m._kind_fill(ints[j], sess, T, S, sess.length, k)
-            host, audit = self._run_extend(m, ints, kb, S, n)
-            with _dtel.stage("bookUs", "batch.book", done=True):
-                m._clock += 1
-                tokens = 0
-                for sess, (u, items) in zip(sessions, rows):
-                    if len(items):
-                        sess.append(items)
-                        sess.length = sess.events
-                        tokens += len(items)
-                        m._trim(sess, sess.length)
-                    sess.touched = m._clock
-                if audit is not None and (m._watched is None or any(
-                        int(u) in m._watched for u, _ in rows)):
-                    m._audits.append((audit, bb, [
-                        (int(u), sess.length) for sess, (u, _)
-                        in zip(sessions, rows)]))
-                if tokens:
-                    _metrics.SESS_TOKENS.inc(amount=tokens,
-                                             program="extend")
-                for kind, rows_ in (("valid", tokens),
-                                    ("padded", bb * T - tokens)):
-                    _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
-                    m._token_rows[kind] += rows_
-                _metrics.SESS_POSITIONS.inc(
-                    amount=sum(s_.length for s_ in sessions))
-                idx, scores = _unpack(host[:, :2 * kb], kb)
-                self._book_counters(
-                    *(float(c) for c in host[0, 2 * kb:].view(np.float32)))
+            try:
+                host, audit = self._run_extend(m, ints, kb, S, n)
+                idx, scores = self._book(m, sessions, rows, host, audit,
+                                         bb, kb)
+            except BaseException:
+                # the program may have run: a slot is then AHEAD of its
+                # session's length
+                m._forget(busy)
+                raise
         return idx[:n], scores[:n]
+
+    def _book(self, m: "SessionTopK", sessions, rows, host, audit, bb: int,
+              kb: int):
+        """After an extend dispatch: the sessions' events and lengths,
+        the audit, the counters; returns the unpacked result."""
+        T = SESS_EVENTS
+        with _dtel.stage("bookUs", "batch.book", done=True):
+            m._clock += 1
+            tokens = 0
+            for sess, (u, items) in zip(sessions, rows):
+                if len(items):
+                    sess.append(items)
+                    sess.length = sess.events
+                    tokens += len(items)
+                    m._trim(sess, sess.length)
+                sess.touched = m._clock
+            if audit is not None and (m._watched is None or any(
+                    int(u) in m._watched for u, _ in rows)):
+                m._audits.append((audit, bb, [
+                    (int(u), sess.length) for sess, (u, _)
+                    in zip(sessions, rows)]))
+            if tokens:
+                _metrics.SESS_TOKENS.inc(amount=tokens, program="extend")
+            for kind, rows_ in (("valid", tokens),
+                                ("padded", bb * T - tokens)):
+                _metrics.SESS_TOKEN_ROWS.inc(amount=rows_, kind=kind)
+                m._token_rows[kind] += rows_
+            _metrics.SESS_POSITIONS.inc(
+                amount=sum(s_.length for s_ in sessions))
+            m._note_state_traffic(sum(1 for _, items in rows if len(items)))
+            self._book_counters(
+                *(float(c) for c in host[0, 2 * kb:].view(np.float32)))
+            return _unpack(host[:, :2 * kb], kb)
 
     @staticmethod
     def _book_counters(selected, eligible, local, touched) -> None:
@@ -447,30 +489,37 @@ class SmallThinkerBackbone(GlmBackbone):
         self.floor = 4 * self.chunk
         self.qb = min(32, self.chunk)
 
-    def serving_theta(self, theta):
+    # the programs' names on the device: jit_swa_extend, jit_swa_prefill
+    program_prefix = "swa"
+
+    @staticmethod
+    def _ops():
+        """The module that holds the block's programs."""
         from predictionio_tpu.ops import smallthinker
 
-        return smallthinker.serving_theta(theta, self.spec)
+        return smallthinker
+
+    def serving_theta(self, theta):
+        return self._ops().serving_theta(theta, self.spec)
 
     def draw_theta(self, V: int, params):
-        from predictionio_tpu.ops import smallthinker
-
-        return smallthinker.draw_serving_theta(V, params)
+        return self._ops().draw_serving_theta(V, params)
 
     def extend_program(self, m: "SessionTopK", kb: int, S: int):
         def make():
             import jax
 
-            from predictionio_tpu.ops import smallthinker
+            ops = self._ops()
 
-            def swa_extend(theta, X, seen_bits, pool, Y, ints):
-                return smallthinker.extend_step(
+            def extend(theta, X, seen_bits, pool, Y, ints):
+                return ops.extend_step(
                     theta, X, seen_bits, pool, Y, ints, spec=self.spec,
                     kb=kb, T=SESS_EVENTS, S=S, bs=m._bs, n_items=m.n_items,
                     mode=m._mode, layout=m._kind_layout(SESS_EVENTS, S),
                     audit=bool(m._audit_keep))
 
-            return jax.jit(swa_extend, donate_argnums=(1, 2, 3))
+            extend.__name__ = f"{self.program_prefix}_extend"
+            return jax.jit(extend, donate_argnums=(1, 2, 3))
 
         return m._program(("sess", kb, S), make)
 
@@ -478,15 +527,16 @@ class SmallThinkerBackbone(GlmBackbone):
         def make():
             import jax
 
-            from predictionio_tpu.ops import smallthinker
+            ops = self._ops()
 
-            def swa_prefill(theta, X, pool, ints):
-                return smallthinker.prefill_chunk(
+            def prefill(theta, X, pool, ints):
+                return ops.prefill_chunk(
                     theta, X, pool, ints, spec=self.spec, C=self.chunk,
                     S=S, bs=m._bs, qb=self.qb,
                     layout=m._kind_layout(self.chunk, S))
 
-            return jax.jit(swa_prefill, donate_argnums=(1, 2))
+            prefill.__name__ = f"{self.program_prefix}_prefill"
+            return jax.jit(prefill, donate_argnums=(1, 2))
 
         return m._program(("sesspre", S), make)
 
@@ -500,6 +550,56 @@ class SmallThinkerBackbone(GlmBackbone):
             _metrics.SESS_EXPERTS_TOUCHED.inc(amount=touched)
 
 
+LIN_CHUNK = 2048        # tokens a prefill chunk of Qwen3-Next holds
+
+
+class Qwen3NextBackbone(SmallThinkerBackbone):
+    """Qwen3-Next's block (``ops/qwen3next.py``) as the session lane
+    serves it: layers of TWO kinds of which only one holds rows: the
+    gated attention layers (every ``full_attention_interval``-th) key
+    and value rows in BLOCKS, every position kept; the Gated DeltaNet
+    layers one SLOT a session (a float32 state and the convolution's
+    tail), advanced in place by every dispatch. A query is answered by
+    ONE extend dispatch, events committed one by one, a session held to
+    the model's ``max_position_embeddings``."""
+
+    program_prefix = "lin"
+
+    @staticmethod
+    def _ops():
+        from predictionio_tpu.ops import qwen3next
+
+        return qwen3next
+
+    def __init__(self, params):
+        ops = self._ops()
+        self.spec = spec = ops.lin_spec(params)
+        self.width = spec.width
+        self.compute_dtype = spec.compute_dtype
+        self.kinds = tuple(LayerKind(*k) for k in spec.kinds)
+        self.max_positions = int(params.max_seq_len)
+        self.cache_rows = (("k", spec.kv_width, "sessionKeys"),
+                           ("v", spec.kv_width, "sessionValues"))
+        # a prefill chunk: whole chunks of the rule's chunked form, a
+        # quarter of the longest session's bucket at most; the shortest
+        # cached-length bucket is four chunks
+        self.chunk = max(ops.GDN_CHUNK, min(
+            LIN_CHUNK, _bucket(max(self.max_positions, 16)) // 4))
+        self.floor = 4 * self.chunk
+        self.qb = min(32, self.chunk)
+
+    @staticmethod
+    def _book_counters(read_full, touched, found, made) -> None:
+        """``qwen3next.extend_step``'s counters."""
+        for counter, amount, labels in (
+                (_metrics.SESS_ROWS_READ, read_full, {"kind": "full"}),
+                (_metrics.SESS_EXPERTS_TOUCHED, touched, {}),
+                (_metrics.SESS_LOCAL_PICKS, found, {}),
+                (_metrics.SESS_PICKS_MADE, made, {})):
+            if amount > 0:
+                counter.inc(amount=amount, **labels)
+
+
 def backbone_of(params):
     """The backbone that serves ``params.block`` from per-user
     caches."""
@@ -511,9 +611,11 @@ def backbone_of(params):
         return SdarBackbone(params)
     if params.block == "smallthinker":
         return SmallThinkerBackbone(params)
+    if params.block == "qwen3_next":
+        return Qwen3NextBackbone(params)
     raise ValueError(
         f"no session backbone for block {params.block!r}: the lane "
-        "serves glm_moe_dsa, sdar_moe and smallthinker")
+        "serves glm_moe_dsa, sdar_moe, smallthinker and qwen3_next")
 
 
 class SessionTopK(DeviceTopK):
@@ -588,21 +690,40 @@ class SessionTopK(DeviceTopK):
         need = np.sum([self._blocks_of(len(h)) for h in
                        self._histories.values()] or
                       [[0] * len(self._kinds)], axis=0)
-        self._kind_blocks = [
-            1 + (whole if kind.keep is None or not need[0] else max(
-                -(-whole * int(n) // int(need[0])),
-                self._blocks_of(self._s_max, chunk=self._chunk)[k] + 1))
-            for k, (kind, n) in enumerate(zip(self._kinds, need))]
+        def sized(k: int, kind: LayerKind, n: int) -> int:
+            """Blocks (slots) kind ``k``'s pool holds beside its
+            spare, ``n`` of them held by the stored histories."""
+            if kind.state:
+                # as many sessions as the block pool is sized for
+                return max(2, -(-whole * int(n) // int(need[0]))
+                           if need[0] else whole * self._bs // self._s_max)
+            if kind.keep is None or not need[0]:
+                return whole
+            return max(-(-whole * int(n) // int(need[0])),
+                       self._blocks_of(self._s_max, chunk=self._chunk)[k] + 1)
+
+        self._kind_blocks = [1 + sized(k, kind, n) for k, (kind, n)
+                             in enumerate(zip(self._kinds, need))]
         self._layer_kind = {i: k for k, kind in enumerate(self._kinds)
                             for i in kind.layers}
+        self._slotted = any(kind.state for kind in self._kinds)
         cache_dtype = jnp.dtype(bb.compute_dtype)
         with self._store_lock, _trace_span("store.upload"):
+            # a pool array a layer THAT HOLDS IT, in layer order: the
+            # cache rows in the layers of the block kinds, a slot
+            # kind's arrays in its own
             self._pool = {
                 name: tuple(jnp.zeros(
                     (self._kind_blocks[self._layer_kind[i]], self._bs,
                      width), cache_dtype)
-                    for i in range(bb.spec.n_layers))
+                    for i in range(bb.spec.n_layers)
+                    if not self._kinds[self._layer_kind[i]].state)
                 for name, width, _ in bb.cache_rows}
+            for k, kind in enumerate(self._kinds):
+                for name, shape, dtype, _ in kind.state:
+                    self._pool[name] = tuple(
+                        jnp.zeros((self._kind_blocks[k],) + tuple(shape),
+                                  jnp.dtype(dtype)) for _ in kind.layers)
             jax.block_until_ready(self._pool)
         self._sess_lock = threading.RLock()
         self._sessions: Dict[int, _Session] = {}
@@ -622,6 +743,10 @@ class SessionTopK(DeviceTopK):
                 dispatch_fn=bb.dispatch)
         _metrics.SESS_CACHE_CAPACITY.set(self._row_layers(
             [n - 1 for n in self._kind_blocks]))
+        for kind, n in zip(self._kinds, self._kind_blocks):
+            if kind.state:
+                _metrics.SESS_STATE_CAPACITY.set(n - 1)
+                _metrics.SESS_STATE_SLOT_BYTES.set(self._slot_bytes(kind))
         self._note_fill()
 
     # -- programs and the ladder ------------------------------------------
@@ -757,17 +882,38 @@ class SessionTopK(DeviceTopK):
         while its last chunk of that many tokens is prefilled, the
         most it ever holds)."""
         whole = -(-int(length) // self._bs)
-        return [whole if kind.keep is None else min(
-            whole, whole - max(0, int(length) - chunk - kind.keep + 1)
-            // self._bs) for kind in self._kinds]
+        return [min(whole, 1) if kind.state else whole
+                if kind.keep is None else min(
+                    whole, whole - max(0, int(length) - chunk - kind.keep + 1)
+                    // self._bs) for kind in self._kinds]
 
     def _row_layers(self, blocks) -> float:
         """``blocks`` a kind as cache rows: row-layers summed over the
-        kinds, over the layers (one kind: its rows), so that the
-        gauges count what is HELD whichever kind holds it."""
-        return self._bs * sum(n * len(kind.layers) for n, kind in
-                              zip(blocks, self._kinds)) \
-            / sum(len(kind.layers) for kind in self._kinds)
+        BLOCK kinds, over their layers (one kind: its rows), so that
+        the gauges count what is HELD whichever kind holds it (a slot
+        kind holds no rows: its slots have gauges of their own)."""
+        rows = [(n, len(kind.layers)) for n, kind in
+                zip(blocks, self._kinds) if not kind.state]
+        return self._bs * sum(n * layers for n, layers in rows) \
+            / max(sum(layers for _, layers in rows), 1)
+
+    @staticmethod
+    def _slot_bytes(kind: LayerKind) -> int:
+        """Bytes ONE slot of ``kind`` holds, over the kind's layers."""
+        import jax.numpy as jnp
+
+        return len(kind.layers) * int(sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for _, shape, dtype, _ in kind.state))
+
+    def _note_state_traffic(self, live: int) -> None:
+        """``live`` queries of one dispatch brought events: each read
+        its session's slot of every slot kind and wrote it back."""
+        if not (self._slotted and live):
+            return
+        moved = live * sum(self._slot_bytes(kind) for kind in self._kinds)
+        for way in ("read", "written"):
+            _metrics.SESS_STATE_BYTES.inc(amount=moved, dir=way)
 
     def _held_blocks(self) -> List[int]:
         return [n - 1 - len(free)
@@ -785,7 +931,8 @@ class SessionTopK(DeviceTopK):
         for ``n`` new rows at positions ``pos0 ..`` of ``sess``."""
         pos = np.arange(pos0, pos0 + n)
         for k, (w, b, t, nb) in enumerate(self._kind_layout(T, S)):
-            row[w:w + n] = self._phys(sess, pos, k)
+            if w >= 0:
+                row[w:w + n] = self._phys(sess, pos, k)
             if b >= 0:
                 row[b] = sess.first[k] * self._bs
             have = min(len(sess.held[k]), nb)
@@ -795,7 +942,10 @@ class SessionTopK(DeviceTopK):
         held = self._held_blocks()
         _metrics.SESS_CACHE_TOKENS.set(self._row_layers(held))
         for kind, n in zip(self._kinds, held):
-            _metrics.SESS_KIND_TOKENS.set(n * self._bs, kind=kind.name)
+            if kind.state:
+                _metrics.SESS_STATE_SLOTS.set(n)
+            else:
+                _metrics.SESS_KIND_TOKENS.set(n * self._bs, kind=kind.name)
 
     def _make_room(self, need, keep: Optional[_Session], busy) -> None:
         """``need`` free blocks (a count a kind; an int: of the first
@@ -821,8 +971,9 @@ class SessionTopK(DeviceTopK):
         """Blocks for ``length`` cached events in every kind
         (:meth:`_make_room`)."""
         whole = -(-int(length) // self._bs)
-        need = [max(0, whole - first - len(held))
-                for first, held in zip(sess.first, sess.held)]
+        need = [max(0, (min(whole, 1) if kind.state else whole) - first
+                    - len(held)) for kind, first, held in
+                zip(self._kinds, sess.first, sess.held)]
         if not any(need):
             return
         with _trace_span("sess.cache_alloc",
@@ -863,6 +1014,18 @@ class SessionTopK(DeviceTopK):
                 return
             self._histories[int(uid)] = sess.items[:sess.events]
             self._give_back(sess)
+
+    def _forget(self, uids) -> None:
+        """After a dispatch that failed once its program may have run:
+        a slot the program advanced is AHEAD of its session's length
+        (the arrays are swapped before the lengths are booked), and a
+        recurrent state cannot be taken back. The dispatch's sessions
+        leave the device; their next touch prefills them from the
+        host's events. (Cache rows past a session's length are read by
+        nobody: a lane of block kinds alone keeps its sessions.)"""
+        if self._slotted:
+            for u in uids:
+                self.release(u)
 
     def open_session(self, uid: int, items) -> None:
         """(Re)build ``uid``'s session from ``items`` (oldest first)."""
@@ -911,7 +1074,8 @@ class SessionTopK(DeviceTopK):
         hist = sess.items[:n_all]
         if n_all == 0:
             return None
-        with _trace_span("sess.prefill", attributes={"events": n_all}):
+        with _trace_span("sess.prefill", attributes={
+                "events": n_all, "chunks": -(-n_all // C)}):
             # room for the most it will hold, made before the first
             # chunk; the blocks are taken a chunk at a time, a window
             # kind giving back what the next chunk no longer reads
@@ -1021,6 +1185,49 @@ class SessionTopK(DeviceTopK):
             row = self._X[int(uid)]
         return np.asarray(row, dtype=np.float32)
 
+    def session_state(self, uid: int) -> Optional[Dict[str, Any]]:
+        """What ``uid``'s SLOT holds now, fetched: ``{"length", and a
+        slot kind's array by name: [the kind's layers, ...]}``; None
+        for a lane without slot kinds or a user without a cached
+        event. Only with the lane idle is it the state AS OF
+        ``length``."""
+        with self._sess_lock, self._store_lock:
+            sess = self._sessions.get(int(uid))
+            out: Dict[str, Any] = {}
+            for k, kind in enumerate(self._kinds):
+                if not kind.state or sess is None or not sess.held[k]:
+                    continue
+                for name, _, _, _ in kind.state:
+                    out[name] = np.stack([np.asarray(
+                        a[sess.held[k][0]].astype("float32"))
+                        for a in self._pool[name]])
+            return dict(out, length=int(sess.length)) if out else None
+
+    def session_rows(self, uid: int) -> Optional[Dict[str, Any]]:
+        """What ``uid``'s BLOCKS hold now in the kinds that keep every
+        position, fetched through the session's own block list:
+        ``{"length", and a cache row by name: [the kinds' layers,
+        length, width]}`` in the cache's dtype, row ``p`` position
+        ``p``'s; None for a user without a cached event. A kind that
+        keeps all never rewrites a row, so what is read once at the end
+        is what every earlier dispatch read of its prefix."""
+        import jax.numpy as jnp
+
+        with self._sess_lock, self._store_lock:
+            sess = self._sessions.get(int(uid))
+            if sess is None or not sess.length:
+                return None
+            held = [i for i in range(self._bb.spec.n_layers)
+                    if not self._kinds[self._layer_kind[i]].state]
+            out: Dict[str, Any] = {}
+            for name, width, _ in self._bb.cache_rows:
+                out[name] = np.stack([np.asarray(a[jnp.asarray(
+                    sess.held[self._layer_kind[i]])].reshape(
+                        -1, width)[:sess.length])
+                    for a, i in zip(self._pool[name], held)
+                    if self._kinds[self._layer_kind[i]].keep is None])
+            return dict(out, length=int(sess.length))
+
     def watch(self, uids=None) -> None:
         """Keep the audits of dispatches that answer one of ``uids``
         only (None: of every dispatch), and drop those kept so far."""
@@ -1077,8 +1284,10 @@ class SessionTopK(DeviceTopK):
                 cacheTokens=int(self._row_layers(held)),
                 capacityTokens=int(self._row_layers(
                     [n - 1 for n in self._kind_blocks])),
-                kinds=[{"name": kind.name, "layers": len(kind.layers),
-                        "keep": kind.keep, "blocks": n - 1, "held": h}
+                kinds=[dict({"name": kind.name, "layers": len(kind.layers),
+                             "keep": kind.keep, "blocks": n - 1, "held": h},
+                            **({"slotBytes": self._slot_bytes(kind)}
+                               if kind.state else {}))
                        for kind, n, h in zip(self._kinds,
                                              self._kind_blocks, held)],
                 events=int(sum(s.events for s in live)),
@@ -1094,10 +1303,14 @@ class SessionTopK(DeviceTopK):
         extra = {"backbone": {"bytes": int(sum(v.nbytes for v in
                                                theta.values())),
                               "scaleBytes": 0, "dtype": dtype}}
-        for name, _, component in self._bb.cache_rows:
+        held = [(name, component, dtype)
+                for name, _, component in self._bb.cache_rows]
+        held += [(name, component, kind_dtype) for kind in self._kinds
+                 for name, _, kind_dtype, component in kind.state]
+        for name, component, of in held:
             extra[component] = {
                 "bytes": int(sum(a.nbytes for a in pool[name])),
-                "scaleBytes": 0, "dtype": dtype}
+                "scaleBytes": 0, "dtype": of}
         report["components"].update(extra)
         report["totalBytes"] += sum(c["bytes"] for c in extra.values())
         report["sessions"] = self.session_report()

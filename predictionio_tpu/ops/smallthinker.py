@@ -224,6 +224,38 @@ def _kind_ints(ints, layout, kind: int, T: int):
             ints[..., t:t + nb])
 
 
+def chunk_attend(q, pool_k, pool_v, table, base, pos, pos0, n_valid,
+                 window: Optional[int], spec, qb: int):
+    """A prefill chunk's queries ``q [C, H, d]`` at positions ``pos``
+    over the cached rows their layer's block ``table`` covers (the
+    chunk's own rows are written already; ``base``: the position of the
+    table's first row, None: 0), gathered once and scored ``qb``
+    queries at a time under :func:`visible` and the chunk's end
+    ``pos0 + n_valid``. ``[C / qb, qb, H x d]`` float32."""
+    import jax
+    import jax.numpy as jnp
+
+    KV, G, d = spec.n_kv, spec.group, spec.head_dim
+    C, bs = q.shape[0], pool_k.shape[1]
+    rows = table.shape[0] * bs
+    ks = jnp.take(pool_k, table, axis=0, mode="clip").reshape(rows, KV, d)
+    vs = jnp.take(pool_v, table, axis=0, mode="clip").reshape(rows, KV, d)
+    at = jnp.arange(rows, dtype=jnp.int32) + (0 if base is None else base)
+
+    def block(args):
+        q_b, pos_b = args
+        ok = visible(pos_b[:, None], at[None, :], window) \
+            & (at[None, :] < pos0 + n_valid)
+        s = _ein("qkgd,skd->kgqs", q_b.reshape(qb, KV, G, d), ks,
+                 spec) * spec.scale
+        a = jax.nn.softmax(
+            jnp.where(ok[None, None], s, PAGED_NEG), axis=-1)
+        return _ein("kgqs,skd->qkgd", a, vs, spec).reshape(qb, -1)
+
+    return jax.lax.map(block, (q.reshape(C // qb, qb, -1, d),
+                               pos.reshape(C // qb, qb)))
+
+
 def prefill_chunk(theta, X, pool, ints, *, spec: SwaSpec, C: int, S: int,
                   bs: int, qb: int, layout: Tuple):
     """One chunk of one session's prefill: ``C`` tokens at positions
@@ -239,7 +271,6 @@ def prefill_chunk(theta, X, pool, ints, *, spec: SwaSpec, C: int, S: int,
     import jax
     import jax.numpy as jnp
 
-    KV, G, d = spec.n_kv, spec.group, spec.head_dim
     pos0, n_valid = ints[1], ints[2]
     tok = ints[3:3 + C]
     pos = pos0 + jnp.arange(C, dtype=jnp.int32)
@@ -253,26 +284,8 @@ def prefill_chunk(theta, X, pool, ints, *, spec: SwaSpec, C: int, S: int,
             q, k, v = sdar.project(theta, i, h, pos, spec,
                                    rotate=bool(spec.pattern[i]))
             pool = sdar._write_layer(pool, i, k, v, wrow, bs)
-            rows = table.shape[0] * bs
-            ks = jnp.take(pool["k"][i], table, axis=0, mode="clip").reshape(
-                rows, KV, d)
-            vs = jnp.take(pool["v"][i], table, axis=0, mode="clip").reshape(
-                rows, KV, d)
-            at = jnp.arange(rows, dtype=jnp.int32) \
-                + (0 if base is None else base)
-
-            def block(args, ks=ks, vs=vs, at=at, window=window):
-                q_b, pos_b = args
-                ok = visible(pos_b[:, None], at[None, :], window) \
-                    & (at[None, :] < pos0 + n_valid)
-                s = _ein("qkgd,skd->kgqs", q_b.reshape(qb, KV, G, d), ks,
-                         spec) * spec.scale
-                a = jax.nn.softmax(
-                    jnp.where(ok[None, None], s, PAGED_NEG), axis=-1)
-                return _ein("kgqs,skd->qkgd", a, vs, spec).reshape(qb, -1)
-
-            o = jax.lax.map(block, (q.reshape(C // qb, qb, -1, d),
-                                    pos.reshape(C // qb, qb)))
+            o = chunk_attend(q, pool["k"][i], pool["v"][i], table, base,
+                             pos, pos0, n_valid, window, spec, qb)
             x = x + _mm(o.reshape(C, -1), theta[f"l{i}_wo"], spec)
         with jax.named_scope("swa/moe"):
             h2 = rms_norm(x, theta[f"l{i}_ln2_g"], spec.norm_eps)

@@ -999,6 +999,11 @@ SESS_LOCAL_PICKS = REGISTRY.counter(
     "pio_sess_expert_picks_local_total",
     "(event, expert) picks of the router that fell on an expert this "
     "chip holds, summed over the expert layers", ())
+SESS_PICKS_MADE = REGISTRY.counter(
+    "pio_sess_expert_picks_total",
+    "(event, expert) picks the router made, summed over the expert "
+    "layers: what pio_sess_expert_picks_local_total is a share of where "
+    "a chip holds a share of the experts", ())
 SESS_POSITIONS = REGISTRY.counter(
     "pio_sess_positions_total",
     "Cached positions the lane's queries could see (a query's cached "
@@ -1010,6 +1015,22 @@ SESS_EXPERTS_TOUCHED = REGISTRY.counter(
     "token rows route too and are not counted), summed over expert "
     "layers and dispatches: whose weights the mathematics has to read",
     ())
+SESS_STATE_SLOTS = REGISTRY.gauge(
+    "pio_sess_state_slots",
+    "Slots the live sessions hold in the layers that keep one "
+    "constant-size state a session (a recurrent state and a "
+    "convolution's tail) instead of a cache row a token", ())
+SESS_STATE_CAPACITY = REGISTRY.gauge(
+    "pio_sess_state_slots_capacity",
+    "Slots the pool holds beside its spare", ())
+SESS_STATE_SLOT_BYTES = REGISTRY.gauge(
+    "pio_sess_state_slot_bytes",
+    "Bytes one session's slot holds, over the layers of its kind", ())
+SESS_STATE_BYTES = REGISTRY.counter(
+    "pio_sess_state_bytes_total",
+    "Bytes of session state the extend dispatches read and wrote back "
+    "(a query with new events: its slot in every layer of the kind, "
+    "each way)", ("dir",))
 SESS_EVICTIONS = REGISTRY.counter(
     "pio_sess_evictions_total",
     "Sessions whose cache blocks were released to make room (their "

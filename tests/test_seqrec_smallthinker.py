@@ -446,6 +446,20 @@ def test_ladder_is_complete_after_warm_up():
     srv.close()
 
 
+def test_the_programs_keep_the_names_the_trace_is_read_by():
+    """The device modules are ``jit_<the jitted function's name>``:
+    the sequence-mixed cell's readers find the lane's device time under
+    ``jit_swa_extend`` (``benchmark/drivers/http_sess_mixed.py``), and
+    the backbone names its closures by ``program_prefix``."""
+    params, theta, cfg = build()
+    srv = server(params, theta, {0: history(9, 0)})
+    S_ = srv._s_bucket(16)
+    names = (srv._bb.extend_program(srv, srv._sess_kb(5), S_).__name__,
+             srv._bb.prefill_program(srv, S_).__name__)
+    assert names == ("swa_extend", "swa_prefill")
+    srv.close()
+
+
 def test_engine_json_selects_the_block():
     from predictionio_tpu.controller.engine import params_from_dict
 
@@ -464,8 +478,8 @@ def test_engine_json_selects_the_block():
     spec = S.block_spec(got).swa
     assert (spec.kv_width, spec.group, spec.window, spec.pattern) \
         == (512, 7, 4096, (0, 1, 1, 1, 0, 1, 1, 1))
-    with pytest.raises(ValueError, match="glm_moe_dsa, sdar_moe and "
-                                         "smallthinker"):
+    with pytest.raises(ValueError, match="glm_moe_dsa, sdar_moe, "
+                                         "smallthinker and qwen3_next"):
         sessions.backbone_of(S.SeqRecParams(block="olmoe"))
 
 
