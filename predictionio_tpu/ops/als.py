@@ -464,7 +464,7 @@ def _refine_solve(A, b, X, solver: Optional[str]):
 def _solve_rows(Y, cols, weights, mask, lam: float, alpha: float,
                 implicit: bool, gram=None, solver: Optional[str] = None,
                 precision: str = "fp32", refine: bool = False,
-                extra_ridge=None):
+                extra_ridge=None, shared=None):
     """Normal-equation solve for one batch of rows: given fixed factors
     ``Y [M, R]`` and padded ratings ``[B, L]`` (+ validity mask), return
     new factors ``[B, R]``. ``gram`` (``Y^T Y``, implicit term) may be
@@ -473,6 +473,18 @@ def _solve_rows(Y, cols, weights, mask, lam: float, alpha: float,
     jit-friendly: static shapes, two einsums + batched Cholesky; runs on
     the MXU. Written to be shard_map-compatible: only ``cols``/``weights``/
     ``mask`` carry the batch dimension.
+
+    What runs where: with the Pallas solver resolved (one TPU device,
+    rank <= ``SPD_MAX_RANK``, or ``PIO_ALS_SOLVER=pallas``) and fp32
+    precision, :func:`_solve_rows_kernel`: the gather of 128-lane rows,
+    ``als_pallas.assemble_normal_equations`` (one kernel reads the
+    gathered block where it lies and writes ``A`` and ``b``
+    batch-minor) and ``als_pallas.spd_solve_batch_minor``; ``shared``
+    is :func:`_kernel_operands`' pair, made once a half-step by
+    :func:`_solve_side_bucketed` and here when absent. Everywhere else
+    (``lanes``: the sharded trainers, rank > 96; ``cho``: CPU, GPU; the
+    bf16 lane) the path below: ``jnp.take``, :func:`_assemble_fp32` or
+    :func:`_assemble_bf16`, :func:`_spd_solve`.
 
     ``lam``/``alpha`` may be python floats (the serial paths, where they
     are static jit args) or traced scalars (the vmapped config-grid
@@ -497,6 +509,11 @@ def _solve_rows(Y, cols, weights, mask, lam: float, alpha: float,
     import jax
     import jax.numpy as jnp
 
+    if assembles_in_kernel(solver, precision):
+        if shared is None:
+            shared = _kernel_operands(Y, lam, implicit, gram, extra_ridge)
+        return _solve_rows_kernel(cols, weights, mask, lam, alpha,
+                                  implicit, refine, *shared)
     with jax.named_scope("gather"):
         Yg = jnp.take(Y, cols, axis=0)        # [B, L, R] gather
     if precision == "bf16":
@@ -553,6 +570,72 @@ def _assemble_fp32(Y, Yg, weights, mask, lam, alpha, implicit: bool,
         A += extra_ridge.astype(A.dtype)[None, None, :] \
             * jnp.eye(R, dtype=A.dtype)
     return A, b, mask
+
+
+def assembles_in_kernel(solver: Optional[str], precision: str) -> bool:
+    """Whether :func:`_solve_rows` hands gather and assembly to the
+    Pallas kernel: wherever the Pallas solver was resolved (one TPU
+    device, rank <= ``SPD_MAX_RANK``, or ``PIO_ALS_SOLVER=pallas``), in
+    the fp32 lane."""
+    return solver == "pallas" and precision != "bf16"
+
+
+def _kernel_operands(Y, lam, implicit: bool, gram, extra_ridge):
+    """What every batch of rows solved against ``Y`` hands the assembly
+    kernel, made once a half-step: ``Y`` as the 128-lane table the
+    kernel's gather reads, and the part of ``A`` every row shares
+    (implicit: Gram + ridge; ``extra_ridge`` on the diagonal), which the
+    kernel sums the weighted outer products onto."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als_pallas
+
+    R = Y.shape[1]
+    eye = jnp.eye(R, dtype=Y.dtype)
+    if implicit:
+        if gram is None:
+            gram = jnp.matmul(Y.T, Y, precision=jax.lax.Precision.HIGHEST)
+        start = gram + lam * eye
+    else:
+        start = jnp.zeros((R, R), Y.dtype)
+    if extra_ridge is not None:
+        start = start + extra_ridge.astype(Y.dtype)[None, :] * eye
+    return als_pallas.widen_table(Y), als_pallas.widen_start(start)
+
+
+def _solve_rows_kernel(cols, weights, mask, lam, alpha, implicit: bool,
+                       refine: bool, wide, start):
+    """:func:`_solve_rows` where the Pallas kernels run: gather and
+    :func:`_assemble_fp32`'s equations come from
+    ``als_pallas.assemble_normal_equations`` batch-minor (``At [R, R,
+    Bq]``, ``bt [R, Bq]``), which is how ``spd_solve``'s kernel reads
+    them, so nothing passes over ``A`` between the two. ``wide`` and
+    ``start`` are :func:`_kernel_operands`'; ALS-WR's ridge, which
+    differs by row, is added behind the kernel."""
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als_pallas
+
+    B, R = cols.shape[0], start.shape[0]
+    dtype = wide.dtype
+    mask = mask.astype(dtype)
+    w = weights.astype(dtype) * mask          # zero out padded slots
+    aw, bw = implicit_weights(w, alpha) if implicit else (mask, w)
+    At, bt = als_pallas.assemble_normal_equations(wide, cols, aw, bw,
+                                                  start)
+    if not implicit:
+        with jax.named_scope("assemble"):
+            n_b = jnp.pad(jnp.sum(mask, axis=1), (0, bt.shape[1] - B))
+            At += jnp.eye(R, dtype=dtype)[:, :, None] \
+                * (lam * jnp.maximum(n_b, 1.0))
+    with jax.named_scope("solve"):
+        X = als_pallas.spd_solve_batch_minor(At, bt)[:, :B].T
+        if refine:
+            A, b = jnp.transpose(At[:, :, :B], (2, 0, 1)), bt[:, :B].T
+            X = _refine_solve(A, b, X, "pallas")
+        return zero_empty_rows(X, mask)
 
 
 def _solve_rows_bf16(Y, Yg, weights, mask, lam: float, alpha: float,
@@ -686,14 +769,21 @@ def _spd_solver_mode(rank: int, operands) -> str:
     return _resolve_spd_solver(rank, operands).name
 
 
-def solve_span_attributes(choice: SolverChoice, systems: int) -> dict:
+def solve_span_attributes(choice: SolverChoice, systems: int,
+                          precision: str = "fp32") -> dict:
     """What a span round a batch of solves says of them: the resolved
     ``solver``, the ``solve_systems`` it was handed (padded rows
-    included) and how many of those took ``lanes`` only because the
-    Pallas kernel could not (``solve_systems_fallback``)."""
+    included), how many of those took ``lanes`` only because the
+    Pallas kernel could not (``solve_systems_fallback``), and how many
+    had their equations assembled by the Pallas kernel
+    (``assemble_systems_kernel``: all where :func:`assembles_in_kernel`
+    says so, else none)."""
     return {"solver": choice.name, "solve_systems": int(systems),
             "solve_systems_fallback":
-                int(systems) if choice.fell_back else 0}
+                int(systems) if choice.fell_back else 0,
+            "assemble_systems_kernel":
+                int(systems) if assembles_in_kernel(choice.name, precision)
+                else 0}
 
 
 def _spd_solve(A, b, mode: str):
@@ -858,6 +948,9 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
             gram = jnp.matmul(Y.T, Y,
                               precision=jax.lax.Precision.HIGHEST) \
                 if implicit else None
+    shared = None
+    if assembles_in_kernel(solver, precision):
+        shared = _kernel_operands(Y, lam, implicit, gram, extra_ridge)
     ids, solved = [], []
     for row_ids, cols, w, m in buckets:
         B, L = cols.shape
@@ -876,7 +969,7 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
                 c_, w_, m_ = args
                 return _solve_rows(Y, c_, w_, m_, lam, alpha, implicit,
                                    _gram, solver, precision, refine,
-                                   extra_ridge)
+                                   extra_ridge, shared)
 
             Xb = jax.lax.map(one, (cols.reshape(nb, block, L),
                                    w.reshape(nb, block, L),
@@ -884,7 +977,8 @@ def _solve_side_bucketed(Y, buckets, n_rows_out: int, lam: float,
             Xb = Xb.reshape(B + pad, R)
         else:
             Xb = _solve_rows(Y, cols, w, m, lam, alpha, implicit, gram,
-                             solver, precision, refine, extra_ridge)
+                             solver, precision, refine, extra_ridge,
+                             shared)
         ids.append(row_ids)
         solved.append(Xb)
     # ONE scatter a half-step, after the last solve: the new factor
@@ -1542,7 +1636,7 @@ def train_als_bucketed(user_side: BucketedRatings,
         systems = sum(int(t[1].shape[0]) for t in u_t + i_t) \
             * kw["num_iterations"] * (2 if kw["refine"] else 1)
         with _tracing.span("als.iterations", attributes=(
-                solve_span_attributes(choice, systems))):
+                solve_span_attributes(choice, systems, precision))):
             compile_s0 = _metrics.JIT_COMPILE_SECONDS.value()
             t0 = _tracing.span_now()
             if ckpt is None:
@@ -1785,7 +1879,7 @@ def fold_in_users(item_factors, cols_list: Sequence[np.ndarray],
             attributes=dict(
                 rec or {}, **solve_span_attributes(
                     choice, int(cols.shape[0])
-                    * (2 if fold_kwargs["refine"] else 1))))
+                    * (2 if fold_kwargs["refine"] else 1), precision)))
     return np.asarray(out[:k], dtype=np.float32)
 
 

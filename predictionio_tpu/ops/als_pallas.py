@@ -1,6 +1,6 @@
 """Pallas TPU kernels for ALS.
 
-Two kernels live here:
+Three kernels live here, two the trainer's and one the server's:
 
 1. ``spd_solve`` — batched symmetric positive-definite solve (Cholesky
    factorization + forward/backward triangular substitution fused in
@@ -23,8 +23,34 @@ Two kernels live here:
    and assembly (PERF.md sections 5 and 6, PR 26). Each of the R
    steps updates the whole ``R x R`` block where only the trailing
    part is live: the open item of PERF.md section 7.
+   ``spd_solve_batch_minor`` is the same kernel on systems that lie
+   batch-minor already, which is how kernel 2 writes them.
 
-2. ``fused_gather_score_topk`` — the SERVING kernel (ROADMAP item 4):
+2. ``assemble_normal_equations`` — the gather and the fp32 normal
+   equations of one batch of rows (PR 44). STATUS — runs wherever
+   ``spd_solve`` was resolved, in the fp32 lane
+   (``ops.als.assembles_in_kernel``: the bucketed trainer, the grid
+   trainer under ``vmap``, fold-in; CPU tests interpret it under
+   ``PIO_ALS_SOLVER=pallas``); everything else (``lanes``, ``cho``, the
+   bf16 lane, the sharded trainers) keeps XLA's einsums
+   (``ops.als._assemble_fp32``). The factor table is gathered as rows
+   of 128 lanes (``widen_table``), because a Mosaic DMA moves a block
+   of 64-lane rows, which lie in HBM padded to 128, at 132 GB/s and a
+   block of whole rows at 693, and the gather writes either in the same
+   time; the kernel reads each ``[rows, slots, 128]`` block once where
+   the gather wrote it (XLA's einsum wants the block re-laid slots-minor
+   first: a fifth of an iteration was that copy), transposes a row's
+   slots onto the lanes in VMEM, and takes eight rows a batched MXU
+   product at ``Precision.HIGHEST``; ``b`` rides in the same product.
+   The sums start from Gram + ridge, stay in VMEM over a row's chunks
+   and leave the kernel batch-minor, ``[R, R, 128]`` a grid step, so
+   nothing passes over ``A`` between the two kernels (XLA spent a tenth
+   of an iteration on three such passes). On a v5e the ML-20M iteration
+   went from 284 to 185 ms (PERF.md sections 5 and 6, PR 44); the kernel
+   is bound by its DMA (0.8-1.2 ns a slot of 512 bytes), then by the
+   batch-minor write-out of short rows (PERF.md section 7).
+
+3. ``fused_gather_score_topk`` — the SERVING kernel (ROADMAP item 4):
    score matvec + seen-row masking + top-k selection fused into one
    program. The XLA chain dispatches gather/einsum/mask/top_k as
    separate HLOs whose ``[B, M]`` score intermediate round-trips HBM
@@ -186,11 +212,8 @@ def spd_solve(A, b, interpret: Optional[bool] = None):
     size with identity systems internally; inputs are transposed to the
     kernel's batch-on-lanes layout (XLA fuses the transpose into the
     producing einsum)."""
-    import jax
     import jax.numpy as jnp
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     B, R = b.shape
     At = jnp.transpose(A.astype(jnp.float32), (1, 2, 0))   # [R, R, B]
     bt = b.astype(jnp.float32).T                           # [R, B]
@@ -201,8 +224,269 @@ def spd_solve(A, b, interpret: Optional[bool] = None):
         At = jnp.concatenate([At, eye], axis=2)
         bt = jnp.concatenate([bt, jnp.zeros((R, pad), jnp.float32)],
                              axis=1)
-    x = _build_spd(B + pad, R, bool(interpret))(At, bt)
-    return x[:, :B].T
+    return spd_solve_batch_minor(At, bt, interpret)[:, :B].T
+
+
+def spd_solve_batch_minor(At, bt, interpret: Optional[bool] = None):
+    """:func:`spd_solve` on systems that are batch-minor already:
+    ``At [R, R, Bq]``, ``bt [R, Bq]`` with ``Bq`` whole blocks of 128
+    systems (what :func:`assemble_normal_equations` writes); ``x^T [R,
+    Bq]``."""
+    import jax
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    R, Bq = bt.shape
+    return _build_spd(Bq, R, bool(interpret))(At, bt)
+
+
+# ---------------------------------------------------------------------------
+# Normal-equation assembly from the gathered block (the trainer's second
+# kernel)
+# ---------------------------------------------------------------------------
+
+# the gathered rows are whole lane tiles: a [*, 64] float32 array lies
+# in HBM as rows of 128 lanes with half of each row padding, and a DMA
+# of such a block moves 256-byte pieces at a fifth of the rate it moves
+# whole rows (132 against 693 GB/s on a v5e); the gather itself writes a
+# 128-lane row as fast as the padded 64-lane one (PERF.md 6, PR 44)
+ASM_LANES = 128
+# rows a batched product takes: one sublane tile of the weights
+_ASM_G = 8
+# the most slots a grid step holds of one row, and of all its rows: a
+# [TB, Lc, 128] block of at most 8 MiB, double-buffered
+_ASM_LC = 512
+_ASM_SLOTS = 16384
+# entries of the batch-minor write-out unrolled in a loop step (rolled
+# up, a strided read waits for the one before)
+_ASM_EMIT = 4
+
+
+def _ceil_to(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _assemble_kernel(g0_ref, aw_ref, bw_ref, z_ref, at_ref, bt_ref,
+                     acc, bacc, *, B: int):
+    """``A_b = G0 + sum_l aw[b, l] z_l z_l^T`` and ``b_b = sum_l
+    bw[b, l] z_l`` for the ``_SPD_BB`` rows of grid step ``i``, taken
+    ``TB`` rows (axis 1) and ``Lc`` slots (axis 2) at a time and
+    written batch-minor at the block's last step, as the solver reads
+    them.
+
+    ``z_ref [TB, Lc, 128]`` is the gathered block as the gather wrote it:
+    a slot a sublane, the factor on the lanes. A row's product contracts
+    over its slots, so the row's block is transposed once in VMEM (slots
+    to the lanes), scaled there by the row's weights, which lie along
+    the lanes as they came, and multiplied on the MXU against the block
+    itself: ``[Rp, Lc] @ [Lc, 128]``, fp32 operands at
+    ``Precision.HIGHEST``, eight rows a batched product. The ``b``
+    weights of the eight rows ride as eight more rows of every left
+    operand, so ``b`` costs no second read. Row
+    ``b``'s sums live in ``acc[b * Rp:(b + 1) * Rp]``; rows at and
+    beyond ``B`` are never touched and leave the kernel as ``G0`` and a
+    zero right-hand side, systems the solver can take."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    TB, Lc, W = z_ref.shape
+    R = at_ref.shape[0]
+    Rp = g0_ref.shape[0]
+    G = _ASM_G
+    i, s, j = (pl.program_id(a) for a in range(3))
+    first = jnp.logical_and(s == 0, j == 0)
+    last = jnp.logical_and(s == pl.num_programs(1) - 1,
+                           j == pl.num_programs(2) - 1)
+
+    @pl.when(first)
+    def _():
+        def fill(b, _):
+            acc[pl.ds(pl.multiple_of(b * Rp, 8), Rp), :] = g0_ref[...]
+            return 0
+
+        jax.lax.fori_loop(0, _SPD_BB, fill, 0)
+        bacc[...] = jnp.zeros_like(bacc)
+
+    # the rows of this block that exist, whole sublane tiles of them
+    # (lax and not jnp for the index arithmetic: every jnp call is a
+    # nested jit to trace and lower, in 21 kernels a program)
+    n_rows = jax.lax.clamp(0, B - (i * _SPD_BB + s * TB), TB)
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (G, G, 1), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (G, G, 1), 1)
+           ).astype(jnp.float32)
+
+    def group(k, _):
+        # eight rows in one batched product: as fast as eight unrolled
+        # products (rolled up, a row waits for the one before: 2.4 times
+        # the time at L 128) for an eighth of the ops to trace and lower
+        r0 = pl.multiple_of(k * G, G)             # in this block of rows
+        b0 = s * TB + r0                          # in the step's 128
+        z = z_ref[pl.ds(r0, G)]                           # [G, Lc, 128]
+        lhs = jnp.concatenate(
+            [jnp.swapaxes(z, 1, 2)[:, :Rp]
+             * aw_ref[pl.ds(r0, G), :].reshape(G, 1, Lc),
+             jnp.broadcast_to(bw_ref[pl.ds(r0, G), :][None], (G, G, Lc))],
+            axis=1)                                       # [G, Rp + G, Lc]
+        out = jax.lax.dot_general(
+            lhs, z, (((2,), (1,)), ((0,), (0,))),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)           # [G, Rp + G, 128]
+        acc[pl.ds(pl.multiple_of(b0 * Rp, 8), G * Rp), :] += \
+            out[:, :Rp].reshape(G * Rp, W)
+        # row g's b is its own weights' product: [g, Rp + g, :]
+        bacc[pl.ds(pl.multiple_of(b0, G), G), :] += \
+            jnp.sum(out[:, Rp:] * eye, axis=1)
+        return 0
+
+    jax.lax.fori_loop(0, jax.lax.div(n_rows, G), group, 0)
+
+    @pl.when(last)
+    def _():
+        # entry (r, :) of every row's matrix, one strided read: [128
+        # rows, 128 lanes] -> transposed, the rows on the lanes
+        def emit(r):
+            at_ref[r] = acc[pl.ds(r, _SPD_BB, stride=Rp), :].T[:R]
+
+        def emit_some(k, _):
+            jax.lax.fori_loop(
+                0, _ASM_EMIT, lambda u, _: emit(k * _ASM_EMIT + u), None,
+                unroll=True)
+            return 0
+
+        jax.lax.fori_loop(0, R // _ASM_EMIT, emit_some, 0)
+        for r in range(R // _ASM_EMIT * _ASM_EMIT, R):
+            emit(r)
+        bt_ref[...] = bacc[...].T[:R]
+
+
+def _assemble_blocks(B: int, L: int):
+    """``(TB, Lc, Lp)``: rows and slots of a block, and ``L`` padded to
+    whole blocks (to the sublane tile under ``_ASM_LC`` slots). ``TB``
+    divides the 128 rows of a grid step."""
+    Lp = _ceil_to(L, 8 if L <= _ASM_LC else _ASM_LC)
+    Lc = min(Lp, _ASM_LC)
+    TB = _ASM_G
+    while 2 * TB * Lc <= _ASM_SLOTS and 2 * TB <= min(_SPD_BB, B):
+        TB *= 2
+    return TB, Lc, Lp
+
+
+@functools.lru_cache(maxsize=64)
+def _build_assemble(B: int, Lp: int, R: int, TB: int, Lc: int,
+                    interpret: bool):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    W, BB = ASM_LANES, _SPD_BB
+    Rp = _ceil_to(R, 8)
+    n_blocks = -(-B // TB)                  # blocks of rows that exist
+    steps = -(-B // BB)
+    per_step = -(-min(B, BB) // TB)
+
+    def rows(i, s, j):
+        # the last step's blocks past B do not exist: they re-read the
+        # last that does, and the body skips them
+        return jnp.minimum(i * (BB // TB) + s, n_blocks - 1)
+
+    # the gathered block, the weights' blocks and both outputs twice
+    # (the pipeline double-buffers them), the accumulators once
+    vmem_bytes = 4 * (2 * TB * Lc * W + 4 * TB * Lc + 2 * Rp * W
+                      + BB * Rp * W + BB * W + 2 * R * R * BB + 2 * R * BB)
+    return pl.pallas_call(
+        functools.partial(_assemble_kernel, B=B),
+        grid=(steps, per_step, Lp // Lc),
+        in_specs=[
+            pl.BlockSpec((Rp, W), lambda i, s, j: (0, 0)),
+            pl.BlockSpec((TB, Lc), lambda i, s, j: (rows(i, s, j), j)),
+            pl.BlockSpec((TB, Lc), lambda i, s, j: (rows(i, s, j), j)),
+            pl.BlockSpec((TB, Lc, W),
+                         lambda i, s, j: (rows(i, s, j), j, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((R, R, BB), lambda i, s, j: (0, 0, i)),
+            pl.BlockSpec((R, BB), lambda i, s, j: (0, i)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((R, R, steps * BB), jnp.float32),
+                   jax.ShapeDtypeStruct((R, steps * BB), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((BB * Rp, W), jnp.float32),    # acc
+                        pltpu.VMEM((BB, W), jnp.float32)],        # bacc
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes + _SPD_VMEM_MARGIN),
+        interpret=interpret,
+        name="als_assemble",
+    )
+
+
+def widen_table(Y):
+    """``Y [M, R]`` as the ``[M, 128]`` float32 table the assembly
+    gathers from: zeros beyond ``R``. Behind a barrier, or XLA moves
+    the padding behind the gather and pads every gathered block in a
+    pass of its own."""
+    import jax
+    import jax.numpy as jnp
+
+    R = Y.shape[1]
+    if R > ASM_LANES:
+        raise ValueError(
+            f"the assembly kernel takes rank <= {ASM_LANES}, got {R}")
+    return jax.lax.optimization_barrier(
+        jnp.pad(Y.astype(jnp.float32), ((0, 0), (0, ASM_LANES - R))))
+
+
+def widen_start(g0):
+    """``g0 [R, R]``, what every row's ``A`` starts from, as the kernel
+    takes it: ``[R, 128]`` float32, zeros beyond ``R``."""
+    import jax.numpy as jnp
+
+    return jnp.pad(g0.astype(jnp.float32),
+                   ((0, 0), (0, ASM_LANES - g0.shape[0])))
+
+
+def assemble_normal_equations(Yw, cols, aw, bw, g0,
+                              interpret: Optional[bool] = None):
+    """``A_b = g0 + sum_l aw[b, l] y_l y_l^T`` and ``b_b = sum_l
+    bw[b, l] y_l`` with ``y_l = Yw[cols[b, l]]``: the gather and the
+    normal equations of one batch of rows, fp32 throughout, the block
+    read once where the gather wrote it. Returned BATCH-MINOR, as
+    :func:`spd_solve_batch_minor` takes them: ``At [R, R, Bq]`` and
+    ``bt [R, Bq]`` with ``Bq`` the rows rounded up to whole solver
+    blocks, the rows past ``B`` holding ``g0`` and zeros.
+
+    ``Yw`` is :func:`widen_table`'s and ``g0 [R, 128]``
+    :func:`widen_start`'s: rows of 128 lanes, so that both the gather
+    and the kernel's DMA move whole rows. The slot axis is padded to
+    whole blocks, and the rows (``g0``'s too) to the sublane tile, with
+    slots of weight zero."""
+    import jax
+    import jax.numpy as jnp
+
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    B, L = cols.shape
+    R = g0.shape[0]
+    if R % 8:
+        g0 = jnp.pad(g0, ((0, (-R) % 8), (0, 0)))
+    B8 = _ceil_to(B, _ASM_G)
+    TB, Lc, Lp = _assemble_blocks(B8, L)
+    if (B8, Lp) != (B, L):
+        grow = ((0, B8 - B), (0, Lp - L))
+        cols, aw, bw = (jnp.pad(a, grow) for a in (cols, aw, bw))
+    with jax.named_scope("gather"):
+        # one gather primitive (jnp.take wraps it in a jit of its own
+        # to trace and lower, once a bucket); the columns are in bounds
+        z = jax.lax.gather(                               # [B8, Lp, 128]
+            Yw, cols[..., None],
+            jax.lax.GatherDimensionNumbers(
+                offset_dims=(2,), collapsed_slice_dims=(0,),
+                start_index_map=(0,)),
+            slice_sizes=(1, ASM_LANES), mode="clip")
+    with jax.named_scope("assemble"):
+        return _build_assemble(B8, Lp, R, TB, Lc, bool(interpret))(
+            g0, aw, bw, z)
 
 
 # ---------------------------------------------------------------------------

@@ -189,3 +189,175 @@ class TestScoring:
         items = np.array([[1.0, 0.0], [0.0, 1.0]])
         np.testing.assert_allclose(
             predict_scores_for_user(u, items), [1.0, 2.0])
+
+
+# ---------------------------------------------------------------------------
+# the assembly kernel (PR 44), interpreted here: where the Pallas solver
+# runs, gather and normal equations come from
+# als_pallas.assemble_normal_equations, batch-minor
+# ---------------------------------------------------------------------------
+
+def _assembly_problem(B, L, R, seed=0, n_cols=50):
+    import jax
+    import jax.numpy as jnp
+
+    k = jax.random.split(jax.random.PRNGKey(seed), 5)
+    Y = jax.random.normal(k[0], (n_cols, R), jnp.float32)
+    cols = jax.random.randint(k[1], (B, L), 0, n_cols)
+    w = jax.random.uniform(k[2], (B, L), jnp.float32, -2.0, 5.0)
+    m = (jax.random.uniform(k[3], (B, L)) < 0.8).astype(jnp.float32)
+    m = m.at[1].set(0.0)                    # an all-padding row
+    ridge = jnp.abs(jax.random.normal(k[4], (R,), jnp.float32))
+    return Y, cols, w, m, ridge
+
+
+def _kernel_equations(Y, cols, w, m, lam, alpha, implicit, ridge):
+    """``A [B, R, R]``, ``b [B, R]`` as the kernel path assembles them,
+    and the batch-minor arrays themselves."""
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als, als_pallas
+
+    B, R = cols.shape[0], Y.shape[1]
+    wide, start = als._kernel_operands(Y, lam, implicit, None, ridge)
+    wm = w * m
+    aw, bw = als.implicit_weights(wm, alpha) if implicit else (m, wm)
+    At, bt = als_pallas.assemble_normal_equations(wide, cols, aw, bw,
+                                                  start, interpret=True)
+    A = jnp.transpose(At[:, :, :B], (2, 0, 1))
+    if not implicit:                        # ALS-WR's ridge, by row
+        A = A + (lam * jnp.maximum(m.sum(axis=1), 1.0))[:, None, None] \
+            * jnp.eye(R)
+    return A, bt[:, :B].T, At, bt, start
+
+
+def _float64_equations(Y, cols, w, m, lam, alpha, implicit, ridge):
+    """The same equations in float64 numpy: what both float32
+    assemblies are off from (at L 4,096 the einsum's ``b`` by 1.2e-6 of
+    its largest entry, the kernel's by 1.5e-7)."""
+    Y, w, m = (np.asarray(a, np.float64) for a in (Y, w, m))
+    R, Yg, w = Y.shape[1], Y[np.asarray(cols)], w * m
+    if implicit:
+        aw = alpha * np.abs(w)
+        bw = (w > 0) * (1.0 + aw)
+        A = Y.T @ Y + lam * np.eye(R) \
+            + np.einsum("bl,blr,bls->brs", aw, Yg, Yg)
+    else:
+        bw = w
+        A = np.einsum("bl,blr,bls->brs", m, Yg, Yg) \
+            + (lam * np.maximum(m.sum(axis=1), 1.0))[:, None, None] \
+            * np.eye(R)
+    if ridge is not None:
+        A = A + np.diag(np.asarray(ridge, np.float64))
+    return A, np.einsum("bl,blr->br", bw, Yg)
+
+
+def _worst(got, want) -> float:
+    """Largest difference as a share of the largest entry wanted."""
+    want = np.asarray(want, np.float64)
+    return float(np.abs(np.asarray(got, np.float64) - want).max()
+                 / max(np.abs(want).max(), 1e-30))
+
+
+@pytest.mark.pallas
+class TestAssemblyKernel:
+    # B 13: under one sublane tile of rows past 8, an all-padding row;
+    # L 200 is a multiple of no tile, 4,096 is eight chunks of a row
+    @pytest.mark.parametrize("with_ridge", [False, True],
+                             ids=["", "extra_ridge"])
+    @pytest.mark.parametrize("L", [16, 64, 200, 4096])
+    @pytest.mark.parametrize("implicit", [True, False],
+                             ids=["implicit", "explicit"])
+    def test_kernel_matches_the_einsum_assembly(self, implicit, L,
+                                                with_ridge):
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        B, R = 13, 16
+        Y, cols, w, m, ridge = _assembly_problem(B, L, R, seed=L)
+        ridge = ridge if with_ridge else None
+        A, b, At, bt, start = _kernel_equations(Y, cols, w, m, 0.1, 1.5,
+                                                implicit, ridge)
+        A0, b0, _ = als._assemble_fp32(Y, jnp.take(Y, cols, axis=0), w, m,
+                                       0.1, 1.5, implicit, None, ridge)
+        A64, b64 = _float64_equations(Y, cols, w, m, 0.1, 1.5, implicit,
+                                      ridge)
+        # within 1e-6 of the exact sums, and of the einsum's as far as
+        # the einsum is itself
+        assert _worst(A, A64) <= 1e-6 and _worst(b, b64) <= 1e-6
+        assert _worst(A, A0) <= 1e-6 + _worst(A0, A64)
+        assert _worst(b, b0) <= 1e-6 + _worst(b0, b64)
+        assert _worst(jnp.swapaxes(A, 1, 2), A) <= 1e-6
+        # the all-padding row: what every row shares, and no right side
+        assert float(jnp.abs(b[1]).max()) == 0.0
+        np.testing.assert_array_equal(np.asarray(At[:, :, 1]),
+                                      np.asarray(start[:, :R]))
+        # the rows past B, up to the solver's block of 128: systems the
+        # solver can take
+        assert At.shape == (R, R, 128) and bt.shape == (R, 128)
+        np.testing.assert_array_equal(
+            np.asarray(At[:, :, B:]),
+            np.broadcast_to(np.asarray(start[:, :R])[:, :, None],
+                            (R, R, 128 - B)))
+        assert float(jnp.abs(bt[:, B:]).max()) == 0.0
+
+    @pytest.mark.parametrize("B,R", [(136, 8), (260, 10), (72, 50)])
+    def test_rows_over_several_blocks_and_odd_ranks(self, B, R):
+        """B 136 and 260: two and three solver blocks, the last a
+        partial one whose input blocks past B do not exist; rank 10 and
+        50: no multiple of the sublane tile."""
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        Y, cols, w, m, ridge = _assembly_problem(B, 64, R, seed=B)
+        A, b, At, _, _ = _kernel_equations(Y, cols, w, m, 0.05, 2.0, True,
+                                           ridge)
+        A0, b0, _ = als._assemble_fp32(Y, jnp.take(Y, cols, axis=0), w, m,
+                                       0.05, 2.0, True, None, ridge)
+        assert At.shape[2] == -(-B // 128) * 128
+        assert _worst(A, A0) <= 1e-6 and _worst(b, b0) <= 1e-6
+
+    @pytest.mark.parametrize("implicit", [True, False],
+                             ids=["implicit", "explicit"])
+    def test_solve_rows_same_under_vmap_over_configs(self, implicit):
+        """The grid trainer's use: one table of ratings, a factor set,
+        a lambda, an alpha and a ridge a config."""
+        import jax
+        import jax.numpy as jnp
+
+        from predictionio_tpu.ops import als
+
+        B, L, R, k = 24, 40, 8, 3
+        _, cols, w, m, _ = _assembly_problem(B, L, R, seed=3)
+        keys = jax.random.split(jax.random.PRNGKey(9), 2)
+        Ys = jax.random.normal(keys[0], (k, 50, R), jnp.float32)
+        lam = jnp.asarray([0.01, 0.1, 1.0], jnp.float32)
+        alpha = jnp.asarray([0.5, 1.0, 4.0], jnp.float32)
+        ridge = jnp.abs(jax.random.normal(keys[1], (k, R), jnp.float32))
+
+        def solve(solver):
+            return jax.vmap(
+                lambda Yk, lk, ak, rk: als._solve_rows(
+                    Yk, cols, w, m, lk, ak, implicit, None, solver,
+                    "fp32", False, rk))(Ys, lam, alpha, ridge)
+
+        got, want = solve("pallas"), solve("cho")
+        assert got.shape == (k, B, R)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-5)
+        assert float(jnp.abs(got[:, 1]).max()) == 0.0   # the empty row
+
+    @pytest.mark.parametrize("solver,fell_back,precision,kernel", [
+        ("pallas", False, "fp32", True), ("cho", False, "fp32", False),
+        ("lanes", True, "fp32", False), ("pallas", False, "bf16", False)])
+    def test_span_counts_the_systems_the_kernel_assembled(
+            self, solver, fell_back, precision, kernel):
+        from predictionio_tpu.ops.als import (
+            SolverChoice, solve_span_attributes)
+
+        attrs = solve_span_attributes(SolverChoice(solver, fell_back), 7,
+                                      precision)
+        assert attrs["assemble_systems_kernel"] == (7 if kernel else 0)
+        assert attrs["solve_systems"] == 7

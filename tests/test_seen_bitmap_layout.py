@@ -401,6 +401,33 @@ def test_v5e_spd_solve_lowers_at_every_template_rank(rank, one_chip):
     assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
 
 
+# the assembly kernel beside it (PR 44): B = 300 is three solver blocks,
+# the last a partial one; L = 1,100 is three chunks of a row, padded; at
+# rank 96 the kernel holds 36 MiB of VMEM (its blocks of 8 MiB twice,
+# the accumulator, the batch-minor output twice) and asks for them
+@pytest.mark.parametrize("rank", [8, 10, 64, 96])
+def test_v5e_assembly_lowers_at_every_template_rank(rank, one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from predictionio_tpu.ops import als_pallas
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def equations(Y, cols, aw, bw, g0):
+        return als_pallas.assemble_normal_equations(
+            als_pallas.widen_table(Y), cols, aw, bw,
+            als_pallas.widen_start(g0), interpret=False)
+
+    compiled = jax.jit(equations).lower(
+        sds((27_000, rank)), sds((300, 1100), jnp.int32), sds((300, 1100)),
+        sds((300, 1100)), sds((rank, rank))).compile()
+    hlo = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    assert f"f32[{rank},{rank},384]" in hlo      # batch-minor, 3 blocks
+
+
 # the padded bucket tables of cell rec-ml20m.train (138,000 x 27,000,
 # 17.5M pairs): (rows, slots) a bucket, users then items
 ML20M_BUCKETS = (
@@ -414,15 +441,19 @@ ML20M_BUCKETS = (
 def test_v5e_training_program_keeps_the_factors_in_vmem(one_chip,
                                                         monkeypatch):
     """The 5-iteration program of the training cell with the solver a
-    TPU resolves: one Mosaic kernel a bucket, and the user factors born
-    in VMEM (``S(1)``) after the half-step's ONE scatter, so that every
-    item-step gather reads them there. Scattered into bucket by bucket,
-    between the kernels, they stayed in HBM and those gathers ran at a
-    seventh of the rate (PERF.md section 6, PR 26)."""
+    TPU resolves: two Mosaic kernels a bucket (assembly, solve), and the
+    user factors born in VMEM (``S(1)``) after the half-step's ONE
+    scatter, so that every item-step gather reads them there. Scattered
+    into bucket by bucket, between the kernels, they stayed in HBM and
+    those gathers ran at a seventh of the rate (PERF.md section 6, PR
+    26). Since PR 44 the table gathered from is the 128-lane one
+    (``als_pallas.widen_table``), the assembly kernel reads each
+    gathered block where the gather wrote it, and the solver reads the
+    equations where the assembly wrote them: no ``copy`` of either."""
     import jax
     import jax.numpy as jnp
 
-    from predictionio_tpu.ops import als
+    from predictionio_tpu.ops import als, als_pallas
 
     # spd_solve reads "compiled, not interpreted" off the platform
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -448,18 +479,35 @@ def test_v5e_training_program_keeps_the_factors_in_vmem(one_chip,
         alpha=1.0, implicit=True, num_iterations=5, slot_budget=None,
         solver="pallas", precision="fp32", refine=False).compile()
     hlo = compiled.as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 21
+    buckets = ML20M_BUCKETS[0] + ML20M_BUCKETS[1]
+    kernels = hlo.count('custom_call_target="tpu_custom_call"')
+    assert kernels == 42 and kernels <= 2 * len(buckets)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.5e9
+    # no gathered block is laid out anew, as written ([B, L, 64] until
+    # PR 44: 20 of the 21 had a copy to {1,2,0}) or as padded to the
+    # kernel's blocks ([B, Lp, 128]); every bucket takes the kernel
+    copied = re.findall(r"= (f32\[\d+,\d+,\d+\])\{[^}]*\} copy\(", hlo)
+    blocks = {f"f32[{b},{l},64]" for b, l in buckets} | {
+        f"f32[{b},{als_pallas._assemble_blocks(b, l)[2]},"
+        f"{als_pallas.ASM_LANES}]" for b, l in buckets}
+    assert not blocks & set(copied), sorted(blocks & set(copied))
+    # nor the equations between assembly and solve: A leaves the one
+    # kernel batch-minor and whole blocks of systems wide
+    systems = {f"f32[64,64,{-(-b // 128) * 128}]" for b, _ in buckets} | {
+        f"f32[{b},64,64]" for b, _ in buckets}
+    assert not systems & set(copied), sorted(systems & set(copied))
     defs = dict(re.findall(r"^\s*%(\S+) = (\S+)", hlo, re.M))
-    gathers = re.findall(
-        r"^\s*%\S+ = f32\[\d+,64\]\S* fusion\(%(\S+), [^\n]*kind=kCustom"
-        r"[^\n]*item_step/gather", hlo, re.M)
-    assert len(gathers) == 10
-    in_hbm = [t for t in gathers
-              if not (defs[t].startswith("f32[138000,64]")
-                      and "S(1)" in defs[t])]
-    assert not in_hbm, f"item-step gathers read the factors in HBM: {in_hbm}"
+    for step, table, n in (("user_step", "f32[27000,128]", 11),
+                           ("item_step", "f32[138000,128]", 10)):
+        gathers = re.findall(
+            r"^\s*%\S+ = f32\[\d+,128\]\S* fusion\(%(\S+), [^\n]*"
+            rf"kind=kCustom[^\n]*{step}/gather", hlo, re.M)
+        assert len(gathers) == n
+        in_hbm = [t for t in gathers
+                  if not (defs[t].startswith(table) and "S(1)" in defs[t])]
+        assert not in_hbm, \
+            f"{step} gathers read the factors in HBM: {in_hbm}"
 
 
 @pytest.mark.parametrize("spec", [("data", None), (None, None)],

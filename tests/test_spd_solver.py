@@ -201,7 +201,8 @@ class TestResolver:
                           for b in us.buckets + its.buckets)
         assert sp["attributes"] == {
             "solver": solver, "solve_systems": systems,
-            "solve_systems_fallback": systems if fallback else 0}
+            "solve_systems_fallback": systems if fallback else 0,
+            "assemble_systems_kernel": 0 if fallback else systems}
 
     def test_forced_lanes_is_no_fallback(self, monkeypatch):
         from predictionio_tpu.ops.als import solve_span_attributes
@@ -211,7 +212,7 @@ class TestResolver:
         assert choice == ("lanes", False)
         assert solve_span_attributes(choice, 7) == {
             "solver": "lanes", "solve_systems": 7,
-            "solve_systems_fallback": 0}
+            "solve_systems_fallback": 0, "assemble_systems_kernel": 0}
 
     def test_fold_in_span_says_what_ran(self, monkeypatch):
         from predictionio_tpu.utils import tracing

@@ -942,7 +942,7 @@ class TestDeviceProgramNames:
         assert "fold_in/gather" in text and "fold_in/solve" in text
         src = inspect.getsource(als_pallas)
         assert src.count("pl.pallas_call(") == \
-            len(re.findall(r'\n        name="[a-z_]+",\n', src)) == 2
+            len(re.findall(r'\n        name="[a-z_]+",\n', src)) == 3
 
 
 # ---------------------------------------------------------------------------
