@@ -908,19 +908,6 @@ def _serve_aot_enabled() -> bool:
         not in ("0", "off", "false")
 
 
-def _batch_window() -> float:
-    """``PIO_BATCH_WINDOW`` — the batching BUDGET in seconds (default
-    2ms): how long the dispatcher may hold a lone query hoping more
-    arrive to share its device dispatch. 0 disables the hold (dispatch
-    as soon as the dispatcher is free, the pre-PR-10 behavior). At
-    light load the budget is the whole added latency (~2ms against a
-    multi-ms query); under load batches fill to ``max_batch`` long
-    before it expires and the window never binds."""
-    from predictionio_tpu.utils.resilience import _env_float
-
-    return max(0.0, _env_float("PIO_BATCH_WINDOW", 0.002))
-
-
 class _BatchResult:
     """One batched dispatch's output, shared by every request in the
     group. Per-request rendering (row slice, clip to the request's own
@@ -956,7 +943,8 @@ class _BatchResult:
 
 class _Pending:
     """One queued query: payload (uid, or item-index tuple), its k, its
-    batching deadline (arrival + window; the EDF sort key) and the
+    batching deadline (the EDF sort key: its arrival, plus the window
+    a caller stated for it, if any did) and the
     future the waiting thread blocks on. ``arrival`` (monotonic) feeds
     the flight recorder's queue-wait figure; ``ctx`` carries the
     submitting thread's trace context so the dispatcher thread can
@@ -1064,7 +1052,7 @@ class BatchLane:
         self.dispatches = 0
         self.batched_queries = 0
         self.rejections = 0
-        self.triggers = {"size": 0, "window": 0, "drain": 0}
+        self.triggers = {"size": 0, "window": 0, "free": 0, "drain": 0}
         self.depth_samples: collections.deque = collections.deque(
             maxlen=512)
 
@@ -1096,8 +1084,9 @@ class BatchLane:
     def submit_async(self, payload, k: int,
                      window: Optional[float] = None) -> Future:
         """Enqueue without blocking; the future resolves to
-        ``(_BatchResult, row)``. ``window`` overrides this query's
-        batching budget (the EDF deadline is arrival + window)."""
+        ``(_BatchResult, row)``. ``window`` states this query's own
+        batching budget (its EDF deadline is arrival + window, and a
+        free dispatcher holds it until then)."""
         return self._d.enqueue(self, payload, int(k), window=window)
 
     def stats(self) -> Dict[str, Any]:
@@ -1144,14 +1133,27 @@ class BatchDispatcher:
     the dispatcher (``_thread_lock``, making the closed-check + append
     atomic against ``close()``) is never held across a device dispatch
     — submits never wait on device work. The thread moves arrivals into per-lane
-    queues kept sorted by DEADLINE (earliest-deadline-first; deadline =
-    arrival + ``PIO_BATCH_WINDOW``) and dispatches a lane when:
+    queues kept sorted by DEADLINE (earliest-deadline-first; a query's
+    deadline is its arrival, plus a window where a caller stated one)
+    and dispatches a lane when:
 
     - ``size``:   the lane holds ``max_batch`` queries — a full batch
                   amortizes one device dispatch over all of them;
-    - ``window``: the OLDEST query's batching budget expired — light
-      load pays at most the ~2ms window, never an unbounded wait;
+    - ``free``:   the dispatcher is free and nobody asked it to hold
+      the lane's OLDEST query: it goes at once, with whatever queued
+      up behind it while the previous dispatch was in flight;
+    - ``window``: the window a caller stated for the oldest query
+      (``BatchDispatcher(window=...)`` for every query,
+      ``submit_async(..., window=...)`` for one) has run out — a
+      contract, honoured to the letter: the query is held for company
+      until then and no longer;
     - ``drain``:  the dispatcher is closing and flushes what is queued.
+
+    Nothing is held by default, and no setting asks for it: a busy
+    dispatcher batches what arrives during its dispatch in flight, and
+    a free one that waited for company would charge every query the
+    wait for a batch the next dispatch gathers anyway (measured at
+    60-800 qps: PERF.md section 6, PR 45).
 
     A lane whose queries take several device rounds (the slate lane,
     ``ops/slates.py``) RETURNS from its dispatch function the queries
@@ -1172,12 +1174,11 @@ class BatchDispatcher:
 
     name = "pio-microbatch-dispatcher"
 
-    def __init__(self, server: "DeviceTopK",
-                 window: Optional[float] = None):
+    def __init__(self, server: "DeviceTopK", window: float = 0.0):
         # weakref: the dispatcher thread must not pin the server's
         # factor matrices alive after the owner drops it (model swap)
         self._srv_ref = weakref.ref(server)
-        self.window = _batch_window() if window is None else float(window)
+        self.window = float(window)
         # queue deadline resolved ONCE (env read off the submit path);
         # a server restart picks up a changed PIO_QUERY_QUEUE_DEADLINE
         self._deadline = _queue_deadline()
@@ -1341,10 +1342,12 @@ class BatchDispatcher:
                                          Optional[str]]:
         """The lane to dispatch NOW, with its trigger — a full lane
         first, else the lane whose earliest deadline has expired
-        (earliest wins across lanes), else nothing yet. Between two
-        rounds of a lane that handed queries back, the lanes still
-        ``_owed`` a turn are asked first, by the same rule, once each:
-        a long query holds no other lane back for longer than a round."""
+        (earliest wins across lanes; ``window`` where that query was
+        held for a stated window, ``free`` where nothing held it),
+        else nothing yet. Between two rounds of a lane that handed
+        queries back, the lanes still ``_owed`` a turn are asked
+        first, by the same rule, once each: a long query holds no
+        other lane back for longer than a round."""
         if self._owed:
             owed, self._owed = self._owed, []
             lane, trigger = self._due(owed, now)
@@ -1356,7 +1359,7 @@ class BatchDispatcher:
     def _due(self, lanes: List[BatchLane], now: float
              ) -> Tuple[Optional[BatchLane], Optional[str]]:
         best: Optional[BatchLane] = None
-        best_deadline = 0.0
+        head: Optional[_Pending] = None
         for lane in lanes:
             q = lane.queue
             if not q:
@@ -1365,10 +1368,13 @@ class BatchDispatcher:
                 return lane, "drain"
             if len(q) >= lane.max_batch:
                 return lane, "size"
-            d = q[0].deadline
-            if d <= now and (best is None or d < best_deadline):
-                best, best_deadline = lane, d
-        return (best, "window") if best is not None else (None, None)
+            it = q[0]
+            if it.deadline <= now and (head is None
+                                       or it.deadline < head.deadline):
+                best, head = lane, it
+        if head is None:
+            return None, None
+        return best, "window" if head.deadline > head.arrival else "free"
 
     def _next_delay(self, now: float) -> Optional[float]:
         earliest: Optional[float] = None

@@ -374,14 +374,6 @@ def build_parser() -> argparse.ArgumentParser:
                           "$PIO_SLO_* env (windows, burn threshold, "
                           "per-objective budget/thresholdSec/disabled "
                           "— see README 'Fleet observability')")
-    dep.add_argument("--batch-window", type=float, default=None,
-                     metavar="SEC",
-                     help="micro-batch budget in seconds (default "
-                          "0.002; env PIO_BATCH_WINDOW): how long the "
-                          "dispatcher holds a lone query hoping more "
-                          "arrive to share its device dispatch; 0 "
-                          "dispatches as soon as the dispatcher is "
-                          "free")
     _add_metrics_arg(dep)
     _add_tracing_args(dep)
     _add_serve_precision_arg(dep)

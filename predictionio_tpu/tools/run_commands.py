@@ -106,13 +106,6 @@ def _apply_precision_flags(args) -> None:
     serve_kernel = getattr(args, "serve_kernel", None)
     if serve_kernel:
         os.environ["PIO_SERVE_KERNEL"] = serve_kernel
-    # --batch-window -> $PIO_BATCH_WINDOW: the micro-batch dispatcher
-    # resolves the budget at construction, same env-as-truth discipline
-    batch_window = getattr(args, "batch_window", None)
-    if batch_window is not None:
-        if batch_window < 0:
-            raise SystemExit("--batch-window must be >= 0")
-        os.environ["PIO_BATCH_WINDOW"] = repr(float(batch_window))
 
 
 def _apply_checkpoint_flags(args) -> None:

@@ -26,7 +26,9 @@ device-time accounting, applied to the serving plane:
   time (:class:`stage`, :func:`record_dispatch`): ``gapUs`` from the
   previous dispatch's ``block_until_ready`` return to this dispatch's
   program call, of which ``gapIdleUs`` asleep with every lane empty,
-  ``gapWindowUs`` asleep on a batching window, ``pickUs`` moving
+  ``gapWindowUs`` asleep holding a queued query until the window a
+  caller stated for it runs out (0 where none was stated: a
+  dispatcher holds nothing by default), ``pickUs`` moving
   arrivals into the lanes' queues, choosing the lane and putting
   handed-back queries back, ``formUs`` forming the batch, ``bookUs``
   the bookkeeping between a program's end and the next forming (the

@@ -730,7 +730,8 @@ MICROBATCH_BATCH_SIZE = REGISTRY.histogram(
 MICROBATCH_TRIGGERS = REGISTRY.counter(
     "pio_microbatch_dispatch_triggers_total",
     "Dispatches by what formed the batch (size = max_batch reached; "
-    "window = the oldest query's PIO_BATCH_WINDOW budget expired; "
+    "free = the dispatcher was free and nothing held the oldest "
+    "query; window = the window a caller stated for it ran out; "
     "drain = shutdown flush)",
     ("batcher", "trigger"))
 # fill ratio needs its own bounds: COUNT_BUCKETS are absolute sizes,

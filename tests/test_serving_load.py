@@ -257,6 +257,148 @@ class TestBatchFormation:
         d.close()
 
 
+class _Clock:
+    """``time`` with a monotonic clock the test sets."""
+
+    def __init__(self, now=1000.0):
+        self.now = now
+
+    def monotonic(self):
+        return self.now
+
+    def __getattr__(self, name):
+        return getattr(time, name)
+
+
+def _run_on_clock(monkeypatch, arrivals, window=0.0, windows=None):
+    """The dispatcher's own loop (:meth:`BatchDispatcher._run`'s drain,
+    pick, dispatch, else sleep until the next hold ends) stepped by
+    hand on a stated clock, with no thread and no sleep: query ``i``
+    is submitted through ``enqueue`` at ``arrivals[i]`` seconds with
+    ``windows.get(i)`` as its own window, and a dispatch takes no
+    time. Returns ``[(seconds, trigger, [i, ...]), ...]``, a row a
+    dispatch, and the lane."""
+    clock = _Clock()
+    t0 = clock.now
+    monkeypatch.setattr(serving, "time", clock)
+    srv = _Srv()
+    log = []
+    d = BatchDispatcher(srv, window=window)
+    monkeypatch.setattr(d, "_ensure_thread", lambda: None)
+    lane = d.add_lane("t-clock", max_batch=100,
+                      dispatch_fn=lambda s, group: _resolve_all(group))
+    todo = [(t0 + at, i) for i, at in enumerate(arrivals)]
+    while todo or lane.queue:
+        while todo and todo[0][0] <= clock.now:
+            _, i = todo.pop(0)
+            lane.submit_async(i, 5, window=(windows or {}).get(i))
+        d._drain_handoff()
+        picked, trigger = d._pick(clock.now)
+        if picked is not None:
+            queued = [it.payload for it in lane.queue]
+            d._dispatch(picked, trigger)
+            log.append((round(clock.now - t0, 9), trigger,
+                        queued[:len(queued) - len(lane.queue)]))
+            continue
+        delay = d._next_delay(clock.now)
+        ends = [clock.now + delay] if delay is not None else []
+        clock.now = min(ends + [at for at, _ in todo[:1]])
+    return log, lane
+
+
+_DENSE = 2.0 ** -10           # 0.98 ms apart: over 1,000 qps
+_SPARSE = 0.011               # 90 qps
+
+
+def _every(gap, n, start=0.0):
+    return [start + i * gap for i in range(n)]
+
+
+REGIMES = {
+    "cold": [0.0],
+    "dense": _every(_DENSE, 41),
+    "sparse": _every(_SPARSE, 41),
+    # a busy spell, a second of nothing, a busy spell again
+    "bursts": _every(_DENSE, 20) + _every(_DENSE, 21, start=1.0),
+}
+
+
+class TestNoDefaultHold:
+    """A free dispatcher holds a queued query only for a window that a
+    caller stated (PR 45: the constant 2 ms hold of every query is
+    gone), and honours a stated one to the letter whatever the traffic
+    around it. Arrivals come at stated times on a stated clock."""
+
+    def test_lone_query_on_an_idle_default_dispatcher_goes_at_once(self):
+        srv = _Srv()
+        d = BatchDispatcher(srv)
+        lane = d.add_lane("t-free", max_batch=100,
+                          dispatch_fn=lambda s, group: _resolve_all(group))
+        lane.submit(7, 5)
+        st = lane.stats()
+        assert st["dispatchTriggers"] == {"size": 0, "window": 0,
+                                          "free": 1, "drain": 0}
+        assert st["windowSec"] == 0.0
+        d.close()
+
+    @pytest.mark.parametrize("regime", list(REGIMES))
+    def test_default_dispatcher_never_sleeps_on_a_queued_query(
+            self, monkeypatch, regime):
+        arrivals = REGIMES[regime]
+        log, lane = _run_on_clock(monkeypatch, arrivals)
+        # each query at its own arrival, alone (a dispatch takes no
+        # time on this clock), and the loop never asked for a sleep
+        # that ends anywhere but at the next arrival
+        assert log == [(pytest.approx(at, abs=1e-9), "free", [i])
+                       for i, at in enumerate(arrivals)]
+        assert lane.stats()["dispatchTriggers"]["window"] == 0
+
+    def test_queries_that_met_a_busy_dispatcher_share_its_next_dispatch(
+            self, monkeypatch):
+        # five arrive while a dispatch is in flight (here: before the
+        # loop's next turn); the free dispatcher takes them together
+        log, _ = _run_on_clock(monkeypatch, [0.0] * 5)
+        assert log == [(0.0, "free", [0, 1, 2, 3, 4])]
+
+    @pytest.mark.parametrize("regime", list(REGIMES))
+    @pytest.mark.parametrize("where", ["dispatcher", "query"])
+    def test_a_stated_window_is_honoured_in_every_regime(
+            self, monkeypatch, regime, where):
+        # the last query is the probe; everything before it sets the
+        # regime and, where the dispatcher states the window, is held
+        # by the same contract
+        arrivals = REGIMES[regime]
+        stated = 0.05
+        probe = len(arrivals) - 1
+        log, lane = _run_on_clock(
+            monkeypatch, arrivals,
+            window=stated if where == "dispatcher" else 0.0,
+            windows={probe: stated} if where == "query" else None)
+        (at, trig, g), = [row for row in log if probe in row[2]]
+        assert trig == "window"
+        assert at == pytest.approx(arrivals[g[0]] + stated, abs=1e-9)
+        if where == "dispatcher":
+            assert {trig for _, trig, _ in log} == {"window"}
+            assert lane.stats()["windowSec"] == stated
+            # whoever arrived during a hold left with it
+            assert sorted(i for _, _, g in log for i in g) == \
+                list(range(len(arrivals)))
+            assert len(log) == {"cold": 1, "dense": 1, "sparse": 9,
+                                "bursts": 2}[regime]
+        else:
+            assert g == [probe]
+            assert [row[1:] for row in log[:-1]] == \
+                [("free", [i]) for i in range(probe)]
+
+    def test_a_held_query_leaves_with_an_unheld_one_of_its_lane(
+            self, monkeypatch):
+        # EDF as ever: the lane goes when its EARLIEST deadline is due,
+        # and the group is whatever the lane holds
+        log, _ = _run_on_clock(monkeypatch, [0.0, 0.01],
+                               windows={0: 0.05})
+        assert log == [(pytest.approx(0.01, abs=1e-9), "free", [1, 0])]
+
+
 class TestZeroCompileSteadyState:
     """The AOT bucket ladder contract, asserted via the PR-2 jit
     monitor: after warmup, NO query in the warmed envelope compiles."""
@@ -542,7 +684,7 @@ class TestStatsSurface:
         for lane_stats in st.values():
             assert set(lane_stats) == self.EXPECTED_KEYS
             assert set(lane_stats["dispatchTriggers"]) == \
-                {"size", "window", "drain"}
+                {"size", "window", "free", "drain"}
         assert st["users"]["batcher"] == "pio-microbatch"
         assert st["items"]["batcher"] == "pio-microbatch-items"
         # the process-wide aggregation includes both lanes
@@ -554,11 +696,15 @@ class TestStatsSurface:
         rng = np.random.default_rng(4)
         srv = DeviceTopK(rng.normal(size=(10, 4)).astype(np.float32),
                          rng.normal(size=(20, 4)).astype(np.float32))
-        before = metrics.MICROBATCH_TRIGGERS.value(
-            batcher="pio-microbatch", trigger="window")
+        # a lone query on an idle default dispatcher is held for nobody
+        before = {t: metrics.MICROBATCH_TRIGGERS.value(
+            batcher="pio-microbatch", trigger=t)
+            for t in ("free", "window")}
         srv.user_topk(0, 5)
-        assert metrics.MICROBATCH_TRIGGERS.value(
-            batcher="pio-microbatch", trigger="window") == before + 1
+        assert {t: metrics.MICROBATCH_TRIGGERS.value(
+            batcher="pio-microbatch", trigger=t)
+            for t in ("free", "window")} == \
+            {"free": before["free"] + 1, "window": before["window"]}
         fills = metrics.MICROBATCH_FILL.child(batcher="pio-microbatch")
         assert fills.summary()["count"] >= 1
         depth = metrics.MICROBATCH_QUEUE_AT_DISPATCH.child(
