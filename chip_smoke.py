@@ -4,7 +4,7 @@ chip: load -> ``pio train`` -> ``pio deploy --foldin on`` -> queries ->
 online fold-in of a new user -> ``POST /stop``, at the one shape the repo
 names as its target (BASELINE.json: 138,000 users x 27,000 items x
 20,000,000 ``rate`` events, rank 64 — MovieLens-20M-sized, drawn from
-``--seed`` with bench.py's power laws).
+``--seed`` with the power laws of :func:`synthetic_events`).
 
 Every stage that touches the device is a child process running the real
 CLI (``python -m predictionio_tpu.tools.console ...``), one at a time, so
@@ -46,7 +46,7 @@ import numpy as np
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 
-# the published shape (BASELINE.json / bench.py::scale_ingest_bench).
+# the published shape (BASELINE.json).
 # Rank and the two table sizes are never cut; --events may be, never
 # below ``min_events``, and every cut is printed under "reduced".
 FULL = dict(n_users=138_000, n_items=27_000, events=20_000_000,
@@ -173,7 +173,7 @@ def metric_value(exposition: str, name: str) -> float:
 
 def synthetic_events(n_users: int, n_items: int, n_events: int, seed: int,
                      chunk: int):
-    """bench.py::synthetic_ratings' power laws (item popularity
+    """Power-law synthetic ratings (item popularity
     rank^-0.8, user activity rank^-0.6, ratings 1..5), drawn chunk by
     chunk from one generator so the stream never has to exist whole."""
     rng = np.random.default_rng(seed)
@@ -189,7 +189,7 @@ def synthetic_events(n_users: int, n_items: int, n_events: int, seed: int,
 
 
 def event_lines(user_ids, items, ratings) -> List[str]:
-    """``rate`` events as wire-format JSON lines (bench.py's spelling);
+    """``rate`` events as wire-format JSON lines (the event API's spelling);
     ``user_ids`` are entity ids, ``items`` item numbers."""
     return [f'{{"event":"rate","entityType":"user","entityId":"{u}",'
             f'"targetEntityType":"item","targetEntityId":"i{i}",'
@@ -315,7 +315,7 @@ class Smoke:
         app_id = next(int(ln.split("ID:")[1]) for ln in out.splitlines()
                       if "ID:" in ln)
         # bulk set-up through the store's own bulk lane
-        # (append_raw_lines, what `pio import` and bench.py use): 20M
+        # (append_raw_lines, what `pio import` uses): 20M
         # events are data, not traffic
         from predictionio_tpu.data.storage.jsonlfs import JsonlFsLEvents
 

@@ -12,7 +12,7 @@ to call directly. Two consumers share this module:
   thread while the ingest pipeline's H2D transfers stream (PR 6);
 - ``ops/serving.py`` precompiles the query bucket LADDER at deploy so
   no live query ever pays a serve-time compile (SURVEY hard part #4,
-  asserted by the jit-compile monitor in ``bench.serving_load_bench``).
+  asserted by ``tests/test_serving_load.py::TestZeroCompileSteadyState``).
 
 A cache MISS falls back to the plain jit wrapper, which compiles as
 before — correctness never depends on the cache, only latency does. A

@@ -2457,8 +2457,8 @@ class DeviceTopK:
             -> Dict[str, int]:
         """Make EVERY ladder program up to ``max_k`` serve-ready at
         deploy time (SURVEY hard part #4: no live query may ever pay an
-        XLA compile — asserted by the jit-compile monitor in
-        ``bench.serving_load_bench``): AOT-precompile the full
+        XLA compile — asserted by ``tests/test_serving_load.py`` and by
+        the benchmark's ``compiles_in_window``): AOT-precompile the full
         :meth:`aot_plan` ladder, execute the handful AOT declined so
         their jit fallbacks compile NOW, then run one sacrificial query
         per lane to pin the runtime dispatch caches. ``batch_sizes``

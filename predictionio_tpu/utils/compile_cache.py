@@ -1,8 +1,8 @@
 """Where JAX's persistent compilation cache lives — the one place that
 decides it.
 
-Every ``pio`` process that compiles (train, eval, deploy, batchpredict),
-``bench.py`` and ``chip_smoke.py``'s children call :func:`configure`
+Every ``pio`` process that compiles (train, eval, deploy, batchpredict)
+and ``chip_smoke.py``'s children call :func:`configure`
 before their first compile. The bucketed training program takes tens of
 seconds to compile at the ML-20M shape and the serving ladder is dozens
 of programs, so a second process with the same shapes should find them

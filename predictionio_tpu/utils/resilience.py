@@ -29,8 +29,8 @@ and serving path now shares:
   500ing. Serving a stale answer beats serving an error page.
 
 Kill switch: ``PIO_RESILIENCE=0`` (or :func:`set_enabled`) bypasses
-retry + breaker logic entirely — the overhead lane of
-``bench.py::chaos_serving_bench`` measures against it.
+retry + breaker logic entirely (no caller outside
+``tests/test_resilience.py``: ROADMAP Design 5).
 """
 
 from __future__ import annotations

@@ -398,8 +398,8 @@ def warm_up(dep: Deployment,
     Pallas top-k programs (``--serve-kernel fused``, the TPU default)
     ride the same ``aot_plan()`` entries — the store signature and the
     program builders change underneath, the zero-serve-time-compile
-    contract does not (asserted by ``bench.py::serving_load_bench``'s
-    jit monitor for every lane, int8+fused included)."""
+    contract does not (every cell of ``benchmark/`` counts it as
+    ``compiles_in_window``, tier-1 in ``tests/test_serving_load.py``)."""
     for algo, model in zip(dep.algorithms, dep.models):
         # no catch here: a device-served model whose ladder does not
         # compile must fail the deploy with the compiler's message, not
@@ -461,8 +461,8 @@ def _device_reachable() -> bool:
     for 60s only — a backend that recovers must flip readiness back
     without a restart, but a dead one must not hang every poll.
     The probe itself runs on a daemon thread with a bounded join: a
-    hung backend init BLOCKS inside jax.local_devices() forever (the
-    exact hang bench.py's _device_watchdog guards against), and
+    hung backend init BLOCKS inside jax.local_devices() forever (seen
+    when the accelerator's transport is down), and
     healthz liveness is the response itself — it must always return.
     While a probe is still in flight, polls report not-ready without
     stacking further probe threads."""

@@ -204,11 +204,10 @@ class TestQualityGate:
     def test_bf16_precision_at_10_within_gate(self):
         """The hard gate the bf16 policy ships behind: Precision@10 on
         the ml100k-shaped leave-last-out protocol drops at most 0.02
-        absolute vs the fp32 lane (bench_quality.run_precision_check —
-        the same figure the bench reports)."""
-        import bench_quality
+        absolute vs the fp32 lane (quality_gates.run_precision_check)."""
+        import quality_gates
 
-        out = bench_quality.run_precision_check()
+        out = quality_gates.run_precision_check()
         assert out["bf16_precision_at_10"] >= \
             out["fp32_precision_at_10"] - 0.02, out
 
@@ -216,8 +215,8 @@ class TestQualityGate:
         """The same hard gate for the int8 SERVING lane (ISSUE-11):
         scoring through the symmetric per-row absmax round-trip drops
         Precision@10 at most 0.02 absolute vs fp32."""
-        import bench_quality
+        import quality_gates
 
-        out = bench_quality.run_precision_check()
+        out = quality_gates.run_precision_check()
         assert out["int8_serving_precision_at_10"] >= \
             out["fp32_precision_at_10"] - 0.02, out

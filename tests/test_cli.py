@@ -531,8 +531,7 @@ class TestTemplateAndLifecycleVerbs:
 
 class TestPrecisionFlags:
     """--precision / --serve-precision plumbing (the CLI arm of the
-    ops/als.py + ops/serving.py precision policy) and the bench device
-    watchdog's configurable-deadline skip artifact."""
+    ops/als.py + ops/serving.py precision policy)."""
 
     def test_unknown_precision_value_rejected(self, capsys):
         # argparse choices: a typo'd lane must never reach training.
@@ -584,40 +583,3 @@ class TestPrecisionFlags:
         monkeypatch.setenv("PIO_SERVE_PRECISION", "")
         _apply_precision_flags(argparse.Namespace(serve_precision="bf16"))
         assert os.environ.get("PIO_SERVE_PRECISION") == "bf16"
-
-    def test_bench_watchdog_skip_artifact_is_immediate(self):
-        """A probe that FAILS fast (backend init refusing, not hanging)
-        must emit the skip artifact immediately — not burn the full
-        PIO_BENCH_DEVICE_TIMEOUT deadline, and not exit artifact-less."""
-        import json
-        import os
-        import subprocess
-        import sys
-        import time
-
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "bogus"  # backend init raises fast
-        env["PIO_BENCH_DEVICE_TIMEOUT"] = "120"
-        t0 = time.monotonic()
-        proc = subprocess.run(
-            [sys.executable, "-c",
-             "import bench; bench._device_watchdog()"],
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(
-                __file__))),
-            env=env, capture_output=True, text=True, timeout=110)
-        took = time.monotonic() - t0
-        assert proc.returncode == 3
-        assert took < 60, f"skip artifact took {took:.0f}s"
-        artifact = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert artifact["metric"] == \
-            "als_implicit_ml100k_rank64_events_per_sec"
-        assert artifact["value"] == 0
-        assert "failed immediately" in artifact["error"]
-
-    def test_bench_watchdog_timeout_env_override(self, monkeypatch):
-        """PIO_BENCH_DEVICE_TIMEOUT configures the hang deadline; a
-        healthy backend returns well inside it."""
-        import bench
-
-        monkeypatch.setenv("PIO_BENCH_DEVICE_TIMEOUT", "45")
-        bench._device_watchdog()  # healthy CPU backend: returns

@@ -50,7 +50,7 @@ class TestDataMap:
         assert DataMap({"a": 1}) == {"a": 1}
 
     def test_get_mapping_semantics(self):
-        # ADVICE r1: dm.get(key, default) must behave like Mapping.get
+        # dm.get(key, default) must behave like Mapping.get
         d = DataMap({"a": 1})
         assert d.get("a", 0) == 1
         assert d.get("missing", "fallback") == "fallback"

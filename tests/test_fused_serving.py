@@ -446,7 +446,8 @@ class TestDeviceTopKFusedEndToEnd:
                                                  factor_pair):
         """The fused programs ride the AOT ladder: warmup precompiles
         every entry and steady-state queries hit those executables (the
-        serve-time-compile contract the bench asserts end to end)."""
+        serve-time-compile contract the benchmark's
+        ``compiles_in_window`` asserts end to end)."""
         from predictionio_tpu.utils import metrics
 
         X, Y, seen = factor_pair

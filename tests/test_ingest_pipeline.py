@@ -382,16 +382,39 @@ class TestTemplateWiring:
             ds.read_training(ComputeContext())
 
 
+def _write_scale_store(tmp: str, n_users: int, n_items: int, nnz: int,
+                       seed: int):
+    """A partitioned jsonlfs store of power-law ``rate`` events."""
+    from predictionio_tpu.data.storage.jsonlfs import JsonlFsPEvents
+
+    rng = np.random.default_rng(seed)
+    item_p = 1.0 / np.arange(1, n_items + 1) ** 0.8
+    item_p /= item_p.sum()
+    user_p = 1.0 / np.arange(1, n_users + 1) ** 0.6
+    user_p /= user_p.sum()
+    pe = JsonlFsPEvents({"path": tmp, "part_max_events": 1_000_000})
+    pe._l.init(1)
+    rs = rng.choice(n_users, size=nnz, p=user_p)
+    cs = rng.choice(n_items, size=nnz, p=item_p)
+    vs = rng.integers(1, 6, size=nnz)
+    pe._l.append_raw_lines(
+        [f'{{"event":"rate","entityType":"user","entityId":"u{r}",'
+         f'"targetEntityType":"item","targetEntityId":"i{c}",'
+         f'"properties":{{"rating":{v}}},'
+         f'"eventTime":"2020-01-01T00:00:00+00:00"}}'
+         for r, c, v in zip(rs, cs, vs)], 1)
+    return pe
+
+
 @pytest.mark.slow
 class TestEndToEndSmoke:
     def test_store_to_train_one_iteration(self, tmp_path):
         """CI smoke: write a partitioned store, pipelined ingest with
         device staging + warm-up, one bucketed train iteration — all on
         CPU."""
-        from bench import _write_scale_store
         from predictionio_tpu.ops.als import ALSParams, train_als_bucketed
 
-        pe, _ = _write_scale_store(str(tmp_path), 300, 80, 20_000, 21)
+        pe = _write_scale_store(str(tmp_path), 300, 80, 20_000, 21)
         params = ALSParams(rank=8, num_iterations=1, seed=2)
         res = ingest_ratings_pipelined(
             pe.find_columnar_blocks(

@@ -73,7 +73,7 @@ class TestPartitioning:
 
 class TestTornAppendRecovery:
     """A killed writer leaves an unterminated final line; neither the
-    next append nor any reader may be poisoned by it (ADVICE r4)."""
+    next append nor any reader may be poisoned by it."""
 
     def _torn_store(self, tmp_path, n_good=4):
         le = JsonlFsLEvents({"path": str(tmp_path / "ev"),

@@ -1692,10 +1692,9 @@ class TestFeedbackBounded:
 @pytest.mark.slow
 class TestResilienceOverhead:
     def test_hot_path_overhead_small(self, mem_storage):
-        """The bench gate is <3% on the served-query path
-        (``chaos_serving_bench``); this guardrail asserts the raw
-        storage-op wrapper cost stays single-digit-percent against the
-        kill switch on a much cheaper op."""
+        """A CPU guardrail, not a speed: the raw storage-op wrapper
+        cost stays single-digit-percent against the kill switch on a
+        cheap op."""
         le = storage.get_levents()
         le.init(1)
         ids = le.insert_batch([_event(i) for i in range(50)], 1)

@@ -237,7 +237,7 @@ class TestBatchFormation:
     def test_dispatcher_restarts_after_idle_exit(self):
         """The weakref-idle path stops the thread when the server is
         dropped; a dispatcher whose thread died must restart on the
-        next submit (ADVICE.md low: no eternal hang on a dead thread)."""
+        next submit (no eternal hang on a dead thread)."""
         srv = _Srv()
 
         def fn(s, group):
@@ -729,26 +729,3 @@ class TestStatsSurface:
         assert isinstance(payload.get("batchers"), list)
         for lane_stats in payload["batchers"]:
             assert self.EXPECTED_KEYS <= set(lane_stats)
-
-
-@pytest.mark.perf
-@pytest.mark.slow
-class TestServingSLOSmoke:
-    """The perf-marked smoke SLO gate: the closed-loop load bench at
-    the smoke shape must hold a CPU-relaxed p50 and record ZERO jit
-    compiles in steady state (the acceptance criteria, asserted)."""
-
-    def test_load_bench_slo_gate(self):
-        import bench
-
-        r = bench.serving_load_bench(
-            n_users=96, n_items=64, levels=(50.0, 100.0),
-            duration_sec=1.0, clients=4)
-        assert r["zero_compile_steady_state"], \
-            f"{r['jit_compiles_steady_state']} steady-state compiles"
-        assert sum(lv["errors"] for lv in r["levels"]) == 0
-        # CPU-relaxed: the bench-host (accelerator) target is sub-10ms;
-        # a shared CI CPU gets 100ms of headroom against the 150ms
-        # thread-per-request baseline this PR replaces
-        assert r["p50_ms"] is not None and r["p50_ms"] < 100.0
-        assert r["max_sustainable_qps"] is not None

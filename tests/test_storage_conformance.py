@@ -363,7 +363,7 @@ class TestLocalFSModels:
 
 
 class TestSqliteConcurrency:
-    """ADVICE r1: ':memory:' must be one shared database across threads."""
+    """':memory:' must be one shared database across threads."""
 
     def test_memory_db_shared_across_threads(self):
         import threading

@@ -819,8 +819,7 @@ class TestTracingOverhead:
         by the per-query site count and budgets it against a real
         served query's wall time. The fully-enabled lane (100%
         sampling, every span recorded) is additionally bounded as a
-        pathology check; `bench.py::tracing_overhead_bench` reports its
-        exact figure."""
+        pathology check."""
         import http.client
 
         from test_query_server import seed_ratings, train_once

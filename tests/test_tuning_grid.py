@@ -430,11 +430,6 @@ class TestCliGridEval:
         assert algo["params"]["rank"] == 4
         assert algo["params"]["lambda_"] == winner["params"]["lambda"]
         assert ep["datasource"]["params"]["app_name"] == "tuneapp"
-        # bench-schema conformance of the CLI artifact (satellite 6)
-        import bench
-        lane = {"device": "cpu", **board, "leaderboard": board["rows"]}
-        assert bench.artifact_schema_problems(
-            {"accelerator": False, "detail": {"cli": lane}}) == []
 
     def test_rejects_unknown_and_non_sweepable_fields(self, mem_storage,
                                                       tmp_path, capsys):

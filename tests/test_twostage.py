@@ -1192,11 +1192,10 @@ class TestQualityGate:
         seqrec Markov stream: NDCG@10 of the SERVED two-stage list
         (TwoStageTopK.twos_topk) >= max(ALS alone, seqrec alone) —
         fusing retrieval + re-rank into one device program costs no
-        quality (bench_quality.run_twostage_check, the same figure the
-        bench artifact embeds)."""
-        import bench_quality
+        quality (quality_gates.run_twostage_check)."""
+        import quality_gates
 
-        out = bench_quality.run_twostage_check(
+        out = quality_gates.run_twostage_check(
             n_users=80, n_items=50, num_steps=150)
         assert out["gate_ndcg_not_worse"] is True, out
         # the stream is built so the sequence model carries the signal;
