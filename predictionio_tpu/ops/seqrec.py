@@ -165,6 +165,33 @@ class SeqRecParams(Params):
     full_attention_interval: int = 0
     partial_rotary_factor: float = 1.0
     shared_expert_width: int = 0
+    # the falcon_h1 block (ops/falconh1.py), under config.json's names:
+    # every layer runs ``mamba_n_heads`` Mamba-2 heads of
+    # ``mamba_d_head`` (state ``mamba_d_state``, B and C in
+    # ``mamba_n_groups`` groups, a convolution of ``mamba_d_conv``, the
+    # chunked form in chunks of ``mamba_chunk_size``; the gated norm
+    # AFTER the gate: ``mamba_norm_before_gate`` false, the one form
+    # published) BESIDE its attention heads, then a dense SwiGLU of
+    # ``intermediate_size``;
+    # the muP multipliers scale what their names say
+    # (``ssm_multipliers``: the z, x, B, C and dt slices of the input
+    # projection; ``mlp_multipliers``: the gate's input, the output)
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 0
+    mamba_d_conv: int = 0
+    mamba_chunk_size: int = 0
+    intermediate_size: int = 0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    ssm_out_multiplier: float = 1.0
+    mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
     # the session lane that serves these blocks (ops/sessions.py): the
     # cache pool's rows (0: twice the stored histories) and how many
     # dispatches' audits it keeps for a check to read (0: the
@@ -242,6 +269,26 @@ QWEN3_NEXT_80B_A3B = dict(
     full_attention_interval=4)
 
 
+# the block of Falcon-H1-34B-Instruct
+# (https://huggingface.co/tiiuae/Falcon-H1-34B-Instruct, model_type
+# falcon_h1) as its config.json publishes it; ``n_layers`` and the rows
+# of the tables are the deployment's
+FALCON_H1_34B = dict(
+    block="falcon_h1", rank=5120, n_heads=20, n_kv_heads=4, head_dim=128,
+    norm="rmsnorm", norm_eps=1e-5, positions="rope", rope_theta=1e11,
+    tied=False, vocab_rows=261120, intermediate_size=21504,
+    mamba_n_heads=32, mamba_d_head=128, mamba_d_state=256,
+    mamba_n_groups=2, mamba_d_conv=4, mamba_chunk_size=128,
+    attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+    key_multiplier=0.011048543456039804,
+    embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+    ssm_in_multiplier=0.25,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    ssm_out_multiplier=0.08838834764831845,
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284))
+
+
 @dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What of :class:`SeqRecParams` shapes the compiled programs (the
@@ -266,6 +313,7 @@ class BlockSpec:
     sdar: Any = None  # ops/sdar.py::SdarSpec of the sdar_moe block
     swa: Any = None   # ops/smallthinker.py::SwaSpec of the smallthinker block
     lin: Any = None   # ops/qwen3next.py::LinSpec of the qwen3_next block
+    hyb: Any = None   # ops/falconh1.py::HybSpec of the falcon_h1 block
 
     @property
     def sparse(self) -> bool:
@@ -322,6 +370,11 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         from predictionio_tpu.ops import qwen3next
 
         lin = qwen3next.lin_spec(params)
+    hyb = None
+    if params.block == "falcon_h1":
+        from predictionio_tpu.ops import falconh1
+
+        hyb = falconh1.hyb_spec(params)
     return BlockSpec(
         params.block, int(params.n_layers), H, head_dim, params.norm,
         float(params.norm_eps), params.positions,
@@ -329,7 +382,7 @@ def block_spec(params: SeqRecParams) -> BlockSpec:
         int(params.n_experts) if sparse else 0,
         int(params.experts_per_token) if sparse else 0,
         float(params.lb_coef), float(params.z_coef),
-        params.compute_dtype, glm, sdar, swa, lin)
+        params.compute_dtype, glm, sdar, swa, lin, hyb)
 
 
 @dataclasses.dataclass
@@ -546,6 +599,10 @@ def _theta_shapes(n_items: int, params: SeqRecParams
         from predictionio_tpu.ops import qwen3next
 
         return qwen3next.theta_shapes(V, spec.lin)
+    if spec.hyb is not None:
+        from predictionio_tpu.ops import falconh1
+
+        return falconh1.theta_shapes(V, spec.hyb)
     A = spec.n_heads * spec.head_dim
     out: List[Tuple[str, Tuple[int, ...], Any]] = [
         ("item_emb", (V, D), ("div", math.sqrt(D)))]
@@ -823,11 +880,22 @@ def _qwen3next_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
     return qwen3next.qwen3next_layer(theta, i, x, seg, pos, spec.lin), None
 
 
+def _falconh1_layer(theta, i: int, x, seg, pos, keep, spec: BlockSpec,
+                    attention_fn, low):
+    """Falcon-H1's layer (``ops/falconh1.py``): attention heads and
+    Mamba-2 heads (the chunked form from a zero state; ONE segment a
+    row) side by side on one normed input, one residual add for both,
+    then a dense SwiGLU."""
+    from predictionio_tpu.ops import falconh1
+
+    return falconh1.falconh1_layer(theta, i, x, seg, pos, spec.hyb), None
+
+
 # one function per layer kind; ``SeqRecParams.block`` names one
 BLOCKS = {"sasrec": _sasrec_layer, "olmoe": _olmoe_layer,
           "glm_moe_dsa": _glm_layer, "sdar_moe": _sdar_layer,
           "smallthinker": _smallthinker_layer,
-          "qwen3_next": _qwen3next_layer}
+          "qwen3_next": _qwen3next_layer, "falcon_h1": _falconh1_layer}
 
 
 def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
@@ -855,6 +923,8 @@ def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
     x = jnp.take(theta["item_emb"], ids, axis=0)
     if spec.block == "sasrec":
         x = x * math.sqrt(D)
+    if spec.hyb is not None:
+        x = x * spec.hyb.emb_mult
     if spec.positions == "learned":
         x = x + jnp.take(theta["pos_emb"], pos, axis=0)
     x = x * keep
@@ -865,6 +935,10 @@ def encoder_forward(theta, ids, seg, pos=None, *, spec: BlockSpec,
                       low or {})
         stats.append(st)
     x = _norm(theta, "ln_f", x, spec)
+    if spec.hyb is not None:
+        # (so that a state scores against the output table as
+        # published: ``W_head h * lm_head_multiplier``)
+        x = x * spec.hyb.head_mult
     return x * keep, stats
 
 
@@ -1277,6 +1351,11 @@ def train_seqrec(buckets, n_items: int, params: SeqRecParams,
         raise ValueError(
             "the qwen3_next block is not trained here (the chunked "
             "rule has no backward pass and no training cell holds it: "
+            "ROADMAP Reach). Serve it with numSteps 0 and seededWeights")
+    if spec.hyb is not None and int(params.num_steps) > 0:
+        raise ValueError(
+            "the falcon_h1 block is not trained here (the chunked SSD "
+            "form has no backward pass and no training cell holds it: "
             "ROADMAP Reach). Serve it with numSteps 0 and seededWeights")
     with _tracing.span("seq.stage"):
         if theta is None:

@@ -11,7 +11,8 @@ ladder) whose store holds, beside the output table ``Y``:
   ``ops/sdar.py``; the ``smallthinker`` block,
   :class:`SmallThinkerBackbone` over ``ops/smallthinker.py``; the
   ``qwen3_next`` block, :class:`Qwen3NextBackbone` over
-  ``ops/qwen3next.py``);
+  ``ops/qwen3next.py``; the ``falcon_h1`` block,
+  :class:`FalconH1Backbone` over ``ops/falconh1.py``);
 - ``X``: every user's LAST hidden state (final norm applied), so that
   the inherited ``users`` lane answers a query without new events;
 - a POOL of cache blocks: per layer one array ``[blocks, bs, width]``
@@ -43,6 +44,15 @@ ladder) whose store holds, beside the output table ``Y``:
   forgets its sessions (:meth:`SessionTopK._forget`: the next touch
   prefills them again from the host's events), so that no query ever
   runs on a state ahead of its session's length.
+- BOTH in one layer: the kinds' layer lists may OVERLAP. Falcon-H1's
+  every layer runs attention heads and Mamba-2 heads side by side, so
+  its block kind (key and value rows) and its slot kind (the state-space
+  state and the convolution's tail) name the SAME layers: the cache
+  rows' arrays are indexed by a layer's place among the layers of its
+  BLOCK kind, a slot kind's arrays by its place in that slot kind, a
+  query row carries one block table AND one slot id that the same
+  layer's program reads, and every count (``memory_report()``,
+  ``session_report()``) takes a layer once a kind.
 
 The manager keeps the blocks, the tables, allocation, release and
 eviction over all kinds (a session leaves every kind at once), the
@@ -116,7 +126,9 @@ class LayerKind(NamedTuple):
     ``cache_rows`` (``keep``: how many trailing positions a layer of
     that kind reads; None: all) or, with ``state``, one SLOT of the
     arrays it names: ``(name, shape, dtype, the component's name in
-    memory_report())``."""
+    memory_report())``. A layer belongs to at most ONE block kind and
+    to any number of slot kinds: a block kind and a slot kind may name
+    the same layers (a layer with two memories)."""
 
     name: str
     layers: Tuple[int, ...]
@@ -571,20 +583,26 @@ class Qwen3NextBackbone(SmallThinkerBackbone):
 
         return qwen3next
 
-    def __init__(self, params):
+    def _shape(self, params):
+        """``(spec, positions a chunk of the chunked form holds, tokens
+        a prefill chunk holds at most)``."""
         ops = self._ops()
-        self.spec = spec = ops.lin_spec(params)
+        return ops.lin_spec(params), ops.GDN_CHUNK, LIN_CHUNK
+
+    def __init__(self, params):
+        self.spec, scan_chunk, tokens = self._shape(params)
+        spec = self.spec
         self.width = spec.width
         self.compute_dtype = spec.compute_dtype
         self.kinds = tuple(LayerKind(*k) for k in spec.kinds)
         self.max_positions = int(params.max_seq_len)
         self.cache_rows = (("k", spec.kv_width, "sessionKeys"),
                            ("v", spec.kv_width, "sessionValues"))
-        # a prefill chunk: whole chunks of the rule's chunked form, a
-        # quarter of the longest session's bucket at most; the shortest
+        # a prefill chunk: whole chunks of the chunked form, a quarter
+        # of the longest session's bucket at most; the shortest
         # cached-length bucket is four chunks
-        self.chunk = max(ops.GDN_CHUNK, min(
-            LIN_CHUNK, _bucket(max(self.max_positions, 16)) // 4))
+        self.chunk = max(scan_chunk, min(
+            tokens, _bucket(max(self.max_positions, 16)) // 4))
         self.floor = 4 * self.chunk
         self.qb = min(32, self.chunk)
 
@@ -600,6 +618,37 @@ class Qwen3NextBackbone(SmallThinkerBackbone):
                 counter.inc(amount=amount, **labels)
 
 
+HYB_CHUNK = 2048        # tokens a prefill chunk of Falcon-H1 holds
+
+
+class FalconH1Backbone(Qwen3NextBackbone):
+    """Falcon-H1's block (``ops/falconh1.py``) as the session lane
+    serves it: TWO kinds over the SAME layers: every layer holds key
+    and value rows in BLOCKS (every position kept) AND one SLOT a
+    session (the Mamba-2 heads' float32 state and the convolution's
+    tail), advanced in place by every dispatch. A query is answered by
+    ONE extend dispatch, events committed one by one, a session held to
+    the model's ``max_position_embeddings``."""
+
+    program_prefix = "hyb"
+
+    @staticmethod
+    def _ops():
+        from predictionio_tpu.ops import falconh1
+
+        return falconh1
+
+    def _shape(self, params):
+        spec = self._ops().hyb_spec(params)
+        return spec, spec.chunk, HYB_CHUNK
+
+    @staticmethod
+    def _book_counters(read, *_spare) -> None:
+        """``falconh1.extend_step``'s counters."""
+        if read > 0:
+            _metrics.SESS_ROWS_READ.inc(amount=read, kind="attn")
+
+
 def backbone_of(params):
     """The backbone that serves ``params.block`` from per-user
     caches."""
@@ -613,9 +662,12 @@ def backbone_of(params):
         return SmallThinkerBackbone(params)
     if params.block == "qwen3_next":
         return Qwen3NextBackbone(params)
+    if params.block == "falcon_h1":
+        return FalconH1Backbone(params)
     raise ValueError(
         f"no session backbone for block {params.block!r}: the lane "
-        "serves glm_moe_dsa, sdar_moe, smallthinker and qwen3_next")
+        "serves glm_moe_dsa, sdar_moe, smallthinker, qwen3_next and "
+        "falcon_h1")
 
 
 class SessionTopK(DeviceTopK):
@@ -704,23 +756,34 @@ class SessionTopK(DeviceTopK):
 
         self._kind_blocks = [1 + sized(k, kind, n) for k, (kind, n)
                              in enumerate(zip(self._kinds, need))]
-        self._layer_kind = {i: k for k, kind in enumerate(self._kinds)
-                            for i in kind.layers}
+        # a layer's BLOCK kind (a layer a slot kind alone names has
+        # none and holds no cache rows); a slot kind's arrays are
+        # indexed by a layer's place in that kind's own list
+        self._layer_kind: Dict[int, int] = {}
+        for k, kind in enumerate(self._kinds):
+            for i in (() if kind.state else kind.layers):
+                if i in self._layer_kind:
+                    raise ValueError(
+                        f"layer {i} is in two block kinds: one table "
+                        "names a session's rows in a layer")
+                self._layer_kind[i] = k
         self._slotted = any(kind.state for kind in self._kinds)
         cache_dtype = jnp.dtype(bb.compute_dtype)
         with self._store_lock, _trace_span("store.upload"):
             # a pool array a layer THAT HOLDS IT, in layer order: the
             # cache rows in the layers of the block kinds, a slot
-            # kind's arrays in its own
+            # kind's arrays in its own (the same layers, where a layer
+            # has both)
             self._pool = {
                 name: tuple(jnp.zeros(
                     (self._kind_blocks[self._layer_kind[i]], self._bs,
                      width), cache_dtype)
-                    for i in range(bb.spec.n_layers)
-                    if not self._kinds[self._layer_kind[i]].state)
+                    for i in sorted(self._layer_kind))
                 for name, width, _ in bb.cache_rows}
             for k, kind in enumerate(self._kinds):
                 for name, shape, dtype, _ in kind.state:
+                    if name in self._pool:
+                        raise ValueError(f"two pool arrays named {name!r}")
                     self._pool[name] = tuple(
                         jnp.zeros((self._kind_blocks[k],) + tuple(shape),
                                   jnp.dtype(dtype)) for _ in kind.layers)
@@ -1217,8 +1280,7 @@ class SessionTopK(DeviceTopK):
             sess = self._sessions.get(int(uid))
             if sess is None or not sess.length:
                 return None
-            held = [i for i in range(self._bb.spec.n_layers)
-                    if not self._kinds[self._layer_kind[i]].state]
+            held = sorted(self._layer_kind)
             out: Dict[str, Any] = {}
             for name, width, _ in self._bb.cache_rows:
                 out[name] = np.stack([np.asarray(a[jnp.asarray(
@@ -1308,9 +1370,11 @@ class SessionTopK(DeviceTopK):
         held += [(name, component, kind_dtype) for kind in self._kinds
                  for name, _, kind_dtype, component in kind.state]
         for name, component, of in held:
-            extra[component] = {
-                "bytes": int(sum(a.nbytes for a in pool[name])),
-                "scaleBytes": 0, "dtype": of}
+            # (a pool array is counted once, under its component: the
+            # kinds may share layers, never an array)
+            entry = extra.setdefault(
+                component, {"bytes": 0, "scaleBytes": 0, "dtype": of})
+            entry["bytes"] += int(sum(a.nbytes for a in pool[name]))
         report["components"].update(extra)
         report["totalBytes"] += sum(c["bytes"] for c in extra.values())
         report["sessions"] = self.session_report()

@@ -107,7 +107,8 @@ class DataSourceParams(Params):
 
 
 # blocks served from per-user caches (``ops/sessions.py``)
-SESSION_BLOCKS = ("glm_moe_dsa", "sdar_moe", "smallthinker", "qwen3_next")
+SESSION_BLOCKS = ("glm_moe_dsa", "sdar_moe", "smallthinker", "qwen3_next",
+                  "falcon_h1")
 
 
 class SequenceTrainingData:
@@ -315,8 +316,8 @@ class SeqRecModel(_DeviceServedModel):
     RE-ENCODE a user's sequence instead of re-solving a linear
     system.
 
-    A ``glm_moe_dsa``, ``sdar_moe``, ``smallthinker`` or ``qwen3_next``
-    block is SESSION-served instead
+    A ``glm_moe_dsa``, ``sdar_moe``, ``smallthinker``, ``qwen3_next`` or
+    ``falcon_h1`` block is SESSION-served instead
     (``ops/sessions.py::SessionTopK``): a backbone that wide has no
     user-vector table worth holding, so each user's history lives on
     the device as a cache (built at deploy from ``histories``) and a

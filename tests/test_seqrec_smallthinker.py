@@ -479,7 +479,8 @@ def test_engine_json_selects_the_block():
     assert (spec.kv_width, spec.group, spec.window, spec.pattern) \
         == (512, 7, 4096, (0, 1, 1, 1, 0, 1, 1, 1))
     with pytest.raises(ValueError, match="glm_moe_dsa, sdar_moe, "
-                                         "smallthinker and qwen3_next"):
+                                         "smallthinker, qwen3_next and "
+                                         "falcon_h1"):
         sessions.backbone_of(S.SeqRecParams(block="olmoe"))
 
 
