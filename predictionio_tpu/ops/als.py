@@ -795,8 +795,8 @@ def _spd_solve(A, b, mode: str):
     whole matrix batch through HBM on every column (129 ms per 16,384
     rank-64 systems on a v5e) and :func:`spd_solve_lanes` once a panel
     (29.6 ms); the Pallas kernel keeps 128 systems in VMEM for all R
-    steps (4.5 ms alone, 3.7 in the training program), so it is the
-    default there up to
+    steps (1.9 ms since PR 49, 3.7 before), so it is the default there
+    up to
     ``als_pallas.SPD_MAX_RANK``. CPU/GPU keep LAPACK-backed cho_solve."""
     import jax
 

@@ -17,12 +17,14 @@ Three kernels live here, two the trainer's and one the server's:
    so one written under ``lanes`` is refused on resume under
    ``pallas`` (``CheckpointMismatchError``). Runs through Mosaic on a
    v5e at ranks 8, 10, 20, 50, 64 and 96 and agrees with LAPACK there
-   (rank 96 needs 18.3 MiB of VMEM and asks for it by name); in the
-   ML-20M training program 16,384 rank-64 systems take 3.7 ms where
-   ``lanes`` took 29.6, 13% of an iteration that is now mostly gather
-   and assembly (PERF.md sections 5 and 6, PR 26). Each of the R
-   steps updates the whole ``R x R`` block where only the trailing
-   part is live: the open item of PERF.md section 7.
+   (rank 96 holds 13.8 MiB of VMEM and asks for it by name); 16,384
+   rank-64 systems take 1.88 ms where ``lanes`` took 29.6 (PERF.md
+   sections 5 and 6, PR 26). Until PR 49 each of the R steps updated
+   the whole ``R x R`` block (3.68 ms); now a step updates the row
+   blocks that still have a live row, from each block's diagonal on,
+   and ``x`` is the same to the bit. What is left is half stores and
+   half the serial head of a step (pivot, ``sqrt``, divide, the
+   sublane shuffles): PERF.md section 7.
    ``spd_solve_batch_minor`` is the same kernel on systems that lie
    batch-minor already, which is how kernel 2 writes them.
 
@@ -99,9 +101,14 @@ from typing import Optional
 _SPD_BB = 128
 # room beside the kernel's own buffers for Mosaic's internal scratch
 _SPD_VMEM_MARGIN = 4 << 20
+# rows of a block of the trailing update: whole sublane tiles, so that a
+# block's columns start on one. Blocks of 8 store a quarter less and
+# take the same time on a v5e (twice the predicates) for 44 more
+# equations a kernel to trace and lower (PERF.md section 6, PR 49)
+_SPD_ROWS = 16
 
 
-def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, lt, ywork, bwork):
+def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, ywork, bwork):
     """Solve ``A x = b`` for one block of ``BB`` SPD systems.
 
     Layout is the whole trick: the batch lives on the LANE dimension
@@ -115,9 +122,23 @@ def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, lt, ywork, bwork):
     measured ALS bottleneck this kernel replaces.)
 
     The trailing update uses the symmetry of A: column k == row k, so
-    the pivot column is ``awork[k]`` directly."""
+    the pivot column is ``awork[k]`` directly, and only the upper
+    triangle is ever read. What step ``k`` writes (PR 49): the rows are
+    cut into blocks of ``_SPD_ROWS``, a block that still has a row
+    ``i > k`` gets ``a[i, j] -= u_i u_j`` on its rows and on the
+    columns from its own first row on (the upper triangle by block,
+    the diagonal block whole; ``u`` is zero up to ``k``, so the rows
+    ``<= k`` of the block the step stands in are rewritten as they
+    were), and a block the steps have passed is skipped (but the last,
+    which only the last step has passed). Then row
+    ``k``, dead from here on, takes ``L``'s column ``k``, zeros above
+    the diagonal: the substitutions read ``L^T`` out of ``awork``.
+    Every entry that is read has seen the arithmetic of the whole-block
+    update this replaces, in its order: ``x`` is that kernel's to the
+    bit (``tests/als_reference.py::spd_solve_whole_block``)."""
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
     R = a_ref.shape[0]
     awork[:] = a_ref[:]
@@ -130,20 +151,33 @@ def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, lt, ywork, bwork):
         ge = (iota_r >= k).astype(jnp.float32)
         lcol = c * inv[None, :] * ge                # L[:, k], rows >= k
         u = lcol * (iota_r > k).astype(jnp.float32)
-        awork[:] = awork[:] - u[None, :, :] * u[:, None, :]
-        lt[k] = lcol                                # Lt row k == L col k
+        for lo in range(0, R, _SPD_ROWS):
+            hi = min(lo + _SPD_ROWS, R)
+
+            def update():                           # traced in this pass
+                awork[lo:hi, lo:, :] = (
+                    awork[lo:hi, lo:, :]
+                    - u[lo:][None, :, :] * u[lo:hi][:, None, :])
+
+            # the last block is dead at the last step alone, where u is
+            # zero: no predicate (and none at all up to _SPD_ROWS ranks)
+            if hi == R:
+                update()
+            else:
+                pl.when(k < hi - 1)(update)
+        awork[k] = lcol                             # Lt row k == L col k
         return 0
 
     jax.lax.fori_loop(0, R, fact_step, 0)
 
-    # forward substitution L y = b, column sweep: rows < k of lt[k] are
-    # zero, so the update never touches already-solved entries
+    # forward substitution L y = b, column sweep: rows < k of Lt's row k
+    # are zero, so the update never touches already-solved entries
     bwork[:] = b_ref[:]
 
     def fwd_step(k, _):
-        yk = bwork[k] / lt[k, k]
+        yk = bwork[k] / awork[k, k]
         ywork[k] = yk
-        bwork[:] = bwork[:] - lt[k] * yk[None, :]
+        bwork[:] = bwork[:] - awork[k] * yk[None, :]
         return 0
 
     jax.lax.fori_loop(0, R, fwd_step, 0)
@@ -153,9 +187,9 @@ def _spd_solve_kernel(a_ref, b_ref, x_ref, awork, lt, ywork, bwork):
 
     def bwd_step(i, _):
         k = R - 1 - i
-        ltk = lt[k]                                 # Lt row k over j >= k
+        ltk = awork[k]                              # Lt row k over j >= k
         s = jnp.sum(ltk * x_ref[:], axis=0)         # x[k] still 0
-        x_ref[k] = (ywork[k] - s) / lt[k, k]
+        x_ref[k] = (ywork[k] - s) / awork[k, k]
         return 0
 
     jax.lax.fori_loop(0, R, bwd_step, 0)
@@ -170,11 +204,9 @@ def _build_spd(B: int, R: int, interpret: bool):
 
     assert B % _SPD_BB == 0
     # what the kernel holds in VMEM, to the byte: the [R, R, BB] input
-    # block twice (the pipeline double-buffers it), awork and lt once,
-    # b and x twice, ywork and bwork once. Rank 96 needs 18.3 MiB, over
-    # the compiler's default scoped limit of 16; smaller ranks ask for
-    # less than the default and leave the rest to the program around
-    vmem_bytes = 4 * _SPD_BB * (4 * R * R + 6 * R)
+    # block twice (the pipeline double-buffers it), awork once, b and x
+    # twice, ywork and bwork once: 13.8 MiB at rank 96
+    vmem_bytes = 4 * _SPD_BB * (3 * R * R + 6 * R)
     fn = pl.pallas_call(
         _spd_solve_kernel,
         grid=(B // _SPD_BB,),
@@ -186,7 +218,6 @@ def _build_spd(B: int, R: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((R, B), jnp.float32),
         scratch_shapes=[
             pltpu.VMEM((R, R, _SPD_BB), jnp.float32),   # awork
-            pltpu.VMEM((R, R, _SPD_BB), jnp.float32),   # lt
             pltpu.VMEM((R, _SPD_BB), jnp.float32),      # ywork
             pltpu.VMEM((R, _SPD_BB), jnp.float32),      # bwork
         ],
@@ -198,8 +229,9 @@ def _build_spd(B: int, R: int, interpret: bool):
     return fn
 
 
-# above this rank the three [R, R, BB] VMEM buffers exceed scoped VMEM;
-# ops.als._resolve_spd_solver names spd_solve_lanes there
+# above this rank ops.als._resolve_spd_solver names spd_solve_lanes: the
+# line ISSUE 26 drew (the kernel's three [R, R, BB] buffers are 24 MiB
+# of VMEM at rank 128; PERF.md section 7)
 SPD_MAX_RANK = 96
 
 

@@ -1,8 +1,11 @@
 """Plain-numpy ALS over (row, col, value) triples: the reference the
 trainers in ``ops/als.py`` are held to. It shares nothing with them but
 the seeded start (``init_factors``): no padding, no buckets, float64,
-one ``np.linalg.solve`` a row."""
+one ``np.linalg.solve`` a row. And the recurrence the Pallas SPD kernel
+is held to, to the bit (:func:`spd_solve_whole_block`)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.ops.als import (
@@ -69,3 +72,43 @@ def train_from_triples(rows, cols, vals, n_users, n_items,
     """The one-device trainer on both sides of the same triples."""
     return train_als_bucketed(
         *bucket_ratings_pair(rows, cols, vals, n_users, n_items), params)
+
+
+@jax.jit
+def spd_solve_whole_block(A, b):
+    """``x: A @ x = b`` for ``A [B, R, R]``, ``b [B, R]`` by the
+    recurrence ``als_pallas.spd_solve`` ran until PR 49, in plain
+    ``jax.numpy``: batch-minor, float32, every one of the R steps
+    subtracting ``u u^T`` from the WHOLE ``[R, R]`` block. The kernel
+    updates only what a later step reads; each entry it reads has seen
+    these operations in this order, so its ``x`` equals this one to the
+    bit, for a symmetric ``A`` or not (nothing below the diagonal is
+    read, here or there)."""
+    R = b.shape[1]
+    a = jnp.transpose(A.astype(jnp.float32), (1, 2, 0))    # [R, R, B]
+    rhs = b.astype(jnp.float32).T                          # [R, B]
+    iota_r = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+
+    def fact_step(k, carry):
+        a, lt = carry
+        d = jnp.maximum(a[k, k], 1e-30)
+        inv = 1.0 / jnp.sqrt(d)
+        lcol = a[k] * inv[None, :] * (iota_r >= k).astype(jnp.float32)
+        u = lcol * (iota_r > k).astype(jnp.float32)
+        return a - u[None, :, :] * u[:, None, :], lt.at[k].set(lcol)
+
+    _, lt = jax.lax.fori_loop(0, R, fact_step, (a, jnp.zeros_like(a)))
+
+    def fwd_step(k, carry):
+        rhs, y = carry
+        yk = rhs[k] / lt[k, k]
+        return rhs - lt[k] * yk[None, :], y.at[k].set(yk)
+
+    _, y = jax.lax.fori_loop(0, R, fwd_step, (rhs, jnp.zeros_like(rhs)))
+
+    def bwd_step(i, x):
+        k = R - 1 - i
+        s = jnp.sum(lt[k] * x, axis=0)                     # x[k] still 0
+        return x.at[k].set((y[k] - s) / lt[k, k])
+
+    return jax.lax.fori_loop(0, R, bwd_step, jnp.zeros_like(rhs)).T

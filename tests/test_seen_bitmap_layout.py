@@ -381,9 +381,10 @@ def test_v5e_sharded_user_program_does_not_copy_the_bitmap(
 # ---------------------------------------------------------------------------
 
 # B = 300 is three grid steps, so the input block is double-buffered:
-# at rank 96 the kernel then needs 18.3 MiB of VMEM, over the compiler's
-# default scoped limit (the chip refused it until the kernel asked for
-# its bytes by name); rank 10 is a sublane count off the multiple of 8
+# at rank 96 the kernel then holds 13.8 MiB of VMEM and asks for it by
+# name (until PR 49 kept L in the working block it was 18.3, which the
+# chip refused under the compiler's default scoped limit); rank 10 is a
+# sublane count off the multiple of 8
 @pytest.mark.parametrize("rank", [8, 10, 64, 96])
 def test_v5e_spd_solve_lowers_at_every_template_rank(rank, one_chip):
     import jax

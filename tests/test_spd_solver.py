@@ -11,6 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from als_reference import spd_solve_whole_block
 
 from predictionio_tpu.ops.als import (
     ALSParams,
@@ -41,6 +42,16 @@ def ill_scaled_systems(B, R, seed=3):
     A = ((M @ M.transpose(0, 2, 1)) * scales
          + 0.01 * np.eye(R, dtype=np.float32)).astype(np.float32)
     b = rng.normal(size=(B, R)).astype(np.float32)
+    return A, b
+
+
+def asymmetric_systems(B, R, seed=7):
+    """``spd_systems`` with garbage below the diagonal: the kernel reads
+    the upper triangle alone (the pivot row stands in for the column)."""
+    A, b = spd_systems(B, R, seed)
+    lower = np.tril_indices(R, -1)
+    A[:, lower[0], lower[1]] = 50 * np.random.default_rng(seed).normal(
+        size=(B, len(lower[0]))).astype(np.float32)
     return A, b
 
 
@@ -117,6 +128,47 @@ class TestPallasKernelInterpret:
             want = np.linalg.solve(A.astype(np.float64),
                                    b.astype(np.float64)[..., None])[..., 0]
             np.testing.assert_allclose(x, want, rtol=2e-3, atol=2e-4)
+
+    # same shapes as above; PR 49 cut the trailing update down to the
+    # entries a later step reads, and nothing else may have moved
+    @pytest.mark.parametrize("B,R", [(5, 8), (130, 10), (257, 64),
+                                     (40, 96)])
+    @pytest.mark.parametrize("systems", [spd_systems, ill_scaled_systems,
+                                         asymmetric_systems])
+    def test_equals_the_whole_block_recurrence_to_the_bit(self, B, R,
+                                                          systems):
+        from predictionio_tpu.ops.als_pallas import spd_solve
+
+        A, b = (jnp.asarray(a) for a in systems(B, R))
+        x = np.asarray(spd_solve(A, b, interpret=True))
+        want = np.asarray(spd_solve_whole_block(A, b))
+        assert np.isfinite(want).all()
+        np.testing.assert_array_equal(x.view(np.int32),
+                                      want.view(np.int32))
+
+    # every equation of the kernel's body is traced and lowered once a
+    # bucket, 21 solve kernels in the ML-20M program, at 0.1-0.2 ms
+    # each: `setup_s` is bound at 10% and refused PR 41 on 0.73 s. The
+    # ceilings are what PR 49 landed with (90 and 112) plus a tenth; the
+    # whole-block kernel before it had 56 at every rank, as this one
+    # still has up to rank 16
+    @pytest.mark.parametrize("R,ceiling", [(64, 99), (96, 123)])
+    def test_kernel_body_stays_small(self, R, ceiling):
+        from predictionio_tpu.ops.als_pallas import _SPD_BB, _build_spd
+
+        def equations(jaxpr):
+            n = len(jaxpr.eqns)
+            for eqn in jaxpr.eqns:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    n += equations(sub)
+            return n
+
+        outer = jax.make_jaxpr(_build_spd(_SPD_BB, R, True))(
+            jax.ShapeDtypeStruct((R, R, _SPD_BB), jnp.float32),
+            jax.ShapeDtypeStruct((R, _SPD_BB), jnp.float32))
+        (call,) = [e for e in outer.jaxpr.eqns
+                   if e.primitive.name == "pallas_call"]
+        assert equations(call.params["jaxpr"]) <= ceiling
 
     def test_rank_128_takes_lanes_and_is_named(self, monkeypatch):
         """Above the kernel's rank the resolver says ``lanes`` — in the
